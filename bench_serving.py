@@ -131,8 +131,9 @@ def build_toy(sparse=False):
     import jax.numpy as jnp
     import numpy as np
 
-    if os.environ.get("DALLE_TPU_FORCE_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["DALLE_TPU_FORCE_PLATFORM"])
+    from dalle_pytorch_tpu.utils.compile_cache import enable_xla_cache
+
+    enable_xla_cache()  # before the first compile
 
     from dalle_pytorch_tpu.models.dalle import DALLE
     from dalle_pytorch_tpu.models.dvae import DiscreteVAE
@@ -1948,23 +1949,37 @@ def main_restart_bench():
        ejected->half_open->healthy rejoin accounting.
     """
     import os as _os
+    import shutil
     import signal as _signal
-    import tempfile
-    from pathlib import Path
+    import subprocess
+    import sys
 
     import numpy as np
 
     from dalle_pytorch_tpu.serving.router import FleetRouter, RouterServer
     from dalle_pytorch_tpu.training.metrics import MetricsRegistry
+    from dalle_pytorch_tpu.utils.compile_cache import xla_cache_dir
 
+    # One process per chip: this parent never imports jax. The checkpoint
+    # is built by a child that exits before the first replica starts, and
+    # the replicas run on whatever platform the environment selects.
+    # NOTE the supervised half runs TWO replicas at once: it needs one
+    # device per replica (or the CPU); on a one-chip machine the second
+    # replica cannot get the chip.
     chunk_tokens = int(_os.environ.get("SERVE_CHUNK_TOKENS", "4"))
-    work = Path(tempfile.mkdtemp(prefix="dalle_restart_bench_"))
-    ckpt = _toy_checkpoint(work / "dalle.npz")
+    # a fixed place beside the XLA cache (the path is part of the cache
+    # key, so it must not move between runs), wiped so boot one is cold
+    work = xla_cache_dir() / "restart_bench"
+    shutil.rmtree(work, ignore_errors=True)
+    work.mkdir(parents=True)
+    ckpt = work / "dalle.npz"
+    subprocess.run(
+        [sys.executable, __file__, "--make_toy_checkpoint", str(ckpt)],
+        check=True,
+    )
+    assert "jax" not in sys.modules, "the restart-bench parent imported jax"
     cache_dir = work / "compile_cache"
     env = dict(_os.environ)
-    env["DALLE_TPU_FORCE_PLATFORM"] = env.get(
-        "DALLE_TPU_FORCE_PLATFORM", ""
-    ) or env.get("JAX_PLATFORMS", "") or "cpu"
 
     def boot_once():
         rep = _ReplicaProc(
@@ -2251,8 +2266,12 @@ def main():
         "stage attribution over the measured window only — to each "
         "engine's JSON line",
     )
+    p.add_argument("--make_toy_checkpoint", metavar="PATH", default=None,
+                   help=argparse.SUPPRESS)  # --restart_bench's builder child
     args = p.parse_args()
-    if args.stream:
+    if args.make_toy_checkpoint:
+        _toy_checkpoint(args.make_toy_checkpoint)
+    elif args.stream:
         main_stream_bench(kv_layout=args.kv_layout)
     elif args.drain_bench:
         main_drain_bench()

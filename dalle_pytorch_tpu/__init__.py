@@ -8,10 +8,16 @@ plus pretrained-VAE import wrappers.
 """
 
 from dalle_pytorch_tpu.version import __version__
-from dalle_pytorch_tpu.models.dvae import DiscreteVAE
-from dalle_pytorch_tpu.models.dalle import DALLE
-from dalle_pytorch_tpu.models.clip import CLIP
-from dalle_pytorch_tpu.models.vae_io import OpenAIDiscreteVAE, VQGanVAE
+from dalle_pytorch_tpu._lazy import lazy_exports
+
+_EXPORTS = {
+    "CLIP": "models.clip",
+    "DALLE": "models.dalle",
+    "DiscreteVAE": "models.dvae",
+    "OpenAIDiscreteVAE": "models.vae_io",
+    "VQGanVAE": "models.vae_io",
+}
+__getattr__, __dir__ = lazy_exports(__name__, _EXPORTS)
 
 __all__ = [
     "DALLE",

@@ -3,13 +3,14 @@
 This supplies the capability the reference gets from the external
 youtokentome C++ library (`/root/reference/dalle_pytorch/tokenizer.py:232-266`)
 — fast host-side BPE train/encode/decode — as part of this framework's own
-native runtime. The shared library is built on demand with g++ (cached by
-source mtime); tokenization is host-side, so no TPU involvement.
+native runtime. The shared library is built on demand with g++ (keyed by a
+hash of the source); tokenization is host-side, so no TPU involvement.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from pathlib import Path
@@ -20,26 +21,45 @@ import numpy as np
 _REPO_ROOT = Path(__file__).resolve().parents[2]
 _NATIVE_DIR = _REPO_ROOT / "native"
 _SRC = _NATIVE_DIR / "bpe.cpp"
-_LIB = _NATIVE_DIR / "build" / "libdalle_bpe.so"
+_BUILD_DIR = _NATIVE_DIR / "build"
 
 _lib = None
 
 
+def _library_path() -> Path:
+    """The built library is named by a hash of its source, so a stale or
+    foreign binary left in native/build/ (a copied tree, an older
+    checkout) can never stand in for the committed bpe.cpp."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libdalle_bpe-{digest}.so"
+
+
 def _build_library() -> Path:
-    if _LIB.exists() and _LIB.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _LIB
-    _LIB.parent.mkdir(parents=True, exist_ok=True)
+    lib = _library_path()
+    if lib.exists():
+        return lib
+    _BUILD_DIR.mkdir(parents=True, exist_ok=True)
+    # build beside the target and rename: concurrent builders (xdist
+    # workers, trainer + server) never load a half-written file
+    tmp = lib.with_name(f".{lib.name}.{os.getpid()}")
     cmd = [
         os.environ.get("CXX", "g++"),
         "-O2", "-std=c++17", "-fPIC", "-shared", "-Wall",
-        "-o", str(_LIB), str(_SRC), "-lpthread",
+        "-o", str(tmp), str(_SRC), "-lpthread",
     ]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
+    try:
+        proc = subprocess.run(cmd, capture_output=True, text=True)
+    except OSError as e:  # no compiler on PATH
+        raise RuntimeError(
+            f"native BPE build failed ({' '.join(cmd)}): {e}"
+        ) from e
     if proc.returncode != 0:
+        tmp.unlink(missing_ok=True)
         raise RuntimeError(
             f"native BPE build failed ({' '.join(cmd)}):\n{proc.stderr}"
         )
-    return _LIB
+    os.replace(tmp, lib)
+    return lib
 
 
 def _load_library():

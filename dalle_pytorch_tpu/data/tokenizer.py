@@ -23,7 +23,6 @@ Differences from the reference, by design:
 from __future__ import annotations
 
 import html
-import warnings
 from functools import lru_cache
 from pathlib import Path
 from typing import List, Optional, Sequence, Union
@@ -344,32 +343,19 @@ class NativeBPETokenizer(_TokenizerBase):
         return self.bpe.decode(ids)
 
 
-# Cached default-vocabulary decision: ("native", Path) once a probe
-# succeeds. `get_tokenizer()` with no flags probes the shipped
-# default_bpe_*.model files, warning for each unusable candidate — but
-# builders construct tokenizers repeatedly (trainer, generate CLI, serving
-# engine), and re-probing a broken vocabulary re-emitted the same
-# `default_bpe_32k.model unusable` UserWarning every time. Only SUCCESS is
-# cached: the ByteTokenizer fallback keeps re-probing (so a transiently
-# unusable vocabulary — e.g. the native extension still compiling — can
-# recover later in the process) but its warnings fire once per process via
-# `_warned_default_probe`. A cached "native" decision that stops
-# constructing (toolchain vanished, monkeypatched test double) invalidates
-# itself and re-probes.
-_default_decision = None
-_warned_default_probe = False
-
-
 def get_tokenizer(
     bpe_path: Optional[str] = None,
     hug: bool = False,
     chinese: bool = False,
     yttm: bool = False,
     native: bool = False,
+    byte: bool = False,
 ) -> _TokenizerBase:
     """Tokenizer selection mirroring the trainer flags
     (`/root/reference/train_dalle.py:131-135`), plus the framework-native
-    C++ BPE backend."""
+    C++ BPE backend and the dependency-free byte tokenizer (`byte`)."""
+    if byte:
+        return ByteTokenizer()
     if chinese:
         return ChineseTokenizer()
     if native:
@@ -383,20 +369,13 @@ def get_tokenizer(
         return HugTokenizer(bpe_path)
     if bpe_path:
         return SimpleTokenizer(bpe_path)
-    # No flags: use the shipped native BPE vocabulary (the analogue of the
+    # No flags: the shipped native BPE vocabulary (the analogue of the
     # reference's vendored CLIP vocab, `tokenizer.py:64-68`) — trained by
     # scripts/train_default_vocab.py and committed to the repo. Discovery is
-    # by glob so any regenerated default_bpe_<N>k.model is picked up;
-    # largest vocabulary wins (the CLIP-scale 32k model over the lighter 8k
-    # fallback kept for fast tests).
-    global _default_decision, _warned_default_probe
-    if _default_decision is not None:
-        kind, model_path = _default_decision
-        try:
-            return NativeBPETokenizer(model_path)
-        except Exception:
-            _default_decision = None  # stale decision: re-probe (and re-warn)
-            _warned_default_probe = False
+    # by glob so a regenerated default_bpe_<N>k.model is picked up; the
+    # largest vocabulary wins. The vocabulary size is a model width
+    # (num_text_tokens), so nothing here degrades: a native library that
+    # cannot be built raises, naming the compiler command.
 
     def _vocab_k(p: Path) -> int:
         try:
@@ -404,38 +383,14 @@ def get_tokenizer(
         except ValueError:
             return 0
 
-    existing = sorted(
-        Path(__file__).parent.glob("default_bpe_*.model"),
-        key=_vocab_k, reverse=True,
+    models = sorted(
+        Path(__file__).parent.glob("default_bpe_*.model"), key=_vocab_k
     )
-    for default_model in existing:
-        try:
-            tok = NativeBPETokenizer(default_model)
-            _default_decision = ("native", default_model)
-            return tok
-        except Exception as e:  # e.g. no C++ toolchain, corrupt model file
-            if _warned_default_probe:
-                continue
-            next_step = (
-                "trying the next candidate"
-                if default_model != existing[-1]
-                else "falling back to the 257-symbol ByteTokenizer"
-            )
-            warnings.warn(
-                f"default BPE vocabulary {default_model.name} unusable "
-                f"({e}); {next_step}",
-                stacklevel=2,
-            )
-    if not existing and not _warned_default_probe:
-        warnings.warn(
-            "no default BPE vocabulary "
-            f"(no {Path(__file__).parent}/default_bpe_*.model — run "
-            "scripts/train_default_vocab.py); "
-            "falling back to the 257-symbol ByteTokenizer, which trains "
-            "byte-level models only",
-            stacklevel=2,
+    if not models:
+        raise FileNotFoundError(
+            f"no default BPE vocabulary ({Path(__file__).parent}/"
+            "default_bpe_*.model): run scripts/train_default_vocab.py, or "
+            "choose a tokenizer explicitly (bpe_path=..., or byte=true for "
+            "the 257-symbol ByteTokenizer)"
         )
-    # fallback is NOT cached — the next call re-probes (silently), so a
-    # vocabulary that becomes usable later in the process is picked up
-    _warned_default_probe = True
-    return ByteTokenizer()
+    return NativeBPETokenizer(models[-1])

@@ -53,46 +53,48 @@ from dalle_pytorch_tpu.ops.pallas_decode import (
 )
 from dalle_pytorch_tpu.ops.rotary import apply_rotary
 
+# The three thresholds below come from a ROOFLINE MODEL, not from a chip:
+# scripts/flash_crossover.py takes `cost_analysis()` of programs compiled on
+# the CPU and places their FLOPs and bytes on the v5e's published peaks
+# (197 TFLOP/s bf16, 819 GB/s). No chip timing stands behind any of them
+# yet (PERF.md); re-derive them from ledger rows when those exist.
+
 # Sequence length at or above which `attn_impl="auto"` switches from the
 # dense einsum to the Pallas flash kernel (O(N) memory vs dense's O(N^2)
-# score tensors). MEASURED default (scripts/flash_crossover.py, recorded in
-# BASELINE.md §flash-crossover): on the v5e roofline over compiled-program
-# cost analysis, dense attention is bandwidth-bound from seq 256 up (score
-# chain 212 MB @256 → 4.5 GB @1280 vs flash's tiled 10→137 MB), but
-# op-level counting can't resolve the sub-1k region (fusion may keep short
-# score chains out of HBM), so the default is the largest bench-grid point
-# that still auto-selects flash for the flagship 1280 — where the r3 HBM
-# analysis, this measurement, and the r4 hardware run (flash wall == dense
-# even under dispatch overhead) all agree. Overridable per model
-# (attn_impl=) or by rebinding this constant; the on-chip wall-clock A/B
-# (`scripts/pallas_onchip.py`) stays armed as the final decider.
+# score tensors). Modeled: dense attention is bandwidth-bound from seq 256
+# up (score chain 212 MB @256 -> 4.5 GB @1280 vs flash's tiled 10 -> 137
+# MB), but op-level counting can't resolve the sub-1k region (fusion may
+# keep short score chains out of HBM), so the default is the largest
+# bench-grid point that still auto-selects flash for the flagship 1280.
+# Overridable per model (attn_impl=) or by rebinding this constant; an
+# on-chip wall-clock A/B (`scripts/pallas_onchip.py`) is the final decider.
 AUTO_FLASH_MIN_SEQ = 1024
 
 # Cache length at or above which `attn_impl="auto"` runs the CACHED decode
 # path through the Pallas flash-decode kernel (ops/pallas_decode.py) instead
-# of dense attention over the whole [B, H, max_len, D] cache. MEASURED
-# (same script/table): one decode step's K/V reads cross at max_len 512 —
-# below it the per-kernel overhead charge beats the saved reads at expected
-# live length max_len/2; at the flagship cache (1281) flash-decode halves
-# the average K/V reads and cuts them ~3x for a freshly-admitted
-# continuous-batching slot still at its text prefix.
+# of dense attention over the whole [B, H, max_len, D] cache. Modeled (same
+# script): one decode step's K/V reads cross at max_len 512 — below it the
+# per-kernel overhead charge beats the saved reads at expected live length
+# max_len/2; at the flagship cache (1281) flash-decode halves the average
+# K/V reads and cuts them ~3x for a freshly-admitted continuous-batching
+# slot still at its text prefix.
 AUTO_FLASH_DECODE_MIN_LEN = 512
 
 # KV tile width for POLICY-sparse flash decode (the per-row block bitmap in
-# ops/pallas_decode.py:block_sparse_flash_decode_attention). MEASURED
-# (scripts/flash_crossover.py --sparse sweep, BASELINE.md §block-sparse):
-# the skip fraction a policy can express falls with tile width (an axial
-# row policy at the flagship cache keeps 48% of 64-wide tiles live but 60%
-# of 128-wide and 79% of 256-wide — every tile a single live position
-# touches is read whole), while the per-tile grid charge grows as tiles
-# shrink: on the v5e roofline a 32-wide sweep is SLOWER than plain
-# length-skip flash at 128. 128 is the knee: near-minimal modeled step
-# time (25.4 us vs 24.7 at 256) while capturing ~72% of the reachable
-# byte savings, and it matches `flash_decode_attention`'s default block_k
-# — so the all-ones bitmap keeps BIT-IDENTITY with the dense-causal flash
-# path (same tile boundaries, same accumulation order), the serving
-# stack's parity pin. Overridable per model (decode_sparse_block=); must
-# divide into whole pages on the paged "kernel" impl (page_size | block).
+# ops/pallas_decode.py:block_sparse_flash_decode_attention). Modeled
+# (scripts/flash_crossover.py --sparse sweep): the skip fraction a policy
+# can express falls with tile width (an axial row policy at the flagship
+# cache keeps 48% of 64-wide tiles live but 60% of 128-wide and 79% of
+# 256-wide — every tile a single live position touches is read whole),
+# while the per-tile grid charge grows as tiles shrink: on the v5e roofline
+# a 32-wide sweep is SLOWER than plain length-skip flash at 128. 128 is the
+# knee: near-minimal modeled step time (25.4 us vs 24.7 at 256) while
+# capturing ~72% of the reachable byte savings, and it matches
+# `flash_decode_attention`'s default block_k — so the all-ones bitmap keeps
+# BIT-IDENTITY with the dense-causal flash path (same tile boundaries, same
+# accumulation order), the serving stack's parity pin. Overridable per model
+# (decode_sparse_block=); must divide into whole pages on the paged "kernel"
+# impl (page_size | block).
 DECODE_SPARSE_BLOCK = 128
 
 
@@ -170,6 +172,12 @@ class Attention(nn.Module):
     # (ShardedContinuousEngine clones the model with its model_axis).
     decode_mesh: Any = None
     decode_heads_axis: str = "tp"
+    # trainer mesh for the UNCACHED flash kernel, for the same reason: under
+    # a multi-device pjit the bare pallas_call is refused ("Mosaic kernels
+    # cannot be automatically partitioned"), so the trainer's mesh rides
+    # here and the kernel runs under shard_map — batch over (dp, fsdp),
+    # heads over tp, each shard the unmodified single-device kernel.
+    train_mesh: Any = None
     # KV tile width the decode-time block bitmap is expressed at (None =
     # DECODE_SPARSE_BLOCK). Static model config: the serving engine clones
     # the model with it when --decode_sparsity=policy, and the policy's
@@ -199,6 +207,36 @@ class Attention(nn.Module):
         if self.attn_impl == "dense" or key_mask is not None:
             return False
         return n >= AUTO_FLASH_MIN_SEQ
+
+    def _flash(self, q, k, v, n: int):
+        """The in-repo flash kernel over [B, H, N, D]; under a multi-device
+        `train_mesh`, shard_mapped over batch and heads (attention mixes
+        neither, so the concatenation of the shards is exact). An axis
+        that does not divide its dimension stays replicated — e.g. the
+        batch-1 dummy of `model.init`."""
+        mask = self._full_mask(n, n) if self.static_mask is not None else None
+
+        def kernel(q_, k_, v_):
+            return flash_attention(q_, k_, v_, mask=mask, causal=self.causal)
+
+        mesh = self.train_mesh
+        if mesh is None or mesh.size == 1:
+            return kernel(q, k, v)
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        shape = dict(mesh.shape)
+        data = tuple(a for a in ("dp", "fsdp") if shape.get(a, 1) > 1)
+        n_data = int(np.prod([shape[a] for a in data])) if data else 1
+        spec = P(
+            data if data and q.shape[0] % n_data == 0 else None,
+            "tp" if shape.get("tp", 1) > 1 and q.shape[1] % shape["tp"] == 0 else None,
+            None, None,
+        )
+        return shard_map(
+            kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
+        )(q, k, v)
 
     def _use_flash_decode(
         self, max_len: int, has_pattern: bool, sparse: bool = False
@@ -518,11 +556,7 @@ class Attention(nn.Module):
                 if self.attn_impl == "lib_flash":
                     out = lib_flash_attention(q, k, v, causal=self.causal)
                 else:
-                    out = flash_attention(
-                        q, k, v,
-                        mask=self._full_mask(n, n) if self.static_mask is not None else None,
-                        causal=self.causal,
-                    )
+                    out = self._flash(q, k, v, n)
             else:
                 mask = self._full_mask(n, n)
                 mask = None if mask is None else jnp.asarray(mask)[None, None]

@@ -50,30 +50,31 @@ histogram family (`training/metrics.py`), so `/metrics` and the traces
 agree on where the time went.
 """
 
-from dalle_pytorch_tpu.obs.tracing import (
-    NULL_EXPORTER,
-    NULL_TRACE,
-    Span,
-    Trace,
-    Tracer,
-)
-from dalle_pytorch_tpu.obs.aggregate import (
-    TRACE_HEADER,
-    TraceExporter,
-    format_trace_header,
-    parse_trace_header,
-)
-from dalle_pytorch_tpu.obs.collector import CollectorServer, TraceCollector
-from dalle_pytorch_tpu.obs.logging import StructuredLog
-from dalle_pytorch_tpu.obs.profiler import ProfilerBusy, ProfilerCapture
-from dalle_pytorch_tpu.obs.vitals import (
-    NULL_VITALS,
-    EngineVitals,
-    ProgramCostTable,
-    SLOTarget,
-    SLOTracker,
-    StallWatchdog,
-)
+from dalle_pytorch_tpu._lazy import lazy_exports
+
+_EXPORTS = {
+    "CollectorServer": "collector",
+    "EngineVitals": "vitals",
+    "NULL_EXPORTER": "tracing",
+    "NULL_TRACE": "tracing",
+    "NULL_VITALS": "vitals",
+    "ProfilerBusy": "profiler",
+    "ProfilerCapture": "profiler",
+    "ProgramCostTable": "vitals",
+    "SLOTarget": "vitals",
+    "SLOTracker": "vitals",
+    "Span": "tracing",
+    "StallWatchdog": "vitals",
+    "StructuredLog": "logging",
+    "TRACE_HEADER": "aggregate",
+    "Trace": "tracing",
+    "TraceCollector": "collector",
+    "TraceExporter": "aggregate",
+    "Tracer": "tracing",
+    "format_trace_header": "aggregate",
+    "parse_trace_header": "aggregate",
+}
+__getattr__, __dir__ = lazy_exports(__name__, _EXPORTS)
 
 __all__ = [
     "CollectorServer",

@@ -75,10 +75,12 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 from dalle_pytorch_tpu.utils import compile_guard
 
-# v5e roofline anchors, shared with scripts/hbm_model.py and
-# scripts/flash_crossover.py (import from here, don't re-declare)
-V5E_PEAK_FLOPS = 197e12
-V5E_HBM_BPS = 819e9  # ~819 GB/s
+from dalle_pytorch_tpu.utils.flops import PEAKS, lookup_peaks
+
+# v5e roofline anchors for the OFFLINE models (scripts/hbm_model.py,
+# scripts/flash_crossover.py — they model a v5e whatever they run on).
+# Live gauges resolve the peaks of the device they run on instead.
+V5E_PEAK_FLOPS, V5E_HBM_BPS = PEAKS["v5e"]
 
 
 def extract_cost(compiled) -> Dict[str, float]:
@@ -223,13 +225,22 @@ class ProgramCostTable:
 
     def __init__(
         self,
-        peak_flops: float = V5E_PEAK_FLOPS,
-        hbm_bps: float = V5E_HBM_BPS,
+        peak_flops: Optional[float] = None,
+        hbm_bps: Optional[float] = None,
         registry=None,
         ema_alpha: float = 0.2,
     ):
-        self.peak_flops = float(peak_flops)
-        self.hbm_bps = float(hbm_bps)
+        if peak_flops is None or hbm_bps is None:
+            # the roofline of the device this process runs on; a device
+            # with no published peak (the CPU) has no utilization to
+            # report — rows and the bandwidth gauge still export
+            import jax
+
+            listed = lookup_peaks(jax.devices()[0].device_kind) or (None, None)
+            peak_flops = listed[0] if peak_flops is None else peak_flops
+            hbm_bps = listed[1] if hbm_bps is None else hbm_bps
+        self.peak_flops = None if peak_flops is None else float(peak_flops)
+        self.hbm_bps = None if hbm_bps is None else float(hbm_bps)
         self.ema_alpha = float(ema_alpha)
         self._rows: Dict[str, _ProgramRow] = {}
         self._errors: Dict[str, str] = {}
@@ -326,6 +337,11 @@ class ProgramCostTable:
 
     # ---------------------------------------------------------- live wall
 
+    def _mfu(self, flops: float, wall_s: float) -> Optional[float]:
+        if self.peak_flops is None:
+            return None
+        return min(1.0, flops / (wall_s * self.peak_flops))
+
     def record_wall(self, name: str, seconds: float,
                     synced: bool = True) -> None:
         with self._lock:
@@ -344,9 +360,7 @@ class ProgramCostTable:
             mfu = bw = None
             shard_stats = []
             if export:
-                mfu = min(
-                    1.0, row.flops / (row.wall_ema_s * self.peak_flops)
-                )
+                mfu = self._mfu(row.flops, row.wall_ema_s)
                 bw = row.bytes_accessed / row.wall_ema_s / 1e9
                 if row.per_shard:
                     # the dispatch is collective — every shard shares the
@@ -356,14 +370,13 @@ class ProgramCostTable:
                     shard_stats = [
                         (
                             dev,
-                            min(1.0, c["flops"]
-                                / (row.wall_ema_s * self.peak_flops)),
+                            self._mfu(c["flops"], row.wall_ema_s),
                             c["bytes_accessed"] / row.wall_ema_s / 1e9,
                         )
                         for dev, c in row.per_shard.items()
                     ]
         if export:
-            if self._m_mfu is not None:
+            if self._m_mfu is not None and mfu is not None:
                 self._m_mfu.labels(name).set(mfu)
                 for dev, s_mfu, _ in shard_stats:
                     self._m_mfu.labels_extra(name, device=dev).set(s_mfu)
@@ -377,7 +390,7 @@ class ProgramCostTable:
             row = self._rows.get(name)
         if row is None or not row.synced or not row.wall_ema_s:
             return None
-        return min(1.0, row.flops / (row.wall_ema_s * self.peak_flops))
+        return self._mfu(row.flops, row.wall_ema_s)
 
     # ------------------------------------------------------------- export
 
@@ -407,8 +420,9 @@ class ProgramCostTable:
                 if r.synced and r.wall_ema_s > 0:
                     # significant figures, not decimal places: a toy CPU
                     # engine's honest MFU is ~1e-7 and must not render 0
-                    mfu = min(1.0, r.flops / (r.wall_ema_s * self.peak_flops))
-                    row["mfu"] = float(f"{mfu:.4g}")
+                    mfu = self._mfu(r.flops, r.wall_ema_s)
+                    if mfu is not None:
+                        row["mfu"] = float(f"{mfu:.4g}")
                     row["hbm_gbps"] = float(
                         f"{r.bytes_accessed / r.wall_ema_s / 1e9:.4g}"
                     )
@@ -422,11 +436,9 @@ class ProgramCostTable:
                         "memory": c["memory"],
                     }
                     if live and r.synced and r.wall_ema_s > 0:
-                        s_mfu = min(
-                            1.0,
-                            c["flops"] / (r.wall_ema_s * self.peak_flops),
-                        )
-                        shard["mfu"] = float(f"{s_mfu:.4g}")
+                        s_mfu = self._mfu(c["flops"], r.wall_ema_s)
+                        if s_mfu is not None:
+                            shard["mfu"] = float(f"{s_mfu:.4g}")
                         shard["hbm_gbps"] = float(
                             f"{c['bytes_accessed'] / r.wall_ema_s / 1e9:.4g}"
                         )

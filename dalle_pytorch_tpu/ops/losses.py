@@ -3,7 +3,8 @@
 The straightforward loss path materializes `[B, N, V]` fp32 logits twice
 (forward + softmax-minus-onehot backward); at the flagship geometry
 (B16 x N1280 x V18448) that is ~1.5 GB per materialization and ~24 GB of
-HBM traffic per step (BASELINE.md round-3 decomposition). This module
+HBM traffic per step (op-level count of the compiled step,
+scripts/hbm_model.py; not a chip measurement). This module
 computes the same split cross-entropy by scanning the vocabulary in
 chunks: each chunk's logits live only in registers/VMEM-sized transients,
 and `jax.checkpoint` on the scan body makes the backward recompute chunk
@@ -29,7 +30,9 @@ from jax import lax
 NEG = np.float32(-1e30)  # np, NOT jnp: a module-level jax Array would be
 # hoisted into every fused-CE executable as a runtime constant argument,
 # and the jit C++ fastpath drops hoisted const args after 2 calls on
-# jax 0.9 ("Execution supplied N buffers but compiled program expected M")
+# jax 0.9.0 ("Execution supplied N buffers but compiled program expected
+# M") — still so on the CPU and on the v5e (PR 21: with jnp here,
+# tests/test_dalle.py::TestFusedCEMultiStep fails on both)
 
 
 def chunked_masked_ce(
@@ -104,21 +107,11 @@ def chunked_masked_ce(
         g = jnp.where(in_chunk, gold_c, g)
         return (m_new, s, g), None
 
-    if n_chunks == 1:
-        # single chunk: call the body directly. A length-1 lax.scan here
-        # miscompiles under grad on jax 0.9 ("Execution supplied N buffers
-        # but compiled program expected M" after a few cached-executable
-        # calls); the scan is pointless at length 1 anyway.
-        (m, s, g), _ = body(
-            (m0, s0, g0),
-            (jnp.zeros((), jnp.int32), kernel_chunks[0], bias_chunks[0]),
-        )
-    else:
-        (m, s, g), _ = lax.scan(
-            body,
-            (m0, s0, g0),
-            (jnp.arange(n_chunks), kernel_chunks, bias_chunks),
-        )
+    (m, s, g), _ = lax.scan(
+        body,
+        (m0, s0, g0),
+        (jnp.arange(n_chunks), kernel_chunks, bias_chunks),
+    )
     logz = m + jnp.log(s)
     return logz - g
 

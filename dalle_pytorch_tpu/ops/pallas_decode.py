@@ -73,6 +73,19 @@ def _last_live_block(length, block_k):
     return jnp.maximum(length - 1, 0) // block_k
 
 
+def _scale_rows(scale, block_k=None):
+    """int8 scale sidecar [B|P, H, S] -> the kernel operand [B|P, H, 1, S]
+    (padded along S to `block_k` when given). Mosaic wants the last two
+    block dims divisible by (8, 128) or equal to the array's: a
+    (1, 1, block_k) tile of a 3-D array has H in the sublane slot and is
+    refused; (1, 1, 1, block_k) over the unit axis is legal and keeps the
+    scales lane-dense in HBM (an [.., S, 1] layout would pad 1 -> 128)."""
+    scale = scale.astype(jnp.float32)
+    if block_k is not None:
+        scale = _pad_to(scale, 2, block_k)
+    return scale[:, :, None, :]
+
+
 def _decode_kernel(
     lengths_ref, q_ref, *refs,
     sm_scale, block_k, n_real_q, nk_blocks, quantized=False, live_ref=None,
@@ -83,7 +96,7 @@ def _decode_kernel(
     index-map steps repeat the last live tile, so the copy is elided).
 
     `quantized=True` interleaves per-(position, head) fp32 scale refs
-    ([block_k] tiles) after each int8 K/V ref and dequantizes IN KERNEL —
+    ([1, block_k] tiles) after each int8 K/V ref and dequantizes IN KERNEL —
     the HBM read stays 1 byte/element; compute is fp32 as always.
 
     `live_ref` ([B, nk_blocks] int32 in SMEM, block-sparse mode) replaces
@@ -119,8 +132,8 @@ def _decode_kernel(
         kb = k_ref[0, 0].astype(jnp.float32)  # [bk, d]
         vb = v_ref[0, 0].astype(jnp.float32)
         if quantized:
-            kb = kb * ks_ref[0, 0][:, None]
-            vb = vb * vs_ref[0, 0][:, None]
+            kb = kb * ks_ref[0, 0].reshape(block_k, 1)
+            vb = vb * vs_ref[0, 0].reshape(block_k, 1)
         s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32)  # [bq, bk]
         bq = q.shape[0]
         col = ki * block_k + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
@@ -176,9 +189,9 @@ def flash_decode_attention(
     cache: K/V tiles are dequantized inside the kernel (tile element *
     its position's scale) before the fp32 flash math — so the per-token
     HBM read is 1 byte/element and no fp copy of the cache ever
-    materializes. (TPU note: the scale tiles are (1, 1, block_k) —
-    fine for the Mosaic layouts this repo's geometries use; the CPU
-    interpret path the tests pin is layout-agnostic.)
+    materializes. The kernel takes them as [B, H, 1, S] in
+    (1, 1, 1, block_k) tiles (`_scale_rows`) — the 3-D (1, 1, block_k)
+    tile the TPU compiler refuses; tests/test_tpu_compile.py asks it.
 
     Matches `dense_attention(q, k, v, mask)` over that mask to fp32
     tolerance (pinned in tests/test_pallas_decode.py). Not differentiable
@@ -225,13 +238,13 @@ def flash_decode_attention(
     operands = [qp, kp, vp]
     if quantized:
         sspec = pl.BlockSpec(
-            (1, 1, block_k),
+            (1, 1, 1, block_k),
             lambda b_, h_, j, lens: (
-                b_, h_, jnp.minimum(j, _last_live_block(lens[b_], block_k)),
+                b_, h_, 0, jnp.minimum(j, _last_live_block(lens[b_], block_k)),
             ),
         )
-        ksp = _pad_to(k_scale.astype(jnp.float32), 2, block_k)
-        vsp = _pad_to(v_scale.astype(jnp.float32), 2, block_k)
+        ksp = _scale_rows(k_scale, block_k)
+        vsp = _scale_rows(v_scale, block_k)
         in_specs = [qspec, kspec, sspec, kspec, sspec]
         operands = [qp, kp, ksp, vp, vsp]
     out = pl.pallas_call(
@@ -370,11 +383,11 @@ def block_sparse_flash_decode_attention(
     operands = [qp, kp, vp]
     if quantized:
         sspec = pl.BlockSpec(
-            (1, 1, block_k),
-            lambda b_, h_, j, lens, live, bmap: (b_, h_, bmap[b_, j]),
+            (1, 1, 1, block_k),
+            lambda b_, h_, j, lens, live, bmap: (b_, h_, 0, bmap[b_, j]),
         )
-        ksp = _pad_to(k_scale.astype(jnp.float32), 2, block_k)
-        vsp = _pad_to(v_scale.astype(jnp.float32), 2, block_k)
+        ksp = _scale_rows(k_scale, block_k)
+        vsp = _scale_rows(v_scale, block_k)
         in_specs = [qspec, kspec, sspec, kspec, sspec]
         operands = [qp, kp, ksp, vp, vsp]
     out = pl.pallas_call(
@@ -519,13 +532,12 @@ def paged_flash_decode_attention(
     if quantized:
         def sv_idx(b_, h_, j, lens, pt):
             jc = jnp.minimum(j, _last_live_block(lens[b_], page_size))
-            return (pt[b_, jc], h_, 0)
+            return (pt[b_, jc], h_, 0, 0)
 
-        svspec = pl.BlockSpec((1, 1, page_size), sv_idx)
+        svspec = pl.BlockSpec((1, 1, 1, page_size), sv_idx)
         in_specs = [qspec, kvspec, svspec, kvspec, svspec]
         operands = [
-            qp, k_pages, k_scale.astype(jnp.float32),
-            v_pages, v_scale.astype(jnp.float32),
+            qp, k_pages, _scale_rows(k_scale), v_pages, _scale_rows(v_scale),
         ]
     out = pl.pallas_call(
         kernel,
@@ -626,13 +638,12 @@ def block_sparse_paged_flash_decode_attention(
     operands = [qp, k_pages, v_pages]
     if quantized:
         def sv_idx(b_, h_, j, lens, pt, live, bmap):
-            return (pt[b_, bmap[b_, j]], h_, 0)
+            return (pt[b_, bmap[b_, j]], h_, 0, 0)
 
-        svspec = pl.BlockSpec((1, 1, page_size), sv_idx)
+        svspec = pl.BlockSpec((1, 1, 1, page_size), sv_idx)
         in_specs = [qspec, kvspec, svspec, kvspec, svspec]
         operands = [
-            qp, k_pages, k_scale.astype(jnp.float32),
-            v_pages, v_scale.astype(jnp.float32),
+            qp, k_pages, _scale_rows(k_scale), v_pages, _scale_rows(v_scale),
         ]
     out = pl.pallas_call(
         kernel,
@@ -761,8 +772,7 @@ def sharded_flash_decode_attention(
     sparse_block: Optional[int] = None,
 ):
     """`flash_decode_attention` split over `head_axis` of `mesh` via
-    shard_map (`parallel/mesh.py`'s compat wrapper keeps it running on
-    jax 0.4.37). Heads that don't divide the axis fall back to the
+    shard_map. Heads that don't divide the axis fall back to the
     unsharded kernel — same drop-to-replicated posture as
     `serving_partition`'s divisibility rule. int8 caches hand their
     [B, H, S] scale leaves along — per-head scales split with the heads
@@ -770,7 +780,7 @@ def sharded_flash_decode_attention(
     to the unsharded quantized one. `block_bitmap`/`sparse_block` arm
     policy tile skipping: the bitmap is head-independent so it REPLICATES
     (P()) like the lengths and every head shard skips the same tiles."""
-    from dalle_pytorch_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def dispatch(q_, k_, v_, lens_, bm_=None, ks_=None, vs_=None):
@@ -844,7 +854,7 @@ def sharded_paged_decode_attention(
     rows' pages through the global table (tracelint TL008 flags it).
     `block_bitmap`/`sparse_block` replicate (P()) like the page table —
     policy skipping is head-independent."""
-    from dalle_pytorch_tpu.parallel.mesh import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def dispatch(q_, kp_, vp_, lens_, pt_, bm_=None, ks_=None, vs_=None):

@@ -29,10 +29,9 @@ from typing import Callable
 
 import jax
 
-from dalle_pytorch_tpu.parallel.mesh import axis_size, shard_map
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -95,7 +94,7 @@ def pipeline_layers(
     ever used for *memory* scaling, move injection/collection to
     stage-local slices instead.
     """
-    n_stages = axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     p = lax.axis_index(axis_name)
     fwd_perm = [(i, i + 1) for i in range(n_stages - 1)]
     ticks = n_micro + n_stages - 1

@@ -31,34 +31,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax promoted shard_map out of jax.experimental (and later added
-# lax.axis_size) at different versions; resolve once here so ring.py /
-# gpipe.py run on whichever jax the image bakes in (same compat class as
-# pallas_attention.CompilerParams)
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, **kwargs):
-        # the replication-check kwarg was renamed check_rep -> check_vma;
-        # callers use the new name, translate for the old API
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_experimental(f, **kwargs)
-
-if hasattr(jax.lax, "axis_size"):
-    def axis_size(axis_name) -> int:
-        """STATIC size of a mapped mesh axis (usable in Python loop
-        bounds inside shard_map bodies)."""
-        return jax.lax.axis_size(axis_name)
-else:  # pragma: no cover - depends on installed jax
-    def axis_size(axis_name) -> int:
-        """Pre-`lax.axis_size` jax: the axis env carries the static size."""
-        from jax._src import core as _core
-
-        return _core.get_axis_env().axis_size(axis_name)
-
 MESH_AXES = ("dp", "fsdp", "tp", "sp")
 
 

@@ -21,10 +21,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from dalle_pytorch_tpu.parallel.mesh import axis_size, shard_map
 
 _NEG = -1e30
 
@@ -42,7 +40,7 @@ def ring_attention(
     Shard i owns global positions [i*n_local, (i+1)*n_local). Must run
     inside shard_map over `axis_name`.
     """
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, h, n_local, d = q.shape
     scale = d**-0.5 if scale is None else scale

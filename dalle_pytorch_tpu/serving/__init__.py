@@ -72,54 +72,48 @@ the same `GenerationEngine` for one-shot CLI batches, so the two paths
 cannot drift.
 """
 
-from dalle_pytorch_tpu.serving.engine import (
-    ContinuousEngine,
-    GenerationEngine,
-    SampleSpec,
-    SlotAllocator,
-    engine_from_checkpoint,
-)
-from dalle_pytorch_tpu.serving.sharded import (
-    ShardedContinuousEngine,
-    build_serving_mesh,
-    parse_mesh_shape,
-)
-from dalle_pytorch_tpu.serving.batcher import (
-    ContinuousBatcher,
-    MicroBatcher,
-    QueueFullError,
-    RequestCancelled,
-    RequestTimeout,
-    ShuttingDownError,
-)
-from dalle_pytorch_tpu.serving.faults import FaultInjector, InjectedFault
-from dalle_pytorch_tpu.serving.migrate import (
-    CheckpointCorrupt,
-    CheckpointMismatch,
-    CheckpointSpool,
-    MigratedError,
-    RequestCheckpoint,
-    RowCheckpoint,
-    decode_checkpoint,
-    encode_checkpoint,
-    from_wire,
-    to_wire,
-)
-from dalle_pytorch_tpu.serving.qos import (
-    PRIORITY_CLASSES,
-    ShedError,
-    TenantQuotaError,
-    WeightedFairQueue,
-)
-from dalle_pytorch_tpu.serving.router import (
-    FleetRouter,
-    QuarantineTracker,
-    RetryBudget,
-    RouterServer,
-    request_fingerprint,
-)
-from dalle_pytorch_tpu.serving.server import ServingServer
-from dalle_pytorch_tpu.serving.supervisor import ReplicaSupervisor
+from dalle_pytorch_tpu._lazy import lazy_exports
+
+_EXPORTS = {
+    "CheckpointCorrupt": "migrate",
+    "CheckpointMismatch": "migrate",
+    "CheckpointSpool": "migrate",
+    "ContinuousBatcher": "batcher",
+    "ContinuousEngine": "engine",
+    "FaultInjector": "faults",
+    "FleetRouter": "router",
+    "GenerationEngine": "engine",
+    "InjectedFault": "faults",
+    "MicroBatcher": "batcher",
+    "MigratedError": "migrate",
+    "PRIORITY_CLASSES": "qos",
+    "QuarantineTracker": "router",
+    "QueueFullError": "batcher",
+    "ReplicaSupervisor": "supervisor",
+    "RequestCancelled": "batcher",
+    "RequestCheckpoint": "migrate",
+    "RequestTimeout": "batcher",
+    "RetryBudget": "router",
+    "RouterServer": "router",
+    "RowCheckpoint": "migrate",
+    "SampleSpec": "engine",
+    "ServingServer": "server",
+    "ShardedContinuousEngine": "sharded",
+    "ShedError": "qos",
+    "ShuttingDownError": "batcher",
+    "SlotAllocator": "engine",
+    "TenantQuotaError": "qos",
+    "WeightedFairQueue": "qos",
+    "build_serving_mesh": "sharded",
+    "decode_checkpoint": "migrate",
+    "encode_checkpoint": "migrate",
+    "engine_from_checkpoint": "engine",
+    "from_wire": "migrate",
+    "parse_mesh_shape": "sharded",
+    "request_fingerprint": "router",
+    "to_wire": "migrate",
+}
+__getattr__, __dir__ = lazy_exports(__name__, _EXPORTS)
 
 __all__ = [
     "CheckpointCorrupt",

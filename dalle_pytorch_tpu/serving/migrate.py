@@ -156,7 +156,7 @@ def encode_checkpoint(cp: RequestCheckpoint, fingerprint: str) -> bytes:
     """RequestCheckpoint -> self-validating blob, via the SAME container
     pack the compile cache's AOT artifacts use
     (`utils/compile_cache.pack_artifact`) — one integrity layout, one
-    reject taxonomy, one set of fault seams."""
+    reject classification, one set of fault seams."""
     from dalle_pytorch_tpu.utils.compile_cache import pack_artifact
 
     payload = json.dumps(

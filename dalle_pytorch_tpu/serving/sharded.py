@@ -26,8 +26,7 @@ TPUv4", PAPERS.md):
     through `ops/pallas_decode.py:sharded_flash_decode_attention` —
     shard_map over the mesh's tp axis, heads split, exactly the
     SNIPPETS.md [1] pattern (a Pallas call is a single-device program
-    GSPMD cannot partition). `parallel/mesh.py`'s shard_map shim keeps
-    this running on jax 0.4.37.
+    GSPMD cannot partition).
 
 The engine seam is the whole point: `prefill_slots` / `step_chunk` /
 `harvest` / `release` keep their signatures, so the continuous batcher,

@@ -147,6 +147,7 @@ class TrainConfig:
     hug: bool = False
     yttm: bool = False
     native: bool = False  # framework-native C++ BPE (native/bpe.cpp)
+    byte: bool = False  # 257-symbol ByteTokenizer (no vocabulary, no build)
     bpe_path: Optional[str] = None
     truncate_captions: bool = False
 
@@ -157,6 +158,7 @@ class TrainConfig:
     # optimization
     epochs: int = 20
     save_every_n_steps: int = 1000
+    log_every_n_steps: int = 10  # loss print/log cadence
     keep_n_checkpoints: Optional[int] = None
     batch_size: int = 4
     ga_steps: int = 1

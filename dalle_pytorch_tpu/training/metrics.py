@@ -21,8 +21,6 @@ from collections import deque
 from pathlib import Path
 from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
-import jax
-
 
 # ------------------------------------------------- Prometheus-style registry
 #
@@ -750,6 +748,8 @@ class ProfilerHook:
         # exact step index; profile the first dispatch at/after it instead
         # of stopping later without ever having traced
         if self.enabled and not self._done and step >= self.profile_step:
+            import jax  # not at module level: MetricsRegistry serves jax-free parents
+
             Path(self.out_dir).mkdir(parents=True, exist_ok=True)
             jax.profiler.start_trace(self.out_dir)
             self._active = True
@@ -757,6 +757,8 @@ class ProfilerHook:
     def after_step(self, step: int) -> bool:
         """Returns True when training should stop (profiler finished)."""
         if self._active:
+            import jax
+
             jax.profiler.stop_trace()
             self._active = False
             self._done = True

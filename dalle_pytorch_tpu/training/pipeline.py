@@ -27,7 +27,7 @@ from dalle_pytorch_tpu.version import __version__
 def build_tokenizer(cfg: TrainConfig):
     return get_tokenizer(
         bpe_path=cfg.bpe_path, hug=cfg.hug, chinese=cfg.chinese, yttm=cfg.yttm,
-        native=getattr(cfg, "native", False)
+        native=getattr(cfg, "native", False), byte=getattr(cfg, "byte", False),
     )
 
 
@@ -188,7 +188,6 @@ REMAT_POLICIES = frozenset(
         "checkpoint_dots",
         "checkpoint_dots_with_no_batch_dims",
     }
-    & set(dir(jax.checkpoint_policies))
 )
 
 
@@ -215,6 +214,11 @@ def dalle_from_config(
             f"{sorted(REMAT_POLICIES)}"
         )
     attn_impl = m.attn_impl
+    # a Pallas call is a single-device program: on a multi-device mesh the
+    # flash kernel must be shard_mapped, which needs the mesh itself
+    train_mesh = None
+    if sp_mesh is not None and sp_mesh.size > 1 and "pp" not in sp_mesh.axis_names:
+        train_mesh = sp_mesh  # (the pp trunk runs inside gpipe's own shard_map)
     executor = getattr(m, "executor", "unrolled")
     if executor not in ("unrolled", "scan"):
         raise ValueError(
@@ -289,6 +293,7 @@ def dalle_from_config(
         img_loss_coeff_inv=cfg.img_loss_coeff_inv,
         attn_impl=attn_impl,
         sp_mesh=sp_mesh,
+        train_mesh=train_mesh,
         executor=executor,
         fused_ce=getattr(m, "fused_ce", False),
         dtype=jnp.bfloat16 if cfg.bf16 else jnp.float32,

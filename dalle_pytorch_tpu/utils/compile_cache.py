@@ -6,11 +6,15 @@ dominated by XLA compilation, not failure detection (the pjit/TPUv4
 systems literature treats compile amortization as a first-class
 operational constraint, PAPERS.md). This module makes a restart cheap:
 
-  * **XLA executable store** (`DIR/xla/`): handed to jax's persistent
-    compilation cache (`jax_compilation_cache_dir`), so every jit/pjit
-    compile — warmup ladder, AOT cost capture, lazy pixel decode — is
-    content-addressed by HLO hash and the second boot LOADS executables
-    instead of compiling them. `utils/compile_guard.py` counts the
+  * **XLA executable store**: jax's persistent compilation cache, so
+    every jit/pjit compile — warmup ladder, AOT cost capture, lazy pixel
+    decode — is content-addressed by HLO hash and the second boot LOADS
+    executables instead of compiling them. ONE rule places it, for every
+    entry point of the repo (`enable_xla_cache`): where the environment
+    sets `JAX_COMPILATION_CACHE_DIR`, that directory is the store and no
+    code sets another; otherwise a CLI uses `<checkout>/.jax_cache` and a
+    `CompileCache` its own `DIR/xla/`. The path is part of the cache key,
+    so it never moves between runs. `utils/compile_guard.py` counts the
     cache-hit events, so the warm-boot contract is pinnable:
     `tally.uncached == 0` across a full warmup + serve cycle.
   * **AOT artifact export** (`DIR/aot/`): each warmed program's
@@ -28,11 +32,6 @@ Accounting: `dalle_boot_cache_{hits,misses,rejects}_total` counters and
 a `dalle_boot_seconds{phase=}` gauge family (checkpoint / plan / warmup /
 export) so dashboards can separate "slow because cold" from "slow
 because sick".
-
-Backend caveat: XLA:CPU (jax 0.4.37) serializes executables but cannot
-DESERIALIZE them into a callable ("Symbols not found") — `deserialize`
-degrades to None there; the warm boot still works because the dispatch
-path loads through the XLA store above. On TPU both paths are live.
 """
 
 from __future__ import annotations
@@ -52,6 +51,46 @@ MAGIC = b"DALLEAOT\n"
 
 #: manifest filename inside DIR/aot/
 MANIFEST = "MANIFEST.json"
+
+#: jax reads this variable itself; where it is set, no code of this repo
+#: points the persistent cache anywhere else
+XLA_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def xla_cache_dir(default=None) -> Path:
+    """Where this process keeps jax's persistent compilation cache: the
+    environment's directory if it names one, else `default`, else the
+    fixed `<checkout>/.jax_cache` (git-ignored)."""
+    env = os.environ.get(XLA_CACHE_ENV)
+    if env:
+        return Path(env)
+    if default is not None:
+        return Path(default)
+    return Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_xla_cache(default=None) -> Path:
+    """Turn jax's persistent compilation cache on at `xla_cache_dir()`.
+    Every CLI calls this before its first compile. Thresholds are zeroed
+    so every program is stored: a second run of the same command then
+    compiles nothing."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    directory = xla_cache_dir(default)
+    if not os.environ.get(XLA_CACHE_ENV):
+        directory.mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", str(directory))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax latches the cache state on the FIRST compile of the process; a
+    # compile that ran before this call would otherwise leave it off
+    compilation_cache.reset_cache()
+    # hits and misses are counted from here on (compile_guard.log_compiles)
+    from dalle_pytorch_tpu.utils import compile_guard
+
+    compile_guard.install_listener()
+    return directory
 
 
 def _canonical(obj) -> str:
@@ -201,9 +240,8 @@ class CompileCache:
 
     def __init__(self, directory, registry=None, log=None):
         self.dir = Path(directory)
-        self.xla_dir = self.dir / "xla"
+        self.xla_dir = self.dir / "xla"  # made by install(), if used
         self.aot_dir = self.dir / "aot"
-        self.xla_dir.mkdir(parents=True, exist_ok=True)
         self.aot_dir.mkdir(parents=True, exist_ok=True)
         self.log = log
         self.fingerprint: Optional[str] = None
@@ -242,43 +280,25 @@ class CompileCache:
 
     # ------------------------------------------------------------ wiring
 
-    @staticmethod
-    def _reset_jax_cache_state() -> None:
-        """jax latches its compilation-cache state (`_cache_checked` /
-        `_cache_initialized`) on the FIRST compile of the process — a
-        compile that ran before the dir was configured permanently
-        disables the cache unless the state is reset. Best-effort
-        private-API touch; a jax without it just means install() must
-        precede the first compile (which serve.py guarantees anyway)."""
-        try:
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
-
     def install(self) -> "CompileCache":
-        """Point jax's persistent compilation cache at `DIR/xla` —
-        process-wide, ideally before the first compile (a pre-existing
-        latch is reset). Thresholds are zeroed so toy/CPU programs cache
-        too (the default min-compile-time guard would skip exactly the
-        programs tests exercise)."""
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", str(self.xla_dir))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        self._reset_jax_cache_state()
+        """Turn jax's persistent compilation cache on — process-wide,
+        ideally before the first compile — at `DIR/xla`, unless the
+        environment placed it (`enable_xla_cache`): then jax's directory
+        is left alone and only the AOT artifacts live under `DIR/aot`."""
+        enable_xla_cache(default=self.xla_dir)
         return self
 
     @staticmethod
     def uninstall() -> None:
         """Detach the process from the persistent cache (tests restore
-        global state; serving processes never call this)."""
+        global state; serving processes never call this). A directory
+        the environment chose stays configured."""
         import jax
+        from jax.experimental.compilation_cache import compilation_cache
 
-        jax.config.update("jax_compilation_cache_dir", None)
-        CompileCache._reset_jax_cache_state()
+        if not os.environ.get(XLA_CACHE_ENV):
+            jax.config.update("jax_compilation_cache_dir", None)
+        compilation_cache.reset_cache()
 
     def bind(self, fingerprint: str, programs: Iterable[str]) -> "CompileCache":
         self.fingerprint = str(fingerprint)
@@ -471,8 +491,8 @@ class CompileCache:
     # --------------------------------------------------------------- load
 
     def _deserialize(self, blob: bytes):
-        """Artifact bytes -> loaded executable, or None where the backend
-        cannot deserialize (XLA:CPU). Overridable seam for tests."""
+        """Artifact bytes -> loaded executable. Overridable seam for
+        tests."""
         import pickle
 
         from jax.experimental import serialize_executable

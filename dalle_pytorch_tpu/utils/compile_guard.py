@@ -36,7 +36,7 @@ from typing import Deque, Iterator, List
 #: the event jax.monitoring emits once per backend (XLA) compilation
 _COMPILE_EVENT_SUFFIX = "backend_compile"
 #: the event the persistent compilation cache emits once per CACHE HIT —
-#: measured on jax 0.4.37: a hit still fires the backend_compile event
+#: on jax 0.9.0 (tests/test_compile_cache_dir.py): a hit still fires the backend_compile event
 #: (around the executable load), so `compiles - cache_hits` is the count
 #: of compilations that actually ran XLA. The warm-boot contract
 #: (`utils/compile_cache.py`) pins `uncached == 0` on a second boot.
@@ -46,6 +46,7 @@ _lock = threading.Lock()
 _installed = False
 _compile_count = 0
 _cache_hit_count = 0
+_compile_seconds = 0.0
 #: recent event names only (error-message context) — a bare counter plus a
 #: bounded deque keeps a long-lived process from accumulating one string
 #: per compilation forever
@@ -62,12 +63,13 @@ def _install_listener() -> None:
         def _on_event(name: str, duration: float, **kwargs) -> None:
             # '/jax/core/compile/backend_compile_duration' et al.
             if _COMPILE_EVENT_SUFFIX in name:
-                global _compile_count
+                global _compile_count, _compile_seconds
                 # the deque append is guarded so `recent_events()` can
                 # snapshot from other threads (the vitals state dump);
                 # compiles are rare, the lock is noise
                 with _lock:
                     _compile_count += 1
+                    _compile_seconds += duration
                     _recent_events.append(name)
             elif _CACHE_HIT_EVENT_SUFFIX in name:
                 global _cache_hit_count
@@ -102,6 +104,30 @@ def cache_hit_count() -> int:
     that actually paid XLA time. 0 forever when no cache dir is
     configured."""
     return _cache_hit_count
+
+
+def compile_seconds() -> float:
+    """Wall seconds spent inside backend-compile events so far (a
+    persistent-cache hit still fires one, around the executable load, so
+    a warm run reports its load time here and `cache_hits == count`)."""
+    return _compile_seconds
+
+
+COMPILES_LINE_PREFIX = "[compiles] "
+
+
+def log_compiles() -> None:
+    """Print this process's compile receipt as one parseable line — the
+    batch CLIs end with it, so a cold run and a warm run of the same
+    command can be told apart from their output alone."""
+    import json
+
+    count, hits = _compile_count, _cache_hit_count
+    print(COMPILES_LINE_PREFIX + json.dumps({
+        "count": count, "cache_hits": hits,
+        "uncached": max(0, count - hits),
+        "seconds": round(_compile_seconds, 2),
+    }), flush=True)
 
 
 def recent_events() -> List[str]:

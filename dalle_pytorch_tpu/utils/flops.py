@@ -9,24 +9,38 @@ the trainer's live MFU log (the reference logs only `sample_per_sec`,
 
 from __future__ import annotations
 
-# published bf16 peak FLOP/s per chip, keyed by substrings of
-# jax.Device.device_kind (lowercased)
-PEAK_FLOPS = {
-    "v4": 275e12,
-    "v5 lite": 197e12,
-    "v5e": 197e12,
-    "v5": 459e12,  # v5p
-    "v6": 918e12,
-    "cpu": 5e11,  # nominal, so CPU smoke runs still report something
+# published per-chip peaks, keyed by substrings of jax.Device.device_kind
+# (lowercased; first match wins, so "v5 lite"/"v5e" precede "v5"):
+# (bf16 FLOP/s, HBM bytes/s). Source: Google Cloud TPU documentation, the
+# system-architecture page of each generation ("TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s). A device that is not listed is an error, not a default.
+PEAKS = {
+    "v4": (275e12, 1228e9),
+    "v5 lite": (197e12, 819e9),
+    "v5e": (197e12, 819e9),
+    "v5": (459e12, 2765e9),  # v5p
+    "v6": (918e12, 1640e9),
 }
 
 
-def peak_flops_per_chip(device_kind: str) -> float:
+def lookup_peaks(device_kind: str):
+    """(bf16 FLOP/s, HBM bytes/s) of a listed device kind, else None."""
     kind = device_kind.lower()
-    for key, val in PEAK_FLOPS.items():
+    for key, peaks in PEAKS.items():
         if key in kind:
-            return val
-    return 197e12
+            return peaks
+    return None
+
+
+def peak_flops_per_chip(device_kind: str) -> float:
+    peaks = lookup_peaks(device_kind)
+    if peaks is None:
+        raise KeyError(
+            f"no published peak for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)}); utilization is only defined "
+            "against a listed chip"
+        )
+    return peaks[0]
 
 
 def transformer_train_flops(

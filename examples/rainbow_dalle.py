@@ -248,8 +248,7 @@ def main():
         report += f" | test: exact {te_exact:.2f}, per-token {te_tok:.3f}"
     print(report)
     print("(reference notebook bar at convergence: exact 1.0 train / ~0.3 test)")
-    # machine-readable line for the TPU experiment matrix
-    # (scripts/run_tpu_experiments.sh greps '^{')
+    # machine-readable line
     import json
 
     print(json.dumps({

@@ -53,16 +53,18 @@ def parse_args():
 def main():
     args = parse_args()
     import jax
-    import os as _os
+    from dalle_pytorch_tpu.utils.compile_cache import enable_xla_cache
 
-    if _os.environ.get("DALLE_TPU_FORCE_PLATFORM"):
-        jax.config.update("jax_platforms", _os.environ["DALLE_TPU_FORCE_PLATFORM"])
+    enable_xla_cache()  # before the first compile
     import jax.numpy as jnp
 
     from dalle_pytorch_tpu.models.dalle import generate_images, generate_texts
     from dalle_pytorch_tpu.models.dvae import DiscreteVAE
     from dalle_pytorch_tpu.serving.engine import SampleSpec, engine_from_checkpoint
+    from dalle_pytorch_tpu.utils.device import log_device
     from dalle_pytorch_tpu.utils.images import save_image_grid, to_uint8
+
+    log_device()
 
     # one compiled shape: the CLI always dispatches full --batch_size
     # batches (the engine pads the final partial chunk)
@@ -156,6 +158,9 @@ def main():
             Image.fromarray(to_uint8(img)).save(out_dir / f"{i}.png")
         save_image_grid(images, out_dir / "grid.png")
         print(f"created {len(images)} images at {out_dir}")
+    from dalle_pytorch_tpu.utils.compile_guard import log_compiles
+
+    log_compiles()
 
 
 if __name__ == "__main__":

@@ -23,17 +23,6 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
-def _apply_platform_override():
-    import os
-
-    if os.environ.get("DALLE_TPU_FORCE_PLATFORM"):
-        import jax
-
-        jax.config.update(
-            "jax_platforms", os.environ["DALLE_TPU_FORCE_PLATFORM"]
-        )
-
-
 def check_openai(cache_dir: str) -> int:
     import jax.numpy as jnp
 
@@ -93,7 +82,6 @@ def main() -> int:
     args = ap.parse_args()
     if not args.openai and not args.vqgan:
         ap.error("pass --openai and/or --vqgan")
-    _apply_platform_override()
     rc = 0
     if args.openai:
         rc |= check_openai(args.openai)

@@ -24,12 +24,11 @@ The decode crossover compares one cached step's K/V reads: dense always
 reads the whole [B, H, max_len, D] cache; flash-decode reads
 ceil(live/block_k) tiles (expected live ~ max_len/2 over an image) plus a
 per-kernel overhead charge. Emits one JSON line per seq and a final
-recommendation line. Caveats stated in BASELINE.md §flash-crossover; the
-on-chip wall-clock A/B (`scripts/pallas_onchip.py`) stays armed in the
-watchdog matrix as the final decider.
+recommendation line. This is a roofline MODEL over compiled-program cost
+analysis, computed on the CPU — not a chip measurement; the on-chip
+wall-clock A/B (`scripts/pallas_onchip.py`) is the final decider.
 
-`--sparse` runs the BLOCK-SPARSE decode sweep instead (BASELINE.md
-§block-sparse): for the flagship axial-row layout it reduces the static
+`--sparse` runs the BLOCK-SPARSE decode sweep instead: for the flagship axial-row layout it reduces the static
 pattern to per-row KV-tile bitmaps at several tile widths (the same
 `ops/masks.py:mask_to_block_bitmap` reduction the serving policy ships at
 runtime) and models, per width, the expected tiles read/skipped over a
@@ -59,7 +58,7 @@ from dalle_pytorch_tpu.obs.vitals import (  # noqa: E402
 #: host dispatch — the kernel runs inside the jitted step)
 KERNEL_OVERHEAD_S = 5e-6
 
-# serving/training flagship geometry (BASELINE.md): heads 16, head dim 64
+# serving/training flagship geometry: heads 16, head dim 64
 BATCH, HEADS, DIM_HEAD = 4, 16, 64
 BLOCK = 128
 SEQS = (256, 384, 512, 640, 768, 1024, 1280, 1536, 2048, 4096)

@@ -1,7 +1,7 @@
 """Predicted-HBM ladder: XLA cost analysis of the bench configurations.
 
-The round-3 finding (BASELINE.md) is that the flagship step is
-HBM-bandwidth-bound, so the *bytes accessed* of the compiled program is
+The working hypothesis (TPU_RESULTS.jsonl's round-4 rows; to be tested
+against ledger rows) is that the flagship step is HBM-bandwidth-bound, so the *bytes accessed* of the compiled program is
 the best hardware-free predictor of which configuration wins. This script
 AOT-compiles the real train step (CPU backend — same HLO structure as
 TPU for everything except the Pallas flash kernel) at FULL flagship

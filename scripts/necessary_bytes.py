@@ -1,10 +1,9 @@
 #!/usr/bin/env python
 """Analytic NECESSARY-HBM-traffic model for the flagship train step.
 
-VERDICT r4 #3 asks for a measured roofline from the banked 7.7% MFU to
-the >=45% target — or a quantitative refutation. The hardware half (the
-probe ladder) is armed in the watchdog matrix; this script supplies the
-model half: a lower-bound estimate of the HBM bytes a WELL-FUSED XLA
+The gap from the one recorded on-chip MFU (7.7%, TPU_RESULTS.jsonl) to
+the >=45% target needs a roofline. The hardware half is
+scripts/perf_probe.py on the chip; this script supplies the model half: a lower-bound estimate of the HBM bytes a WELL-FUSED XLA
 program must move per step, as opposed to `cost_analysis()`'s op-level
 operand counting (which charges every elementwise op its full operands
 — 886 GB/step at the same levers (flash+policy+fused CE); 1.34 TB for

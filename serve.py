@@ -399,9 +399,6 @@ def main(argv=None):
     import jax
     import os as _os
 
-    if _os.environ.get("DALLE_TPU_FORCE_PLATFORM"):
-        jax.config.update("jax_platforms", _os.environ["DALLE_TPU_FORCE_PLATFORM"])
-
     from dalle_pytorch_tpu.obs import (
         EngineVitals, ProfilerCapture, ProgramCostTable, SLOTarget,
         SLOTracker, StallWatchdog, StructuredLog, TraceExporter, Tracer,
@@ -410,8 +407,11 @@ def main(argv=None):
     from dalle_pytorch_tpu.training.metrics import MetricsRegistry
     from dalle_pytorch_tpu.utils import compile_guard
     from dalle_pytorch_tpu.utils.compile_cache import (
-        CompileCache, boot_fingerprint,
+        CompileCache, boot_fingerprint, enable_xla_cache,
     )
+    from dalle_pytorch_tpu.utils.device import bytes_in_use_per_device, log_device
+
+    log_device()
 
     # structured JSONL on stdout replaces the old ad-hoc status prints;
     # the one surviving print is the "[serve] listening" readiness line,
@@ -430,6 +430,8 @@ def main(argv=None):
         cache = CompileCache(
             args.compile_cache, registry=registry, log=log
         ).install()
+    else:
+        enable_xla_cache()
 
     batch_shapes = tuple(int(b) for b in args.batch_shapes.split(",") if b)
     phases = cache.boot_phase if cache is not None else _null_phase
@@ -456,6 +458,9 @@ def main(argv=None):
             # stream, so the knob is continuous-only either way)
             preview_enabled=args.preview_every > 0,
         )
+    # after placement: a sharded engine that piled everything on the
+    # first device shows here, per device
+    log.event("placement", bytes_in_use=bytes_in_use_per_device())
     if cache is not None:
         # identity of this compiled-ladder universe: any drift (jax
         # upgrade, backend, mesh, model config, new program) turns the
@@ -494,6 +499,8 @@ def main(argv=None):
             boot_cache_mode=cache.plan["mode"] if cache is not None else None,
             boot_seconds=dict(cache.boot_seconds) if cache is not None else None,
         )
+
+    compiles_at_ready = compile_guard.compile_count()
 
     crash_spec = _os.environ.get("DALLE_SERVE_CRASH")
     if crash_spec:
@@ -610,6 +617,12 @@ def main(argv=None):
           flush=True)
     server.serve_forever()
     stopped.wait(timeout=60)  # let the drain finish before exiting
+    # the steady-state contract, said by the process itself: nothing
+    # compiled between the readiness line and shutdown
+    log.event(
+        "served",
+        compiles_while_serving=compile_guard.compile_count() - compiles_at_ready,
+    )
     print("[serve] shutdown complete", flush=True)
     return 0
 

@@ -3,7 +3,9 @@
 import jax
 from jax.sharding import PartitionSpec as P
 
-from dalle_pytorch_tpu.parallel.mesh import make_mesh, shard_map
+from jax import shard_map
+
+from dalle_pytorch_tpu.parallel.mesh import make_mesh
 
 
 def _impl(x):

@@ -81,44 +81,37 @@ class TestSimpleTokenizer:
         assert default.decode(ids[0]) == "small red circle"
         assert isinstance(get_tokenizer(bpe_path=str(bpe_file)), SimpleTokenizer)
 
-    def test_byte_fallback_warns(self, monkeypatch, tmp_path):
-        """A missing default vocab must degrade LOUDLY, not silently."""
-        import dalle_pytorch_tpu.data.tokenizer as tok
+    def test_default_raises_when_native_build_fails(self, monkeypatch, tmp_path):
+        """The default vocabulary size is a model width: a native library
+        that cannot be built is an error naming the compiler command, never
+        a smaller vocabulary or the byte tokenizer."""
+        import dalle_pytorch_tpu.data.native_bpe as nb
 
-        # drop the process-wide probe cache (monkeypatch restores the real
-        # decision afterwards, so later tests see the shipped vocab again)
-        monkeypatch.setattr(tok, "_default_decision", None)
-        monkeypatch.setattr(tok, "_warned_default_probe", False)
-        monkeypatch.setattr(
-            tok, "NativeBPETokenizer",
-            type("Broken", (), {"__init__": lambda self, p: (_ for _ in ()).throw(OSError("no toolchain"))}),
-        )
-        with pytest.warns(UserWarning, match="ByteTokenizer"):
-            assert isinstance(get_tokenizer(), ByteTokenizer)
+        monkeypatch.setattr(nb, "_lib", None)
+        monkeypatch.setattr(nb, "_BUILD_DIR", tmp_path / "build")
+        monkeypatch.setenv("CXX", "no-such-compiler")
+        with pytest.raises(RuntimeError, match="no-such-compiler .*-shared"):
+            get_tokenizer()
 
-    def test_byte_fallback_warns_once_per_process(self, monkeypatch):
-        """The `default_bpe_*.model unusable` warning fires once: repeated
-        default-tokenizer construction (trainer + generate CLI + serving
-        engine in one process) reuses the cached probe decision silently."""
-        import warnings as _warnings
+    def test_byte_tokenizer_by_explicit_choice(self):
+        assert isinstance(get_tokenizer(byte=True), ByteTokenizer)
 
-        import dalle_pytorch_tpu.data.tokenizer as tok
+    def test_native_library_is_keyed_by_source_hash(self, monkeypatch, tmp_path):
+        """A stale or foreign binary in native/build/ never stands in for
+        the committed bpe.cpp: the library file is named by the source's
+        hash and rebuilt when that changes."""
+        import dalle_pytorch_tpu.data.native_bpe as nb
 
-        real = tok.NativeBPETokenizer
-        monkeypatch.setattr(tok, "_default_decision", None)
-        monkeypatch.setattr(tok, "_warned_default_probe", False)
-        broken = type("Broken", (), {"__init__": lambda self, p: (_ for _ in ()).throw(OSError("no toolchain"))})
-        monkeypatch.setattr(tok, "NativeBPETokenizer", broken)
-        with pytest.warns(UserWarning):
-            assert isinstance(get_tokenizer(), ByteTokenizer)
-        with _warnings.catch_warnings():
-            _warnings.simplefilter("error")  # a second warning would raise
-            assert isinstance(get_tokenizer(), ByteTokenizer)
-        # the fallback is a re-probe, not a latch: once the vocabulary
-        # becomes usable the default tokenizer recovers mid-process
-        monkeypatch.setattr(tok, "NativeBPETokenizer", real)
-        recovered = get_tokenizer()
-        assert not isinstance(recovered, ByteTokenizer)
+        built = nb._build_library()
+        assert built.exists() and built == nb._library_path()
+        src = tmp_path / "bpe.cpp"
+        src.write_bytes(nb._SRC.read_bytes() + b"\n// edited\n")
+        monkeypatch.setattr(nb, "_SRC", src)
+        monkeypatch.setattr(nb, "_BUILD_DIR", tmp_path / "build")
+        (tmp_path / "build").mkdir()
+        (tmp_path / "build" / "libdalle_bpe.so").write_bytes(b"foreign")
+        rebuilt = nb._build_library()
+        assert rebuilt.name != built.name and rebuilt.stat().st_size > 1000
 
 
 class TestRainbow:
@@ -334,7 +327,7 @@ class TestTokenDataset:
 
         repo = Path(__file__).resolve().parents[1]
         env = {**os.environ, "PYTHONPATH": str(repo),
-               "DALLE_TPU_FORCE_PLATFORM": "cpu"}
+               "JAX_PLATFORMS": "cpu"}
 
         # tiny dVAE checkpoint
         import jax, jax.numpy as jnp

@@ -109,7 +109,7 @@ def _assert_same_npz(a: dict, b: dict, name: str):
 
 def run_cli(script, *cli_args, cwd):
     env = dict(os.environ)
-    env["DALLE_TPU_FORCE_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
     result = subprocess.run(
         [sys.executable, str(REPO / script), *cli_args],
@@ -611,7 +611,7 @@ class TestAttnImplCli:
         )
 
         # invalid configs fail loudly, not silently
-        env = {**os.environ, "DALLE_TPU_FORCE_PLATFORM": "cpu"}
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
         env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
         bad = subprocess.run(
             [sys.executable, str(REPO / "train_dalle.py"),

@@ -212,7 +212,7 @@ def test_composes_with_data_parallel_axis():
         )
         return outs[None]
 
-    from dalle_pytorch_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     outs = jax.jit(
         shard_map(

@@ -180,7 +180,7 @@ class TestOpenAIConverter:
         np.testing.assert_allclose(ours, golden, rtol=1e-3, atol=1e-4)
 
     def test_no_torch_in_hot_path(self, vae):
-        """The VERDICT criterion: encode/decode must be pure XLA."""
+        """Encode/decode must be pure XLA."""
         import inspect
 
         v, _, _ = vae
@@ -231,7 +231,7 @@ class TestReleasedGeometry:
     encoder.pkl/decoder.pkl state-dict layout fails here rather than at
     load time (`/root/reference/dalle_pytorch/vae.py:111-157`). The real
     *weights* cannot be fetched in this egress-less environment
-    (documented limitation, BASELINE.md); spatial extent is reduced to
+    (a documented limitation); spatial extent is reduced to
     32px — state-dict structure is resolution-independent.
     """
 

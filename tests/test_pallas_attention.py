@@ -13,7 +13,6 @@ import pytest
 
 from dalle_pytorch_tpu.ops.attention_core import dense_attention
 from dalle_pytorch_tpu.ops.pallas_attention import (
-    HAS_FORCE_TPU_INTERPRET,
     flash_attention,
     mask_block_layout,
 )
@@ -195,12 +194,6 @@ def test_attention_module_flash_matches_dense():
     np.testing.assert_allclose(out_f, out_d, atol=2e-5)
 
 
-@pytest.mark.skipif(
-    not HAS_FORCE_TPU_INTERPRET,
-    reason="this jax has no pltpu.force_tpu_interpret_mode: the LIBRARY "
-    "kernel cannot be interpreted on CPU (the in-repo kernels can and are "
-    "tested above); lib_flash is TPU-hardware-only here",
-)
 class TestLibFlash:
     """jax library TPU flash kernel behind `lib_flash_attention` /
     attn_impl="lib_flash" (interpret mode on CPU)."""

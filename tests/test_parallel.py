@@ -188,6 +188,57 @@ class TestShardedTrainStep:
             )
 
 
+    @pytest.mark.parametrize("executor", ["unrolled", "scan"])
+    def test_sharded_flash_step_matches_unsharded(self, executor):
+        """A Pallas call is a single-device program: under a multi-device
+        pjit the TPU compiler refuses the bare kernel ("Mosaic kernels
+        cannot be automatically partitioned"), so with the trainer's mesh
+        on the model (`train_mesh`) the flash kernel runs under shard_map —
+        batch over (dp, fsdp), heads over tp — and the step still equals
+        the single-device one."""
+        from dalle_pytorch_tpu.models.dalle import DALLE
+        from dalle_pytorch_tpu.training import TrainState, make_optimizer, make_dalle_train_step
+
+        kw = dict(
+            dim=32, depth=2, num_image_tokens=16, image_fmap_size=4,
+            num_text_tokens=26, text_seq_len=6, heads=2, dim_head=8,
+            attn_impl="flash", executor=executor,
+        )
+        mesh = make_mesh(dp=2, fsdp=2, tp=2)
+        plain, sharded_model = DALLE(**kw), DALLE(train_mesh=mesh, **kw)
+        text = jax.random.randint(jax.random.PRNGKey(0), (8, 6), 1, 26)
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 16)
+        batch = {"text": text, "image_tokens": tokens}
+        params = plain.init(jax.random.PRNGKey(2), text, tokens)["params"]
+        tx = make_optimizer(1e-3, clip_grad_norm=0.5)
+        rng = jax.random.PRNGKey(3)
+
+        state = TrainState.create(apply_fn=plain.apply, params=params, tx=tx)
+        ref_state, ref_metrics = jax.jit(make_dalle_train_step(plain))(state, batch, rng)
+
+        state = TrainState.create(apply_fn=sharded_model.apply, params=params, tx=tx)
+        state_sh = state_shardings(state, mesh)
+        bs = batch_sharding(mesh)
+        sharded_step = jax.jit(
+            make_dalle_train_step(sharded_model),
+            in_shardings=(state_sh, {k: bs for k in batch}, None),
+            out_shardings=(state_sh, None),
+        )
+        new_state, metrics = sharded_step(
+            jax.device_put(state, state_sh),
+            {k: jax.device_put(v, bs) for k, v in batch.items()}, rng,
+        )
+        np.testing.assert_allclose(
+            float(metrics["loss"]), float(ref_metrics["loss"]), rtol=1e-5
+        )
+        for a, b in zip(
+            jax.tree.leaves(ref_state.params), jax.tree.leaves(new_state.params)
+        ):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5
+            )
+
+
 class TestRingInModel:
     """attn_impl="ring": sequence-parallel DALLE must match the dense model
     bit-for-bit in function value and gradients (long-context training path,

@@ -522,7 +522,7 @@ class TestServeCliEndToEnd:
         import os
 
         env = dict(os.environ)
-        env["DALLE_TPU_FORCE_PLATFORM"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         trace_dump = tmp_path / "traces.json"
         proc = subprocess.Popen(
             [

@@ -236,12 +236,13 @@ class TestProfilerHook:
         from dalle_pytorch_tpu.training.metrics import ProfilerHook
 
         calls = []
+        # (the hook imports jax when it fires, not at module import: the
+        # metrics registry also serves parents that must stay off jax)
         monkeypatch.setattr(
-            "dalle_pytorch_tpu.training.metrics.jax.profiler",
-            type("P", (), {
-                "start_trace": staticmethod(lambda d: calls.append(("start", d))),
-                "stop_trace": staticmethod(lambda: calls.append(("stop",))),
-            }),
+            "jax.profiler.start_trace", lambda d: calls.append(("start", d))
+        )
+        monkeypatch.setattr(
+            "jax.profiler.stop_trace", lambda: calls.append(("stop",))
         )
         hook = ProfilerHook(True, profile_step=200, out_dir=str(tmp_path / "p"))
         # stride-3 window sequence around 200: 198 -> 201 -> 204

@@ -107,6 +107,22 @@ class TestProgramCostTable:
         table.record_wall("never_captured", 0.5)  # must not raise
         assert table.mfu("never_captured") is None
 
+    def test_unlisted_device_exports_no_mfu(self):
+        """Peaks resolve from the device kind; the CPU is not in the table,
+        so the default table reports cost rows and bandwidth but never a
+        utilization against some other chip's peak."""
+        reg = MetricsRegistry()
+        table = ProgramCostTable(registry=reg)
+        assert table.peak_flops is None and table.hbm_bps is None
+        table.add("chunk", FakeCompiled(flops=1e9, nbytes=1e8))
+        table.record_wall("chunk", 0.010, synced=True)
+        assert table.mfu("chunk") is None
+        (row,) = table.rows()
+        assert "mfu" not in row and row["hbm_gbps"] == pytest.approx(10.0)
+        out = reg.render()
+        assert "dalle_serving_mfu{" not in out
+        assert 'dalle_serving_hbm_gbps{program="chunk"}' in out
+
     def test_capture_records_errors_instead_of_raising(self):
         table = ProgramCostTable()
 
@@ -771,7 +787,11 @@ def vital_server():
 
     _, cont = _build(max_batch=2, chunk_tokens=4, prefill_batch=2)
     cont.tokenizer = ByteTokenizer()
-    cont.cost_table = ProgramCostTable(registry=cont.registry)
+    # explicit roofline: the CPU these tests run on has no published peak,
+    # and a table that resolves none exports no MFU (pinned below)
+    cont.cost_table = ProgramCostTable(
+        peak_flops=197e12, hbm_bps=819e9, registry=cont.registry
+    )
     cont.warmup()
     slo = SLOTracker(
         [

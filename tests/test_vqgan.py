@@ -327,7 +327,7 @@ class TestReleasedGeometry:
     real heibox checkpoint ships with — so any naming/structural mismatch
     our importer has against a real state dict fails here, not at load
     time on a user's machine. Real *weights* still cannot be validated in
-    this egress-less environment (documented limitation, BASELINE.md);
+    this egress-less environment (a documented limitation);
     spatial extent is reduced to 64px (structure and state-dict keys are
     resolution-independent; attention placement follows the config's
     declared 256px schedule identically in both implementations).

@@ -45,10 +45,9 @@ def parse_args():
 def main():
     args = parse_args()
     import jax
-    import os
+    from dalle_pytorch_tpu.utils.compile_cache import enable_xla_cache
 
-    if os.environ.get("DALLE_TPU_FORCE_PLATFORM"):
-        jax.config.update("jax_platforms", os.environ["DALLE_TPU_FORCE_PLATFORM"])
+    enable_xla_cache()  # before the first compile
     import jax.numpy as jnp
     import numpy as np
 

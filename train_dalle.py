@@ -51,10 +51,9 @@ def parse_args():
 def main():
     args = parse_args()
     import jax
-    import os as _os
+    from dalle_pytorch_tpu.utils.compile_cache import enable_xla_cache
 
-    if _os.environ.get("DALLE_TPU_FORCE_PLATFORM"):
-        jax.config.update("jax_platforms", _os.environ["DALLE_TPU_FORCE_PLATFORM"])
+    enable_xla_cache()  # before the first compile
     import jax.numpy as jnp
 
     from dalle_pytorch_tpu.models.dalle import generate_images
@@ -68,6 +67,9 @@ def main():
     # multi-host rendezvous (launch.py env vars / TPU pod auto); no-op
     # single-host. Must run before the first device query.
     initialize_distributed()
+    from dalle_pytorch_tpu.utils.device import log_device, log_placement
+
+    device = log_device()
     from dalle_pytorch_tpu.training import (
         TrainState, make_optimizer, make_dalle_train_step, make_multi_step,
         window_keys,
@@ -193,8 +195,18 @@ def main():
             MESH_AXES + ("pp",),
         )
     else:
+        devices = jax.devices()
+        want = cfg.mesh.dp * cfg.mesh.fsdp * cfg.mesh.tp * cfg.mesh.sp
+        if 0 < want < len(devices):
+            # a fully explicit mesh smaller than the host (the pp branch's
+            # and build_serving_mesh's convention): e.g. mesh.dp=1 runs the
+            # single-device reference on a four-chip host
+            print(f"WARNING: mesh uses {want} of {len(devices)} devices — "
+                  "the rest sit idle (set mesh.dp=-1 to absorb them)")
+            devices = devices[:want]
         mesh = make_mesh(
-            dp=cfg.mesh.dp, fsdp=cfg.mesh.fsdp, tp=cfg.mesh.tp, sp=cfg.mesh.sp
+            dp=cfg.mesh.dp, fsdp=cfg.mesh.fsdp, tp=cfg.mesh.tp, sp=cfg.mesh.sp,
+            devices=devices,
         )
     model = dalle_from_config(
         cfg,
@@ -242,6 +254,7 @@ def main():
     state_sh = state_shardings(state, mesh)
     txt_sh = batch_sharding(mesh, extra_dims=1)
     state = jax.device_put(state, state_sh)
+    log_placement(mesh.shape)
 
     in_step_encode = isinstance(vae, DiscreteVAE) and not cfg.tokens_path
     if in_step_encode:
@@ -302,8 +315,11 @@ def main():
         entity=cfg.wandb_entity,
     )
     from dalle_pytorch_tpu.utils.flops import (
-        dalle_train_flops_per_sample, mfu as flops_mfu,
+        dalle_train_flops_per_sample, lookup_peaks, mfu as flops_mfu,
     )
+
+    # utilization is only defined against a chip with a published peak
+    log_mfu = lookup_peaks(device["kind"]) is not None
 
     # mode-aware: forward_forward / forward_reverse_partial run two full
     # fwd+bwd passes per sample, so the MFU numerator counts both
@@ -467,7 +483,7 @@ def main():
 
                 last_loss = metrics["loss"]  # lazy device scalar; no sync here
                 log = {}
-                if crossed(10):
+                if crossed(cfg.log_every_n_steps):
                     step_loss = float(last_loss)
                     epoch_losses.append(step_loss)
                     log.update(
@@ -534,10 +550,10 @@ def main():
                     # only sample_per_sec)
                     # rate is PER-PROCESS samples/s (each host iterates its
                     # own data shard), so normalize by the local chip count
-                    log["mfu"] = round(
-                        flops_mfu(rate, flops_per_sample,
-                                  jax.devices()[0].device_kind,
-                                  jax.local_device_count()), 4)
+                    if log_mfu:
+                        log["mfu"] = round(
+                            flops_mfu(rate, flops_per_sample, device["kind"],
+                                      jax.local_device_count()), 4)
                     print(epoch, global_step, f"sample_per_sec - {rate:.2f}")
                 if log:
                     logger.log(log, step=global_step)
@@ -546,6 +562,18 @@ def main():
                     stop = True
                     break
 
+        except jax.errors.JaxRuntimeError as exc:
+            if "RESOURCE_EXHAUSTED" not in str(exc):
+                raise
+            # said once, clearly; the trainer never retries smaller itself
+            raise SystemExit(
+                f"train step does not fit device memory at batch_size="
+                f"{cfg.batch_size}, ga_steps={cfg.ga_steps}, model.reversible="
+                f"{cfg.model.reversible}: set model.reversible=true "
+                "(recompute activations), raise ga_steps (smaller "
+                "microbatches) or lower batch_size. XLA said: "
+                + str(exc).splitlines()[0]
+            ) from exc
         finally:
             batch_iter.close()
 
@@ -566,6 +594,9 @@ def main():
     ckpt.wait()
     logger.finish()
     print(f"final checkpoint -> {out_file}")
+    from dalle_pytorch_tpu.utils.compile_guard import log_compiles
+
+    log_compiles()
 
 
 if __name__ == "__main__":

@@ -42,10 +42,9 @@ def parse_args():
 def main():
     args = parse_args()
     import jax
-    import os as _os
+    from dalle_pytorch_tpu.utils.compile_cache import enable_xla_cache
 
-    if _os.environ.get("DALLE_TPU_FORCE_PLATFORM"):
-        jax.config.update("jax_platforms", _os.environ["DALLE_TPU_FORCE_PLATFORM"])
+    enable_xla_cache()  # before the first compile
     import jax.numpy as jnp
 
     from dalle_pytorch_tpu.parallel import (
@@ -57,6 +56,9 @@ def main():
     # multi-host rendezvous (launch.py env vars / TPU pod auto); no-op
     # single-host. Must run before the first device query.
     initialize_distributed()
+    from dalle_pytorch_tpu.utils.device import log_device
+
+    log_device()
     from dalle_pytorch_tpu.training import (
         TrainState, make_optimizer, make_vae_train_step, make_multi_step,
         window_keys,
@@ -242,7 +244,7 @@ def main():
                 rate = meter.update(global_step, cfg.batch_size)
                 if rate is not None:
                     log["sample_per_sec"] = rate
-                if crossed(10):
+                if crossed(cfg.log_every_n_steps):
                     log["loss"] = float(metrics["loss"])
                     print(epoch, global_step, f"loss - {log['loss']:.5f}")
                 if log:
@@ -263,6 +265,9 @@ def main():
     if is_root():
         save_vae_checkpoint(args.output, vae, last_params_h, cfg.epochs)
     logger.finish()
+    from dalle_pytorch_tpu.utils.compile_guard import log_compiles
+
+    log_compiles()
 
 
 if __name__ == "__main__":
