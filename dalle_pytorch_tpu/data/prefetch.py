@@ -21,6 +21,8 @@ import threading
 import time
 from typing import Any, Callable, Iterable, Iterator, Optional
 
+from dalle_pytorch_tpu.obs.tracing import host_span
+
 
 class _Sentinel:
     pass
@@ -56,8 +58,12 @@ class Prefetcher:
 
     def _produce(self, it: Iterator[Any]) -> None:
         try:
-            for raw in it:
-                batch = self._transform(raw) if self._transform else raw
+            while True:
+                with host_span("input.assemble"):
+                    raw = next(it, _DONE)
+                    if raw is _DONE:
+                        break
+                    batch = self._transform(raw) if self._transform else raw
                 while not self._stop.is_set():
                     try:
                         self._q.put(batch, timeout=0.1)
@@ -81,7 +87,8 @@ class Prefetcher:
 
     def __next__(self):
         t0 = time.perf_counter()
-        item = self._q.get()
+        with host_span("input.wait"):
+            item = self._q.get()
         self._wait_s += time.perf_counter() - t0
         if isinstance(item, _Sentinel):
             if self._err is not None:

@@ -106,27 +106,29 @@ def _cache_write(buf: jnp.ndarray, val: jnp.ndarray, index) -> jnp.ndarray:
     micro-batch decode scan) or a [B] vector (each row sits at its OWN
     position — the continuous-batching slot cache, where rows were admitted
     at different times)."""
-    if jnp.ndim(index) == 0:
-        return lax.dynamic_update_slice(
-            buf, val.astype(buf.dtype), (0, 0, index, 0)
-        )
-    return jax.vmap(
-        lambda b, v, i: lax.dynamic_update_slice(
-            b, v.astype(b.dtype), (0, i, 0)
-        )
-    )(buf, val, index)
+    with jax.named_scope("cache_write"):
+        if jnp.ndim(index) == 0:
+            return lax.dynamic_update_slice(
+                buf, val.astype(buf.dtype), (0, 0, index, 0)
+            )
+        return jax.vmap(
+            lambda b, v, i: lax.dynamic_update_slice(
+                b, v.astype(b.dtype), (0, i, 0)
+            )
+        )(buf, val, index)
 
 
 def _scale_write(buf: jnp.ndarray, val: jnp.ndarray, index) -> jnp.ndarray:
     """`_cache_write` for the per-(position, head) scale leaves: val
     [B,H,n] into buf [B,H,S] at sequence position `index`."""
-    if jnp.ndim(index) == 0:
-        return lax.dynamic_update_slice(
-            buf, val.astype(buf.dtype), (0, 0, index)
-        )
-    return jax.vmap(
-        lambda b, v, i: lax.dynamic_update_slice(b, v.astype(b.dtype), (0, i))
-    )(buf, val, index)
+    with jax.named_scope("cache_write"):
+        if jnp.ndim(index) == 0:
+            return lax.dynamic_update_slice(
+                buf, val.astype(buf.dtype), (0, 0, index)
+            )
+        return jax.vmap(
+            lambda b, v, i: lax.dynamic_update_slice(b, v.astype(b.dtype), (0, i))
+        )(buf, val, index)
 
 
 def _kv_quantize(x: jnp.ndarray):
@@ -360,19 +362,20 @@ class Attention(nn.Module):
                 # slotted dynamic_update_slice does
                 page = jnp.take_along_axis(pt, pos // page_size, axis=1)
                 off = pos % page_size
-                ck = cache["k"].at[page, :, off, :].set(
-                    qk.transpose(0, 2, 1, 3).astype(cache["k"].dtype)
-                )
-                cv = cache["v"].at[page, :, off, :].set(
-                    qv.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
-                )
-                if quant:
-                    cks = cache["k_scale"].at[page, :, off].set(
-                        k_sc.transpose(0, 2, 1)
+                with jax.named_scope("cache_write"):
+                    ck = cache["k"].at[page, :, off, :].set(
+                        qk.transpose(0, 2, 1, 3).astype(cache["k"].dtype)
                     )
-                    cvs = cache["v_scale"].at[page, :, off].set(
-                        v_sc.transpose(0, 2, 1)
+                    cv = cache["v"].at[page, :, off, :].set(
+                        qv.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
                     )
+                    if quant:
+                        cks = cache["k_scale"].at[page, :, off].set(
+                            k_sc.transpose(0, 2, 1)
+                        )
+                        cvs = cache["v_scale"].at[page, :, off].set(
+                            v_sc.transpose(0, 2, 1)
+                        )
             else:
                 ck = _cache_write(cache["k"], qk, index)
                 cv = _cache_write(cache["v"], qv, index)
@@ -442,27 +445,29 @@ class Attention(nn.Module):
                 else:
                     out = flash_decode_attention(q, ck, cv, lengths, **scales)
             else:
-                if paged:
-                    # one gathered view per dispatch; dead positions hold
-                    # garbage-page bytes but the causal mask below replaces
-                    # their scores with the same NEG constant the slotted
-                    # path uses, so outputs stay bit-identical
-                    gk = paged_gather(ck, pt, max_len)
-                    gv = paged_gather(cv, pt, max_len)
-                    if quant:
-                        gk = _kv_dequantize(
-                            gk,
-                            paged_gather(cks[..., None], pt, max_len)[..., 0],
-                        )
-                        gv = _kv_dequantize(
-                            gv,
-                            paged_gather(cvs[..., None], pt, max_len)[..., 0],
-                        )
-                else:
-                    gk, gv = ck, cv
-                    if quant:
-                        gk = _kv_dequantize(gk, cks)
-                        gv = _kv_dequantize(gv, cvs)
+                with jax.named_scope("cache_read"):
+                    if paged:
+                        # one gathered view per dispatch; dead positions
+                        # hold garbage-page bytes but the causal mask below
+                        # replaces their scores with the same NEG constant
+                        # the slotted path uses, so outputs stay
+                        # bit-identical
+                        gk = paged_gather(ck, pt, max_len)
+                        gv = paged_gather(cv, pt, max_len)
+                        if quant:
+                            gk = _kv_dequantize(
+                                gk,
+                                paged_gather(cks[..., None], pt, max_len)[..., 0],
+                            )
+                            gv = _kv_dequantize(
+                                gv,
+                                paged_gather(cvs[..., None], pt, max_len)[..., 0],
+                            )
+                    else:
+                        gk, gv = ck, cv
+                        if quant:
+                            gk = _kv_dequantize(gk, cks)
+                            gv = _kv_dequantize(gv, cvs)
                 # query row i sits at global position index + i: causal over
                 # the written prefix (the reference instead relies on only
                 # having written the prefix, `attention.py:71-76,86`)
@@ -513,7 +518,10 @@ class Attention(nn.Module):
                         )
                     if mask_array is not None:
                         mask = mask & mask_rows_at(mask_array)
-                out = dense_attention(q, gk, gv, mask=mask, stable=self.stable)
+                with jax.named_scope("attend"):
+                    out = dense_attention(
+                        q, gk, gv, mask=mask, stable=self.stable
+                    )
             new_cache = {"k": ck, "v": cv, "index": index + n}
             if quant:
                 new_cache["k_scale"] = cks

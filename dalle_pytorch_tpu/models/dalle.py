@@ -45,6 +45,8 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from dalle_pytorch_tpu.models.transformer import Transformer, DivideMax, make_decode_cache
+from dalle_pytorch_tpu.obs import scopes
+from dalle_pytorch_tpu.obs.tracing import host_span
 from dalle_pytorch_tpu.ops.sampling import top_k_filter, gumbel_sample
 
 NEG_MASK_VALUE = -float(np.finfo(np.float32).max)
@@ -272,14 +274,15 @@ class DALLE(nn.Module):
         labels = jnp.concatenate([text[:, 1:], offsetted_image], axis=1)
         split = self.text_seq_len
         row_is_text = jnp.arange(seq_len) < self.text_seq_len
-        per_pos = chunked_masked_ce(
-            h, kernel, bias, labels,
-            row_is_text=row_is_text,
-            num_text_vocab=self.total_text_tokens,
-        )
         ct = self.text_loss_coeff
         ci = self.loss_img_weight if self.img_loss_coeff is None else self.img_loss_coeff
-        loss = split_weighted_mean(per_pos, split, ct, ci)
+        with jax.named_scope("loss"):
+            per_pos = chunked_masked_ce(
+                h, kernel, bias, labels,
+                row_is_text=row_is_text,
+                num_text_vocab=self.total_text_tokens,
+            )
+            loss = split_weighted_mean(per_pos, split, ct, ci)
         return loss, None
 
     def _logits_kernel(self):
@@ -320,13 +323,16 @@ class DALLE(nn.Module):
         split = self.image_seq_len
         # image-first layout: rows >= image_seq_len are text rows
         row_is_text = jnp.arange(seq_len) >= split
-        per_pos = chunked_masked_ce(
-            h, kernel, bias, labels,
-            row_is_text=row_is_text,
-            num_text_vocab=self.total_text_tokens,
-        )
         ci, ct = self.img_loss_coeff_inv, self.text_loss_coeff_inv
-        loss = split_weighted_mean(per_pos, split, ci, ct, drop_last_of_first=True)
+        with jax.named_scope("loss"):
+            per_pos = chunked_masked_ce(
+                h, kernel, bias, labels,
+                row_is_text=row_is_text,
+                num_text_vocab=self.total_text_tokens,
+            )
+            loss = split_weighted_mean(
+                per_pos, split, ci, ct, drop_last_of_first=True
+            )
 
         # 3-token sequence accuracy (`:697-699`) on dense logits for rows
         # [split, split+3) only — text rows, where every image-vocab column
@@ -421,12 +427,18 @@ class DALLE(nn.Module):
         logits = self.to_logits(out)
 
         lmask = self._logits_blocked(seq_len, inverse_mapping)[None]
-        logits = jnp.where(lmask, NEG_MASK_VALUE, logits.astype(jnp.float32))
+        with jax.named_scope("logits_mask"):
+            logits = jnp.where(lmask, NEG_MASK_VALUE, logits.astype(jnp.float32))
 
         if not return_loss:
             return logits
 
         assert image is not None, "when training, image must be supplied"
+        with jax.named_scope("loss"):
+            return self._dense_loss(logits, text, image, inverse_mapping)
+
+    def _dense_loss(self, logits, text, image, inverse_mapping):
+        """Split text/image cross-entropy over materialized logits."""
         offsetted_image = image + self.total_text_tokens
 
         if inverse_mapping:
@@ -594,10 +606,28 @@ def _jitted_sampler(fn_builder, model, static_key):
     dispatch would keep TWO copies of the whole slot KV cache alive and
     pay a full-cache copy).
     """
-    return jax.jit(
+    return scopes.remembering(jax.jit(
         fn_builder(model, static_key),
         donate_argnums=getattr(fn_builder, "_donate_argnums", ()),
-    )
+    ))
+
+
+def _program(name: str):
+    """Name the function a builder returns: `jax.jit` calls the compiled
+    module `jit_<name>`, which is what a device trace and the compile cache
+    show. One name per builder, set here and nowhere else; the sharded
+    engine jits the same builders' functions, so its ladder reads the same."""
+
+    def wrap(builder):
+        @functools.wraps(builder)
+        def build(model, key):
+            fn = builder(model, key)
+            fn.__name__ = name
+            return fn
+
+        return build
+
+    return wrap
 
 
 _warned_eager_sampler = False
@@ -620,7 +650,8 @@ def _jit_sample(fn_builder, model, static_key, *args):
                 stacklevel=3,
             )
         return fn_builder(model, static_key)(*args)
-    return jitted(*args)
+    with host_span("sample.dispatch", program=jitted.name):
+        return jitted(*args)
 
 
 def generate_images_cached(
@@ -663,6 +694,7 @@ def generate_images_cached(
     )
 
 
+@_program("sample_cached")
 def _cached_sampler_builder(model, key):
     filter_thres, temperature, cond_scale, num_init, vae = key
 
@@ -726,14 +758,16 @@ def _generate_images_cached_impl(
 
     def step(carry, i):
         img_tokens, cache, row, rng = carry
-        rng, sample_rng = jax.random.split(rng)
-        masked = jnp.where(blocked, NEG_MASK_VALUE, blend(row))
-        filtered = top_k_filter(masked, thres=filter_thres)
-        sample = gumbel_sample(sample_rng, filtered, temperature=temperature)
-        sample = (sample - model.total_text_tokens).astype(jnp.int32)
-        prev = jax.lax.dynamic_index_in_dim(img_tokens, i, axis=1, keepdims=False)
-        new = jnp.where(i < primed, prev, sample)
-        img_tokens = jax.lax.dynamic_update_slice(img_tokens, new[:, None], (0, i))
+        with jax.named_scope("rng_split"):
+            rng, sample_rng = jax.random.split(rng)
+        with jax.named_scope("sample"):
+            masked = jnp.where(blocked, NEG_MASK_VALUE, blend(row))
+            filtered = top_k_filter(masked, thres=filter_thres)
+            sample = gumbel_sample(sample_rng, filtered, temperature=temperature)
+            sample = (sample - model.total_text_tokens).astype(jnp.int32)
+            prev = jax.lax.dynamic_index_in_dim(img_tokens, i, axis=1, keepdims=False)
+            new = jnp.where(i < primed, prev, sample)
+            img_tokens = jax.lax.dynamic_update_slice(img_tokens, new[:, None], (0, i))
         feed = jnp.concatenate([new, new], axis=0) if use_null else new
         row, cache = model.apply(
             variables, feed, i, cache, method=DALLE.decode_image_step
@@ -785,6 +819,7 @@ def generate_images_cached_batched(
     )
 
 
+@_program("sample_cached_batched")
 def _batched_sampler_builder(model, key):
     cond_scale, vae = key
 
@@ -842,15 +877,16 @@ def _generate_images_cached_batched_impl(
 
     def step(carry, i):
         img_tokens, cache, row = carry
-        masked = jnp.where(blocked, NEG_MASK_VALUE, blend(row))
-        filtered = top_k_filter_per_row(masked, keep_k)
-        # (seed, image position) keyed RNG — shared derivation with the
-        # continuous-batching chunk decode (ops/sampling.py), so the two
-        # engines sample bit-identical streams per row
-        step_keys = per_row_step_keys(seeds, jnp.full((b,), i, jnp.int32))
-        sample = gumbel_sample_per_row(step_keys, filtered, temperatures)
-        sample = (sample - model.total_text_tokens).astype(jnp.int32)
-        img_tokens = jax.lax.dynamic_update_slice(img_tokens, sample[:, None], (0, i))
+        with jax.named_scope("sample"):
+            masked = jnp.where(blocked, NEG_MASK_VALUE, blend(row))
+            filtered = top_k_filter_per_row(masked, keep_k)
+            # (seed, image position) keyed RNG — shared derivation with the
+            # continuous-batching chunk decode (ops/sampling.py), so the two
+            # engines sample bit-identical streams per row
+            step_keys = per_row_step_keys(seeds, jnp.full((b,), i, jnp.int32))
+            sample = gumbel_sample_per_row(step_keys, filtered, temperatures)
+            sample = (sample - model.total_text_tokens).astype(jnp.int32)
+            img_tokens = jax.lax.dynamic_update_slice(img_tokens, sample[:, None], (0, i))
         feed = jnp.concatenate([sample, sample], axis=0) if use_null else sample
         row, cache = model.apply(
             variables, feed, i, cache, method=DALLE.decode_image_step
@@ -978,6 +1014,7 @@ def prefill_into_slots(
     )
 
 
+@_program("slots_prefill")
 def _prefill_slots_builder(model, key):
     prefill_batch = key[0]
     sparse = "sparse" in key
@@ -1085,6 +1122,7 @@ def resume_into_slots(
     )
 
 
+@_program("slots_resume")
 def _resume_slots_builder(model, key):
     (prefill_batch,) = key
     batch_axis = 1 if model.executor == "scan" else 0
@@ -1152,6 +1190,7 @@ def release_slots(model: DALLE, state: dict, mask) -> dict:
     )
 
 
+@_program("slots_release")
 def _release_builder(model, key):
     del model, key
 
@@ -1199,6 +1238,7 @@ def decode_image_chunk(
     )
 
 
+@_program("slots_chunk")
 def _chunk_builder(model, key):
     chunk = key[0]
     return _make_chunk_fn(model, chunk, paged=False, sparse="sparse" in key)
@@ -1233,16 +1273,17 @@ def _make_chunk_fn(model, chunk, paged, sparse=False):
             cache, row, img_tokens, img_pos = carry
             live = active & (img_pos < image_seq_len)
 
-            masked = jnp.where(blocked, NEG_MASK_VALUE, row)
-            filtered = top_k_filter_per_row(masked, keep_k)
-            keys = per_row_step_keys(seeds, img_pos)
-            sample = gumbel_sample_per_row(keys, filtered, temps)
-            sample = (sample - model.total_text_tokens).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                masked = jnp.where(blocked, NEG_MASK_VALUE, row)
+                filtered = top_k_filter_per_row(masked, keep_k)
+                keys = per_row_step_keys(seeds, img_pos)
+                sample = gumbel_sample_per_row(keys, filtered, temps)
+                sample = (sample - model.total_text_tokens).astype(jnp.int32)
 
-            written = jax.vmap(
-                lambda r, t, p: jax.lax.dynamic_update_slice(r, t[None], (p,))
-            )(img_tokens, sample, jnp.clip(img_pos, 0, image_seq_len - 1))
-            img_tokens = jnp.where(live[:, None], written, img_tokens)
+                written = jax.vmap(
+                    lambda r, t, p: jax.lax.dynamic_update_slice(r, t[None], (p,))
+                )(img_tokens, sample, jnp.clip(img_pos, 0, image_seq_len - 1))
+                img_tokens = jnp.where(live[:, None], written, img_tokens)
 
             # stamp every layer's cache index from the per-slot position,
             # then run one decode step at per-row positions
@@ -1516,6 +1557,7 @@ def prefill_into_slots_paged(
     )
 
 
+@_program("slots_prefill_paged")
 def _prefill_slots_paged_builder(model, key):
     prefill_batch, page_size, n_text_pages = key[:3]
     sparse = "sparse" in key
@@ -1673,6 +1715,7 @@ def resume_into_slots_paged(
     )
 
 
+@_program("slots_resume_paged")
 def _resume_slots_paged_builder(model, key):
     prefill_batch, page_size, n_pages_row = key
     batch_axis = 1 if model.executor == "scan" else 0
@@ -1780,6 +1823,7 @@ def slice_prefix_sidecar(model: DALLE, sidecar: dict, r: int):
     )
 
 
+@_program("sidecar_slice")
 def _slice_sidecar_builder(model, key):
     del model, key
 
@@ -1823,6 +1867,7 @@ def admit_cached_prefix(
     )
 
 
+@_program("prefix_admit")
 def _admit_prefix_builder(model, key):
     (page_size,) = key
     batch_axis = 1 if model.executor == "scan" else 0
@@ -1907,6 +1952,7 @@ def decode_image_chunk_paged(
     )
 
 
+@_program("slots_chunk_paged")
 def _chunk_paged_builder(model, key):
     chunk = key[0]
     return _make_chunk_fn(model, chunk, paged=True, sparse="sparse" in key)
@@ -1953,6 +1999,7 @@ def generate_images(
     )
 
 
+@_program("sample_full")
 def _full_sampler_builder(model, key):
     filter_thres, temperature, cond_scale, num_init = key
 
@@ -1999,18 +2046,20 @@ def _generate_images_impl(
 
     def step(carry, i):
         img_tokens, rng = carry
-        rng, sample_rng = jax.random.split(rng)
+        with jax.named_scope("rng_split"):
+            rng, sample_rng = jax.random.split(rng)
         logits = forward_with_cond_scale(
             model, variables, text, img_tokens, cond_scale=cond_scale
         )
-        pos_logits = logits[:, model.text_seq_len + i]
-        filtered = top_k_filter(pos_logits, thres=filter_thres)
-        sample = gumbel_sample(sample_rng, filtered, temperature=temperature)
-        sample = (sample - model.total_text_tokens).astype(jnp.int32)
-        keep = i < primed
-        prev = jax.lax.dynamic_index_in_dim(img_tokens, i, axis=1, keepdims=False)
-        new = jnp.where(keep, prev, sample)
-        img_tokens = jax.lax.dynamic_update_slice(img_tokens, new[:, None], (0, i))
+        with jax.named_scope("sample"):
+            pos_logits = logits[:, model.text_seq_len + i]
+            filtered = top_k_filter(pos_logits, thres=filter_thres)
+            sample = gumbel_sample(sample_rng, filtered, temperature=temperature)
+            sample = (sample - model.total_text_tokens).astype(jnp.int32)
+            keep = i < primed
+            prev = jax.lax.dynamic_index_in_dim(img_tokens, i, axis=1, keepdims=False)
+            new = jnp.where(keep, prev, sample)
+            img_tokens = jax.lax.dynamic_update_slice(img_tokens, new[:, None], (0, i))
         return (img_tokens, rng), None
 
     (img_tokens, _), _ = jax.lax.scan(
@@ -2040,6 +2089,7 @@ def generate_texts(
     )
 
 
+@_program("sample_text")
 def _text_sampler_builder(model, key):
     filter_thres, temperature = key
 
@@ -2074,17 +2124,19 @@ def _generate_texts_impl(
 
     def step(carry, i):
         text, rng = carry
-        rng, sample_rng = jax.random.split(rng)
+        with jax.named_scope("rng_split"):
+            rng, sample_rng = jax.random.split(rng)
         logits = model.apply(variables, text)  # image part absent
-        pos_logits = logits[:, i]  # position i predicts text token i (bos shift)
-        filtered = top_k_filter(pos_logits, thres=filter_thres)
-        sample = gumbel_sample(sample_rng, filtered, temperature=temperature).astype(
-            jnp.int32
-        )
-        keep = i < prefix_len
-        prev = jax.lax.dynamic_index_in_dim(text, i, axis=1, keepdims=False)
-        new = jnp.where(keep, prev, sample)
-        text = jax.lax.dynamic_update_slice(text, new[:, None], (0, i))
+        with jax.named_scope("sample"):
+            pos_logits = logits[:, i]  # position i predicts text token i (bos shift)
+            filtered = top_k_filter(pos_logits, thres=filter_thres)
+            sample = gumbel_sample(
+                sample_rng, filtered, temperature=temperature
+            ).astype(jnp.int32)
+            keep = i < prefix_len
+            prev = jax.lax.dynamic_index_in_dim(text, i, axis=1, keepdims=False)
+            new = jnp.where(keep, prev, sample)
+            text = jax.lax.dynamic_update_slice(text, new[:, None], (0, i))
         return (text, rng), None
 
     (text, _), _ = jax.lax.scan(

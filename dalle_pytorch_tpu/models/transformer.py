@@ -165,14 +165,15 @@ def shift_with_ring(h, ring, pos, text_len, fmap, ring_end=None):
     Single-token decode: streaming shift at traced position `pos`.
     Returns (shifted h, new ring or None).
     """
-    if ring is None:
-        return shift_tokens_dalle(h, text_len, fmap), None
-    if h.shape[1] > 1:
-        shifted = shift_tokens_dalle(h, text_len, fmap)
-        if ring_end is not None:
-            return shifted, shift_ring_from_prefill_at(h, fmap, ring_end)
-        return shifted, shift_ring_from_prefill(h, fmap)
-    return shift_token_step(h, ring, pos, text_len, fmap)
+    with jax.named_scope("token_shift"):
+        if ring is None:
+            return shift_tokens_dalle(h, text_len, fmap), None
+        if h.shape[1] > 1:
+            shifted = shift_tokens_dalle(h, text_len, fmap)
+            if ring_end is not None:
+                return shifted, shift_ring_from_prefill_at(h, fmap, ring_end)
+            return shifted, shift_ring_from_prefill(h, fmap)
+        return shift_token_step(h, ring, pos, text_len, fmap)
 
 
 class _ScanBlock(nn.Module):
@@ -211,9 +212,10 @@ class _ScanBlock(nn.Module):
                  cache, key_mask, rotary):
         # pattern_idx is the scanned per-layer index into the broadcast
         # table of unique [S, S] pattern masks; None = uniform full attention
-        pattern_mask = (
-            None if pattern_table is None else pattern_table[pattern_idx]
-        )
+        with jax.named_scope("pattern_mask"):
+            pattern_mask = (
+                None if pattern_table is None else pattern_table[pattern_idx]
+            )
         cached = cache is not None
         pos = cache["attn"]["index"] if cached else None
         # per-row resume window (decode_resume injects it; absent on the
@@ -317,13 +319,21 @@ class _ScanStack(nn.Module):
         stack = scanned(
             deterministic=deterministic, name="layers", **self.block_kwargs
         )
-        x, new_cache = stack(
-            x, attn_scales, ff_scales, pattern_idx, pattern_table, cache,
-            key_mask, rotary,
-        )
-        if cache is not None:
-            return x, new_cache
-        return x
+        if cache is None:
+            x, _ = stack(
+                x, attn_scales, ff_scales, pattern_idx, pattern_table, cache,
+                key_mask, rotary,
+            )
+            return x
+        # `lax.scan` itself slices each layer's K/V out of the depth-stacked
+        # cache and stacks the new one back: no scope can be drawn inside
+        # it, so the cached scan is named as a whole (obs/scopes.py reads its
+        # bare slices as `cache_read` / `cache_write`)
+        with jax.named_scope("cached_scan"):
+            return stack(
+                x, attn_scales, ff_scales, pattern_idx, pattern_table, cache,
+                key_mask, rotary,
+            )
 
 
 class Transformer(nn.Module):

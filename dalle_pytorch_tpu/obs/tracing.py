@@ -28,6 +28,10 @@ Export is Chrome/Perfetto `trace_event` JSON (the "JSON Array Format" /
 one complete (`ph: "X"`) event per closed span, one synthetic track per
 trace so concurrent requests render as parallel rows.
 
+`host_span` puts the worker-thread stages (input wait, dispatch, chunk,
+harvest) on the profiler's clock as `dalle:*` annotations: the stages that
+can explain a device gap, in the same file as the device's operations.
+
 Fleet hooks (obs/aggregate.py): a tracer may carry a `TraceExporter` that
 ships every finished trace to a cross-process collector. The default is
 the shared `NULL_EXPORTER` no-op — same counter-gated zero-overhead
@@ -151,6 +155,21 @@ class _NullExporter:
 NULL_SPAN = _NullSpan()
 NULL_TRACE = _NullTrace()
 NULL_EXPORTER = _NullExporter()
+
+HOST_SPAN_PREFIX = "dalle:"
+
+
+def host_span(name: str, **args):
+    """A host span on the PROFILER's clock: `with host_span("serve.chunk",
+    rows=3):` writes `dalle:serve.chunk` into a running `jax.profiler`
+    capture (`POST /debug/profile`, `--flops_profiler`), beside the device's
+    own operations, so a device gap can be read against what the worker
+    thread was doing. Free when no capture is on. For layer boundaries
+    (one per dispatch, wave or step), never per operation; the request
+    tracer's `Span`s above keep their own monotonic clock."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(HOST_SPAN_PREFIX + name, **args)
 
 
 class Trace:

@@ -87,10 +87,11 @@ def chunked_masked_ce(
         ci, kc, bc = inp
         base = ci * chunk
         # [B, N, chunk] fp32 — the only logits transient that ever exists
-        logits = jnp.einsum(
-            "bnd,dc->bnc", h, kc.astype(h.dtype),
-            preferred_element_type=jnp.float32,
-        ) + bc.astype(jnp.float32)
+        with jax.named_scope("logits_chunk"):  # the head's matmul, not the loss
+            logits = jnp.einsum(
+                "bnd,dc->bnc", h, kc.astype(h.dtype),
+                preferred_element_type=jnp.float32,
+            ) + bc.astype(jnp.float32)
         ids = base + jnp.arange(chunk)
         id_is_text = (ids < num_text_vocab)[None, None, :]
         id_is_real = (ids < V)[None, None, :]

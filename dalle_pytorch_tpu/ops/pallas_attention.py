@@ -222,8 +222,12 @@ def _flash_forward(
         ]
         operands = [layout, q, k, v, mask_pad]
 
+    # the name is also the kernel's innermost scope, and the chip names the
+    # custom call after it (`%fwd_flash.3`): forward, dq and dkv are told
+    # apart by name alone, and all three still end in `_flash`
     o, lse = pl.pallas_call(
         kernel,
+        name="fwd_flash",
         grid=(b, h, nq_blocks, nk_blocks),
         in_specs=in_specs,
         out_specs=[
@@ -429,6 +433,7 @@ def _flash_backward(
             _dq_kernel, sm_scale=sm_scale, block_k=block_k, causal=causal,
             has_mask=has_mask, n_real_k=n_real_k, nk_blocks=nk_blocks,
         ),
+        name="dq_flash",
         grid=(b, h, nq_blocks, nk_blocks),
         in_specs=dq_in,
         out_specs=qspec,
@@ -479,6 +484,7 @@ def _flash_backward(
             has_mask=has_mask, n_real_q=n_real_q, n_real_k=n_real_k,
             block_k=block_k, nq_blocks=nq_blocks,
         ),
+        name="dkv_flash",
         grid=(b, h, nk_blocks, nq_blocks),
         in_specs=dkv_in,
         out_specs=[kspec2, kspec2],

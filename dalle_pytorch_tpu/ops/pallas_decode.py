@@ -249,6 +249,7 @@ def flash_decode_attention(
         operands = [qp, kp, ksp, vp, vsp]
     out = pl.pallas_call(
         kernel,
+        name="decode_slots",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, h, nk_blocks),
@@ -392,6 +393,7 @@ def block_sparse_flash_decode_attention(
         operands = [qp, kp, ksp, vp, vsp]
     out = pl.pallas_call(
         kernel,
+        name="decode_sparse",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, h, nk_blocks),
@@ -541,6 +543,7 @@ def paged_flash_decode_attention(
         ]
     out = pl.pallas_call(
         kernel,
+        name="decode_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, h, n_pages),
@@ -647,6 +650,7 @@ def block_sparse_paged_flash_decode_attention(
         ]
     out = pl.pallas_call(
         kernel,
+        name="decode_sparse_paged",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, h, n_pages),

@@ -25,6 +25,8 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from dalle_pytorch_tpu.obs.tracing import host_span
+
 
 @dataclass
 class SampleSpec:
@@ -613,6 +615,16 @@ class ContinuousEngine(GenerationEngine):
             "dalle_serving_chunks_total",
             "decode chunk dispatches by the continuous engine",
         )
+        # the micro-batcher observes the same series per flushed batch;
+        # here a "batch" is one chunk dispatch, so mean occupancy is
+        # _sum / _count on either engine
+        self._m_occupancy = self.registry.histogram(
+            "dalle_serving_batch_occupancy_rows",
+            "live rows per decode chunk dispatch (continuous engine)",
+            buckets=tuple(
+                float(b) for b in range(1, min(self.max_batch, 32) + 1)
+            ),
+        )
         self._m_prefills = self.registry.counter(
             "dalle_serving_prefills_total",
             "prompts prefilled into cache slots",
@@ -773,9 +785,10 @@ class ContinuousEngine(GenerationEngine):
             t0 = time.perf_counter()
             self.vitals.dispatch_begin("prefill")
             try:
-                self._replace_state(lambda s: self._prefill_op(
-                    s, texts, slots, seeds, temps, keep,
-                ), fault_tag="prefill")
+                with host_span("serve.prefill", rows=n):
+                    self._replace_state(lambda s: self._prefill_op(
+                        s, texts, slots, seeds, temps, keep,
+                    ), fault_tag="prefill")
             finally:
                 wall = time.perf_counter() - t0
                 self.vitals.dispatch_end("prefill", wall)
@@ -882,10 +895,11 @@ class ContinuousEngine(GenerationEngine):
             t0 = time.perf_counter()
             self.vitals.dispatch_begin("resume")
             try:
-                self._replace_state(lambda s: self._resume_op(
-                    s, texts, img_tokens, img_pos, slots, seeds, temps,
-                    keep,
-                ), fault_tag="resume")
+                with host_span("serve.resume", rows=n):
+                    self._replace_state(lambda s: self._resume_op(
+                        s, texts, img_tokens, img_pos, slots, seeds, temps,
+                        keep,
+                    ), fault_tag="resume")
             finally:
                 wall = time.perf_counter() - t0
                 self.vitals.dispatch_end("resume", wall)
@@ -937,9 +951,14 @@ class ContinuousEngine(GenerationEngine):
         with self._lock:
             t0 = time.perf_counter()
             self.vitals.dispatch_begin("chunk")
+            # live rows at the chunk's start: the span's `rows=` and the
+            # occupancy histogram read the same number
+            rows = int(np.count_nonzero(self._host_active))
             try:
-                self._replace_state(self._chunk_op, fault_tag="chunk")
+                with host_span("serve.chunk", rows=rows):
+                    self._replace_state(self._chunk_op, fault_tag="chunk")
                 if not _warmup:
+                    self._m_occupancy.observe(rows)
                     self._m_chunks.inc()
                     self.chunk_index += 1
                     self.stats.batches += 1
@@ -1002,7 +1021,8 @@ class ContinuousEngine(GenerationEngine):
                 # rows would compile one program PER finished-count (1..max_batch)
                 # and break the exactly-the-warmup-set compile discipline that
                 # tests/test_continuous.py pins with assert_no_recompiles
-                toks = jax.device_get(self._state["img_tokens"])  # tracelint: disable=TL002 -- retirement harvest is a designed sync; fixed-shape transfer beats a per-count compiled gather
+                with host_span("serve.harvest", rows=len(slots)):
+                    toks = jax.device_get(self._state["img_tokens"])  # tracelint: disable=TL002 -- retirement harvest is a designed sync; fixed-shape transfer beats a per-count compiled gather
             finally:
                 self.vitals.dispatch_end(
                     "harvest", time.perf_counter() - t0
@@ -1684,7 +1704,8 @@ class PagedContinuousEngine(ContinuousEngine):
         t0 = time.perf_counter()
         self.vitals.dispatch_begin("prefill")
         try:
-            self._admit_wave(hits, misses, stats, _warmup)
+            with host_span("serve.prefill", rows=n):
+                self._admit_wave(hits, misses, stats, _warmup)
         finally:
             wall = time.perf_counter() - t0
             self.vitals.dispatch_end("prefill", wall)
@@ -1876,10 +1897,11 @@ class PagedContinuousEngine(ContinuousEngine):
             with self._lock:
                 # on failure _replace_state rebuilds state AND (via
                 # _fresh_state) the kv manager, discarding the mappings
-                self._replace_state(lambda s: self._paged_resume_op(
-                    s, texts, img_tokens, img_pos, slots, seeds, temps,
-                    keep, page_rows,
-                ), fault_tag="resume")
+                with host_span("serve.resume", rows=n):
+                    self._replace_state(lambda s: self._paged_resume_op(
+                        s, texts, img_tokens, img_pos, slots, seeds, temps,
+                        keep, page_rows,
+                    ), fault_tag="resume")
                 if _warmup:
                     self._capture_cost(
                         "resume",

@@ -209,7 +209,11 @@ class _MeshServingMixin:
     def _sharded_program(self, name: str, build):
         fn = self._sharded_programs.get(name)
         if fn is None:
-            fn = build()
+            from dalle_pytorch_tpu.obs import scopes
+
+            # remembered at its first dispatch with the shardings its
+            # arguments carry, under the builder's own program name
+            fn = scopes.remembering(build())
             self._sharded_programs[name] = fn
         return fn
 

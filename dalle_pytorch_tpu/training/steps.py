@@ -31,6 +31,7 @@ import optax
 from flax.training import train_state
 
 from dalle_pytorch_tpu.models.dvae import DiscreteVAE
+from dalle_pytorch_tpu.obs import scopes
 
 MODES = ("forward_only", "forward_forward", "forward_reverse_partial", "reverse_only")
 
@@ -64,6 +65,13 @@ def set_learning_rate(state: TrainState, lr: float) -> TrainState:
     hyper = dict(opt_state.hyperparams)
     hyper["learning_rate"] = jnp.asarray(lr, jnp.float32)
     return state.replace(opt_state=opt_state._replace(hyperparams=hyper))
+
+
+def _update(state: TrainState, grads) -> TrainState:
+    """Gradient clipping and the Adam update, named: no flax module bounds
+    them, so without the scope their operations carry no path at all."""
+    with jax.named_scope("optimizer"):
+        return state.apply_gradients(grads=grads)
 
 
 def _accumulate(loss_and_metrics_fn, params, batches, rng, accum: int):
@@ -116,14 +124,14 @@ def make_vae_train_step(vae: DiscreteVAE, grad_accum: int = 1) -> Callable:
         )
         return loss, {"loss": loss}
 
-    def step(state: TrainState, images, rng, temp):
+    def vae_step(state: TrainState, images, rng, temp):
         fn = lambda p, mb, r: loss_fn(p, mb, r, temp)
         grads, metrics = _accumulate(
             fn, state.params, _microbatch(images, grad_accum), rng, grad_accum
         )
-        return state.apply_gradients(grads=grads), metrics
+        return _update(state, grads), metrics
 
-    return step
+    return vae_step
 
 
 def make_dalle_train_step(
@@ -211,11 +219,16 @@ def make_dalle_train_step(
         return loss, metrics
 
     def step(state: TrainState, batch, rng, vae_params=None):
+        # runs while the step is TRACED, once per compile and never per
+        # step: the shapes it is traced at, to be lowered again as the
+        # trainer jits it (obs/scopes.py)
+        args = (state, batch, rng) + (() if vae_params is None else (vae_params,))
+        scopes.remember("step", step, args, donate_argnums=0)
         fn = lambda p, mb, r: loss_fn(p, mb, r, vae_params)
         grads, metrics = _accumulate(
             fn, state.params, _microbatch(batch, grad_accum), rng, grad_accum
         )
-        return state.apply_gradients(grads=grads), metrics
+        return _update(state, grads), metrics
 
     return step
 
@@ -308,10 +321,10 @@ def make_clip_train_step(clip_model, grad_accum: int = 1) -> Callable:
         )
         return loss, {"loss": loss}
 
-    def step(state: TrainState, batch, rng):
+    def clip_step(state: TrainState, batch, rng):
         grads, metrics = _accumulate(
             loss_fn, state.params, _microbatch(batch, grad_accum), rng, grad_accum
         )
-        return state.apply_gradients(grads=grads), metrics
+        return _update(state, grads), metrics
 
-    return step
+    return clip_step

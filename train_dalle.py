@@ -78,6 +78,8 @@ def main():
     )
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from dalle_pytorch_tpu.data.prefetch import Prefetcher
+    from dalle_pytorch_tpu.obs import scopes
+    from dalle_pytorch_tpu.obs.tracing import host_span
     from dalle_pytorch_tpu.training.config import load_config
     from dalle_pytorch_tpu.training.checkpoint import CheckpointManager
     from dalle_pytorch_tpu.training.metrics import (
@@ -275,12 +277,14 @@ def main():
             null_cond_prob=cfg.null_cond_prob, pp_trunk=pp_trunk,
         )
         extra_shardings = ()
-    step_fn = jax.jit(
+    # remembered at its first dispatch, shardings and all, so that a
+    # profiler capture of this run can be reduced by component (obs/scopes.py)
+    step_fn = scopes.remembering(jax.jit(
         raw_step,
         in_shardings=(state_sh, batch_shardings, None) + extra_shardings,
         out_shardings=(state_sh, None),
         donate_argnums=0,
-    )
+    ))
     # steps_per_dispatch>1: scan T optimizer steps into one dispatch
     # (make_multi_step) — host-loop elimination; window batches get a
     # leading unsharded step axis on top of the per-step batch specs
@@ -292,12 +296,12 @@ def main():
             batch_shardings,
             is_leaf=lambda x: isinstance(x, NamedSharding),
         )
-        multi_fn = jax.jit(
+        multi_fn = scopes.remembering(jax.jit(
             make_multi_step(raw_step, steps_per_dispatch),
             in_shardings=(state_sh, win_shardings, None) + extra_shardings,
             out_shardings=(state_sh, None),
             donate_argnums=0,
-        )
+        ))
 
     run_dir = Path(cfg.output_dir)
     ckpt = CheckpointManager(run_dir / "dalle_ckpt", keep_n=cfg.keep_n_checkpoints)
@@ -452,10 +456,11 @@ def main():
                 # steps_per_dispatch never changes the randomness
                 if multi_fn is not None and not isinstance(dev_batch, list):
                     keys = window_keys(rng, global_step, steps_per_dispatch)
-                    if in_step_encode:
-                        state, metrics = multi_fn(state, dev_batch, keys, vae_params)
-                    else:
-                        state, metrics = multi_fn(state, dev_batch, keys)
+                    with host_span("train.dispatch", steps=steps_per_dispatch):
+                        if in_step_encode:
+                            state, metrics = multi_fn(state, dev_batch, keys, vae_params)
+                        else:
+                            state, metrics = multi_fn(state, dev_batch, keys)
                     global_step += steps_per_dispatch
                     epoch_batch += steps_per_dispatch
                 else:
@@ -466,10 +471,11 @@ def main():
                     for dev_b, caps_i, head_i in singles:
                         captions, text_head = caps_i, head_i
                         r = jax.random.fold_in(rng, global_step)
-                        if in_step_encode:
-                            state, metrics = step_fn(state, dev_b, r, vae_params)
-                        else:
-                            state, metrics = step_fn(state, dev_b, r)
+                        with host_span("train.dispatch", steps=1):
+                            if in_step_encode:
+                                state, metrics = step_fn(state, dev_b, r, vae_params)
+                            else:
+                                state, metrics = step_fn(state, dev_b, r)
                         global_step += 1
                         epoch_batch += 1
 
@@ -484,7 +490,8 @@ def main():
                 last_loss = metrics["loss"]  # lazy device scalar; no sync here
                 log = {}
                 if crossed(cfg.log_every_n_steps):
-                    step_loss = float(last_loss)
+                    with host_span("train.log_sync"):  # the loop's one sync
+                        step_loss = float(last_loss)
                     epoch_losses.append(step_loss)
                     log.update(
                         epoch=epoch, iter=global_step, loss=step_loss,
@@ -500,18 +507,22 @@ def main():
                     # cross-host-sharded arrays natively (and copies to
                     # host before its async write), where device_get would
                     # raise on non-addressable fsdp/tp shards
-                    ckpt.save(
-                        global_step, state,
-                        metadata={
-                            "epoch": epoch, "step": global_step,
-                            "epoch_batch": epoch_batch,
-                            "epoch_losses": epoch_losses,
-                            "last_loss": (
-                                float(last_loss) if last_loss is not None else None
-                            ),
-                            "plateau": plateau.state_dict() if plateau else None,
-                        },
-                    )
+                    with host_span("train.checkpoint", step=global_step):
+                        ckpt.save(
+                            global_step, state,
+                            metadata={
+                                "epoch": epoch, "step": global_step,
+                                "epoch_batch": epoch_batch,
+                                "epoch_losses": epoch_losses,
+                                "last_loss": (
+                                    float(last_loss)
+                                    if last_loss is not None else None
+                                ),
+                                "plateau": (
+                                    plateau.state_dict() if plateau else None
+                                ),
+                            },
+                        )
 
                 # ALL processes run the sampling computation (it is an
                 # SPMD program over the sharded params); only the logger
@@ -522,10 +533,11 @@ def main():
                     # the reference (`train_dalle.py:564-576`)
                     # (disjoint from the train-step keys: extra fold_in tag)
                     gr = jax.random.fold_in(jax.random.fold_in(rng, global_step), 1)
-                    toks = generate_images(
-                        model, {"params": state.params},
-                        gr, jnp.asarray(text_head), filter_thres=0.9,
-                    )
+                    with host_span("train.sample", step=global_step):
+                        toks = generate_images(
+                            model, {"params": state.params},
+                            gr, jnp.asarray(text_head), filter_thres=0.9,
+                        )
                     if isinstance(vae, DiscreteVAE):
                         if dvae_decode is None:
                             dvae_decode = jax.jit(lambda p, t: vae.apply(
