@@ -56,8 +56,9 @@ from dalle_pytorch_tpu.ops.rotary import apply_rotary
 # The three thresholds below come from a ROOFLINE MODEL, not from a chip:
 # scripts/flash_crossover.py takes `cost_analysis()` of programs compiled on
 # the CPU and places their FLOPs and bytes on the v5e's published peaks
-# (197 TFLOP/s bf16, 819 GB/s). No chip timing stands behind any of them
-# yet (PERF.md); re-derive them from ledger rows when those exist.
+# (197 TFLOP/s bf16, 819 GB/s). One chip timing stands behind the first
+# (below); none behind the other two yet (PERF.md): re-derive them from
+# ledger rows when those exist.
 
 # Sequence length at or above which `attn_impl="auto"` switches from the
 # dense einsum to the Pallas flash kernel (O(N) memory vs dense's O(N^2)
@@ -68,6 +69,8 @@ from dalle_pytorch_tpu.ops.rotary import apply_rotary
 # bench-grid point that still auto-selects flash for the flagship 1280.
 # Overridable per model (attn_impl=) or by rebinding this constant; an
 # on-chip wall-clock A/B (`scripts/pallas_onchip.py`) is the final decider.
+# Measured at 1280 only (one v5e, the flagship train step, PERF.md PR 26):
+# flash 33.9k tokens/s, dense 30.7k; lengths below it are not measured.
 AUTO_FLASH_MIN_SEQ = 1024
 
 # Cache length at or above which `attn_impl="auto"` runs the CACHED decode

@@ -22,8 +22,11 @@ Design:
     log-sum-exp (two kernels: dq over q blocks, dk/dv over k blocks), the
     same recompute-instead-of-store trade the reference's reversible layers
     make (`reversible.py:57-127`);
-  * fp32 accumulation regardless of input dtype (bf16 inputs stay bf16 on
-    the MXU operands).
+  * fp32 scores, softmax state and accumulators regardless of input dtype;
+    the MXU operands keep the dtype they arrive in (bf16 stays bf16);
+  * tiles chosen from the shape (`choose_tiles`), the streamed operand's
+    tiles walked by a loop inside the kernel, and each kernel emitted
+    through a jitted function, so a program holds each body once.
 
 Interpret mode (CPU) is selected automatically off-TPU so the full test
 suite exercises these kernels without hardware.
@@ -104,136 +107,385 @@ def mask_block_layout(mask: np.ndarray, block_q: int, block_k: int):
     return padded, layout
 
 
-# ------------------------------------------------------------------ forward
+# ------------------------------------------------------------------- tiles
+
+#: VMEM a call may plan for: v5e's scoped default is 16 MiB a kernel, and
+#: Mosaic's own temporaries (relayouts, the spilled score tile) want room.
+VMEM_BUDGET = 12 * 1024 * 1024
+_LANES = 128  # a [rows, 1] block is laid out [rows, 128] in VMEM
+
+#: kernel bodies built (traced and lowered to Mosaic) by this process, and
+#: the tiles each distinct call shape got: what the jitted emitters below
+#: keep at 3 or 4 for a whole train step where every call site used to
+#: build its own (tests pin it the way they pin `obs.scopes.remembered`).
+kernel_bodies = 0
+tiles_chosen: dict = {}
 
 
-def _fwd_kernel(
-    *refs,
-    sm_scale: float,
-    block_k: int,
-    causal: bool,
-    has_mask: bool,
-    n_real_k: int,
-    nk_blocks: int,
-):
-    """Grid (b, h, qi, ki): the q block stays put over the inner ki steps
-    while [block_k, d] k/v tiles stream through (auto double-buffered), so
-    VMEM holds one tile of each operand regardless of sequence length. The
-    online-softmax state (m, l, acc) carries across ki in fp32 VMEM
-    scratch and the normalized output flushes on the last step."""
+def forget() -> None:
+    """Drop the counter, the tiles and the emitters' trace caches (tests)."""
+    global kernel_bodies
+    kernel_bodies = 0
+    tiles_chosen.clear()
+    for emit in (_emit_fwd, _emit_dq, _emit_dkv):
+        emit.clear_cache()
+
+
+def _built(kind: str, q, k, block_q: int, block_k: int) -> None:
+    global kernel_bodies
+    kernel_bodies += 1
+    tiles_chosen[(kind, q.shape, k.shape[2], q.dtype.name)] = (block_q, block_k)
+
+
+def _sides(n: int) -> list:
+    """The sides a score tile may take along a length: the whole length up
+    to one lane row, else the multiples of 128 that DIVIDE the length
+    rounded up to 128 (128, 256, 640, 1280 at 1280, never 512, which would
+    pad every operand to 1536; 128, 384 at 257, over a padded 384)."""
+    if n <= _LANES:
+        return [max(n, 1)]
+    n_pad = -(-n // _LANES) * _LANES
+    return [c for c in range(_LANES, n_pad + 1, _LANES) if n_pad % c == 0]
+
+
+def _span(n: int, block: int, row_bytes: int) -> int:
+    """How much of a streamed operand one grid step holds: the whole row
+    where a third of the budget takes it twice (always, at DALL-E
+    lengths), else the longest run of whole blocks that divides it."""
+    steps = -(-n // block)
+    fit = max(VMEM_BUDGET // 3 // (2 * row_bytes * block), 1)
+    return block * max(s for s in range(1, steps + 1) if steps % s == 0 and s <= fit)
+
+
+def _spans(n_q: int, n_k: int, block_q: int, block_k: int, d: int, itemsize: int):
+    """(span_q, span_k): the q rows `dkv` and the k rows `fwd`/`dq` keep
+    resident per grid step, walked tile by tile inside the kernel."""
+    span_q = _span(n_q, block_q, 2 * d * itemsize + 2 * _LANES * 4)
+    span_k = _span(n_k, block_k, 2 * d * itemsize)
+    return span_q, span_k
+
+
+def vmem_bytes(block_q: int, block_k: int, span_q: int, span_k: int, d: int,
+               itemsize: int, masked: bool = False) -> int:
+    """What the widest of the three kernels holds in VMEM at these tiles:
+    every operand block twice (Pallas double-buffers), `[rows, 1]` blocks
+    at their lane-padded size, a static mask's int8 block, the fp32
+    scratch, and four score tiles (s, p, dp, ds) in fp32."""
+    rows = lambda n: n * _LANES * 4  # lse, delta
+    fwd_dq = 2 * (3 * block_q * d * itemsize + 2 * span_k * d * itemsize
+                  + 2 * rows(block_q) + masked * block_q * span_k)
+    dkv = 2 * (2 * span_q * d * itemsize + 4 * block_k * d * itemsize
+               + 2 * rows(span_q) + masked * span_q * block_k)
+    scratch = (block_q + 2 * block_k) * d * 4 + 2 * rows(block_q)
+    return max(fwd_dq, dkv) + scratch + 4 * block_q * block_k * 4
+
+
+def choose_tiles(n_q: int, n_k: int, d: int, dtype, masked: bool = False) -> tuple:
+    """(block_q, block_k) for a call, from its shape alone: evaluated at
+    trace time, nothing timed, nothing read from the environment.
+
+    The largest score tile that fits `VMEM_BUDGET`; of two as large the
+    squarer, then the one with more keys. Measured on the v5e at
+    16 x 16 x 1280 x 64 bf16 (PERF.md, PR 26): every tile pays one serial
+    round of softmax state (row max and sum across lanes, `m`, `l`, the
+    rescaled accumulator) whatever its width, so few wide tiles beat many
+    narrow ones even though a causal diagonal then crosses more dead
+    pairs: 640 x 640 runs fwd + fwd + dq + dkv in 8.4 ms a layer,
+    256 x 256 in 15.5, 128 x 128 in 27.4.
+    """
+    itemsize = jnp.dtype(dtype).itemsize
+    fits = [
+        (bq * bk, min(bq, bk), bk, bq)
+        for bq in _sides(n_q) for bk in _sides(n_k)
+        if vmem_bytes(bq, bk, *_spans(n_q, n_k, bq, bk, d, itemsize), d, itemsize,
+                      masked) <= VMEM_BUDGET
+    ]
+    # nothing fits only where one 128 x 128 tile's operands do not (a head
+    # thousands wide): take the narrowest and let the compiler say so
+    *_, bk, bq = max(fits) if fits else (_sides(n_k)[0], _sides(n_q)[0])
+    return bq, bk
+
+
+# ---------------------------------------------------------------- kernels
+#
+# All three run a grid (b, h, resident block, span of the streamed operand)
+# and walk the span's tiles in a `lax.fori_loop` INSIDE the kernel: a grid
+# step costs ~0.35 us on the v5e whatever it does (25,600 of them were the
+# whole 11 ms of a 128 x 128 call), a loop step a few cycles, and a causal
+# loop simply stops at the diagonal. MXU operands keep the dtype they
+# arrive in (bf16 stays bf16: one MXU pass, not the multi-pass f32
+# product); scores, softmax state and accumulators are fp32, and `p`/`ds`
+# are rounded to the operand dtype only where they enter a dot, as the
+# dense path's weights x V does.
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """fp32 product of two MXU operands. Float32 operands multiply at the
+    precision the process is set to, as they always did; narrower ones
+    take the MXU's one native pass (a process-wide `highest` has no
+    meaning for bf16 operands, and Mosaic refuses it)."""
+    precision = None if a.dtype == jnp.float32 else lax.Precision.DEFAULT
+    return lax.dot_general(
+        a, b, dims, precision=precision, preferred_element_type=jnp.float32
+    )
+
+
+def _scores(q, kb, *, sm_scale, row0, col0, causal, mask, n_real_k, n_real_q=None):
+    """fp32 [bq, bk] scores of one tile with everything that is not
+    attended set to NEG_INF. `row0`/`col0`: the tile's first row and
+    column in the whole (padded) score matrix."""
+    bq, bk = q.shape[0], kb.shape[0]
+    s = _dot(q, kb, _NT) * sm_scale
+    if causal:
+        rel = (lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+               - lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+        s = jnp.where(rel >= col0 - row0, s, NEG_INF)
+    if mask is not None:  # the static mask's tile, int8
+        s = jnp.where(mask.astype(jnp.int32) != 0, s, NEG_INF)
+    if n_real_k % bk != 0:  # key padding
+        col = col0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        s = jnp.where(col < n_real_k, s, NEG_INF)
+    if n_real_q is not None and n_real_q % bq != 0:
+        # padded q rows have garbage lse: drop them (dk/dv sum over rows)
+        row = row0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        s = jnp.where(row < n_real_q, s, NEG_INF)
+    return s
+
+
+def _walk(body, *, block, steps, lo, hi, occupied=None):
+    """Run `body(tile, start)` over tiles `lo` to `hi - 1` of this grid
+    step's span: `tile` the tile's index in the whole row, `start` its
+    first row in the span. `occupied(tile)` reads a static mask's
+    occupancy layout: every tile is visited and the empty ones skipped."""
+    t0 = pl.program_id(3) * steps
+
+    def step(j, carry):
+        start = pl.multiple_of(j * block, block)
+        if occupied is None:
+            body(t0 + j, start)
+        else:
+            pl.when(occupied(t0 + j) != 0)(lambda: body(t0 + j, start))
+        return carry
+
+    lax.fori_loop(lo, hi, step, 0)
+
+
+def _walk_k(body, *, qi, bq, block_k, steps, causal, layout_ref):
+    """The forward's and dq's walk over the live k tiles of the span: a
+    causal loop ends at the diagonal."""
+    hi = steps
+    if causal:
+        k0 = pl.program_id(3) * steps
+        hi = jnp.clip(_causal_last_live_k(qi, bq, block_k) + 1 - k0, 0, steps)
+    _walk(body, block=block_k, steps=steps, lo=0, hi=hi,
+          occupied=None if layout_ref is None else lambda kj: layout_ref[qi, kj])
+
+
+def _fwd_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
+    """q block resident, k/v span resident, online softmax over its tiles;
+    (m, l, acc) carry across spans in fp32 scratch and the normalized
+    output flushes on the last one."""
     if has_mask:
         (layout_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
          m_ref, l_ref, acc_ref) = refs
     else:
         layout_ref = mask_ref = None
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
-
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
     bq = q_ref.shape[2]
 
-    @pl.when(ki == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    if causal and not has_mask:
-        # block-triangle cut: k blocks strictly above the diagonal never run
-        live = ki <= _causal_last_live_k(qi, bq, block_k)
-    elif has_mask:
-        live = layout_ref[qi, ki] != 0
-    else:
-        live = True
-
-    @pl.when(live)
-    def _attend():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [bq, d]
-        kb = k_ref[0, 0].astype(jnp.float32)  # [bk, d]
-        vb = v_ref[0, 0].astype(jnp.float32)
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32)  # [bq, bk]
-        col = ki * block_k + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-        if causal and not has_mask:
-            row = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            s = jnp.where(row >= col, s, NEG_INF)
-        if has_mask:
-            s = jnp.where(mask_ref[...], s, NEG_INF)
-        if n_real_k % block_k != 0:  # mask key padding
-            s = jnp.where(col < n_real_k, s, NEG_INF)
+    def attend(kj, start):
+        cols = pl.ds(start, block_k)
+        vb = v_ref[0, 0, cols, :]
+        s = _scores(
+            q_ref[0, 0], k_ref[0, 0, cols, :], sm_scale=sm_scale,
+            row0=qi * bq, col0=kj * block_k, causal=causal,
+            mask=mask_ref[:, cols] if has_mask else None, n_real_k=n_real_k,
+        )
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(vb.dtype), vb, preferred_element_type=jnp.float32
-        )
+        acc_ref[...] = acc_ref[...] * corr + _dot(p.astype(vb.dtype), vb)
 
-    @pl.when(ki == nk_blocks - 1)
+    _walk_k(attend, qi=qi, bq=bq, block_k=block_k, steps=steps, causal=causal,
+            layout_ref=layout_ref)
+
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():
         safe_l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(safe_l)  # [bq, 1]
 
 
-def _flash_forward(
-    q, k, v, mask_pad, layout, *,
-    sm_scale, block_q, block_k, causal, n_real_q, n_real_k, interpret,
-):
+def _dq_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
+    """Same walk as the forward; dq accumulates in fp32 scratch."""
+    if has_mask:
+        (layout_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         mask_ref, dq_ref, acc_ref) = refs
+    else:
+        layout_ref = mask_ref = None
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = refs
+    qi = pl.program_id(2)
+    bq = q_ref.shape[2]
+
+    @pl.when(pl.program_id(3) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def attend(kj, start):
+        cols = pl.ds(start, block_k)
+        kb = k_ref[0, 0, cols, :]
+        s = _scores(
+            q_ref[0, 0], kb, sm_scale=sm_scale, row0=qi * bq,
+            col0=kj * block_k, causal=causal,
+            mask=mask_ref[:, cols] if has_mask else None, n_real_k=n_real_k,
+        )
+        p = jnp.exp(s - lse_ref[0, 0])
+        dp = _dot(do_ref[0, 0], v_ref[0, 0, cols, :], _NT)
+        ds = p * (dp - delta_ref[0, 0])  # sm_scale: once, at the flush
+        acc_ref[...] += _dot(ds.astype(kb.dtype), kb)
+
+    _walk_k(attend, qi=qi, bq=bq, block_k=block_k, steps=steps, causal=causal,
+            layout_ref=layout_ref)
+
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
+    def _flush():
+        dq_ref[0, 0] = (acc_ref[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
+                n_real_k, steps):
+    """Transposed walk: the k/v block is resident, the q/do/lse/delta span
+    is resident, and the loop runs over the span's q tiles from the first
+    one that attends to this k block. dk/dv accumulate in fp32 scratch."""
+    if has_mask:
+        (layout_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         mask_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
+    else:
+        layout_ref = mask_ref = None
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dk_acc, dv_acc) = refs
+    ki = pl.program_id(2)
+    bk = k_ref.shape[2]
+    q0 = pl.program_id(3) * steps
+
+    @pl.when(pl.program_id(3) == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def attend(qj, start):
+        rows = pl.ds(start, block_q)
+        qb, dob = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
+        s = _scores(
+            qb, k_ref[0, 0], sm_scale=sm_scale, row0=qj * block_q,
+            col0=ki * bk, causal=causal,
+            mask=mask_ref[rows, :] if has_mask else None, n_real_k=n_real_k,
+            n_real_q=n_real_q,
+        )
+        p = jnp.exp(s - lse_ref[0, 0, rows, :])
+        dv_acc[...] += _dot(p.astype(dob.dtype), dob, _TN)
+        dp = _dot(dob, v_ref[0, 0], _NT)
+        ds = p * (dp - delta_ref[0, 0, rows, :])  # sm_scale: at the flush
+        dk_acc[...] += _dot(ds.astype(qb.dtype), qb, _TN)
+
+    lo = 0
+    if causal:
+        lo = jnp.clip(_causal_first_live_q(ki, bk, block_q) - q0, 0, steps)
+    _walk(attend, block=block_q, steps=steps, lo=lo, hi=steps,
+          occupied=None if layout_ref is None else lambda qj: layout_ref[qj, ki])
+
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
+    def _flush():
+        dk_ref[0, 0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# --------------------------------------------------------------- emitters
+#
+# One jitted function per kernel, the statics as static arguments: JAX
+# caches the trace by signature and lowers a jitted callee ONCE per
+# program, so a 12-layer step holds each body once (forward, forward under
+# remat, dq, dkv) and calls it 12 times, where every call site used to
+# trace the body and lower it to Mosaic again (48 bodies, 2 to 3 s of
+# every start, warm or cold). The callee's operations carry names relative
+# to the call site's, so the instruction-to-component table
+# (`obs/scopes.py`) still sees each call under its own layer and phase.
+
+_PARALLEL = CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+)
+_STATICS = ("sm_scale", "block_q", "block_k", "causal", "n_real_q",
+            "n_real_k", "interpret")
+
+
+def _row(block, d):
+    """A block of an operand that the grid's third axis walks."""
+    return pl.BlockSpec((1, 1, block, d), lambda b_, h_, i, j: (b_, h_, i, 0))
+
+
+def _k_span_spec(span_k, d, block_q, causal):
+    """The k/v span of grid step (i, j). Causal: spans wholly above the
+    diagonal are dead (the loop runs no tile of them), and re-indexing
+    them to the last live span makes consecutive dead steps name the same
+    block, whose copy Pallas then elides. The kernels' loop bounds and
+    this map share `_causal_last_live_k`: they must stay in lockstep."""
+    if causal:
+        return pl.BlockSpec((1, 1, span_k, d), lambda b_, h_, i, j: (
+            b_, h_, jnp.minimum(j, _causal_last_live_k(i, block_q, span_k)), 0))
+    return pl.BlockSpec((1, 1, span_k, d), lambda b_, h_, i, j: (b_, h_, j, 0))
+
+
+def _with_mask(in_specs, operands, layout, mask_pad, mask_spec):
+    """Masked calls: the occupancy layout rides whole in SMEM in front,
+    the token mask's tile behind."""
+    # tracelint: disable=TL001 -- an optional operand's None-ness is static (a pytree with no leaves)
+    if mask_pad is None:
+        return in_specs, operands
+    return (
+        [pl.BlockSpec(memory_space=pltpu.SMEM), *in_specs, mask_spec],
+        [layout, *operands, mask_pad],
+    )
+
+
+@functools.partial(jax.jit, static_argnames=_STATICS)
+def _emit_fwd(q, k, v, mask_pad, layout, *, sm_scale, block_q, block_k,
+              causal, n_real_q, n_real_k, interpret):
     b, h, n_q, d = q.shape
     n_k = k.shape[2]
-    nq_blocks = n_q // block_q
-    nk_blocks = n_k // block_k
-    has_mask = mask_pad is not None
-
+    _, span_k = _spans(n_q, n_k, block_q, block_k, d, q.dtype.itemsize)
+    _built("fwd", q, k, block_q, block_k)
     kernel = functools.partial(
-        _fwd_kernel,
-        sm_scale=sm_scale,
-        block_k=block_k,
-        causal=causal,
-        has_mask=has_mask,
-        n_real_k=n_real_k,
-        nk_blocks=nk_blocks,
+        _fwd_kernel, sm_scale=sm_scale, block_k=block_k, causal=causal,
+        has_mask=mask_pad is not None, n_real_k=n_real_k,
+        steps=span_k // block_k,
     )
-    qspec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    if causal and not has_mask:
-        # Causal DMA skip: k tiles strictly above the block diagonal are
-        # dead (the kernel predicates compute with `live`), but a naive
-        # j-index map still streams them in — ~2x K/V tile traffic at the
-        # diagonal-heavy DALL-E lengths. Remapping every dead step to the
-        # LAST live tile makes consecutive dead steps index the same
-        # block, and Pallas elides the copy when the block index repeats,
-        # so the dead region costs zero DMA. (min(j, ...) also keeps the
-        # index in range: the clamp target never exceeds j itself.)
-        k_idx = lambda b_, h_, i, j: (
-            b_, h_, jnp.minimum(j, _causal_last_live_k(i, block_q, block_k)), 0
-        )
-    else:
-        k_idx = lambda b_, h_, i, j: (b_, h_, j, 0)
-    kspec = pl.BlockSpec((1, 1, block_k, d), k_idx)
-    in_specs = [qspec, kspec, kspec]
-    operands = [q, k, v]
-    if has_mask:
-        in_specs = [
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # layout, whole array
-            *in_specs,
-            pl.BlockSpec((block_q, block_k), lambda b_, h_, i, j: (i, j)),
-        ]
-        operands = [layout, q, k, v, mask_pad]
-
+    kspec = _k_span_spec(span_k, d, block_q, causal)
+    in_specs, operands = _with_mask(
+        [_row(block_q, d), kspec, kspec], [q, k, v], layout, mask_pad,
+        pl.BlockSpec((block_q, span_k), lambda b_, h_, i, j: (i, j)),
+    )
     # the name is also the kernel's innermost scope, and the chip names the
     # custom call after it (`%fwd_flash.3`): forward, dq and dkv are told
     # apart by name alone, and all three still end in `_flash`
-    o, lse = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         name="fwd_flash",
-        grid=(b, h, nq_blocks, nk_blocks),
+        grid=(b, h, n_q // block_q, n_k // span_k),
         in_specs=in_specs,
-        out_specs=[
-            qspec,
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
-        ],
+        out_specs=[_row(block_q, d), _row(block_q, 1)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, n_q, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, n_q, 1), jnp.float32),
@@ -243,251 +495,88 @@ def _flash_forward(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary",
-            ),
-        ),
+        compiler_params=_PARALLEL,
         interpret=interpret,
     )(*operands)
-    return o, lse
 
 
-# ----------------------------------------------------------------- backward
-
-
-def _dq_kernel(
-    *refs, sm_scale, block_k, causal, has_mask, n_real_k, nk_blocks,
-):
-    """Grid (b, h, qi, ki): the q block stays put over the inner ki steps
-    while [block_k, d] k/v tiles stream through — VMEM holds one tile of
-    each operand regardless of sequence length (the previous revision gave
-    every program instance the ENTIRE K/V, which scales VMEM with n_k).
-    dq accumulates in an fp32 VMEM scratch across ki and flushes on the
-    last step."""
-    if has_mask:
-        (layout_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         mask_ref, dq_ref, acc_ref) = refs
-    else:
-        layout_ref = mask_ref = None
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = refs
-
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    bq = q_ref.shape[2]
-    if causal and not has_mask:
-        # k blocks strictly above the block triangle contribute nothing
-        live = ki <= _causal_last_live_k(qi, bq, block_k)
-    elif has_mask:
-        live = layout_ref[qi, ki] != 0
-    else:
-        live = True
-
-    @pl.when(live)
-    def _attend():
-        q = q_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]  # [bq, 1]
-        delta = delta_ref[0, 0]
-        kb = k_ref[0, 0].astype(jnp.float32)  # [bk, d]
-        vb = v_ref[0, 0].astype(jnp.float32)
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * sm_scale
-        col = ki * block_k + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-        if causal and not has_mask:
-            row = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            s = jnp.where(row >= col, s, NEG_INF)
-        if has_mask:
-            s = jnp.where(mask_ref[...], s, NEG_INF)
-        if n_real_k % block_k != 0:
-            s = jnp.where(col < n_real_k, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        acc_ref[...] += jnp.dot(ds, kb, preferred_element_type=jnp.float32)
-
-    @pl.when(ki == nk_blocks - 1)
-    def _flush():
-        dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(
-    *refs, sm_scale, block_q, causal, has_mask, n_real_q, n_real_k,
-    block_k, nq_blocks,
-):
-    """Grid (b, h, ki, qi): the k/v blocks stay put over the inner qi steps
-    while [block_q, d] q/do tiles stream through (bounded VMEM — see
-    `_dq_kernel`). dk/dv accumulate in fp32 VMEM scratch across qi and
-    flush on the last step."""
-    if has_mask:
-        (layout_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         mask_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        layout_ref = mask_ref = None
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-         dk_acc, dv_acc) = refs
-
-    ki = pl.program_id(2)
-    qi = pl.program_id(3)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    bk = k_ref.shape[2]
-    if causal and not has_mask:
-        # q blocks strictly below the k-block diagonal start never attend
-        live = qi >= _causal_first_live_q(ki, bk, block_q)
-    elif has_mask:
-        live = layout_ref[qi, ki] != 0
-    else:
-        live = True
-
-    @pl.when(live)
-    def _attend():
-        kb = k_ref[0, 0].astype(jnp.float32)  # [bk, d]
-        vb = v_ref[0, 0].astype(jnp.float32)
-        qb = q_ref[0, 0].astype(jnp.float32)  # [bq, d]
-        dob = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]  # [bq, 1]
-        delta = delta_ref[0, 0]
-        col = ki * bk + lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-        s = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32) * sm_scale
-        if causal and not has_mask:
-            row = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0
-            )
-            s = jnp.where(row >= col, s, NEG_INF)
-        if has_mask:
-            s = jnp.where(mask_ref[...], s, NEG_INF)
-        if n_real_k % bk != 0:
-            s = jnp.where(col < n_real_k, s, NEG_INF)
-        if n_real_q % block_q != 0:  # padded q rows have garbage lse: drop them
-            row = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0
-            )
-            s = jnp.where(row < n_real_q, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dv_acc[...] += jnp.dot(p.T, dob, preferred_element_type=jnp.float32)
-        dp = jnp.dot(dob, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dk_acc[...] += jnp.dot(ds.T, qb, preferred_element_type=jnp.float32)
-
-    @pl.when(qi == nq_blocks - 1)
-    def _flush():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _flash_backward(
-    res, g, *, sm_scale, block_q, block_k, causal, n_real_q, n_real_k, interpret,
-):
-    q, k, v, o, lse, mask_pad, layout = res
-    do = g
+@functools.partial(jax.jit, static_argnames=_STATICS)
+def _emit_dq(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
+             block_k, causal, n_real_q, n_real_k, interpret):
     b, h, n_q, d = q.shape
     n_k = k.shape[2]
-    has_mask = mask_pad is not None
-
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
+    _, span_k = _spans(n_q, n_k, block_q, block_k, d, q.dtype.itemsize)
+    _built("dq", q, k, block_q, block_k)
+    kernel = functools.partial(
+        _dq_kernel, sm_scale=sm_scale, block_k=block_k, causal=causal,
+        has_mask=mask_pad is not None, n_real_k=n_real_k,
+        steps=span_k // block_k,
     )
-
-    nq_blocks = n_q // block_q
-    nk_blocks = n_k // block_k
-
-    # Both passes run a 4D grid with the reduction as the INNER dimension
-    # and fp32 VMEM scratch carrying the accumulator across its steps; every
-    # operand arrives as one [block, d] tile per step (auto double-buffered
-    # by Pallas), so VMEM use is flat in sequence length — the previous
-    # revision's whole-K/V ("kfull") BlockSpecs scaled VMEM with n_k and
-    # became hostile at exactly the long sequences flash exists for.
-
-    # dq: grid (b, h, qi, ki) — q-indexed tiles ignore ki, k-indexed use ki
-    qspec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    if causal and not has_mask:
-        # causal DMA skip (see _flash_forward): dead above-diagonal steps
-        # re-index the last live k tile so Pallas elides their copies
-        k_idx = lambda b_, h_, i, j: (
-            b_, h_, jnp.minimum(j, _causal_last_live_k(i, block_q, block_k)), 0
-        )
-    else:
-        k_idx = lambda b_, h_, i, j: (b_, h_, j, 0)
-    kspec = pl.BlockSpec((1, 1, block_k, d), k_idx)
-    rowspec = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
-    dq_in = [qspec, kspec, kspec, qspec, rowspec, rowspec]
-    dq_ops = [q, k, v, do, lse, delta]
-    if has_mask:
-        dq_in = [
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            *dq_in,
-            pl.BlockSpec((block_q, block_k), lambda b_, h_, i, j: (i, j)),
-        ]
-        dq_ops = [layout, *dq_ops, mask_pad]
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, sm_scale=sm_scale, block_k=block_k, causal=causal,
-            has_mask=has_mask, n_real_k=n_real_k, nk_blocks=nk_blocks,
-        ),
+    qspec, rowspec = _row(block_q, d), _row(block_q, 1)
+    kspec = _k_span_spec(span_k, d, block_q, causal)
+    in_specs, operands = _with_mask(
+        [qspec, kspec, kspec, qspec, rowspec, rowspec],
+        [q, k, v, do, lse, delta], layout, mask_pad,
+        pl.BlockSpec((block_q, span_k), lambda b_, h_, i, j: (i, j)),
+    )
+    return pl.pallas_call(
+        kernel,
         name="dq_flash",
-        grid=(b, h, nq_blocks, nk_blocks),
-        in_specs=dq_in,
+        grid=(b, h, n_q // block_q, n_k // span_k),
+        in_specs=in_specs,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary",
-            ),
-        ),
+        compiler_params=_PARALLEL,
         interpret=interpret,
-    )(*dq_ops)
+    )(*operands)
 
-    # dk/dv: grid (b, h, ki, qi) — k-indexed tiles ignore qi
-    kspec2 = pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    if causal and not has_mask:
-        # causal DMA skip, transposed: for k block i the dead q tiles are
-        # the PREFIX qi < first_live; clamp re-indexes them to the first
-        # live tile so their copies are elided. The outer min keeps the
-        # index in range when n_k > n_q (a fully-dead k row's first_live
-        # would point past the last q block — the whole row is dead, so
-        # any in-range tile serves; without the min the DMA reads out of
+
+@functools.partial(jax.jit, static_argnames=_STATICS)
+def _emit_dkv(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
+              block_k, causal, n_real_q, n_real_k, interpret):
+    b, h, n_q, d = q.shape
+    n_k = k.shape[2]
+    span_q, _ = _spans(n_q, n_k, block_q, block_k, d, q.dtype.itemsize)
+    n_spans = n_q // span_q
+    _built("dkv", q, k, block_q, block_k)
+    kernel = functools.partial(
+        _dkv_kernel, sm_scale=sm_scale, block_q=block_q, causal=causal,
+        has_mask=mask_pad is not None, n_real_q=n_real_q, n_real_k=n_real_k,
+        steps=span_q // block_q,
+    )
+    if causal:
+        # causal DMA skip, transposed: for k block i the dead q spans are
+        # the PREFIX before the first live one; the clamp re-indexes them
+        # to it so their copies are elided. The outer min keeps the index
+        # in range when n_k > n_q (a fully-dead k row's first live span
+        # would lie past the last one — the whole row is dead, so any
+        # in-range span serves; without the min the DMA reads out of
         # bounds)
         q_idx = lambda b_, h_, i, j: (
             b_, h_,
             jnp.minimum(
-                jnp.maximum(j, _causal_first_live_q(i, block_k, block_q)),
-                nq_blocks - 1,
+                jnp.maximum(j, _causal_first_live_q(i, block_k, span_q)),
+                n_spans - 1,
             ),
             0,
         )
     else:
         q_idx = lambda b_, h_, i, j: (b_, h_, j, 0)
-    qspec2 = pl.BlockSpec((1, 1, block_q, d), q_idx)
-    rowspec2 = pl.BlockSpec((1, 1, block_q, 1), q_idx)
-    dkv_in = [qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2]
-    dkv_ops = [q, k, v, do, lse, delta]
-    if has_mask:
-        dkv_in = [
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            *dkv_in,
-            pl.BlockSpec((block_q, block_k), lambda b_, h_, i, j: (j, i)),
-        ]
-        dkv_ops = [layout, *dkv_ops, mask_pad]
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, sm_scale=sm_scale, block_q=block_q, causal=causal,
-            has_mask=has_mask, n_real_q=n_real_q, n_real_k=n_real_k,
-            block_k=block_k, nq_blocks=nq_blocks,
-        ),
+    qspec = pl.BlockSpec((1, 1, span_q, d), q_idx)
+    rowspec = pl.BlockSpec((1, 1, span_q, 1), q_idx)
+    kspec = _row(block_k, d)
+    in_specs, operands = _with_mask(
+        [qspec, kspec, kspec, qspec, rowspec, rowspec],
+        [q, k, v, do, lse, delta], layout, mask_pad,
+        pl.BlockSpec((span_q, block_k), lambda b_, h_, i, j: (j, i)),
+    )
+    return pl.pallas_call(
+        kernel,
         name="dkv_flash",
-        grid=(b, h, nk_blocks, nq_blocks),
-        in_specs=dkv_in,
-        out_specs=[kspec2, kspec2],
+        grid=(b, h, n_k // block_k, n_spans),
+        in_specs=in_specs,
+        out_specs=[kspec, kspec],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -496,14 +585,9 @@ def _flash_backward(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary",
-            ),
-        ),
+        compiler_params=_PARALLEL,
         interpret=interpret,
-    )(*dkv_ops)
-    return dq, dk, dv
+    )(*operands)
 
 
 # -------------------------------------------------------------- public API
@@ -517,8 +601,8 @@ def flash_attention(
     *,
     causal: bool = True,
     sm_scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Flash attention over [B, H, N, D] with an optional STATIC token mask.
@@ -529,47 +613,54 @@ def flash_attention(
     see `mask_block_layout`). When `mask` is None and `causal=True`,
     causality is enforced in-kernel with a block-triangle loop bound and no
     materialized mask. Differentiable (custom VJP, recompute-based backward).
+
+    `block_q`/`block_k` are the sides of the score tile; left out, they are
+    chosen from the shape (`choose_tiles`). Lengths a tile does not divide
+    are zero-padded up to it and the padding masked.
     """
     assert q.ndim == 4, f"expected [B,H,N,D], got {q.shape}"
     n_q, n_k = q.shape[2], k.shape[2]
     d = q.shape[3]
-    block_q = min(block_q, max(n_q, 1))
-    block_k = min(block_k, max(n_k, 1))
+    chosen = choose_tiles(n_q, n_k, d, q.dtype, masked=mask is not None)
+    block_q = chosen[0] if block_q is None else min(block_q, max(n_q, 1))
+    block_k = chosen[1] if block_k is None else min(block_k, max(n_k, 1))
     scale = d**-0.5 if sm_scale is None else sm_scale
     interp = _use_interpret() if interpret is None else interpret
 
+    # host arrays, handed to the emitters as they are: a device constant
+    # made here would belong to whatever trace is open (a `jax.checkpoint`
+    # body, say) and leak from it through the VJP's closures
+    mask_pad = layout = None
     if mask is not None:
         assert mask.shape == (n_q, n_k), f"mask {mask.shape} != {(n_q, n_k)}"
-        mask_pad_np, layout_np = mask_block_layout(mask, block_q, block_k)
-        mask_pad = jnp.asarray(mask_pad_np)
-        layout = jnp.asarray(layout_np)
-    else:
-        mask_pad = layout = None
-
-    qp = _pad_to(q, 2, block_q)
-    kp = _pad_to(k, 2, block_k)
-    vp = _pad_to(v, 2, block_k)
+        mask_pad, layout = mask_block_layout(mask, block_q, block_k)
+        mask_pad = mask_pad.astype(np.int8)  # a bool block is held as int32
 
     static = dict(
-        sm_scale=scale, block_q=block_q, block_k=block_k,
+        sm_scale=float(scale), block_q=block_q, block_k=block_k,
         causal=causal and mask is None, n_real_q=n_q, n_real_k=n_k,
-        interpret=interp,
+        interpret=bool(interp),
     )
 
     @jax.custom_vjp
     def _attn(q_, k_, v_):
-        o, _ = _flash_forward(q_, k_, v_, mask_pad, layout, **static)
-        return o
+        return _emit_fwd(q_, k_, v_, mask_pad, layout, **static)[0]
 
     def _attn_fwd(q_, k_, v_):
-        o, lse = _flash_forward(q_, k_, v_, mask_pad, layout, **static)
-        return o, (q_, k_, v_, o, lse, mask_pad, layout)
+        o, lse = _emit_fwd(q_, k_, v_, mask_pad, layout, **static)
+        return o, (q_, k_, v_, o, lse)
 
-    def _attn_bwd(res, g):
-        return _flash_backward(res, g, **static)
+    def _attn_bwd(res, do):
+        q_, k_, v_, o, lse = res
+        delta = jnp.sum(
+            do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
+        )
+        dq = _emit_dq(q_, k_, v_, do, lse, delta, mask_pad, layout, **static)
+        dk, dv = _emit_dkv(q_, k_, v_, do, lse, delta, mask_pad, layout, **static)
+        return dq, dk, dv
 
     _attn.defvjp(_attn_fwd, _attn_bwd)
-    out = _attn(qp, kp, vp)
+    out = _attn(_pad_to(q, 2, block_q), _pad_to(k, 2, block_k), _pad_to(v, 2, block_k))
     return out[:, :, :n_q, :]
 
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from dalle_pytorch_tpu.ops.attention_core import dense_attention
+from dalle_pytorch_tpu.ops import pallas_attention as pa
 from dalle_pytorch_tpu.ops.pallas_attention import (
     flash_attention,
     mask_block_layout,
@@ -192,6 +193,175 @@ def test_attention_module_flash_matches_dense():
     out_d, _ = dense_attn.apply(params, x)
     out_f, _ = flash_attn.apply(params, x)
     np.testing.assert_allclose(out_f, out_d, atol=2e-5)
+
+
+# ---------------------------------------------------------- chosen tiles
+
+
+def _flagship_axial_mask(n):
+    return axial_static_mask(n - 1, 32, axis=0)[:n, :n] & causal_mask(n)
+
+
+# (batch, heads, n, d, dtype, masked): the flagship in its stated dtype and
+# in float32, the auto threshold's length, a ragged and a short one, a
+# patterned layer, and what one shard of a dp=4 x tp=4 mesh is handed
+TILE_CASES = {
+    "flagship_bf16": (1, 1, 1280, 64, jnp.bfloat16, False),
+    "flagship_f32": (1, 1, 1280, 64, jnp.float32, False),
+    "auto_threshold": (1, 2, 1024, 64, jnp.float32, False),
+    "ragged": (1, 2, 257, 64, jnp.float32, False),
+    "short": (2, 3, 96, 32, jnp.float32, False),
+    "axial_layout": (1, 1, 1280, 64, jnp.float32, True),
+    "per_shard": (4, 4, 1280, 64, jnp.bfloat16, False),
+}
+
+
+@pytest.mark.parametrize("case", TILE_CASES)
+def test_chosen_tiles_are_legal_and_exact(case):
+    """The tiles `flash_attention` picks when none are given: they divide
+    the padded length, meet the (8, 128) tiling, fit the VMEM figure, a
+    multiple of 128 is never padded, and forward and gradients at those
+    tiles agree with dense attention on operands of the same dtype."""
+    b, h, n, d, dtype, masked = TILE_CASES[case]
+    bq, bk = pa.choose_tiles(n, n, d, dtype)
+    for block in (bq, bk):
+        n_pad = -(-n // block) * block
+        assert n_pad % block == 0 and n_pad - n < 128
+        assert block % 128 == 0 or (block == n and n <= 128)
+        if n % 128 == 0:
+            assert n_pad == n  # 1280 = 10 x 128 takes no pad copy
+    itemsize = jnp.dtype(dtype).itemsize
+    n_pad = -(-n // bq) * bq
+    span_q, span_k = pa._spans(n_pad, n_pad, bq, bk, d, itemsize)
+    assert n_pad % span_q == 0 and span_q % bq == 0
+    assert n_pad % span_k == 0 and span_k % bk == 0
+    assert pa.vmem_bytes(bq, bk, span_q, span_k, d, itemsize) <= pa.VMEM_BUDGET
+
+    mask = _flagship_axial_mask(n) if masked else None
+    if masked:
+        padded, layout = mask_block_layout(mask, bq, bk)
+        assert layout.shape == (n_pad // bq, n_pad // bk)
+        assert padded.shape == (n_pad, n_pad)
+        assert 0 < layout.sum() < layout.size  # the pattern leaves empty tiles
+
+    rng = np.random.RandomState(11)
+    q, k, v = (jnp.asarray(rng.randn(b, h, n, d), dtype) for _ in range(3))
+    dense_mask = jnp.asarray(causal_mask(n) if mask is None else mask)[None, None]
+
+    def loss_flash(q, k, v):
+        out = flash_attention(q, k, v, mask=mask, causal=mask is None)
+        return (out.astype(jnp.float32) ** 2).sum(), out
+
+    def loss_dense(q, k, v):
+        out = dense_attention(q, k, v, mask=dense_mask)
+        return (out.astype(jnp.float32) ** 2).sum(), out
+
+    pa.forget()
+    (_, out), gf = jax.value_and_grad(loss_flash, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, ref), gd = jax.value_and_grad(loss_dense, (0, 1, 2), has_aux=True)(q, k, v)
+    assert set(pa.tiles_chosen.values()) == {(bq, bk)}
+    assert out.dtype == dtype
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(out, ref, atol=2e-5)
+        for a, g in zip(gf, gd):
+            np.testing.assert_allclose(a, g, atol=5e-4)
+    else:  # against dense attention on bf16 operands, at bf16's resolution
+        np.testing.assert_allclose(f32(out), f32(ref), atol=3e-2)
+        for a, g in zip(gf, gd):
+            assert np.linalg.norm(f32(a) - f32(g)) < 2e-2 * np.linalg.norm(f32(g))
+
+
+def test_explicit_tiles_override_the_choice():
+    q, k, v = _qkv(96, seed=8)
+    pa.forget()
+    flash_attention(q, k, v, block_q=32, block_k=64)
+    assert set(pa.tiles_chosen.values()) == {(32, 64)}
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters
+    (jitted callees, kernels, loop bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _kernel_dots(fn, *args):
+    """(lhs dtype, rhs dtype, result dtype) of every dot in the kernels of
+    every pallas_call `fn` traces."""
+    return [
+        tuple(str(x.aval.dtype) for x in (*eqn.invars, eqn.outvars[0]))
+        for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+        if eqn.primitive.name == "dot_general"
+    ]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_mxu_operands_keep_the_tensors_dtype(dtype, grad):
+    """bf16 tensors multiply as bf16 (one MXU pass), float32 tensors as
+    float32 (the parity tests' precision); every product accumulates in
+    float32 whatever its operands."""
+    q, k, v = _qkv(64, dtype=jnp.dtype(dtype))
+    fn = lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum()
+    if grad:
+        fn = jax.grad(fn, (0, 1, 2))
+    pa.forget()
+    dots = _kernel_dots(fn, q, k, v)
+    assert len(dots) == (2 + 3 + 4 if grad else 2)  # fwd; + dq, dkv
+    assert set(dots) == {(dtype, dtype, "float32")}
+
+
+def _layers(x, depth, remat, mask=None):
+    """`depth` attention layers over [B, H, N, D], each under
+    `jax.checkpoint` as the trainer's remat executor runs them."""
+    layer = lambda x: x + flash_attention(x, x, x, mask=mask, causal=mask is None)
+    if remat:
+        layer = jax.checkpoint(layer)
+    for _ in range(depth):
+        x = layer(x)
+    return x.sum()
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_a_program_builds_each_kernel_body_once(remat):
+    """Six layers, forward and backward: 3 kernel bodies traced where each
+    call site used to build its own (18). Under remat 4 (and not 24): the
+    forward is traced once inside `jax.checkpoint`'s own tracing context
+    and once from the VJP's forward rule, which JAX keys apart. As many
+    `pallas_call`s in the program as before."""
+    x = _qkv(64, seed=9)[0]
+    pa.forget()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda x: _layers(x, 6, remat)))(x)
+    assert pa.kernel_bodies == 3 + remat
+    assert {kind for kind, *_ in pa.tiles_chosen} == {"fwd", "dq", "dkv"}
+    assert set(pa.tiles_chosen.values()) == {(64, 64)}
+
+    calls = sum(e.primitive.name == "pallas_call" for e in _eqns(jaxpr.jaxpr))
+    assert calls == 6 * (3 + remat)
+    # a second program of the same shapes builds nothing anew
+    jax.make_jaxpr(jax.grad(lambda x: _layers(x, 2, remat)))(x)
+    assert pa.kernel_bodies == 3 + remat
+
+
+def test_masked_flash_trains_under_remat():
+    """A patterned layer under `jax.checkpoint`: the padded mask and its
+    layout reach the kernels as host constants, so no tracer of the
+    checkpoint's trace is left in the VJP's closures (it used to leak)."""
+    n = 80
+    mask = axial_static_mask(n - 1, 8, axis=0)[:n, :n] & causal_mask(n)
+    x = _qkv(n, seed=10)[0]
+    got = jax.jit(jax.grad(lambda x: _layers(x, 2, True, mask=mask)))(x)
+
+    def dense_layers(x):
+        for _ in range(2):
+            x = x + _dense(x, x, x, mask)
+        return x.sum()
+
+    want = jax.grad(dense_layers)(x)
+    np.testing.assert_allclose(got, want, atol=5e-4)
 
 
 class TestLibFlash:

@@ -15,6 +15,7 @@ and all of it lives in this ONE file so one worker owns the library.
 
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -95,18 +96,34 @@ def _axial_mask():
     )[:SEQ, :SEQ]
 
 
+TRAIN_BATCH = 16  # the train cell's: the kernels see 16 x 16 x 1280 x 64
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
 @pytest.mark.parametrize("masked", [False, True], ids=["causal", "axial"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
-def test_flash_attention_compiles(one_chip, masked, grad):
-    from dalle_pytorch_tpu.ops.pallas_attention import flash_attention
+def test_flash_attention_compiles(one_chip, masked, grad, dtype):
+    """fwd, dq and dkv at the train cell's shape and the tiles the chooser
+    gives it: Mosaic takes the tiles, the in-kernel loops over them, the
+    bf16 MXU operands and the VMEM they ask for."""
+    from dalle_pytorch_tpu.ops import pallas_attention as pa
 
     mask = _axial_mask() if masked else None
-    attn = functools.partial(flash_attention, mask=mask, interpret=False)
+    attn = functools.partial(pa.flash_attention, mask=mask, interpret=False)
     if grad:
         fn = jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
     else:
         fn = attn
-    _compile(fn, *_qkv(one_chip, SEQ))
+    shapes = [
+        jax.ShapeDtypeStruct((TRAIN_BATCH, H, SEQ, D), dtype, sharding=one_chip)
+    ] * 3
+    pa.forget()
+    compiled = _compile(fn, *shapes)
+    assert compiled.as_text().count("tpu_custom_call") == (3 if grad else 1)
+    assert pa.kernel_bodies == (3 if grad else 1)
+    # float32 operands and a mask's block both take VMEM from the score tile
+    want = (640, 640) if dtype == jnp.bfloat16 and not masked else (256, 640)
+    assert set(pa.tiles_chosen.values()) == {want}
 
 
 @pytest.mark.parametrize("n", [1, 4, 256])
@@ -274,6 +291,72 @@ def _flagship_step(devices, mesh_axes, batch, executor="scan", reversible=True):
     return step.lower(
         shaped(state, state_sh), batch_shapes, rng, shaped(vae_params, vae_sh)
     )
+
+
+# This file's unrolled flagship step lowers to 9,163,364 characters with 4
+# kernel bodies in it, and did to 9,529,737 with 48 (one per call site,
+# before PR 26). The bound leaves 1% for paths and line numbers, which the
+# serialized kernels embed; a body built per call site again breaks it.
+LOWERED_TEXT_BYTES = 9_255_000
+
+
+def test_flagship_step_holds_each_kernel_body_once(topo, compiled_kernels):
+    """The unrolled 12-layer step with remat calls the flash kernels 48
+    times (fwd, fwd again under remat, dq, dkv per layer) and holds 4
+    bodies: the emitters are jitted, so each is traced once and lowered to
+    Mosaic once per program. Lowering only; nothing is compiled."""
+    from dalle_pytorch_tpu.ops import pallas_attention as pa
+
+    pa.forget()
+    text = _flagship_step(
+        [topo.devices[0]], dict(dp=1), batch=TRAIN_BATCH, executor="unrolled"
+    ).as_text()
+    # the process built one body more than the step holds: the forward at
+    # batch 1, traced (and never lowered) by `model.init`'s shape pass
+    init = [key for key in pa.tiles_chosen if key[1][0] == 1]
+    assert [key[0] for key in init] == ["fwd"]
+    assert pa.kernel_bodies - len(init) == 4, pa.tiles_chosen
+    assert text.count("tpu_custom_call") == 4
+    for emitter, calls in (("_emit_fwd", 24), ("_emit_dq", 12), ("_emit_dkv", 12)):
+        assert len(re.findall(rf"call @{emitter}\w*\(", text)) == calls, emitter
+    assert set(pa.tiles_chosen.values()) == {(640, 640)}
+    assert {key[1] for key in pa.tiles_chosen if key not in init} == {
+        (TRAIN_BATCH, H, SEQ, D)
+    }
+    assert len(text) < LOWERED_TEXT_BYTES, len(text)
+
+
+@pytest.mark.slow
+def test_flagship_step_kernels_keep_their_names_and_phases(topo, compiled_kernels):
+    """Compiled, the shared bodies are 48 custom calls again, each named
+    for its kernel (`fwd_flash.N`: the benchmark's regexes and
+    `obs/scopes.py` find them by that) with the output signature its
+    reader matches, and each under ITS call site's layer and phase: the
+    callee's names are relative and XLA prefixes the call site's."""
+    from dalle_pytorch_tpu.obs import scopes
+
+    text = _flagship_step(
+        [topo.devices[0]], dict(dp=1), batch=TRAIN_BATCH, executor="unrolled"
+    ).compile().as_text()
+    table = scopes.classify(scopes.parse(text))
+    by_phase = {}
+    for name, (opcode, shape, component, phase) in table.items():
+        if component != "attn_kernel" or opcode != "custom-call":
+            continue  # a tuple's `get-tuple-element` carries the scope too
+        by_phase.setdefault((name.rsplit(".", 1)[0], phase), []).append(shape)
+    assert {k: len(v) for k, v in by_phase.items()} == {
+        ("fwd_flash", "fwd"): 12, ("fwd_flash", "remat"): 12,
+        ("dq_flash", "bwd"): 12, ("dkv_flash", "bwd"): 12,
+    }
+    o = f"bf16[{TRAIN_BATCH},{H},{SEQ},{D}]"
+    assert set(by_phase["fwd_flash", "fwd"]) == {f"({o},f32[{TRAIN_BATCH},{H},{SEQ},1])"}
+    assert set(by_phase["dq_flash", "bwd"]) == {o}
+    assert set(by_phase["dkv_flash", "bwd"]) == {f"({o},{o})"}
+    layers = {
+        m for line in text.splitlines() if "fwd_flash" in line and "custom-call(" in line
+        for m in re.findall(r"/(attn_\d+)/", line)
+    }
+    assert layers == {f"attn_{i}" for i in range(12)}
 
 
 def _device_bytes(compiled):
