@@ -51,7 +51,7 @@ from dalle_pytorch_tpu.ops.pallas_decode import (
     sharded_flash_decode_attention,
     sharded_paged_decode_attention,
 )
-from dalle_pytorch_tpu.ops.rotary import apply_rotary
+from dalle_pytorch_tpu.ops.rotary import apply_rotary, apply_rotary_half
 
 # The three thresholds below come from a ROOFLINE MODEL, not from a chip:
 # scripts/flash_crossover.py takes `cost_analysis()` of programs compiled on
@@ -190,6 +190,15 @@ class Attention(nn.Module):
     # itself stays traced data — only this boundary is baked into the
     # compiled program).
     decode_sparse_block: Optional[int] = None
+    # K/V heads, each shared by `heads // kv_heads` query heads (None: one
+    # per query head, the DALL-E layout and its fused 3 x inner projection)
+    kv_heads: Optional[int] = None
+    # per-head RMS norm of q and k over dim_head, before the rotation
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    # sliding window: query t sees key p iff 0 <= t - p < window
+    window: Optional[int] = None
+    use_bias: bool = True  # to_out's (to_qkv never had one)
     dtype: Any = jnp.float32
 
     def _use_flash(self, n: int, key_mask) -> bool:
@@ -222,7 +231,9 @@ class Attention(nn.Module):
         mask = self._full_mask(n, n) if self.static_mask is not None else None
 
         def kernel(q_, k_, v_):
-            return flash_attention(q_, k_, v_, mask=mask, causal=self.causal)
+            if self.window is None:
+                return flash_attention(q_, k_, v_, mask=mask, causal=self.causal)
+            return flash_attention(q_, k_, v_, causal=self.causal, window=self.window)
 
         mesh = self.train_mesh
         if mesh is None or mesh.size == 1:
@@ -242,6 +253,29 @@ class Attention(nn.Module):
             kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )(q, k, v)
+
+    def _grouped_qkv(self, x, rotary_cs):
+        """q [B, H, n, dh] and k, v [B, kv_heads, n, dh] from one fused
+        projection of (H + 2 kv_heads) x dh columns; q and k normed per head
+        and turned by the rotate-half tables, v left as it is."""
+        b, n, _ = x.shape
+        h, dh = self.heads, self.dim_head
+        hkv = h if self.kv_heads is None else self.kv_heads
+        assert h % hkv == 0, f"{h} query heads cannot share {hkv} K/V heads"
+        qkv = nn.Dense((h + 2 * hkv) * dh, use_bias=False, dtype=self.dtype,
+                       name="to_qkv")(x)
+        q, k, v = jnp.split(qkv, [h * dh, (h + hkv) * dh], axis=-1)
+        q = q.reshape(b, n, h, dh)
+        k, v = (t.reshape(b, n, hkv, dh) for t in (k, v))
+        if self.qk_norm:
+            q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="k_norm")(k)
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        if rotary_cs is not None:
+            with jax.named_scope("rotary"):
+                cos, sin = (t[:n] for t in rotary_cs)
+                q, k = apply_rotary_half(cos, sin, q), apply_rotary_half(cos, sin, k)
+        return q, k, v
 
     def _use_flash_decode(
         self, max_len: int, has_pattern: bool, sparse: bool = False
@@ -270,6 +304,8 @@ class Attention(nn.Module):
         mask = None
         if self.causal:
             mask = np.tril(np.ones((n_k, n_k), dtype=bool))[n_k - n_q :, :]
+            if self.window is not None:
+                mask &= ~np.tril(np.ones((n_k, n_k), dtype=bool), -self.window)[n_k - n_q :, :]
         if self.static_mask is not None:
             sm = np.asarray(self.static_mask)[n_k - n_q : n_k, :n_k]
             mask = sm if mask is None else (mask & sm)
@@ -284,8 +320,13 @@ class Attention(nn.Module):
         cache: Optional[dict] = None,
         deterministic: bool = True,
         mask_array: Optional[jnp.ndarray] = None,
+        rotary_cs: Optional[tuple] = None,
     ):
-        """`mask_array`: a TRACED [S, S] bool pattern mask (True = attend),
+        """`rotary_cs`: (cos, sin) float32 [>= n, dim_head] tables of the
+        rotate-half rotary (`ops/rotary.py:rotary_cos_sin`), applied to q and
+        k only; `rotary` is the DALL-E angle table, applied to q, k and v.
+
+        `mask_array`: a TRACED [S, S] bool pattern mask (True = attend),
         the per-layer scanned-input analogue of the host-side `static_mask`
         attribute — used by the scan executor, where each layer's pattern
         arrives as data rather than a compile-time constant. Dense paths
@@ -304,11 +345,21 @@ class Attention(nn.Module):
         h, dh = self.heads, self.dim_head
         inner = h * dh
 
-        qkv = nn.Dense(inner * 3, use_bias=False, dtype=self.dtype, name="to_qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q, k, v = (t.reshape(b, n, h, dh).transpose(0, 2, 1, 3) for t in (q, k, v))
+        grouped = self.kv_heads is not None or self.qk_norm or rotary_cs is not None
+        if not grouped:
+            qkv = nn.Dense(inner * 3, use_bias=False, dtype=self.dtype, name="to_qkv")(x)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q, k, v = (t.reshape(b, n, h, dh).transpose(0, 2, 1, 3) for t in (q, k, v))
+        else:
+            q, k, v = self._grouped_qkv(x, rotary_cs)
 
         new_cache = None
+        if cache is not None and (grouped or self.window is not None):
+            raise NotImplementedError(
+                "cached decode keeps one K/V head per query head and one cache "
+                "geometry: kv_heads, qk_norm, a window and the rotate-half "
+                "rotary are training-only (ROADMAP.md, Queue 2 B)"
+            )
         if cache is not None:
             # n-token chunk (prefill or single-token decode) written into a
             # fixed-shape cache at sequence position `index`. A scalar index
@@ -571,6 +622,8 @@ class Attention(nn.Module):
             else:
                 mask = self._full_mask(n, n)
                 mask = None if mask is None else jnp.asarray(mask)[None, None]
+                if k.shape[1] != h:  # the dense path spells the sharing out
+                    k, v = (jnp.repeat(t, h // t.shape[1], axis=1) for t in (k, v))
                 if mask_array is not None:
                     tm = mask_array[:n, :n][None, None]
                     mask = tm if mask is None else (mask & tm)
@@ -580,6 +633,7 @@ class Attention(nn.Module):
                 out = dense_attention(q, k, v, mask=mask, stable=self.stable)
 
         out = out.transpose(0, 2, 1, 3).reshape(b, n, inner)
-        out = nn.Dense(self.dim, dtype=self.dtype, name="to_out")(out)
+        out = nn.Dense(self.dim, use_bias=self.use_bias, dtype=self.dtype,
+                       name="to_out")(out)
         out = nn.Dropout(self.dropout)(out, deterministic=deterministic)
         return out, new_cache

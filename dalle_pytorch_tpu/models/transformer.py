@@ -64,7 +64,8 @@ from dalle_pytorch_tpu.ops.masks import (
     block_sparse_layout,
     block_layout_to_token_mask,
 )
-from dalle_pytorch_tpu.ops.rotary import build_dalle_rotary
+from dalle_pytorch_tpu.models.moe import RoutedExperts
+from dalle_pytorch_tpu.ops.rotary import build_dalle_rotary, rotary_cos_sin
 from dalle_pytorch_tpu.ops.shift import (
     shift_tokens_dalle,
     shift_ring_from_prefill,
@@ -378,10 +379,44 @@ class Transformer(nn.Module):
     # depth-stacked scanned pattern masks; cached decode is native,
     # pattern masks included. No shared ids, no revnet.
     executor: str = "unrolled"
+    # ---- the block's variants; the defaults are the DALL-E block
+    norm: str = "layer"  # "layer" | "rms" (norm_eps; LayerNorm keeps flax's 1e-6)
+    norm_eps: float = 1e-6
+    ff_kind: str = "geglu"  # "geglu" | "swiglu_experts" (models/moe.py)
+    use_bias: bool = True  # to_out's and the feed-forward's
+    layerscale: bool = True
+    kv_heads: Optional[int] = None  # K/V heads shared by groups of query heads
+    qk_norm: bool = False  # per-head RMS norm of q and k
+    # "window" among attn_types: query t sees key p iff 0 <= t - p < window
+    window: Optional[int] = None
+    # attn type -> `ops/rotary.py:rotary_cos_sin` spec: one rotate-half table
+    # per KIND of layer, on q and k, in place of the DALL-E table
+    rotary_specs: Optional[Any] = None
+    # swiglu_experts: router width, choices per token, the (first, count)
+    # held here, their width, and the static bound on assignments made here
+    experts_total: int = 0
+    experts_per_token: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    expert_dim: int = 0
+    moe_buffer_rows: int = 0
     dtype: Any = jnp.float32
+
+    def _block_variant(self) -> Optional[str]:
+        """The first block option that is not the DALL-E block's, or None."""
+        defaults = dict(norm="layer", ff_kind="geglu", use_bias=True, layerscale=True,
+                        kv_heads=None, qk_norm=False, window=None, rotary_specs=None)
+        return next((k for k, v in defaults.items() if getattr(self, k) != v), None)
+
+    def _norm(self):
+        if self.norm == "rms":
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype)
+        assert self.norm == "layer", f"unknown norm {self.norm!r}"
+        return nn.LayerNorm(dtype=self.dtype)
 
     def _scan_supported(self) -> Optional[str]:
         """None if the scan executor can run this config, else the reason."""
+        if self._block_variant() is not None:
+            return f"the block option {self._block_variant()} (one scanned body, one kind of layer)"
         if self.attn_types and any(t != "full" for t in self.attn_types):
             # masked attn types run as dense + per-layer pattern masks
             # scanned over depth; flash/lib_flash need host-side masks for
@@ -446,7 +481,7 @@ class Transformer(nn.Module):
                     causal=self.causal,
                     dropout=self.attn_dropout,
                     stable=self.stable,
-                    static_mask=_build_static_mask(
+                    static_mask=None if attn_type == "window" else _build_static_mask(
                         attn_type, self.seq_len, self.image_fmap_size, ind
                     ),
                     attn_impl=self.attn_impl,
@@ -457,6 +492,7 @@ class Transformer(nn.Module):
                     decode_sparse_block=self.decode_sparse_block,
                     dtype=self.dtype,
                     name=f"attn_{attn_id}",
+                    **self._attn_variant(attn_type),
                 )
                 shared_attn[attn_id] = attn
                 shared_attn_type[attn_id] = attn_type
@@ -464,7 +500,20 @@ class Transformer(nn.Module):
 
             if ff_id in shared_ff:
                 ff = shared_ff[ff_id]
+            elif self.ff_kind == "swiglu_experts":
+                ff = shared_ff[ff_id] = RoutedExperts(
+                    dim=self.dim,
+                    expert_dim=self.expert_dim,
+                    experts_total=self.experts_total,
+                    experts_per_token=self.experts_per_token,
+                    experts_held=tuple(self.experts_held),
+                    buffer_rows=self.moe_buffer_rows,
+                    dtype=self.dtype,
+                    name=f"ff_{ff_id}",
+                )
             else:
+                assert self.ff_kind == "geglu", f"unknown ff_kind {self.ff_kind!r}"
+                assert self.use_bias, "the GEGLU feed-forward keeps its biases"
                 ff = FeedForward(
                     dim=self.dim,
                     mult=self.ff_mult,
@@ -477,11 +526,20 @@ class Transformer(nn.Module):
 
         self.attn_layers = attn_layers
         self.ff_layers = ff_layers
-        self.attn_norms = [nn.LayerNorm(dtype=self.dtype) for _ in range(depth)]
-        self.ff_norms = [nn.LayerNorm(dtype=self.dtype) for _ in range(depth)]
+        self.type_per_layer = tuple(type_per_layer)
+        self.attn_norms = [self._norm() for _ in range(depth)]
+        self.ff_norms = [self._norm() for _ in range(depth)]
         if self.sandwich_norm:
-            self.attn_norms_out = [nn.LayerNorm(dtype=self.dtype) for _ in range(depth)]
-            self.ff_norms_out = [nn.LayerNorm(dtype=self.dtype) for _ in range(depth)]
+            self.attn_norms_out = [self._norm() for _ in range(depth)]
+            self.ff_norms_out = [self._norm() for _ in range(depth)]
+        self.rotary_table = self._build_rotary_table()
+        self.rotary_cs = {
+            kind: rotary_cos_sin(np.arange(self.seq_len), dict(spec))
+            for kind, spec in dict(self.rotary_specs or {}).items()
+        }
+        self.text_len = self._derived_text_len()
+        if not self.layerscale:
+            return
         self.attn_scales = [
             self.param(
                 f"attn_scale_{i}",
@@ -499,8 +557,18 @@ class Transformer(nn.Module):
             for i in range(depth)
         ]
 
-        self.rotary_table = self._build_rotary_table()
-        self.text_len = self._derived_text_len()
+    def _attn_variant(self, attn_type: str) -> dict:
+        """What an attention layer of this kind is given beyond the DALL-E
+        block's arguments: nothing, for that block."""
+        if attn_type == "window":
+            assert self.window, 'attn_types has "window" and the model no window length'
+        if self._block_variant() is None:
+            return {}
+        return dict(
+            kv_heads=self.kv_heads, qk_norm=self.qk_norm, norm_eps=self.norm_eps,
+            use_bias=self.use_bias,
+            window=self.window if attn_type == "window" else None,
+        )
 
     def _derived_text_len(self) -> int:
         return (
@@ -510,7 +578,7 @@ class Transformer(nn.Module):
         )
 
     def _build_rotary_table(self):
-        if not self.rotary_emb:
+        if not self.rotary_emb or self.rotary_specs:
             return None
         assert self.image_fmap_size is not None
         return build_dalle_rotary(
@@ -631,15 +699,21 @@ class Transformer(nn.Module):
                 h, layer_cache.get("shift_attn") if cached else None, pos,
                 ring_end=layer_cache.get("ring_end") if cached else None,
             )
+        variant = (
+            {"rotary_cs": self.rotary_cs[self.type_per_layer[i]]} if self.rotary_cs else {}
+        )
         h, attn_cache = self.attn_layers[i](
             h,
             key_mask=key_mask,
             rotary=self.rotary_table,
             cache=layer_cache["attn"] if cached else None,
             deterministic=deterministic,
+            **variant,
         )
         if self.sandwich_norm:
             h = self.attn_norms_out[i](h)
+        if not self.layerscale:
+            return h, attn_cache, ring
         return h * self.attn_scales[i].astype(h.dtype), attn_cache, ring
 
     def _half_ff(self, i, x, layer_cache, pos, deterministic=True):
@@ -657,7 +731,20 @@ class Transformer(nn.Module):
         h = self.ff_layers[i](h, deterministic=deterministic)
         if self.sandwich_norm:
             h = self.ff_norms_out[i](h)
+        if not self.layerscale:
+            return h, ring
         return h * self.ff_scales[i].astype(h.dtype), ring
+
+    def route_choices(self, x: jnp.ndarray, layer: int = 0) -> jnp.ndarray:
+        """[B, N, k]: the experts layer `layer`'s router chooses for each
+        token of x [B, N, dim] (the trunk's input), through the layers
+        before it and its own attention half: what the routed layer itself
+        would choose, read outside any train step."""
+        assert self.ff_kind == "swiglu_experts", "only a routed layer chooses"
+        for i in range(layer):
+            x = self._layer(i, x, None, None, True)[0]
+        x = x + self._half_attn(layer, x, None, None, True)[0]
+        return self.ff_layers[layer].choices(self.ff_norms[layer](x))
 
     def _rev_f(self, x: jnp.ndarray, i: int, deterministic: bool = True):
         return self._half_attn(i, x, None, None, deterministic)[0]

@@ -34,7 +34,7 @@ from typing import Dict, Iterable, Optional, Tuple
 COMPONENTS = (
     "attn_kernel", "attn_proj", "attn_glue", "attend", "cache_read",
     "cache_write", "ff", "norm_resid", "embed", "head", "loss", "optimizer",
-    "sample", "pixels", "unscoped",
+    "sample", "pixels", "moe_router", "moe_dispatch", "moe_experts", "unscoped",
 )
 PHASES = ("fwd", "bwd", "remat")
 
@@ -46,6 +46,10 @@ KERNELS = (
     "fwd_flash", "dq_flash", "dkv_flash",
     "decode_slots", "decode_sparse", "decode_paged", "decode_sparse_paged",
 )
+
+# the grouped products of a routed layer (`name=` in ops/grouped_matmul.py),
+# known by their instruction names like the seven above
+EXPERT_KERNELS = ("gmm_fwd", "gmm_dlhs", "gmm_drhs")
 
 CONTAINERS = ("while", "conditional", "call")  # their bodies are events too
 
@@ -83,7 +87,13 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         ("unscoped", r"(^|/)(scan_stack|cached_scan)/while/(body|cond)/[\w\-]+$"),
         ("attend", _E("attend")),
         ("attn_proj", _E("to_qkv|to_out")),
-        ("embed", r"(^|/)(DALLE\.embed_text|text_emb|image_emb|\w*pos_emb)(/|$)"),
+        # a routed layer's three parts (models/moe.py), before `ff`, whose
+        # module they live in: the router's product, softmax and top-k; the
+        # sort, the two gathers and the weights; the grouped products
+        ("moe_router", _E("moe_router")),
+        ("moe_dispatch", _E("moe_dispatch")),
+        ("moe_experts", _E("moe_experts|" + "|".join(EXPERT_KERNELS))),
+        ("embed", r"(^|/)(DALLE\.embed_text|text_emb|image_emb|token_emb|\w*pos_emb)(/|$)"),
         ("norm_resid", r"(^|/)(\w*norms?_\w+|norm_by_max)(/|$)"),
         # token shift is glue by the issue's definition, wherever it runs
         ("attn_glue", r"(^|/)(token_shift|transformer\._shift|pattern_mask)(/|$)"),
@@ -107,6 +117,8 @@ def component(op_name: Optional[str], opcode: str = "",
     base = instruction_name.lstrip("%").rsplit(".", 1)[0]
     if base in KERNELS:
         found = "attn_kernel"
+    elif base in EXPERT_KERNELS:
+        found = "moe_experts"
     elif not op_name or opcode in CONTAINERS:  # loop control has no owner
         return "unscoped", "fwd"
     else:

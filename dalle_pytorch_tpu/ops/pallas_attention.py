@@ -82,6 +82,21 @@ def _causal_first_live_q(ki, block_k, block_q):
     return (ki * block_k) // block_q
 
 
+def _window_first_live_k(qi, block_q, block_k, window):
+    """First k-block index a q block `qi` attends to under a sliding window
+    (key p is seen by query t iff t - p < window): the block of the first
+    row's oldest key. Shared, like the causal pair above, by the kernels'
+    loop bounds and the DMA-skip index maps."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _window_last_live_q(ki, block_k, block_q, window):
+    """Last q-block index that attends to k block `ki` under a sliding
+    window (transposed twin of `_window_first_live_k`); may lie past the
+    last block, callers clip."""
+    return ((ki + 1) * block_k - 1 + window - 1) // block_q
+
+
 def mask_block_layout(mask: np.ndarray, block_q: int, block_k: int):
     """(padded token mask, [nq, nk] int32 occupancy layout) for a static mask.
 
@@ -233,7 +248,8 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     )
 
 
-def _scores(q, kb, *, sm_scale, row0, col0, causal, mask, n_real_k, n_real_q=None):
+def _scores(q, kb, *, sm_scale, row0, col0, causal, mask, n_real_k, n_real_q=None,
+            window=None):
     """fp32 [bq, bk] scores of one tile with everything that is not
     attended set to NEG_INF. `row0`/`col0`: the tile's first row and
     column in the whole (padded) score matrix."""
@@ -242,7 +258,10 @@ def _scores(q, kb, *, sm_scale, row0, col0, causal, mask, n_real_k, n_real_q=Non
     if causal:
         rel = (lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
                - lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
-        s = jnp.where(rel >= col0 - row0, s, NEG_INF)
+        live = rel >= col0 - row0
+        if window is not None:  # causal AND at most window - 1 keys back
+            live &= rel < window + col0 - row0
+        s = jnp.where(live, s, NEG_INF)
     if mask is not None:  # the static mask's tile, int8
         s = jnp.where(mask.astype(jnp.int32) != 0, s, NEG_INF)
     if n_real_k % bk != 0:  # key padding
@@ -255,12 +274,15 @@ def _scores(q, kb, *, sm_scale, row0, col0, causal, mask, n_real_k, n_real_q=Non
     return s
 
 
-def _walk(body, *, block, steps, lo, hi, occupied=None):
+def _walk(body, *, block, steps, lo, hi, occupied=None, t0=None):
     """Run `body(tile, start)` over tiles `lo` to `hi - 1` of this grid
     step's span: `tile` the tile's index in the whole row, `start` its
     first row in the span. `occupied(tile)` reads a static mask's
-    occupancy layout: every tile is visited and the empty ones skipped."""
-    t0 = pl.program_id(3) * steps
+    occupancy layout: every tile is visited and the empty ones skipped.
+    `t0`: the span's first tile, where the last grid axis does not count
+    spans alone (dkv over a group of query heads)."""
+    if t0 is None:
+        t0 = pl.program_id(3) * steps
 
     def step(j, carry):
         start = pl.multiple_of(j * block, block)
@@ -273,18 +295,22 @@ def _walk(body, *, block, steps, lo, hi, occupied=None):
     lax.fori_loop(lo, hi, step, 0)
 
 
-def _walk_k(body, *, qi, bq, block_k, steps, causal, layout_ref):
+def _walk_k(body, *, qi, bq, block_k, steps, causal, layout_ref, window=None):
     """The forward's and dq's walk over the live k tiles of the span: a
-    causal loop ends at the diagonal."""
-    hi = steps
+    causal loop ends at the diagonal, a windowed one also starts at the
+    window's far edge."""
+    lo, hi = 0, steps
     if causal:
         k0 = pl.program_id(3) * steps
         hi = jnp.clip(_causal_last_live_k(qi, bq, block_k) + 1 - k0, 0, steps)
-    _walk(body, block=block_k, steps=steps, lo=0, hi=hi,
+    if window is not None:
+        lo = jnp.clip(_window_first_live_k(qi, bq, block_k, window) - k0, 0, steps)
+    _walk(body, block=block_k, steps=steps, lo=lo, hi=hi,
           occupied=None if layout_ref is None else lambda kj: layout_ref[qi, kj])
 
 
-def _fwd_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
+def _fwd_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps,
+                window=None):
     """q block resident, k/v span resident, online softmax over its tiles;
     (m, l, acc) carry across spans in fp32 scratch and the normalized
     output flushes on the last one."""
@@ -310,6 +336,7 @@ def _fwd_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
             q_ref[0, 0], k_ref[0, 0, cols, :], sm_scale=sm_scale,
             row0=qi * bq, col0=kj * block_k, causal=causal,
             mask=mask_ref[:, cols] if has_mask else None, n_real_k=n_real_k,
+            window=window,
         )
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -320,7 +347,7 @@ def _fwd_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
         acc_ref[...] = acc_ref[...] * corr + _dot(p.astype(vb.dtype), vb)
 
     _walk_k(attend, qi=qi, bq=bq, block_k=block_k, steps=steps, causal=causal,
-            layout_ref=layout_ref)
+            layout_ref=layout_ref, window=window)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():
@@ -329,7 +356,8 @@ def _fwd_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
         lse_ref[0, 0] = m_ref[...] + jnp.log(safe_l)  # [bq, 1]
 
 
-def _dq_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
+def _dq_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps,
+               window=None):
     """Same walk as the forward; dq accumulates in fp32 scratch."""
     if has_mask:
         (layout_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -351,6 +379,7 @@ def _dq_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
             q_ref[0, 0], kb, sm_scale=sm_scale, row0=qi * bq,
             col0=kj * block_k, causal=causal,
             mask=mask_ref[:, cols] if has_mask else None, n_real_k=n_real_k,
+            window=window,
         )
         p = jnp.exp(s - lse_ref[0, 0])
         dp = _dot(do_ref[0, 0], v_ref[0, 0, cols, :], _NT)
@@ -358,7 +387,7 @@ def _dq_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
         acc_ref[...] += _dot(ds.astype(kb.dtype), kb)
 
     _walk_k(attend, qi=qi, bq=bq, block_k=block_k, steps=steps, causal=causal,
-            layout_ref=layout_ref)
+            layout_ref=layout_ref, window=window)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():
@@ -366,10 +395,13 @@ def _dq_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps):
 
 
 def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
-                n_real_k, steps):
+                n_real_k, steps, window=None, n_spans=None):
     """Transposed walk: the k/v block is resident, the q/do/lse/delta span
     is resident, and the loop runs over the span's q tiles from the first
-    one that attends to this k block. dk/dv accumulate in fp32 scratch."""
+    one that attends to this k block (to the last, under a window). dk/dv
+    accumulate in fp32 scratch. Where a group of query heads shares this
+    K/V head (`n_spans` given), the last grid axis walks every span of
+    every head of the group in turn and the sums run over all of them."""
     if has_mask:
         (layout_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          mask_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -379,7 +411,11 @@ def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
          dk_acc, dv_acc) = refs
     ki = pl.program_id(2)
     bk = k_ref.shape[2]
-    q0 = pl.program_id(3) * steps
+    if n_spans is None:
+        q0, grouped = pl.program_id(3) * steps, {}
+    else:  # the last axis counts (head of the group, span)
+        q0 = (pl.program_id(3) % n_spans) * steps
+        grouped = {"t0": q0}
 
     @pl.when(pl.program_id(3) == 0)
     def _init():
@@ -393,7 +429,7 @@ def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
             qb, k_ref[0, 0], sm_scale=sm_scale, row0=qj * block_q,
             col0=ki * bk, causal=causal,
             mask=mask_ref[rows, :] if has_mask else None, n_real_k=n_real_k,
-            n_real_q=n_real_q,
+            n_real_q=n_real_q, window=window,
         )
         p = jnp.exp(s - lse_ref[0, 0, rows, :])
         dv_acc[...] += _dot(p.astype(dob.dtype), dob, _TN)
@@ -401,10 +437,12 @@ def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
         ds = p * (dp - delta_ref[0, 0, rows, :])  # sm_scale: at the flush
         dk_acc[...] += _dot(ds.astype(qb.dtype), qb, _TN)
 
-    lo = 0
+    lo, hi = 0, steps
     if causal:
         lo = jnp.clip(_causal_first_live_q(ki, bk, block_q) - q0, 0, steps)
-    _walk(attend, block=block_q, steps=steps, lo=lo, hi=steps,
+    if window is not None:
+        hi = jnp.clip(_window_last_live_q(ki, bk, block_q, window) + 1 - q0, 0, steps)
+    _walk(attend, block=block_q, steps=steps, lo=lo, hi=hi, **grouped,
           occupied=None if layout_ref is None else lambda qj: layout_ref[qj, ki])
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
@@ -428,7 +466,13 @@ _PARALLEL = CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
 )
 _STATICS = ("sm_scale", "block_q", "block_k", "causal", "n_real_q",
-            "n_real_k", "interpret")
+            "n_real_k", "interpret", "window")
+
+
+def _windowed(window):
+    """The kernel's `window=` where there is one: a call without hands its
+    kernel the arguments it always had."""
+    return {} if window is None else {"window": window}
 
 
 def _row(block, d):
@@ -436,16 +480,23 @@ def _row(block, d):
     return pl.BlockSpec((1, 1, block, d), lambda b_, h_, i, j: (b_, h_, i, 0))
 
 
-def _k_span_spec(span_k, d, block_q, causal):
+def _k_span_spec(span_k, d, block_q, causal, group=1, window=None):
     """The k/v span of grid step (i, j). Causal: spans wholly above the
     diagonal are dead (the loop runs no tile of them), and re-indexing
     them to the last live span makes consecutive dead steps name the same
-    block, whose copy Pallas then elides. The kernels' loop bounds and
-    this map share `_causal_last_live_k`: they must stay in lockstep."""
+    block, whose copy Pallas then elides; under a window the spans wholly
+    before it are dead the same way. The kernels' loop bounds and this map
+    share `_causal_last_live_k` and `_window_first_live_k`: they must stay
+    in lockstep. Query head `h_` reads K/V head `h_ // group`."""
+    head = (lambda h_: h_) if group == 1 else (lambda h_: h_ // group)
+    if causal and window is not None:
+        return pl.BlockSpec((1, 1, span_k, d), lambda b_, h_, i, j: (
+            b_, head(h_), jnp.clip(j, _window_first_live_k(i, block_q, span_k, window),
+                                   _causal_last_live_k(i, block_q, span_k)), 0))
     if causal:
         return pl.BlockSpec((1, 1, span_k, d), lambda b_, h_, i, j: (
-            b_, h_, jnp.minimum(j, _causal_last_live_k(i, block_q, span_k)), 0))
-    return pl.BlockSpec((1, 1, span_k, d), lambda b_, h_, i, j: (b_, h_, j, 0))
+            b_, head(h_), jnp.minimum(j, _causal_last_live_k(i, block_q, span_k)), 0))
+    return pl.BlockSpec((1, 1, span_k, d), lambda b_, h_, i, j: (b_, head(h_), j, 0))
 
 
 def _with_mask(in_specs, operands, layout, mask_pad, mask_spec):
@@ -462,7 +513,7 @@ def _with_mask(in_specs, operands, layout, mask_pad, mask_spec):
 
 @functools.partial(jax.jit, static_argnames=_STATICS)
 def _emit_fwd(q, k, v, mask_pad, layout, *, sm_scale, block_q, block_k,
-              causal, n_real_q, n_real_k, interpret):
+              causal, n_real_q, n_real_k, interpret, window=None):
     b, h, n_q, d = q.shape
     n_k = k.shape[2]
     _, span_k = _spans(n_q, n_k, block_q, block_k, d, q.dtype.itemsize)
@@ -470,9 +521,9 @@ def _emit_fwd(q, k, v, mask_pad, layout, *, sm_scale, block_q, block_k,
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, block_k=block_k, causal=causal,
         has_mask=mask_pad is not None, n_real_k=n_real_k,
-        steps=span_k // block_k,
+        steps=span_k // block_k, **_windowed(window),
     )
-    kspec = _k_span_spec(span_k, d, block_q, causal)
+    kspec = _k_span_spec(span_k, d, block_q, causal, h // k.shape[1], window)
     in_specs, operands = _with_mask(
         [_row(block_q, d), kspec, kspec], [q, k, v], layout, mask_pad,
         pl.BlockSpec((block_q, span_k), lambda b_, h_, i, j: (i, j)),
@@ -502,7 +553,7 @@ def _emit_fwd(q, k, v, mask_pad, layout, *, sm_scale, block_q, block_k,
 
 @functools.partial(jax.jit, static_argnames=_STATICS)
 def _emit_dq(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
-             block_k, causal, n_real_q, n_real_k, interpret):
+             block_k, causal, n_real_q, n_real_k, interpret, window=None):
     b, h, n_q, d = q.shape
     n_k = k.shape[2]
     _, span_k = _spans(n_q, n_k, block_q, block_k, d, q.dtype.itemsize)
@@ -510,10 +561,10 @@ def _emit_dq(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
     kernel = functools.partial(
         _dq_kernel, sm_scale=sm_scale, block_k=block_k, causal=causal,
         has_mask=mask_pad is not None, n_real_k=n_real_k,
-        steps=span_k // block_k,
+        steps=span_k // block_k, **_windowed(window),
     )
     qspec, rowspec = _row(block_q, d), _row(block_q, 1)
-    kspec = _k_span_spec(span_k, d, block_q, causal)
+    kspec = _k_span_spec(span_k, d, block_q, causal, h // k.shape[1], window)
     in_specs, operands = _with_mask(
         [qspec, kspec, kspec, qspec, rowspec, rowspec],
         [q, k, v, do, lse, delta], layout, mask_pad,
@@ -534,47 +585,53 @@ def _emit_dq(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
 
 @functools.partial(jax.jit, static_argnames=_STATICS)
 def _emit_dkv(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
-              block_k, causal, n_real_q, n_real_k, interpret):
+              block_k, causal, n_real_q, n_real_k, interpret, window=None):
     b, h, n_q, d = q.shape
     n_k = k.shape[2]
+    group = h // k.shape[1]  # query heads that share one K/V head
     span_q, _ = _spans(n_q, n_k, block_q, block_k, d, q.dtype.itemsize)
     n_spans = n_q // span_q
     _built("dkv", q, k, block_q, block_k)
     kernel = functools.partial(
         _dkv_kernel, sm_scale=sm_scale, block_q=block_q, causal=causal,
         has_mask=mask_pad is not None, n_real_q=n_real_q, n_real_k=n_real_k,
-        steps=span_q // block_q,
+        steps=span_q // block_q, **_windowed(window),
+        **({} if group == 1 else {"n_spans": n_spans}),
     )
-    if causal:
-        # causal DMA skip, transposed: for k block i the dead q spans are
-        # the PREFIX before the first live one; the clamp re-indexes them
-        # to it so their copies are elided. The outer min keeps the index
-        # in range when n_k > n_q (a fully-dead k row's first live span
-        # would lie past the last one — the whole row is dead, so any
-        # in-range span serves; without the min the DMA reads out of
-        # bounds)
+
+    def live_span(i, j):
+        """Causal DMA skip, transposed: for k block i the dead q spans are
+        the PREFIX before the first live one (and, under a window, the
+        suffix after the last); the clamp re-indexes them to a live one so
+        their copies are elided. The outer min keeps the index in range
+        when n_k > n_q (a fully-dead k row's first live span would lie
+        past the last one: the whole row is dead, so any in-range span
+        serves; without the min the DMA reads out of bounds)."""
+        if not causal:
+            return j
+        j = jnp.maximum(j, _causal_first_live_q(i, block_k, span_q))
+        if window is not None:
+            j = jnp.minimum(j, _window_last_live_q(i, block_k, span_q, window))
+        return jnp.minimum(j, n_spans - 1)
+
+    if group == 1:
+        q_idx = lambda b_, h_, i, j: (b_, h_, live_span(i, j), 0)
+    else:  # the last axis walks the group's heads, each head's spans in turn
         q_idx = lambda b_, h_, i, j: (
-            b_, h_,
-            jnp.minimum(
-                jnp.maximum(j, _causal_first_live_q(i, block_k, span_q)),
-                n_spans - 1,
-            ),
-            0,
-        )
-    else:
-        q_idx = lambda b_, h_, i, j: (b_, h_, j, 0)
+            b_, h_ * group + j // n_spans, live_span(i, j % n_spans), 0)
     qspec = pl.BlockSpec((1, 1, span_q, d), q_idx)
     rowspec = pl.BlockSpec((1, 1, span_q, 1), q_idx)
     kspec = _row(block_k, d)
     in_specs, operands = _with_mask(
         [qspec, kspec, kspec, qspec, rowspec, rowspec],
         [q, k, v, do, lse, delta], layout, mask_pad,
-        pl.BlockSpec((span_q, block_k), lambda b_, h_, i, j: (j, i)),
+        pl.BlockSpec((span_q, block_k), (lambda b_, h_, i, j: (j, i)) if group == 1
+                     else (lambda b_, h_, i, j: (j % n_spans, i))),
     )
     return pl.pallas_call(
         kernel,
         name="dkv_flash",
-        grid=(b, h, n_k // block_k, n_spans),
+        grid=(b, k.shape[1], n_k // block_k, group * n_spans),
         in_specs=in_specs,
         out_specs=[kspec, kspec],
         out_shape=[
@@ -604,8 +661,11 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Flash attention over [B, H, N, D] with an optional STATIC token mask.
+    """Flash attention over q [B, Hq, N, D], k/v [B, Hkv, N, D] with an
+    optional STATIC token mask. Hq is a multiple of Hkv: query head j reads
+    K/V head `j // (Hq // Hkv)`, and dk/dv sum over the group.
 
     `mask` must be a host-side numpy bool array [Nq, Nk] (True = attend); it
     is analyzed into a block-occupancy layout so empty tiles are skipped.
@@ -614,11 +674,21 @@ def flash_attention(
     causality is enforced in-kernel with a block-triangle loop bound and no
     materialized mask. Differentiable (custom VJP, recompute-based backward).
 
+    `window` (causal, unmasked calls only): query t sees key p iff
+    `0 <= t - p < window`. It is a second loop bound beside the causal one,
+    in all three kernels and in the DMA skip, and the tiles that straddle
+    either edge are masked from their positions; no mask is materialized.
+
     `block_q`/`block_k` are the sides of the score tile; left out, they are
     chosen from the shape (`choose_tiles`). Lengths a tile does not divide
     are zero-padded up to it and the padding masked.
     """
     assert q.ndim == 4, f"expected [B,H,N,D], got {q.shape}"
+    assert q.shape[1] % k.shape[1] == 0 and k.shape[1] == v.shape[1], (
+        f"{q.shape[1]} query heads cannot share {k.shape[1]} K/V heads")
+    if window is not None:
+        assert causal and mask is None and q.shape[2] == k.shape[2], (
+            "a window is a bound of the causal, unmasked, self-attention walk")
     n_q, n_k = q.shape[2], k.shape[2]
     d = q.shape[3]
     chosen = choose_tiles(n_q, n_k, d, q.dtype, masked=mask is not None)
@@ -639,7 +709,7 @@ def flash_attention(
     static = dict(
         sm_scale=float(scale), block_q=block_q, block_k=block_k,
         causal=causal and mask is None, n_real_q=n_q, n_real_k=n_k,
-        interpret=bool(interp),
+        interpret=bool(interp), **_windowed(window),
     )
 
     @jax.custom_vjp

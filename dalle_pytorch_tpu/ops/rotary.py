@@ -106,3 +106,56 @@ def apply_rotary(angles: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
     t_rot, t_pass = t[..., :d_rot], t[..., d_rot:]
     t_rot = t_rot * jnp.cos(angles) + _rotate_half(t_rot) * jnp.sin(angles)
     return jnp.concatenate([t_rot, t_pass], axis=-1)
+
+
+# ------------------------------------------------- 1-D rotary, rotate-half
+#
+# The language-model path: one table per KIND of layer, channel i paired
+# with channel i + dim/2 (the "rotate-half" convention of the published
+# language models), q and k only. Angles, cosines and sines are float32
+# whatever the model computes in: at position 8191 a bf16 angle is off by
+# up to 32 radians (PERF.md, fault 1), which the DALL-E tables above keep
+# only because their configurations state it.
+
+
+def rotary_inv_freq(spec) -> np.ndarray:
+    """[dim / 2] float32 inverse frequencies of a rotary `spec`:
+    `{"type": "default", "dim", "theta"}`, or `"yarn"` with `factor`,
+    `original_max_position_embeddings`, `beta_fast`, `beta_slow` (YaRN,
+    arXiv:2309.00071: the slow channels interpolated by `factor`, the fast
+    ones left alone, a linear ramp between the two correction dims)."""
+    dim, theta = int(spec["dim"]), float(spec["theta"])
+    inv_freq = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if spec["type"] == "default":
+        return inv_freq.astype(np.float32)
+    if spec["type"] != "yarn":
+        raise ValueError(f"unknown rotary type {spec['type']!r}")
+    original = float(spec["original_max_position_embeddings"])
+
+    def correction_dim(rotations: float) -> float:
+        return dim * np.log(original / (rotations * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(int(np.floor(correction_dim(float(spec["beta_fast"])))), 0)
+    high = min(int(np.ceil(correction_dim(float(spec["beta_slow"])))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    interpolated = inv_freq / float(spec["factor"])
+    return (interpolated * ramp + inv_freq * (1.0 - ramp)).astype(np.float32)
+
+
+def rotary_cos_sin(positions, spec):
+    """(cos, sin), each float32 [..., dim], of `positions` under `spec`;
+    YaRN's `attention_factor` multiplies both."""
+    inv_freq = jnp.asarray(rotary_inv_freq(spec))
+    angles = jnp.asarray(positions, jnp.float32)[..., None] * inv_freq
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    scale = np.float32(spec.get("attention_factor", 1.0) if spec["type"] == "yarn" else 1.0)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def apply_rotary_half(cos: jnp.ndarray, sin: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
+    """Rotate every channel of t [..., n, dim] by float32 tables [n, dim],
+    halves paired; the product is float32 and the result t's dtype."""
+    x = t.astype(jnp.float32)
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    turned = jnp.concatenate([-x2, x1], axis=-1)
+    return (x * cos + turned * sin).astype(t.dtype)

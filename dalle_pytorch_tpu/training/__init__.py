@@ -7,6 +7,7 @@ _EXPORTS = {
     "get_learning_rate": "steps",
     "make_clip_train_step": "steps",
     "make_dalle_train_step": "steps",
+    "make_lm_train_step": "steps",
     "make_multi_step": "steps",
     "make_optimizer": "steps",
     "make_vae_train_step": "steps",

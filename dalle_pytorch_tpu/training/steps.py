@@ -41,10 +41,14 @@ class TrainState(train_state.TrainState):
 
 
 def make_optimizer(
-    learning_rate: float, clip_grad_norm: Optional[float] = None
+    learning_rate: float, clip_grad_norm: Optional[float] = None, warmup_steps: int = 0
 ) -> optax.GradientTransformation:
     """Adam with optional global-norm clipping; lr is a mutable hyperparam
-    (host-side schedulers rewrite it, see lr.py)."""
+    (host-side schedulers rewrite it, see lr.py). With `warmup_steps` the
+    rate rises linearly inside the step, `learning_rate * k / warmup_steps`
+    at update k, and stays at `learning_rate` from there on (the injected
+    value is then the schedule's at every update: a host-side scheduler's
+    rewrite would be overwritten, so a trainer uses one or the other)."""
 
     def build(learning_rate):
         steps = []
@@ -53,6 +57,9 @@ def make_optimizer(
         steps.append(optax.adam(learning_rate))
         return optax.chain(*steps)
 
+    if warmup_steps:
+        peak = learning_rate
+        learning_rate = lambda count: peak * jnp.minimum(1.0, (count + 1) / warmup_steps)
     return optax.inject_hyperparams(build)(learning_rate=learning_rate)
 
 
@@ -231,6 +238,41 @@ def make_dalle_train_step(
         return _update(state, grads), metrics
 
     return step
+
+
+def make_lm_train_step(model, grad_accum: int = 1) -> Callable:
+    """step(state, batch, rng) -> (state, metrics) for a `CausalLM`.
+
+    batch: {"tokens": [B, N] ids}; the loss is the model's next-token
+    cross-entropy. Built from the same `_accumulate`, `_update` and
+    `TrainState` as the DALL-E step. Where the trunk has routed layers the
+    metrics carry what they counted, per layer: `moe_load` [depth, held],
+    `moe_rows` and `moe_dropped` [depth] (an assignment past a layer's
+    buffer is a dropped token: callers require 0).
+    """
+
+    def loss_fn(params, batch, rng):
+        del rng  # no dropout in this trunk; the signature is the trainers'
+        loss, aux = model.apply(
+            {"params": params}, batch["tokens"], return_loss=True, mutable=["stats"]
+        )
+        metrics = {"loss": loss}
+        layers = aux.get("stats", {}).get("transformer", {})
+        if layers:
+            for name in ("moe_load", "moe_rows", "moe_dropped"):
+                metrics[name] = jnp.stack(
+                    [layers[f"ff_{i}"][name] for i in range(model.depth)]
+                )
+        return loss, metrics
+
+    def lm_step(state: TrainState, batch, rng):
+        scopes.remember("lm_step", lm_step, (state, batch, rng), donate_argnums=0)
+        grads, metrics = _accumulate(
+            loss_fn, state.params, _microbatch(batch, grad_accum), rng, grad_accum
+        )
+        return _update(state, grads), metrics
+
+    return lm_step
 
 
 def make_multi_step(step_fn: Callable, n_steps: int) -> Callable:

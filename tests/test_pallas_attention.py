@@ -426,3 +426,62 @@ class TestLibFlash:
         params = attn.init(jax.random.PRNGKey(0), x)
         with pytest.raises(ValueError, match="lib_flash"):
             attn.apply(params, x, key_mask=jnp.ones((1, n), bool))
+
+
+# ------------------------------------------- grouped K/V heads and a window
+
+
+def _grouped_dense(q, k, v, window):
+    """Dense float32 oracle: query head j reads K/V head j // group; key p is
+    seen by query t iff 0 <= t - p < window."""
+    n, group = q.shape[2], q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, 1), jnp.repeat(v, group, 1)
+    t, p = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    live = (p <= t) & ((t - p < window) if window else True)
+    return dense_attention(q, k, v, mask=live[None, None])
+
+
+def _grouped_qkv(n, heads, kv_heads, seed=0):
+    rng = np.random.RandomState(seed)
+    q, w = (jnp.asarray(rng.randn(2, heads, n, 16), jnp.float32) for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(2, kv_heads, n, 16), jnp.float32) for _ in range(2))
+    return q, k, v, w
+
+
+@pytest.mark.parametrize("n,heads,kv_heads,window,blocks,budget", [
+    (32, 4, 2, 8, (None, None), None),       # the small model's layer
+    (300, 4, 2, 40, (128, 128), None),       # a length the tile does not divide
+    (300, 4, 1, None, (128, 128), None),     # groups alone
+    (512, 2, 2, 100, (128, 128), None),      # a window alone
+    (1024, 4, 2, 130, (128, 128), 200 << 10),  # several spans: the DMA skip
+    (1000, 4, 2, 300, (128, 256), 200 << 10),  # both, ragged, oblong tiles
+])
+def test_window_and_grouped_heads_match_dense_forward_and_backward(
+        monkeypatch, n, heads, kv_heads, window, blocks, budget):
+    if budget:  # so little VMEM that a row is held in several spans
+        monkeypatch.setattr(pa, "VMEM_BUDGET", budget)
+        assert pa._spans(n, n, *blocks, 16, 4)[0] < n
+    q, k, v, w = _grouped_qkv(n, heads, kv_heads)
+    attn = lambda q, k, v: flash_attention(
+        q, k, v, window=window, block_q=blocks[0], block_k=blocks[1])
+    np.testing.assert_allclose(attn(q, k, v), _grouped_dense(q, k, v, window), atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(attn(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_grouped_dense(*a, window) * w), (0, 1, 2))(q, k, v)
+    for g, r, name in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(g, r, atol=5e-5, err_msg=name)
+
+
+def test_a_window_as_long_as_the_sequence_is_plain_causal():
+    q, k, v, _ = _grouped_qkv(96, 2, 2)
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, window=96), flash_attention(q, k, v), atol=1e-6)
+
+
+def test_window_and_groups_refuse_what_they_cannot_do():
+    q, k, v, _ = _grouped_qkv(64, 4, 2)
+    with pytest.raises(AssertionError, match="cannot share"):
+        flash_attention(q, k[:, :1].repeat(3, 1), v[:, :1].repeat(3, 1))
+    with pytest.raises(AssertionError, match="window"):
+        flash_attention(q, k, v, window=8, causal=False)
+    with pytest.raises(AssertionError, match="window"):
+        flash_attention(q, k, v, window=8, mask=np.tril(np.ones((64, 64), bool)))

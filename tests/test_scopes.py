@@ -169,7 +169,29 @@ def program_texts():
     avars = attn.init(jax.random.PRNGKey(4), x)
     quant = jax.jit(lambda v, x, c: attn.apply(v, x, cache=c)).lower(
         avars, x, cache).compile().as_text()
-    return {"train": train, "sample": sample, "quant": quant}
+    return {"train": train, "sample": sample, "quant": quant, "lm": _lm_step_text()}
+
+
+def _lm_step_text() -> str:
+    """Compiled text of a tiny language-model train step: routed layers, a
+    window and a full layer, remat."""
+    from dalle_pytorch_tpu.models.lm import CausalLM
+    from dalle_pytorch_tpu.training import TrainState, make_lm_train_step, make_optimizer
+
+    rope = {"type": "default", "dim": 8, "theta": 1e4}
+    lm = CausalLM(
+        num_tokens=40, dim=32, depth=2, seq_len=16, heads=2, dim_head=8, reversible=True,
+        trunk=dict(norm="rms", ff_kind="swiglu_experts", use_bias=False, layerscale=False,
+                   kv_heads=1, qk_norm=True, window=4, attn_types=("window", "full"),
+                   rotary_specs={"window": rope, "full": rope}, experts_total=4,
+                   experts_per_token=2, experts_held=(0, 2), expert_dim=16,
+                   moe_buffer_rows=64, attn_impl="flash"))
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    state = TrainState.create(
+        apply_fn=lm.apply, params=jax.jit(lm.init)(jax.random.PRNGKey(0), tokens)["params"],
+        tx=make_optimizer(3e-4, clip_grad_norm=0.5))
+    step = jax.jit(make_lm_train_step(lm), donate_argnums=0)
+    return step.lower(state, {"tokens": tokens}, jax.random.PRNGKey(1)).compile().as_text()
 
 
 def test_every_rule_is_hit_by_the_programs_own_text(program_texts):

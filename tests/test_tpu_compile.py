@@ -326,6 +326,75 @@ def test_flagship_step_holds_each_kernel_body_once(topo, compiled_kernels):
     assert len(text) < LOWERED_TEXT_BYTES, len(text)
 
 
+def test_flagship_step_holds_four_bodies_with_the_new_arguments_spelled_out(
+        topo, compiled_kernels, monkeypatch):
+    """What the shared kernels owe `flagship.train`: a call that names the
+    language-model path's arguments at their defaults (`window=None`, K/V
+    heads as many as query heads) lowers to the same four kernel bodies and
+    the same tiles as one that leaves them out."""
+    from dalle_pytorch_tpu.models import attention
+    from dalle_pytorch_tpu.ops import pallas_attention as pa
+
+    real = attention.flash_attention
+
+    def spelled_out(q, k, v, **kw):
+        assert q.shape[1] == k.shape[1]
+        return real(q, k, v, **{"window": None, **kw})
+
+    monkeypatch.setattr(attention, "flash_attention", spelled_out)
+    pa.forget()
+    text = _flagship_step(
+        [topo.devices[0]], dict(dp=1), batch=TRAIN_BATCH, executor="unrolled"
+    ).as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert set(pa.tiles_chosen.values()) == {(640, 640)}
+    assert len(text) < LOWERED_TEXT_BYTES, len(text)
+
+
+# the language-model cell's attention: 4 rows, 32 query heads over 4 K/V
+# heads of 128, 8192 positions, window 1024 on three layers of four
+LM_B, LM_H, LM_HKV, LM_N, LM_D, LM_WINDOW = 4, 32, 4, 8192, 128, 1024
+
+
+@pytest.mark.parametrize("window", [LM_WINDOW, None], ids=["window", "full"])
+def test_flash_attention_compiles_with_grouped_heads_and_a_window(one_chip, window):
+    """fwd, dq and dkv at the language-model cell's shape: head 128, length
+    8192 (where a row no longer stays resident: spans of 4096 keys and 1024
+    query rows), 8 query heads a K/V head, the window's loop bounds and DMA
+    skip. The tiles come from the shape alone."""
+    from dalle_pytorch_tpu.ops import pallas_attention as pa
+
+    q = jax.ShapeDtypeStruct((LM_B, LM_H, LM_N, LM_D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((LM_B, LM_HKV, LM_N, LM_D), jnp.bfloat16, sharding=one_chip)
+    attn = functools.partial(pa.flash_attention, window=window, interpret=False)
+    pa.forget()
+    compiled = _compile(
+        jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(), (0, 1, 2)), q, kv, kv)
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert set(pa.tiles_chosen.values()) == {(512, 512)}
+    assert pa._spans(LM_N, LM_N, 512, 512, LM_D, 2) == (1024, 4096)
+
+
+@pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)], ids=["gate_up", "down"])
+def test_grouped_matmul_compiles(one_chip, compiled_kernels, monkeypatch, k, n):
+    """The routed layer's products at the cell's shapes (a buffer of 262144
+    rows, 16 experts, 2304 x 896 and back): `gmm_fwd`, `gmm_dlhs` and
+    `gmm_drhs` with their prefetched work plan, tiles of 512 rows by 768 or
+    896, float32 scratch, parameters float32 and rows bf16."""
+    from dalle_pytorch_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    lhs = jax.ShapeDtypeStruct((262144, k), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((16, k, n), jnp.float32, sharding=one_chip)
+    fn = jax.value_and_grad(
+        lambda l, r, s: gm.grouped_matmul(l, r, s).astype(jnp.float32).sum(), (0, 1))
+    text = _compile(fn, lhs, rhs, _i32(one_chip, 16)).as_text()
+    kernels = dict(re.findall(r"%(gmm_\w+?)[.\d]* = (\w+\[[\d,]*\])", text))
+    assert kernels == {"gmm_fwd": f"bf16[262144,{n}]", "gmm_dlhs": f"bf16[262144,{k}]",
+                       "gmm_drhs": f"f32[16,{k},{n}]"}
+    assert (gm._tile(2304), gm._tile(896)) == (768, 896)
+
+
 @pytest.mark.slow
 def test_flagship_step_kernels_keep_their_names_and_phases(topo, compiled_kernels):
     """Compiled, the shared bodies are 48 custom calls again, each named
