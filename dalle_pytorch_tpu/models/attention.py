@@ -101,37 +101,46 @@ AUTO_FLASH_DECODE_MIN_LEN = 512
 DECODE_SPARSE_BLOCK = 128
 
 
-def _cache_write(buf: jnp.ndarray, val: jnp.ndarray, index) -> jnp.ndarray:
+def _cache_write(buf: jnp.ndarray, val: jnp.ndarray, index, layer=None) -> jnp.ndarray:
     """Write val [B,H,n,D] into buf [B,H,S,D] at sequence position `index`
-    (n = 1 for single-token decode, larger for prefill chunks).
+    (n = 1 for single-token decode, larger for prefill chunks). The scale
+    leaves take the same write one rank lower: val [B,H,n] into [B,H,S].
 
     `index` is either a scalar (the whole batch sits at one position — the
     micro-batch decode scan) or a [B] vector (each row sits at its OWN
     position — the continuous-batching slot cache, where rows were admitted
-    at different times)."""
+    at different times).
+
+    `layer` (the scan executor's traced layer index) says `buf` is the
+    depth-stacked leaf [L,B,H,S,D]: the same n positions are written at
+    `buf[layer]`, in place, and the whole stack comes back."""
+    tail = (0,) * (val.ndim - 3)  # (0,) for K/V, () for their scales
     with jax.named_scope("cache_write"):
+        val = val.astype(buf.dtype)
+        if layer is not None:
+            val = val[None]
         if jnp.ndim(index) == 0:
+            start = (0, 0, index) + tail
             return lax.dynamic_update_slice(
-                buf, val.astype(buf.dtype), (0, 0, index, 0)
+                buf, val, start if layer is None else (layer,) + start
             )
+        if layer is None:
+            return jax.vmap(
+                lambda b, v, i: lax.dynamic_update_slice(b, v, (0, i) + tail)
+            )(buf, val, index)
         return jax.vmap(
-            lambda b, v, i: lax.dynamic_update_slice(
-                b, v.astype(b.dtype), (0, i, 0)
-            )
+            lambda b, v, i: lax.dynamic_update_slice(b, v, (layer, 0, i) + tail),
+            in_axes=(1, 1, 0), out_axes=1,
         )(buf, val, index)
 
 
-def _scale_write(buf: jnp.ndarray, val: jnp.ndarray, index) -> jnp.ndarray:
-    """`_cache_write` for the per-(position, head) scale leaves: val
-    [B,H,n] into buf [B,H,S] at sequence position `index`."""
-    with jax.named_scope("cache_write"):
-        if jnp.ndim(index) == 0:
-            return lax.dynamic_update_slice(
-                buf, val.astype(buf.dtype), (0, 0, index)
-            )
-        return jax.vmap(
-            lambda b, v, i: lax.dynamic_update_slice(b, v.astype(b.dtype), (0, i))
-        )(buf, val, index)
+def _cache_view(buf: jnp.ndarray, layer) -> jnp.ndarray:
+    """This layer's K/V (or scales, or page pool) as attention reads it: the
+    leaf itself, or `buf[layer]` of the scan executor's depth-stacked one."""
+    if layer is None:
+        return buf
+    with jax.named_scope("cache_read"):
+        return lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
 
 
 def _kv_quantize(x: jnp.ndarray):
@@ -379,7 +388,17 @@ class Attention(nn.Module):
             # slotted cache (bit-for-bit — the paging parity contract) or
             # stream pages directly through the paged Pallas kernel
             # (ops/pallas_decode.py PAGED_DECODE_IMPL).
+            #
+            # A cache carrying a "layer" key is the scan executor's: k, v
+            # (and their scales) are the DEPTH-STACKED leaves [L, ...] held
+            # in the layer scan's carry, and `layer` is this layer's traced
+            # index. The chunk's n positions are written into the stack at
+            # [layer], in place; what attention reads is `stack[layer]` of
+            # the updated stack, a view nothing else consumes. The other
+            # leaves (index, page_table, block_bitmap) arrive as the
+            # layer's own.
             index = cache["index"]
+            layer = cache.get("layer")
             per_row = jnp.ndim(index) == 1
             paged = "page_table" in cache
             if rotary is not None:
@@ -405,7 +424,7 @@ class Attention(nn.Module):
             if paged:
                 assert per_row, "paged caches always carry per-row indices"
                 pt = cache["page_table"]
-                page_size = cache["k"].shape[2]
+                page_size = cache["k"].shape[-2]
                 # virtual contiguous length == the slotted cache's max_len
                 # (total_seq_len + 1): gather crops to it so dense/flash see
                 # byte-identical shapes on both layouts
@@ -416,27 +435,32 @@ class Attention(nn.Module):
                 # slotted dynamic_update_slice does
                 page = jnp.take_along_axis(pt, pos // page_size, axis=1)
                 off = pos % page_size
+                # [B, n] pages and offsets, under the stack's [layer]
+                kv_at = (page, slice(None), off, slice(None))
+                if layer is not None:
+                    kv_at = (layer,) + kv_at
+                sc_at = kv_at[:-1]
                 with jax.named_scope("cache_write"):
-                    ck = cache["k"].at[page, :, off, :].set(
+                    new_k = cache["k"].at[kv_at].set(
                         qk.transpose(0, 2, 1, 3).astype(cache["k"].dtype)
                     )
-                    cv = cache["v"].at[page, :, off, :].set(
+                    new_v = cache["v"].at[kv_at].set(
                         qv.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
                     )
                     if quant:
-                        cks = cache["k_scale"].at[page, :, off].set(
-                            k_sc.transpose(0, 2, 1)
-                        )
-                        cvs = cache["v_scale"].at[page, :, off].set(
-                            v_sc.transpose(0, 2, 1)
-                        )
+                        new_ks = cache["k_scale"].at[sc_at].set(k_sc.transpose(0, 2, 1))
+                        new_vs = cache["v_scale"].at[sc_at].set(v_sc.transpose(0, 2, 1))
             else:
-                ck = _cache_write(cache["k"], qk, index)
-                cv = _cache_write(cache["v"], qv, index)
+                new_k = _cache_write(cache["k"], qk, index, layer)
+                new_v = _cache_write(cache["v"], qv, index, layer)
                 if quant:
-                    cks = _scale_write(cache["k_scale"], k_sc, index)
-                    cvs = _scale_write(cache["v_scale"], v_sc, index)
-                max_len = ck.shape[2]
+                    new_ks = _cache_write(cache["k_scale"], k_sc, index, layer)
+                    new_vs = _cache_write(cache["v_scale"], v_sc, index, layer)
+                max_len = new_k.shape[-2]
+            # this layer's K/V as the reads below take it
+            ck, cv = _cache_view(new_k, layer), _cache_view(new_v, layer)
+            if quant:
+                cks, cvs = _cache_view(new_ks, layer), _cache_view(new_vs, layer)
             # policy block bitmap ([B, nb] int32, nb = ceil(max_len /
             # decode_sparse_block), nonzero = KV tile may be read): traced
             # DATA riding the cache pytree (models/dalle.py threads it from
@@ -576,15 +600,16 @@ class Attention(nn.Module):
                     out = dense_attention(
                         q, gk, gv, mask=mask, stable=self.stable
                     )
-            new_cache = {"k": ck, "v": cv, "index": index + n}
+            new_cache = {"k": new_k, "v": new_v, "index": index + n}
             if quant:
-                new_cache["k_scale"] = cks
-                new_cache["v_scale"] = cvs
+                new_cache["k_scale"] = new_ks
+                new_cache["v_scale"] = new_vs
             if paged:
                 new_cache["page_table"] = pt
             if sparse:
-                # structural round-trip: nn.scan requires carry-in/carry-out
-                # pytrees to match, so the bitmap leaf rides back out
+                # structural round-trip: the cache that comes back has the
+                # leaves it came with (the callers strip page_table and
+                # block_bitmap from both executors' caches alike)
                 new_cache["block_bitmap"] = bitmap
         else:
             if rotary is not None:

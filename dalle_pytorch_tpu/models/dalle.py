@@ -1402,8 +1402,9 @@ def _with_block_bitmap(cache, bitmaps, executor, depth):
 
 
 def _without_block_bitmap(cache, executor):
-    """Strip the bitmap leaves (attention round-trips them for nn.scan
-    carry-structure parity) so the persistent donated state keeps its
+    """Strip the bitmap leaves (the cache comes back with the leaves it
+    went in with: per layer from attention, in the layer scan's carry for
+    the scan executor) so the persistent donated state keeps its
     bitmap-free shape — the policy table is host state, like the page
     table."""
     if executor == "scan":
@@ -1424,7 +1425,8 @@ def _with_ring_end(cache, ring_end, executor, depth):
     """Inject the per-row resume window `ring_end` [B] into a decode
     cache so `shift_with_ring` rebuilds rings per row (decode_resume).
     Same smuggling idiom as `_with_page_table`; the transformer's output
-    cache is rebuilt without the leaf, so nothing strips it."""
+    cache comes back without the leaf (rebuilt per layer, or dropped after
+    the layer scan that carried it), so nothing strips it."""
     re_ = jnp.asarray(ring_end, jnp.int32)
     if executor == "scan":
         return {**cache, "ring_end": jnp.broadcast_to(re_, (depth,) + re_.shape)}

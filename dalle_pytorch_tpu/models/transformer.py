@@ -40,10 +40,13 @@ Layer executors (orthogonal to the reversible memory modes):
     copies, so programs compile ~depth× faster at identical runtime
     math. Attn-type cycling runs
     as dense attention with per-layer pattern masks scanned over depth;
-    no cross-layer sharing. KV-cached decode is native (the depth-stacked
-    cache rides the layer scan as scanned input and output), pattern
-    masks included — each layer's traced mask row-slices at the decode
-    position like the unrolled executor's static masks.
+    no cross-layer sharing. KV-cached decode is native: the depth-stacked
+    cache rides the layer scan's CARRY beside x, each layer writes its
+    chunk's positions into the stack at its own index and attends over
+    `stack[layer]`, so a token step moves one token's K/V and the loop
+    hands back the buffer it took. Pattern masks included — each layer's
+    traced mask row-slices at the decode position like the unrolled
+    executor's static masks.
 """
 
 from __future__ import annotations
@@ -57,7 +60,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from dalle_pytorch_tpu.models.attention import Attention
+from dalle_pytorch_tpu.models.attention import Attention, _cache_view
 from dalle_pytorch_tpu.ops.masks import (
     axial_static_mask,
     conv_like_mask,
@@ -177,6 +180,42 @@ def shift_with_ring(h, ring, pos, text_len, fmap, ring_end=None):
         return shift_token_step(h, ring, pos, text_len, fmap)
 
 
+# depth-stacked cache leaves a layer never slices out whole: attention
+# writes its chunk into them at [layer] and reads `leaf[layer]` as a view
+_STACKED_KV = ("k", "v", "k_scale", "v_scale")
+
+
+def _layer_cache(stack: dict, layer) -> dict:
+    """One layer's cache out of the scan executor's depth-stacked one: the
+    small leaves (index, shift rings, page_table, block_bitmap, ring_end)
+    sliced at `layer`, K/V and their scales left stacked beside `layer`."""
+    attn = {
+        name: leaf if name in _STACKED_KV else _cache_view(leaf, layer)
+        for name, leaf in stack["attn"].items()
+    }
+    rest = {
+        name: _cache_view(leaf, layer) for name, leaf in stack.items() if name != "attn"
+    }
+    return {"attn": {**attn, "layer": layer}, **rest}
+
+
+def _stack_cache(stack: dict, layer, attn_cache: dict, rings: dict) -> dict:
+    """The depth-stacked cache after one layer: K/V as attention left them
+    (already written in place), the layer's new index and rings written
+    back at `layer`; what a layer only reads stays as it was."""
+    with jax.named_scope("cache_write"):
+        put = lambda leaf, new: jax.lax.dynamic_update_index_in_dim(
+            leaf, new.astype(leaf.dtype), layer, 0
+        )
+        attn = {
+            **stack["attn"],
+            **{name: attn_cache[name] for name in _STACKED_KV if name in attn_cache},
+            "index": put(stack["attn"]["index"], attn_cache["index"]),
+        }
+        ring_stacks = {name: put(stack[name], ring) for name, ring in rings.items()}
+    return {**stack, "attn": attn, **ring_stacks}
+
+
 class _ScanBlock(nn.Module):
     """One (attn, ff) residual pair in scannable form.
 
@@ -184,6 +223,9 @@ class _ScanBlock(nn.Module):
     full-attention case; LayerScale vectors arrive as scanned-over inputs
     (they are per-layer constants at init, so they live as one stacked
     parameter on the owning Transformer instead of inside the body).
+
+    Uncached, the carry is x and `layer` None. Cached, the carry is
+    (x, the whole depth-stacked cache) and `layer` the scanned layer index.
     """
 
     dim: int
@@ -209,18 +251,20 @@ class _ScanBlock(nn.Module):
     dtype: Any
 
     @nn.compact
-    def __call__(self, x, attn_scale, ff_scale, pattern_idx, pattern_table,
-                 cache, key_mask, rotary):
+    def __call__(self, carry, attn_scale, ff_scale, pattern_idx, pattern_table,
+                 layer, key_mask, rotary):
         # pattern_idx is the scanned per-layer index into the broadcast
         # table of unique [S, S] pattern masks; None = uniform full attention
         with jax.named_scope("pattern_mask"):
             pattern_mask = (
                 None if pattern_table is None else pattern_table[pattern_idx]
             )
-        cached = cache is not None
+        cached = layer is not None
+        x, stack = carry if cached else (carry, None)
+        cache = _layer_cache(stack, layer) if cached else None
         pos = cache["attn"]["index"] if cached else None
         # per-row resume window (decode_resume injects it; absent on the
-        # ordinary prefill/decode paths and dropped from the new cache)
+        # ordinary prefill/decode paths)
         ring_end = cache.get("ring_end") if cached else None
 
         def shift(h, ring):
@@ -269,11 +313,11 @@ class _ScanBlock(nn.Module):
 
         if not cached:
             return x, None
-        new_cache = {"attn": attn_cache}
-        if self.shift_tokens:
-            new_cache["shift_attn"] = ring_attn
-            new_cache["shift_ff"] = ring_ff
-        return x, new_cache
+        rings = (
+            {"shift_attn": ring_attn, "shift_ff": ring_ff}
+            if self.shift_tokens else {}
+        )
+        return (x, _stack_cache(stack, layer, attn_cache, rings)), None
 
 
 class _ScanStack(nn.Module):
@@ -304,15 +348,16 @@ class _ScanStack(nn.Module):
         # attn-type cycling: each layer picks its pattern mask from the
         # broadcast table of UNIQUE masks via a scanned [depth] index;
         # None (uniform full attention) broadcasts through. The decode
-        # cache (depth-stacked leaves) is scanned in AND collected back
-        # out as the scan's per-layer output.
+        # cache (depth-stacked leaves) is CARRIED beside x, and the body is
+        # told which layer it is by a scanned index (`reverse` flips it
+        # with the parameters).
         idx_axis = nn.broadcast if pattern_idx is None else 0
-        cache_axis = nn.broadcast if cache is None else 0
+        layer_axis = nn.broadcast if cache is None else 0
         scanned = nn.scan(
             body,
             variable_axes={"params": 0},
             split_rngs={"params": True, "dropout": True},
-            in_axes=(0, 0, idx_axis, nn.broadcast, cache_axis, nn.broadcast,
+            in_axes=(0, 0, idx_axis, nn.broadcast, layer_axis, nn.broadcast,
                      nn.broadcast),
             length=self.depth,
             reverse=reverse,
@@ -322,19 +367,21 @@ class _ScanStack(nn.Module):
         )
         if cache is None:
             x, _ = stack(
-                x, attn_scales, ff_scales, pattern_idx, pattern_table, cache,
+                x, attn_scales, ff_scales, pattern_idx, pattern_table, None,
                 key_mask, rotary,
             )
             return x
-        # `lax.scan` itself slices each layer's K/V out of the depth-stacked
-        # cache and stacks the new one back: no scope can be drawn inside
-        # it, so the cached scan is named as a whole (obs/scopes.py reads its
-        # bare slices as `cache_read` / `cache_write`)
+        # the cached scan is named as a whole: what the loop itself slices
+        # (parameters, the layer index) lands under it with no scope of its
+        # own, and obs/scopes.py reads that as `unscoped`
         with jax.named_scope("cached_scan"):
-            return stack(
-                x, attn_scales, ff_scales, pattern_idx, pattern_table, cache,
-                key_mask, rotary,
+            (x, cache), _ = stack(
+                (x, cache), attn_scales, ff_scales, pattern_idx, pattern_table,
+                jnp.arange(self.depth, dtype=jnp.int32), key_mask, rotary,
             )
+        # decode_resume's per-row window is read by every layer and is not
+        # part of the cache that comes back
+        return x, {k: v for k, v in cache.items() if k != "ring_end"}
 
 
 class Transformer(nn.Module):
@@ -1090,8 +1137,8 @@ def make_decode_cache(
     Standalone (not a module method) so model owners like DALLE can build
     it from config without binding parameters. The unrolled executor
     takes per-layer dicts ("layer_{i}"); the scan executor takes the same
-    leaves depth-stacked along axis 0 (they ride the layer scan as
-    scanned inputs/outputs).
+    leaves depth-stacked along axis 0 (they ride the layer scan's carry,
+    each layer writing and reading at its own index).
 
     `per_row=True` sizes the `index` leaves [batch] (scan: [depth, batch])
     instead of scalar, putting each batch row at its OWN sequence position —

@@ -73,17 +73,11 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         ("pixels", r"(^|/)DiscreteVAE\."),
         ("cache_read", _E("cache_read")),
         ("cache_write", _E("cache_write")),
-        # the scan executor hands each layer its slice of the depth-stacked
-        # decode cache, and stacks the new one back, in `lax.scan`'s own
-        # slicing (`dynamic_index_in_dim`, `dynamic_update_index_in_dim`): no
-        # scope can be drawn inside it, so the cached scan is named as a
-        # whole (`cached_scan`) and the bare primitives of its body are the
-        # cache moving (with the few per-layer vectors XLA left unfused)
-        ("cache_read", r"(^|/)cached_scan/while/body/(dynamic_slice|squeeze)$"),
-        ("cache_write", r"(^|/)cached_scan/while/body/"
-                        r"(dynamic_update_slice|broadcast_in_dim|reshape)$"),
-        # the same slicing in an uncached scan moves parameters, and the
-        # loop's counter is nobody's: no owner
+        # a scan's own slicing (`dynamic_index_in_dim` of the stacked
+        # parameters, LayerScale vectors and the layer index) and its counter
+        # are nobody's: no owner. The cached scan carries the depth-stacked
+        # decode cache and is named as a whole (`cached_scan`); what moves
+        # the cache inside its body is under `cache_read` / `cache_write`
         ("unscoped", r"(^|/)(scan_stack|cached_scan)/while/(body|cond)/[\w\-]+$"),
         ("attend", _E("attend")),
         ("attn_proj", _E("to_qkv|to_out")),

@@ -13,7 +13,9 @@ import pytest
 
 from dalle_pytorch_tpu.models.transformer import (
     Transformer,
+    make_decode_cache,
     scan_params_to_unrolled,
+    set_decode_cache_index,
     unrolled_params_to_scan,
 )
 from dalle_pytorch_tpu.models.dalle import DALLE, generate_images_cached
@@ -177,6 +179,105 @@ class TestScanParity:
             )
 
 
+PATTERNS = ("full", "axial_row", "axial_col", "conv_like")
+
+
+class TestScanCachedChunks:
+    """What the scan executor's in-place cache write must carry, below the
+    samplers: the depth-stacked cache rides the layer scan's carry, each
+    layer writes its chunk at `[layer]` and attends over `stack[layer]`.
+    A prefill chunk of 4 positions, then single steps, against the
+    unrolled executor's per-layer cache: outputs and every cache leaf."""
+
+    STEPS = 5
+
+    @pytest.mark.parametrize("attn_types", [None, PATTERNS], ids=["full", "patterns"])
+    @pytest.mark.parametrize(
+        "carries",
+        ["lockstep", "reverse_model", "per_row", "int8", "per_row_int8"],
+    )
+    def test_cached_chunks_match_unrolled(self, carries, attn_types):
+        unr, scn = pair(attn_types=attn_types)
+        x = x_input()  # [2, SEQ, DIM]
+        vu = unr.init(jax.random.PRNGKey(1), x)
+        vs = {"params": unrolled_params_to_scan(vu["params"], DEPTH)}
+        per_row = carries.startswith("per_row")
+        reverse = carries == "reverse_model"
+        kw = dict(
+            depth=DEPTH, batch=2, max_len=SEQ + 1, heads=2, dim_head=8, dim=DIM,
+            image_fmap_size=FMAP, shift_tokens=True, per_row=per_row,
+            kv_dtype="int8" if carries.endswith("int8") else None,
+        )
+        cu = make_decode_cache(executor="unrolled", **kw)
+        cs = make_decode_cache(executor="scan", **kw)
+
+        def both(chunk, cu, cs):
+            ou, cu = unr.apply(vu, chunk, cache=cu, reverse_model=reverse)
+            os_, cs = scn.apply(vs, chunk, cache=cs, reverse_model=reverse)
+            np.testing.assert_allclose(
+                np.asarray(ou), np.asarray(os_), rtol=2e-5, atol=2e-5
+            )
+            return cu, cs
+
+        cu, cs = both(x[:, :4], cu, cs)
+        for t in range(4, 4 + self.STEPS):
+            if per_row:
+                # row 1 stops at position 6 (a retired slot rewrites its
+                # last position), row 0 goes on: the rows sit apart
+                pos = jnp.array([t, min(t, 6)], jnp.int32)
+                cu = set_decode_cache_index(cu, pos, "unrolled")
+                cs = set_decode_cache_index(cs, pos, "scan")
+            cu, cs = both(x[:, t : t + 1], cu, cs)
+
+        # the same leaves, whichever way they are held: int8 K/V bit for bit
+        assert jax.tree.structure(cs) == jax.tree.structure(
+            make_decode_cache(executor="scan", **kw)
+        )
+        stacked = jax.tree.map(
+            lambda *leaves: jnp.stack(leaves), *(cu[f"layer_{i}"] for i in range(DEPTH))
+        )
+        for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(stacked), jax.tree.leaves(cs)
+        ):
+            assert a.shape == b.shape and a.dtype == b.dtype, path
+            if a.dtype == jnp.int8:
+                # a rounding tie may fall either way on a last float32 bit
+                assert np.abs(np.asarray(a, np.int32) - np.asarray(b, np.int32)).max() <= 1, path
+            else:
+                np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5,
+                    err_msg=jax.tree_util.keystr(path),
+                )
+        if reverse:
+            # sanity that the flag acted on the cached path too
+            fwd, _ = scn.apply(
+                vs, x[:, :4], cache=make_decode_cache(executor="scan", **kw)
+            )
+            rev, _ = scn.apply(
+                vs, x[:, :4], cache=make_decode_cache(executor="scan", **kw),
+                reverse_model=True,
+            )
+            assert not np.allclose(np.asarray(fwd), np.asarray(rev))
+
+    def test_decode_resume_window_is_read_and_not_returned(self):
+        """`ring_end` rides the carry like the rest, every layer reads its
+        own slice, and the cache that comes back has the leaves it had."""
+        _, scn = pair()
+        x = x_input()
+        vs = scn.init(jax.random.PRNGKey(1), x)
+        cache = make_decode_cache(
+            depth=DEPTH, batch=2, max_len=SEQ + 1, heads=2, dim_head=8, dim=DIM,
+            image_fmap_size=FMAP, shift_tokens=True, executor="scan",
+        )
+        ring_end = jnp.broadcast_to(jnp.array([7, 9], jnp.int32), (DEPTH, 2))
+        _, out = scn.apply(vs, x, cache={**cache, "ring_end": ring_end})
+        assert jax.tree.structure(out) == jax.tree.structure(cache)
+        _, plain = scn.apply(vs, x, cache=cache)
+        assert not np.allclose(
+            np.asarray(out["shift_attn"]), np.asarray(plain["shift_attn"])
+        )
+
+
 class TestScanCLIP:
     """CLIP's two non-causal encoders under the scan executor (incl. the
     text encoder's dynamic key-padding mask through nn.broadcast)."""
@@ -241,7 +342,7 @@ class TestScanDALLE:
 
     def test_native_cached_decode_matches_unrolled(self):
         """The scan executor's OWN KV-cached decode (depth-stacked cache
-        scanned in and out) must produce the same tokens as the unrolled
+        carried through the layer scan) must produce the same tokens as the unrolled
         cached sampler on the converted checkpoint — no conversion needed."""
         mu, ms = self._model("unrolled"), self._model("scan")
         text = jnp.array([[3, 5, 2, 0]], jnp.int32)

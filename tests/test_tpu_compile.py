@@ -469,3 +469,81 @@ def test_flagship_train_step_shards_over_four_chips(topo, compiled_kernels):
     sharded_args = compiled.memory_analysis().argument_size_in_bytes
     assert sharded_args < 0.5 * one.memory_analysis().argument_size_in_bytes
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+# ------------------------------------------------- the scan executor's decode
+
+
+def test_scan_decode_holds_its_cache_in_place(topo, one_chip, capsys):
+    """The cached sampler of a scan model at `paper64.generate`'s widths (dim
+    1024, 16 heads x 64, cache 1281, the four patterns, batch 2; depth 8 of
+    the 64, the compile is a third of a minute): the depth-stacked K/V rides
+    both loops' carries and nothing but a chunk's own positions is written.
+    Read from the optimized HLO: no loop copies a stacked K/V leaf, every
+    `dynamic-update-slice` into one is as wide as its chunk (257 positions
+    in the prefill, 1 in a token step), a token step materializes no layer's
+    view, and the program's temporaries stay under a bound the scanned-in,
+    collected-out threading broke (1.63e9 bytes then, 0.63e9 now). What is
+    left is ONE relayout of a leaf in the entry computation, between the
+    prefill's loop and the token loop, once a batch."""
+    from dalle_pytorch_tpu.models.dalle import DALLE, _generate_images_cached_impl
+    from dalle_pytorch_tpu.obs import scopes
+
+    depth, batch = 8, 2
+    model = DALLE(
+        dim=1024, depth=depth, heads=H, dim_head=D, text_seq_len=TEXT,
+        num_text_tokens=32768, num_image_tokens=8192, image_fmap_size=FMAP,
+        shift_tokens=True, rotary_emb=True, executor="scan", attn_impl="auto",
+        attn_types=("full", "axial_row", "axial_col", "conv_like"),
+        dtype=jnp.bfloat16,
+    )
+    variables = jax.eval_shape(
+        lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, TEXT), jnp.int32),
+            jnp.zeros((1, FMAP * FMAP), jnp.int32),
+        )
+    )
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree
+    )
+    compiled = jax.jit(
+        lambda v, rng, text: _generate_images_cached_impl(
+            model, v, rng, text, filter_thres=0.9
+        )
+    ).lower(
+        on_chip(variables),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+        _i32(one_chip, batch, TEXT),
+    ).compile()
+    text = compiled.as_text()
+
+    leaf = f"bf16[{depth},{batch},{H},{CACHE},{D}]"
+    view = f"bf16[{batch},{H},{CACHE},{D}]"
+    shapes, copies, writes, in_entry = {}, [], [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            in_entry = line.startswith("ENTRY")
+            continue
+        got = scopes.instruction(line)
+        if got is None:
+            continue
+        name, opcode, shape = got
+        shapes[name] = shape
+        if shape == leaf and opcode in ("copy", "copy-start"):
+            copies.append((name, in_entry))
+        if shape == leaf and opcode == "dynamic-update-slice":
+            writes.append(line.split("dynamic-update-slice(", 1)[1].split(",")[1].strip(" %"))
+    assert [name for name, entry in copies if not entry] == [], copies
+    assert len(copies) <= 1, copies
+    widths = sorted(int(shapes[update].split(",")[3]) for update in writes)
+    assert widths == [1, 1, TEXT + 1, TEXT + 1], [shapes[u] for u in writes]
+    token_step = [
+        (name, row) for name, row in scopes.parse(text).items()
+        if row[1] == view and "decode_image_step" in (row[2] or "")
+    ]
+    assert token_step == [], token_step
+    assert compiled.memory_analysis().temp_size_in_bytes < 800e6, compiled.memory_analysis()
+    with capsys.disabled():
+        layouts = sorted(set(re.findall(re.escape(leaf) + r"(\{[^{}]*\})", text)))
+        print(f"\n[scan decode] {leaf} is laid out as {layouts}; "
+              f"temporaries {compiled.memory_analysis().temp_size_in_bytes}")
