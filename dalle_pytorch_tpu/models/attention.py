@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import jax.lax as lax
 import flax.linen as nn
 
+from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.ops.attention_core import dense_attention
 from dalle_pytorch_tpu.ops.pallas_attention import (
     flash_attention,
@@ -53,94 +54,41 @@ from dalle_pytorch_tpu.ops.pallas_decode import (
 )
 from dalle_pytorch_tpu.ops.rotary import apply_rotary, apply_rotary_half
 
-# The three thresholds below come from a ROOFLINE MODEL, not from a chip:
-# scripts/flash_crossover.py takes `cost_analysis()` of programs compiled on
-# the CPU and places their FLOPs and bytes on the v5e's published peaks
-# (197 TFLOP/s bf16, 819 GB/s). One chip timing stands behind the first
-# (below); none behind the other two yet (PERF.md): re-derive them from
-# ledger rows when those exist.
+# Of the three thresholds below one has a chip reading behind it, at one
+# length; the other two were set from a roofline model of CPU-compiled
+# programs (scripts since deleted) and are NOT measured: no cell decodes
+# through the flash-decode kernel yet (PERF.md section 7, cells 0, 2, 5).
 
 # Sequence length at or above which `attn_impl="auto"` switches from the
 # dense einsum to the Pallas flash kernel (O(N) memory vs dense's O(N^2)
-# score tensors). Modeled: dense attention is bandwidth-bound from seq 256
-# up (score chain 212 MB @256 -> 4.5 GB @1280 vs flash's tiled 10 -> 137
-# MB), but op-level counting can't resolve the sub-1k region (fusion may
-# keep short score chains out of HBM), so the default is the largest
-# bench-grid point that still auto-selects flash for the flagship 1280.
-# Overridable per model (attn_impl=) or by rebinding this constant; an
-# on-chip wall-clock A/B (`scripts/pallas_onchip.py`) is the final decider.
-# Measured at 1280 only (one v5e, the flagship train step, PERF.md PR 26):
-# flash 33.9k tokens/s, dense 30.7k; lengths below it are not measured.
+# score tensors). Measured at 1280 only (one v5e, `flagship.train`;
+# ledger, PR 26, and PERF.md section 6): flash 33,854 tokens/s, dense
+# 30,719. Lengths between 256 and 1280 are not measured; 1024 is the
+# largest power of two that still selects flash for the flagship.
+# Overridable per model (attn_impl=) or by rebinding this constant.
 AUTO_FLASH_MIN_SEQ = 1024
 
 # Cache length at or above which `attn_impl="auto"` runs the CACHED decode
 # path through the Pallas flash-decode kernel (ops/pallas_decode.py) instead
-# of dense attention over the whole [B, H, max_len, D] cache. Modeled (same
-# script): one decode step's K/V reads cross at max_len 512 — below it the
-# per-kernel overhead charge beats the saved reads at expected live length
-# max_len/2; at the flagship cache (1281) flash-decode halves the average
-# K/V reads and cuts them ~3x for a freshly-admitted continuous-batching
-# slot still at its text prefix.
+# of dense attention over the whole [B, H, max_len, D] cache. Not measured:
+# the argument is that the kernel reads only each row's live K/V blocks
+# (half the cache on average, a third for a freshly admitted slot still at
+# its text prefix) against a per-call charge that wins below some length.
 AUTO_FLASH_DECODE_MIN_LEN = 512
 
 # KV tile width for POLICY-sparse flash decode (the per-row block bitmap in
-# ops/pallas_decode.py:block_sparse_flash_decode_attention). Modeled
-# (scripts/flash_crossover.py --sparse sweep): the skip fraction a policy
-# can express falls with tile width (an axial row policy at the flagship
-# cache keeps 48% of 64-wide tiles live but 60% of 128-wide and 79% of
-# 256-wide — every tile a single live position touches is read whole),
-# while the per-tile grid charge grows as tiles shrink: on the v5e roofline
-# a 32-wide sweep is SLOWER than plain length-skip flash at 128. 128 is the
-# knee: near-minimal modeled step time (25.4 us vs 24.7 at 256) while
-# capturing ~72% of the reachable byte savings, and it matches
-# `flash_decode_attention`'s default block_k — so the all-ones bitmap keeps
+# ops/pallas_decode.py:block_sparse_flash_decode_attention). Not measured.
+# The trade: the skip fraction a policy can express falls with tile width
+# (every tile a single live position touches is read whole: an axial-row
+# policy at the flagship cache keeps 48% of 64-wide tiles live, 60% of
+# 128-wide, 79% of 256-wide; arithmetic on the masks), while the per-tile
+# grid charge grows as tiles shrink. 128 matches
+# `flash_decode_attention`'s default block_k, so the all-ones bitmap keeps
 # BIT-IDENTITY with the dense-causal flash path (same tile boundaries, same
 # accumulation order), the serving stack's parity pin. Overridable per model
 # (decode_sparse_block=); must divide into whole pages on the paged "kernel"
 # impl (page_size | block).
 DECODE_SPARSE_BLOCK = 128
-
-
-def _cache_write(buf: jnp.ndarray, val: jnp.ndarray, index, layer=None) -> jnp.ndarray:
-    """Write val [B,H,n,D] into buf [B,H,S,D] at sequence position `index`
-    (n = 1 for single-token decode, larger for prefill chunks). The scale
-    leaves take the same write one rank lower: val [B,H,n] into [B,H,S].
-
-    `index` is either a scalar (the whole batch sits at one position — the
-    micro-batch decode scan) or a [B] vector (each row sits at its OWN
-    position — the continuous-batching slot cache, where rows were admitted
-    at different times).
-
-    `layer` (the scan executor's traced layer index) says `buf` is the
-    depth-stacked leaf [L,B,H,S,D]: the same n positions are written at
-    `buf[layer]`, in place, and the whole stack comes back."""
-    tail = (0,) * (val.ndim - 3)  # (0,) for K/V, () for their scales
-    with jax.named_scope("cache_write"):
-        val = val.astype(buf.dtype)
-        if layer is not None:
-            val = val[None]
-        if jnp.ndim(index) == 0:
-            start = (0, 0, index) + tail
-            return lax.dynamic_update_slice(
-                buf, val, start if layer is None else (layer,) + start
-            )
-        if layer is None:
-            return jax.vmap(
-                lambda b, v, i: lax.dynamic_update_slice(b, v, (0, i) + tail)
-            )(buf, val, index)
-        return jax.vmap(
-            lambda b, v, i: lax.dynamic_update_slice(b, v, (layer, 0, i) + tail),
-            in_axes=(1, 1, 0), out_axes=1,
-        )(buf, val, index)
-
-
-def _cache_view(buf: jnp.ndarray, layer) -> jnp.ndarray:
-    """This layer's K/V (or scales, or page pool) as attention reads it: the
-    leaf itself, or `buf[layer]` of the scan executor's depth-stacked one."""
-    if layer is None:
-        return buf
-    with jax.named_scope("cache_read"):
-        return lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
 
 
 def _kv_quantize(x: jnp.ndarray):
@@ -398,7 +346,7 @@ class Attention(nn.Module):
             # leaves (index, page_table, block_bitmap) arrive as the
             # layer's own.
             index = cache["index"]
-            layer = cache.get("layer")
+            layer = cache.get(decode_cache.LAYER)
             per_row = jnp.ndim(index) == 1
             paged = "page_table" in cache
             if rotary is not None:
@@ -415,52 +363,24 @@ class Attention(nn.Module):
             # attention reads), carry per-(position, head) fp32 scales in
             # sibling leaves; q stays full precision
             quant = "k_scale" in cache
+            if quant:
+                (qk, k_sc), (qv, v_sc) = _kv_quantize(k), _kv_quantize(v)
+                chunk = {"k": qk, "v": qv, "k_scale": k_sc, "v_scale": v_sc}
+            else:
+                chunk = {"k": k, "v": v}
+            # the chunk written at the cache's index (lanes or pages, a
+            # leaf or a stack at [layer]); max_len is the virtual
+            # contiguous length, the slotted cache's (total_seq_len + 1),
+            # so dense/flash see identical shapes on both stores
+            written, max_len = decode_cache.write(cache, chunk, self.seq_len + 1)
+            pt = cache.get("page_table")
+            # this layer's K/V as the reads below take it
+            ck, cv = (decode_cache.view(written[x], layer) for x in ("k", "v"))
             cks = cvs = None
             if quant:
-                qk, k_sc = _kv_quantize(k)
-                qv, v_sc = _kv_quantize(v)
-            else:
-                qk, qv = k, v
-            if paged:
-                assert per_row, "paged caches always carry per-row indices"
-                pt = cache["page_table"]
-                page_size = cache["k"].shape[-2]
-                # virtual contiguous length == the slotted cache's max_len
-                # (total_seq_len + 1): gather crops to it so dense/flash see
-                # byte-identical shapes on both layouts
-                max_len = min(pt.shape[-1] * page_size, self.seq_len + 1)
-                pos = jnp.minimum(
-                    index[:, None] + jnp.arange(n), max_len - 1
-                )  # [B, n]; finished rows clamp to the spare slot like the
-                # slotted dynamic_update_slice does
-                page = jnp.take_along_axis(pt, pos // page_size, axis=1)
-                off = pos % page_size
-                # [B, n] pages and offsets, under the stack's [layer]
-                kv_at = (page, slice(None), off, slice(None))
-                if layer is not None:
-                    kv_at = (layer,) + kv_at
-                sc_at = kv_at[:-1]
-                with jax.named_scope("cache_write"):
-                    new_k = cache["k"].at[kv_at].set(
-                        qk.transpose(0, 2, 1, 3).astype(cache["k"].dtype)
-                    )
-                    new_v = cache["v"].at[kv_at].set(
-                        qv.transpose(0, 2, 1, 3).astype(cache["v"].dtype)
-                    )
-                    if quant:
-                        new_ks = cache["k_scale"].at[sc_at].set(k_sc.transpose(0, 2, 1))
-                        new_vs = cache["v_scale"].at[sc_at].set(v_sc.transpose(0, 2, 1))
-            else:
-                new_k = _cache_write(cache["k"], qk, index, layer)
-                new_v = _cache_write(cache["v"], qv, index, layer)
-                if quant:
-                    new_ks = _cache_write(cache["k_scale"], k_sc, index, layer)
-                    new_vs = _cache_write(cache["v_scale"], v_sc, index, layer)
-                max_len = new_k.shape[-2]
-            # this layer's K/V as the reads below take it
-            ck, cv = _cache_view(new_k, layer), _cache_view(new_v, layer)
-            if quant:
-                cks, cvs = _cache_view(new_ks, layer), _cache_view(new_vs, layer)
+                cks, cvs = (
+                    decode_cache.view(written[x], layer) for x in ("k_scale", "v_scale")
+                )
             # policy block bitmap ([B, nb] int32, nb = ceil(max_len /
             # decode_sparse_block), nonzero = KV tile may be read): traced
             # DATA riding the cache pytree (models/dalle.py threads it from
@@ -600,16 +520,14 @@ class Attention(nn.Module):
                     out = dense_attention(
                         q, gk, gv, mask=mask, stable=self.stable
                     )
-            new_cache = {"k": new_k, "v": new_v, "index": index + n}
-            if quant:
-                new_cache["k_scale"] = new_ks
-                new_cache["v_scale"] = new_vs
+            # structural round-trip: the cache that comes back has the
+            # leaves it came with, side leaves included (the callers strip
+            # them from both layouts alike)
+            new_cache = {"k": written["k"], "v": written["v"], "index": index + n}
+            new_cache.update(written)  # + an int8 store's scale leaves
             if paged:
                 new_cache["page_table"] = pt
             if sparse:
-                # structural round-trip: the cache that comes back has the
-                # leaves it came with (the callers strip page_table and
-                # block_bitmap from both executors' caches alike)
                 new_cache["block_bitmap"] = bitmap
         else:
             if rotary is not None:

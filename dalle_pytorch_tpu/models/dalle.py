@@ -44,7 +44,8 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from dalle_pytorch_tpu.models.transformer import Transformer, DivideMax, make_decode_cache
+from dalle_pytorch_tpu.models import decode_cache
+from dalle_pytorch_tpu.models.transformer import Transformer, DivideMax
 from dalle_pytorch_tpu.obs import scopes
 from dalle_pytorch_tpu.obs.tracing import host_span
 from dalle_pytorch_tpu.ops.sampling import top_k_filter, gumbel_sample
@@ -265,9 +266,9 @@ class DALLE(nn.Module):
 
     def _fused_forward_loss(self, out, text, image, seq_len):
         """Forward-mode split CE via the vocab-chunked kernel — identical
-        numerics to the dense path (tests/test_dalle.py parity), ~20 GB
-        less HBM traffic per flagship step by the op-level count of
-        scripts/hbm_model.py (a model, not a chip measurement)."""
+        numerics to the dense path (tests/test_dalle.py parity) without
+        the [B, N, V] logits. Not measured on the chip: `flagship.train`
+        runs the dense loss (`loss_pct.train` 4.11, PERF.md section 5)."""
         from dalle_pytorch_tpu.ops.losses import chunked_masked_ce, split_weighted_mean
 
         h, kernel, bias, offsetted_image = self._fused_head(out, image)
@@ -532,9 +533,9 @@ class DALLE(nn.Module):
             ]
         seq = jnp.concatenate([tokens, img.astype(tokens.dtype)], axis=1)
         image_pos = jnp.asarray(image_pos, jnp.int32)
-        cache = dict(cache)
-        ring_end = text_len + image_pos  # [B] global resume positions
-        cache = _with_ring_end(cache, ring_end, self.executor, self.depth)
+        # [B] global resume positions: `shift_with_ring` rebuilds each row's
+        # rings below its own (the output cache comes back without the leaf)
+        cache = decode_cache.with_side(cache, ring_end=text_len + image_pos)
         out, cache = self.transformer(seq, cache=cache)
         # pending logits for per-row position k live at global position
         # text_len - 1 + k (the output of feeding token k-1; k = 0 reads
@@ -546,25 +547,24 @@ class DALLE(nn.Module):
         return row, cache
 
 
-def init_decode_cache(model: DALLE, batch: int, dtype=None) -> dict:
-    """Fixed-shape decode cache for `generate_images_cached`.
-
-    Sized total_seq_len + 1 so the scan can uniformly feed every sampled
-    token (the final write lands in the spare slot and its logits are
-    discarded)."""
-    return make_decode_cache(
-        depth=model.depth,
-        batch=batch,
-        max_len=model.total_seq_len + 1,
-        heads=model.heads,
-        dim_head=model.dim_head,
-        dim=model.dim,
-        image_fmap_size=model.image_fmap_size,
-        shift_tokens=model.shift_tokens,
-        dtype=model.dtype if dtype is None else dtype,
-        executor=model.executor,
+def _init_cache(model: DALLE, batch: int, dtype=None, **store) -> dict:
+    """A zeroed decode cache of `model`'s geometry, sized total_seq_len + 1
+    so a sampler can uniformly feed every sampled token (the final write
+    lands in the spare slot and its logits are discarded). The trunk says
+    which layout its executor takes; `store` is `per_row=` / `pages=`."""
+    trunk = Transformer(**model.transformer_kwargs(), parent=None)
+    return trunk.init_cache(
+        batch,
+        model.total_seq_len + 1,
+        model.dtype if dtype is None else dtype,
         kv_dtype=getattr(model, "kv_dtype", None),
+        **store,
     )
+
+
+def init_decode_cache(model: DALLE, batch: int, dtype=None) -> dict:
+    """Fixed-shape lockstep decode cache for `generate_images_cached`."""
+    return _init_cache(model, batch, dtype)
 
 
 def _primed_image_tokens(
@@ -933,20 +933,7 @@ def init_slot_state(model: DALLE, max_batch: int, dtype=None) -> dict:
     """
     s = int(max_batch)
     return {
-        "cache": make_decode_cache(
-            depth=model.depth,
-            batch=s,
-            max_len=model.total_seq_len + 1,
-            heads=model.heads,
-            dim_head=model.dim_head,
-            dim=model.dim,
-            image_fmap_size=model.image_fmap_size,
-            shift_tokens=model.shift_tokens,
-            dtype=model.dtype if dtype is None else dtype,
-            executor=model.executor,
-            per_row=True,
-            kv_dtype=getattr(model, "kv_dtype", None),
-        ),
+        "cache": _init_cache(model, s, dtype, per_row=True),
         # pending next-position logits per slot (what the next sample
         # draws from; written by prefill, refreshed every decode step)
         "row": jnp.zeros((s, model.total_tokens), jnp.float32),
@@ -1014,72 +1001,71 @@ def prefill_into_slots(
     )
 
 
+def _prefill_rows(model, variables, texts, block_bitmap=None):
+    """The batch-R text prefill the admission programs run, the same
+    `decode_prefill` as the micro-batch sampler's: (pending logits [R, V],
+    the fresh cache). `block_bitmap` rides the fresh cache through the
+    forward and is stripped from what comes back: the persistent state
+    carries no bitmap leaves."""
+    cache0 = init_decode_cache(model, texts.shape[0])
+    if block_bitmap is not None:
+        cache0 = decode_cache.with_side(cache0, block_bitmap=block_bitmap)
+    rows, cache_r = model.apply(
+        variables, texts, cache0, method=DALLE.decode_prefill
+    )
+    return rows, decode_cache.without_side(cache_r, decode_cache.BLOCK_BITMAP)
+
+
+def _resume_rows(model, variables, texts, img_tokens, img_pos):
+    """`_prefill_rows` for rows that arrive mid-decode (`decode_resume`)."""
+    return model.apply(
+        variables, texts, img_tokens, img_pos,
+        init_decode_cache(model, texts.shape[0]),
+        method=DALLE.decode_resume,
+    )
+
+
+def _admit_rows(model, state, cache, rows, slots, seeds, temperatures, keep_ks,
+                img_tokens=None, img_pos=None):
+    """The state after admitting R rows into `slots`: the scattered `cache`,
+    each row's pending logits, its token buffer (zeros, or a resumed row's
+    generated prefix) and its per-slot control state. Padded rows repeat a
+    real (slot, value) pair, so whichever duplicate lands last is
+    identical."""
+    out = dict(state)
+    out["cache"] = cache
+    row_buf = state["row"]
+    tok_buf = state["img_tokens"]
+    fresh = img_tokens is None  # a prefill: no tokens yet, position 0
+    zero_row = jnp.zeros((1, model.image_seq_len), jnp.int32) if fresh else None
+    for r in range(rows.shape[0]):
+        row_buf = jax.lax.dynamic_update_slice(
+            row_buf, rows[r : r + 1].astype(row_buf.dtype), (slots[r], 0)
+        )
+        tok_buf = jax.lax.dynamic_update_slice(
+            tok_buf, zero_row if fresh else img_tokens[r : r + 1], (slots[r], 0)
+        )
+    out["row"] = row_buf
+    out["img_tokens"] = tok_buf
+    out["img_pos"] = state["img_pos"].at[slots].set(0 if fresh else img_pos)
+    out["active"] = state["active"].at[slots].set(True)
+    out["seeds"] = state["seeds"].at[slots].set(seeds)
+    out["temps"] = state["temps"].at[slots].set(temperatures)
+    out["keep_k"] = state["keep_k"].at[slots].set(keep_ks)
+    return out
+
+
 @_program("slots_prefill")
 def _prefill_slots_builder(model, key):
-    prefill_batch = key[0]
-    sparse = "sparse" in key
-    batch_axis = 1 if model.executor == "scan" else 0
+    del key  # (prefill batch[, "sparse"]): the arguments' shapes say both
 
     def fn(variables, state, texts, slots, seeds, temperatures, keep_ks,
-           *sparse_args):
-        cache0 = init_decode_cache(model, prefill_batch)
-        if sparse:
-            (block_bitmap,) = sparse_args
-            cache0 = _with_block_bitmap(
-                cache0, block_bitmap, model.executor, model.depth
-            )
-        rows, cache_r = model.apply(
-            variables,
-            texts,
-            cache0,
-            method=DALLE.decode_prefill,
+           *block_bitmap):
+        rows, cache_r = _prefill_rows(model, variables, texts, *block_bitmap)
+        cache = decode_cache.scatter_rows(state["cache"], cache_r, slots)
+        return _admit_rows(
+            model, state, cache, rows, slots, seeds, temperatures, keep_ks
         )
-        if sparse:
-            # the persistent slot cache carries no bitmap leaves — strip
-            # the round-tripped ones before the structural scatter below
-            cache_r = _without_block_bitmap(cache_r, model.executor)
-
-        def write(path, s_leaf, p_leaf):
-            # `index` leaves are not scattered: the chunk step stamps every
-            # layer's index from the per-slot `img_pos` (single source of
-            # truth for position — see set_decode_cache_index)
-            if getattr(path[-1], "key", None) == "index":
-                return s_leaf
-            out = s_leaf
-            for r in range(prefill_batch):
-                p_row = jax.lax.dynamic_slice_in_dim(
-                    p_leaf, r, 1, axis=batch_axis
-                )
-                out = jax.lax.dynamic_update_slice_in_dim(
-                    out, p_row.astype(out.dtype), slots[r], axis=batch_axis
-                )
-            return out
-
-        new_cache = jax.tree_util.tree_map_with_path(
-            write, state["cache"], cache_r
-        )
-        out = dict(state)
-        out["cache"] = new_cache
-        row_buf = state["row"]
-        tok_buf = state["img_tokens"]
-        zero_row = jnp.zeros((1, model.image_seq_len), jnp.int32)
-        for r in range(prefill_batch):
-            row_buf = jax.lax.dynamic_update_slice(
-                row_buf, rows[r : r + 1].astype(row_buf.dtype), (slots[r], 0)
-            )
-            tok_buf = jax.lax.dynamic_update_slice(
-                tok_buf, zero_row, (slots[r], 0)
-            )
-        out["row"] = row_buf
-        out["img_tokens"] = tok_buf
-        # scatter-with-duplicates is safe here: padded rows repeat a real
-        # (slot, value) pair, so whichever duplicate lands last is identical
-        out["img_pos"] = state["img_pos"].at[slots].set(0)
-        out["active"] = state["active"].at[slots].set(True)
-        out["seeds"] = state["seeds"].at[slots].set(seeds)
-        out["temps"] = state["temps"].at[slots].set(temperatures)
-        out["keep_k"] = state["keep_k"].at[slots].set(keep_ks)
-        return out
 
     return fn
 
@@ -1124,57 +1110,16 @@ def resume_into_slots(
 
 @_program("slots_resume")
 def _resume_slots_builder(model, key):
-    (prefill_batch,) = key
-    batch_axis = 1 if model.executor == "scan" else 0
+    del key  # (prefill batch,)
 
     def fn(variables, state, texts, img_tokens, img_pos, slots, seeds,
            temperatures, keep_ks):
-        rows, cache_r = model.apply(
-            variables,
-            texts,
-            img_tokens,
-            img_pos,
-            init_decode_cache(model, prefill_batch),
-            method=DALLE.decode_resume,
+        rows, cache_r = _resume_rows(model, variables, texts, img_tokens, img_pos)
+        cache = decode_cache.scatter_rows(state["cache"], cache_r, slots)
+        return _admit_rows(
+            model, state, cache, rows, slots, seeds, temperatures, keep_ks,
+            img_tokens, img_pos,
         )
-
-        def write(path, s_leaf, p_leaf):
-            # `index` leaves are not scattered: the chunk step stamps
-            # every layer's index from the per-slot `img_pos`
-            if getattr(path[-1], "key", None) == "index":
-                return s_leaf
-            out = s_leaf
-            for r in range(prefill_batch):
-                p_row = jax.lax.dynamic_slice_in_dim(
-                    p_leaf, r, 1, axis=batch_axis
-                )
-                out = jax.lax.dynamic_update_slice_in_dim(
-                    out, p_row.astype(out.dtype), slots[r], axis=batch_axis
-                )
-            return out
-
-        new_cache = jax.tree_util.tree_map_with_path(
-            write, state["cache"], cache_r
-        )
-        out = dict(state)
-        out["cache"] = new_cache
-        row_buf = state["row"]
-        tok_buf = state["img_tokens"]
-        for r in range(prefill_batch):
-            row_buf = jax.lax.dynamic_update_slice(
-                row_buf, rows[r : r + 1].astype(row_buf.dtype), (slots[r], 0)
-            )
-            tok_buf = jax.lax.dynamic_update_slice(
-                tok_buf, img_tokens[r : r + 1], (slots[r], 0)
-            )
-        out["row"] = row_buf
-        out["img_tokens"] = tok_buf
-        out["img_pos"] = state["img_pos"].at[slots].set(img_pos)
-        out["active"] = state["active"].at[slots].set(True)
-        out["seeds"] = state["seeds"].at[slots].set(seeds)
-        out["temps"] = state["temps"].at[slots].set(temperatures)
-        out["keep_k"] = state["keep_k"].at[slots].set(keep_ks)
-        return out
 
     return fn
 
@@ -1252,7 +1197,6 @@ def _make_chunk_fn(model, chunk, paged, sparse=False):
     injects it into every layer's attention cache for the duration of the
     scan, and strips it from the result (the table is host state, not part
     of the donated device state)."""
-    from dalle_pytorch_tpu.models.transformer import set_decode_cache_index
     from dalle_pytorch_tpu.ops.sampling import (
         gumbel_sample_per_row, per_row_step_keys, top_k_filter_per_row,
     )
@@ -1287,9 +1231,7 @@ def _make_chunk_fn(model, chunk, paged, sparse=False):
 
             # stamp every layer's cache index from the per-slot position,
             # then run one decode step at per-row positions
-            cache = set_decode_cache_index(
-                cache, img_pos + text_len, model.executor
-            )
+            cache = decode_cache.set_index(cache, img_pos + text_len)
             new_row, cache = model.apply(
                 variables, sample, img_pos, cache,
                 method=DALLE.decode_image_step,
@@ -1303,44 +1245,20 @@ def _make_chunk_fn(model, chunk, paged, sparse=False):
         )
         return jax.lax.scan(step, carry, None, length=chunk)[0]
 
-    if paged:
-        def fn(variables, state, page_table, *sparse_args):
-            cache0 = _with_page_table(
-                state["cache"], page_table, model.executor, model.depth
-            )
-            if sparse:
-                (block_bitmap,) = sparse_args
-                cache0 = _with_block_bitmap(
-                    cache0, block_bitmap, model.executor, model.depth
-                )
-            cache, row, img_tokens, img_pos = run(variables, state, cache0)
-            if sparse:
-                cache = _without_block_bitmap(cache, model.executor)
-            return {
-                **state,
-                "cache": _without_page_table(cache, model.executor),
-                "row": row,
-                "img_tokens": img_tokens,
-                "img_pos": img_pos,
-            }
-    else:
-        def fn(variables, state, *sparse_args):
-            cache0 = state["cache"]
-            if sparse:
-                (block_bitmap,) = sparse_args
-                cache0 = _with_block_bitmap(
-                    cache0, block_bitmap, model.executor, model.depth
-                )
-            cache, row, img_tokens, img_pos = run(variables, state, cache0)
-            if sparse:
-                cache = _without_block_bitmap(cache, model.executor)
-            return {
-                **state,
-                "cache": cache,
-                "row": row,
-                "img_tokens": img_tokens,
-                "img_pos": img_pos,
-            }
+    def fn(variables, state, *side):
+        # the side leaves ride the cache through the scan and are stripped
+        # from the result: the persistent donated state keeps its shape
+        names = ("page_table",) if paged else ()
+        names += ("block_bitmap",) if sparse else ()
+        cache0 = decode_cache.with_side(state["cache"], **dict(zip(names, side)))
+        cache, row, img_tokens, img_pos = run(variables, state, cache0)
+        return {
+            **state,
+            "cache": decode_cache.without_side(cache, *names),
+            "row": row,
+            "img_tokens": img_tokens,
+            "img_pos": img_pos,
+        }
 
     return fn
 
@@ -1366,88 +1284,6 @@ _chunk_builder._donate_argnums = (1,)  # state
 # (ops/pallas_decode.py).
 
 
-def _with_page_table(cache, page_table, executor, depth):
-    """Inject the [B, n_pages] table into every layer's attention cache
-    (depth-stacked for the scan executor, which slices it per layer)."""
-    pt = jnp.asarray(page_table, jnp.int32)
-    if executor == "scan":
-        ptd = jnp.broadcast_to(pt, (depth,) + pt.shape)
-        return {**cache, "attn": {**cache["attn"], "page_table": ptd}}
-    return {
-        name: {**layer, "attn": {**layer["attn"], "page_table": pt}}
-        for name, layer in cache.items()
-    }
-
-
-def _with_block_bitmap(cache, bitmaps, executor, depth):
-    """Inject the per-layer decode-sparsity bitmaps [depth, B, nb] into
-    every layer's attention cache (same smuggling idiom as
-    `_with_page_table`; the scan executor slices its depth-stacked leaf
-    per layer). nb = ceil(max_len / decode_sparse_block); nonzero =
-    KV tile may be read. TRACED data — the serving policy re-derives the
-    table every chunk from each row's position without recompiling."""
-    bm = jnp.asarray(bitmaps, jnp.int32)
-    if executor == "scan":
-        return {**cache, "attn": {**cache["attn"], "block_bitmap": bm}}
-    return {
-        name: {
-            **layer,
-            "attn": {
-                **layer["attn"],
-                "block_bitmap": bm[int(name.split("_")[-1])],
-            },
-        }
-        for name, layer in cache.items()
-    }
-
-
-def _without_block_bitmap(cache, executor):
-    """Strip the bitmap leaves (the cache comes back with the leaves it
-    went in with: per layer from attention, in the layer scan's carry for
-    the scan executor) so the persistent donated state keeps its
-    bitmap-free shape — the policy table is host state, like the page
-    table."""
-    if executor == "scan":
-        attn = {k: v for k, v in cache["attn"].items() if k != "block_bitmap"}
-        return {**cache, "attn": attn}
-    return {
-        name: {
-            **layer,
-            "attn": {
-                k: v for k, v in layer["attn"].items() if k != "block_bitmap"
-            },
-        }
-        for name, layer in cache.items()
-    }
-
-
-def _with_ring_end(cache, ring_end, executor, depth):
-    """Inject the per-row resume window `ring_end` [B] into a decode
-    cache so `shift_with_ring` rebuilds rings per row (decode_resume).
-    Same smuggling idiom as `_with_page_table`; the transformer's output
-    cache comes back without the leaf (rebuilt per layer, or dropped after
-    the layer scan that carried it), so nothing strips it."""
-    re_ = jnp.asarray(ring_end, jnp.int32)
-    if executor == "scan":
-        return {**cache, "ring_end": jnp.broadcast_to(re_, (depth,) + re_.shape)}
-    return {name: {**layer, "ring_end": re_} for name, layer in cache.items()}
-
-
-def _without_page_table(cache, executor):
-    if executor == "scan":
-        attn = {k: v for k, v in cache["attn"].items() if k != "page_table"}
-        return {**cache, "attn": attn}
-    return {
-        name: {
-            **layer,
-            "attn": {
-                k: v for k, v in layer["attn"].items() if k != "page_table"
-            },
-        }
-        for name, layer in cache.items()
-    }
-
-
 def init_paged_slot_state(
     model: DALLE, max_batch: int, n_pages: int, page_size: int, dtype=None
 ) -> dict:
@@ -1455,23 +1291,10 @@ def init_paged_slot_state(
     `init_slot_state`, with K/V in a page pool instead of per-slot lanes.
     Page 0 is the serving layer's reserved garbage page (never allocated),
     so the pool must be sized n_pages >= usable pages + 1."""
-    from dalle_pytorch_tpu.models.transformer import make_paged_decode_cache
-
     s = int(max_batch)
     return {
-        "cache": make_paged_decode_cache(
-            depth=model.depth,
-            batch=s,
-            n_pages=int(n_pages),
-            page_size=int(page_size),
-            heads=model.heads,
-            dim_head=model.dim_head,
-            dim=model.dim,
-            image_fmap_size=model.image_fmap_size,
-            shift_tokens=model.shift_tokens,
-            dtype=model.dtype if dtype is None else dtype,
-            executor=model.executor,
-            kv_dtype=getattr(model, "kv_dtype", None),
+        "cache": _init_cache(
+            model, s, dtype, pages=(int(n_pages), int(page_size))
         ),
         "row": jnp.zeros((s, model.total_tokens), jnp.float32),
         "img_tokens": jnp.zeros((s, model.image_seq_len), jnp.int32),
@@ -1481,27 +1304,6 @@ def init_paged_slot_state(
         "temps": jnp.ones((s,), jnp.float32),
         "keep_k": jnp.ones((s,), jnp.int32),
     }
-
-
-def _extract_rings(cache_r, executor):
-    """Row-major token-shift-ring sidecar from a fresh prefill cache: the
-    part of a prefix's post-prefill state that is NOT page-addressable
-    (plus the pending logits, captured separately). Empty dict when the
-    model doesn't shift tokens."""
-    if executor == "scan":
-        return {
-            name: jnp.moveaxis(cache_r[name], 1, 0)  # [R, depth, fmap, dim]
-            for name in ("shift_attn", "shift_ff")
-            if name in cache_r
-        }
-    out = {}
-    for lname, layer in cache_r.items():
-        rings = {
-            n: layer[n] for n in ("shift_attn", "shift_ff") if n in layer
-        }
-        if rings:
-            out[lname] = rings
-    return out
 
 
 def prefill_into_slots_paged(
@@ -1561,111 +1363,20 @@ def prefill_into_slots_paged(
 
 @_program("slots_prefill_paged")
 def _prefill_slots_paged_builder(model, key):
-    prefill_batch, page_size, n_text_pages = key[:3]
-    sparse = "sparse" in key
-    batch_axis = 1 if model.executor == "scan" else 0
-
-    def block_of(p_leaf, r, j, last_axis=False):
-        """Row r's K/V slice for text block j, zero-padded to page_size
-        past the prefill cache's end (static shapes throughout).
-        `last_axis` addresses scale leaves ([.., H, max_len]; the
-        sequence axis is LAST, there is no head-dim axis after it)."""
-        row_kv = p_leaf[:, r] if batch_axis == 1 else p_leaf[r]
-        seq_ax = row_kv.ndim - (1 if last_axis else 2)
-        max_len = row_kv.shape[seq_ax]
-        lo = j * page_size
-        hi = min(lo + page_size, max_len)
-        blk = jax.lax.slice_in_dim(row_kv, lo, hi, axis=seq_ax)
-        if hi - lo < page_size:
-            pad = [(0, 0)] * row_kv.ndim
-            pad[seq_ax] = (0, page_size - (hi - lo))
-            blk = jnp.pad(blk, pad)
-        return blk
+    page_size = key[1]  # (prefill batch, page size, text pages[, "sparse"])
 
     def fn(variables, state, texts, slots, seeds, temperatures, keep_ks,
-           page_rows, partial_dst, *sparse_args):
-        cache0 = init_decode_cache(model, prefill_batch)
-        if sparse:
-            (block_bitmap,) = sparse_args
-            cache0 = _with_block_bitmap(
-                cache0, block_bitmap, model.executor, model.depth
-            )
-        rows, cache_r = model.apply(
-            variables,
-            texts,
-            cache0,
-            method=DALLE.decode_prefill,
+           page_rows, partial_dst, *block_bitmap):
+        rows, cache_r = _prefill_rows(model, variables, texts, *block_bitmap)
+        cache = decode_cache.scatter_rows(
+            state["cache"], cache_r, slots, pages=(page_rows, page_size, partial_dst)
         )
-        if sparse:
-            cache_r = _without_block_bitmap(cache_r, model.executor)
-
-        def write(path, s_leaf, p_leaf):
-            key_ = getattr(path[-1], "key", None)
-            if key_ == "index":
-                # stamped from per-slot img_pos every chunk step
-                return s_leaf
-            if key_ in ("k", "v", "k_scale", "v_scale"):
-                last_axis = key_.endswith("_scale")
-
-                def put(out, blk, page):
-                    if batch_axis == 1:
-                        idx = (0, page) + (0,) * (out.ndim - 2)
-                        return jax.lax.dynamic_update_slice(
-                            out, blk[:, None], idx
-                        )
-                    idx = (page,) + (0,) * (out.ndim - 1)
-                    return jax.lax.dynamic_update_slice(out, blk[None], idx)
-
-                out = s_leaf
-                for r in range(prefill_batch):
-                    for j in range(n_text_pages):
-                        blk = block_of(p_leaf, r, j, last_axis).astype(
-                            out.dtype
-                        )
-                        out = put(out, blk, page_rows[r, j])
-                    # prefix-cache snapshot of the divergence block (page 0
-                    # = not registering; the garbage page absorbs it)
-                    blk = block_of(
-                        p_leaf, r, n_text_pages - 1, last_axis
-                    ).astype(out.dtype)
-                    out = put(out, blk, partial_dst[r])
-                return out
-            # shift rings: per-slot row scatter, same as the slotted path
-            out = s_leaf
-            for r in range(prefill_batch):
-                p_row = jax.lax.dynamic_slice_in_dim(
-                    p_leaf, r, 1, axis=batch_axis
-                )
-                out = jax.lax.dynamic_update_slice_in_dim(
-                    out, p_row.astype(out.dtype), slots[r], axis=batch_axis
-                )
-            return out
-
-        new_cache = jax.tree_util.tree_map_with_path(
-            write, state["cache"], cache_r
+        out = _admit_rows(
+            model, state, cache, rows, slots, seeds, temperatures, keep_ks
         )
-        out = dict(state)
-        out["cache"] = new_cache
-        row_buf = state["row"]
-        tok_buf = state["img_tokens"]
-        zero_row = jnp.zeros((1, model.image_seq_len), jnp.int32)
-        for r in range(prefill_batch):
-            row_buf = jax.lax.dynamic_update_slice(
-                row_buf, rows[r : r + 1].astype(row_buf.dtype), (slots[r], 0)
-            )
-            tok_buf = jax.lax.dynamic_update_slice(
-                tok_buf, zero_row, (slots[r], 0)
-            )
-        out["row"] = row_buf
-        out["img_tokens"] = tok_buf
-        out["img_pos"] = state["img_pos"].at[slots].set(0)
-        out["active"] = state["active"].at[slots].set(True)
-        out["seeds"] = state["seeds"].at[slots].set(seeds)
-        out["temps"] = state["temps"].at[slots].set(temperatures)
-        out["keep_k"] = state["keep_k"].at[slots].set(keep_ks)
         sidecar = {
             "row": rows.astype(jnp.float32),
-            "rings": _extract_rings(cache_r, model.executor),
+            "rings": decode_cache.extract_rings(cache_r),
         }
         return out, sidecar
 
@@ -1719,96 +1430,18 @@ def resume_into_slots_paged(
 
 @_program("slots_resume_paged")
 def _resume_slots_paged_builder(model, key):
-    prefill_batch, page_size, n_pages_row = key
-    batch_axis = 1 if model.executor == "scan" else 0
-
-    def block_of(p_leaf, r, j, last_axis=False):
-        """Row r's K/V slice for block j, zero-padded to page_size past
-        the resume cache's end (static shapes throughout). `last_axis`
-        addresses scale leaves (sequence axis LAST)."""
-        row_kv = p_leaf[:, r] if batch_axis == 1 else p_leaf[r]
-        seq_ax = row_kv.ndim - (1 if last_axis else 2)
-        max_len = row_kv.shape[seq_ax]
-        lo = j * page_size
-        hi = min(lo + page_size, max_len)
-        if hi <= lo:
-            shape = list(row_kv.shape)
-            shape[seq_ax] = page_size
-            return jnp.zeros(shape, row_kv.dtype)
-        blk = jax.lax.slice_in_dim(row_kv, lo, hi, axis=seq_ax)
-        if hi - lo < page_size:
-            pad = [(0, 0)] * row_kv.ndim
-            pad[seq_ax] = (0, page_size - (hi - lo))
-            blk = jnp.pad(blk, pad)
-        return blk
+    page_size = key[1]  # (prefill batch, page size, pages a row)
 
     def fn(variables, state, texts, img_tokens, img_pos, slots, seeds,
            temperatures, keep_ks, page_rows):
-        rows, cache_r = model.apply(
-            variables,
-            texts,
-            img_tokens,
-            img_pos,
-            init_decode_cache(model, prefill_batch),
-            method=DALLE.decode_resume,
+        rows, cache_r = _resume_rows(model, variables, texts, img_tokens, img_pos)
+        cache = decode_cache.scatter_rows(
+            state["cache"], cache_r, slots, pages=(page_rows, page_size, None)
         )
-
-        def write(path, s_leaf, p_leaf):
-            key_ = getattr(path[-1], "key", None)
-            if key_ == "index":
-                return s_leaf
-            if key_ in ("k", "v", "k_scale", "v_scale"):
-                last_axis = key_.endswith("_scale")
-                out = s_leaf
-                for r in range(prefill_batch):
-                    for j in range(n_pages_row):
-                        blk = block_of(p_leaf, r, j, last_axis).astype(
-                            out.dtype
-                        )
-                        if batch_axis == 1:
-                            idx = (0, page_rows[r, j]) + (0,) * (out.ndim - 2)
-                            out = jax.lax.dynamic_update_slice(
-                                out, blk[:, None], idx
-                            )
-                        else:
-                            idx = (page_rows[r, j],) + (0,) * (out.ndim - 1)
-                            out = jax.lax.dynamic_update_slice(
-                                out, blk[None], idx
-                            )
-                return out
-            # shift rings: per-slot row scatter, same as the slotted path
-            out = s_leaf
-            for r in range(prefill_batch):
-                p_row = jax.lax.dynamic_slice_in_dim(
-                    p_leaf, r, 1, axis=batch_axis
-                )
-                out = jax.lax.dynamic_update_slice_in_dim(
-                    out, p_row.astype(out.dtype), slots[r], axis=batch_axis
-                )
-            return out
-
-        new_cache = jax.tree_util.tree_map_with_path(
-            write, state["cache"], cache_r
+        return _admit_rows(
+            model, state, cache, rows, slots, seeds, temperatures, keep_ks,
+            img_tokens, img_pos,
         )
-        out = dict(state)
-        out["cache"] = new_cache
-        row_buf = state["row"]
-        tok_buf = state["img_tokens"]
-        for r in range(prefill_batch):
-            row_buf = jax.lax.dynamic_update_slice(
-                row_buf, rows[r : r + 1].astype(row_buf.dtype), (slots[r], 0)
-            )
-            tok_buf = jax.lax.dynamic_update_slice(
-                tok_buf, img_tokens[r : r + 1], (slots[r], 0)
-            )
-        out["row"] = row_buf
-        out["img_tokens"] = tok_buf
-        out["img_pos"] = state["img_pos"].at[slots].set(img_pos)
-        out["active"] = state["active"].at[slots].set(True)
-        out["seeds"] = state["seeds"].at[slots].set(seeds)
-        out["temps"] = state["temps"].at[slots].set(temperatures)
-        out["keep_k"] = state["keep_k"].at[slots].set(keep_ks)
-        return out
 
     return fn
 
@@ -1872,40 +1505,14 @@ def admit_cached_prefix(
 @_program("prefix_admit")
 def _admit_prefix_builder(model, key):
     (page_size,) = key
-    batch_axis = 1 if model.executor == "scan" else 0
-    page_axis = batch_axis  # pages leaf: optional depth axis, then pages
     has_partial = (model.text_seq_len + 1) % page_size != 0
 
     def fn(state, slot, sidecar, seed, temperature, keep_k,
            partial_src, partial_dst):
-        rings = sidecar["rings"]
-
-        def upd(path, leaf):
-            key_ = getattr(path[-1], "key", None)
-            if key_ in ("k", "v", "k_scale", "v_scale"):
-                if not has_partial:
-                    return leaf
-                blk = jax.lax.dynamic_slice_in_dim(
-                    leaf, partial_src, 1, axis=page_axis
-                )
-                return jax.lax.dynamic_update_slice_in_dim(
-                    leaf, blk, partial_dst, axis=page_axis
-                )
-            if key_ in ("shift_attn", "shift_ff"):
-                node = rings
-                for p in path:
-                    node = node[p.key]
-                return jax.lax.dynamic_update_slice_in_dim(
-                    leaf,
-                    jnp.expand_dims(node, batch_axis).astype(leaf.dtype),
-                    slot,
-                    axis=batch_axis,
-                )
-            return leaf  # index: stamped from img_pos every chunk
-
         out = dict(state)
-        out["cache"] = jax.tree_util.tree_map_with_path(
-            upd, state["cache"]
+        out["cache"] = decode_cache.restore_prefix(
+            state["cache"], sidecar["rings"], slot,
+            page_copy=(partial_src, partial_dst) if has_partial else None,
         )
         out["row"] = jax.lax.dynamic_update_slice(
             state["row"],

@@ -1,0 +1,465 @@
+"""The decode cache: the one module that knows how it is laid out.
+
+A decode cache is a pytree of fixed-shape leaves. ONE layer holds
+
+    {"attn": {"k", "v", "index" [, "k_scale", "v_scale"]}
+     [, "shift_attn", "shift_ff"]}
+
+  * `k`, `v`: K/V lanes [B, H, L, dh], or a page pool [P, H, page, dh]
+    shared by all rows (a host-side page table maps each row's logical
+    blocks to pages; page 0 is the serving layer's garbage page);
+  * `index`: the next position to write: a scalar (the batch decodes in
+    lockstep) or [B] (every row at its OWN position: the continuous-batching
+    slot cache);
+  * `k_scale`, `v_scale`: float32 per-(position, head) scales of an int8
+    K/V store, the K/V shape without its last axis;
+  * `shift_attn`, `shift_ff`: the token-shift rings [B, fmap, dim].
+
+and a cache holds `depth` layers in one of two LAYOUTS:
+
+  * PER_LAYER: a dict of `depth` such layers under `layer_{i}` (the unrolled
+    executor walks them in Python);
+  * STACKED: the same leaves under a leading `depth` axis (they ride the
+    scan executor's carry; each layer writes and reads at its own index).
+
+The layout is read off the tree (a stacked cache has `attn` at its top), so
+nothing below takes an executor or a depth. Per-call SIDE leaves ride the
+tree while a program runs and are stripped from what it returns:
+`page_table` [B, n_pages] and `block_bitmap` [depth, B, nb] inside `attn`,
+`ring_end` [B] beside it.
+
+Arrows point one way: attention, transformer, dalle, serving/ and
+parallel/serving_partition.py import this module; it imports none of them.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+STACKED, PER_LAYER = "stacked", "per_layer"
+
+ATTN = "attn"
+K, V, K_SCALE, V_SCALE, INDEX = "k", "v", "k_scale", "v_scale", "index"
+SCALE_KEYS = (K_SCALE, V_SCALE)
+KV_KEYS = (K, V) + SCALE_KEYS
+RING_KEYS = ("shift_attn", "shift_ff")
+PAGE_TABLE, BLOCK_BITMAP, RING_END = "page_table", "block_bitmap", "ring_end"
+# the stacked layout's layer coordinate, beside the stacked K/V a layer's
+# attention is handed (see `layer_view`)
+LAYER = "layer"
+
+
+def layer_key(i: int) -> str:
+    """The per-layer layout's name for layer i."""
+    return f"layer_{i}"
+
+
+def _layer_number(name: str) -> int:
+    return int(name.rsplit("_", 1)[1])
+
+
+# ------------------------------------------------------------------ making
+
+
+def kv_store_dtype(dtype, kv_dtype):
+    """(storage dtype, has-scale-leaves) for a K/V store request.
+    `kv_dtype=None` stores K/V at the cache `dtype` with no scale leaves."""
+    if kv_dtype is None:
+        return dtype, False
+    assert str(kv_dtype) == "int8", f"unsupported kv_dtype: {kv_dtype!r}"
+    return jnp.int8, True
+
+
+def layer_spec(
+    *,
+    batch: int,
+    heads: int,
+    dim_head: int,
+    dim: int,
+    max_len: Optional[int] = None,
+    pages: Optional[tuple] = None,
+    per_row: bool = False,
+    image_fmap_size: Optional[int] = None,
+    shift_tokens: bool = False,
+    dtype=jnp.float32,
+    kv_dtype=None,
+) -> dict:
+    """ONE layer's leaves as `jax.ShapeDtypeStruct`s, from the geometry.
+
+    K/V are lanes [batch, heads, max_len, dim_head], or with `pages =
+    (n_pages, page_size)` a pool [n_pages, heads, page_size, dim_head]
+    (then `index` is per row: paged rows never decode in lockstep).
+    `per_row` sizes `index` [batch] instead of scalar. `kv_dtype="int8"`
+    stores K/V quantized beside float32 scales; rings and index keep their
+    dtypes."""
+    rows, length = (batch, max_len) if pages is None else pages
+    kv_dt, scaled = kv_store_dtype(dtype, kv_dtype)
+    spec = jax.ShapeDtypeStruct
+    attn = {
+        K: spec((rows, heads, length, dim_head), kv_dt),
+        V: spec((rows, heads, length, dim_head), kv_dt),
+        INDEX: spec((batch,) if per_row or pages is not None else (), jnp.int32),
+    }
+    if scaled:
+        attn[K_SCALE] = spec((rows, heads, length), jnp.float32)
+        attn[V_SCALE] = spec((rows, heads, length), jnp.float32)
+    layer = {ATTN: attn}
+    if shift_tokens:
+        assert image_fmap_size is not None
+        for name in RING_KEYS:
+            layer[name] = spec((batch, image_fmap_size, dim), dtype)
+    return layer
+
+
+def _zeros(spec: dict, lead: tuple) -> dict:
+    # insertion order kept (jax.tree.map would sort the keys)
+    return {
+        name: _zeros(s, lead) if isinstance(s, dict) else jnp.zeros(lead + s.shape, s.dtype)
+        for name, s in spec.items()
+    }
+
+
+def make(layout: str, depth: int, **geometry) -> dict:
+    """A zeroed cache of `depth` layers of `layer_spec(**geometry)`: the
+    layout applied in this one place."""
+    spec = layer_spec(**geometry)
+    if layout == STACKED:
+        return _zeros(spec, (depth,))
+    assert layout == PER_LAYER, f"unknown cache layout {layout!r}"
+    return {layer_key(i): _zeros(spec, ()) for i in range(depth)}
+
+
+# ------------------------------------------------- reading the layout back
+
+
+def layout_of(cache: dict) -> str:
+    return STACKED if ATTN in cache else PER_LAYER
+
+
+def depth_of(cache: dict) -> int:
+    if layout_of(cache) == STACKED:
+        return cache[ATTN][INDEX].shape[0]
+    return len(cache)
+
+
+def batch_axis(cache: dict) -> int:
+    """The axis of a leaf that counts rows (or pages, for a K/V pool)."""
+    return 1 if layout_of(cache) == STACKED else 0
+
+
+def _map_layers(fn, cache: dict) -> dict:
+    """`fn(layer, put)` over every layer-shaped dict of the cache: the whole
+    stacked tree once, or each `layer_{i}`. `put(x, per_layer=False)` turns
+    a value for the layers into the leaf that dict takes: x for all of them
+    (broadcast under a depth axis when stacked), or x[i] of a [depth, ...]
+    table."""
+    if layout_of(cache) == STACKED:
+        depth = depth_of(cache)
+        return fn(cache, lambda x, per_layer=False: (
+            x if per_layer else jnp.broadcast_to(x, (depth,) + x.shape)
+        ))
+    # by name, not by position: a tree that has been through jit is sorted
+    # layer_0, layer_1, layer_10, ...
+    return {
+        name: fn(layer, lambda x, per_layer=False, i=_layer_number(name): (
+            x[i] if per_layer else x
+        ))
+        for name, layer in cache.items()
+    }
+
+
+def set_index(cache: dict, pos: jnp.ndarray) -> dict:
+    """Overwrite every layer's `index` with `pos`.
+
+    Layers advance in lockstep, so the per-layer indices are copies of one
+    logical position; the continuous-batching chunk loop keeps it as
+    explicit per-slot state (`img_pos`) and stamps it in before each step,
+    which is also how retired slots stay frozen."""
+    return _map_layers(
+        lambda layer, put: {
+            **layer, ATTN: {**layer[ATTN], INDEX: put(pos).astype(jnp.int32)}
+        },
+        cache,
+    )
+
+
+def with_side(cache: dict, page_table=None, block_bitmap=None, ring_end=None) -> dict:
+    """The cache with per-call side leaves injected into every layer:
+
+    `page_table` [B, n_pages]: K/V are a page pool and this maps each row's
+    logical blocks to pages (host state, traced data: one program whatever
+    is mapped). `block_bitmap` [depth, B, nb]: the decode-sparsity policy's
+    per-layer KV tile bitmaps (nonzero = may be read). `ring_end` [B]: the
+    per-row resume window `shift_with_ring` rebuilds rings below
+    (`decode_resume`)."""
+    table, bitmaps, end = (
+        None if x is None else jnp.asarray(x, jnp.int32)
+        for x in (page_table, block_bitmap, ring_end)
+    )
+
+    def inject(layer, put):
+        attn = dict(layer[ATTN])
+        if table is not None:
+            attn[PAGE_TABLE] = put(table)
+        if bitmaps is not None:
+            attn[BLOCK_BITMAP] = put(bitmaps, per_layer=True)
+        layer = {**layer, ATTN: attn}
+        if end is not None:
+            layer[RING_END] = put(end)
+        return layer
+
+    return _map_layers(inject, cache)
+
+
+def without_side(cache: dict, *names: str) -> dict:
+    """The cache without the named side leaves (the persistent donated
+    state keeps its side-free shape: tables are host state)."""
+    return _map_layers(
+        lambda layer, put: {
+            **{n: leaf for n, leaf in layer.items() if n not in names},
+            ATTN: {n: leaf for n, leaf in layer[ATTN].items() if n not in names},
+        },
+        cache,
+    )
+
+
+def extract_rings(cache: dict) -> dict:
+    """Row-major token-shift rings of a fresh prefill cache, in the cache's
+    own tree shape (stacked: [R, depth, fmap, dim] per ring): the part of a
+    prefix's post-prefill state that is not page-addressable. Empty when
+    the model shifts no tokens."""
+    if layout_of(cache) == STACKED:
+        return {n: jnp.moveaxis(cache[n], 1, 0) for n in RING_KEYS if n in cache}
+    out = {}
+    for name, layer in cache.items():
+        rings = {n: layer[n] for n in RING_KEYS if n in layer}
+        if rings:
+            out[name] = rings
+    return out
+
+
+# ------------------------------------- a fresh cache's rows into the state
+
+
+def leaf_name(path) -> str:
+    """Last mapping key of a tree path ('k', 'img_pos', ...)."""
+    for p in reversed(path):
+        key = getattr(p, "key", None)
+        if key is not None:
+            return str(key)
+    return ""
+
+
+def kv_bytes(cache: dict) -> int:
+    """Bytes of the K/V leaves, quantization scales included."""
+    return sum(
+        leaf.size * leaf.dtype.itemsize
+        for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+        if leaf_name(path) in KV_KEYS
+    )
+
+
+def row_block(leaf, r: int, j: int, page_size: int, *, name: str, stacked: bool):
+    """Row r's block j of a K/V lane leaf (`name` tells K/V from a scale
+    leaf, whose sequence axis is last), zero-padded to `page_size` on the
+    sequence axis past the leaf's end (static shapes throughout)."""
+    row = leaf[:, r] if stacked else leaf[r]
+    seq_ax = row.ndim - (1 if name in SCALE_KEYS else 2)
+    max_len = row.shape[seq_ax]
+    lo = j * page_size
+    hi = min(lo + page_size, max_len)
+    if hi <= lo:
+        shape = list(row.shape)
+        shape[seq_ax] = page_size
+        return jnp.zeros(shape, row.dtype)
+    blk = lax.slice_in_dim(row, lo, hi, axis=seq_ax)
+    if hi - lo < page_size:
+        pad = [(0, 0)] * row.ndim
+        pad[seq_ax] = (0, page_size - (hi - lo))
+        blk = jnp.pad(blk, pad)
+    return blk
+
+
+def scatter_rows(state_cache: dict, fresh: dict, slots, pages=None) -> dict:
+    """Row r of the fresh prefill cache written into slot `slots[r]` of the
+    persistent one, leaf by leaf. `index` leaves are not scattered: the
+    chunk step stamps every layer's index from the per-slot `img_pos`
+    (`set_index`).
+
+    `pages = (page_rows [R, n], page_size, extra [R] or None)`: the state's
+    K/V are a page pool, and row r's block j goes to page `page_rows[r, j]`
+    (+ its last block once more to `extra[r]`: the prefix cache's snapshot
+    of the divergence block; page 0 absorbs it for rows not registering)."""
+    stacked = layout_of(state_cache) == STACKED
+    axis = batch_axis(state_cache)
+    n_rows = slots.shape[0]
+
+    def put_page(out, blk, page):
+        blk = blk[:, None] if stacked else blk[None]
+        start = ((0, page) if stacked else (page,)) + (0,) * (blk.ndim - axis - 1)
+        return lax.dynamic_update_slice(out, blk, start)
+
+    def write(path, s_leaf, p_leaf):
+        name = leaf_name(path)
+        if name == INDEX:
+            return s_leaf
+        out = s_leaf
+        if pages is not None and name in KV_KEYS:
+            page_rows, page_size, extra = pages
+            block = lambda r, j: row_block(
+                p_leaf, r, j, page_size, name=name, stacked=stacked
+            ).astype(out.dtype)
+            for r in range(n_rows):
+                for j in range(page_rows.shape[1]):
+                    out = put_page(out, block(r, j), page_rows[r, j])
+                if extra is not None:
+                    out = put_page(out, block(r, page_rows.shape[1] - 1), extra[r])
+            return out
+        for r in range(n_rows):
+            p_row = lax.dynamic_slice_in_dim(p_leaf, r, 1, axis=axis)
+            out = lax.dynamic_update_slice_in_dim(
+                out, p_row.astype(out.dtype), slots[r], axis=axis
+            )
+        return out
+
+    return jax.tree_util.tree_map_with_path(write, state_cache, fresh)
+
+
+def restore_prefix(state_cache: dict, rings: dict, slot, page_copy=None) -> dict:
+    """A cached prefix's non-page-addressable state into `slot` of a paged
+    cache: its rings (one row of `extract_rings`), and with `page_copy =
+    (src, dst)` the pool's page src copied to dst in every K/V leaf (the
+    divergence block, copy-on-write). `index` is stamped every chunk."""
+    axis = batch_axis(state_cache)
+
+    def upd(path, leaf):
+        name = leaf_name(path)
+        if name in KV_KEYS:
+            if page_copy is None:
+                return leaf
+            src, dst = page_copy
+            blk = lax.dynamic_slice_in_dim(leaf, src, 1, axis=axis)
+            return lax.dynamic_update_slice_in_dim(leaf, blk, dst, axis=axis)
+        if name in RING_KEYS:
+            node = rings
+            for p in path:
+                node = node[p.key]
+            return lax.dynamic_update_slice_in_dim(
+                leaf, jnp.expand_dims(node, axis).astype(leaf.dtype), slot, axis=axis
+            )
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(upd, state_cache)
+
+
+# --------------------------------------- one layer's access, inside a step
+
+
+def view(buf: jnp.ndarray, layer) -> jnp.ndarray:
+    """This layer's leaf as its reader takes it: the leaf itself, or
+    `buf[layer]` of a depth-stacked one."""
+    if layer is None:
+        return buf
+    with jax.named_scope("cache_read"):
+        return lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+
+
+def layer_view(stack: dict, layer) -> dict:
+    """One layer's cache out of a stacked one: the small leaves (index,
+    rings, side leaves) sliced at `layer`, K/V and their scales left
+    stacked beside the `layer` coordinate: attention writes its chunk into
+    them at [layer] and reads `leaf[layer]` as a view."""
+    attn = {
+        name: leaf if name in KV_KEYS else view(leaf, layer)
+        for name, leaf in stack[ATTN].items()
+    }
+    rest = {name: view(leaf, layer) for name, leaf in stack.items() if name != ATTN}
+    return {ATTN: {**attn, LAYER: layer}, **rest}
+
+
+def layer_store(stack: dict, layer, attn_cache: dict, rings: dict) -> dict:
+    """The stacked cache after one layer: K/V as attention left them
+    (already written in place), the layer's new index and rings written
+    back at `layer`; what a layer only reads stays as it was."""
+    with jax.named_scope("cache_write"):
+        put = lambda leaf, new: lax.dynamic_update_index_in_dim(
+            leaf, new.astype(leaf.dtype), layer, 0
+        )
+        attn = {
+            **stack[ATTN],
+            **{name: attn_cache[name] for name in KV_KEYS if name in attn_cache},
+            INDEX: put(stack[ATTN][INDEX], attn_cache[INDEX]),
+        }
+        ring_stacks = {name: put(stack[name], ring) for name, ring in rings.items()}
+    return {**stack, ATTN: attn, **ring_stacks}
+
+
+def _write_lanes(buf, val, index, layer):
+    """val [B,H,n,D] into buf [B,H,S,D] at sequence position `index` (the
+    scale leaves one rank lower), a scalar or per row [B]; with `layer`,
+    buf is the depth-stacked leaf and the write lands at `buf[layer]`, in
+    place, the whole stack coming back."""
+    tail = (0,) * (val.ndim - 3)  # (0,) for K/V, () for their scales
+    val = val.astype(buf.dtype)
+    if layer is not None:
+        val = val[None]
+    if jnp.ndim(index) == 0:
+        start = (0, 0, index) + tail
+        return lax.dynamic_update_slice(
+            buf, val, start if layer is None else (layer,) + start
+        )
+    if layer is None:
+        return jax.vmap(
+            lambda b, v, i: lax.dynamic_update_slice(b, v, (0, i) + tail)
+        )(buf, val, index)
+    return jax.vmap(
+        lambda b, v, i: lax.dynamic_update_slice(b, v, (layer, 0, i) + tail),
+        in_axes=(1, 1, 0), out_axes=1,
+    )(buf, val, index)
+
+
+def write(attn_cache: dict, vals: dict, seq_cap: int):
+    """One chunk written into a layer's attention cache at its `index`:
+    `vals` are the chunk's leaves by name (k, v [B,H,n,D] and, for an int8
+    store, their scales [B,H,n]). Returns (the written leaves by name, the
+    cache length attention sees).
+
+    Lanes take the chunk at [.., index:index+n] (per row for a [B] index).
+    A paged cache (it carries `page_table`) takes position p of row b at
+    page `table[b, p // page_size]`, offset `p % page_size`; its virtual
+    length is the slotted cache's (`seq_cap`), and finished rows clamp to
+    the spare last position as the lanes' dynamic_update_slice does. A
+    stacked leaf (the cache carries `layer`) is written at [layer]."""
+    index, layer = attn_cache[INDEX], attn_cache.get(LAYER)
+    n = vals[K].shape[2]
+    if PAGE_TABLE not in attn_cache:
+        with jax.named_scope("cache_write"):
+            out = {
+                name: _write_lanes(attn_cache[name], val, index, layer)
+                for name, val in vals.items()
+            }
+        return out, out[K].shape[-2]
+    assert jnp.ndim(index) == 1, "paged caches always carry per-row indices"
+    table = attn_cache[PAGE_TABLE]
+    page_size = attn_cache[K].shape[-2]
+    max_len = min(table.shape[-1] * page_size, seq_cap)
+    pos = jnp.minimum(index[:, None] + jnp.arange(n), max_len - 1)  # [B, n]
+    page = jnp.take_along_axis(table, pos // page_size, axis=1)
+    off = pos % page_size
+    at = (page, slice(None), off, slice(None))
+    if layer is not None:
+        at = (layer,) + at
+    with jax.named_scope("cache_write"):
+        out = {}
+        for name, val in vals.items():
+            scale = name in SCALE_KEYS
+            leaf = attn_cache[name]
+            out[name] = leaf.at[at[:-1] if scale else at].set(
+                val.transpose(0, 2, 1) if scale
+                else val.transpose(0, 2, 1, 3).astype(leaf.dtype)
+            )
+    return out, max_len

@@ -60,7 +60,8 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from dalle_pytorch_tpu.models.attention import Attention, _cache_view
+from dalle_pytorch_tpu.models import decode_cache
+from dalle_pytorch_tpu.models.attention import Attention
 from dalle_pytorch_tpu.ops.masks import (
     axial_static_mask,
     conv_like_mask,
@@ -180,42 +181,6 @@ def shift_with_ring(h, ring, pos, text_len, fmap, ring_end=None):
         return shift_token_step(h, ring, pos, text_len, fmap)
 
 
-# depth-stacked cache leaves a layer never slices out whole: attention
-# writes its chunk into them at [layer] and reads `leaf[layer]` as a view
-_STACKED_KV = ("k", "v", "k_scale", "v_scale")
-
-
-def _layer_cache(stack: dict, layer) -> dict:
-    """One layer's cache out of the scan executor's depth-stacked one: the
-    small leaves (index, shift rings, page_table, block_bitmap, ring_end)
-    sliced at `layer`, K/V and their scales left stacked beside `layer`."""
-    attn = {
-        name: leaf if name in _STACKED_KV else _cache_view(leaf, layer)
-        for name, leaf in stack["attn"].items()
-    }
-    rest = {
-        name: _cache_view(leaf, layer) for name, leaf in stack.items() if name != "attn"
-    }
-    return {"attn": {**attn, "layer": layer}, **rest}
-
-
-def _stack_cache(stack: dict, layer, attn_cache: dict, rings: dict) -> dict:
-    """The depth-stacked cache after one layer: K/V as attention left them
-    (already written in place), the layer's new index and rings written
-    back at `layer`; what a layer only reads stays as it was."""
-    with jax.named_scope("cache_write"):
-        put = lambda leaf, new: jax.lax.dynamic_update_index_in_dim(
-            leaf, new.astype(leaf.dtype), layer, 0
-        )
-        attn = {
-            **stack["attn"],
-            **{name: attn_cache[name] for name in _STACKED_KV if name in attn_cache},
-            "index": put(stack["attn"]["index"], attn_cache["index"]),
-        }
-        ring_stacks = {name: put(stack[name], ring) for name, ring in rings.items()}
-    return {**stack, "attn": attn, **ring_stacks}
-
-
 class _ScanBlock(nn.Module):
     """One (attn, ff) residual pair in scannable form.
 
@@ -261,7 +226,7 @@ class _ScanBlock(nn.Module):
             )
         cached = layer is not None
         x, stack = carry if cached else (carry, None)
-        cache = _layer_cache(stack, layer) if cached else None
+        cache = decode_cache.layer_view(stack, layer) if cached else None
         pos = cache["attn"]["index"] if cached else None
         # per-row resume window (decode_resume injects it; absent on the
         # ordinary prefill/decode paths)
@@ -317,7 +282,7 @@ class _ScanBlock(nn.Module):
             {"shift_attn": ring_attn, "shift_ff": ring_ff}
             if self.shift_tokens else {}
         )
-        return (x, _stack_cache(stack, layer, attn_cache, rings)), None
+        return (x, decode_cache.layer_store(stack, layer, attn_cache, rings)), None
 
 
 class _ScanStack(nn.Module):
@@ -913,7 +878,7 @@ class Transformer(nn.Module):
                 x1 = x2 = x
                 new_cache = {}
                 for i in order:
-                    lc = cache[f"layer_{i}"]
+                    lc = cache[decode_cache.layer_key(i)]
                     pos = lc["attn"]["index"]
                     h, attn_cache, ring_a = self._half_attn(
                         i, x2, key_mask, lc, deterministic
@@ -925,7 +890,7 @@ class Transformer(nn.Module):
                     if self.shift_tokens:
                         layer_new["shift_attn"] = ring_a
                         layer_new["shift_ff"] = ring_f
-                    new_cache[f"layer_{i}"] = layer_new
+                    new_cache[decode_cache.layer_key(i)] = layer_new
                 return (x1 + x2) / 2, new_cache
             assert key_mask is None, "revnet executor has no key-mask path"
             assert deterministic or (self.attn_dropout == 0 and self.ff_dropout == 0), (
@@ -948,27 +913,46 @@ class Transformer(nn.Module):
                 )(self, x)
             else:
                 x, layer_cache = self._layer(
-                    i, x, key_mask, cache[f"layer_{i}"] if cache else None, deterministic
+                    i, x, key_mask,
+                    cache[decode_cache.layer_key(i)] if cache else None,
+                    deterministic,
                 )
                 if layer_cache:
-                    new_cache[f"layer_{i}"] = layer_cache
+                    new_cache[decode_cache.layer_key(i)] = layer_cache
         if cache is not None:
             return x, new_cache
         return x
 
-    def init_cache(self, batch: int, max_len: int, dtype=jnp.float32) -> dict:
-        """Fixed-shape decode cache pytree (KV + token-shift rings)."""
-        return make_decode_cache(
-            depth=self.depth,
+    @property
+    def cache_layout(self) -> str:
+        """The decode-cache layout this executor takes: the one place that
+        says so (`models/decode_cache.py` owns what each layout IS)."""
+        return (
+            decode_cache.STACKED if self.executor == "scan" else decode_cache.PER_LAYER
+        )
+
+    def init_cache(
+        self, batch: int, max_len: int, dtype=jnp.float32, *,
+        per_row: bool = False, pages: Optional[tuple] = None, kv_dtype=None,
+    ) -> dict:
+        """Zeroed decode cache for this trunk's geometry (K/V + token-shift
+        rings), in the layout its executor takes. Pure config math: usable
+        unbound. `per_row`, `pages = (n_pages, page_size)` and `kv_dtype`
+        as `decode_cache.layer_spec` reads them."""
+        return decode_cache.make(
+            self.cache_layout,
+            self.depth,
             batch=batch,
             max_len=max_len,
+            pages=pages,
+            per_row=per_row,
             heads=self.heads,
             dim_head=self.dim_head,
             dim=self.dim,
             image_fmap_size=self.image_fmap_size,
             shift_tokens=self.shift_tokens,
             dtype=dtype,
-            executor=self.executor,
+            kv_dtype=kv_dtype,
         )
 
 
@@ -1116,193 +1100,3 @@ def pipeline_trunk_apply(
     return make_pipeline_trunk(transformer, mesh, n_micro)(
         tparams, x, key_mask
     )
-
-
-def make_decode_cache(
-    depth: int,
-    batch: int,
-    max_len: int,
-    heads: int,
-    dim_head: int,
-    dim: int,
-    image_fmap_size: Optional[int] = None,
-    shift_tokens: bool = False,
-    dtype=jnp.float32,
-    executor: str = "unrolled",
-    per_row: bool = False,
-    kv_dtype=None,
-) -> dict:
-    """Decode cache pytree for a Transformer of this geometry.
-
-    Standalone (not a module method) so model owners like DALLE can build
-    it from config without binding parameters. The unrolled executor
-    takes per-layer dicts ("layer_{i}"); the scan executor takes the same
-    leaves depth-stacked along axis 0 (they ride the layer scan's carry,
-    each layer writing and reading at its own index).
-
-    `per_row=True` sizes the `index` leaves [batch] (scan: [depth, batch])
-    instead of scalar, putting each batch row at its OWN sequence position —
-    the continuous-batching slot cache, where rows are admitted at token
-    boundaries rather than in lockstep (`models/dalle.py:init_slot_state`).
-
-    `kv_dtype="int8"` stores K/V quantized with symmetric per-(position,
-    head) fp32 scales in sibling `k_scale`/`v_scale` leaves ([B, H, L];
-    scan: [depth, B, H, L]) — dequantized inside the attention read
-    (`ops/pallas_decode.py`), never materialized back to fp. Everything
-    else (shift rings, index) stays in `dtype`.
-    """
-    idx_shape = (batch,) if per_row else ()
-    kv_dt, scaled = _kv_store_dtype(dtype, kv_dtype)
-    if executor == "scan":
-        attn = {
-            "k": jnp.zeros((depth, batch, heads, max_len, dim_head), kv_dt),
-            "v": jnp.zeros((depth, batch, heads, max_len, dim_head), kv_dt),
-            "index": jnp.zeros((depth,) + idx_shape, jnp.int32),
-        }
-        if scaled:
-            attn["k_scale"] = jnp.zeros(
-                (depth, batch, heads, max_len), jnp.float32
-            )
-            attn["v_scale"] = jnp.zeros(
-                (depth, batch, heads, max_len), jnp.float32
-            )
-        cache = {"attn": attn}
-        if shift_tokens:
-            assert image_fmap_size is not None
-            cache["shift_attn"] = jnp.zeros(
-                (depth, batch, image_fmap_size, dim), dtype
-            )
-            cache["shift_ff"] = jnp.zeros(
-                (depth, batch, image_fmap_size, dim), dtype
-            )
-        return cache
-    cache = {}
-    for i in range(depth):
-        attn = {
-            "k": jnp.zeros((batch, heads, max_len, dim_head), kv_dt),
-            "v": jnp.zeros((batch, heads, max_len, dim_head), kv_dt),
-            "index": jnp.zeros(idx_shape, jnp.int32),
-        }
-        if scaled:
-            attn["k_scale"] = jnp.zeros((batch, heads, max_len), jnp.float32)
-            attn["v_scale"] = jnp.zeros((batch, heads, max_len), jnp.float32)
-        layer = {"attn": attn}
-        if shift_tokens:
-            assert image_fmap_size is not None
-            layer["shift_attn"] = jnp.zeros((batch, image_fmap_size, dim), dtype)
-            layer["shift_ff"] = jnp.zeros((batch, image_fmap_size, dim), dtype)
-        cache[f"layer_{i}"] = layer
-    return cache
-
-
-def _kv_store_dtype(dtype, kv_dtype):
-    """(storage dtype, has-scale-leaves) for a KV cache request.
-
-    `kv_dtype=None` keeps the historical behavior (K/V stored at the
-    cache `dtype`, no scale leaves) so every default tree stays
-    byte-identical to pre-quantization builds.
-    """
-    if kv_dtype is None:
-        return dtype, False
-    assert str(kv_dtype) == "int8", f"unsupported kv_dtype: {kv_dtype!r}"
-    return jnp.int8, True
-
-
-def make_paged_decode_cache(
-    depth: int,
-    batch: int,
-    n_pages: int,
-    page_size: int,
-    heads: int,
-    dim_head: int,
-    dim: int,
-    image_fmap_size: Optional[int] = None,
-    shift_tokens: bool = False,
-    dtype=jnp.float32,
-    executor: str = "unrolled",
-    kv_dtype=None,
-) -> dict:
-    """Block-paged decode cache: K/V live in a physical page pool
-    [n_pages, heads, page_size, dim_head] shared by all `batch` rows
-    instead of per-row [max_len] lanes; a host-side page table (passed as
-    a traced argument per dispatch, NOT stored here) maps each row's
-    logical blocks to pages. Same tree keys as `make_decode_cache` so the
-    scatter/gather model ops tree-map across both layouts; shift rings and
-    the per-row `index` stay row-indexed (they are small — paging them
-    would buy nothing).
-
-    `kv_dtype="int8"` pairs the int8 pool with fp32 `k_scale`/`v_scale`
-    pools [n_pages, heads, page_size] (scan: +depth) addressed by the
-    SAME page table.
-    """
-    kv_dt, scaled = _kv_store_dtype(dtype, kv_dtype)
-    if executor == "scan":
-        attn = {
-            "k": jnp.zeros(
-                (depth, n_pages, heads, page_size, dim_head), kv_dt
-            ),
-            "v": jnp.zeros(
-                (depth, n_pages, heads, page_size, dim_head), kv_dt
-            ),
-            "index": jnp.zeros((depth, batch), jnp.int32),
-        }
-        if scaled:
-            attn["k_scale"] = jnp.zeros(
-                (depth, n_pages, heads, page_size), jnp.float32
-            )
-            attn["v_scale"] = jnp.zeros(
-                (depth, n_pages, heads, page_size), jnp.float32
-            )
-        cache = {"attn": attn}
-        if shift_tokens:
-            assert image_fmap_size is not None
-            cache["shift_attn"] = jnp.zeros(
-                (depth, batch, image_fmap_size, dim), dtype
-            )
-            cache["shift_ff"] = jnp.zeros(
-                (depth, batch, image_fmap_size, dim), dtype
-            )
-        return cache
-    cache = {}
-    for i in range(depth):
-        attn = {
-            "k": jnp.zeros((n_pages, heads, page_size, dim_head), kv_dt),
-            "v": jnp.zeros((n_pages, heads, page_size, dim_head), kv_dt),
-            "index": jnp.zeros((batch,), jnp.int32),
-        }
-        if scaled:
-            attn["k_scale"] = jnp.zeros(
-                (n_pages, heads, page_size), jnp.float32
-            )
-            attn["v_scale"] = jnp.zeros(
-                (n_pages, heads, page_size), jnp.float32
-            )
-        layer = {"attn": attn}
-        if shift_tokens:
-            assert image_fmap_size is not None
-            layer["shift_attn"] = jnp.zeros((batch, image_fmap_size, dim), dtype)
-            layer["shift_ff"] = jnp.zeros((batch, image_fmap_size, dim), dtype)
-        cache[f"layer_{i}"] = layer
-    return cache
-
-
-def set_decode_cache_index(cache: dict, pos: jnp.ndarray, executor: str) -> dict:
-    """Overwrite every layer's cache `index` with `pos`.
-
-    Layers always advance in lockstep, so the per-layer indices are copies
-    of one logical position; the continuous-batching chunk loop keeps that
-    position as explicit per-slot state (`img_pos`) and stamps it into the
-    cache before each step — which is also how retired/inactive slots are
-    kept frozen (their position simply never advances).
-    """
-    if executor == "scan":
-        depth = cache["attn"]["index"].shape[0]
-        idx = jnp.broadcast_to(pos, (depth,) + pos.shape).astype(jnp.int32)
-        return {**cache, "attn": {**cache["attn"], "index": idx}}
-    out = {}
-    for name, layer in cache.items():
-        out[name] = {
-            **layer,
-            "attn": {**layer["attn"], "index": pos.astype(jnp.int32)},
-        }
-    return out

@@ -12,10 +12,10 @@ healthy and well-utilized" — with four cooperating pieces:
     Combined with measured dispatch wall time (EMA) this yields live
     model-FLOPs-utilization and achieved-bandwidth gauges per program
     (`dalle_serving_mfu{program=}`, `dalle_serving_hbm_gbps{program=}`)
-    — the same roofline arithmetic as `scripts/hbm_model.py` /
-    `scripts/flash_crossover.py`, which import `extract_cost` and the
-    peak constants from here so offline and live accounting cannot
-    drift. Capture costs ONE extra backend compile per program at warmup
+    — FLOPs and bytes over the peaks of the device it runs on
+    (`utils/flops.py:lookup_peaks`); a host-clock estimate, not the
+    trace's (ROADMAP D7; no cell serves, so not measured on the chip).
+    Capture costs ONE extra backend compile per program at warmup
     (JAX's AOT path does not share the jit dispatch cache — measured),
     which is why it is opt-in via `engine.cost_table`. Mesh-sharded
     engines pass their device labels at capture: where jax exposes
@@ -75,18 +75,12 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 from dalle_pytorch_tpu.utils import compile_guard
 
-from dalle_pytorch_tpu.utils.flops import PEAKS, lookup_peaks
-
-# v5e roofline anchors for the OFFLINE models (scripts/hbm_model.py,
-# scripts/flash_crossover.py — they model a v5e whatever they run on).
-# Live gauges resolve the peaks of the device they run on instead.
-V5E_PEAK_FLOPS, V5E_HBM_BPS = PEAKS["v5e"]
+from dalle_pytorch_tpu.utils.flops import lookup_peaks
 
 
 def extract_cost(compiled) -> Dict[str, float]:
     """`compiled.cost_analysis()` as one flat dict, across jax versions
-    (older jax returns `[dict]`). The shared extraction helper for this
-    module and the offline roofline scripts."""
+    (older jax returns `[dict]`)."""
     cost = compiled.cost_analysis()
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else {}

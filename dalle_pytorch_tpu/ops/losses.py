@@ -2,9 +2,9 @@
 
 The straightforward loss path materializes `[B, N, V]` fp32 logits twice
 (forward + softmax-minus-onehot backward); at the flagship geometry
-(B16 x N1280 x V18448) that is ~1.5 GB per materialization and ~24 GB of
-HBM traffic per step (op-level count of the compiled step,
-scripts/hbm_model.py; not a chip measurement). This module
+(B16 x N1280 x V18448) that is ~1.5 GB per materialization (arithmetic;
+on the chip the dense loss is `loss_pct.train` 4.11 of the flagship step,
+24.9 ms, PERF.md section 5; this path is not measured there). This module
 computes the same split cross-entropy by scanning the vocabulary in
 chunks: each chunk's logits live only in registers/VMEM-sized transients,
 and `jax.checkpoint` on the scan body makes the backward recompute chunk

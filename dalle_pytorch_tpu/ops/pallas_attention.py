@@ -747,9 +747,9 @@ def lib_flash_attention(
 
     Alternative backend to the in-repo `flash_attention` for plain
     causal/full attention (no static-mask block skipping — the library
-    kernel has no occupancy layout). Exists so the on-chip A/B
-    (`scripts/pallas_onchip.py`) can pick whichever is faster on real
-    hardware; differentiable (the library defines its own custom VJP).
+    kernel has no occupancy layout). Not measured on the chip against
+    `flash` (ROADMAP D4: a path a user picks and no cell runs);
+    differentiable (the library defines its own custom VJP).
 
     CPU caveat: the interpret guard below covers only the forward trace;
     the library's custom-VJP backward traces its own pallas_calls at grad

@@ -45,6 +45,7 @@ from typing import Any
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.parallel.partition import _divisible, partition_params
 
 #: mesh axis the KV heads / vocab columns shard over (the "model" axis of
@@ -55,41 +56,30 @@ SERVING_MODEL_AXIS = "tp"
 #: decode-state leaves that are per-row control state — replicated so the
 #: chunk-boundary host snapshot stays a local read
 _ROW_SCALAR_KEYS = frozenset(
-    {"img_pos", "active", "seeds", "temps", "keep_k", "img_tokens", "index"}
+    {"img_pos", "active", "seeds", "temps", "keep_k", "img_tokens", decode_cache.INDEX}
 )
-#: token-shift ring leaves — [B, fmap, dim]-ish, too small to shard
-_RING_KEYS = frozenset({"shift_attn", "shift_ff"})
-
-
-def _leaf_key(path) -> str:
-    """Last mapping key of a tree path ('k', 'img_pos', ...)."""
-    for p in reversed(path):
-        key = getattr(p, "key", None)
-        if key is not None:
-            return str(key)
-    return ""
 
 
 def decode_state_spec(path, leaf, model_axis: str = SERVING_MODEL_AXIS) -> P:
     """PartitionSpec for ONE decode-state leaf, before the divisibility
     fallback. Covers both the slotted (`init_slot_state`) and paged
     (`init_paged_slot_state`) layouts — the tree keys are shared."""
-    key = _leaf_key(path)
+    key = decode_cache.leaf_name(path)
     rank = getattr(leaf, "ndim", 0)
-    if key in ("k", "v"):
+    if key in (decode_cache.K, decode_cache.V):
         # [B|P, H, L, dh] (unrolled) or [depth, B|P, H, L, dh] (scan):
         # heads sit at rank-3 in both layouts
         assert rank in (4, 5), f"unexpected cache leaf {key} rank {rank}"
         return P(*([None] * (rank - 3)), model_axis)
-    if key in ("k_scale", "v_scale"):
+    if key in decode_cache.SCALE_KEYS:
         # int8-cache per-(position, head) fp32 scales: [B, H, L] slotted /
         # [P, H, page_size] paged (scan adds depth) — heads at rank-2, so
         # the scales split WITH the heads they scale and the head-split
         # shard_map kernel reads its shard's scales locally
         assert rank in (3, 4), f"unexpected scale leaf {key} rank {rank}"
         return P(*([None] * (rank - 2)), model_axis)
-    if key in _RING_KEYS or key in _ROW_SCALAR_KEYS:
-        return P()
+    if key in decode_cache.RING_KEYS or key in _ROW_SCALAR_KEYS:
+        return P()  # rings are [B, fmap, dim]-ish: too small to shard
     if key == "row":
         # pending next-token logits [S, total_tokens]: vocab columns over
         # the model axis, matching the logits head's (fsdp, tp) split

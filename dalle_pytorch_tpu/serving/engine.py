@@ -680,21 +680,9 @@ class ContinuousEngine(GenerationEngine):
     def _kv_cache_bytes(self) -> int:
         """Total bytes of the K/V leaves (values + quantization scales)
         in the live decode state."""
-        import jax
+        from dalle_pytorch_tpu.models.decode_cache import kv_bytes
 
-        total = 0
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-            self._state["cache"]
-        )[0]:
-            key = ""
-            for p in reversed(path):
-                k = getattr(p, "key", None)
-                if k is not None:
-                    key = str(k)
-                    break
-            if key in ("k", "v", "k_scale", "v_scale"):
-                total += int(leaf.size) * int(np.dtype(leaf.dtype).itemsize)
-        return total
+        return int(kv_bytes(self._state["cache"]))
 
     def kv_bytes_per_slot(self) -> int:
         """K/V (+ scale) bytes backing ONE decode slot. int8 pages cut

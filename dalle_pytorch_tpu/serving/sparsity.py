@@ -8,7 +8,7 @@ masked rows fell back to dense attention over the whole cache. This module
 precomputes, per layer and per image position, the BLOCK-level shadow of
 each pattern (tile width = the model's `decode_sparse_block`), and the
 engine ships the per-slot rows of that table into every chunk dispatch as
-traced data (`models/dalle.py:_with_block_bitmap`). Policy semantics:
+traced data (`models/decode_cache.py:with_side`). Policy semantics:
 
   * conservative by construction — a tile any pattern row in the chunk
     window touches is read whole (`ops/masks.py:mask_to_block_bitmap`),

@@ -267,8 +267,8 @@ class Family:
 
     def items(self) -> List:
         """Snapshot of (label value, child instrument) pairs — the public
-        read surface for per-label reporting (bench_serving's per-stage
-        breakdown reads the stage family through this)."""
+        read surface for per-label reporting (`render` and the tests'
+        per-stage breakdowns read a family through this)."""
         with self._lock:
             return sorted(self._children.items())
 

@@ -2,8 +2,8 @@
 
 Counts matmul FLOPs only (the MXU-relevant work) for the DALLE
 transformer; elementwise/softmax/embedding work is excluded by
-convention, matching how MFU is normally quoted. Used by `bench.py` and
-the trainer's live MFU log (the reference logs only `sample_per_sec`,
+convention, matching how MFU is normally quoted. Used by the trainer's
+live MFU log (the reference logs only `sample_per_sec`,
 `/root/reference/train_dalle.py:578-581`).
 """
 

@@ -113,8 +113,8 @@ def parse_args(argv=None):
                    "per-(position, head) fp32 scales, dequantized inside "
                    "the decode kernels — roughly 2x decode rows per HBM "
                    "byte (exactly 2D/(D+4) at head dim D) at a small "
-                   "quantization error (bench_serving.py reports the "
-                   "CLIP-score delta beside the speedup)")
+                   "quantization error (tests/test_kv_quant.py bounds the "
+                   "token drift on a toy; not measured on the chip)")
     p.add_argument("--decode_sparsity", choices=("causal", "policy"),
                    default="causal",
                    help="decode-attention sparsity (continuous engine). "
@@ -125,8 +125,8 @@ def parse_args(argv=None):
                    "attention layouts (serving/sparsity.py) and shipped "
                    "as traced data — dead tiles skip compute AND DMA, "
                    "zero extra compiled programs after warmup "
-                   "(bench_serving.py reports kv_tiles_skipped and the "
-                   "CLIP-score delta beside the speedup)")
+                   "(/metrics counts dalle_serving_kv_tiles_skipped_total; "
+                   "not measured on the chip)")
     p.add_argument("--max_queue", type=int, default=64,
                    help="queue bound in rows; beyond it requests get 503")
     p.add_argument("--request_timeout_s", type=float, default=120.0)

@@ -1,6 +1,6 @@
 """TL007 negative (block-sparse decode): the bitmap rides as TRACED data
 — the engine derives it host-side per chunk and threads it in as an
-argument (models/dalle.py:_with_block_bitmap), so inside the scan body it
+argument (models/decode_cache.py:with_side), so inside the scan body it
 is already a tracer; or it is built ONCE outside the body and closed over
 as a device array. Both are the shipped pattern and must stay clean."""
 

@@ -152,7 +152,7 @@ class TestParentsStayOffJax:
         assert proc.stdout.strip().splitlines()[-1] == "0 [False]", proc.stdout + proc.stderr
 
     @pytest.mark.parametrize("module", [
-        "chip_smoke", "bench_common", "dalle_pytorch_tpu.serving.supervisor",
+        "chip_smoke", "dalle_pytorch_tpu.serving.supervisor",
         "dalle_pytorch_tpu.serving.router", "dalle_pytorch_tpu.training.metrics",
     ])
     def test_import_does_not_pull_jax(self, module):

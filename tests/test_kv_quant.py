@@ -238,8 +238,8 @@ class TestDecodeQuality:
         bit-identical by design (scale/2 rounding in every attention
         read) — but on the toy model the token stream must stay close to
         the full-precision decode. The bound is deliberately loose;
-        quality is measured properly (CLIP, full-size model) by
-        bench_serving.py's quality block."""
+        quality at full size (CLIP on the chip) is not measured: no
+        cell serves int8 K/V yet (PERF.md section 7, cell 5)."""
         model, params = toy
         ref_eng = ContinuousEngine(
             model=model, variables=params, max_batch=2, chunk_tokens=8,

@@ -19,14 +19,10 @@ The load-bearing contracts, in order of consequence:
 """
 
 import json
-import os
-import subprocess
-import sys
 import threading
 import time
 import urllib.error
 import urllib.request
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -869,43 +865,3 @@ class TestHTTPQoS:
             assert retry is not None and int(retry) >= 1
         finally:
             server.shutdown()
-
-
-# ------------------------------------------------------- bench line schema
-
-
-@pytest.mark.slow
-def test_priority_mix_bench_schema():
-    """`bench_serving --priority_mix` emits one JSON line with the
-    per-class/QoS schema downstream tooling parses."""
-    env = {
-        **os.environ,
-        "JAX_PLATFORMS": "cpu",
-        "SERVE_DIM": "32", "SERVE_DEPTH": "2", "SERVE_FMAP": "4",
-        "SERVE_TEXT_SEQ": "8", "SERVE_BATCH_SHAPES": "1,2",
-        "SERVE_OPEN_SECONDS": "2", "SERVE_CHUNK_TOKENS": "4",
-        "SERVE_PRIORITY_TIMEOUT": "20",
-    }
-    out = subprocess.run(
-        [sys.executable, "bench_serving.py", "--mode", "open-loop",
-         "--priority_mix", "0.3"],
-        cwd=Path(__file__).resolve().parents[1],
-        env=env, capture_output=True, text=True, timeout=540,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["metric"] == "serving_priority_mix"
-    for key in (
-        "classes", "preemptions", "resumptions", "shed",
-        "ttft_unloaded_p50_ms", "ttft_unloaded_p95_ms", "rate_rps",
-        "saturation_rps", "overload_factor", "dispatch_retries",
-        "priority_mix", "kv_layout", "value",
-    ):
-        assert key in line, f"missing {key}"
-    assert set(line["classes"]) <= {"high", "low"}
-    for stats in line["classes"].values():
-        for k in (
-            "offered", "completed", "shed", "rejected", "errors",
-            "ttft_p50_ms", "ttft_p95_ms",
-        ):
-            assert k in stats

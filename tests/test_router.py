@@ -1094,7 +1094,7 @@ class TestChaosRealReplicas:
                 s.shutdown()
 
 
-# ------------------------------------------------- router-down bench client
+# --------------------------------------------------------- the router CLI
 
 
 @pytest.mark.slow
@@ -1138,82 +1138,3 @@ def test_serve_cli_router_mode_e2e():
         if proc.poll() is None:
             proc.kill()
         stub.kill()
-
-
-@pytest.mark.slow
-def test_fleet_bench_schema():
-    """`bench_serving --replicas 2` emits one JSON line with the fleet
-    schema: healthy vs killed windows, router accounting, and a
-    100%-completion chaos headline."""
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    env = {
-        **os.environ,
-        "JAX_PLATFORMS": "cpu",
-        "SERVE_DIM": "32", "SERVE_DEPTH": "2", "SERVE_FMAP": "4",
-        "SERVE_TEXT_SEQ": "8",
-        "SERVE_FLEET_SECONDS": "3", "SERVE_FLEET_SLOTS": "2",
-        "SERVE_CHUNK_TOKENS": "4",
-    }
-    out = subprocess.run(
-        [sys.executable, "bench_serving.py", "--mode", "open-loop",
-         "--replicas", "2"],
-        cwd=Path(__file__).resolve().parents[1],
-        env=env, capture_output=True, text=True, timeout=540,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["bench"] == "serving_fleet"
-    assert line["metric"] == "fleet_completion_with_replica_killed"
-    for key in ("replicas", "healthy", "killed", "router",
-                "killed_replica", "p95_killed_vs_healthy", "value"):
-        assert key in line, f"missing {key}"
-    for window in (line["healthy"], line["killed"]):
-        for k in ("offered", "completed", "errors", "rps",
-                  "latency_p50_ms", "latency_p95_ms"):
-            assert k in window, f"missing window key {k}"
-    router_block = line["router"]
-    for k in ("failovers", "hedges", "ejections", "retry_budget",
-              "per_replica_share"):
-        assert k in router_block, f"missing router key {k}"
-    fleet_block = line["fleet"]
-    for k in ("goodput_fraction", "suggested_replicas",
-              "scrape_generations", "chip_seconds_by_tenant",
-              "chip_seconds_total"):
-        assert k in fleet_block, f"missing fleet key {k}"
-    # the final sweep sees the killed replica: its generation is stale
-    assert fleet_block["scrape_generations"]["r0"]["stale"] is True
-    # both synthetic tenants got chip-seconds attributed
-    tenants = {
-        k.split("/")[0] for k in fleet_block["chip_seconds_by_tenant"]
-    }
-    assert {"tenant-a", "tenant-b"} <= tenants
-    assert fleet_block["chip_seconds_total"] > 0
-    assert 0.0 <= fleet_block["goodput_fraction"] <= 1.0
-    # the chaos claim: killing a replica mid-window loses nothing
-    assert line["killed"]["completed"] == line["killed"]["offered"], line
-    assert line["value"] == 1.0
-
-
-class TestRouterDownClient:
-    def test_bench_fleet_client_survives_router_down(self):
-        """bench_serving's fleet client records a router-down request as
-        an error outcome instead of raising out of the load loop."""
-        from bench_serving import fleet_request
-
-        # nothing listens on this port (bound then closed)
-        import socket as socket_mod
-
-        s = socket_mod.socket()
-        s.bind(("127.0.0.1", 0))
-        dead_port = s.getsockname()[1]
-        s.close()
-        out = fleet_request(
-            dead_port, {"prompt": "x", "seed": 1}, timeout=1.0
-        )
-        assert out["ok"] is False and out["status"] is None
-        assert out["error"]
-        assert out["latency_s"] >= 0

@@ -11,11 +11,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from dalle_pytorch_tpu.models import decode_cache
+from dalle_pytorch_tpu.models.decode_cache import PER_LAYER, STACKED
 from dalle_pytorch_tpu.models.transformer import (
     Transformer,
-    make_decode_cache,
     scan_params_to_unrolled,
-    set_decode_cache_index,
     unrolled_params_to_scan,
 )
 from dalle_pytorch_tpu.models.dalle import DALLE, generate_images_cached
@@ -208,8 +208,8 @@ class TestScanCachedChunks:
             image_fmap_size=FMAP, shift_tokens=True, per_row=per_row,
             kv_dtype="int8" if carries.endswith("int8") else None,
         )
-        cu = make_decode_cache(executor="unrolled", **kw)
-        cs = make_decode_cache(executor="scan", **kw)
+        cu = decode_cache.make(PER_LAYER, **kw)
+        cs = decode_cache.make(STACKED, **kw)
 
         def both(chunk, cu, cs):
             ou, cu = unr.apply(vu, chunk, cache=cu, reverse_model=reverse)
@@ -225,13 +225,13 @@ class TestScanCachedChunks:
                 # row 1 stops at position 6 (a retired slot rewrites its
                 # last position), row 0 goes on: the rows sit apart
                 pos = jnp.array([t, min(t, 6)], jnp.int32)
-                cu = set_decode_cache_index(cu, pos, "unrolled")
-                cs = set_decode_cache_index(cs, pos, "scan")
+                cu = decode_cache.set_index(cu, pos)
+                cs = decode_cache.set_index(cs, pos)
             cu, cs = both(x[:, t : t + 1], cu, cs)
 
         # the same leaves, whichever way they are held: int8 K/V bit for bit
         assert jax.tree.structure(cs) == jax.tree.structure(
-            make_decode_cache(executor="scan", **kw)
+            decode_cache.make(STACKED, **kw)
         )
         stacked = jax.tree.map(
             lambda *leaves: jnp.stack(leaves), *(cu[f"layer_{i}"] for i in range(DEPTH))
@@ -251,10 +251,10 @@ class TestScanCachedChunks:
         if reverse:
             # sanity that the flag acted on the cached path too
             fwd, _ = scn.apply(
-                vs, x[:, :4], cache=make_decode_cache(executor="scan", **kw)
+                vs, x[:, :4], cache=decode_cache.make(STACKED, **kw)
             )
             rev, _ = scn.apply(
-                vs, x[:, :4], cache=make_decode_cache(executor="scan", **kw),
+                vs, x[:, :4], cache=decode_cache.make(STACKED, **kw),
                 reverse_model=True,
             )
             assert not np.allclose(np.asarray(fwd), np.asarray(rev))
@@ -265,9 +265,9 @@ class TestScanCachedChunks:
         _, scn = pair()
         x = x_input()
         vs = scn.init(jax.random.PRNGKey(1), x)
-        cache = make_decode_cache(
-            depth=DEPTH, batch=2, max_len=SEQ + 1, heads=2, dim_head=8, dim=DIM,
-            image_fmap_size=FMAP, shift_tokens=True, executor="scan",
+        cache = decode_cache.make(
+            STACKED, depth=DEPTH, batch=2, max_len=SEQ + 1, heads=2, dim_head=8,
+            dim=DIM, image_fmap_size=FMAP, shift_tokens=True,
         )
         ring_end = jnp.broadcast_to(jnp.array([7, 9], jnp.int32), (DEPTH, 2))
         _, out = scn.apply(vs, x, cache={**cache, "ring_end": ring_end})
