@@ -10,18 +10,35 @@ for it.
 
 Assignments to held experts are sorted by expert into a buffer of
 `buffer_rows` rows (static: a bound on the assignments made here, not a
-capacity per expert), the experts' two products run grouped over it
+capacity per expert), the experts' three products run grouped over it
 (`ops/grouped_matmul.py`: rows past the assignments cost nothing and hold
-nothing that may be read), and the
-weighted results are summed back per token. Both moves are gathers, forward
-and backward (each is the other's transpose): a scatter-add of 10^5 rows
-is the slow way on the chip. An assignment past the buffer would be a
-dropped token; the layer counts them (`moe_dropped`) and the callers
-require 0.
+nothing that may be read), and the weighted results are summed back per
+token. Both moves are gathers, forward and backward (each is the other's
+transpose): a scatter-add of 10^5 rows is the slow way on the chip. An
+assignment past the buffer would be a dropped token; the layer counts them
+(`moe_dropped`) and the callers require 0.
+
+Every pass over the buffer is sized by the rows PRESENT, `kept =
+min(assignments, buffer_rows)`, which `route` counts on the device, and not
+by the buffer's bound: as the grouped products are. A pass is a loop over
+chunks of `CHUNK_ROWS` rows that stops at the last row present (`_fill`) and
+writes its chunks in place, into a buffer nobody initialised or over one the
+caller has done with; the rows past `kept` hold anything at all, and are
+selected around, never multiplied by 0. So walk the tokens' rows into the
+buffer (`experts`: forward, and again in its backward, which keeps no copy),
+SiLU(gate) x up and its transpose, the sum of the buffer's two cotangents,
+and the rows' cotangent with `d weights` beside it (`_rows_cotangent`: one
+gather of the tokens' cotangent serves both). The way back sums, per token,
+the rows of its present assignments only (`_sum_back`): the tokens stand in
+the order of how many they have, so those with a j-th present assignment
+are a prefix, and rank j's steps stop where the prefix ends. A full buffer
+is walked whole, an empty one not at all. `moe_moved` counts the buffer
+rows a pass walks: `kept` rounded up to the chunk.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Tuple
 
 import jax
@@ -29,7 +46,14 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax import lax
 
-from dalle_pytorch_tpu.ops.grouped_matmul import grouped_matmul
+from dalle_pytorch_tpu.ops.grouped_matmul import (
+    grouped_matmul, grouped_matmul_dlhs, grouped_matmul_drhs)
+
+CHUNK_ROWS = 2048  # rows a loop step moves: 9 MB of 2,304 bf16
+
+
+def _chunk(n_rows: int) -> int:
+    return min(CHUNK_ROWS, n_rows)
 
 
 def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows: int):
@@ -37,10 +61,11 @@ def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows
 
     Returns a dict: `weights` [T, k] (the chosen experts' probabilities,
     renormalised), `experts` [T, k], `assign` [R] (the assignment, t * k +
-    slot, that buffer row r holds), `live` [R] bool, `pos` [T, k] (the
-    buffer row of each assignment, R where it has none: not held here, or
-    dropped), `group_sizes` [count] (clipped to the buffer), and the
-    counters `load` [count], `rows`, `dropped`.
+    slot, that buffer row r holds), `kept` (the rows that hold one), `pos`
+    [T, k] (the buffer row of each assignment, R where it has none: not
+    held here, or dropped), `group_sizes` [count] (clipped to the buffer),
+    the way back by rank (`_ranked`), and the counters `load` [count],
+    `rows`, `dropped`, `moved`.
     """
     first, count = held
     buffer_rows = min(buffer_rows, probs.shape[0] * per_token)
@@ -58,74 +83,256 @@ def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows
     kept = jnp.minimum(rows, buffer_rows)
     ends = jnp.minimum(ends, buffer_rows)
     pos = jnp.argsort(order)  # where each assignment stands in that order
+    pos = jnp.where(pos < kept, pos, buffer_rows).reshape(experts.shape)
+    chunk = _chunk(buffer_rows)
     return {
         "weights": weights, "experts": experts,
-        "assign": order[:buffer_rows],
-        "live": jnp.arange(buffer_rows) < kept,
-        "pos": jnp.where(pos < kept, pos, buffer_rows).reshape(experts.shape),
+        "assign": order[:buffer_rows], "kept": kept, "pos": pos,
         "group_sizes": jnp.diff(ends, prepend=0).astype(jnp.int32),
+        "back": _ranked(pos, buffer_rows),
         "load": load, "rows": rows, "dropped": rows - kept,
+        "moved": jnp.minimum(-(-kept // chunk) * chunk, buffer_rows),
     }
 
 
-def _picked(rows, pos):
-    """[T, k, D] float32: each slot's buffer row, 0 where the slot has none
-    (pos == R). Selected, never multiplied by 0: rows that hold no
-    assignment hold anything at all."""
-    n_rows = rows.shape[0]
-    picked = rows[jnp.minimum(pos, n_rows - 1).reshape(-1)].reshape(*pos.shape, -1)
-    return jnp.where((pos < n_rows)[..., None], picked, 0).astype(jnp.float32)
+def _ranked(pos, buffer_rows: int):
+    """The way back from the buffer, by rank: a token's present assignments
+    stand first among its slots (in slot order), and the tokens stand in the
+    order of how many they have, so the tokens with a rank-j assignment are
+    the first `holders[j]`. `row`, `slot` [k * T]: at j * T + i the buffer
+    row (R where there is none) and the assignment of the rank-j present
+    assignment of the i-th token in that order; `token` [T] that order's
+    inverse (where each token stands in it); `holders` [k]."""
+    n_tok, per_token = pos.shape
+    slots = jnp.broadcast_to(jnp.arange(per_token, dtype=pos.dtype), pos.shape)
+    present = pos < buffer_rows
+    _, row, slot = lax.sort(((~present).astype(pos.dtype), pos, slots),
+                            dimension=1, num_keys=1, is_stable=True)
+    have = jnp.sum(present, axis=1, dtype=pos.dtype)
+    _, by_count = lax.sort((-have, jnp.arange(n_tok, dtype=pos.dtype)),
+                           num_keys=1, is_stable=True)
+    return {
+        "row": row[by_count].T.reshape(-1),
+        "slot": (by_count[:, None] * per_token + slot[by_count]).T.reshape(-1),
+        "token": jnp.argsort(by_count),
+        "holders": jnp.sum(have[:, None] > jnp.arange(per_token), axis=0, dtype=pos.dtype),
+    }
 
 
-def _sum_slots(rows, pos, weights):
-    """[T, D] float32: sum over slots of weights[t, j] * rows[pos[t, j]]: one
-    gather of every slot's row and one weighted sum over the slots, on the
-    VPU in float32 (no MXU pass rounds a weight)."""
-    picked = _picked(rows, pos)
-    if weights is not None:
-        picked = picked * weights.astype(jnp.float32)[..., None]
-    return jnp.sum(picked, axis=1)
+def _fill(live_rows, buffers, chunk_of, read=False, once=False):
+    """`buffers` with their rows below `live_rows` set to what
+    `chunk_of(start, size, current)` gives for the rows from `start` on:
+    written a chunk at a time, in place, by a loop that stops at the last
+    live row; nothing writes the rows past the last chunk. A buffer is a
+    `ShapeDtypeStruct`: made here and never initialised (zeroing it would be
+    a pass over the buffer); or an array the caller reads no more, written
+    over as an elementwise operation would write over its operand. Without
+    `read`, `current` is None, and a last chunk that would overhang starts
+    earlier and writes some rows again. With `read` (arrays only) `current`
+    is the buffers' own rows there, and an overhanging chunk keeps what the
+    rows it visits again hold; `once` for a step with a reader of a buffer
+    whose result is not written back into it: the rows are then read once,
+    behind a barrier, before anything of the chunk is written (XLA would
+    else copy the whole buffer at every step)."""
+    n_rows = buffers[0].shape[0]
+    size = _chunk(n_rows)
+
+    def step(i, held):
+        start = jnp.minimum(i * size, n_rows - size)
+        if read:
+            current = [_window(b, start, size) for b in held]
+            if once:
+                current = lax.optimization_barrier(current)
+            new = start + jnp.arange(size) >= i * size
+            chunks = [jnp.where(new.reshape(-1, *[1] * (c.ndim - 1)), c, old)
+                      for c, old in zip(chunk_of(start, size, current), current)]
+        else:
+            chunks = chunk_of(start, size, None)
+        return tuple(lax.dynamic_update_slice_in_dim(b, c.astype(b.dtype), start, 0)
+                     for b, c in zip(held, chunks))
+
+    made = tuple(lax.empty(b.shape, b.dtype) if isinstance(b, jax.ShapeDtypeStruct) else b
+                 for b in buffers)
+    return lax.fori_loop(0, -(-live_rows // size), step, made)
+
+
+def _window(x, start, size):
+    return lax.dynamic_slice_in_dim(x, start, size, 0)
+
+
+def _take(x, index):
+    """x[index] for an index that is in bounds: no pass mends or masks it."""
+    return x.at[index].get(mode="promise_in_bounds")
+
+
+def _live(start, size, kept):
+    """[size, 1] bool: which of a chunk's rows hold an assignment."""
+    return (start + jnp.arange(size) < kept)[:, None]
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("per_token", "buffer_rows"))
+def _rows_of_tokens(h, assign, kept, over, *, per_token, buffer_rows):
+    """[R, D]: row r is the token's that r's assignment belongs to; written
+    over the array `over`, which the caller reads no more, where one is given."""
+    made = jax.ShapeDtypeStruct((buffer_rows, h.shape[1]), h.dtype) if over is None else over
+    return _fill(kept, (made,), lambda start, size, _: (
+        _take(h, _window(assign, start, size) // per_token),))[0]
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("out_dtype",))
+def _sum_back(rows, weights, back, *, out_dtype):
+    """[T, D]: each token's sum of its present assignments' buffer rows,
+    times their `weights` [T, k] where given: products and sums on the VPU
+    in float32 (no MXU pass rounds a weight), in slot order. Rank 0 walks
+    every token and starts its sum (0 where it has no assignment here); rank
+    j adds to the first `holders[j]` sums; the tokens' own order comes back
+    by one gather of T rows."""
+    n_rows, n_tok = rows.shape[0], back["token"].shape[0]
+    size = _chunk(n_tok)
+    chunks = -(-back["holders"].at[0].set(n_tok) // size)
+    ends = jnp.cumsum(chunks)
+
+    def step(i, sums):
+        rank = jnp.sum(i >= ends, dtype=i.dtype)
+        first = (i - (ends[rank] - chunks[rank])) * size
+        start = jnp.minimum(first, n_tok - size)
+        at = rank * n_tok + start
+        row = _window(back["row"], at, size)
+        picked = _take(rows, jnp.minimum(row, n_rows - 1)).astype(jnp.float32)
+        if weights is not None:
+            picked = picked * _take(weights.reshape(-1), _window(back["slot"], at, size))[:, None]
+        new = (start + jnp.arange(size) >= first)[:, None]  # not an overhang's second visit
+        so_far = jnp.where(new & (rank == 0), 0, _window(sums, start, size))
+        picked = jnp.where(new & (row < n_rows)[:, None], picked, 0)
+        return lax.dynamic_update_slice_in_dim(sums, so_far + picked, start, 0)
+
+    sums = lax.fori_loop(0, ends[-1], step, lax.empty((n_tok, rows.shape[1]), jnp.float32))
+    return _take(sums.astype(out_dtype), back["token"])
 
 
 @jax.custom_vjp
-def to_rows(h, assign, pos):
-    """[R, D]: the token each buffer row's assignment belongs to (a row
-    that holds no assignment gets some token's row: nothing reads it)."""
-    return h[assign // pos.shape[1]]
-
-
-def _to_rows_fwd(h, assign, pos):
-    return h[assign // pos.shape[1]], pos
-
-
-def _to_rows_bwd(pos, d_rows):  # rows have h's dtype, and so has its cotangent
-    return _sum_slots(d_rows, pos, None).astype(d_rows.dtype), None, None
-
-
-to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
-
-
-@jax.custom_vjp
-def to_tokens(rows, weights, assign, pos, live):
+def to_tokens(rows, weights, assign, kept, pos, back):
     """[T, D]: each token's weighted sum of its assignments' rows."""
-    return _sum_slots(rows, pos, weights).astype(rows.dtype)
+    return _sum_back(rows, weights, back, out_dtype=rows.dtype)
 
 
-def _to_tokens_fwd(rows, weights, assign, pos, live):
-    return (_sum_slots(rows, pos, weights).astype(rows.dtype),
-            (rows, weights, assign, pos, live))
+def _to_tokens_fwd(rows, weights, assign, kept, pos, back):
+    return to_tokens(rows, weights, assign, kept, pos, back), (rows, weights, assign, kept, pos)
+
+
+@functools.partial(jax.jit, inline=True)
+def _rows_cotangent(rows, weights, assign, kept, pos, d_tokens):
+    """(d rows [R, D], d weights [T, k]) of `to_tokens`. One walk over the
+    rows present gathers each row's token's cotangent once: times the row's
+    weight it is the row's cotangent, and its product with the row the
+    weight's (found again by `pos`, from R numbers)."""
+    per_token = pos.shape[1]
+
+    def chunk_of(start, size, current):
+        slot = _window(assign, start, size)
+        d_token = _take(d_tokens, slot // per_token).astype(jnp.float32)
+        weight = _take(weights.reshape(-1), slot).astype(jnp.float32)[:, None]
+        return (jnp.where(_live(start, size, kept), d_token * weight, 0),
+                jnp.sum(current[0].astype(jnp.float32) * d_token, axis=-1))
+
+    # the rows' cotangent is written where the rows were
+    d_rows, d_weight = _fill(kept, (rows, jnp.zeros(rows.shape[:1], jnp.float32)), chunk_of,
+                             read=True, once=True)
+    n_rows = rows.shape[0]
+    d_weights = jnp.where(pos < n_rows, _take(d_weight, jnp.minimum(pos, n_rows - 1)), 0)
+    return d_rows, d_weights.astype(weights.dtype)
 
 
 def _to_tokens_bwd(res, d_tokens):
-    rows, weights, assign, pos, live = res
-    per_token = pos.shape[1]
-    w_row = jnp.where(live, weights.reshape(-1)[assign], 0.0)
-    d_rows = (d_tokens[assign // per_token].astype(jnp.float32) * w_row[:, None]).astype(rows.dtype)
-    d_weights = jnp.sum(_picked(rows, pos) * d_tokens.astype(jnp.float32)[:, None, :], axis=-1)
-    return d_rows, d_weights.astype(weights.dtype), None, None, None
+    return (*_rows_cotangent(*res, d_tokens), None, None, None, None)
 
 
 to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
+
+
+def _gated(gate, up):
+    return nn.silu(gate) * up
+
+
+@functools.partial(jax.jit, inline=True)
+def _gated_rows(gate, up, kept):
+    """[R, F]: SiLU(gate) x up over the rows present."""
+    return _fill(kept, (jax.ShapeDtypeStruct(gate.shape, gate.dtype),), lambda start, size, _: (
+        _gated(_window(gate, start, size), _window(up, start, size)),))[0]
+
+
+@functools.partial(jax.jit, inline=True)
+def _gated_cotangents(gate, up, d_act, kept):
+    """(d gate, d up) of `_gated_rows`, written where gate and up were; 0 in
+    a walked row that holds no assignment (`gmm_drhs` sums over a tile's
+    rows: 0 x NaN)."""
+    def chunk_of(start, size, gate_up):
+        live = _live(start, size, kept)
+        return [jnp.where(live, d, 0)
+                for d in jax.vjp(_gated, *gate_up)[1](_window(d_act, start, size))]
+
+    return _fill(kept, (gate, up), chunk_of, read=True)
+
+
+@functools.partial(jax.jit, inline=True)
+def _sum_of_rows(a, b, kept):
+    """[R, D]: a + b over the rows present, written where `a` was."""
+    return _fill(kept, (a,), lambda start, size, current: (
+        current[0] + _window(b, start, size),), read=True)[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def experts(h, assign, kept, back, group_sizes, w_gate, w_up, w_out, buffer_rows):
+    """[R, D] from tokens [T, D]: each buffer row's token (`moe_dispatch`),
+    then (SiLU(rows @ gate) x (rows @ up)) @ out, each row by its own
+    group's matrices (`moe_experts`): three grouped products, and between
+    them passes over the rows present."""
+    return _experts_fwd(h, assign, kept, back, group_sizes, w_gate, w_up, w_out, buffer_rows)[0]
+
+
+def _tokens_rows(h, assign, kept, back, buffer_rows, over=None):
+    with jax.named_scope("moe_dispatch"):
+        return _rows_of_tokens(h, assign, kept, over, buffer_rows=buffer_rows,
+                               per_token=back["row"].shape[0] // h.shape[0])
+
+
+def _experts_fwd(h, assign, kept, back, group_sizes, w_gate, w_up, w_out, buffer_rows):
+    rows = _tokens_rows(h, assign, kept, back, buffer_rows)
+    with jax.named_scope("moe_experts"):
+        gate = grouped_matmul(rows, w_gate, group_sizes)
+        up = grouped_matmul(rows, w_up, group_sizes)
+        act = _gated_rows(gate, up, kept)
+        out = grouped_matmul(act, w_out, group_sizes)
+    # not the tokens' rows: 1.2 GB that the backward needs last, and gathers again
+    return out, (h, assign, kept, back, group_sizes, w_gate, w_up, w_out, gate, up, act)
+
+
+def _experts_bwd(buffer_rows, res, d_out):
+    """Every buffer of the backward is written where one it has done with
+    was, and one is made at a time (the barriers): the step's plan is
+    within a few percent of the chip's memory."""
+    h, assign, kept, back, group_sizes, w_gate, w_up, w_out, gate, up, act = res
+    with jax.named_scope("moe_experts"):
+        d_act = grouped_matmul_dlhs(w_out, group_sizes, d_out)
+        d_w_out = grouped_matmul_drhs(act, w_out, group_sizes, d_out)
+        d_gate, d_up = _gated_cotangents(gate, up, d_act, kept)
+        # the tokens' rows again, where the result's cotangent was
+        d_w_out, d_gate, d_out = lax.optimization_barrier((d_w_out, d_gate, d_out))
+    rows = _tokens_rows(h, assign, kept, back, buffer_rows, over=d_out)
+    with jax.named_scope("moe_experts"):
+        d_w_gate = grouped_matmul_drhs(rows, w_gate, group_sizes, d_gate)
+        d_w_up = grouped_matmul_drhs(rows, w_up, group_sizes, d_up)
+        # and they are read no more before the buffer's two cotangents are made
+        d_w_gate, d_w_up, d_gate, d_up = lax.optimization_barrier((d_w_gate, d_w_up, d_gate, d_up))
+        by_gate = grouped_matmul_dlhs(w_gate, group_sizes, d_gate)
+        by_up = grouped_matmul_dlhs(w_up, group_sizes, d_up)
+        d_rows = _sum_of_rows(by_gate, by_up, kept)
+    with jax.named_scope("moe_dispatch"):  # rows have h's dtype, and so has its cotangent
+        d_h = _sum_back(d_rows, None, back, out_dtype=h.dtype)
+    return d_h, None, None, None, None, d_w_gate, d_w_up, d_w_out
+
+
+experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def _fan_in(key, shape, dtype=jnp.float32):
@@ -139,8 +346,8 @@ class RoutedExperts(nn.Module):
     dim, expert_dim], `w_out` [count, expert_dim, dim]; no biases. Gate and
     up are two grouped products, so that every product of the layer, forward
     or transposed, is rows x dim x expert_dim. Sows `moe_load` [count],
-    `moe_rows` and `moe_dropped`
-    into the `stats` collection where the caller makes it mutable.
+    `moe_rows`, `moe_dropped` and `moe_moved` into the `stats` collection
+    where the caller makes it mutable.
     """
 
     dim: int
@@ -176,14 +383,11 @@ class RoutedExperts(nn.Module):
         with jax.named_scope("moe_dispatch"):
             r = route(probs, self.experts_per_token, tuple(self.experts_held),
                       self.buffer_rows)
-            rows = to_rows(h, r["assign"], r["pos"])
-        with jax.named_scope("moe_experts"):
-            gate = grouped_matmul(rows, self.w_gate, r["group_sizes"])
-            up = grouped_matmul(rows, self.w_up, r["group_sizes"])
-            rows = grouped_matmul(nn.silu(gate) * up, self.w_out, r["group_sizes"])
+        rows = experts(h, r["assign"], r["kept"], r["back"], r["group_sizes"],
+                       self.w_gate, self.w_up, self.w_out, r["assign"].shape[0])
         with jax.named_scope("moe_dispatch"):
-            y = to_tokens(rows, r["weights"], r["assign"], r["pos"], r["live"])
-        for name in ("load", "rows", "dropped"):
+            y = to_tokens(rows, r["weights"], r["assign"], r["kept"], r["pos"], r["back"])
+        for name in ("load", "rows", "dropped", "moved"):
             self.sow("stats", f"moe_{name}", r[name], reduce_fn=lambda _, new: new,
                      init_fn=lambda: None)
         return y.reshape(x.shape)

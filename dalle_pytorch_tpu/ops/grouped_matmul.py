@@ -115,8 +115,9 @@ def _rows_kernel(offsets_ref, group_ref, tile_ref, n_ref, lhs_ref, rhs_ref, out_
 
 def _drhs_kernel(offsets_ref, group_ref, tile_ref, n_ref, lhs_ref, dout_ref, out_ref, acc_ref,
                  *, tm):
-    """One (tile, group) pair: acc += (lhs tile, the group's rows only)^T @
-    dout tile; the group's [K tile, N tile] is stored on its last pair."""
+    """One (tile, group) pair: acc += (lhs tile)^T @ dout tile, the group's
+    rows only of both (a row of neither may be read: 0 x NaN); the group's
+    [K tile, N tile] is stored on its last pair."""
     w = pl.program_id(2)
     group = group_ref[w]
     last_pair = n_ref[0] - 1
@@ -129,7 +130,8 @@ def _drhs_kernel(offsets_ref, group_ref, tile_ref, n_ref, lhs_ref, dout_ref, out
     def _multiply():
         own = _own_rows(offsets_ref, group, tile_ref[w], tm)
         lhs = jnp.where(own, lhs_ref[...], jnp.zeros_like(lhs_ref))
-        acc_ref[...] += _dot(lhs, dout_ref[...], _TN)
+        dout = jnp.where(own, dout_ref[...], jnp.zeros_like(dout_ref))
+        acc_ref[...] += _dot(lhs, dout, _TN)
 
     @pl.when((w == last_pair) | ((w < last_pair)
                                  & (group_ref[jnp.minimum(w + 1, pl.num_programs(2) - 1)] != group)))
@@ -220,6 +222,19 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray)
                       interpret=_use_interpret())
 
 
+def grouped_matmul_dlhs(rhs, group_sizes, dout):
+    """`gmm_dlhs`: [R, K], the cotangent of `grouped_matmul`'s rows for the
+    cotangent `dout` [R, N] of its result (which has the rows' dtype)."""
+    return _emit_rows(dout, rhs.astype(dout.dtype), group_sizes, transposed=True,
+                      interpret=_use_interpret())
+
+
+def grouped_matmul_drhs(lhs, rhs, group_sizes, dout):
+    """`gmm_drhs`: [G, K, N], the cotangent of `grouped_matmul`'s matrices."""
+    return _emit_drhs(lhs, dout.astype(lhs.dtype), group_sizes, out_dtype=rhs.dtype,
+                      interpret=_use_interpret())
+
+
 def _fwd(lhs, rhs, group_sizes):
     return grouped_matmul(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
 
@@ -227,11 +242,8 @@ def _fwd(lhs, rhs, group_sizes):
 def _bwd(res, dout):
     lhs, rhs, group_sizes = res
     dout = dout.astype(lhs.dtype)
-    interpret = _use_interpret()
-    dlhs = _emit_rows(dout, rhs.astype(lhs.dtype), group_sizes, transposed=True,
-                      interpret=interpret)
-    drhs = _emit_drhs(lhs, dout, group_sizes, out_dtype=rhs.dtype, interpret=interpret)
-    return dlhs, drhs, None
+    return (grouped_matmul_dlhs(rhs, group_sizes, dout),
+            grouped_matmul_drhs(lhs, rhs, group_sizes, dout), None)
 
 
 grouped_matmul.defvjp(_fwd, _bwd)

@@ -247,8 +247,9 @@ def make_lm_train_step(model, grad_accum: int = 1) -> Callable:
     cross-entropy. Built from the same `_accumulate`, `_update` and
     `TrainState` as the DALL-E step. Where the trunk has routed layers the
     metrics carry what they counted, per layer: `moe_load` [depth, held],
-    `moe_rows` and `moe_dropped` [depth] (an assignment past a layer's
-    buffer is a dropped token: callers require 0).
+    `moe_rows`, `moe_dropped` and `moe_moved` [depth] (an assignment past a
+    layer's buffer is a dropped token: callers require 0; the buffer rows a
+    pass walked: the rows present, rounded up to the chunk).
     """
 
     def loss_fn(params, batch, rng):
@@ -259,7 +260,7 @@ def make_lm_train_step(model, grad_accum: int = 1) -> Callable:
         metrics = {"loss": loss}
         layers = aux.get("stats", {}).get("transformer", {})
         if layers:
-            for name in ("moe_load", "moe_rows", "moe_dropped"):
+            for name in ("moe_load", "moe_rows", "moe_dropped", "moe_moved"):
                 metrics[name] = jnp.stack(
                     [layers[f"ff_{i}"][name] for i in range(model.depth)]
                 )

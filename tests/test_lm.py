@@ -91,6 +91,28 @@ def test_three_optimizer_steps_match_the_reference(cfg, pair, warmup_steps):
         np.testing.assert_allclose(got[name], w, rtol=2e-3, err_msg=name)
 
 
+def test_the_step_carries_what_the_routed_layers_counted(cfg, pair):
+    """`moe_load`, `moe_rows`, `moe_dropped` to the integer, as the layer
+    counted them before its moves followed the rows present (this seed's
+    numbers, read off the parent commit), and `moe_moved` beside them: the
+    rows present rounded up to the chunk, at most the buffer."""
+    from dalle_pytorch_tpu.models import moe
+
+    mdl, variables, _ = pair
+    state = TrainState.create(apply_fn=mdl.apply, params=variables["params"],
+                              tx=make_optimizer(OPT["learning_rate"]))
+    _, metrics = jax.jit(make_lm_train_step(mdl))(
+        state, {"tokens": _tokens(seed=10)}, jax.random.PRNGKey(0))
+    got = {k: np.asarray(v).tolist() for k, v in metrics.items() if k.startswith("moe_")}
+    assert got["moe_load"] == [[7, 4, 4, 9], [4, 11, 37, 16], [14, 10, 14, 4], [21, 6, 27, 24]]
+    assert got["moe_rows"] == [24, 68, 42, 78] and got["moe_dropped"] == [0, 0, 0, 0]
+    buffer_rows = min(mdl.trunk["moe_buffer_rows"], 2 * N * cfg["num_experts_per_tok"])
+    chunk = moe._chunk(buffer_rows)
+    assert got["moe_moved"] == [min(-(-rows // chunk) * chunk, buffer_rows)
+                                for rows in got["moe_rows"]]
+    assert all(r <= m <= buffer_rows for r, m in zip(got["moe_rows"], got["moe_moved"]))
+
+
 def test_first_layer_choices_match_the_reference(cfg, pair):
     mdl, variables, ref = pair
     tokens = _tokens(seed=2)
@@ -273,3 +295,4 @@ def test_the_trainer_script_trains_and_needs_nothing_of_the_benchmark(tmp_path):
     assert done.returncode == 0, done.stderr[-2000:]
     steps = [line for line in done.stdout.splitlines() if line.startswith("step ")]
     assert len(steps) == 3 and all("moe_dropped 0" in line for line in steps)
+    assert all(" moe_moved " in line for line in steps)

@@ -547,3 +547,52 @@ def test_scan_decode_holds_its_cache_in_place(topo, one_chip, capsys):
         layouts = sorted(set(re.findall(re.escape(leaf) + r"(\{[^{}]*\})", text)))
         print(f"\n[scan decode] {leaf} is laid out as {layouts}; "
               f"temporaries {compiled.memory_analysis().temp_size_in_bytes}")
+
+
+def test_routed_layer_moves_walk_the_rows_present(one_chip, compiled_kernels, monkeypatch):
+    """One routed layer, forward + backward, at `mellum2.train.8k`'s widths
+    (2,304 x 896 bf16, 16 of 64 experts held, 8 a token; 4,096 of the cell's
+    32,768 tokens, so a buffer of 32,768 rows). Read from the optimized HLO:
+    no gather anywhere makes a buffer's worth of rows ([buffer_rows, dim], or
+    [tokens * 8, dim] on the way back): the moves are loops bounded by the
+    rows present whose steps gather one chunk; no float32 [tokens, 8, dim]
+    holds every slot's row; every pass over the buffer writes in place; and
+    each grouped kernel is there as often as before (three of each)."""
+    from collections import Counter
+
+    from dalle_pytorch_tpu.models import moe
+    from dalle_pytorch_tpu.obs import scopes
+    from dalle_pytorch_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    tokens, dim, width, per_token = 4096, 2304, 896, 8
+    buffer_rows, chunk = tokens * per_token, moe.CHUNK_ROWS
+    layer = moe.RoutedExperts(dim=dim, expert_dim=width, experts_total=64,
+                              experts_per_token=per_token, experts_held=(0, 16),
+                              buffer_rows=buffer_rows)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, dim), jnp.bfloat16)))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                          {"params": params["params"]})
+    loss = lambda p, x: jnp.sum(layer.apply(p, x).astype(jnp.float32) ** 2)
+    compiled = _compile(jax.value_and_grad(loss, (0, 1)), params,
+                        jax.ShapeDtypeStruct((1, tokens, dim), jnp.bfloat16, sharding=one_chip))
+    text = compiled.as_text()
+
+    kernels = Counter(re.findall(r"%(gmm_[a-z]+)[.\d]* = ", text))
+    assert kernels == {"gmm_fwd": 3, "gmm_dlhs": 3, "gmm_drhs": 3}
+    gathered, copies = Counter(), []
+    for line in text.splitlines():
+        got = scopes.instruction(line)
+        if got is None:
+            continue
+        name, opcode, shape = got
+        assert shape != f"f32[{tokens},{per_token},{dim}]", line
+        if opcode == "gather" and shape.endswith(f",{dim}]"):
+            gathered[shape] += 1
+        if opcode in ("copy", "copy-start") and shape.startswith(f"bf16[{buffer_rows},"):
+            copies.append(name)
+    # a chunk a step, and the tokens' own order back by one gather of their rows
+    assert set(gathered) == {f"bf16[{chunk},{dim}]", f"bf16[{tokens},{dim}]"}, gathered
+    assert copies == [], copies
+    assert len(re.findall(r" while\(", text)) >= 8  # the moves, forward and backward

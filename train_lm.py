@@ -174,6 +174,7 @@ def main(argv=None):
                     load = jnp.asarray(m["moe_load"], jnp.float32)
                     row.update(
                         moe_dropped=int(jnp.sum(m["moe_dropped"])),
+                        moe_moved=int(jnp.sum(m["moe_moved"])),
                         expert_load_max_over_mean=float(jnp.mean(
                             load.max(-1) / jnp.maximum(load.mean(-1), 1e-9))))
                 print(f"step {step}: " + " ".join(f"{k} {v:.4g}" for k, v in row.items()))
