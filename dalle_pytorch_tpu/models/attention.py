@@ -40,6 +40,7 @@ import flax.linen as nn
 
 from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.ops.attention_core import dense_attention
+from dalle_pytorch_tpu.ops.latent_decode import latent_decode_attention
 from dalle_pytorch_tpu.ops.pallas_attention import (
     flash_attention,
     lib_flash_attention,
@@ -580,3 +581,105 @@ class Attention(nn.Module):
                        name="to_out")(out)
         out = nn.Dropout(self.dropout)(out, deterministic=deterministic)
         return out, new_cache
+
+
+class LatentAttention(nn.Module):
+    """Causal multi-head LATENT attention: queries through a low-rank
+    bottleneck, and keys and values of every head expanded from one
+    compressed vector a position, beside one rotary key that all heads share.
+
+        c_q = rms(x W_dq)            q = c_q W_uq -> per head q_n | q_r
+        c | k_r = x W_dkv            c = rms(c);  q_r, k_r rotated (rotate-half)
+        k_n | v = c W_ukv            per head
+        s = (q_n . k_n + q_r . k_r) / sqrt(qk_nope_dim + qk_rope_dim)
+
+    Two forms that must agree. Without a cache, and for a prefill chunk
+    written into one, the EXPANDED form: k and v of all heads made from the
+    chunk's latent, attention by the dense path or the flash kernels (q and k
+    `qk_nope_dim + qk_rope_dim` wide; v is padded with zeros to that width,
+    because the kernels keep one width, and the padding is cut from the
+    result). With a one-token step, the ABSORBED form over the cache's
+    latent itself: `q_c = q_n W_uk^T` per head, the scores and the weighted
+    sum against `c` (ops/latent_decode.py), and `o = o_c W_uv` after; the
+    cache holds `c` and the rotated `k_r` (models/decode_cache.py, kind
+    `latent`) and no head ever has its keys or values written out.
+
+    Parameters: `to_q_latent`, `q_norm`, `to_q`, `to_kv_latent`, `kv_norm`,
+    `to_kv` [kv_lora_rank, heads * (qk_nope_dim + v_dim)], `to_out`; no
+    biases; matrices stored in `param_dtype`, the two gains in float32.
+    """
+
+    dim: int
+    seq_len: int
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_dim: int
+    norm_eps: float = 1e-6
+    attn_impl: str = "auto"
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, key_mask=None, rotary=None, cache=None, deterministic=True,
+                 rotary_cs=None):
+        assert key_mask is None and rotary is None and rotary_cs is not None, (
+            "latent attention is causal, unpadded, under a rotate-half table")
+        b, n, _ = x.shape
+        h, dn, dr, dv, rank = (self.heads, self.qk_nope_dim, self.qk_rope_dim, self.v_dim,
+                               self.kv_lora_rank)
+        sm_scale = (dn + dr) ** -0.5
+        dense = lambda width, name: nn.Dense(
+            width, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype, name=name)
+        norm = lambda name: nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name=name)
+        to_kv = self.param("to_kv", nn.initializers.lecun_normal(),
+                           (rank, h * (dn + dv)), self.param_dtype).astype(self.dtype)
+        index = 0 if cache is None else cache["index"]
+        with jax.named_scope("mla_proj"):
+            q = dense(h * (dn + dr), "to_q")(norm("q_norm")(dense(self.q_lora_rank, "to_q_latent")(x)))
+            q = q.reshape(b, n, h, dn + dr).transpose(0, 2, 1, 3)  # [b, h, n, dn + dr]
+            c, k_r = jnp.split(dense(rank + dr, "to_kv_latent")(x), [rank], axis=-1)
+            c = norm("kv_norm")(c)  # [b, n, rank]
+            cos, sin = (lax.dynamic_slice_in_dim(t, index, n, axis=0) for t in rotary_cs)
+            q_n, q_r = q[..., :dn], apply_rotary_half(cos, sin, q[..., dn:])
+            k_r = apply_rotary_half(cos, sin, k_r)  # [b, n, dr]: one head
+            chunk = {decode_cache.LATENT: c, decode_cache.ROPE: k_r.transpose(0, 2, 1)}
+
+        new_cache = None
+        if cache is not None:
+            written, _ = decode_cache.write(cache, chunk, None)
+            new_cache = {**written, "index": index + n}
+        if cache is not None and n == 1:
+            with jax.named_scope("mla_proj"):
+                w = to_kv.reshape(rank, h, dn + dv)
+                q_c = jnp.einsum("bhd,rhd->bhr", q_n[:, :, 0], w[..., :dn])
+            with jax.named_scope("mla_attend"):
+                o_c = latent_decode_attention(
+                    q_c, q_r[:, :, 0], written[decode_cache.LATENT], written[decode_cache.ROPE],
+                    jnp.broadcast_to(index + 1, (b,)), sm_scale=sm_scale)
+            with jax.named_scope("mla_proj"):
+                out = jnp.einsum("bhr,rhv->bhv", o_c, w[..., dn:]).reshape(b, 1, h * dv)
+        else:
+            # a chunk is attended by itself alone: it starts the sequence (a
+            # prefill of new tokens against a cache is not built)
+            with jax.named_scope("mla_proj"):
+                kv = jnp.dot(c, to_kv).reshape(b, n, h, dn + dv).transpose(0, 2, 1, 3)
+                k = jnp.concatenate(
+                    [kv[..., :dn], jnp.broadcast_to(k_r[:, None], (b, h, n, dr))], axis=-1)
+                v = kv[..., dn:]
+            flash = self.attn_impl == "flash" or (
+                self.attn_impl == "auto" and n >= AUTO_FLASH_MIN_SEQ)
+            if flash:
+                with jax.named_scope("mla_proj"):
+                    v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dn + dr - dv)))
+                out = flash_attention(jnp.concatenate([q_n, q_r], -1), k, v,
+                                      causal=True, sm_scale=sm_scale)[..., :dv]
+            else:
+                with jax.named_scope("mla_attend"):
+                    mask = jnp.tril(jnp.ones((n, n), bool))[None, None]
+                    out = dense_attention(jnp.concatenate([q_n, q_r], -1), k, v, mask=mask)
+            out = out.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
+        with jax.named_scope("mla_proj"):
+            return dense(self.dim, "to_out")(out), new_cache

@@ -15,6 +15,23 @@ A decode cache is a pytree of fixed-shape leaves. ONE layer holds
     K/V store, the K/V shape without its last axis;
   * `shift_attn`, `shift_ff`: the token-shift rings [B, fmap, dim].
 
+A LATENT layer (`layer_spec(kind="latent")`: latent attention, whose keys
+and values are expanded from one compressed vector a position) holds, in
+place of `k` and `v`, two leaves without a heads axis:
+
+  * `latent` [B, L, latent_dim]: the normed compressed K/V of each position;
+  * `rope` [B, rope_dim, L]: the one rotated key that all heads share, the
+    positions on its LAST axis.
+
+Two leaves and not one of latent_dim + rope_dim: the values' product reads
+the latent alone, and a slice of a wider leaf would be a copy of it at
+every step. The rotary key lies transposed because the chip lays a last
+axis out in tiles of 128 lanes: 64 numbers there are stored as 128, or (the
+compiler's choice for such a leaf) transposed, and copied back at every
+call of a kernel that wants them as declared (139 MB a layer a step at 64 x
+8,480: PERF.md, PR 31). With the positions last, a position costs its
+latent_dim + rope_dim numbers and no more. Per-layer layout, scalar `index`.
+
 and a cache holds `depth` layers in one of two LAYOUTS:
 
   * PER_LAYER: a dict of `depth` such layers under `layer_{i}` (the unrolled
@@ -45,6 +62,8 @@ STACKED, PER_LAYER = "stacked", "per_layer"
 ATTN = "attn"
 K, V, K_SCALE, V_SCALE, INDEX = "k", "v", "k_scale", "v_scale", "index"
 SCALE_KEYS = (K_SCALE, V_SCALE)
+LATENT, ROPE = "latent", "rope"
+LATENT_KEYS = (LATENT, ROPE)
 KV_KEYS = (K, V) + SCALE_KEYS
 RING_KEYS = ("shift_attn", "shift_ff")
 PAGE_TABLE, BLOCK_BITMAP, RING_END = "page_table", "block_bitmap", "ring_end"
@@ -87,8 +106,15 @@ def layer_spec(
     shift_tokens: bool = False,
     dtype=jnp.float32,
     kv_dtype=None,
+    kind: str = "heads",
+    latent_dim: Optional[int] = None,
+    rope_dim: Optional[int] = None,
 ) -> dict:
     """ONE layer's leaves as `jax.ShapeDtypeStruct`s, from the geometry.
+
+    `kind="latent"`: `latent` [batch, max_len, latent_dim] and `rope`
+    [batch, rope_dim, max_len] with a scalar `index`, and nothing else (no
+    pages, no int8 store, no rings). Otherwise:
 
     K/V are lanes [batch, heads, max_len, dim_head], or with `pages =
     (n_pages, page_size)` a pool [n_pages, heads, page_size, dim_head]
@@ -96,9 +122,18 @@ def layer_spec(
     `per_row` sizes `index` [batch] instead of scalar. `kv_dtype="int8"`
     stores K/V quantized beside float32 scales; rings and index keep their
     dtypes."""
+    spec = jax.ShapeDtypeStruct
+    if kind == "latent":
+        assert pages is None and kv_dtype is None and not per_row and not shift_tokens, (
+            "a latent cache is lanes in the cache dtype, decoded in lockstep")
+        return {ATTN: {
+            LATENT: spec((batch, max_len, latent_dim), dtype),
+            ROPE: spec((batch, rope_dim, max_len), dtype),
+            INDEX: spec((), jnp.int32),
+        }}
+    assert kind == "heads", f"unknown cache kind {kind!r}"
     rows, length = (batch, max_len) if pages is None else pages
     kv_dt, scaled = kv_store_dtype(dtype, kv_dtype)
-    spec = jax.ShapeDtypeStruct
     attn = {
         K: spec((rows, heads, length, dim_head), kv_dt),
         V: spec((rows, heads, length, dim_head), kv_dt),
@@ -127,6 +162,7 @@ def make(layout: str, depth: int, **geometry) -> dict:
     """A zeroed cache of `depth` layers of `layer_spec(**geometry)`: the
     layout applied in this one place."""
     spec = layer_spec(**geometry)
+    assert layout == PER_LAYER or LATENT not in spec[ATTN], "a latent cache is per layer"
     if layout == STACKED:
         return _zeros(spec, (depth,))
     assert layout == PER_LAYER, f"unknown cache layout {layout!r}"
@@ -255,11 +291,12 @@ def leaf_name(path) -> str:
 
 
 def kv_bytes(cache: dict) -> int:
-    """Bytes of the K/V leaves, quantization scales included."""
+    """Bytes of the K/V leaves, quantization scales included (of a latent
+    layer: its latent and its shared rotary key)."""
     return sum(
         leaf.size * leaf.dtype.itemsize
         for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
-        if leaf_name(path) in KV_KEYS
+        if leaf_name(path) in KV_KEYS + LATENT_KEYS
     )
 
 
@@ -433,8 +470,21 @@ def write(attn_cache: dict, vals: dict, seq_cap: int):
     page `table[b, p // page_size]`, offset `p % page_size`; its virtual
     length is the slotted cache's (`seq_cap`), and finished rows clamp to
     the spare last position as the lanes' dynamic_update_slice does. A
-    stacked leaf (the cache carries `layer`) is written at [layer]."""
+    stacked leaf (the cache carries `layer`) is written at [layer]. A latent
+    layer's `vals` are `latent` [B, n, latent_dim] and `rope` [B, rope_dim, n]."""
     index, layer = attn_cache[INDEX], attn_cache.get(LAYER)
+    if LATENT in attn_cache:
+        # a latent layer: the chunk's n positions from `index` on, along
+        # the latent's middle axis and the rotary key's last
+        assert jnp.ndim(index) == 0 and layer is None, "a latent cache decodes in lockstep"
+        with jax.named_scope("cache_write"):
+            out = {
+                name: lax.dynamic_update_slice(
+                    attn_cache[name], val.astype(attn_cache[name].dtype),
+                    (0, 0, index) if name == ROPE else (0, index, 0))
+                for name, val in vals.items()
+            }
+        return out, out[LATENT].shape[1]
     n = vals[K].shape[2]
     if PAGE_TABLE not in attn_cache:
         with jax.named_scope("cache_write"):

@@ -1,7 +1,8 @@
 """A routed feed-forward layer that is told which experts it holds.
 
 The router keeps its published width (`experts_total` outputs, the
-`experts_per_token` largest of the softmax, renormalised); this chip holds
+`experts_per_token` largest of the softmax, or of the sigmoids,
+renormalised); this chip holds
 the `experts_held = (first, count)` of them and computes their part of the
 result for the tokens routed to them. What the absent experts would add is
 left out, as expert parallelism leaves it to the chips that hold them: on
@@ -57,9 +58,10 @@ def _chunk(n_rows: int) -> int:
 
 
 def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows: int):
-    """From router probabilities [T, E] to the buffer's layout.
+    """From router scores [T, E] (a softmax's probabilities, or sigmoids)
+    to the buffer's layout.
 
-    Returns a dict: `weights` [T, k] (the chosen experts' probabilities,
+    Returns a dict: `weights` [T, k] (the chosen experts' scores,
     renormalised), `experts` [T, k], `assign` [R] (the assignment, t * k +
     slot, that buffer row r holds), `kept` (the rows that hold one), `pos`
     [T, k] (the buffer row of each assignment, R where it has none: not
@@ -340,14 +342,20 @@ def _fan_in(key, shape, dtype=jnp.float32):
 
 
 class RoutedExperts(nn.Module):
-    """SwiGLU experts behind a softmax router, the held ones computed.
+    """SwiGLU experts behind a router, the held ones computed; beside them,
+    where `shared_dim` is set, one shared expert that every token passes.
 
     Parameters: `router` [dim, experts_total], `w_gate` and `w_up` [count,
     dim, expert_dim], `w_out` [count, expert_dim, dim]; no biases. Gate and
     up are two grouped products, so that every product of the layer, forward
-    or transposed, is rows x dim x expert_dim. Sows `moe_load` [count],
-    `moe_rows`, `moe_dropped` and `moe_moved` into the `stats` collection
-    where the caller makes it mutable.
+    or transposed, is rows x dim x expert_dim. The shared expert is a dense
+    SwiGLU (`shared_gate`, `shared_up` [dim, shared_dim], `shared_out`),
+    whole on every chip that shares the layer. `score` says how the router's
+    outputs become scores: `softmax` over all of them, or a `sigmoid` of
+    each; the chosen ones are renormalised and multiplied by `routed_scale`.
+    The matrices are stored in `param_dtype`, the router in float32. Sows
+    `moe_load` [count], `moe_rows`, `moe_dropped` and `moe_moved` into the
+    `stats` collection where the caller makes it mutable.
     """
 
     dim: int
@@ -356,20 +364,39 @@ class RoutedExperts(nn.Module):
     experts_per_token: int
     experts_held: Tuple[int, int]
     buffer_rows: int
+    score: str = "softmax"  # "softmax" | "sigmoid"
+    routed_scale: float = 1.0
+    shared_dim: int = 0  # 0: no shared expert
     dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
 
     def setup(self):
         count = self.experts_held[1]
+        assert self.score in ("softmax", "sigmoid"), f"unknown router score {self.score!r}"
+        matrix = lambda name, *shape: self.param(name, _fan_in, shape, self.param_dtype)
         self.router = self.param("router", _fan_in, (self.dim, self.experts_total))
-        self.w_gate = self.param("w_gate", _fan_in, (count, self.dim, self.expert_dim))
-        self.w_up = self.param("w_up", _fan_in, (count, self.dim, self.expert_dim))
-        self.w_out = self.param("w_out", _fan_in, (count, self.expert_dim, self.dim))
+        self.w_gate = matrix("w_gate", count, self.dim, self.expert_dim)
+        self.w_up = matrix("w_up", count, self.dim, self.expert_dim)
+        self.w_out = matrix("w_out", count, self.expert_dim, self.dim)
+        if self.shared_dim:
+            self.shared_gate = matrix("shared_gate", self.dim, self.shared_dim)
+            self.shared_up = matrix("shared_up", self.dim, self.shared_dim)
+            self.shared_out = matrix("shared_out", self.shared_dim, self.dim)
 
     def router_probs(self, h2d: jnp.ndarray) -> jnp.ndarray:
-        """Float32 probabilities [T, experts_total] of tokens [T, dim]."""
+        """Float32 scores [T, experts_total] of tokens [T, dim]."""
         logits = jnp.dot(h2d.astype(jnp.float32), self.router,
                          precision=lax.Precision.HIGHEST)
+        if self.score == "sigmoid":
+            return jax.nn.sigmoid(logits)
         return jax.nn.softmax(logits, axis=-1)
+
+    def shared(self, h: jnp.ndarray) -> jnp.ndarray:
+        """[T, dim]: the shared expert of tokens [T, dim]."""
+        with jax.named_scope("moe_shared"):
+            gate, up, out = (w.astype(h.dtype) for w in
+                             (self.shared_gate, self.shared_up, self.shared_out))
+            return jnp.dot(_gated(jnp.dot(h, gate), jnp.dot(h, up)), out)
 
     def choices(self, x: jnp.ndarray) -> jnp.ndarray:
         """[B, N, k] the experts the router chooses, largest first."""
@@ -383,10 +410,15 @@ class RoutedExperts(nn.Module):
         with jax.named_scope("moe_dispatch"):
             r = route(probs, self.experts_per_token, tuple(self.experts_held),
                       self.buffer_rows)
+            weights = r["weights"]
+            if self.routed_scale != 1.0:
+                weights = weights * self.routed_scale
         rows = experts(h, r["assign"], r["kept"], r["back"], r["group_sizes"],
                        self.w_gate, self.w_up, self.w_out, r["assign"].shape[0])
         with jax.named_scope("moe_dispatch"):
-            y = to_tokens(rows, r["weights"], r["assign"], r["kept"], r["pos"], r["back"])
+            y = to_tokens(rows, weights, r["assign"], r["kept"], r["pos"], r["back"])
+        if self.shared_dim:
+            y = y + self.shared(h)
         for name in ("load", "rows", "dropped", "moved"):
             self.sow("stats", f"moe_{name}", r[name], reduce_fn=lambda _, new: new,
                      init_fn=lambda: None)

@@ -61,7 +61,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from dalle_pytorch_tpu.models import decode_cache
-from dalle_pytorch_tpu.models.attention import Attention
+from dalle_pytorch_tpu.models.attention import Attention, LatentAttention
 from dalle_pytorch_tpu.ops.masks import (
     axial_static_mask,
     conv_like_mask,
@@ -120,6 +120,22 @@ class FeedForward(nn.Module):
         x = x * nn.gelu(gates)
         x = nn.Dropout(self.dropout)(x, deterministic=deterministic)
         return nn.Dense(self.dim, dtype=self.dtype)(x)
+
+
+class SwiGLU(nn.Module):
+    """Dense gated feed-forward, (silu(x W_gate) * (x W_up)) W_out, no biases."""
+
+    dim: int
+    hidden: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
+        dense = lambda width, name: nn.Dense(
+            width, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype, name=name)
+        return dense(self.dim, "w_out")(
+            nn.silu(dense(self.hidden, "w_gate")(x)) * dense(self.hidden, "w_up")(x))
 
 
 def _build_static_mask(
@@ -394,7 +410,11 @@ class Transformer(nn.Module):
     # ---- the block's variants; the defaults are the DALL-E block
     norm: str = "layer"  # "layer" | "rms" (norm_eps; LayerNorm keeps flax's 1e-6)
     norm_eps: float = 1e-6
-    ff_kind: str = "geglu"  # "geglu" | "swiglu_experts" (models/moe.py)
+    ff_kind: str = "geglu"  # "geglu" | "swiglu" (width ff_dim) | "swiglu_experts" (models/moe.py)
+    # the feed-forward kind of EACH layer, where they differ (leading dense
+    # layers before routed ones); None: `ff_kind` in every layer
+    ff_kinds: Optional[Sequence[str]] = None
+    ff_dim: int = 0  # the dense SwiGLU's width
     use_bias: bool = True  # to_out's and the feed-forward's
     layerscale: bool = True
     kv_heads: Optional[int] = None  # K/V heads shared by groups of query heads
@@ -411,12 +431,25 @@ class Transformer(nn.Module):
     experts_held: Optional[Tuple[int, int]] = None
     expert_dim: int = 0
     moe_buffer_rows: int = 0
+    moe_score: str = "softmax"  # or "sigmoid": how router outputs become scores
+    routed_scale: float = 1.0  # on the renormalised weights of the chosen
+    shared_dim: int = 0  # width of the shared expert beside the routed ones (0: none)
+    # "latent" among attn_types (models/attention.py:LatentAttention)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_dim: int = 0
     dtype: Any = jnp.float32
+    # what the MATRICES are stored in (the new block options' only: norm
+    # gains and the router stay float32, the DALL-E block keeps float32)
+    param_dtype: Any = jnp.float32
 
     def _block_variant(self) -> Optional[str]:
         """The first block option that is not the DALL-E block's, or None."""
-        defaults = dict(norm="layer", ff_kind="geglu", use_bias=True, layerscale=True,
-                        kv_heads=None, qk_norm=False, window=None, rotary_specs=None)
+        defaults = dict(norm="layer", ff_kind="geglu", ff_kinds=None, use_bias=True,
+                        layerscale=True, kv_heads=None, qk_norm=False, window=None,
+                        rotary_specs=None)
         return next((k for k, v in defaults.items() if getattr(self, k) != v), None)
 
     def _norm(self):
@@ -470,6 +503,8 @@ class Transformer(nn.Module):
         type_per_layer = list(islice(cycle(attn_types), depth))
         attn_ids = list(islice(cycle(self.shared_attn_ids or range(depth)), depth))
         ff_ids = list(islice(cycle(self.shared_ff_ids or range(depth)), depth))
+        ff_kinds = tuple(self.ff_kinds) if self.ff_kinds else (self.ff_kind,) * depth
+        assert len(ff_kinds) == depth, f"{len(ff_kinds)} ff_kinds for {depth} layers"
 
         shared_attn, shared_attn_type = {}, {}
         shared_ff = {}
@@ -484,6 +519,15 @@ class Transformer(nn.Module):
                         f"reused_attn_type = {shared_attn_type[attn_id]!r})"
                     )
                 attn = shared_attn[attn_id]
+            elif attn_type == "latent":
+                attn = shared_attn[attn_id] = LatentAttention(
+                    dim=self.dim, seq_len=self.seq_len, heads=self.heads,
+                    q_lora_rank=self.q_lora_rank, kv_lora_rank=self.kv_lora_rank,
+                    qk_nope_dim=self.qk_nope_dim, qk_rope_dim=self.qk_rope_dim,
+                    v_dim=self.v_dim, norm_eps=self.norm_eps, attn_impl=self.attn_impl,
+                    dtype=self.dtype, param_dtype=self.param_dtype, name=f"attn_{attn_id}",
+                )
+                shared_attn_type[attn_id] = attn_type
             else:
                 attn = Attention(
                     dim=self.dim,
@@ -512,7 +556,7 @@ class Transformer(nn.Module):
 
             if ff_id in shared_ff:
                 ff = shared_ff[ff_id]
-            elif self.ff_kind == "swiglu_experts":
+            elif ff_kinds[ind] == "swiglu_experts":
                 ff = shared_ff[ff_id] = RoutedExperts(
                     dim=self.dim,
                     expert_dim=self.expert_dim,
@@ -520,11 +564,20 @@ class Transformer(nn.Module):
                     experts_per_token=self.experts_per_token,
                     experts_held=tuple(self.experts_held),
                     buffer_rows=self.moe_buffer_rows,
+                    score=self.moe_score,
+                    routed_scale=self.routed_scale,
+                    shared_dim=self.shared_dim,
                     dtype=self.dtype,
+                    param_dtype=self.param_dtype,
                     name=f"ff_{ff_id}",
                 )
+            elif ff_kinds[ind] == "swiglu":
+                ff = shared_ff[ff_id] = SwiGLU(
+                    dim=self.dim, hidden=self.ff_dim, dtype=self.dtype,
+                    param_dtype=self.param_dtype, name=f"ff_{ff_id}",
+                )
             else:
-                assert self.ff_kind == "geglu", f"unknown ff_kind {self.ff_kind!r}"
+                assert ff_kinds[ind] == "geglu", f"unknown ff_kind {ff_kinds[ind]!r}"
                 assert self.use_bias, "the GEGLU feed-forward keeps its biases"
                 ff = FeedForward(
                     dim=self.dim,
@@ -538,6 +591,7 @@ class Transformer(nn.Module):
 
         self.attn_layers = attn_layers
         self.ff_layers = ff_layers
+        self.ff_kind_per_layer = ff_kinds
         self.type_per_layer = tuple(type_per_layer)
         self.attn_norms = [self._norm() for _ in range(depth)]
         self.ff_norms = [self._norm() for _ in range(depth)]
@@ -752,7 +806,7 @@ class Transformer(nn.Module):
         token of x [B, N, dim] (the trunk's input), through the layers
         before it and its own attention half: what the routed layer itself
         would choose, read outside any train step."""
-        assert self.ff_kind == "swiglu_experts", "only a routed layer chooses"
+        assert self.ff_kind_per_layer[layer] == "swiglu_experts", "only a routed layer chooses"
         for i in range(layer):
             x = self._layer(i, x, None, None, True)[0]
         x = x + self._half_attn(layer, x, None, None, True)[0]
@@ -938,7 +992,17 @@ class Transformer(nn.Module):
         """Zeroed decode cache for this trunk's geometry (K/V + token-shift
         rings), in the layout its executor takes. Pure config math: usable
         unbound. `per_row`, `pages = (n_pages, page_size)` and `kv_dtype`
-        as `decode_cache.layer_spec` reads them."""
+        as `decode_cache.layer_spec` reads them. A trunk of latent
+        attention layers takes the latent kind of layer."""
+        types = set(self.attn_types or ("full",))
+        if "latent" in types:
+            assert types == {"latent"}, "latent and K/V layers in one cache are not built"
+            assert not per_row and pages is None and kv_dtype is None
+            return decode_cache.make(
+                self.cache_layout, self.depth, kind="latent", batch=batch, max_len=max_len,
+                heads=self.heads, dim_head=self.dim_head, dim=self.dim,
+                latent_dim=self.kv_lora_rank, rope_dim=self.qk_rope_dim, dtype=dtype,
+            )
         return decode_cache.make(
             self.cache_layout,
             self.depth,

@@ -35,10 +35,11 @@ COMPONENTS = (
     "attn_kernel", "attn_proj", "attn_glue", "attend", "cache_read",
     "cache_write", "ff", "norm_resid", "embed", "head", "loss", "optimizer",
     "sample", "pixels", "moe_router", "moe_dispatch", "moe_experts", "unscoped",
+    "mla_attend", "mla_proj", "moe_shared",
 )
 PHASES = ("fwd", "bwd", "remat")
 
-# the names the program gives its seven Pallas kernels (`name=` in
+# the names the program gives its seven Pallas attention kernels (`name=` in
 # ops/pallas_attention.py and ops/pallas_decode.py). The chip names the
 # custom call after the innermost scope, which is the kernel's name, so
 # these also find a kernel by its INSTRUCTION name (`%dq_flash.7`).
@@ -50,6 +51,9 @@ KERNELS = (
 # the grouped products of a routed layer (`name=` in ops/grouped_matmul.py),
 # known by their instruction names like the seven above
 EXPERT_KERNELS = ("gmm_fwd", "gmm_dlhs", "gmm_drhs")
+
+# the latent decode attention (`name=` in ops/latent_decode.py)
+LATENT_KERNEL = "decode_latent"
 
 CONTAINERS = ("while", "conditional", "call")  # their bodies are events too
 
@@ -73,6 +77,13 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         ("pixels", r"(^|/)DiscreteVAE\."),
         ("cache_read", _E("cache_read")),
         ("cache_write", _E("cache_write")),
+        # latent attention's two halves (models/attention.py:LatentAttention),
+        # before the attention rules they would otherwise fall under: scores,
+        # softmax and weights x latent, with the kernel by name; and the down-
+        # and up-projections, the two absorbed products, the norms of both
+        # latents and the rotary. The latent's write is `cache_write`, above
+        ("mla_attend", _E("mla_attend|" + LATENT_KERNEL)),
+        ("mla_proj", _E("mla_proj")),
         # a scan's own slicing (`dynamic_index_in_dim` of the stacked
         # parameters, LayerScale vectors and the layer index) and its counter
         # are nobody's: no owner. The cached scan carries the depth-stacked
@@ -86,6 +97,7 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         # sort, the two gathers and the weights; the grouped products
         ("moe_router", _E("moe_router")),
         ("moe_dispatch", _E("moe_dispatch")),
+        ("moe_shared", _E("moe_shared")),
         ("moe_experts", _E("moe_experts|" + "|".join(EXPERT_KERNELS))),
         ("embed", r"(^|/)(DALLE\.embed_text|text_emb|image_emb|token_emb|\w*pos_emb)(/|$)"),
         ("norm_resid", r"(^|/)(\w*norms?_\w+|norm_by_max)(/|$)"),
@@ -113,6 +125,8 @@ def component(op_name: Optional[str], opcode: str = "",
         found = "attn_kernel"
     elif base in EXPERT_KERNELS:
         found = "moe_experts"
+    elif base == LATENT_KERNEL:
+        found = "mla_attend"
     elif not op_name or opcode in CONTAINERS:  # loop control has no owner
         return "unscoped", "fwd"
     else:

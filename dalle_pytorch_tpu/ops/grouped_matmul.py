@@ -144,8 +144,15 @@ def _padded(x, tm):
     return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
 
 
-def _row_tile(rows: int) -> int:
-    return TILE_ROWS if rows >= TILE_ROWS else -(-rows // 8) * 8
+def _row_tile(rows: int, groups: int = 1) -> int:
+    """Rows a tile holds: TILE_ROWS, or all the rows of a shorter buffer.
+    Every pair multiplies its WHOLE tile by its group's matrix, so where a
+    group's even share of the buffer is under a tile (a token step: 512 rows
+    for 16 experts, about 2 present each), a tile no taller than the MXU
+    keeps a pair's arithmetic under its matrix's read."""
+    if rows < TILE_ROWS:
+        return -(-rows // 8) * 8
+    return TILE_ROWS if rows // groups >= TILE_ROWS else 128
 
 
 @functools.partial(jax.jit, static_argnames=("transposed", "interpret"))
@@ -154,7 +161,7 @@ def _emit_rows(lhs, rhs, group_sizes, *, transposed, interpret):
     N], the result [R, K], the matrices read as they lie)."""
     rows, k = lhs.shape
     n = rhs.shape[1] if transposed else rhs.shape[2]
-    tm, tk, tn = _row_tile(rows), _tile(k), _tile(n)
+    tm, tk, tn = _row_tile(rows, rhs.shape[0]), _tile(k), _tile(n)
     lhs = _padded(lhs, tm)
     n_tiles = lhs.shape[0] // tm
     plan = _plan(group_sizes, n_tiles, tm)

@@ -198,19 +198,27 @@ def test_a_window_layer_without_a_window_length_is_refused():
 @pytest.mark.parametrize("name,leaves,digest", [
     ("dalle-flagship", 162, "2ef544e1db6836a7"),
     ("dalle-paper64", 19, "35d5a93cd7103b2e"),
+    ("mellum2-12b-ep4", 43, "69dcdcd7311ddf51"),
 ])
 def test_the_dalle_configurations_build_the_parameter_trees_they_built(name, leaves, digest):
-    """No DALL-E default moved: paths, shapes and dtypes of both benchmark
-    configurations' trees, as the commit before the block options built them."""
+    """No default of an accepted configuration moved: paths, shapes and
+    dtypes of the benchmark configurations' trees, the DALL-E ones as the
+    commit before the block options built them, the language model's as the
+    commit before the generation options did."""
     import hashlib
 
     from benchmark import build
 
     with open(ROOT / "benchmark" / "configs" / f"{name}.json") as f:
-        mdl = build.model(json.load(f))
-    shapes = jax.eval_shape(
-        mdl.init, jax.random.PRNGKey(0), jnp.zeros((1, mdl.text_seq_len), jnp.int32),
-        jnp.zeros((1, mdl.image_seq_len), jnp.int32))["params"]
+        cfg = json.load(f)
+    if "model" in cfg:
+        mdl = build.model(cfg)
+        tokens = (jnp.zeros((1, mdl.text_seq_len), jnp.int32),
+                  jnp.zeros((1, mdl.image_seq_len), jnp.int32))
+    else:
+        mdl = CausalLM.from_config(cfg, 8192)
+        tokens = (jnp.zeros((1, mdl.seq_len), jnp.int32),)
+    shapes = jax.eval_shape(mdl.init, jax.random.PRNGKey(0), *tokens)["params"]
     flat = sorted(
         ("/".join(str(getattr(k, "key", k)) for k in path), tuple(x.shape), str(x.dtype))
         for path, x in jax.tree_util.tree_leaves_with_path(shapes))

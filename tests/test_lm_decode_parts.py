@@ -1,0 +1,223 @@
+"""The parts that generation with the latent-attention language model is made
+of, each against a plain statement of what it computes, at small sizes on
+the CPU: the latent decode kernel (interpreted) on ragged rows, the routed
+layer's sigmoid scores, scale and shared expert, the share test, the
+bf16-stored parameter tree the configuration states, the latent kind of cache
+layer, the new scopes' components, the grouped product's row tile, and the
+`generate_lm.py` command. The model end to end is `tests/test_lm_decode.py`."""
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import pangu_ref
+from dalle_pytorch_tpu.models import decode_cache
+from dalle_pytorch_tpu.models.lm import CausalLM
+from dalle_pytorch_tpu.models.moe import RoutedExperts
+from dalle_pytorch_tpu.obs import scopes
+from dalle_pytorch_tpu.ops import grouped_matmul
+from dalle_pytorch_tpu.ops.latent_decode import latent_decode_attention
+
+ROOT = Path(__file__).resolve().parent.parent
+N, SEED = 32, 7
+
+
+def _cfg(name="_tiny-pangu"):
+    with open(ROOT / "benchmark" / "configs" / f"{name}.json") as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return _cfg()
+
+
+@pytest.mark.parametrize("block", [8, 16, 64])
+def test_the_latent_decode_kernel_matches_dense_on_ragged_rows(block):
+    B, H, R, dr, L = 3, 4, 16, 8, 50
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q_c, q_r = jax.random.normal(ks[0], (B, H, R)), jax.random.normal(ks[1], (B, H, dr))
+    latent, rope = jax.random.normal(ks[2], (B, L, R)), jax.random.normal(ks[3], (B, dr, L))
+    lengths = jnp.asarray([50, 17, 1])  # none a multiple of a block
+    got = latent_decode_attention(q_c, q_r, latent, rope, lengths, sm_scale=0.2, block=block)
+    s = (jnp.einsum("bhr,blr->bhl", q_c, latent) + jnp.einsum("bhd,bdl->bhl", q_r, rope)) * 0.2
+    s = jnp.where(jnp.arange(L)[None, None] < lengths[:, None, None], s, -jnp.inf)
+    want = jnp.einsum("bhl,blr->bhr", jax.nn.softmax(s, -1), latent)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    # positions past a row's length are never read: garbage there changes nothing
+    junk = jnp.where(jnp.arange(L)[None, :, None] < lengths[:, None, None], latent, jnp.nan)
+    again = latent_decode_attention(q_c, q_r, junk, rope, lengths, sm_scale=0.2, block=block)
+    np.testing.assert_array_equal(got, again)
+
+
+def test_the_router_scores_scaling_and_shared_expert_match_a_per_token_loop():
+    dim, width, total, k = 16, 8, 8, 2
+    layer = RoutedExperts(dim=dim, expert_dim=width, experts_total=total, experts_per_token=k,
+                          experts_held=(2, 4), buffer_rows=64, score="sigmoid",
+                          routed_scale=2.5, shared_dim=12)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 10, dim))
+    params = layer.init(jax.random.PRNGKey(2), x)["params"]
+    got = np.asarray(layer.apply({"params": params}, x))[0]
+    p = {n: np.asarray(v, np.float64) for n, v in params.items()}
+    silu = lambda t: t / (1 + np.exp(-t))
+    for t, h in enumerate(np.asarray(x[0], np.float64)):
+        s = 1 / (1 + np.exp(-(h @ p["router"])))
+        chosen = np.argsort(-s)[:k]
+        want = (silu(h @ p["shared_gate"]) * (h @ p["shared_up"])) @ p["shared_out"]
+        for e in chosen:
+            if 2 <= e < 6:
+                w = 2.5 * s[e] / s[chosen].sum()
+                want += w * (silu(h @ p["w_gate"][e - 2]) * (h @ p["w_up"][e - 2])) @ p["w_out"][e - 2]
+        np.testing.assert_allclose(got[t], want, atol=2e-5)
+
+
+def test_the_routed_layers_defaults_are_the_softmax_router_alone():
+    layer = RoutedExperts(dim=16, expert_dim=8, experts_total=4, experts_per_token=2,
+                          experts_held=(0, 4), buffer_rows=16)
+    params = layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 4, 16)))["params"]
+    assert sorted(params) == ["router", "w_gate", "w_out", "w_up"]
+    assert {str(v.dtype) for v in params.values()} == {"float32"}
+
+
+def test_the_four_shares_of_a_routed_layer_add_up_to_the_uncut_layer(cfg):
+    """Expert parallelism's contract (model-configs guide, section 4): the
+    parts that the four shares give (experts 0-1, 2-3, 4-5, 6-7), with what
+    every chip computes alike, the shared expert, and the residual counted
+    once, are the uncut reference's layer. Through the PROGRAM's routed layer."""
+    whole = dict(cfg, n_routed_experts=8)  # the reference holds every expert
+    d = pangu_ref.dims(whole)
+    lp = pangu_ref.init_layer(whole, SEED, 1)
+    y = jax.random.normal(jax.random.PRNGKey(3), (N, d["dim"]))
+    b = pangu_ref._rms(y, lp["norm_ff_g"], d["eps"])
+    weights, _ = pangu_ref.route(b, lp["router_w"], d)
+    uncut = pangu_ref.shared_expert(b, lp) + pangu_ref.routed_experts(
+        b, weights, lp, d, held=(0, 8))
+    shared = {"shared_gate": lp["sh_gate_w"], "shared_up": lp["sh_up_w"],
+              "shared_out": lp["sh_down_w"]}
+    routed, with_shared = [], []
+    for first in (0, 2, 4, 6):
+        params = {"router": lp["router_w"], "w_gate": lp["gate_w"][first:first + 2],
+                  "w_up": lp["up_w"][first:first + 2], "w_out": lp["down_w"][first:first + 2]}
+        kw = dict(dim=d["dim"], expert_dim=d["expert_dim"], experts_total=8, experts_per_token=2,
+                  experts_held=(first, 2), buffer_rows=2 * N, score="sigmoid", routed_scale=2.5)
+        routed.append(RoutedExperts(**kw).apply({"params": params}, b[None])[0])
+        with_shared.append(RoutedExperts(**kw, shared_dim=d["shared_dim"]).apply(
+            {"params": {**params, **shared}}, b[None])[0])
+    the_shared = with_shared[0] - routed[0]  # what every chip computes alike
+    np.testing.assert_allclose(the_shared, pangu_ref.shared_expert(b, lp), atol=2e-5)
+    np.testing.assert_allclose(sum(routed) + the_shared, uncut, atol=3e-5)
+    # and one share alone is what the cut reference computes
+    cut = pangu_ref.shared_expert(b, lp) + pangu_ref.routed_experts(
+        b, weights, {k: v[:2] for k, v in lp.items() if v.ndim == 3}, d, held=(0, 2))
+    np.testing.assert_allclose(with_shared[0], cut, atol=2e-5)
+
+
+def test_parameters_stored_in_bf16_give_the_tree_the_configuration_states():
+    cfg = _cfg("pangu-ultra-moe-ep16")
+    mdl = CausalLM.from_config(cfg, 64)
+    shapes = jax.eval_shape(mdl.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    leaves = jax.tree_util.tree_leaves_with_path(shapes)
+    name = lambda path: "/".join(str(getattr(k, "key", k)) for k in path)
+    float32 = {name(p) for p, x in leaves if x.dtype == jnp.float32}
+    assert all(n.endswith("/scale") or n.endswith("/router") for n in float32)
+    assert all(x.dtype in (jnp.float32, jnp.bfloat16) for _, x in leaves)
+    count = sum(int(np.prod(x.shape)) for _, x in leaves)
+    assert count == pangu_ref.n_params(cfg) == 4_919_139_840
+    assert "4919 M" in cfg["deployment"]["parameters_here"]
+    stored = sum(int(np.prod(x.shape)) * x.dtype.itemsize for _, x in leaves)
+    assert 9.83e9 < stored < 9.86e9  # "9.84 GB in bf16"
+    # every published width is the file's own
+    for key, value in dict(hidden_size=7680, q_lora_rank=1536, kv_lora_rank=512,
+                           qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                           num_attention_heads=128, intermediate_size=18432,
+                           moe_intermediate_size=2048, num_experts_per_tok=8,
+                           routed_scaling_factor=2.5, rms_norm_eps=1e-5,
+                           rope_theta=25600000).items():
+        assert cfg[key] == value
+    assert cfg["published"] == dict(num_hidden_layers=61, first_k_dense_replace=3,
+                                    n_routed_experts=256, vocab_size=153600,
+                                    num_nextn_predict_layers=1)
+    assert set(cfg["reduced"]) == set(cfg["published"])
+    assert mdl.trunk["experts_total"] == 256 and mdl.trunk["experts_held"] == (0, 16)
+
+
+def test_the_latent_cache_has_no_heads_axis_and_the_bytes_the_issue_states():
+    cfg = _cfg("pangu-ultra-moe-ep16")
+    mdl = CausalLM.from_config(cfg, 8480)
+    cache = jax.eval_shape(lambda: mdl.init_cache(64))
+    assert decode_cache.layout_of(cache) == decode_cache.PER_LAYER and len(cache) == 5
+    attn = cache["layer_0"]["attn"]
+    assert attn["latent"].shape == (64, 8480, 512) and attn["rope"].shape == (64, 64, 8480)
+    assert attn["index"].shape == () and attn["latent"].dtype == jnp.bfloat16
+    assert decode_cache.kv_bytes(cache) == 64 * 8480 * 5 * 1152  # 3.13 GB
+
+
+def test_the_cache_module_writes_and_stamps_a_latent_layer():
+    cache = decode_cache.make(decode_cache.PER_LAYER, 2, kind="latent", batch=2, max_len=6,
+                              heads=4, dim_head=16, dim=64, latent_dim=3, rope_dim=2)
+    cache = decode_cache.set_index(cache, jnp.asarray(4))
+    attn = cache["layer_1"]["attn"]
+    assert int(attn["index"]) == 4
+    written, length = decode_cache.write(
+        attn, {"latent": jnp.ones((2, 2, 3)), "rope": 2 * jnp.ones((2, 2, 2))}, None)
+    assert length == 6
+    assert np.array_equal(np.asarray(written["latent"][0, :, 0]), [0, 0, 0, 0, 1, 1])
+    assert np.array_equal(np.asarray(written["rope"][1, 0]), [0, 0, 0, 0, 2, 2])
+    with pytest.raises(AssertionError, match="per layer"):
+        decode_cache.make(decode_cache.STACKED, 2, kind="latent", batch=2, max_len=6, heads=4,
+                          dim_head=16, dim=64, latent_dim=3, rope_dim=2)
+
+
+@pytest.mark.parametrize("path,want", [
+    ("jit(lm_sample)/while/body/CausalLM.decode_step/transformer/attn_1/mla_proj/to_q/dot_general", "mla_proj"),
+    ("jit(lm_sample)/while/body/transformer/attn_1/mla_proj/q_norm/rsqrt", "mla_proj"),
+    ("jit(lm_sample)/while/body/transformer/attn_1/mla_attend/decode_latent", "mla_attend"),
+    ("jit(lm_sample)/while/body/transformer/attn_1/cache_write/dynamic_update_slice", "cache_write"),
+    ("jit(lm_sample)/while/body/transformer/ff_2/moe_shared/dot_general", "moe_shared"),
+    ("jit(lm_sample)/while/body/transformer/ff_2/moe_router/dot_general", "moe_router"),
+    ("jit(lm_sample)/while/body/transformer/ff_0/w_gate/dot_general", "ff"),
+    ("jit(lm_sample)/while/body/sample/top_k", "sample"),
+    ("jit(lm_sample)/while/body/transformer/ff_norms_out_3/rsqrt", "norm_resid"),
+    ("jit(lm_sample)/while/body/CausalLM.decode_step/logits_dense/dot_general", "head"),
+])
+def test_the_new_scopes_fall_under_their_own_components(path, want):
+    assert scopes.component(path)[0] == want
+    assert want in scopes.COMPONENTS
+
+
+def test_the_latent_kernel_is_known_by_its_instruction_name():
+    assert scopes.component(None, "custom-call", "%decode_latent.70") == ("mla_attend", "fwd")
+
+
+@pytest.mark.parametrize("rows,groups,tile", [
+    (512, 16, 128),  # a token step: 2 rows an expert, a tile no taller than the MXU
+    (262144, 16, 512), (32768, 16, 512),  # training and prefill buffers keep the whole tile
+    (192, 4, 192), (64, 16, 64),  # shorter buffers are one tile, as they were
+])
+def test_the_grouped_products_row_tile_follows_a_groups_share(rows, groups, tile):
+    assert grouped_matmul._row_tile(rows, groups) == tile
+
+
+def test_the_cli_generates_token_ids_from_seeded_prompts(tmp_path):
+    out = tmp_path / "ids.json"
+    tiny = ("hidden_size=64 num_attention_heads=4 q_lora_rank=24 kv_lora_rank=16 "
+            "qk_nope_head_dim=8 qk_rope_head_dim=8 v_head_dim=8 intermediate_size=96 "
+            "moe_intermediate_size=32 n_routed_experts=4 num_hidden_layers=3 vocab_size=64 "
+            "dtype=float32 weights_dtype=float32").split()
+    p = subprocess.run(
+        [sys.executable, str(ROOT / "generate_lm.py"), "--prompts", "seeded:3", "--batch", "2",
+         "--prompt_len", "12", "--max_new_tokens", "6", "--filter_thres", "1.0",
+         "--out", str(out), "--set", *tiny],
+        capture_output=True, text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert p.returncode == 0, p.stderr[-2000:]
+    got = json.loads(out.read_text())
+    assert np.asarray(got["tokens"]).shape == (2, 6)
+    assert 0 <= np.min(got["tokens"]) and np.max(got["tokens"]) < 64
+    assert "benchmark" not in "".join(
+        line for line in open(ROOT / "generate_lm.py") if line.startswith(("import", "from")))

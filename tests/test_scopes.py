@@ -175,7 +175,8 @@ def program_texts():
     avars = attn.init(jax.random.PRNGKey(4), x)
     quant = jax.jit(lambda v, x, c: attn.apply(v, x, cache=c)).lower(
         avars, x, cache).compile().as_text()
-    return {"train": train, "sample": sample, "quant": quant, "lm": _lm_step_text()}
+    return {"train": train, "sample": sample, "quant": quant, "lm": _lm_step_text(),
+            "lm_sample": _lm_sample_text()}
 
 
 def _lm_step_text() -> str:
@@ -198,6 +199,27 @@ def _lm_step_text() -> str:
         tx=make_optimizer(3e-4, clip_grad_norm=0.5))
     step = jax.jit(make_lm_train_step(lm), donate_argnums=0)
     return step.lower(state, {"tokens": tokens}, jax.random.PRNGKey(1)).compile().as_text()
+
+
+def _lm_sample_text() -> str:
+    """Compiled text of a tiny language-model sampler: latent attention over
+    its cache, a dense layer and a routed one with a shared expert."""
+    from dalle_pytorch_tpu.models import lm
+
+    rope = {"type": "default", "dim": 8, "theta": 1e4}
+    mdl = lm.CausalLM(
+        num_tokens=40, dim=32, depth=2, seq_len=16, heads=2, dim_head=16,
+        trunk=dict(norm="rms", use_bias=False, layerscale=False, sandwich_norm=True,
+                   attn_types=("latent",), rotary_specs={"latent": rope}, q_lora_rank=12,
+                   kv_lora_rank=8, qk_nope_dim=8, qk_rope_dim=8, v_dim=8,
+                   ff_kinds=("swiglu", "swiglu_experts"), ff_dim=48, experts_total=4,
+                   experts_per_token=2, experts_held=(0, 2), expert_dim=16, moe_buffer_rows=64,
+                   moe_score="sigmoid", routed_scale=2.5, shared_dim=16))
+    variables = jax.jit(mdl.init)(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    sampler = jax.jit(lm._sampler_builder(mdl, (4, 0.9, 1.0, 1)), donate_argnums=(2,))
+    return sampler.lower(
+        variables, jax.random.PRNGKey(1), mdl.init_cache(2), jnp.zeros((2, 2), jnp.int32),
+        jnp.asarray(8, jnp.int32)).compile().as_text()
 
 
 def test_every_rule_is_hit_by_the_programs_own_text(program_texts):

@@ -596,3 +596,39 @@ def test_routed_layer_moves_walk_the_rows_present(one_chip, compiled_kernels, mo
     assert set(gathered) == {f"bf16[{chunk},{dim}]", f"bf16[{tokens},{dim}]"}, gathered
     assert copies == [], copies
     assert len(re.findall(r" while\(", text)) >= 8  # the moves, forward and backward
+
+
+def test_latent_decode_attention_compiles(one_chip, monkeypatch):
+    """`decode_latent` at the generation cell's shapes (64 rows, 128 heads
+    against one latent of 512 + a shared rotary key of 64, 8,480 positions,
+    blocks of 1,024): Mosaic takes the block the cache's end cuts short, the
+    rotary key with its positions last, and the VMEM the block asks for; and
+    the cache's two leaves reach the kernel as they are declared, with no
+    copy of either (a 64-wide last axis was transposed and copied back at
+    every call: models/decode_cache.py)."""
+    from dalle_pytorch_tpu.ops import latent_decode as ld
+
+    monkeypatch.setattr(ld, "_use_interpret", lambda: False)
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    fn = functools.partial(ld.latent_decode_attention, sm_scale=192**-0.5)
+    compiled = _compile(fn, s(64, 128, 512), s(64, 128, 64), s(64, 8480, 512), s(64, 64, 8480),
+                        _i32(one_chip, 64))
+    text = compiled.as_text()
+    assert re.search(r"%decode_latent[.\d]* = bf16\[64,128,512\]", text)
+    assert not re.search(r"= bf16\[64,(8480,512|64,8480)\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+    assert ld.BLOCK_POSITIONS == 1024
+
+
+@pytest.mark.parametrize("k,n", [(7680, 2048), (2048, 7680)], ids=["gate_up", "down"])
+def test_grouped_matmul_compiles_at_a_token_steps_sizes(one_chip, monkeypatch, k, n):
+    """`gmm_fwd` as a token step of the generation cell calls it: a buffer of
+    512 rows for 16 held experts stored in bf16, tiles of 128 rows."""
+    from dalle_pytorch_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_use_interpret", lambda: False)
+    lhs = jax.ShapeDtypeStruct((512, k), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((16, k, n), jnp.bfloat16, sharding=one_chip)
+    text = _compile(gm.grouped_matmul, lhs, rhs, _i32(one_chip, 16)).as_text()
+    assert re.search(rf"%gmm_fwd[.\d]* = bf16\[512,{n}\]", text)
+    assert gm._row_tile(512, 16) == 128 and (gm._tile(7680), gm._tile(2048)) == (768, 1024)
