@@ -23,11 +23,21 @@ work is the list of (tile, group) pairs that share a row, at most `tiles +
 groups - 1` of them: made on the device from `group_sizes` (`_plan`), handed
 to the kernels as prefetched scalars, and walked by the grid. Every pair
 multiplies its whole tile by its group's matrix and keeps the rows that are
-the group's; a group without rows still gets one pair, which owns no row
-(so `drhs` writes it a zero matrix); grid steps past the last pair name
-that pair's blocks again (no copy) and do nothing. Three kernels, named for the device trace:
-`gmm_fwd`, `gmm_dlhs` (the same body, the matrices read transposed) and
-`gmm_drhs`. Interpreted on the CPU backend, like the flash kernels.
+the group's. Three kernels, named for the device trace: `gmm_fwd`,
+`gmm_dlhs` (the same body, the matrices read transposed) and `gmm_drhs`.
+Interpreted on the CPU backend, like the flash kernels.
+
+A group without rows. The two rows products give it NO pair: a pair's grid
+steps name the group's matrix, block by block, and the pipeline fetches
+what a step names, so a pair that owns no row would read a whole matrix to
+keep nothing of it (a token step's 58 rows fall on 5 or 6 of 16 held
+experts: PERF.md, PR 32). Their grid walks `n_pairs` pairs, a bound read
+on the device, and no step past them; where no group has a row it walks one
+step that multiplies and stores nothing. `gmm_drhs` owes every group a
+matrix, so there a group without rows keeps one pair, which owns no row and
+stores the zeros it accumulated; its grid is the static `tiles + groups -
+1`, and the steps past the last pair name that pair's blocks again (no copy)
+and do nothing.
 
 `lax.ragged_dot` is the same product and the chip compiles it to a grouped
 kernel of its own; at the routed layer's shapes it was a third as fast
@@ -57,19 +67,24 @@ def _tile(n: int) -> int:
     return max(fits) if fits else n
 
 
-def _plan(group_sizes: jnp.ndarray, n_tiles: int, tm: int):
+def _plan(group_sizes: jnp.ndarray, n_tiles: int, tm: int, *, empty_groups: bool):
     """(offsets [G + 1], pair_group [W], pair_tile [W], n_pairs [1]) for the
-    (tile, group) pairs that share a row, in row order, and one pair for each
-    group without rows (on the tile where it would start); W = tiles +
-    groups - 1 is static, the pairs past `n_pairs` repeat the last one."""
+    (tile, group) pairs that share a row, in row order, so that a tile's
+    pairs stand back to back, and a group's. With `empty_groups` (for
+    `gmm_drhs`, which writes every group's matrix) a group without rows gets
+    one pair too, on the tile where it would start; without (for the rows
+    products, which would only read its matrix) it gets none, and `n_pairs`
+    is 0 where no group has a row. W = tiles + groups - 1 is static, the
+    pairs past `n_pairs` repeat the last one."""
     groups = group_sizes.shape[0]
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
     first = jnp.minimum(starts // tm, n_tiles - 1)
-    tiles_of = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 1)
+    tiles_of = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, int(empty_groups))
     pair_ends = jnp.cumsum(tiles_of)
     n_pairs = pair_ends[-1]
     w = jnp.minimum(jnp.arange(n_tiles + groups - 1), jnp.maximum(n_pairs - 1, 0))
+    # the group whose pairs end past w: `right` steps over the groups without pairs
     pair_group = jnp.minimum(jnp.searchsorted(pair_ends, w, side="right"), groups - 1)
     pair_tile = first[pair_group] + w - (pair_ends - tiles_of)[pair_group]
     offsets = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends])
@@ -91,7 +106,7 @@ def _rows_kernel(offsets_ref, group_ref, tile_ref, n_ref, lhs_ref, rhs_ref, out_
     by several groups is visited once for each, back to back, and stays in
     VMEM between the visits: each keeps what the others wrote."""
     w, k = pl.program_id(1), pl.program_id(2)
-    live = w < n_ref[0]
+    live = w < n_ref[0]  # false only in the one step of a walk without pairs
 
     @pl.when(k == 0)
     def _init():
@@ -148,8 +163,10 @@ def _row_tile(rows: int, groups: int = 1) -> int:
     """Rows a tile holds: TILE_ROWS, or all the rows of a shorter buffer.
     Every pair multiplies its WHOLE tile by its group's matrix, so where a
     group's even share of the buffer is under a tile (a token step: 512 rows
-    for 16 experts, about 2 present each), a tile no taller than the MXU
-    keeps a pair's arithmetic under its matrix's read."""
+    for 16 experts, 58 present on 5 or 6 of them), a tile no taller than the
+    MXU keeps a pair's arithmetic under its matrix's read: the rows products
+    ask with their groups; `gmm_drhs`, whose pairs read rows and no matrix,
+    keeps the whole tile."""
     if rows < TILE_ROWS:
         return -(-rows // 8) * 8
     return TILE_ROWS if rows // groups >= TILE_ROWS else 128
@@ -164,10 +181,10 @@ def _emit_rows(lhs, rhs, group_sizes, *, transposed, interpret):
     tm, tk, tn = _row_tile(rows, rhs.shape[0]), _tile(k), _tile(n)
     lhs = _padded(lhs, tm)
     n_tiles = lhs.shape[0] // tm
-    plan = _plan(group_sizes, n_tiles, tm)
+    plan = _plan(group_sizes, n_tiles, tm, empty_groups=False)
     n_k = k // tk
 
-    # a step past the last pair names the last pair's last blocks again
+    # no pair at all: the one step walked names one block of each operand
     def k_of(w, kk, n_ref):
         return jnp.where(w < n_ref[0], kk, n_k - 1)
 
@@ -181,7 +198,7 @@ def _emit_rows(lhs, rhs, group_sizes, *, transposed, interpret):
         name="gmm_dlhs" if transposed else "gmm_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n // tn, plan[1].shape[0], n_k),
+            grid=(n // tn, jnp.maximum(plan[3][0], 1), n_k),  # the pairs there are, not W
             in_specs=[lhs_spec, rhs_spec],
             out_specs=pl.BlockSpec((tm, tn), lambda j, w, kk, o, g, t, n_: (t[w], j)),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
@@ -201,7 +218,7 @@ def _emit_drhs(lhs, dout, group_sizes, *, out_dtype, interpret):
     n, groups = dout.shape[1], group_sizes.shape[0]
     tm, tk, tn = _row_tile(rows), _tile(k), _tile(n)
     lhs, dout = _padded(lhs, tm), _padded(dout, tm)
-    plan = _plan(group_sizes, lhs.shape[0] // tm, tm)
+    plan = _plan(group_sizes, lhs.shape[0] // tm, tm, empty_groups=True)
     return pl.pallas_call(
         functools.partial(_drhs_kernel, tm=tm),
         name="gmm_drhs",

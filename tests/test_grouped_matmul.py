@@ -1,12 +1,14 @@
-"""`grouped_matmul` and its VJP against a per-group loop, and the rotate-half
-rotary tables (`default` and YaRN) against numbers written out by hand."""
+"""`grouped_matmul` and its VJP against a per-group loop, the work list the
+kernels walk (`_plan`) against the pairs counted row by row, and the
+rotate-half rotary tables (`default` and YaRN) against numbers written out
+by hand."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dalle_pytorch_tpu.ops.grouped_matmul import grouped_matmul
+from dalle_pytorch_tpu.ops.grouped_matmul import _plan, grouped_matmul
 from dalle_pytorch_tpu.ops.rotary import apply_rotary_half, rotary_cos_sin, rotary_inv_freq
 
 
@@ -40,6 +42,52 @@ def test_grouped_matmul_and_its_vjp_match_a_loop_over_groups(sizes):
     np.testing.assert_allclose(got[0][:live], want[0][:live], atol=1e-5)
     # a group without rows gets a zero gradient, written by its own pair
     np.testing.assert_allclose(got[1], want[1], atol=1e-5)
+
+
+# (group sizes, buffer rows, rows a tile): rows past the sizes belong to no group
+PLANS = {
+    "empty_first": ((0, 10, 17, 5), 40, 8),
+    "empty_middle": ((10, 0, 17, 5), 40, 8),
+    "empty_last": ((10, 17, 5, 0), 40, 8),
+    "two_empty_side_by_side": ((9, 0, 0, 23), 40, 8),
+    "empty_where_the_buffer_ends": ((16, 24, 0, 0), 40, 8),
+    "no_rows": ((0, 0, 0, 0), 40, 8),
+    "every_group_full": ((8, 16, 8, 8), 40, 8),
+    # a token step of the generation cell: 58 rows on 6 of 16 held experts
+    "token_step": ((0, 0, 21, 0, 0, 9, 0, 1, 0, 0, 0, 14, 0, 0, 11, 2), 512, 128),
+}
+
+
+@pytest.mark.parametrize("empty_groups", [False, True], ids=["rows_products", "drhs"])
+@pytest.mark.parametrize("case", PLANS)
+def test_the_work_list_names_the_pairs_that_share_a_row(case, empty_groups):
+    """The rows products (`gmm_fwd`, `gmm_dlhs`) walk the (tile, group) pairs
+    that share a row and no other: a group without rows has no pair, so its
+    matrix is never read. `gmm_drhs` gives such a group one pair besides, on
+    the tile where it would start: that pair writes its zero matrix."""
+    sizes, rows, tm = PLANS[case]
+    n_tiles, groups = rows // tm, len(sizes)
+    ends = np.cumsum(sizes)
+    owner = np.searchsorted(ends, np.arange(ends[-1]), side="right")
+    # for the rows products these and no other: none names a group without rows
+    want = sorted({(int(r) // tm, int(g)) for r, g in enumerate(owner)})
+    if empty_groups:  # and every group is there
+        want += [(min(int(ends[g]) // tm, n_tiles - 1), g) for g in range(groups) if not sizes[g]]
+        want.sort(key=lambda pair: (pair[1], pair[0]))  # a group's pairs together, in group order
+    offsets, pair_group, pair_tile, n_pairs = jax.device_get(
+        _plan(jnp.asarray(sizes, jnp.int32), n_tiles, tm, empty_groups=empty_groups))
+    n = int(n_pairs[0])
+    assert pair_group.shape == pair_tile.shape == (n_tiles + groups - 1,)
+    assert list(zip(pair_tile[:n].tolist(), pair_group[:n].tolist())) == want
+    # a tile's pairs back to back (`_rows_kernel` zeroes a tile on its first
+    # visit), and a group's (`_drhs_kernel` stores on a group's last)
+    for walked in (pair_tile[:n], pair_group[:n]):
+        assert np.all(np.diff(walked) >= 0)
+    # the steps past the last pair name its blocks again, inside the arrays
+    last = max(n - 1, 0)
+    assert np.all(pair_group[last:] == pair_group[last]) and 0 <= pair_group[last] < groups
+    assert np.all(pair_tile[last:] == pair_tile[last]) and 0 <= pair_tile[last] < n_tiles
+    np.testing.assert_array_equal(offsets, np.concatenate([[0], ends]))
 
 
 def test_grouped_matmul_keeps_the_operands_dtype_and_float32_parameters():

@@ -3,7 +3,8 @@ of, each against a plain statement of what it computes, at small sizes on
 the CPU: the latent decode kernel (interpreted) on ragged rows, the routed
 layer's sigmoid scores, scale and shared expert, the share test, the
 bf16-stored parameter tree the configuration states, the latent kind of cache
-layer, the new scopes' components, the grouped product's row tile, and the
+layer, the new scopes' components, the grouped product's row tile, a token
+step's routed layer over a buffer whose unowned rows are NaN, and the
 `generate_lm.py` command. The model end to end is `tests/test_lm_decode.py`."""
 import json
 import os
@@ -17,7 +18,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import pangu_ref
-from dalle_pytorch_tpu.models import decode_cache
+from dalle_pytorch_tpu.models import decode_cache, moe
 from dalle_pytorch_tpu.models.lm import CausalLM
 from dalle_pytorch_tpu.models.moe import RoutedExperts
 from dalle_pytorch_tpu.obs import scopes
@@ -56,6 +57,28 @@ def test_the_latent_decode_kernel_matches_dense_on_ragged_rows(block):
     np.testing.assert_array_equal(got, again)
 
 
+def _layer_by_loop(params, x, k, held, scale):
+    """[T, dim] float64: the routed layer of tokens [T, dim] as a loop over
+    tokens and their chosen experts: sigmoid scores, the `k` largest
+    renormalised and scaled, the experts `held = (first, count)` alone, the
+    shared expert beside them."""
+    p = {n: np.asarray(v, np.float64) for n, v in params.items()}
+    silu = lambda t: t / (1 + np.exp(-t))
+    first, count = held
+    out = []
+    for h in np.asarray(x, np.float64):
+        s = 1 / (1 + np.exp(-(h @ p["router"])))
+        chosen = np.argsort(-s)[:k]
+        want = (silu(h @ p["shared_gate"]) * (h @ p["shared_up"])) @ p["shared_out"]
+        for e in chosen:
+            if first <= e < first + count:
+                w = scale * s[e] / s[chosen].sum()
+                g = e - first
+                want += w * (silu(h @ p["w_gate"][g]) * (h @ p["w_up"][g])) @ p["w_out"][g]
+        out.append(want)
+    return np.stack(out)
+
+
 def test_the_router_scores_scaling_and_shared_expert_match_a_per_token_loop():
     dim, width, total, k = 16, 8, 8, 2
     layer = RoutedExperts(dim=dim, expert_dim=width, experts_total=total, experts_per_token=k,
@@ -64,17 +87,7 @@ def test_the_router_scores_scaling_and_shared_expert_match_a_per_token_loop():
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 10, dim))
     params = layer.init(jax.random.PRNGKey(2), x)["params"]
     got = np.asarray(layer.apply({"params": params}, x))[0]
-    p = {n: np.asarray(v, np.float64) for n, v in params.items()}
-    silu = lambda t: t / (1 + np.exp(-t))
-    for t, h in enumerate(np.asarray(x[0], np.float64)):
-        s = 1 / (1 + np.exp(-(h @ p["router"])))
-        chosen = np.argsort(-s)[:k]
-        want = (silu(h @ p["shared_gate"]) * (h @ p["shared_up"])) @ p["shared_out"]
-        for e in chosen:
-            if 2 <= e < 6:
-                w = 2.5 * s[e] / s[chosen].sum()
-                want += w * (silu(h @ p["w_gate"][e - 2]) * (h @ p["w_up"][e - 2])) @ p["w_out"][e - 2]
-        np.testing.assert_allclose(got[t], want, atol=2e-5)
+    np.testing.assert_allclose(got, _layer_by_loop(params, x[0], k, (2, 4), 2.5), atol=2e-5)
 
 
 def test_the_routed_layers_defaults_are_the_softmax_router_alone():
@@ -202,6 +215,52 @@ def test_the_latent_kernel_is_known_by_its_instruction_name():
 ])
 def test_the_grouped_products_row_tile_follows_a_groups_share(rows, groups, tile):
     assert grouped_matmul._row_tile(rows, groups) == tile
+
+
+@pytest.mark.parametrize("touched", [(), (2, 5, 6, 11, 15)], ids=["no_expert", "5_of_16"])
+def test_a_token_steps_routed_layer_takes_nothing_from_rows_no_group_owns(monkeypatch, touched):
+    """A token step's shapes (64 rows, 8 choices each, 16 experts held, a
+    buffer of 512 rows in tiles of 128) with the router steered: no held
+    expert gets a row (the layer is then its shared expert alone), or 5 of
+    the 16 do (58 rows, as in the cell). A group without rows has no pair in
+    the forward product's work list, so whole tiles of the buffer are never
+    written; here every row that no group owns comes out of every grouped
+    product as NaN, and none may reach a token."""
+    tokens, dim, width, total, k, held = 64, 32, 16, 32, 8, 16
+    assert grouped_matmul._row_tile(tokens * k, held) == 128
+
+    def poisoned(lhs, rhs, group_sizes):
+        out = grouped_matmul.grouped_matmul(lhs, rhs, group_sizes)
+        owned = jnp.arange(out.shape[0])[:, None] < jnp.sum(group_sizes)
+        return jnp.where(owned, out, jnp.nan)
+
+    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+    layer = RoutedExperts(dim=dim, expert_dim=width, experts_total=total, experts_per_token=k,
+                          experts_held=(0, held), buffer_rows=tokens * k, score="sigmoid",
+                          routed_scale=2.5, shared_dim=24)
+    rng = np.random.default_rng(5)
+    logits = rng.normal(size=(tokens, total)).astype(np.float32) * 0.1
+    t = np.arange(tokens)
+    for j in range(k):  # every choice an expert held elsewhere ...
+        logits[t, held + (t + j) % (total - held)] = 4.0 + j
+    if touched:  # ... but the first of 58 tokens, which is one of the touched
+        sends = t[t % 11 != 0]
+        logits[sends, held + sends % (total - held)] = 0.0
+        logits[sends, np.asarray(touched)[sends % len(touched)]] = 4.0
+    x = rng.normal(size=(tokens, dim)).astype(np.float32)
+    x[:, :total] = logits  # a token's first features are its router logits
+    params = layer.init(jax.random.PRNGKey(2), jnp.zeros((1, 8, dim)))["params"]
+    params = {**params, "router": jnp.eye(dim, total)}
+    got, aux = layer.apply({"params": params}, jnp.asarray(x)[None], mutable=["stats"])
+    got, load = np.asarray(got)[0], np.asarray(aux["stats"]["moe_load"])
+    assert np.flatnonzero(load).tolist() == list(touched)
+    assert int(aux["stats"]["moe_rows"]) == (58 if touched else 0)
+    assert int(aux["stats"]["moe_dropped"]) == 0 and np.isfinite(got).all()
+    if touched:
+        np.testing.assert_allclose(got, _layer_by_loop(params, x, k, (0, held), 2.5), atol=2e-5)
+    else:
+        np.testing.assert_array_equal(got, np.asarray(layer.apply(
+            {"params": params}, jnp.asarray(x), method=RoutedExperts.shared)))
 
 
 def test_the_cli_generates_token_ids_from_seeded_prompts(tmp_path):
