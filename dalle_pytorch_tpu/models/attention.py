@@ -40,6 +40,7 @@ import flax.linen as nn
 
 from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.ops.attention_core import dense_attention
+from dalle_pytorch_tpu.ops.delta_step import delta_step
 from dalle_pytorch_tpu.ops.latent_decode import latent_decode_attention
 from dalle_pytorch_tpu.ops.pallas_attention import (
     flash_attention,
@@ -151,13 +152,16 @@ class Attention(nn.Module):
     # K/V heads, each shared by `heads // kv_heads` query heads (None: one
     # per query head, the DALL-E layout and its fused 3 x inner projection)
     kv_heads: Optional[int] = None
-    # per-head RMS norm of q and k over dim_head, before the rotation
-    qk_norm: bool = False
+    # RMS norm of q and k before the rotation: True, per head over dim_head;
+    # "whole", one gain over all of a projection's columns, before the heads
+    # are split
+    qk_norm: Any = False
     norm_eps: float = 1e-6
     # sliding window: query t sees key p iff 0 <= t - p < window
     window: Optional[int] = None
     use_bias: bool = True  # to_out's (to_qkv never had one)
     dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32  # what the two matrices are stored in
 
     def _use_flash(self, n: int, key_mask) -> bool:
         """Flash path: static masks only (dynamic key-padding stays dense)."""
@@ -214,20 +218,23 @@ class Attention(nn.Module):
 
     def _grouped_qkv(self, x, rotary_cs):
         """q [B, H, n, dh] and k, v [B, kv_heads, n, dh] from one fused
-        projection of (H + 2 kv_heads) x dh columns; q and k normed per head
-        and turned by the rotate-half tables, v left as it is."""
+        projection of (H + 2 kv_heads) x dh columns; q and k normed (per head,
+        or over their whole width) and turned by the rotate-half tables where
+        there are any, v left as it is."""
         b, n, _ = x.shape
         h, dh = self.heads, self.dim_head
         hkv = h if self.kv_heads is None else self.kv_heads
         assert h % hkv == 0, f"{h} query heads cannot share {hkv} K/V heads"
         qkv = nn.Dense((h + 2 * hkv) * dh, use_bias=False, dtype=self.dtype,
-                       name="to_qkv")(x)
+                       param_dtype=self.param_dtype, name="to_qkv")(x)
         q, k, v = jnp.split(qkv, [h * dh, (h + hkv) * dh], axis=-1)
+        norm = lambda name: nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name=name)
+        if self.qk_norm == "whole":
+            q, k = norm("q_norm")(q), norm("k_norm")(k)
         q = q.reshape(b, n, h, dh)
         k, v = (t.reshape(b, n, hkv, dh) for t in (k, v))
-        if self.qk_norm:
-            q = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="q_norm")(q)
-            k = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name="k_norm")(k)
+        if self.qk_norm is True:
+            q, k = norm("q_norm")(q), norm("k_norm")(k)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         if rotary_cs is not None:
             with jax.named_scope("rotary"):
@@ -312,11 +319,14 @@ class Attention(nn.Module):
             q, k, v = self._grouped_qkv(x, rotary_cs)
 
         new_cache = None
-        if cache is not None and (grouped or self.window is not None):
+        if cache is not None and (
+            k.shape[1] != h or self.window is not None or rotary_cs is not None
+        ):
             raise NotImplementedError(
                 "cached decode keeps one K/V head per query head and one cache "
-                "geometry: kv_heads, qk_norm, a window and the rotate-half "
-                "rotary are training-only (ROADMAP.md, Queue 2 B)"
+                "geometry: fewer K/V heads than query heads, a window and the "
+                "rotate-half rotary are training-only (ROADMAP.md, Queue 2 B); "
+                "q/k norms decode through the cache"
             )
         if cache is not None:
             # n-token chunk (prefill or single-token decode) written into a
@@ -578,7 +588,7 @@ class Attention(nn.Module):
 
         out = out.transpose(0, 2, 1, 3).reshape(b, n, inner)
         out = nn.Dense(self.dim, use_bias=self.use_bias, dtype=self.dtype,
-                       name="to_out")(out)
+                       param_dtype=self.param_dtype, name="to_out")(out)
         out = nn.Dropout(self.dropout)(out, deterministic=deterministic)
         return out, new_cache
 
@@ -683,3 +693,170 @@ class LatentAttention(nn.Module):
             out = out.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
         with jax.named_scope("mla_proj"):
             return dense(self.dim, "to_out")(out), new_cache
+
+
+def delta_rule_chunked(q, k, v, g, beta, chunk: int = 64):
+    """The gated delta rule over n tokens from an EMPTY state, a chunk at a
+    time: `(o [b, n, h, d_v], S [b, h, d_k, d_v])`, float32 throughout.
+
+    q, k [b, n, h, d_k], v [b, n, h, d_v]; g [b, n, h] the LOG of the decay
+    alpha, beta [b, n, h]. With Gamma_t the decay's running product inside a
+    chunk and S_0 the state it starts from, the chunk's updates solve
+
+        (I + A) U = diag(beta) V - diag(beta Gamma) K S_0,
+        A[t, j] = beta_t (k_t . k_j) Gamma_t / Gamma_j   for j < t,
+
+    so T = (I + A)^-1 is made once a chunk by forward substitution (the WY /
+    UT transform of arXiv:2412.06464), `u = T diag(beta) V` and `w = T
+    diag(beta Gamma) K` need no state, and a `lax.scan` over the chunks
+    carries S alone: U = u - w S_0, o_t = Gamma_t S_0^T q_t + sum_{j <= t}
+    (Gamma_t / Gamma_j) (k_j . q_t) U_j, S_C = Gamma_C S_0 + sum_j (Gamma_C /
+    Gamma_j) k_j U_j^T. A tail chunk is padded with tokens that change
+    nothing (k = v = 0, beta = 0, alpha = 1)."""
+    b, n, h, dk = q.shape
+    pad = (-n) % chunk
+    mm = lambda spec, x, y: jnp.einsum(spec, x, y, precision="highest")
+
+    def chunks(t):  # [b, n, h, ...] -> [b, h, c, chunk, ...]
+        t = jnp.pad(t.astype(jnp.float32), ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        t = t.reshape((b, -1, chunk) + t.shape[2:])
+        return jnp.moveaxis(t, 3, 1)
+
+    q, k, v, g, beta = (chunks(t) for t in (q, k, v, g, beta))
+    gamma = jnp.cumsum(g, axis=-1)  # log Gamma [b, h, c, chunk]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    ratio = jnp.where(lower, gamma[..., :, None] - gamma[..., None, :], 0.0)
+    decay = jnp.where(lower, jnp.exp(ratio), 0.0)  # Gamma_t / Gamma_j, j <= t
+    kb, vb = k * beta[..., None], v * beta[..., None]
+    m = -jnp.where(strict, mm("...td,...jd->...tj", kb, k) * decay, 0.0)
+
+    def substitute(i, m):  # row i of (I + A)^-1 - I from the rows above it
+        row = jnp.where(jnp.arange(chunk) < i, m[..., i, :], 0.0)
+        return m.at[..., i, :].set(row + jnp.sum(row[..., :, None] * m, axis=-2))
+
+    t_inv = lax.fori_loop(1, chunk, substitute, m) + jnp.eye(chunk, dtype=jnp.float32)
+    u = mm("...tj,...jd->...td", t_inv, vb)
+    w = mm("...tj,...jd->...td", t_inv, kb * jnp.exp(gamma)[..., None])
+    qk = jnp.where(lower, mm("...td,...jd->...tj", q, k) * decay, 0.0)
+    q_in = q * jnp.exp(gamma)[..., None]
+    k_out = k * jnp.exp(gamma[..., -1:] - gamma)[..., None]
+    total = jnp.exp(gamma[..., -1])  # Gamma_C [b, h, c]
+
+    def one(s, xs):
+        u_i, w_i, qk_i, q_i, k_i, total_i = xs
+        new = u_i - mm("bhtk,bhkv->bhtv", w_i, s)
+        o = mm("bhtk,bhkv->bhtv", q_i, s) + mm("bhtj,bhjv->bhtv", qk_i, new)
+        return s * total_i[..., None, None] + mm("bhtk,bhtv->bhkv", k_i, new), o
+
+    per_chunk = lambda t: jnp.moveaxis(t, 2, 0)
+    s, o = lax.scan(one, jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32),
+                    tuple(per_chunk(t) for t in (u, w, qk, q_in, k_out, total)))
+    o = jnp.moveaxis(o, 0, 2).reshape(b, h, -1, v.shape[-1])[:, :, :n]
+    return o.transpose(0, 2, 1, 3), s
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    dt = jax.random.uniform(key, shape, dtype, 0.001, 0.1)
+    return dt + jnp.log(-jnp.expm1(-dt))  # the inverse of softplus
+
+
+class GatedDeltaAttention(nn.Module):
+    """Causal LINEAR attention by the gated delta rule (Gated DeltaNet,
+    arXiv:2412.06464; beta in (0, 2), arXiv:2411.12537): per row and head a
+    float32 state S [key_dim, value_dim] in place of keys and values (in the
+    cache a row's heads lie side by side: `decode_cache.pack_state`).
+
+        q~ | k~ | v~ = x W_qkv          [H x key_dim | H x key_dim | H x value_dim]
+        q', k', v' = silu(conv(q~)), silu(conv(k~)), silu(conv(v~))
+                     causal depthwise convolution over time, `conv_width` taps
+        q = l2norm(q') / sqrt(key_dim),  k = l2norm(k'),  v = v'        per head
+        beta = 2 sigmoid(x W_b),  alpha = exp(-exp(A_log) softplus(x W_a + dt_bias))
+        S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T
+        o_t = S_t^T q_t;  y_t = rmsnorm(o_t; gain [value_dim]) silu(x W_g)
+        out = concat_h(y_t) W_o
+
+    Two forms that tests hold to each other. Without a cache, and for a
+    prefill chunk written into one, the CHUNKED form from an empty state
+    (`delta_rule_chunked`; a prefill of further tokens against a state is not
+    built), which also leaves the state and the convolution's ring (its last
+    `conv_width - 1` inputs) in the cache. With a one-token step, the
+    RECURRENCE against the cache (ops/delta_step.py: one pass over the
+    state, in place). The cache is the `recurrent` kind of layer
+    (models/decode_cache.py).
+
+    The three projections that feed the recurrence, W_a and W_b give float32
+    (bf16 operands, float32 accumulation, nothing rounded after), and the
+    convolution, the norms, alpha and beta are float32: what is summed into a
+    state for thousands of steps is not rounded on its way there.
+
+    Parameters: `to_qkv`, `to_ab` [dim, 2 H] (beta's columns, then alpha's),
+    `to_gate`, `to_out`, `conv` [conv_width, columns of to_qkv] in
+    `param_dtype`; `A_log`, `dt_bias` [H] and `o_norm` [value_dim] float32.
+    """
+
+    dim: int
+    seq_len: int
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv_width: int = 4
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, key_mask=None, rotary=None, cache=None, deterministic=True):
+        assert key_mask is None and rotary is None, "linear attention is causal and unpadded"
+        b, n, _ = x.shape
+        h, dk, dv, taps = self.heads, self.key_dim, self.value_dim, self.conv_width
+        width = h * (2 * dk + dv)
+        matrix = lambda name, shape: self.param(
+            name, nn.initializers.lecun_normal(), shape, self.param_dtype)
+        dense = lambda cols, name: nn.Dense(
+            cols, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype, name=name)
+        to_qkv, to_ab = matrix("to_qkv", (self.dim, width)), matrix("to_ab", (self.dim, 2 * h))
+        conv = matrix("conv", (taps, width)).astype(jnp.float32)
+        a_log = self.param("A_log", _a_log_init, (h,))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (h,))
+        o_gain = self.param("o_norm", nn.initializers.ones, (dv,))
+        step = cache is not None and n == 1
+        with jax.named_scope("delta_proj"):
+            x = x.astype(self.dtype)
+            wide = lambda w: jnp.dot(x, w.astype(self.dtype), preferred_element_type=jnp.float32)
+            qkv, ab = wide(to_qkv), wide(to_ab)
+            gate = dense(h * dv, "to_gate")(x)
+            beta = 2.0 * jax.nn.sigmoid(ab[..., :h])
+            g = -jnp.exp(a_log) * jax.nn.softplus(ab[..., h:] + dt_bias)  # log alpha
+            # the convolution's inputs: the ring (zeros before a sequence
+            # starts), then this call's
+            ring = (cache[decode_cache.CONV].astype(jnp.float32) if step
+                    else jnp.zeros((b, taps - 1, width), jnp.float32))
+            window = jnp.concatenate([ring, qkv], axis=1)  # [b, taps - 1 + n, width]
+            mixed = sum(conv[j] * window[:, j:j + n] for j in range(taps))
+            q, k, v = jnp.split(jax.nn.silu(mixed), [h * dk, 2 * h * dk], axis=-1)
+            q, k = (t.reshape(b, n, h, dk) for t in (q, k))
+            unit = lambda t: t * lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+            q, k, v = unit(q) * dk**-0.5, unit(k), v.reshape(b, n, h, dv)
+        if step:
+            with jax.named_scope("delta_step"):
+                o, state = delta_step(cache[decode_cache.STATE], q[:, 0], k[:, 0], v[:, 0],
+                                      jnp.exp(g[:, 0]), beta[:, 0])
+                o = o[:, None]
+        else:
+            with jax.named_scope("delta_chunk"):
+                o, state = delta_rule_chunked(q, k, v, g, beta)
+                state = decode_cache.pack_state(state)
+        new_cache = None
+        if cache is not None:
+            new_cache = {**cache, decode_cache.STATE: state,
+                         decode_cache.CONV: window[:, n:].astype(cache[decode_cache.CONV].dtype),
+                         decode_cache.INDEX: cache[decode_cache.INDEX] + n}
+        with jax.named_scope("delta_proj"):
+            o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + self.norm_eps) * o_gain
+            y = o.astype(self.dtype) * jax.nn.silu(gate.reshape(b, n, h, dv))
+            return dense(self.dim, "to_out")(y.reshape(b, n, h * dv)), new_cache

@@ -32,10 +32,39 @@ call of a kernel that wants them as declared (139 MB a layer a step at 64 x
 8,480: PERF.md, PR 31). With the positions last, a position costs its
 latent_dim + rope_dim numbers and no more. Per-layer layout, scalar `index`.
 
+A RECURRENT layer (`layer_spec(kind="recurrent")`: linear attention by the
+gated delta rule, models/attention.py:GatedDeltaAttention) holds no position
+at all but what the sequence so far has been folded into:
+
+  * `state` float32 [B, key_dim, H * value_dim]: each head's matrix
+    [key_dim, value_dim], a row's heads side by side along the last axis
+    (`pack_state` / `running_state`). Not [B, H, key_dim, value_dim]: the
+    chip lays a last axis out in tiles of 128 lanes, so 192 numbers there
+    are stored, read and written as 256 (a third more of the largest
+    traffic of a step, and 1.0 GB more at 56 rows x 30 heads x 12 layers
+    with the snapshot; the compiler's plan, PERF.md, PR 33); 30 x 192 are
+    45 whole tiles;
+  * `conv` float32 [B, taps - 1, columns]: the ring of the short
+    convolution's last inputs (float32 like the state: the token step's
+    convolution reads the numbers the chunked prefill read);
+  * `state_at`, `conv_at`: the SNAPSHOT, the two leaves above as they stood
+    at the position `set_index` goes back to.
+
+A K/V layer is rewound by its `index` alone, because what lies past it is
+masked and overwritten. A state cannot be rewound: the tokens of a turn are
+folded in. So a cache that takes further turns over one document keeps each
+row's state at its document's end beside the running one: `snapshot(cache)`
+copies running to kept (after a prefill), `restore(cache)` kept to running
+(before a turn; it also hands the kept leaves out of the tree while a token
+loop runs, and `snapshot(cache, kept)` puts them back), and those two
+functions alone know the pair. Both leave a cache without a recurrent layer
+as it is. Per-layer layout, scalar `index`.
+
 and a cache holds `depth` layers in one of two LAYOUTS:
 
   * PER_LAYER: a dict of `depth` such layers under `layer_{i}` (the unrolled
-    executor walks them in Python);
+    executor walks them in Python); the layers may differ in KIND
+    (`make(..., kinds=[...])`: recurrent and K/V layers in one tree);
   * STACKED: the same leaves under a leading `depth` axis (they ride the
     scan executor's carry; each layer writes and reads at its own index).
 
@@ -64,6 +93,9 @@ K, V, K_SCALE, V_SCALE, INDEX = "k", "v", "k_scale", "v_scale", "index"
 SCALE_KEYS = (K_SCALE, V_SCALE)
 LATENT, ROPE = "latent", "rope"
 LATENT_KEYS = (LATENT, ROPE)
+STATE, CONV = "state", "conv"
+# a recurrent layer's running leaves and, beside each, its snapshot
+SNAPSHOT = {STATE: "state_at", CONV: "conv_at"}
 KV_KEYS = (K, V) + SCALE_KEYS
 RING_KEYS = ("shift_attn", "shift_ff")
 PAGE_TABLE, BLOCK_BITMAP, RING_END = "page_table", "block_bitmap", "ring_end"
@@ -109,12 +141,19 @@ def layer_spec(
     kind: str = "heads",
     latent_dim: Optional[int] = None,
     rope_dim: Optional[int] = None,
+    key_dim: Optional[int] = None,
+    value_dim: Optional[int] = None,
+    conv_taps: Optional[int] = None,
+    linear_heads: Optional[int] = None,
 ) -> dict:
     """ONE layer's leaves as `jax.ShapeDtypeStruct`s, from the geometry.
 
     `kind="latent"`: `latent` [batch, max_len, latent_dim] and `rope`
     [batch, rope_dim, max_len] with a scalar `index`, and nothing else (no
-    pages, no int8 store, no rings). Otherwise:
+    pages, no int8 store, no rings). `kind="recurrent"`: `state`
+    [batch, key_dim, linear_heads * value_dim] and `conv` [batch, conv_taps - 1,
+    linear_heads * (2 key_dim + value_dim)], both float32, their snapshot
+    beside them, and a scalar `index`. Otherwise:
 
     K/V are lanes [batch, heads, max_len, dim_head], or with `pages =
     (n_pages, page_size)` a pool [n_pages, heads, page_size, dim_head]
@@ -131,6 +170,16 @@ def layer_spec(
             ROPE: spec((batch, rope_dim, max_len), dtype),
             INDEX: spec((), jnp.int32),
         }}
+    if kind == "recurrent":
+        assert pages is None and kv_dtype is None and not per_row and not shift_tokens, (
+            "a recurrent cache is one state a row, decoded in lockstep")
+        running = {
+            STATE: spec((batch, key_dim, linear_heads * value_dim), jnp.float32),
+            CONV: spec((batch, conv_taps - 1, linear_heads * (2 * key_dim + value_dim)),
+                       jnp.float32),
+        }
+        return {ATTN: {**running, **{SNAPSHOT[n]: s for n, s in running.items()},
+                       INDEX: spec((), jnp.int32)}}
     assert kind == "heads", f"unknown cache kind {kind!r}"
     rows, length = (batch, max_len) if pages is None else pages
     kv_dt, scaled = kv_store_dtype(dtype, kv_dtype)
@@ -158,15 +207,21 @@ def _zeros(spec: dict, lead: tuple) -> dict:
     }
 
 
-def make(layout: str, depth: int, **geometry) -> dict:
+def make(layout: str, depth: int, kinds=None, **geometry) -> dict:
     """A zeroed cache of `depth` layers of `layer_spec(**geometry)`: the
-    layout applied in this one place."""
-    spec = layer_spec(**geometry)
-    assert layout == PER_LAYER or LATENT not in spec[ATTN], "a latent cache is per layer"
+    layout applied in this one place. `kinds`: the kind of EACH layer, where
+    they differ (else `geometry`'s one `kind` in every layer)."""
+    kind = geometry.pop("kind", "heads")
+    kinds = tuple(kinds) if kinds else (kind,) * depth
+    assert len(kinds) == depth, f"{len(kinds)} kinds for {depth} layers"
     if layout == STACKED:
-        return _zeros(spec, (depth,))
+        assert set(kinds) == {"heads"}, (
+            f"the stacked layout holds {depth} equal K/V layers under one depth axis; "
+            f"a cache with {sorted(set(kinds))} layers is per layer (the unrolled executor)")
+        return _zeros(layer_spec(kind="heads", **geometry), (depth,))
     assert layout == PER_LAYER, f"unknown cache layout {layout!r}"
-    return {layer_key(i): _zeros(spec, ()) for i in range(depth)}
+    specs = {kind: layer_spec(kind=kind, **geometry) for kind in set(kinds)}
+    return {layer_key(i): _zeros(specs[kind], ()) for i, kind in enumerate(kinds)}
 
 
 # ------------------------------------------------- reading the layout back
@@ -221,6 +276,54 @@ def set_index(cache: dict, pos: jnp.ndarray) -> dict:
         },
         cache,
     )
+
+
+def snapshot(cache: dict, kept: Optional[dict] = None) -> dict:
+    """The cache with every recurrent layer's snapshot leaves: `kept`, what
+    `restore` took out of it, or (left out) the running state and ring as
+    they stand: a session's document is in."""
+    if layout_of(cache) == STACKED:  # K/V layers alone
+        return cache
+
+    def keep(name, layer):
+        attn = layer[ATTN]
+        if STATE not in attn:
+            return layer
+        held = {SNAPSHOT[n]: attn[n] for n in SNAPSHOT} if kept is None else kept[name]
+        return {**layer, ATTN: {**attn, **held}}
+
+    return {name: keep(name, layer) for name, layer in cache.items()}
+
+
+def restore(cache: dict):
+    """`(cache, kept)`: every recurrent layer's running state and ring as
+    `snapshot` kept them (a turn starts where the document ended), and the
+    snapshot leaves themselves taken OUT of the tree, to be put back by
+    `snapshot(cache, kept)`: a token loop carries what it writes, and a leaf
+    that rides its carry untouched costs a buffer of its own. The one device
+    copy is the running leaves'; the K/V layers' part of going back is
+    `set_index`, and costs nothing."""
+    if layout_of(cache) == STACKED:  # K/V layers alone
+        return cache, {}
+    kept, out = {}, {}
+    with jax.named_scope("state_restore"):
+        for name, layer in cache.items():
+            attn = layer[ATTN]
+            if STATE not in attn:
+                out[name] = layer
+                continue
+            kept[name] = {at: attn[at] for at in SNAPSHOT.values()}
+            out[name] = {**layer, ATTN: {
+                **{n: leaf for n, leaf in attn.items() if n not in kept[name]},
+                # a select on the index's sign, which is never negative: an
+                # operation of its own, under this scope's name in the trace,
+                # that the compiler can neither drop nor hand to its own
+                # unnamed copies, and that reads the running leaf, so a
+                # donated cache's buffer is the copy's target and is paired
+                # with its own output
+                **{n: jnp.where(attn[INDEX] >= 0, attn[at], attn[n])
+                   for n, at in SNAPSHOT.items()}}}
+    return (out, kept) if kept else (cache, kept)
 
 
 def with_side(cache: dict, page_table=None, block_bitmap=None, ring_end=None) -> dict:
@@ -297,6 +400,31 @@ def kv_bytes(cache: dict) -> int:
         leaf.size * leaf.dtype.itemsize
         for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
         if leaf_name(path) in KV_KEYS + LATENT_KEYS
+    )
+
+
+def pack_state(state):
+    """Heads' matrices [B, H, key_dim, value_dim] as the `state` leaf keeps
+    them: [B, key_dim, H * value_dim]."""
+    b, h, dk, dv = state.shape
+    return state.transpose(0, 2, 1, 3).reshape(b, dk, h * dv)
+
+
+def running_state(cache: dict, layer: int, heads: int, rows: Optional[int] = None):
+    """Recurrent layer `layer`'s running state, a matrix a head: [B, heads,
+    key_dim, value_dim], of the first `rows` rows where given."""
+    leaf = cache[layer_key(layer)][ATTN][STATE][:rows]
+    b, dk, _ = leaf.shape
+    return leaf.reshape(b, dk, heads, -1).transpose(0, 2, 1, 3)
+
+
+def state_bytes(cache: dict) -> int:
+    """Bytes of the recurrent layers' leaves, running and kept."""
+    names = tuple(SNAPSHOT) + tuple(SNAPSHOT.values())
+    return sum(
+        leaf.size * leaf.dtype.itemsize
+        for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+        if leaf_name(path) in names
     )
 
 

@@ -6,13 +6,17 @@ mean next-token cross-entropy over positions 0..N-2, computed by the
 vocab-chunked loss of `ops/losses.py` so that the [B, N, V] logits are
 never whole.
 
-Generation, for a trunk of latent attention layers: `prefill` writes a
-batch of prompts into a decode cache (models/decode_cache.py, per-layer
-layout, the latent kind of layer), `decode_step` takes one token a row
+Generation, for a trunk of latent attention layers or of linear (gated
+delta rule) and full ones: `prefill` writes a batch of prompts into a decode
+cache (models/decode_cache.py, per-layer layout: the latent kind of layer, or
+recurrent and K/V layers in one tree), `decode_step` takes one token a row
 against it, and `generate_tokens_cached` runs a whole token loop in one
-dispatch, the cache held in place in the loop's carry. Every other trunk
-trains only: the decode kernels, `serving/paging.py` and the slot cache keep
-one K/V head per query head and one cache geometry (ROADMAP.md, Queue 2 B).
+dispatch, the cache held in place in the loop's carry. A turn starts where
+the session's document ended: the K/V layers' index set back, the recurrent
+layers' state restored from the snapshot `prefill_cached` took. A trunk with
+fewer K/V heads than query heads, a window or a rotate-half rotary outside
+the latent layer trains only; `serving/paging.py` and the slot cache keep one
+cache geometry (ROADMAP.md, Queue 2 B).
 """
 
 from __future__ import annotations
@@ -33,7 +37,8 @@ from dalle_pytorch_tpu.ops.sampling import gumbel_sample, top_k_filter
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 # a published config's `layer_types` in `Transformer.attn_types`' words
-LAYER_KINDS = {"sliding_attention": "window", "full_attention": "full"}
+LAYER_KINDS = {"sliding_attention": "window", "full_attention": "full",
+               "linear_attention": "linear"}
 MOE_COUNTS = ("moe_load", "moe_rows", "moe_dropped")
 
 
@@ -70,6 +75,31 @@ def _latent_trunk(cfg: dict, depth: int, held: int) -> dict:
     )
 
 
+def _hybrid_trunk(cfg: dict, depth: int) -> dict:
+    """The trunk options of the family whose config has `linear_key_head_dim`:
+    gated delta-rule layers (`linear_*` keys) among full ones by `layer_types`,
+    full attention with one RMS gain over all of q's and of k's columns and no
+    rotary embedding (a `rope_theta` of null), a norm on each sublayer's
+    output alone, a dense SwiGLU in every layer."""
+    heads, hidden = cfg["num_attention_heads"], cfg["hidden_size"]
+    if cfg.get("rope_parameters", {}).get("rope_theta") is not None:
+        raise ValueError("this family's full layers take no rotary embedding "
+                         "(rope_parameters.rope_theta is null as published)")
+    if cfg["linear_num_key_heads"] != cfg["linear_num_value_heads"]:
+        raise ValueError("a linear layer's key heads shared by value heads are not built")
+    if cfg.get("num_key_value_heads", heads) != heads or hidden % heads:
+        raise ValueError("a full layer keeps one K/V head of hidden_size / heads a query head")
+    if not cfg.get("linear_allow_neg_eigval", False):
+        raise ValueError("beta is 2 sigmoid(.) (linear_allow_neg_eigval)")
+    return dict(
+        attn_types=tuple(LAYER_KINDS[k] for k in cfg["layer_types"][:depth]),
+        qk_norm="whole", prenorm=False, sandwich_norm=True,
+        ff_kind="swiglu", ff_dim=cfg["intermediate_size"],
+        linear_heads=cfg["linear_num_key_heads"], linear_key_dim=cfg["linear_key_head_dim"],
+        linear_value_dim=cfg["linear_value_head_dim"], linear_conv=cfg["linear_conv_kernel_dim"],
+    )
+
+
 class CausalLM(nn.Module):
     num_tokens: int  # rows of the embedding and of the head held here
     dim: int
@@ -94,8 +124,11 @@ class CausalLM(nn.Module):
         """The model that a published `config.json` describes, as this
         process's share of it. `cfg` holds the published keys at its top
         level: the first `num_hidden_layers` layers, ids below `vocab_size`,
-        and the experts held. Two families of keys are read. With
-        `layer_types`: `hidden_size`, `head_dim`, `rope_parameters`,
+        and the experts held. Three families of keys are read. With
+        `linear_key_head_dim`: `layer_types` of `linear_attention` and
+        `full_attention`, the `linear_*` keys, `intermediate_size`, a null
+        `rope_theta` (gated delta-rule layers among full ones, no experts).
+        Else with `layer_types`: `hidden_size`, `head_dim`, `rope_parameters`,
         `num_experts` ... (grouped K/V heads, window and full layers, every
         layer routed). With `kv_lora_rank`: `q_lora_rank`, `qk_nope_head_dim`,
         `qk_rope_head_dim`, `v_head_dim`, `first_k_dense_replace`,
@@ -113,25 +146,31 @@ class CausalLM(nn.Module):
         depth = int(cfg["num_hidden_layers"])
         if cfg["hidden_act"] != "silu" or cfg["attention_bias"] or cfg["tie_word_embeddings"]:
             raise ValueError("the trunk builds SiLU gates, no biases and an untied head")
-        latent = "kv_lora_rank" in cfg
-        held = int(cfg["n_routed_experts" if latent else "num_experts"])
+        latent, hybrid = "kv_lora_rank" in cfg, "linear_key_head_dim" in cfg
         param_dtype = DTYPES[prog.get("weights_dtype", "float32")]
         trunk = dict(
             norm="rms", norm_eps=float(cfg["rms_norm_eps"]), use_bias=False, layerscale=False,
-            experts_per_token=cfg["num_experts_per_tok"],
-            experts_held=(cfg.get("deployment", {}).get("experts_first", 0), held),
-            expert_dim=cfg["moe_intermediate_size"],
-            moe_buffer_rows=int(prog["moe_buffer_rows"]),
             attn_impl=prog.get("attn_impl", "auto"), executor=prog.get("executor", "unrolled"),
         )
-        if latent:
+        if not hybrid:
+            held = int(cfg["n_routed_experts" if latent else "num_experts"])
+            trunk.update(
+                experts_per_token=cfg["num_experts_per_tok"],
+                experts_held=(cfg.get("deployment", {}).get("experts_first", 0), held),
+                expert_dim=cfg["moe_intermediate_size"],
+                moe_buffer_rows=int(prog["moe_buffer_rows"]),
+            )
+        if hybrid:
+            trunk.update(_hybrid_trunk(cfg, depth), param_dtype=param_dtype)
+            dim_head = cfg["hidden_size"] // cfg["num_attention_heads"]
+        elif latent:
             trunk.update(_latent_trunk(cfg, depth, held), param_dtype=param_dtype)
             dim_head = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
         else:
             if any(t != "sparse" for t in cfg["mlp_layer_types"][:depth]):
                 raise ValueError("every layer's feed-forward has to be routed (`sparse`)")
             if param_dtype != jnp.float32:
-                raise ValueError("weights_dtype is built for the latent-attention trunk only")
+                raise ValueError("weights_dtype is not built for the routed window-and-full trunk")
             trunk.update(
                 ff_kind="swiglu_experts", kv_heads=cfg["num_key_value_heads"], qk_norm=True,
                 window=int(cfg["sliding_window"]),
@@ -227,14 +266,19 @@ class CausalLM(nn.Module):
         x, cache = self.transformer(x, cache=cache)
         h = self.logits_norm(x[:, 0])
         kernel = self.logits_dense.variables["params"]["kernel"]
-        logits = jnp.dot(h, kernel.astype(h.dtype), preferred_element_type=jnp.float32)
-        return logits, cache
+        with jax.named_scope("logits_head"):  # the `head` component (obs/scopes.py)
+            logits = jnp.dot(h, kernel.astype(h.dtype), preferred_element_type=jnp.float32)
+        # whole once: the compiler otherwise computes the product again for its
+        # second reader (a step samples from the logits AND keeps rows of
+        # them), which at 100,352 ids is another read of the head (PERF.md, PR 33)
+        return lax.optimization_barrier(logits), cache
 
     def generate(self, *args, **kwargs):
         raise NotImplementedError(
-            "CausalLM has no uncached sampler: `generate_tokens_cached` decodes a "
-            "latent-attention trunk through its cache; cached decode of K/V heads "
-            "shared by query heads, and serving, are not built (ROADMAP.md, Queue 2 B)"
+            "CausalLM has no uncached sampler: `generate_tokens_cached` decodes a trunk "
+            "of latent layers, or of linear and full ones, through its cache; cached "
+            "decode of K/V heads shared by query heads, of a window or of a rotate-half "
+            "rotary, and serving, are not built (ROADMAP.md, Queue 2 B)"
         )
 
 
@@ -257,8 +301,10 @@ def prefill_cached(model: CausalLM, variables, tokens: jnp.ndarray, cache: dict,
     `tokens` [R, n] from position 0, and the routed layers' counts over the
     prompts (as `generate_tokens_cached` gives them). The prompts go through
     `CausalLM.prefill` into a fresh cache of their own length, whose rows
-    are then written into the sessions' (one dispatch). Every layer's index
-    is left where it was: the caller sets it (`decode_cache.set_index`)."""
+    are then written into the sessions' (one dispatch), a recurrent layer's
+    state both as the running one and as the snapshot a later turn restores
+    (`decode_cache.snapshot`). Every layer's index is left where it was: the
+    caller sets it (`decode_cache.set_index`)."""
     jitted = _jitted(_prefill_builder, model, ())
     with host_span("lm.prefill", program=jitted.name, rows=int(tokens.shape[0])):
         return jitted(variables, tokens, cache, jnp.asarray(row, jnp.int32))
@@ -270,7 +316,7 @@ def _prefill_builder(model, key):
             variables, tokens, model.init_cache(*tokens.shape), method=CausalLM.prefill,
             mutable=["stats"])
         rows = row + jnp.arange(tokens.shape[0], dtype=jnp.int32)
-        return (decode_cache.scatter_rows(cache, fresh, rows),
+        return (decode_cache.scatter_rows(cache, decode_cache.snapshot(fresh), rows),
                 _moe_counts(aux.get("stats", {})))
 
     return lm_prefill
@@ -289,22 +335,33 @@ def generate_tokens_cached(model: CausalLM, variables, key: jax.Array, cache: di
     a prompt's last token) and the row's own previous sample after; every
     step samples from its logits (`ops/sampling.py`: the top `1 -
     filter_thres` of the vocabulary, Gumbel noise at `temperature`; a
-    `filter_thres` of 1.0 keeps one logit, which is greedy). The cache rides
-    the loop's carry and is written in place, one position a step from its
-    index on.
+    `filter_thres` of 1.0 keeps one logit, which is greedy). The turn starts
+    at `start` (the cache's own index where left out): the K/V layers' index
+    is set there, which copies nothing, and a recurrent layer's state and
+    ring are restored from its snapshot, one device copy a turn
+    (`decode_cache.restore`). The cache rides the loop's carry and is written
+    in place, one position a step from its index on.
 
     Returns `(tokens [B, steps] int32, logits [steps, logit_rows, V] float32
     of the first `logit_rows` rows, counts, cache)`; `counts` are the routed
     layers' `moe_load` [L, held], `moe_rows`, `moe_dropped` and `moe_touched`
-    [L] (held experts with at least one row), each summed over the steps.
+    [L] (held experts with at least one row), each summed over the steps;
+    of a cache with recurrent layers also, as host numbers, `state_bytes`
+    (running and kept), `kv_bytes` and `state_restored_bytes`, the turn's copy.
     """
     assert forced.ndim == 2 and 1 <= forced.shape[1] <= steps, forced.shape
     static_key = (int(steps), float(filter_thres), float(temperature), int(logit_rows))
     jitted = _jitted(_sampler_builder, model, static_key)
     if start is None:  # a copy: the cache's own leaf is donated with it
         start = next(iter(cache.values()))[decode_cache.ATTN][decode_cache.INDEX] + 0
+    held = decode_cache.state_bytes(cache)
     with host_span("lm.sample.dispatch", program=jitted.name):
-        return jitted(variables, key, cache, forced, jnp.asarray(start, jnp.int32))
+        tokens, logits, counts, cache = jitted(
+            variables, key, cache, forced, jnp.asarray(start, jnp.int32))
+    if held:
+        counts = {**counts, "state_bytes": held, "kv_bytes": decode_cache.kv_bytes(cache),
+                  "state_restored_bytes": held // 2}
+    return tokens, logits, counts, cache
 
 
 def _moe_counts(stats: dict) -> dict:
@@ -325,6 +382,7 @@ def _sampler_builder(model, key):
 
     def lm_sample(variables, rng, cache, forced, start):
         batch, n_forced = forced.shape
+        cache, kept = decode_cache.restore(cache)
         cache = decode_cache.set_index(cache, start)
 
         def step(carry, i):
@@ -352,7 +410,7 @@ def _sampler_builder(model, key):
             **{k: zeros(routed) for k in ("moe_rows", "moe_dropped", "moe_touched")}}
         carry = (cache, jnp.zeros((batch,), jnp.int32), rng, counts)
         (cache, _, _, counts), (tokens, logits) = lax.scan(step, carry, jnp.arange(steps))
-        return tokens.T, logits, counts, cache
+        return tokens.T, logits, counts, decode_cache.snapshot(cache, kept)
 
     return lm_sample
 

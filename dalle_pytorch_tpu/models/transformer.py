@@ -61,7 +61,11 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from dalle_pytorch_tpu.models import decode_cache
-from dalle_pytorch_tpu.models.attention import Attention, LatentAttention
+from dalle_pytorch_tpu.models.attention import (
+    Attention,
+    GatedDeltaAttention,
+    LatentAttention,
+)
 from dalle_pytorch_tpu.ops.masks import (
     axial_static_mask,
     conv_like_mask,
@@ -418,7 +422,10 @@ class Transformer(nn.Module):
     use_bias: bool = True  # to_out's and the feed-forward's
     layerscale: bool = True
     kv_heads: Optional[int] = None  # K/V heads shared by groups of query heads
-    qk_norm: bool = False  # per-head RMS norm of q and k
+    qk_norm: Any = False  # RMS norm of q and k: True per head, "whole" over all columns
+    # False: no norm on a sublayer's INPUT (with `sandwich_norm`, the norm on
+    # its output is then the only one: h = x + norm(mixer(x)))
+    prenorm: bool = True
     # "window" among attn_types: query t sees key p iff 0 <= t - p < window
     window: Optional[int] = None
     # attn type -> `ops/rotary.py:rotary_cos_sin` spec: one rotate-half table
@@ -440,6 +447,11 @@ class Transformer(nn.Module):
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_dim: int = 0
+    # "linear" among attn_types (models/attention.py:GatedDeltaAttention)
+    linear_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
     dtype: Any = jnp.float32
     # what the MATRICES are stored in (the new block options' only: norm
     # gains and the router stay float32, the DALL-E block keeps float32)
@@ -449,7 +461,7 @@ class Transformer(nn.Module):
         """The first block option that is not the DALL-E block's, or None."""
         defaults = dict(norm="layer", ff_kind="geglu", ff_kinds=None, use_bias=True,
                         layerscale=True, kv_heads=None, qk_norm=False, window=None,
-                        rotary_specs=None)
+                        rotary_specs=None, prenorm=True)
         return next((k for k, v in defaults.items() if getattr(self, k) != v), None)
 
     def _norm(self):
@@ -528,6 +540,14 @@ class Transformer(nn.Module):
                     dtype=self.dtype, param_dtype=self.param_dtype, name=f"attn_{attn_id}",
                 )
                 shared_attn_type[attn_id] = attn_type
+            elif attn_type == "linear":
+                attn = shared_attn[attn_id] = GatedDeltaAttention(
+                    dim=self.dim, seq_len=self.seq_len, heads=self.linear_heads,
+                    key_dim=self.linear_key_dim, value_dim=self.linear_value_dim,
+                    conv_width=self.linear_conv, norm_eps=self.norm_eps, dtype=self.dtype,
+                    param_dtype=self.param_dtype, name=f"attn_{attn_id}",
+                )
+                shared_attn_type[attn_id] = attn_type
             else:
                 attn = Attention(
                     dim=self.dim,
@@ -593,8 +613,10 @@ class Transformer(nn.Module):
         self.ff_layers = ff_layers
         self.ff_kind_per_layer = ff_kinds
         self.type_per_layer = tuple(type_per_layer)
-        self.attn_norms = [self._norm() for _ in range(depth)]
-        self.ff_norms = [self._norm() for _ in range(depth)]
+        assert self.prenorm or self.sandwich_norm, "a sublayer with no norm at all"
+        if self.prenorm:
+            self.attn_norms = [self._norm() for _ in range(depth)]
+            self.ff_norms = [self._norm() for _ in range(depth)]
         if self.sandwich_norm:
             self.attn_norms_out = [self._norm() for _ in range(depth)]
             self.ff_norms_out = [self._norm() for _ in range(depth)]
@@ -632,7 +654,7 @@ class Transformer(nn.Module):
             return {}
         return dict(
             kv_heads=self.kv_heads, qk_norm=self.qk_norm, norm_eps=self.norm_eps,
-            use_bias=self.use_bias,
+            use_bias=self.use_bias, param_dtype=self.param_dtype,
             window=self.window if attn_type == "window" else None,
         )
 
@@ -758,7 +780,7 @@ class Transformer(nn.Module):
         Returns (residual_branch, new_attn_cache, new_shift_ring)."""
         cached = layer_cache is not None
         pos = layer_cache["attn"]["index"] if cached else None
-        h = self.attn_norms[i](x)
+        h = self.attn_norms[i](x) if self.prenorm else x
         ring = None
         if self.shift_tokens:
             h, ring = self._shift(
@@ -766,7 +788,8 @@ class Transformer(nn.Module):
                 ring_end=layer_cache.get("ring_end") if cached else None,
             )
         variant = (
-            {"rotary_cs": self.rotary_cs[self.type_per_layer[i]]} if self.rotary_cs else {}
+            {"rotary_cs": self.rotary_cs[self.type_per_layer[i]]}
+            if self.type_per_layer[i] in self.rotary_cs else {}
         )
         h, attn_cache = self.attn_layers[i](
             h,
@@ -787,7 +810,7 @@ class Transformer(nn.Module):
         `pos` is the pre-update decode position (for the streaming shift).
         Returns (residual_branch, new_shift_ring)."""
         cached = layer_cache is not None
-        h = self.ff_norms[i](x)
+        h = self.ff_norms[i](x) if self.prenorm else x
         ring = None
         if self.shift_tokens:
             h, ring = self._shift(
@@ -992,16 +1015,24 @@ class Transformer(nn.Module):
         """Zeroed decode cache for this trunk's geometry (K/V + token-shift
         rings), in the layout its executor takes. Pure config math: usable
         unbound. `per_row`, `pages = (n_pages, page_size)` and `kv_dtype`
-        as `decode_cache.layer_spec` reads them. A trunk of latent
-        attention layers takes the latent kind of layer."""
-        types = set(self.attn_types or ("full",))
-        if "latent" in types:
-            assert types == {"latent"}, "latent and K/V layers in one cache are not built"
+        as `decode_cache.layer_spec` reads them. Each layer takes the kind
+        its attention is of: latent, recurrent (linear attention) or K/V
+        heads; linear and K/V layers may share a cache."""
+        cache_kind = {"latent": "latent", "linear": "recurrent"}
+        kinds = [cache_kind.get(t, "heads")
+                 for t in islice(cycle(self.attn_types or ("full",)), self.depth)]
+        if "latent" in kinds and set(kinds) != {"latent"}:
+            raise NotImplementedError(
+                "latent layers beside K/V or recurrent ones in one cache are not built "
+                "(recurrent and K/V layers are)")
+        if set(kinds) != {"heads"}:
             assert not per_row and pages is None and kv_dtype is None
             return decode_cache.make(
-                self.cache_layout, self.depth, kind="latent", batch=batch, max_len=max_len,
+                self.cache_layout, self.depth, kinds=kinds, batch=batch, max_len=max_len,
                 heads=self.heads, dim_head=self.dim_head, dim=self.dim,
-                latent_dim=self.kv_lora_rank, rope_dim=self.qk_rope_dim, dtype=dtype,
+                latent_dim=self.kv_lora_rank, rope_dim=self.qk_rope_dim,
+                linear_heads=self.linear_heads, key_dim=self.linear_key_dim,
+                value_dim=self.linear_value_dim, conv_taps=self.linear_conv, dtype=dtype,
             )
         return decode_cache.make(
             self.cache_layout,

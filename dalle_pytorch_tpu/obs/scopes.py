@@ -36,6 +36,7 @@ COMPONENTS = (
     "cache_write", "ff", "norm_resid", "embed", "head", "loss", "optimizer",
     "sample", "pixels", "moe_router", "moe_dispatch", "moe_experts", "unscoped",
     "mla_attend", "mla_proj", "moe_shared",
+    "delta_step", "delta_proj", "delta_chunk", "state_restore",
 )
 PHASES = ("fwd", "bwd", "remat")
 
@@ -54,6 +55,9 @@ EXPERT_KERNELS = ("gmm_fwd", "gmm_dlhs", "gmm_drhs")
 
 # the latent decode attention (`name=` in ops/latent_decode.py)
 LATENT_KERNEL = "decode_latent"
+
+# the gated delta rule's token step (`name=` in ops/delta_step.py)
+DELTA_KERNEL = "delta_step"
 
 CONTAINERS = ("while", "conditional", "call")  # their bodies are events too
 
@@ -84,6 +88,15 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         # latents and the rotary. The latent's write is `cache_write`, above
         ("mla_attend", _E("mla_attend|" + LATENT_KERNEL)),
         ("mla_proj", _E("mla_proj")),
+        # a gated delta-rule layer (models/attention.py:GatedDeltaAttention),
+        # before `attn_proj`, whose `to_out` it also has: the token step's
+        # state update, with the kernel by name; the chunked prefill form;
+        # the projections, the convolution, the norms and the gate. And the
+        # copy that starts a turn from the snapshot (models/decode_cache.py)
+        ("delta_step", _E(DELTA_KERNEL)),
+        ("delta_chunk", _E("delta_chunk")),
+        ("delta_proj", _E("delta_proj")),
+        ("state_restore", _E("state_restore")),
         # a scan's own slicing (`dynamic_index_in_dim` of the stacked
         # parameters, LayerScale vectors and the layer index) and its counter
         # are nobody's: no owner. The cached scan carries the depth-stacked
@@ -127,6 +140,8 @@ def component(op_name: Optional[str], opcode: str = "",
         found = "moe_experts"
     elif base == LATENT_KERNEL:
         found = "mla_attend"
+    elif base == DELTA_KERNEL:
+        found = "delta_step"
     elif not op_name or opcode in CONTAINERS:  # loop control has no owner
         return "unscoped", "fwd"
     else:

@@ -1,8 +1,11 @@
 #!/usr/bin/env python
 """Generate token ids with a causal language model (`models/lm.py:CausalLM`)
-whose trunk decodes through a cache: latent attention (a compressed K/V
-cache), leading dense layers, a shared expert beside sigmoid-routed ones of
-which this process holds a share, parameters stored in bf16.
+whose trunk decodes through a cache. Two families of published configs do:
+latent attention (a compressed K/V cache), leading dense layers, a shared
+expert beside sigmoid-routed ones of which this process holds a share; and
+gated delta-rule linear layers among full ones (a recurrent state beside K/V
+in one cache, `--config benchmark/configs/olmo-hybrid-7b-pp2.json`). Either
+with parameters stored in bf16.
 
 The sampler `generate.py` uses for DALL-E, for token sequences: every prompt
 but its last token is prefilled into a decode cache
@@ -82,8 +85,9 @@ def read_config(args):
             cfg[key] = value
         else:
             raise SystemExit(f"unknown option {key!r} (have: {', '.join([*cfg, *PROGRAM_KEYS])})")
-    if "kv_lora_rank" not in cfg:
-        raise SystemExit("generation is built for the latent-attention trunk (kv_lora_rank ...)")
+    if "kv_lora_rank" not in cfg and "linear_key_head_dim" not in cfg:
+        raise SystemExit("generation is built for the latent-attention trunk (kv_lora_rank ...) "
+                         "and for linear and full layers (linear_key_head_dim ...)")
     return cfg, program
 
 
@@ -91,7 +95,7 @@ def build_model(args, cfg: dict, program: dict, prompt_len: int, rows: int):
     """(CausalLM sized for the prompts and the new tokens, what it was built from)."""
     from dalle_pytorch_tpu.models.lm import CausalLM
 
-    if "moe_buffer_rows" not in program:
+    if "moe_buffer_rows" not in program and "num_experts_per_tok" in cfg:
         # every assignment a prefill dispatch can make: no routing drops a token
         program["moe_buffer_rows"] = (
             min(args.prefill_rows, rows) * max(prompt_len - 1, 1) * cfg["num_experts_per_tok"])

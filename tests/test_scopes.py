@@ -176,7 +176,7 @@ def program_texts():
     quant = jax.jit(lambda v, x, c: attn.apply(v, x, cache=c)).lower(
         avars, x, cache).compile().as_text()
     return {"train": train, "sample": sample, "quant": quant, "lm": _lm_step_text(),
-            "lm_sample": _lm_sample_text()}
+            "lm_sample": _lm_sample_text(), **_hybrid_texts()}
 
 
 def _lm_step_text() -> str:
@@ -199,6 +199,31 @@ def _lm_step_text() -> str:
         tx=make_optimizer(3e-4, clip_grad_norm=0.5))
     step = jax.jit(make_lm_train_step(lm), donate_argnums=0)
     return step.lower(state, {"tokens": tokens}, jax.random.PRNGKey(1)).compile().as_text()
+
+
+def _hybrid_texts() -> dict:
+    """Compiled texts of a tiny linear-and-full language model's sampler and
+    prefill: a gated delta-rule layer and a full one over a cache of both
+    kinds, a turn restored from the snapshot."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl = lm.CausalLM(
+        num_tokens=40, dim=32, depth=2, seq_len=16, heads=2, dim_head=16,
+        trunk=dict(norm="rms", use_bias=False, layerscale=False, sandwich_norm=True,
+                   prenorm=False, qk_norm="whole", attn_types=("linear", "full"),
+                   ff_kind="swiglu", ff_dim=48, linear_heads=2, linear_key_dim=8,
+                   linear_value_dim=16, attn_impl="dense"))
+    variables = jax.jit(mdl.init)(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    sampler = jax.jit(lm._sampler_builder(mdl, (4, 0.9, 1.0, 1)), donate_argnums=(2,))
+    prefill = jax.jit(lm._prefill_builder(mdl, ()), donate_argnums=(2,))
+    zero = jnp.asarray(0, jnp.int32)
+    return {
+        "hybrid_sample": sampler.lower(
+            variables, jax.random.PRNGKey(1), mdl.init_cache(2), jnp.zeros((2, 2), jnp.int32),
+            zero + 8).compile().as_text(),
+        "hybrid_prefill": prefill.lower(
+            variables, jnp.zeros((2, 8), jnp.int32), mdl.init_cache(2), zero).compile().as_text(),
+    }
 
 
 def _lm_sample_text() -> str:

@@ -632,3 +632,42 @@ def test_grouped_matmul_compiles_at_a_token_steps_sizes(one_chip, monkeypatch, k
     text = _compile(gm.grouped_matmul, lhs, rhs, _i32(one_chip, 16)).as_text()
     assert re.search(rf"%gmm_fwd[.\d]* = bf16\[512,{n}\]", text)
     assert gm._row_tile(512, 16) == 128 and (gm._tile(7680), gm._tile(2048)) == (768, 1024)
+
+
+def test_hybrid_token_loop_compiles_with_the_state_held_in_place(one_chip, monkeypatch):
+    """The cached sampler of ONE period of `olmo-hybrid-7b-pp2` (three gated
+    delta-rule layers and a full one at the published widths, 8 sessions of
+    768 positions): Mosaic takes `delta_step`'s blocks of 10 heads x 96 x 192
+    on a state whose last axis is 45 whole lane tiles (no padding of 192 to
+    256), the loop's body holds the kernel once a linear layer and no copy of
+    a state, and the head's product is made once a step (the compiler made it
+    twice before `decode_step` fenced it: PERF.md, PR 33)."""
+    import json
+    from pathlib import Path
+
+    from dalle_pytorch_tpu.models import lm
+    from dalle_pytorch_tpu.ops import delta_step as ds, pallas_attention
+
+    for module in (ds, pallas_attention):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    path = Path(__file__).resolve().parent.parent / "benchmark/configs/olmo-hybrid-7b-pp2.json"
+    cfg = dict(json.loads(path.read_text()), num_hidden_layers=4)
+    mdl = lm.CausalLM.from_config(cfg, 768)
+    on = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    variables = on(jax.eval_shape(mdl.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    cache = on(jax.eval_shape(lambda: mdl.init_cache(8)))
+    assert cache["layer_0"]["attn"]["state"].shape == (8, 96, 30 * 192)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    forced = jax.ShapeDtypeStruct((8, 32), jnp.int32, sharding=one_chip)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lm._sampler_builder(mdl, (256, 0.9, 1.0, 2)), donate_argnums=(2,)).lower(
+        variables, key, cache, forced, start).compile()
+    text = compiled.as_text()
+    body = text[text.index("region_0"):]
+    body = body[:body.index("\n}\n")]
+    assert len(re.findall(r"%delta_step[.\d]* = \(f32\[8,1,5760\]\S*, f32\[8,96,5760\]", body)) == 3
+    assert not re.search(r"= f32\[8,96,5760\]\S* copy\(", body)
+    assert "T(8,128)" in re.search(r"f32\[8,96,5760\]\{[^}]*\}", body).group(0)
+    assert ".remat" not in text
+    assert ds.HEADS_PER_BLOCK == 10
