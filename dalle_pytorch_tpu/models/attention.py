@@ -43,7 +43,9 @@ from dalle_pytorch_tpu.ops.attention_core import dense_attention
 from dalle_pytorch_tpu.ops.delta_step import delta_step
 from dalle_pytorch_tpu.ops.latent_decode import latent_decode_attention
 from dalle_pytorch_tpu.ops.pallas_attention import (
+    TOKEN_MAJOR,
     flash_attention,
+    heads_per_block,
     lib_flash_attention,
 )
 from dalle_pytorch_tpu.ops.pallas_decode import (
@@ -54,6 +56,7 @@ from dalle_pytorch_tpu.ops.pallas_decode import (
     sharded_flash_decode_attention,
     sharded_paged_decode_attention,
 )
+from dalle_pytorch_tpu.ops.pallas_rotary import rotary_split
 from dalle_pytorch_tpu.ops.rotary import apply_rotary, apply_rotary_half
 
 # Of the three thresholds below one has a chip reading behind it, at one
@@ -184,37 +187,85 @@ class Attention(nn.Module):
             return False
         return n >= AUTO_FLASH_MIN_SEQ
 
+    def _head_shards(self) -> int:
+        """How many ways `train_mesh` splits the heads under `_flash`."""
+        mesh = self.train_mesh
+        tp = 1 if mesh is None or mesh.size == 1 else dict(mesh.shape).get("tp", 1)
+        return tp if self.heads % tp == 0 else 1
+
+    def _token_major(self) -> bool:
+        """Whether the flash kernels take this module's heads as column
+        blocks of `[B, N, H, D]` (`heads_per_block`, asked of the heads one
+        shard of `train_mesh` holds): then nothing is transposed or copied
+        between the projections and the kernels. Else q, k, v go to them
+        head-major."""
+        heads = self.heads // self._head_shards()
+        return heads_per_block(self.dim_head, heads, heads) is not None
+
+    def _mesh_axes(self, batch: int):
+        """(data axes, head axis) that `train_mesh` splits `_flash`'s operands
+        over, each None where it does not divide its dimension (the batch-1
+        dummy of `model.init`) and stays replicated."""
+        shape = dict(self.train_mesh.shape)
+        data = tuple(a for a in ("dp", "fsdp") if shape.get(a, 1) > 1)
+        n_data = int(np.prod([shape[a] for a in data])) if data else 1
+        return (data if data and batch % n_data == 0 else None,
+                "tp" if self._head_shards() > 1 else None)
+
+    def _attend(self, q, k, v, n: int, **layout):
+        mask = self._full_mask(n, n) if self.static_mask is not None else None
+        if self.window is None:
+            return flash_attention(q, k, v, mask=mask, causal=self.causal, **layout)
+        return flash_attention(q, k, v, causal=self.causal, window=self.window, **layout)
+
     def _flash(self, q, k, v, n: int):
         """The in-repo flash kernel over [B, H, N, D]; under a multi-device
         `train_mesh`, shard_mapped over batch and heads (attention mixes
-        neither, so the concatenation of the shards is exact). An axis
-        that does not divide its dimension stays replicated — e.g. the
-        batch-1 dummy of `model.init`."""
-        mask = self._full_mask(n, n) if self.static_mask is not None else None
-
-        def kernel(q_, k_, v_):
-            if self.window is None:
-                return flash_attention(q_, k_, v_, mask=mask, causal=self.causal)
-            return flash_attention(q_, k_, v_, causal=self.causal, window=self.window)
-
+        neither, so the concatenation of the shards is exact)."""
+        kernel = lambda q_, k_, v_: self._attend(q_, k_, v_, n)
         mesh = self.train_mesh
         if mesh is None or mesh.size == 1:
             return kernel(q, k, v)
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
-        shape = dict(mesh.shape)
-        data = tuple(a for a in ("dp", "fsdp") if shape.get(a, 1) > 1)
-        n_data = int(np.prod([shape[a] for a in data])) if data else 1
-        spec = P(
-            data if data and q.shape[0] % n_data == 0 else None,
-            "tp" if shape.get("tp", 1) > 1 and q.shape[1] % shape["tp"] == 0 else None,
-            None, None,
-        )
+        on_data, on_heads = self._mesh_axes(q.shape[0])
+        spec = P(on_data, on_heads, None, None)
         return shard_map(
             kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )(q, k, v)
+
+    def _flash_columns(self, qkv, n: int, angles=None):
+        """The same kernels over the fused projection's columns, qkv
+        [B, n, 3 x heads x dh] as `to_qkv` writes them, giving [B, n, heads,
+        dh], the columns `to_out` contracts over: the kernels index a head
+        as a column block, so nothing is transposed, and with `angles` (the
+        DALL-E rotary's) the rotary's one pass also hands q, k and v over
+        as three arrays, so nothing is sliced either. Under a multi-device
+        `train_mesh` each shard takes its heads' columns of all three."""
+        h, dh = self.heads, self.dim_head
+
+        def kernel(qkv_):
+            b, heads = qkv_.shape[0], qkv_.shape[-1] // (3 * dh)
+            if angles is not None:
+                q, k, v = rotary_split(angles, qkv_, heads, 3)
+            else:
+                q, k, v = (t.reshape(b, n, heads, dh) for t in jnp.split(qkv_, 3, axis=-1))
+            return self._attend(q, k, v, n, layout=TOKEN_MAJOR)
+
+        mesh = self.train_mesh
+        if mesh is None or mesh.size == 1:
+            return kernel(qkv)
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        on_data, on_heads = self._mesh_axes(qkv.shape[0])
+        return shard_map(
+            lambda t: kernel(t.reshape(*t.shape[:2], -1)), mesh=mesh,
+            in_specs=(P(on_data, None, None, on_heads, None),),
+            out_specs=P(on_data, None, on_heads, None), check_vma=False,
+        )(qkv.reshape(*qkv.shape[:2], 3, h, dh))
 
     def _grouped_qkv(self, x, rotary_cs):
         """q [B, H, n, dh] and k, v [B, kv_heads, n, dh] from one fused
@@ -311,10 +362,24 @@ class Attention(nn.Module):
         inner = h * dh
 
         grouped = self.kv_heads is not None or self.qk_norm or rotary_cs is not None
+        # the uncached flash kernels read q, k, v where the DALL-E projection
+        # wrote them, [B, n, heads, dh], and write the columns `to_out`
+        # contracts over; every other path takes [B, heads, n, dh] and gives
+        # it back. (The grouped path stays head-major: its per-head norm and
+        # rotate-half rotary are XLA's, and compiled for a v5e with q, k, v
+        # kept token-major, as [.., heads, 128] or as rows of [.., 128], a
+        # layer's reshapes and copies hold 1.9 times the bytes of the
+        # transposes they replace: PERF.md section 6, PR 34. The kernels
+        # take a 128-wide head token-major all the same.)
+        flash = (cache is None and self.attn_impl != "ring" and mask_array is None
+                 and self._use_flash(n, key_mask))
+        tokens = (flash and not grouped and self.attn_impl != "lib_flash"
+                  and self._token_major())
         if not grouped:
             qkv = nn.Dense(inner * 3, use_bias=False, dtype=self.dtype, name="to_qkv")(x)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q, k, v = (t.reshape(b, n, h, dh).transpose(0, 2, 1, 3) for t in (q, k, v))
+            if not tokens:  # else the kernels take `qkv` as it is (`_flash_columns`)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q, k, v = (t.reshape(b, n, h, dh).transpose(0, 2, 1, 3) for t in (q, k, v))
         else:
             q, k, v = self._grouped_qkv(x, rotary_cs)
 
@@ -541,7 +606,7 @@ class Attention(nn.Module):
             if sparse:
                 new_cache["block_bitmap"] = bitmap
         else:
-            if rotary is not None:
+            if rotary is not None and not tokens:  # else beside the kernels, in `_flash_columns`
                 rot = jnp.expand_dims(rotary[:n], (0, 1))
                 q, k, v = (apply_rotary(rot, t) for t in (q, k, v))
             if self.attn_impl == "ring":
@@ -568,9 +633,11 @@ class Attention(nn.Module):
                 out = ring_attention_sharded(
                     self.sp_mesh, q, k, v, causal=self.causal
                 )
-            elif mask_array is None and self._use_flash(n, key_mask):
+            elif flash:
                 if self.attn_impl == "lib_flash":
                     out = lib_flash_attention(q, k, v, causal=self.causal)
+                elif tokens:
+                    out = self._flash_columns(qkv, n, None if rotary is None else rotary[:n])
                 else:
                     out = self._flash(q, k, v, n)
             else:
@@ -586,7 +653,9 @@ class Attention(nn.Module):
                     mask = km if mask is None else (mask & km)
                 out = dense_attention(q, k, v, mask=mask, stable=self.stable)
 
-        out = out.transpose(0, 2, 1, 3).reshape(b, n, inner)
+        if not tokens:
+            out = out.transpose(0, 2, 1, 3)
+        out = out.reshape(b, n, inner)
         out = nn.Dense(self.dim, use_bias=self.use_bias, dtype=self.dtype,
                        param_dtype=self.param_dtype, name="to_out")(out)
         out = nn.Dropout(self.dropout)(out, deterministic=deterministic)

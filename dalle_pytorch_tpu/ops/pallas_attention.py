@@ -129,27 +129,52 @@ def mask_block_layout(mask: np.ndarray, block_q: int, block_k: int):
 VMEM_BUDGET = 12 * 1024 * 1024
 _LANES = 128  # a [rows, 1] block is laid out [rows, 128] in VMEM
 
-#: kernel bodies built (traced and lowered to Mosaic) by this process, and
-#: the tiles each distinct call shape got: what the jitted emitters below
-#: keep at 3 or 4 for a whole train step where every call site used to
-#: build its own (tests pin it the way they pin `obs.scopes.remembered`).
+#: the two layouts an operand may arrive in: `[B, H, N, D]`, a head a block
+#: of the second axis, or `[B, N, H, D]`, the columns of a projection as it
+#: writes them, a head (or a lane row of heads) a block of the last
+HEAD_MAJOR, TOKEN_MAJOR = "head_major", "token_major"
+
+#: kernel bodies built (traced and lowered to Mosaic) by this process, the
+#: tiles each distinct call shape got and the layout each body indexes:
+#: what the jitted emitters below keep at 3 or 4 for a whole train step
+#: where every call site used to build its own (tests pin it the way they
+#: pin `obs.scopes.remembered`).
 kernel_bodies = 0
 tiles_chosen: dict = {}
+layouts_built: dict = {}
 
 
 def forget() -> None:
-    """Drop the counter, the tiles and the emitters' trace caches (tests)."""
+    """Drop the counters, the tiles and the emitters' trace caches (tests)."""
     global kernel_bodies
     kernel_bodies = 0
     tiles_chosen.clear()
+    layouts_built.clear()
     for emit in (_emit_fwd, _emit_dq, _emit_dkv):
         emit.clear_cache()
 
 
-def _built(kind: str, q, k, block_q: int, block_k: int) -> None:
+def _built(kind: str, q, n_k: int, block_q: int, block_k: int, heads) -> None:
     global kernel_bodies
     kernel_bodies += 1
-    tiles_chosen[(kind, q.shape, k.shape[2], q.dtype.name)] = (block_q, block_k)
+    tiles_chosen[(kind, q.shape, n_k, q.dtype.name)] = (block_q, block_k)
+    layout = HEAD_MAJOR if heads is None else TOKEN_MAJOR
+    layouts_built[layout] = layouts_built.get(layout, 0) + 1
+
+
+def heads_per_block(d: int, hq: int, hkv: int) -> Optional[int]:
+    """How many heads one column block of a token-major operand holds, from
+    the head size alone: a head of a multiple of 128 lanes is a block by
+    itself; narrower heads share a 128-lane block (a pair at 64), since a
+    block's last dimension is a multiple of 128 or the whole, and then the
+    block's heads must be the same K/V heads' as query heads'. None where
+    neither holds: such a call keeps the head-major layout."""
+    if d % _LANES == 0:
+        return 1
+    pack = _LANES // d
+    if _LANES % d == 0 and hq == hkv and hq % pack == 0:
+        return pack
+    return None
 
 
 def _sides(n: int) -> list:
@@ -174,7 +199,8 @@ def _span(n: int, block: int, row_bytes: int) -> int:
 
 def _spans(n_q: int, n_k: int, block_q: int, block_k: int, d: int, itemsize: int):
     """(span_q, span_k): the q rows `dkv` and the k rows `fwd`/`dq` keep
-    resident per grid step, walked tile by tile inside the kernel."""
+    resident per grid step, walked tile by tile inside the kernel. `d`: a
+    block's width, the head's own or the 128 lanes narrower heads share."""
     span_q = _span(n_q, block_q, 2 * d * itemsize + 2 * _LANES * 4)
     span_k = _span(n_k, block_k, 2 * d * itemsize)
     return span_q, span_k
@@ -184,20 +210,27 @@ def vmem_bytes(block_q: int, block_k: int, span_q: int, span_k: int, d: int,
                itemsize: int, masked: bool = False) -> int:
     """What the widest of the three kernels holds in VMEM at these tiles:
     every operand block twice (Pallas double-buffers), `[rows, 1]` blocks
-    at their lane-padded size, a static mask's int8 block, the fp32
-    scratch, and four score tiles (s, p, dp, ds) in fp32."""
-    rows = lambda n: n * _LANES * 4  # lse, delta
-    fwd_dq = 2 * (3 * block_q * d * itemsize + 2 * span_k * d * itemsize
-                  + 2 * rows(block_q) + masked * block_q * span_k)
-    dkv = 2 * (2 * span_q * d * itemsize + 4 * block_k * d * itemsize
-               + 2 * rows(span_q) + masked * span_q * block_k)
-    scratch = (block_q + 2 * block_k) * d * 4 + 2 * rows(block_q)
-    return max(fwd_dq, dkv) + scratch + 4 * block_q * block_k * 4
+    at their lane-padded size, a static mask's int8 block, that kernel's
+    own fp32 scratch (the forward's m, l and accumulator, `dq`'s
+    accumulator, or `dkv`'s two), and four score tiles (s, p, dp, ds) in
+    fp32. Heads that share a block's lanes share its `[rows, heads]`
+    statistics too, and take their score tiles one after the other: their
+    plan is one head's at `d` = the block's width."""
+    rows = lambda n: n * _LANES * 4  # lse, delta, m, l
+    fwd_dq = (2 * (3 * block_q * d * itemsize + 2 * span_k * d * itemsize
+                   + 2 * rows(block_q) + masked * block_q * span_k)
+              + block_q * d * 4 + 2 * rows(block_q))
+    dkv = (2 * (2 * span_q * d * itemsize + 4 * block_k * d * itemsize
+                + 2 * rows(span_q) + masked * span_q * block_k)
+           + 2 * block_k * d * 4)
+    return max(fwd_dq, dkv) + 4 * block_q * block_k * 4
 
 
 def choose_tiles(n_q: int, n_k: int, d: int, dtype, masked: bool = False) -> tuple:
     """(block_q, block_k) for a call, from its shape alone: evaluated at
-    trace time, nothing timed, nothing read from the environment.
+    trace time, nothing timed, nothing read from the environment. `d` is
+    the width of a block: the head size, or 128 where narrower heads
+    share a token-major block.
 
     The largest score tile that fits `VMEM_BUDGET`; of two as large the
     squarer, then the one with more keys. Measured on the v5e at
@@ -206,7 +239,8 @@ def choose_tiles(n_q: int, n_k: int, d: int, dtype, masked: bool = False) -> tup
     rescaled accumulator) whatever its width, so few wide tiles beat many
     narrow ones even though a causal diagonal then crosses more dead
     pairs: 640 x 640 runs fwd + fwd + dq + dkv in 8.4 ms a layer,
-    256 x 256 in 15.5, 128 x 128 in 27.4.
+    256 x 256 in 15.5, 128 x 128 in 27.4; token-major, a pair of heads a
+    block (PR 34): 640 x 640 in 9.0, 256 x 640 in 11.0.
     """
     itemsize = jnp.dtype(dtype).itemsize
     fits = [
@@ -232,6 +266,16 @@ def choose_tiles(n_q: int, n_k: int, d: int, dtype, masked: bool = False) -> tup
 # product); scores, softmax state and accumulators are fp32, and `p`/`ds`
 # are rounded to the operand dtype only where they enter a dot, as the
 # dense path's weights x V does.
+#
+# One body serves both layouts. Head-major blocks are `[1, 1, rows, d]`,
+# token-major ones `[1, rows, width]` (`pack` given): the grid's second
+# axis then counts column blocks, and where `pack` heads share a block's
+# 128 lanes (a pair at head size 64) each takes its turn: its scores are
+# `(q, zero outside its lanes) @ k^T` over all the lanes, which costs the
+# MXU what a 64-wide contraction does; `p @ v` comes out a block wide and a
+# lane select keeps the head's own; `ds^T @ (q, zeroed)` lands in the
+# head's lanes by itself. Softmax state is per head: column `s` of a
+# `[rows, pack]` block.
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
@@ -309,8 +353,36 @@ def _walk_k(body, *, qi, bq, block_k, steps, causal, layout_ref, window=None):
           occupied=None if layout_ref is None else lambda kj: layout_ref[qi, kj])
 
 
+def _block_heads(ref, pack):
+    """(lead, own): the unit axes in front of a block's rows, and for each
+    head of the block the `[1, width]` mask of the lanes it owns (None: the
+    whole block is one head's)."""
+    if pack is None:
+        return (0, 0), [None]
+    if pack == 1:
+        return (0,), [None]
+    d = ref.shape[-1] // pack
+    lane = lax.broadcasted_iota(jnp.int32, (1, ref.shape[-1]), 1)
+    return (0,), [(lane >= s * d) & (lane < (s + 1) * d) for s in range(pack)]
+
+
+def _own(x, lanes):
+    """`x` with zeros outside a head's lanes: an MXU operand of that head
+    alone, whichever lanes the other operand fills."""
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _join(parts, own):
+    """One array of each head's: head `s` supplies the lanes it owns
+    (`[rows, 1]` parts are spread over them)."""
+    out = parts[-1]
+    for part, lanes in zip(parts[-2::-1], own[-2::-1]):
+        out = jnp.where(lanes, part, out)
+    return out
+
+
 def _fwd_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps,
-                window=None):
+                window=None, pack=None):
     """q block resident, k/v span resident, online softmax over its tiles;
     (m, l, acc) carry across spans in fp32 scratch and the normalized
     output flushes on the last one."""
@@ -320,8 +392,9 @@ def _fwd_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps,
     else:
         layout_ref = mask_ref = None
         q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
+    lead, own = _block_heads(q_ref, pack)
     qi = pl.program_id(2)
-    bq = q_ref.shape[2]
+    bq = q_ref.shape[-2]
 
     @pl.when(pl.program_id(3) == 0)
     def _init():
@@ -331,33 +404,39 @@ def _fwd_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps,
 
     def attend(kj, start):
         cols = pl.ds(start, block_k)
-        vb = v_ref[0, 0, cols, :]
-        s = _scores(
-            q_ref[0, 0], k_ref[0, 0, cols, :], sm_scale=sm_scale,
-            row0=qi * bq, col0=kj * block_k, causal=causal,
-            mask=mask_ref[:, cols] if has_mask else None, n_real_k=n_real_k,
-            window=window,
-        )
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + _dot(p.astype(vb.dtype), vb)
+        kb, vb = k_ref[(*lead, cols, slice(None))], v_ref[(*lead, cols, slice(None))]
+        corrs, pvs = [], []
+        for s, lanes in enumerate(own):
+            one = slice(s, s + 1)
+            sc = _scores(
+                _own(q_ref[lead], lanes), kb, sm_scale=sm_scale, row0=qi * bq,
+                col0=kj * block_k, causal=causal,
+                mask=mask_ref[:, cols] if has_mask else None,
+                n_real_k=n_real_k, window=window,
+            )
+            m = m_ref[:, one]
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m - m_new)
+            m_ref[:, one] = m_new
+            l_ref[:, one] = l_ref[:, one] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            corrs.append(corr)
+            pvs.append(_dot(p.astype(vb.dtype), vb))
+        acc_ref[...] = acc_ref[...] * _join(corrs, own) + _join(pvs, own)
 
     _walk_k(attend, qi=qi, bq=bq, block_k=block_k, steps=steps, causal=causal,
             layout_ref=layout_ref, window=window)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():
-        safe_l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] + jnp.log(safe_l)  # [bq, 1]
+        safe_l = [jnp.maximum(l_ref[:, s:s + 1], 1e-30) for s in range(len(own))]
+        o_ref[lead] = (acc_ref[...] / _join(safe_l, own)).astype(o_ref.dtype)
+        for s, safe in enumerate(safe_l):  # [bq, 1] a head
+            lse_ref[0, 0, :, s:s + 1] = m_ref[:, s:s + 1] + jnp.log(safe)
 
 
 def _dq_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps,
-               window=None):
+               window=None, pack=None):
     """Same walk as the forward; dq accumulates in fp32 scratch."""
     if has_mask:
         (layout_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -365,8 +444,9 @@ def _dq_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps,
     else:
         layout_ref = mask_ref = None
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref = refs
+    lead, own = _block_heads(q_ref, pack)
     qi = pl.program_id(2)
-    bq = q_ref.shape[2]
+    bq = q_ref.shape[-2]
 
     @pl.when(pl.program_id(3) == 0)
     def _init():
@@ -374,28 +454,32 @@ def _dq_kernel(*refs, sm_scale, block_k, causal, has_mask, n_real_k, steps,
 
     def attend(kj, start):
         cols = pl.ds(start, block_k)
-        kb = k_ref[0, 0, cols, :]
-        s = _scores(
-            q_ref[0, 0], kb, sm_scale=sm_scale, row0=qi * bq,
-            col0=kj * block_k, causal=causal,
-            mask=mask_ref[:, cols] if has_mask else None, n_real_k=n_real_k,
-            window=window,
-        )
-        p = jnp.exp(s - lse_ref[0, 0])
-        dp = _dot(do_ref[0, 0], v_ref[0, 0, cols, :], _NT)
-        ds = p * (dp - delta_ref[0, 0])  # sm_scale: once, at the flush
-        acc_ref[...] += _dot(ds.astype(kb.dtype), kb)
+        kb, vb = k_ref[(*lead, cols, slice(None))], v_ref[(*lead, cols, slice(None))]
+        parts = []
+        for s, lanes in enumerate(own):
+            one = slice(s, s + 1)
+            sc = _scores(
+                _own(q_ref[lead], lanes), kb, sm_scale=sm_scale, row0=qi * bq,
+                col0=kj * block_k, causal=causal,
+                mask=mask_ref[:, cols] if has_mask else None,
+                n_real_k=n_real_k, window=window,
+            )
+            p = jnp.exp(sc - lse_ref[0, 0, :, one])
+            dp = _dot(_own(do_ref[lead], lanes), vb, _NT)
+            ds = p * (dp - delta_ref[0, 0, :, one])  # sm_scale: once, at the flush
+            parts.append(_dot(ds.astype(kb.dtype), kb))
+        acc_ref[...] += _join(parts, own)
 
     _walk_k(attend, qi=qi, bq=bq, block_k=block_k, steps=steps, causal=causal,
             layout_ref=layout_ref, window=window)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():
-        dq_ref[0, 0] = (acc_ref[...] * sm_scale).astype(dq_ref.dtype)
+        dq_ref[lead] = (acc_ref[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
-                n_real_k, steps, window=None, n_spans=None):
+                n_real_k, steps, window=None, n_spans=None, pack=None):
     """Transposed walk: the k/v block is resident, the q/do/lse/delta span
     is resident, and the loop runs over the span's q tiles from the first
     one that attends to this k block (to the last, under a window). dk/dv
@@ -409,8 +493,9 @@ def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
         layout_ref = mask_ref = None
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
          dk_acc, dv_acc) = refs
+    lead, own = _block_heads(k_ref, pack)
     ki = pl.program_id(2)
-    bk = k_ref.shape[2]
+    bk = k_ref.shape[-2]
     if n_spans is None:
         q0, grouped = pl.program_id(3) * steps, {}
     else:  # the last axis counts (head of the group, span)
@@ -424,18 +509,21 @@ def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
 
     def attend(qj, start):
         rows = pl.ds(start, block_q)
-        qb, dob = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
-        s = _scores(
-            qb, k_ref[0, 0], sm_scale=sm_scale, row0=qj * block_q,
-            col0=ki * bk, causal=causal,
-            mask=mask_ref[rows, :] if has_mask else None, n_real_k=n_real_k,
-            n_real_q=n_real_q, window=window,
-        )
-        p = jnp.exp(s - lse_ref[0, 0, rows, :])
-        dv_acc[...] += _dot(p.astype(dob.dtype), dob, _TN)
-        dp = _dot(dob, v_ref[0, 0], _NT)
-        ds = p * (dp - delta_ref[0, 0, rows, :])  # sm_scale: at the flush
-        dk_acc[...] += _dot(ds.astype(qb.dtype), qb, _TN)
+        for s, lanes in enumerate(own):
+            one = slice(s, s + 1)
+            qb = _own(q_ref[(*lead, rows, slice(None))], lanes)
+            dob = _own(do_ref[(*lead, rows, slice(None))], lanes)
+            sc = _scores(
+                qb, k_ref[lead], sm_scale=sm_scale, row0=qj * block_q,
+                col0=ki * bk, causal=causal,
+                mask=mask_ref[rows, :] if has_mask else None, n_real_k=n_real_k,
+                n_real_q=n_real_q, window=window,
+            )
+            p = jnp.exp(sc - lse_ref[0, 0, rows, one])
+            dv_acc[...] += _dot(p.astype(dob.dtype), dob, _TN)
+            dp = _dot(dob, v_ref[lead], _NT)
+            ds = p * (dp - delta_ref[0, 0, rows, one])  # sm_scale: at the flush
+            dk_acc[...] += _dot(ds.astype(qb.dtype), qb, _TN)
 
     lo, hi = 0, steps
     if causal:
@@ -447,8 +535,8 @@ def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():
-        dk_ref[0, 0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[lead] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[lead] = dv_acc[...].astype(dv_ref.dtype)
 
 
 # --------------------------------------------------------------- emitters
@@ -461,12 +549,18 @@ def _dkv_kernel(*refs, sm_scale, block_q, causal, has_mask, n_real_q,
 # every start, warm or cold). The callee's operations carry names relative
 # to the call site's, so the instruction-to-component table
 # (`obs/scopes.py`) still sees each call under its own layer and phase.
+#
+# `heads` = (query heads, K/V heads) says the operands are token-major,
+# `[B, N, heads x d]` with the statistics `[B, blocks, N, pack]` (a column
+# block's heads side by side); without it they are `[B, H, N, d]` and
+# `[B, H, N, 1]`. The two differ in where a block's (batch, head, rows)
+# land in the index tuple and in nothing else (`_spec`).
 
 _PARALLEL = CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
 )
 _STATICS = ("sm_scale", "block_q", "block_k", "causal", "n_real_q",
-            "n_real_k", "interpret", "window")
+            "n_real_k", "interpret", "window", "heads")
 
 
 def _windowed(window):
@@ -475,28 +569,58 @@ def _windowed(window):
     return {} if window is None else {"window": window}
 
 
-def _row(block, d):
-    """A block of an operand that the grid's third axis walks."""
-    return pl.BlockSpec((1, 1, block, d), lambda b_, h_, i, j: (b_, h_, i, 0))
+def _dims(q_shape, k_shape, heads):
+    """(b, query heads, K/V heads, n_q, n_k, d, pack) of a call from its
+    operands' shapes; `pack` is None head-major, else the heads a column
+    block holds."""
+    if heads is None:
+        b, hq, n_q, d = q_shape
+        return b, hq, k_shape[1], n_q, k_shape[2], d, None
+    hq, hkv = heads
+    b, n_q, cols = q_shape
+    d = cols // hq
+    return b, hq, hkv, n_q, k_shape[1], d, heads_per_block(d, hq, hkv)
 
 
-def _k_span_spec(span_k, d, block_q, causal, group=1, window=None):
-    """The k/v span of grid step (i, j). Causal: spans wholly above the
-    diagonal are dead (the loop runs no tile of them), and re-indexing
-    them to the last live span makes consecutive dead steps name the same
-    block, whose copy Pallas then elides; under a window the spans wholly
-    before it are dead the same way. The kernels' loop bounds and this map
-    share `_causal_last_live_k` and `_window_first_live_k`: they must stay
-    in lockstep. Query head `h_` reads K/V head `h_ // group`."""
+def _spec(rows, width, at, tokens=False):
+    """A block of `rows` x `width` at the (batch, head or column block, row
+    block) that `at(*grid indices)` names: `[1, 1, rows, width]` of a
+    head-major operand (and of the statistics, in either layout),
+    `[1, rows, width]` of a token-major one, whose heads are column
+    blocks."""
+    if not tokens:
+        return pl.BlockSpec((1, 1, rows, width), lambda *g: (*at(*g), 0))
+
+    def rows_first(*g):
+        b_, h_, r = at(*g)
+        return b_, r, h_
+
+    return pl.BlockSpec((1, rows, width), rows_first)
+
+
+def _k_span(span_k, block_q, causal, group=1, window=None):
+    """Where the k/v span of grid step (i, j) lies. Causal: spans wholly
+    above the diagonal are dead (the loop runs no tile of them), and
+    re-indexing them to the last live span makes consecutive dead steps
+    name the same block, whose copy Pallas then elides; under a window the
+    spans wholly before it are dead the same way. The kernels' loop bounds
+    and this map share `_causal_last_live_k` and `_window_first_live_k`:
+    they must stay in lockstep. Query head `h_` reads K/V head
+    `h_ // group`."""
     head = (lambda h_: h_) if group == 1 else (lambda h_: h_ // group)
     if causal and window is not None:
-        return pl.BlockSpec((1, 1, span_k, d), lambda b_, h_, i, j: (
+        return lambda b_, h_, i, j: (
             b_, head(h_), jnp.clip(j, _window_first_live_k(i, block_q, span_k, window),
-                                   _causal_last_live_k(i, block_q, span_k)), 0))
+                                   _causal_last_live_k(i, block_q, span_k)))
     if causal:
-        return pl.BlockSpec((1, 1, span_k, d), lambda b_, h_, i, j: (
-            b_, head(h_), jnp.minimum(j, _causal_last_live_k(i, block_q, span_k)), 0))
-    return pl.BlockSpec((1, 1, span_k, d), lambda b_, h_, i, j: (b_, head(h_), j, 0))
+        return lambda b_, h_, i, j: (
+            b_, head(h_), jnp.minimum(j, _causal_last_live_k(i, block_q, span_k)))
+    return lambda b_, h_, i, j: (b_, head(h_), j)
+
+
+def _resident(b_, h_, i, j):
+    """The block that the grid's third axis walks."""
+    return b_, h_, i
 
 
 def _with_mask(in_specs, operands, layout, mask_pad, mask_spec):
@@ -511,22 +635,29 @@ def _with_mask(in_specs, operands, layout, mask_pad, mask_spec):
     )
 
 
+def _packed(pack):
+    """The kernel's `pack=` for token-major operands, as `_windowed`."""
+    return {} if pack is None else {"pack": pack}
+
+
 @functools.partial(jax.jit, static_argnames=_STATICS)
 def _emit_fwd(q, k, v, mask_pad, layout, *, sm_scale, block_q, block_k,
-              causal, n_real_q, n_real_k, interpret, window=None):
-    b, h, n_q, d = q.shape
-    n_k = k.shape[2]
-    _, span_k = _spans(n_q, n_k, block_q, block_k, d, q.dtype.itemsize)
-    _built("fwd", q, k, block_q, block_k)
+              causal, n_real_q, n_real_k, interpret, window=None, heads=None):
+    b, h, hkv, n_q, n_k, d, pack = _dims(q.shape, k.shape, heads)
+    lanes = pack or 1  # heads a block holds; its width is theirs together
+    _, span_k = _spans(n_q, n_k, block_q, block_k, lanes * d, q.dtype.itemsize)
+    _built("fwd", q, n_k, block_q, block_k, heads)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, block_k=block_k, causal=causal,
         has_mask=mask_pad is not None, n_real_k=n_real_k,
-        steps=span_k // block_k, **_windowed(window),
+        steps=span_k // block_k, **_windowed(window), **_packed(pack),
     )
-    kspec = _k_span_spec(span_k, d, block_q, causal, h // k.shape[1], window)
+    tokens = heads is not None
+    qspec = _spec(block_q, lanes * d, _resident, tokens)
+    kspec = _spec(span_k, lanes * d, _k_span(span_k, block_q, causal, h // hkv, window), tokens)
     in_specs, operands = _with_mask(
-        [_row(block_q, d), kspec, kspec], [q, k, v], layout, mask_pad,
-        pl.BlockSpec((block_q, span_k), lambda b_, h_, i, j: (i, j)),
+        [qspec, kspec, kspec], [q, k, v],
+        layout, mask_pad, pl.BlockSpec((block_q, span_k), lambda b_, h_, i, j: (i, j)),
     )
     # the name is also the kernel's innermost scope, and the chip names the
     # custom call after it (`%fwd_flash.3`): forward, dq and dkv are told
@@ -534,17 +665,17 @@ def _emit_fwd(q, k, v, mask_pad, layout, *, sm_scale, block_q, block_k,
     return pl.pallas_call(
         kernel,
         name="fwd_flash",
-        grid=(b, h, n_q // block_q, n_k // span_k),
+        grid=(b, h // lanes, n_q // block_q, n_k // span_k),
         in_specs=in_specs,
-        out_specs=[_row(block_q, d), _row(block_q, 1)],
+        out_specs=[qspec, _spec(block_q, lanes, _resident)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, n_q, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, n_q, 1), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, h // lanes, n_q, lanes), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, lanes * d), jnp.float32),
         ],
         compiler_params=_PARALLEL,
         interpret=interpret,
@@ -553,18 +684,20 @@ def _emit_fwd(q, k, v, mask_pad, layout, *, sm_scale, block_q, block_k,
 
 @functools.partial(jax.jit, static_argnames=_STATICS)
 def _emit_dq(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
-             block_k, causal, n_real_q, n_real_k, interpret, window=None):
-    b, h, n_q, d = q.shape
-    n_k = k.shape[2]
-    _, span_k = _spans(n_q, n_k, block_q, block_k, d, q.dtype.itemsize)
-    _built("dq", q, k, block_q, block_k)
+             block_k, causal, n_real_q, n_real_k, interpret, window=None, heads=None):
+    b, h, hkv, n_q, n_k, d, pack = _dims(q.shape, k.shape, heads)
+    lanes = pack or 1
+    _, span_k = _spans(n_q, n_k, block_q, block_k, lanes * d, q.dtype.itemsize)
+    _built("dq", q, n_k, block_q, block_k, heads)
     kernel = functools.partial(
         _dq_kernel, sm_scale=sm_scale, block_k=block_k, causal=causal,
         has_mask=mask_pad is not None, n_real_k=n_real_k,
-        steps=span_k // block_k, **_windowed(window),
+        steps=span_k // block_k, **_windowed(window), **_packed(pack),
     )
-    qspec, rowspec = _row(block_q, d), _row(block_q, 1)
-    kspec = _k_span_spec(span_k, d, block_q, causal, h // k.shape[1], window)
+    tokens = heads is not None
+    qspec = _spec(block_q, lanes * d, _resident, tokens)
+    rowspec = _spec(block_q, lanes, _resident)
+    kspec = _spec(span_k, lanes * d, _k_span(span_k, block_q, causal, h // hkv, window), tokens)
     in_specs, operands = _with_mask(
         [qspec, kspec, kspec, qspec, rowspec, rowspec],
         [q, k, v, do, lse, delta], layout, mask_pad,
@@ -573,11 +706,11 @@ def _emit_dq(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
     return pl.pallas_call(
         kernel,
         name="dq_flash",
-        grid=(b, h, n_q // block_q, n_k // span_k),
+        grid=(b, h // lanes, n_q // block_q, n_k // span_k),
         in_specs=in_specs,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, lanes * d), jnp.float32)],
         compiler_params=_PARALLEL,
         interpret=interpret,
     )(*operands)
@@ -585,18 +718,18 @@ def _emit_dq(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
 
 @functools.partial(jax.jit, static_argnames=_STATICS)
 def _emit_dkv(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
-              block_k, causal, n_real_q, n_real_k, interpret, window=None):
-    b, h, n_q, d = q.shape
-    n_k = k.shape[2]
-    group = h // k.shape[1]  # query heads that share one K/V head
-    span_q, _ = _spans(n_q, n_k, block_q, block_k, d, q.dtype.itemsize)
+              block_k, causal, n_real_q, n_real_k, interpret, window=None, heads=None):
+    b, h, hkv, n_q, n_k, d, pack = _dims(q.shape, k.shape, heads)
+    lanes = pack or 1
+    group = h // hkv  # query heads that share one K/V head
+    span_q, _ = _spans(n_q, n_k, block_q, block_k, lanes * d, q.dtype.itemsize)
     n_spans = n_q // span_q
-    _built("dkv", q, k, block_q, block_k)
+    _built("dkv", q, n_k, block_q, block_k, heads)
     kernel = functools.partial(
         _dkv_kernel, sm_scale=sm_scale, block_q=block_q, causal=causal,
         has_mask=mask_pad is not None, n_real_q=n_real_q, n_real_k=n_real_k,
         steps=span_q // block_q, **_windowed(window),
-        **({} if group == 1 else {"n_spans": n_spans}),
+        **({} if group == 1 else {"n_spans": n_spans}), **_packed(pack),
     )
 
     def live_span(i, j):
@@ -615,13 +748,14 @@ def _emit_dkv(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
         return jnp.minimum(j, n_spans - 1)
 
     if group == 1:
-        q_idx = lambda b_, h_, i, j: (b_, h_, live_span(i, j), 0)
+        q_at = lambda b_, h_, i, j: (b_, h_, live_span(i, j))
     else:  # the last axis walks the group's heads, each head's spans in turn
-        q_idx = lambda b_, h_, i, j: (
-            b_, h_ * group + j // n_spans, live_span(i, j % n_spans), 0)
-    qspec = pl.BlockSpec((1, 1, span_q, d), q_idx)
-    rowspec = pl.BlockSpec((1, 1, span_q, 1), q_idx)
-    kspec = _row(block_k, d)
+        q_at = lambda b_, h_, i, j: (
+            b_, h_ * group + j // n_spans, live_span(i, j % n_spans))
+    tokens = heads is not None
+    qspec = _spec(span_q, lanes * d, q_at, tokens)
+    rowspec = _spec(span_q, lanes, q_at)
+    kspec = _spec(block_k, lanes * d, _resident, tokens)
     in_specs, operands = _with_mask(
         [qspec, kspec, kspec, qspec, rowspec, rowspec],
         [q, k, v, do, lse, delta], layout, mask_pad,
@@ -631,7 +765,7 @@ def _emit_dkv(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
     return pl.pallas_call(
         kernel,
         name="dkv_flash",
-        grid=(b, k.shape[1], n_k // block_k, group * n_spans),
+        grid=(b, hkv // lanes, n_k // block_k, group * n_spans),
         in_specs=in_specs,
         out_specs=[kspec, kspec],
         out_shape=[
@@ -639,8 +773,8 @@ def _emit_dkv(q, k, v, do, lse, delta, mask_pad, layout, *, sm_scale, block_q,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, lanes * d), jnp.float32),
+            pltpu.VMEM((block_k, lanes * d), jnp.float32),
         ],
         compiler_params=_PARALLEL,
         interpret=interpret,
@@ -662,10 +796,19 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
+    layout: str = HEAD_MAJOR,
 ) -> jnp.ndarray:
     """Flash attention over q [B, Hq, N, D], k/v [B, Hkv, N, D] with an
     optional STATIC token mask. Hq is a multiple of Hkv: query head j reads
     K/V head `j // (Hq // Hkv)`, and dk/dv sum over the group.
+
+    `layout="token_major"`: q [B, N, Hq, D], k/v [B, N, Hkv, D], the
+    columns of a fused projection as it writes them, and the result
+    [B, N, Hq, D], the columns the output projection contracts over; no
+    operand, result or gradient is transposed on the way. The same kernels
+    then take a head as a column block (`heads_per_block(D, Hq, Hkv)` must
+    not be None: a head size that is a multiple of 128, or a divisor of it
+    with as many K/V heads as query heads).
 
     `mask` must be a host-side numpy bool array [Nq, Nk] (True = attend); it
     is analyzed into a block-occupancy layout so empty tiles are skipped.
@@ -683,15 +826,22 @@ def flash_attention(
     chosen from the shape (`choose_tiles`). Lengths a tile does not divide
     are zero-padded up to it and the padding masked.
     """
-    assert q.ndim == 4, f"expected [B,H,N,D], got {q.shape}"
-    assert q.shape[1] % k.shape[1] == 0 and k.shape[1] == v.shape[1], (
-        f"{q.shape[1]} query heads cannot share {k.shape[1]} K/V heads")
+    assert q.ndim == 4, f"expected [B,H,N,D] or [B,N,H,D], got {q.shape}"
+    tokens = layout == TOKEN_MAJOR
+    assert tokens or layout == HEAD_MAJOR, layout
+    heads_at, rows_at = (2, 1) if tokens else (1, 2)
+    hq, hkv = q.shape[heads_at], k.shape[heads_at]
+    assert hq % hkv == 0 and hkv == v.shape[heads_at], (
+        f"{hq} query heads cannot share {hkv} K/V heads")
+    n_q, n_k = q.shape[rows_at], k.shape[rows_at]
     if window is not None:
-        assert causal and mask is None and q.shape[2] == k.shape[2], (
+        assert causal and mask is None and n_q == n_k, (
             "a window is a bound of the causal, unmasked, self-attention walk")
-    n_q, n_k = q.shape[2], k.shape[2]
     d = q.shape[3]
-    chosen = choose_tiles(n_q, n_k, d, q.dtype, masked=mask is not None)
+    pack = heads_per_block(d, hq, hkv) if tokens else None
+    assert pack is not None or not tokens, (
+        f"no column block holds whole heads of {d} ({hq} over {hkv}): use head_major")
+    chosen = choose_tiles(n_q, n_k, d * (pack or 1), q.dtype, masked=mask is not None)
     block_q = chosen[0] if block_q is None else min(block_q, max(n_q, 1))
     block_k = chosen[1] if block_k is None else min(block_k, max(n_k, 1))
     scale = d**-0.5 if sm_scale is None else sm_scale
@@ -700,37 +850,52 @@ def flash_attention(
     # host arrays, handed to the emitters as they are: a device constant
     # made here would belong to whatever trace is open (a `jax.checkpoint`
     # body, say) and leak from it through the VJP's closures
-    mask_pad = layout = None
+    mask_pad = occupancy = None
     if mask is not None:
         assert mask.shape == (n_q, n_k), f"mask {mask.shape} != {(n_q, n_k)}"
-        mask_pad, layout = mask_block_layout(mask, block_q, block_k)
+        mask_pad, occupancy = mask_block_layout(mask, block_q, block_k)
         mask_pad = mask_pad.astype(np.int8)  # a bool block is held as int32
 
     static = dict(
         sm_scale=float(scale), block_q=block_q, block_k=block_k,
         causal=causal and mask is None, n_real_q=n_q, n_real_k=n_k,
         interpret=bool(interp), **_windowed(window),
+        **({"heads": (hq, hkv)} if tokens else {}),
     )
+
+    def row_sums(do, o):
+        """delta [B, H, N, 1] (token-major: [B, blocks, N, pack], a
+        block's heads side by side, as the forward writes lse)."""
+        prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+        if not tokens:
+            return jnp.sum(prod, axis=-1, keepdims=True)
+        # a head's columns summed where they lie; only the sums, two numbers
+        # a row and block, are laid out anew
+        per_head = jnp.sum(prod.reshape(*prod.shape[:2], hq, d), axis=-1)
+        return per_head.reshape(*prod.shape[:2], hq // pack, pack).transpose(0, 2, 1, 3)
 
     @jax.custom_vjp
     def _attn(q_, k_, v_):
-        return _emit_fwd(q_, k_, v_, mask_pad, layout, **static)[0]
+        return _emit_fwd(q_, k_, v_, mask_pad, occupancy, **static)[0]
 
     def _attn_fwd(q_, k_, v_):
-        o, lse = _emit_fwd(q_, k_, v_, mask_pad, layout, **static)
+        o, lse = _emit_fwd(q_, k_, v_, mask_pad, occupancy, **static)
         return o, (q_, k_, v_, o, lse)
 
     def _attn_bwd(res, do):
         q_, k_, v_, o, lse = res
-        delta = jnp.sum(
-            do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
-        )
-        dq = _emit_dq(q_, k_, v_, do, lse, delta, mask_pad, layout, **static)
-        dk, dv = _emit_dkv(q_, k_, v_, do, lse, delta, mask_pad, layout, **static)
+        delta = row_sums(do, o)
+        dq = _emit_dq(q_, k_, v_, do, lse, delta, mask_pad, occupancy, **static)
+        dk, dv = _emit_dkv(q_, k_, v_, do, lse, delta, mask_pad, occupancy, **static)
         return dq, dk, dv
 
     _attn.defvjp(_attn_fwd, _attn_bwd)
-    out = _attn(_pad_to(q, 2, block_q), _pad_to(k, 2, block_k), _pad_to(v, 2, block_k))
+    if tokens:  # a head's columns side by side: the projection's own layout
+        q, k, v = (t.reshape(*t.shape[:2], -1) for t in (q, k, v))
+    out = _attn(_pad_to(q, rows_at, block_q), _pad_to(k, rows_at, block_k),
+                _pad_to(v, rows_at, block_k))
+    if tokens:
+        return out[:, :n_q].reshape(out.shape[0], n_q, hq, d)
     return out[:, :, :n_q, :]
 
 

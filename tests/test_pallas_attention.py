@@ -223,7 +223,7 @@ def test_chosen_tiles_are_legal_and_exact(case):
     multiple of 128 is never padded, and forward and gradients at those
     tiles agree with dense attention on operands of the same dtype."""
     b, h, n, d, dtype, masked = TILE_CASES[case]
-    bq, bk = pa.choose_tiles(n, n, d, dtype)
+    bq, bk = pa.choose_tiles(n, n, d, dtype, masked=masked)  # as `flash_attention` asks
     for block in (bq, bk):
         n_pad = -(-n // block) * block
         assert n_pad % block == 0 and n_pad - n < 128
@@ -235,7 +235,7 @@ def test_chosen_tiles_are_legal_and_exact(case):
     span_q, span_k = pa._spans(n_pad, n_pad, bq, bk, d, itemsize)
     assert n_pad % span_q == 0 and span_q % bq == 0
     assert n_pad % span_k == 0 and span_k % bk == 0
-    assert pa.vmem_bytes(bq, bk, span_q, span_k, d, itemsize) <= pa.VMEM_BUDGET
+    assert pa.vmem_bytes(bq, bk, span_q, span_k, d, itemsize, masked) <= pa.VMEM_BUDGET
 
     mask = _flagship_axial_mask(n) if masked else None
     if masked:
@@ -485,3 +485,223 @@ def test_window_and_groups_refuse_what_they_cannot_do():
         flash_attention(q, k, v, window=8, causal=False)
     with pytest.raises(AssertionError, match="window"):
         flash_attention(q, k, v, window=8, mask=np.tril(np.ones((64, 64), bool)))
+
+
+# ------------------------------------------------------- token-major entry
+#
+# q [B, N, Hq, D], k/v [B, N, Hkv, D] as the projection writes them and the
+# result as `to_out` reads it: the same kernel bodies behind a second family
+# of index maps, held here to the head-major entry (which the tests above
+# hold to dense attention).
+
+# (d, query heads, K/V heads, n, window, masked, tiles, VMEM budget): a pair
+# of 64-wide heads a 128-lane block, four of 32, a 128-wide head a block;
+# one K/V head per query head and eight query heads over one; lengths the
+# tile does not divide; rows held in several spans (the DMA skip's clamps)
+TOKEN_CASES = {
+    "pair_causal_ragged": (64, 4, 4, 200, None, False, (64, 64), None),
+    "pair_window": (64, 4, 4, 256, 100, False, (64, 128), None),
+    "pair_mask": (64, 2, 2, 96, None, True, (32, 32), None),
+    "pair_spans": (64, 2, 2, 1024, 130, False, (128, 128), 1200 << 10),
+    "quad_causal": (32, 4, 4, 96, None, False, (32, 32), None),
+    "one_causal_ragged": (128, 2, 2, 200, None, False, (64, 64), None),
+    "one_grouped_window": (128, 8, 1, 256, 100, False, (64, 64), None),
+    "one_grouped_ragged": (128, 8, 1, 200, None, False, (128, 128), None),
+    "one_grouped_spans": (128, 4, 2, 1000, 300, False, (128, 256), 1600 << 10),
+    "one_mask": (128, 2, 2, 96, None, True, (32, 32), None),
+}
+
+
+def _token_mask(n):
+    mask = np.tril(np.ones((n, n), bool))
+    mask[:, n // 3: n // 2] = False
+    np.fill_diagonal(mask, True)
+    return mask
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", TOKEN_CASES)
+def test_token_major_entry_equals_the_head_major_one(monkeypatch, case, dtype):
+    """Output and all three gradients of `layout="token_major"` against the
+    head-major entry on the transposed operands: the same products in the
+    same order, so float32 agrees to rounding of the sums and bf16 to a
+    unit of its own resolution. `layouts_built` says which index maps each
+    of the three bodies got."""
+    d, hq, hkv, n, window, masked, blocks, budget = TOKEN_CASES[case]
+    if budget:
+        monkeypatch.setattr(pa, "VMEM_BUDGET", budget)
+        width, size = d * pa.heads_per_block(d, hq, hkv), jnp.dtype(dtype).itemsize
+        assert max(pa._spans(n, n, *blocks, width, size)) < n
+    rng = np.random.RandomState(3)
+    q, w = (jnp.asarray(rng.randn(2, hq, n, d), dtype) for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(2, hkv, n, d), dtype) for _ in range(2))
+    kw = dict(window=window, mask=_token_mask(n) if masked else None,
+              block_q=blocks[0], block_k=blocks[1])
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def head(q, k, v):
+        out = flash_attention(q, k, v, **kw)
+        return (f32(out) * f32(w)).sum(), out
+
+    def token(q, k, v):
+        out = t(flash_attention(t(q), t(k), t(v), layout=pa.TOKEN_MAJOR, **kw))
+        return (f32(out) * f32(w)).sum(), out
+
+    pa.forget()
+    (_, want), grads_want = jax.value_and_grad(head, (0, 1, 2), has_aux=True)(q, k, v)
+    assert pa.layouts_built == {pa.HEAD_MAJOR: 3}
+    pa.forget()
+    (_, got), grads_got = jax.value_and_grad(token, (0, 1, 2), has_aux=True)(q, k, v)
+    assert pa.layouts_built == {pa.TOKEN_MAJOR: 3}
+    assert set(pa.tiles_chosen.values()) == {blocks}
+    assert got.dtype == want.dtype == jnp.dtype(dtype)
+    for a, b, name in zip((got, *grads_got), (want, *grads_want), ("o", "dq", "dk", "dv")):
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, atol=2e-5, err_msg=name)
+        else:  # a sum rounded once to bf16 on either side
+            assert np.linalg.norm(f32(a) - f32(b)) < 1e-2 * np.linalg.norm(f32(b)), name
+
+
+def test_heads_per_block_follows_the_head_size():
+    """A head of 128 lanes is a column block; narrower heads share one (a
+    pair at 64) when K/V heads are as many as query heads and fill whole
+    blocks; nothing else is token-major, and the entry says so."""
+    assert pa.heads_per_block(128, 32, 4) == 1
+    assert pa.heads_per_block(256, 8, 8) == 1
+    assert pa.heads_per_block(64, 16, 16) == 2
+    assert pa.heads_per_block(32, 8, 8) == 4
+    assert pa.heads_per_block(64, 16, 4) is None  # a pair would straddle K/V heads
+    assert pa.heads_per_block(64, 3, 3) is None   # half a block left over
+    assert pa.heads_per_block(192, 4, 4) is None and pa.heads_per_block(48, 8, 8) is None
+    q = jnp.zeros((1, 64, 4, 64))
+    with pytest.raises(AssertionError, match="head_major"):
+        flash_attention(q, q[:, :, :2], q[:, :, :2], layout=pa.TOKEN_MAJOR)
+    with pytest.raises(AssertionError):
+        flash_attention(q, q, q, layout="rows")
+
+
+def test_token_major_tiles_plan_for_the_block_not_the_head():
+    """The flagship's call: 16 heads of 64 are 8 blocks of 128 lanes, and
+    the tiles are those of a 128-wide head (the operands in VMEM are twice
+    as wide as a head-major call's)."""
+    x = jax.ShapeDtypeStruct((1, 1280, 2, 64), jnp.bfloat16)
+    pa.forget()
+    jax.eval_shape(lambda x: flash_attention(x, x, x, layout=pa.TOKEN_MAJOR), x)
+    want = pa.choose_tiles(1280, 1280, 128, jnp.bfloat16)
+    assert set(pa.tiles_chosen.values()) == {want}
+    assert pa.layouts_built == {pa.TOKEN_MAJOR: 1}
+
+
+def _module_pair(**kw):
+    from dalle_pytorch_tpu.models.attention import Attention
+
+    return Attention(**kw, attn_impl="dense"), Attention(**kw, attn_impl="flash")
+
+
+def _module_grads(attn, params, x, **call):
+    def loss(params, x):
+        out, _ = attn.apply(params, x, **call)
+        return (out ** 2).sum()
+
+    return jax.grad(loss, (0, 1))(params, x)
+
+
+@pytest.mark.parametrize("path", ["dalle_pair", "dalle_masked", "dalle_128", "grouped",
+                                  "grouped_whole_norm", "head_major_fallback"])
+def test_attention_module_flash_keeps_its_operands_token_major(path):
+    """`attn_impl="flash"` against `"dense"` through the whole module,
+    output, parameter and input gradients. The DALL-E path (angle-table
+    rotary on q, k and v, each channel its own angle; a static mask) at head
+    sizes 64 and 128 builds token-major bodies alone, its rotary the one-pass
+    kernel on `[B, n, heads x dh]`, held here to `apply_rotary` on
+    `[B, heads, n, dh]`. The grouped path (q/k norm, rotate-half rotary on q
+    and k, a window, K/V heads shared) and a module whose heads fill no
+    column block stay head-major."""
+    from dalle_pytorch_tpu.ops.rotary import rotary_cos_sin
+
+    n, dim = 80, 48
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(2, n, dim), jnp.float32)
+    call, layout = {}, pa.TOKEN_MAJOR
+    if path.startswith("dalle") or path == "head_major_fallback":
+        heads, dh = {"head_major_fallback": (3, 64), "dalle_128": (2, 128)}.get(path, (2, 64))
+        kw = dict(dim=dim, seq_len=n, heads=heads, dim_head=dh, causal=True)
+        if path == "dalle_masked":
+            kw["static_mask"] = axial_static_mask(n - 1, 8, axis=0)[:n, :n]
+        if path == "head_major_fallback":
+            layout = pa.HEAD_MAJOR  # three heads of 64 leave half a block over
+        call["rotary"] = jnp.asarray(rng.randn(n, 24), jnp.float32)
+    else:
+        layout = pa.HEAD_MAJOR
+        kw = dict(dim=dim, seq_len=n, heads=4, dim_head=128, kv_heads=2, causal=True,
+                  window=24, use_bias=False,
+                  qk_norm="whole" if path == "grouped_whole_norm" else True)
+        call["rotary_cs"] = rotary_cos_sin(
+            np.arange(n), {"type": "default", "dim": 128, "theta": 10000.0})
+    dense_attn, flash_attn = _module_pair(**kw)
+    params = dense_attn.init(jax.random.PRNGKey(0), x, **call)
+    # gains off 1 so that a norm applied on the wrong axis would show
+    params = jax.tree.map(lambda p: p * (1 + 0.1 * rng.randn(*p.shape).astype(p.dtype)), params)
+    want, _ = dense_attn.apply(params, x, **call)
+    pa.forget()
+    got, _ = flash_attn.apply(params, x, **call)
+    np.testing.assert_allclose(got, want, atol=5e-5)
+    grads_got = _module_grads(flash_attn, params, x, **call)
+    assert pa.layouts_built == {layout: 3}
+    grads_want = _module_grads(dense_attn, params, x, **call)
+    for a, b in zip(jax.tree.leaves(grads_got), jax.tree.leaves(grads_want)):
+        np.testing.assert_allclose(a, b, atol=2e-3, rtol=1e-3)
+
+
+def test_token_major_module_under_a_mesh_takes_its_heads_columns_of_q_k_and_v():
+    """Under `train_mesh` the kernels run in a shard_map over batch and
+    heads. Token-major, a shard's operand is its heads' columns of all three
+    of q, k and v out of the fused projection (the `[B, n, 3, heads, dh]`
+    view split on the head axis), turned and attended there: equal to the
+    module without a mesh, values and gradients, and built token-major (two
+    heads of 64 a shard are one column block; a fallback to head-major
+    would pass the values and fail the counter)."""
+    from dalle_pytorch_tpu.models.attention import Attention
+    from dalle_pytorch_tpu.parallel import make_mesh
+
+    mesh = make_mesh(dp=2, fsdp=2, tp=2)
+    n, dim = 48, 32
+    rng = np.random.RandomState(7)
+    x = jnp.asarray(rng.randn(8, n, dim), jnp.float32)
+    rotary = jnp.asarray(rng.randn(n, 20), jnp.float32)
+    kw = dict(dim=dim, seq_len=n, heads=4, dim_head=64, causal=True, attn_impl="flash")
+    plain, sharded = Attention(**kw), Attention(**kw, train_mesh=mesh)
+    params = plain.init(jax.random.PRNGKey(0), x, rotary=rotary)
+    want = jax.jit(lambda p, x: _module_grads(plain, p, x, rotary=rotary))(params, x)
+    pa.forget()
+    with mesh:
+        got = jax.jit(lambda p, x: _module_grads(sharded, p, x, rotary=rotary))(params, x)
+    assert pa.layouts_built == {pa.TOKEN_MAJOR: 3}
+    # a shard's bodies see its own rows and heads: 8 rows over dp x fsdp, 4 heads over tp
+    assert {key[1] for key in pa.tiles_chosen} == {(2, n, 2 * 64)}
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+def test_token_major_module_holds_no_head_transpose():
+    """On the uncached flash path nothing between `to_qkv` and `to_out` is
+    transposed: the module's jaxpr has no `transpose` of a [.., heads, dh]
+    array, forward or backward (the dense path has eight; the backward's
+    row sums `delta`, two numbers a row and block, are still laid out as
+    the kernel wrote `lse`)."""
+    n, dim = 64, 32
+    x = jnp.zeros((2, n, dim), jnp.float32)
+    rotary = jnp.zeros((n, 32), jnp.float32)
+    dense_attn, flash_attn = _module_pair(dim=dim, seq_len=n, heads=2, dim_head=64, causal=True)
+    params = dense_attn.init(jax.random.PRNGKey(0), x, rotary=rotary)
+
+    def transposes(attn):
+        fn = lambda p, x: _module_grads(attn, p, x, rotary=rotary)
+        return [
+            eqn for eqn in _eqns(jax.make_jaxpr(fn)(params, x).jaxpr)
+            if eqn.primitive.name == "transpose" and eqn.invars[0].aval.shape[-1] == 64
+        ]
+
+    assert len(transposes(dense_attn)) >= 8
+    assert transposes(flash_attn) == []

@@ -96,34 +96,48 @@ def _axial_mask():
     )[:SEQ, :SEQ]
 
 
-TRAIN_BATCH = 16  # the train cell's: the kernels see 16 x 16 x 1280 x 64
+TRAIN_BATCH = 16  # the train cell's: the kernels see 16 x 1280 x (16 x 64)
+# what `choose_tiles` gives the cell's token-major call, a pair of heads a
+# block of 128 lanes: the plan of a 128-wide head, 11.88 MiB of the 12
+TOKEN_TILES = (640, 640)
 
 
+@pytest.mark.parametrize("layout", ["head_major", "token_major"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
 @pytest.mark.parametrize("masked", [False, True], ids=["causal", "axial"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
-def test_flash_attention_compiles(one_chip, masked, grad, dtype):
+def test_flash_attention_compiles(one_chip, masked, grad, dtype, layout):
     """fwd, dq and dkv at the train cell's shape and the tiles the chooser
     gives it: Mosaic takes the tiles, the in-kernel loops over them, the
-    bf16 MXU operands and the VMEM they ask for."""
+    bf16 MXU operands and the VMEM they ask for; token-major, a pair of
+    64-wide heads as one 128-lane column block of `[B, N, H x D]`, its
+    lane masks and selects and the `[rows, 2]` statistics."""
     from dalle_pytorch_tpu.ops import pallas_attention as pa
 
+    tokens = layout == pa.TOKEN_MAJOR
     mask = _axial_mask() if masked else None
-    attn = functools.partial(pa.flash_attention, mask=mask, interpret=False)
+    attn = functools.partial(pa.flash_attention, mask=mask, interpret=False, layout=layout)
     if grad:
         fn = jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
     else:
         fn = attn
-    shapes = [
-        jax.ShapeDtypeStruct((TRAIN_BATCH, H, SEQ, D), dtype, sharding=one_chip)
-    ] * 3
+    shape = (TRAIN_BATCH, SEQ, H, D) if tokens else (TRAIN_BATCH, H, SEQ, D)
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)] * 3
     pa.forget()
-    compiled = _compile(fn, *shapes)
-    assert compiled.as_text().count("tpu_custom_call") == (3 if grad else 1)
+    text = _compile(fn, *shapes).as_text()
+    assert text.count("tpu_custom_call") == (3 if grad else 1)
     assert pa.kernel_bodies == (3 if grad else 1)
-    # float32 operands and a mask's block both take VMEM from the score tile
-    want = (640, 640) if dtype == jnp.bfloat16 and not masked else (256, 640)
+    assert pa.layouts_built == {layout: 3 if grad else 1}
+    # float32 operands, a mask's block and a block twice a head's width each
+    # take VMEM from the score tile: any two of them leave 256 x 640
+    narrow = (dtype != jnp.bfloat16) + masked + tokens
+    want = (640, 640) if narrow < 2 else (256, 640)
     assert set(pa.tiles_chosen.values()) == {want}
+    if tokens:  # the kernels' operands and results are the projection's columns
+        kernels = re.findall(r"%(\w+_flash)[.\d]* = \(?(\w+\[[\d,]*\])", text)
+        assert len(kernels) == (3 if grad else 1)
+        assert {shape for _, shape in kernels} == {
+            f"{'bf16' if dtype == jnp.bfloat16 else 'f32'}[{TRAIN_BATCH},{SEQ},{H * D}]"}
 
 
 @pytest.mark.parametrize("n", [1, 4, 256])
@@ -293,18 +307,27 @@ def _flagship_step(devices, mesh_axes, batch, executor="scan", reversible=True):
     )
 
 
-# This file's unrolled flagship step lowers to 9,163,364 characters with 4
-# kernel bodies in it, and did to 9,529,737 with 48 (one per call site,
-# before PR 26). The bound leaves 1% for paths and line numbers, which the
-# serialized kernels embed; a body built per call site again breaks it.
-LOWERED_TEXT_BYTES = 9_255_000
+# This file's unrolled flagship step lowers to 8,948,581 characters with 4
+# flash bodies and 3 of the rotary's pass in it; it did to 9,159,532 with the
+# rotary as 36 groups of slices, stacks and concatenates on `[.., 16, 64]`
+# and the head transposes around 4 head-major bodies (PR 33), and to
+# 9,529,737 with 48 bodies (one per call site, before PR 26). The bound is
+# the parent's length: paths and line numbers, which the serialized kernels
+# embed, move it by a few thousand; a body built per call site breaks it.
+LOWERED_TEXT_BYTES = 9_159_532
 
 
 def test_flagship_step_holds_each_kernel_body_once(topo, compiled_kernels):
     """The unrolled 12-layer step with remat calls the flash kernels 48
     times (fwd, fwd again under remat, dq, dkv per layer) and holds 4
     bodies: the emitters are jitted, so each is traced once and lowered to
-    Mosaic once per program. Lowering only; nothing is compiled."""
+    Mosaic once per program. All four index the projection's columns,
+    `[B, N, H x D]`, and so does the rotary's pass, which takes `to_qkv`'s
+    result whole and hands q, k and v over (forward and under remat; joined
+    again backward: 36 calls of 3 bodies); between `to_qkv` and `to_out` no
+    array is laid out `[B, H, N, D]`, none is transposed and the fused
+    projection is never sliced.
+    Lowering only; nothing is compiled."""
     from dalle_pytorch_tpu.ops import pallas_attention as pa
 
     pa.forget()
@@ -316,13 +339,23 @@ def test_flagship_step_holds_each_kernel_body_once(topo, compiled_kernels):
     init = [key for key in pa.tiles_chosen if key[1][0] == 1]
     assert [key[0] for key in init] == ["fwd"]
     assert pa.kernel_bodies - len(init) == 4, pa.tiles_chosen
-    assert text.count("tpu_custom_call") == 4
-    for emitter, calls in (("_emit_fwd", 24), ("_emit_dq", 12), ("_emit_dkv", 12)):
-        assert len(re.findall(rf"call @{emitter}\w*\(", text)) == calls, emitter
-    assert set(pa.tiles_chosen.values()) == {(640, 640)}
+    assert pa.layouts_built == {pa.TOKEN_MAJOR: 4 + len(init)}
+    calls = {"_emit_fwd": (24, 2), "_emit_dq": (12, 1), "_emit_dkv": (12, 1),
+             "_emit_split": (24, 2), "_emit_join": (12, 1)}
+    for emitter, (sites, bodies) in calls.items():
+        assert len(re.findall(rf"call @{emitter}\w*\(", text)) == sites, emitter
+        assert len(set(re.findall(rf"func.func private @({emitter}\w*)\(", text))) == bodies
+    assert text.count("tpu_custom_call") == 4 + 3
+    assert set(pa.tiles_chosen.values()) == {TOKEN_TILES}
     assert {key[1] for key in pa.tiles_chosen if key not in init} == {
-        (TRAIN_BATCH, H, SEQ, D)
+        (TRAIN_BATCH, SEQ, H * D)
     }
+    assert f"{TRAIN_BATCH}x{H}x{SEQ}x{D}x" not in text  # nothing head-major
+    token_major = f"tensor<{TRAIN_BATCH}x{SEQ}x{H}x{D}x"
+    assert not [line for line in text.splitlines()
+                if "stablehlo.transpose" in line and token_major in line]
+    assert not [line for line in text.splitlines()
+                if "stablehlo.slice" in line and f"{TRAIN_BATCH}x{SEQ}x{3 * H * D}x" in line]
     assert len(text) < LOWERED_TEXT_BYTES, len(text)
 
 
@@ -338,7 +371,7 @@ def test_flagship_step_holds_four_bodies_with_the_new_arguments_spelled_out(
     real = attention.flash_attention
 
     def spelled_out(q, k, v, **kw):
-        assert q.shape[1] == k.shape[1]
+        assert q.shape[2] == k.shape[2] and kw["layout"] == pa.TOKEN_MAJOR
         return real(q, k, v, **{"window": None, **kw})
 
     monkeypatch.setattr(attention, "flash_attention", spelled_out)
@@ -346,8 +379,8 @@ def test_flagship_step_holds_four_bodies_with_the_new_arguments_spelled_out(
     text = _flagship_step(
         [topo.devices[0]], dict(dp=1), batch=TRAIN_BATCH, executor="unrolled"
     ).as_text()
-    assert text.count("tpu_custom_call") == 4
-    assert set(pa.tiles_chosen.values()) == {(640, 640)}
+    assert text.count("tpu_custom_call") == 4 + 3  # flash bodies + the rotary's
+    assert set(pa.tiles_chosen.values()) == {TOKEN_TILES}
     assert len(text) < LOWERED_TEXT_BYTES, len(text)
 
 
@@ -356,23 +389,71 @@ def test_flagship_step_holds_four_bodies_with_the_new_arguments_spelled_out(
 LM_B, LM_H, LM_HKV, LM_N, LM_D, LM_WINDOW = 4, 32, 4, 8192, 128, 1024
 
 
+@pytest.mark.parametrize("layout", ["head_major", "token_major"])
 @pytest.mark.parametrize("window", [LM_WINDOW, None], ids=["window", "full"])
-def test_flash_attention_compiles_with_grouped_heads_and_a_window(one_chip, window):
+def test_flash_attention_compiles_with_grouped_heads_and_a_window(one_chip, window, layout):
     """fwd, dq and dkv at the language-model cell's shape: head 128, length
     8192 (where a row no longer stays resident: spans of 4096 keys and 1024
     query rows), 8 query heads a K/V head, the window's loop bounds and DMA
-    skip. The tiles come from the shape alone."""
+    skip. The tiles come from the shape alone. Token-major a head is one
+    128-lane column block of q `[B, N, 32 x 128]` and of k, v
+    `[B, N, 4 x 128]`, with the same tiles and spans."""
     from dalle_pytorch_tpu.ops import pallas_attention as pa
 
-    q = jax.ShapeDtypeStruct((LM_B, LM_H, LM_N, LM_D), jnp.bfloat16, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((LM_B, LM_HKV, LM_N, LM_D), jnp.bfloat16, sharding=one_chip)
-    attn = functools.partial(pa.flash_attention, window=window, interpret=False)
+    tokens = layout == pa.TOKEN_MAJOR
+    # token-major operands arrive as a projection writes them, `[B, N, H x D]`
+    # (a 4-D parameter of 4 heads would be tiled (4, 128) and copied out of it)
+    shape = lambda h: (LM_B, LM_N, h * LM_D) if tokens else (LM_B, h, LM_N, LM_D)
+    heads = lambda t: t.reshape(LM_B, LM_N, -1, LM_D) if tokens else t
+    q = jax.ShapeDtypeStruct(shape(LM_H), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct(shape(LM_HKV), jnp.bfloat16, sharding=one_chip)
+    attn = functools.partial(pa.flash_attention, window=window, interpret=False, layout=layout)
+    loss = lambda q, k, v: attn(heads(q), heads(k), heads(v)).astype(jnp.float32).sum()
     pa.forget()
-    compiled = _compile(
-        jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(), (0, 1, 2)), q, kv, kv)
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    text = _compile(jax.grad(loss, (0, 1, 2)), q, kv, kv).as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert pa.layouts_built == {layout: 3}
     assert set(pa.tiles_chosen.values()) == {(512, 512)}
     assert pa._spans(LM_N, LM_N, 512, 512, LM_D, 2) == (1024, 4096)
+    if tokens:
+        kernels = dict(re.findall(r"%(\w+_flash)[.\d]* = \(?(\w+\[[\d,]*\])", text))
+        assert kernels == {
+            "fwd_flash": f"bf16[{LM_B},{LM_N},{LM_H * LM_D}]",
+            "dq_flash": f"bf16[{LM_B},{LM_N},{LM_H * LM_D}]",
+            "dkv_flash": f"bf16[{LM_B},{LM_N},{LM_HKV * LM_D}]",
+        }
+        # no bf16 operand or result of a kernel is laid out anew. (Float32
+        # copies remain and say nothing of the program: the row sums `delta`
+        # are laid out as `lse` is written, head-major too, and this loss
+        # converts the whole output to float32, which the module never does.)
+        assert not re.search(r"= bf16\[[\d,]*\]\S* (transpose|copy)\(", text)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_rotary_pass_compiles(one_chip, compiled_kernels, dtype):
+    """The rotary's one pass at the train cell's shape, forward (the fused
+    projection `[B, N, 3 x 1024]` in, q, k and v out) and backward (their
+    cotangents in, the projection's out): lane rotations of `[rows, 1024]`
+    in float32, a part at a time, blocks of 160 rows (80 of float32
+    operands) inside the VMEM it plans; nothing is sliced or concatenated
+    outside the kernels."""
+    from dalle_pytorch_tpu.ops import pallas_rotary as pr
+
+    t = jax.ShapeDtypeStruct((TRAIN_BATCH, SEQ, 3 * H * D), dtype, sharding=one_chip)
+    angles = _f32(one_chip, SEQ, 60)
+
+    def both(t, angles):  # the forward's results are returned, so the forward stays
+        outs, vjp = jax.vjp(lambda t: pr.rotary_split(angles, t, H, 3), t)
+        return outs, vjp(outs)[0]
+
+    text = _compile(both, t, angles).as_text()
+    name = "bf16" if dtype == jnp.bfloat16 else "f32"
+    kernels = dict(re.findall(r"%(rotary_\w+?)[.\d]* = \(?(\w+\[[\d,]*\])", text))
+    assert kernels == {"rotary_split": f"{name}[{TRAIN_BATCH},{SEQ},{H * D}]",
+                       "rotary_join": f"{name}[{TRAIN_BATCH},{SEQ},{3 * H * D}]"}
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(r" (slice|concatenate)\(", entry)
+    assert pr._rows(SEQ, H * D, jnp.dtype(dtype).itemsize, 3) == (160 if dtype == jnp.bfloat16 else 80)
 
 
 @pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304)], ids=["gate_up", "down"])
@@ -417,8 +498,8 @@ def test_flagship_step_kernels_keep_their_names_and_phases(topo, compiled_kernel
         ("fwd_flash", "fwd"): 12, ("fwd_flash", "remat"): 12,
         ("dq_flash", "bwd"): 12, ("dkv_flash", "bwd"): 12,
     }
-    o = f"bf16[{TRAIN_BATCH},{H},{SEQ},{D}]"
-    assert set(by_phase["fwd_flash", "fwd"]) == {f"({o},f32[{TRAIN_BATCH},{H},{SEQ},1])"}
+    o = f"bf16[{TRAIN_BATCH},{SEQ},{H * D}]"  # a pair of heads a column block
+    assert set(by_phase["fwd_flash", "fwd"]) == {f"({o},f32[{TRAIN_BATCH},{H // 2},{SEQ},2])"}
     assert set(by_phase["dq_flash", "bwd"]) == {o}
     assert set(by_phase["dkv_flash", "bwd"]) == {f"({o},{o})"}
     layers = {
