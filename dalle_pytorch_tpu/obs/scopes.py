@@ -31,6 +31,8 @@ import re
 import threading
 from typing import Dict, Iterable, Optional, Tuple
 
+from dalle_pytorch_tpu.utils import compile_guard
+
 COMPONENTS = (
     "attn_kernel", "attn_proj", "attn_glue", "attend", "cache_read",
     "cache_write", "ff", "norm_resid", "embed", "head", "loss", "optimizer",
@@ -387,7 +389,9 @@ def _build(p: dict) -> Dict[str, list]:
         fn = jax.jit(fn, **p["jit"])
     lowered += 1
     try:
-        text = fn.lower(*p["specs"]).compile().as_text()
+        # the ledger keeps this second pass apart from the program's own
+        with compile_guard.attributed("scope_table"):
+            text = fn.lower(*p["specs"]).compile().as_text()
     except Exception as exc:  # a reader reports nothing; it never raises
         p["error"] = f"{type(exc).__name__}: {exc}"[:500]
         return {}

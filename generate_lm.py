@@ -125,7 +125,7 @@ def main(argv=None):
     import numpy as np
 
     from dalle_pytorch_tpu.models.lm import generate_tokens_cached, prefill_cached
-    from dalle_pytorch_tpu.utils import compile_guard
+    from dalle_pytorch_tpu.utils.compile_guard import log_compiles
 
     cfg, program = read_config(args)
     prompts = load_prompts(args, cfg["vocab_size"])
@@ -155,9 +155,7 @@ def main(argv=None):
             json.dump(result, f)
     else:
         print(json.dumps(result))
-    print("[compiles] " + json.dumps({
-        "count": compile_guard.compile_count(), "cache_hits": compile_guard.cache_hit_count(),
-        "seconds": compile_guard.compile_seconds()}))
+    log_compiles()
 
 
 if __name__ == "__main__":

@@ -137,7 +137,7 @@ def main(argv=None):
     from dalle_pytorch_tpu.training import TrainState, make_lm_train_step, make_optimizer
     from dalle_pytorch_tpu.training.checkpoint import CheckpointManager
     from dalle_pytorch_tpu.training.metrics import MetricsLogger, ThroughputMeter
-    from dalle_pytorch_tpu.utils import compile_guard
+    from dalle_pytorch_tpu.utils.compile_guard import log_compiles
 
     mdl, options = build_model(args)
     tokens0 = jnp.zeros((1, args.seq_len), jnp.int32)
@@ -192,9 +192,7 @@ def main(argv=None):
         logger.finish()
         if ckpt:
             ckpt.close()
-    print("[compiles] " + json.dumps({
-        "count": compile_guard.compile_count(), "cache_hits": compile_guard.cache_hit_count(),
-        "seconds": compile_guard.compile_seconds()}))
+    log_compiles()
 
 
 if __name__ == "__main__":
