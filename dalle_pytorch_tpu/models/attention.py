@@ -162,6 +162,10 @@ class Attention(nn.Module):
     norm_eps: float = 1e-6
     # sliding window: query t sees key p iff 0 <= t - p < window
     window: Optional[int] = None
+    # the most positions a cached STEP takes, attended against the cache at
+    # each row's own index (a verify step takes two). Grouped K/V heads, a
+    # window and the rotate-half rotary only (`_cached_grouped`)
+    step_positions: int = 1
     use_bias: bool = True  # to_out's (to_qkv never had one)
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32  # what the two matrices are stored in
@@ -267,11 +271,12 @@ class Attention(nn.Module):
             out_specs=P(on_data, None, on_heads, None), check_vma=False,
         )(qkv.reshape(*qkv.shape[:2], 3, h, dh))
 
-    def _grouped_qkv(self, x, rotary_cs):
+    def _grouped_qkv(self, x, rotary_cs, positions=None):
         """q [B, H, n, dh] and k, v [B, kv_heads, n, dh] from one fused
         projection of (H + 2 kv_heads) x dh columns; q and k normed (per head,
         or over their whole width) and turned by the rotate-half tables where
-        there are any, v left as it is."""
+        there are any (their rows 0..n-1, or with `positions` [B, n] each
+        row's own), v left as it is."""
         b, n, _ = x.shape
         h, dh = self.heads, self.dim_head
         hkv = h if self.kv_heads is None else self.kv_heads
@@ -289,9 +294,61 @@ class Attention(nn.Module):
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         if rotary_cs is not None:
             with jax.named_scope("rotary"):
-                cos, sin = (t[:n] for t in rotary_cs)
+                if positions is None:
+                    cos, sin = (t[:n] for t in rotary_cs)
+                else:  # [B, 1, n, dh], over the heads
+                    cos, sin = (jnp.take(t, positions, axis=0, mode="clip")[:, None]
+                                for t in rotary_cs)
                 q, k = apply_rotary_half(cos, sin, q), apply_rotary_half(cos, sin, k)
         return q, k, v
+
+    def _cached_grouped(self, q, k, v, cache, start):
+        """`(out or None, cache)` of a cached chunk whose K/V heads are shared
+        by groups of query heads (query head j reads K/V head j // group),
+        under a window or not; q and k come rotated at the positions they
+        take. The cache's `index` is per row.
+
+        A STEP (not `start`; n <= `step_positions`) is written from each
+        row's own index on, a full layer's along its lanes, a window layer's
+        into its ring, and attended against the cache, each query under its
+        own mask by true position: causal for a full layer (scope
+        `global_attend`), `0 <= t - p < window` over the positions the ring's
+        slots hold for a window layer (`window_attend`): XLA's grouped
+        product over the whole leaf, the group's heads and the step's
+        positions as one operand's rows. A chunk that STARTS the rows'
+        sequences (`start`: a prefill) takes positions 0..n-1 whatever the
+        index was, and attends itself (`out` None: the caller's uncached
+        path, flash kernels included). A longer chunk onto what a cache holds
+        (a later turn's prompt, a prefill in chunks) is not built, and is
+        refused here rather than answered from the chunk alone."""
+        b, h, n, dh = q.shape
+        index = cache["index"]
+        chunk = {"k": k, "v": v}
+        if not start and n > self.step_positions:
+            raise NotImplementedError(
+                f"a cached chunk of {n} positions onto what the cache holds: a step takes "
+                f"at most {self.step_positions}, and a longer chunk has to start the rows' "
+                "sequences (`start=True`: a prefill); a prefill in chunks is not built")
+        write = decode_cache.write_ring if self.window is not None else decode_cache.write_rows
+        written = write(cache, chunk, start)
+        if start:
+            return None, {**cache, **written, "index": jnp.full_like(index, n)}
+        new_cache = {**cache, **written, "index": index + n}
+        ck, cv = written["k"], written["v"]
+        hkv, length = ck.shape[1], ck.shape[2]
+        at = index[:, None] + jnp.arange(n, dtype=index.dtype)  # [B, n]
+        if self.window is not None:
+            held = decode_cache.ring_positions(index + n - 1, length)[:, None]  # [B, 1, ring]
+            gap = at[:, :, None] - held
+            mask = (gap >= 0) & (gap < self.window) & (held >= 0)
+        else:
+            mask = jnp.arange(length, dtype=index.dtype)[None, None] <= at[:, :, None]
+        group = h // hkv
+        mask = jnp.broadcast_to(mask[:, None, None], (b, 1, group, n, length))
+        with jax.named_scope("global_attend" if self.window is None else "window_attend"):
+            out = dense_attention(q.reshape(b, hkv, group * n, dh), ck, cv,
+                                  mask=mask.reshape(b, 1, group * n, length))
+        return out.reshape(b, h, n, dh), new_cache
 
     def _use_flash_decode(
         self, max_len: int, has_pattern: bool, sparse: bool = False
@@ -337,10 +394,17 @@ class Attention(nn.Module):
         deterministic: bool = True,
         mask_array: Optional[jnp.ndarray] = None,
         rotary_cs: Optional[tuple] = None,
+        start: bool = False,
     ):
         """`rotary_cs`: (cos, sin) float32 [>= n, dim_head] tables of the
         rotate-half rotary (`ops/rotary.py:rotary_cos_sin`), applied to q and
         k only; `rotary` is the DALL-E angle table, applied to q, k and v.
+
+        `start`: the cached chunk starts every row's sequence (a prefill into
+        a cache whose rows hold nothing yet). The grouped cached path
+        (`_cached_grouped`) has to be told: it takes a longer chunk in no
+        other way. The DALL-E path attends whatever the cache holds and takes
+        no notice.
 
         `mask_array`: a TRACED [S, S] bool pattern mask (True = attend),
         the per-layer scanned-input analogue of the host-side `static_mask`
@@ -361,7 +425,8 @@ class Attention(nn.Module):
         h, dh = self.heads, self.dim_head
         inner = h * dh
 
-        grouped = self.kv_heads is not None or self.qk_norm or rotary_cs is not None
+        grouped = (self.kv_heads is not None or self.qk_norm or rotary_cs is not None
+                   or (cache is not None and self.window is not None))
         # the uncached flash kernels read q, k, v where the DALL-E projection
         # wrote them, [B, n, heads, dh], and write the columns `to_out`
         # contracts over; every other path takes [B, heads, n, dh] and gives
@@ -371,7 +436,12 @@ class Attention(nn.Module):
         # layer's reshapes and copies hold 1.9 times the bytes of the
         # transposes they replace: PERF.md section 6, PR 34. The kernels
         # take a 128-wide head token-major all the same.)
-        flash = (cache is None and self.attn_impl != "ring" and mask_array is None
+        # a cached chunk of the grouped kind that starts the rows' sequences
+        # is written into the cache and attends itself
+        variant = cache is not None and (
+            (self.kv_heads or h) != h or self.window is not None or rotary_cs is not None)
+        alone = cache is None or (variant and start)
+        flash = (alone and self.attn_impl != "ring" and mask_array is None
                  and self._use_flash(n, key_mask))
         tokens = (flash and not grouped and self.attn_impl != "lib_flash"
                   and self._token_major())
@@ -380,20 +450,19 @@ class Attention(nn.Module):
             if not tokens:  # else the kernels take `qkv` as it is (`_flash_columns`)
                 q, k, v = jnp.split(qkv, 3, axis=-1)
                 q, k, v = (t.reshape(b, n, h, dh).transpose(0, 2, 1, 3) for t in (q, k, v))
+        elif variant:
+            index = cache["index"]
+            assert jnp.ndim(index) == 1, "grouped, windowed or rotated cached decode is per row"
+            q, k, v = self._grouped_qkv(
+                x, rotary_cs,
+                None if start else index[:, None] + jnp.arange(n, dtype=index.dtype))
         else:
             q, k, v = self._grouped_qkv(x, rotary_cs)
 
         new_cache = None
-        if cache is not None and (
-            k.shape[1] != h or self.window is not None or rotary_cs is not None
-        ):
-            raise NotImplementedError(
-                "cached decode keeps one K/V head per query head and one cache "
-                "geometry: fewer K/V heads than query heads, a window and the "
-                "rotate-half rotary are training-only (ROADMAP.md, Queue 2 B); "
-                "q/k norms decode through the cache"
-            )
-        if cache is not None:
+        if variant:
+            out, new_cache = self._cached_grouped(q, k, v, cache, start)
+        elif cache is not None:
             # n-token chunk (prefill or single-token decode) written into a
             # fixed-shape cache at sequence position `index`. A scalar index
             # means the whole batch decodes in lockstep; a [B] index means
@@ -605,7 +674,7 @@ class Attention(nn.Module):
                 new_cache["page_table"] = pt
             if sparse:
                 new_cache["block_bitmap"] = bitmap
-        else:
+        if alone:
             if rotary is not None and not tokens:  # else beside the kernels, in `_flash_columns`
                 rot = jnp.expand_dims(rotary[:n], (0, 1))
                 q, k, v = (apply_rotary(rot, t) for t in (q, k, v))

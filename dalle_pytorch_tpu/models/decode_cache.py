@@ -50,6 +50,29 @@ at all but what the sequence so far has been folded into:
   * `state_at`, `conv_at`: the SNAPSHOT, the two leaves above as they stood
     at the position `set_index` goes back to.
 
+A WINDOW layer (`layer_spec(kind="window")`: attention whose query at t sees
+key p iff 0 <= t - p < window) holds `k`, `v` [B, kv_heads, ring, dh], a RING:
+position p lies at slot `p mod ring`, and a slot is read under the window's
+mask by the TRUE position it holds, which is the newest one written there
+(`ring_positions`). `ring = window + draft`: the window and the `draft`
+further positions a step may write beyond its first (a verify step of two
+writes t and t + 1). `index` is per row. Full K/V layers (`kind="heads"`,
+whose `heads` is the K/V head count) share a cache with it; the stacked layout
+does not hold it. Beside the ring lie `k_at`, `v_at`, its SNAPSHOT: the ring
+as it stood where the session's document ended.
+
+A position that a verify step wrote and then REJECTED leaves every kind of
+K/V layer with no snapshot and no copy. A full layer: by its row's `index`
+alone, because what lies at or past it is masked and overwritten. A ring:
+because the slot the rejected position t + 1 took held position t + 1 - ring
+<= t - window, which had already left the window of every query at t or
+later; the next step writes t + 1 again, and until then the slot is masked
+as lying in the future. A TURN's rewind is another matter: a turn longer than
+the ring overwrites every slot, the positions the document's last query sees
+among them, so going back to the document's end restores the ring from its
+snapshot (`restore`: `ring` positions a layer, 2 MB a session where the full
+layers hold 67).
+
 A K/V layer is rewound by its `index` alone, because what lies past it is
 masked and overwritten. A state cannot be rewound: the tokens of a turn are
 folded in. So a cache that takes further turns over one document keeps each
@@ -57,8 +80,9 @@ row's state at its document's end beside the running one: `snapshot(cache)`
 copies running to kept (after a prefill), `restore(cache)` kept to running
 (before a turn; it also hands the kept leaves out of the tree while a token
 loop runs, and `snapshot(cache, kept)` puts them back), and those two
-functions alone know the pair. Both leave a cache without a recurrent layer
-as it is. Per-layer layout, scalar `index`.
+functions alone know the pair; a window layer's ring is kept by them likewise.
+Both leave a cache with neither kind of layer as it is. Per-layer layout, a
+recurrent layer's `index` scalar.
 
 and a cache holds `depth` layers in one of two LAYOUTS:
 
@@ -96,9 +120,11 @@ LATENT_KEYS = (LATENT, ROPE)
 STATE, CONV = "state", "conv"
 # a recurrent layer's running leaves and, beside each, its snapshot
 SNAPSHOT = {STATE: "state_at", CONV: "conv_at"}
+RING_SNAPSHOT = {K: "k_at", V: "v_at"}  # a window layer's ring, kept likewise
 KV_KEYS = (K, V) + SCALE_KEYS
 RING_KEYS = ("shift_attn", "shift_ff")
 PAGE_TABLE, BLOCK_BITMAP, RING_END = "page_table", "block_bitmap", "ring_end"
+HIDDEN = "hidden"  # a drafting block's layer: the row's last prompt state
 # the stacked layout's layer coordinate, beside the stacked K/V a layer's
 # attention is handed (see `layer_view`)
 LAYER = "layer"
@@ -145,6 +171,8 @@ def layer_spec(
     value_dim: Optional[int] = None,
     conv_taps: Optional[int] = None,
     linear_heads: Optional[int] = None,
+    ring: Optional[int] = None,
+    hidden: bool = False,
 ) -> dict:
     """ONE layer's leaves as `jax.ShapeDtypeStruct`s, from the geometry.
 
@@ -153,7 +181,12 @@ def layer_spec(
     pages, no int8 store, no rings). `kind="recurrent"`: `state`
     [batch, key_dim, linear_heads * value_dim] and `conv` [batch, conv_taps - 1,
     linear_heads * (2 key_dim + value_dim)], both float32, their snapshot
-    beside them, and a scalar `index`. Otherwise:
+    beside them, and a scalar `index`. `kind="window"`: K/V [batch, heads,
+    ring, dim_head] read as a ring (`ring_positions`), their snapshot beside
+    them, `index` per row.
+    `hidden`: beside `attn`, a leaf `hidden` [batch, dim] (a drafting
+    block's layer keeps the trunk's last hidden state of each row's prompt
+    there: what its next position is made from). Otherwise:
 
     K/V are lanes [batch, heads, max_len, dim_head], or with `pages =
     (n_pages, page_size)` a pool [n_pages, heads, page_size, dim_head]
@@ -180,7 +213,12 @@ def layer_spec(
         }
         return {ATTN: {**running, **{SNAPSHOT[n]: s for n, s in running.items()},
                        INDEX: spec((), jnp.int32)}}
-    assert kind == "heads", f"unknown cache kind {kind!r}"
+    if kind == "window":
+        assert pages is None and kv_dtype is None and per_row and not shift_tokens, (
+            "a ring is lanes in the cache dtype, every row at its own position")
+        max_len = ring
+    else:
+        assert kind == "heads", f"unknown cache kind {kind!r}"
     rows, length = (batch, max_len) if pages is None else pages
     kv_dt, scaled = kv_store_dtype(dtype, kv_dtype)
     attn = {
@@ -191,7 +229,11 @@ def layer_spec(
     if scaled:
         attn[K_SCALE] = spec((rows, heads, length), jnp.float32)
         attn[V_SCALE] = spec((rows, heads, length), jnp.float32)
+    if kind == "window":
+        attn.update({at: attn[n] for n, at in RING_SNAPSHOT.items()})
     layer = {ATTN: attn}
+    if hidden:
+        layer[HIDDEN] = spec((batch, dim), dtype)
     if shift_tokens:
         assert image_fmap_size is not None
         for name in RING_KEYS:
@@ -278,30 +320,36 @@ def set_index(cache: dict, pos: jnp.ndarray) -> dict:
     )
 
 
+def _kept_pairs(attn: dict) -> dict:
+    """{running leaf: its snapshot leaf} of a layer that keeps one."""
+    pairs = SNAPSHOT if STATE in attn else RING_SNAPSHOT
+    return pairs if all(at in attn for at in pairs.values()) else {}
+
+
 def snapshot(cache: dict, kept: Optional[dict] = None) -> dict:
-    """The cache with every recurrent layer's snapshot leaves: `kept`, what
-    `restore` took out of it, or (left out) the running state and ring as
-    they stand: a session's document is in."""
+    """The cache with every recurrent or window layer's snapshot leaves:
+    `kept`, what `restore` took out of it, or (left out) the running state
+    and ring as they stand: a session's document is in."""
     if layout_of(cache) == STACKED:  # K/V layers alone
         return cache
 
     def keep(name, layer):
         attn = layer[ATTN]
-        if STATE not in attn:
-            return layer
-        held = {SNAPSHOT[n]: attn[n] for n in SNAPSHOT} if kept is None else kept[name]
-        return {**layer, ATTN: {**attn, **held}}
+        if kept is not None:
+            return {**layer, ATTN: {**attn, **kept.get(name, {})}}
+        return {**layer, ATTN: {**attn, **{at: attn[n] for n, at in _kept_pairs(attn).items()}}}
 
     return {name: keep(name, layer) for name, layer in cache.items()}
 
 
 def restore(cache: dict):
-    """`(cache, kept)`: every recurrent layer's running state and ring as
-    `snapshot` kept them (a turn starts where the document ended), and the
+    """`(cache, kept)`: every recurrent layer's running state and ring, and
+    every window layer's K/V ring, as `snapshot` kept them (a turn starts
+    where the document ended), and the
     snapshot leaves themselves taken OUT of the tree, to be put back by
     `snapshot(cache, kept)`: a token loop carries what it writes, and a leaf
     that rides its carry untouched costs a buffer of its own. The one device
-    copy is the running leaves'; the K/V layers' part of going back is
+    copy is the running leaves'; the full K/V layers' part of going back is
     `set_index`, and costs nothing."""
     if layout_of(cache) == STACKED:  # K/V layers alone
         return cache, {}
@@ -309,10 +357,13 @@ def restore(cache: dict):
     with jax.named_scope("state_restore"):
         for name, layer in cache.items():
             attn = layer[ATTN]
-            if STATE not in attn:
+            pairs = _kept_pairs(attn)
+            if not pairs:
                 out[name] = layer
                 continue
-            kept[name] = {at: attn[at] for at in SNAPSHOT.values()}
+            kept[name] = {at: attn[at] for at in pairs.values()}
+            # a window layer's index is per row
+            rows = (lambda x: x[:, None, None, None]) if attn[INDEX].ndim else (lambda x: x)
             out[name] = {**layer, ATTN: {
                 **{n: leaf for n, leaf in attn.items() if n not in kept[name]},
                 # a select on the index's sign, which is never negative: an
@@ -321,8 +372,8 @@ def restore(cache: dict):
                 # unnamed copies, and that reads the running leaf, so a
                 # donated cache's buffer is the copy's target and is paired
                 # with its own output
-                **{n: jnp.where(attn[INDEX] >= 0, attn[at], attn[n])
-                   for n, at in SNAPSHOT.items()}}}
+                **{n: jnp.where(rows(attn[INDEX] >= 0), attn[at], attn[n])
+                   for n, at in pairs.items()}}}
     return (out, kept) if kept else (cache, kept)
 
 
@@ -585,6 +636,66 @@ def _write_lanes(buf, val, index, layer):
         lambda b, v, i: lax.dynamic_update_slice(b, v, (layer, 0, i) + tail),
         in_axes=(1, 1, 0), out_axes=1,
     )(buf, val, index)
+
+
+def ring_positions(last: jnp.ndarray, ring: int) -> jnp.ndarray:
+    """[B, ring] int32: the position each slot of a ring holds once positions
+    up to `last` [B] are written: the newest p <= last with p mod ring == slot
+    (negative: the slot was never written)."""
+    slots = jnp.arange(ring, dtype=jnp.int32)
+    return last[:, None] - (last[:, None] - slots) % ring
+
+
+def write_ring(attn_cache: dict, vals: dict, start: bool) -> dict:
+    """A window layer's chunk `vals` (k, v [B, H, n, D]) written into its
+    ring, position p at slot p mod ring. A step's few positions go in from
+    each row's own `index` on: a select over the ring's slots, one pass over
+    a leaf that is a window long. A chunk that STARTS every row's sequence
+    (positions 0..n-1, as a prefill's does) lays out what of it the ring can
+    hold, its last `ring` positions, by one static roll. Returns the written
+    leaves by name."""
+    index = attn_cache[INDEX]
+    out = {}
+    with jax.named_scope("cache_write"):
+        for name, val in vals.items():
+            buf = attn_cache[name]
+            ring, n = buf.shape[2], val.shape[2]
+            val = val.astype(buf.dtype)
+            if start:
+                if n >= ring:
+                    buf = jnp.roll(val[:, :, n - ring:], (n - ring) % ring, axis=2)
+                else:
+                    buf = lax.dynamic_update_slice(buf, val, (0, 0, 0, 0))
+            else:
+                slots = jnp.arange(ring, dtype=index.dtype)
+                for j in range(n):
+                    here = slots == ((index + j) % ring)[:, None]  # [B, ring]
+                    buf = jnp.where(here[:, None, :, None], val[:, :, j:j + 1], buf)
+            out[name] = buf
+    return out
+
+
+def write_rows(attn_cache: dict, vals: dict, start: bool) -> dict:
+    """A full K/V layer's chunk `vals` (k, v [B, H, n, D]) written along its
+    lanes: from position 0 on where it STARTS every row's sequence, else from
+    each row's own `index` on, in place, one `dynamic_update_slice` a row.
+    (Batched over the rows, XLA makes a loop of it whose body carries no
+    name, so that its time belongs to nobody in the trace; a `fori_loop` over
+    the rows sends the described v5e's compiler into a RET_CHECK: PERF.md,
+    PR 37.) Returns the written leaves by name."""
+    index = attn_cache[INDEX]
+    out = {}
+    with jax.named_scope("cache_write"):
+        for name, val in vals.items():
+            buf = attn_cache[name]
+            val = val.astype(buf.dtype)
+            if start:
+                buf = lax.dynamic_update_slice(buf, val, (0, 0, 0, 0))
+            else:
+                for r in range(val.shape[0]):
+                    buf = lax.dynamic_update_slice(buf, val[r:r + 1], (r, 0, index[r], 0))
+            out[name] = buf
+    return out
 
 
 def write(attn_cache: dict, vals: dict, seq_cap: int):

@@ -6,21 +6,31 @@ mean next-token cross-entropy over positions 0..N-2, computed by the
 vocab-chunked loss of `ops/losses.py` so that the [B, N, V] logits are
 never whole.
 
-Generation, for a trunk of latent attention layers or of linear (gated
-delta rule) and full ones: `prefill` writes a batch of prompts into a decode
-cache (models/decode_cache.py, per-layer layout: the latent kind of layer, or
-recurrent and K/V layers in one tree), `decode_step` takes one token a row
-against it, and `generate_tokens_cached` runs a whole token loop in one
-dispatch, the cache held in place in the loop's carry. A turn starts where
-the session's document ended: the K/V layers' index set back, the recurrent
-layers' state restored from the snapshot `prefill_cached` took. A trunk with
-fewer K/V heads than query heads, a window or a rotate-half rotary outside
-the latent layer trains only; `serving/paging.py` and the slot cache keep one
+Generation, for a trunk of latent attention layers, of linear (gated delta
+rule) and full ones, or of window and full ones over grouped K/V heads:
+`prefill` writes a batch of prompts into a decode cache
+(models/decode_cache.py, per-layer layout: the latent kind of layer, or
+recurrent and K/V layers in one tree, or window rings beside full K/V),
+`decode_step` takes one token a row against it, and `generate_tokens_cached`
+runs a whole token loop in one dispatch, the cache held in place in the
+loop's carry. A turn starts where the session's document ended: the K/V
+layers' index set back, the recurrent layers' state and the window layers'
+rings restored from the snapshot `prefill_cached` took.
+
+The window-and-full trunk keeps every row at its OWN position and may carry a
+multi-token module (`draft_layers`; `CausalLM.draft_step`): one more block
+after the trunk that, from the trunk's last hidden state at position i and
+the token at i + 1, drafts the token at i + 2. Its token loop
+(`_verify_sampler_builder`) feeds each row its committed token and its draft,
+two positions a step, keeps the draft iff it IS the token the first
+position's logits give, and drops a rejected position from every layer by the
+row's index alone. `serving/paging.py` and the slot cache keep the DALL-E
 cache geometry (ROADMAP.md, Queue 2 B).
 """
 
 from __future__ import annotations
 
+from itertools import cycle, islice
 from typing import Any, Optional
 
 import jax
@@ -33,7 +43,7 @@ from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.models.transformer import Transformer
 from dalle_pytorch_tpu.obs.tracing import host_span
 from dalle_pytorch_tpu.ops.losses import chunked_masked_ce
-from dalle_pytorch_tpu.ops.sampling import gumbel_sample, top_k_filter
+from dalle_pytorch_tpu.ops.sampling import gumbel_sample, gumbel_sample_per_row, top_k_filter
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 # a published config's `layer_types` in `Transformer.attn_types`' words
@@ -52,14 +62,32 @@ def rotary_spec(spec: dict, dim: int) -> dict:
     return out
 
 
+def _routed_ff(cfg: dict, dense: tuple, held: int, experts: str, shared: str,
+               score: str) -> dict:
+    """What both routed families read for their feed-forward: `dense` says
+    which layers are a dense SwiGLU of `intermediate_size` and not routed;
+    `experts` and `shared` name the keys that count the routed experts and
+    the shared ones (of the routed width each); `scoring_func` (`score`
+    where the config has no such key), `routed_scaling_factor`, and
+    `norm_topk_prob`, which the routed layer always does."""
+    if not cfg.get("norm_topk_prob", True):
+        raise ValueError("the routed layer renormalises the chosen scores (norm_topk_prob)")
+    return dict(
+        ff_kinds=tuple("swiglu" if d else "swiglu_experts" for d in dense),
+        ff_dim=cfg["intermediate_size"] if any(dense) else 0,
+        experts_total=cfg.get("published", {}).get(experts, held),
+        moe_score=cfg.get("scoring_func", score),
+        routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        shared_dim=int(cfg.get(shared, 0)) * cfg["moe_intermediate_size"],
+    )
+
+
 def _latent_trunk(cfg: dict, depth: int, held: int) -> dict:
     """The trunk options of the family whose config has `kv_lora_rank`:
     latent attention in every layer, `first_k_dense_replace` dense SwiGLU
     layers before the routed ones, a shared expert of `n_shared_experts`
     times the routed width, sigmoid scores times `routed_scaling_factor`."""
     dense = int(cfg["first_k_dense_replace"])
-    if not cfg.get("norm_topk_prob", True):
-        raise ValueError("the routed layer renormalises the chosen scores (norm_topk_prob)")
     return dict(
         attn_types=("latent",),
         rotary_specs={"latent": {"type": "default", "dim": cfg["qk_rope_head_dim"],
@@ -67,11 +95,36 @@ def _latent_trunk(cfg: dict, depth: int, held: int) -> dict:
         q_lora_rank=cfg["q_lora_rank"], kv_lora_rank=cfg["kv_lora_rank"],
         qk_nope_dim=cfg["qk_nope_head_dim"], qk_rope_dim=cfg["qk_rope_head_dim"],
         v_dim=cfg["v_head_dim"], sandwich_norm=bool(cfg.get("sandwich_norm", False)),
-        ff_kinds=("swiglu",) * min(dense, depth) + ("swiglu_experts",) * max(depth - dense, 0),
-        ff_dim=cfg["intermediate_size"],
-        experts_total=cfg.get("published", {}).get("n_routed_experts", held),
-        moe_score="sigmoid", routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
-        shared_dim=int(cfg.get("n_shared_experts", 0)) * cfg["moe_intermediate_size"],
+        **_routed_ff(cfg, tuple(i < dense for i in range(depth)), held,
+                     "n_routed_experts", "n_shared_experts", "sigmoid"),
+    )
+
+
+def _window_trunk(cfg: dict, depth: int, held: int) -> dict:
+    """The trunk options of the family whose config has `layer_types` of
+    `sliding_attention` and `full_attention`: `num_key_value_heads` K/V heads
+    shared by groups of query heads, a per-head q/k norm, a window of
+    `sliding_window`; `mlp_layer_types` says which layers are `dense` and
+    which `sparse` (routed: `num_experts`, `num_shared_experts`,
+    `scoring_func`, `routed_scaling_factor`). `rope_parameters` holds an
+    entry a kind of layer, or ONE entry for the model: the rotate-half
+    rotary then turns the window layers alone and the full ones take none
+    (the family's convention for a hybrid of local and global layers). A
+    config with the keys `n_group` and `topk_group` has the router they
+    belong to: the experts are chosen by score plus a learned
+    score-correction bias (one group: a group-limited choice is not built)."""
+    rope, dim_head = cfg["rope_parameters"], cfg["head_dim"]
+    biased = "n_group" in cfg
+    if biased and (cfg["n_group"], cfg.get("topk_group", 1)) != (1, 1):
+        raise ValueError("a group-limited choice of experts is not built (n_group, topk_group)")
+    return dict(
+        kv_heads=cfg["num_key_value_heads"], qk_norm=True, window=int(cfg["sliding_window"]),
+        attn_types=tuple(LAYER_KINDS[k] for k in cfg["layer_types"][:depth]),
+        rotary_specs=({"window": rotary_spec(rope, dim_head)} if "rope_type" in rope else
+                      {LAYER_KINDS[k]: rotary_spec(spec, dim_head) for k, spec in rope.items()}),
+        moe_score_bias=biased,
+        **_routed_ff(cfg, tuple(t == "dense" for t in cfg["mlp_layer_types"][:depth]), held,
+                     "num_experts", "num_shared_experts", "softmax"),
     )
 
 
@@ -110,6 +163,9 @@ class CausalLM(nn.Module):
     # every further `Transformer` option (block variants, attn_types,
     # experts, attn_impl ...), as the configuration gives them
     trunk: Any = None
+    # blocks of the multi-token module after the trunk (0: none; 1: one full-
+    # attention routed block that drafts one token a row a step)
+    draft_layers: int = 0
     reversible: bool = False  # per-layer remat, as `DALLE.reversible`
     reversible_impl: str = "remat"
     remat_policy: Optional[str] = None
@@ -129,8 +185,10 @@ class CausalLM(nn.Module):
         `full_attention`, the `linear_*` keys, `intermediate_size`, a null
         `rope_theta` (gated delta-rule layers among full ones, no experts).
         Else with `layer_types`: `hidden_size`, `head_dim`, `rope_parameters`,
-        `num_experts` ... (grouped K/V heads, window and full layers, every
-        layer routed). With `kv_lora_rank`: `q_lora_rank`, `qk_nope_head_dim`,
+        `mlp_layer_types`, `num_experts`, `num_shared_experts`,
+        `scoring_func`, `num_nextn_predict_layers` ... (grouped K/V heads,
+        window and full layers, dense or routed by layer, a shared expert, a
+        multi-token module: `_window_trunk`). With `kv_lora_rank`: `q_lora_rank`, `qk_nope_head_dim`,
         `qk_rope_head_dim`, `v_head_dim`, `first_k_dense_replace`,
         `n_routed_experts`, `n_shared_experts`, `routed_scaling_factor`,
         `sandwich_norm` ... (latent attention, leading dense layers, a shared
@@ -144,7 +202,8 @@ class CausalLM(nn.Module):
         `reversible_impl`. `overrides` replace keys of `program`."""
         prog = dict(cfg.get("program", {}), **overrides)
         depth = int(cfg["num_hidden_layers"])
-        if cfg["hidden_act"] != "silu" or cfg["attention_bias"] or cfg["tie_word_embeddings"]:
+        if (cfg["hidden_act"] != "silu" or cfg.get("attention_bias", False)
+                or cfg["tie_word_embeddings"]):
             raise ValueError("the trunk builds SiLU gates, no biases and an untied head")
         latent, hybrid = "kv_lora_rank" in cfg, "linear_key_head_dim" in cfg
         param_dtype = DTYPES[prog.get("weights_dtype", "float32")]
@@ -167,24 +226,20 @@ class CausalLM(nn.Module):
             trunk.update(_latent_trunk(cfg, depth, held), param_dtype=param_dtype)
             dim_head = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
         else:
-            if any(t != "sparse" for t in cfg["mlp_layer_types"][:depth]):
-                raise ValueError("every layer's feed-forward has to be routed (`sparse`)")
-            if param_dtype != jnp.float32:
-                raise ValueError("weights_dtype is not built for the routed window-and-full trunk")
-            trunk.update(
-                ff_kind="swiglu_experts", kv_heads=cfg["num_key_value_heads"], qk_norm=True,
-                window=int(cfg["sliding_window"]),
-                attn_types=tuple(LAYER_KINDS[k] for k in cfg["layer_types"][:depth]),
-                rotary_specs={LAYER_KINDS[k]: rotary_spec(spec, cfg["head_dim"])
-                              for k, spec in cfg["rope_parameters"].items()},
-                experts_total=cfg.get("published", {}).get("num_experts", held),
-            )
+            drafts = int(cfg.get("num_nextn_predict_layers", 0))
+            if drafts > 1 or (drafts and cfg.get("mtp_layer_types") != ["full_attention"]):
+                raise ValueError("the multi-token module is one full-attention block")
+            trunk.update(_window_trunk(cfg, depth, held), param_dtype=param_dtype,
+                         draft_positions=drafts)
             dim_head = cfg["head_dim"]
+        if (latent or hybrid) and cfg.get("num_nextn_predict_layers", 0):
+            raise ValueError("a multi-token module is built after the window-and-full trunk")
         return cls(
             num_tokens=cfg["vocab_size"], dim=cfg["hidden_size"], depth=depth,
             seq_len=seq_len, heads=cfg["num_attention_heads"], dim_head=dim_head,
             # frozen: the model is hashable, which keys its compiled samplers
-            trunk=freeze(trunk), reversible=bool(prog.get("reversible", False)),
+            trunk=freeze(trunk), draft_layers=trunk.get("draft_positions", 0),
+            reversible=bool(prog.get("reversible", False)),
             reversible_impl=prog.get("reversible_impl", "remat"),
             dtype=DTYPES[prog.get("dtype", "bfloat16")], param_dtype=param_dtype,
         )
@@ -208,11 +263,62 @@ class CausalLM(nn.Module):
         )
         self.logits_dense = nn.Dense(self.num_tokens, use_bias=False, dtype=self.dtype,
                                      param_dtype=self.param_dtype)
+        if not self.draft_layers:
+            return
+        assert self.draft_layers == 1 and norm == "rms", "one block, under RMS norms"
+        rms = lambda: nn.RMSNorm(epsilon=trunk.get("norm_eps", 1e-6), dtype=self.dtype)
+        self.mtp_norm_e, self.mtp_norm_h, self.mtp_norm_f = rms(), rms(), rms()
+        self.mtp_proj = nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                                 param_dtype=self.param_dtype)
+        # the trunk's block, with full attention and a routed feed-forward
+        self.mtp_block = Transformer(
+            dim=self.dim, depth=1, seq_len=self.seq_len, heads=self.heads,
+            dim_head=self.dim_head, rotary_emb=False, dtype=self.dtype,
+            **{**trunk, "attn_types": ("full",), "ff_kinds": ("swiglu_experts",)})
 
     def hidden(self, tokens: jnp.ndarray) -> jnp.ndarray:
         """[B, N, dim]: the trunk's output under the final norm."""
         x = self.token_emb(tokens).astype(self.dtype)
         return self.logits_norm(self.transformer(x))
+
+    def _head(self, h: jnp.ndarray) -> jnp.ndarray:
+        """Float32 logits of normed states [..., dim]."""
+        kernel = self.logits_dense.variables["params"]["kernel"]
+        with jax.named_scope("logits_head"):  # the `head` component (obs/scopes.py)
+            return jnp.dot(h, kernel.astype(h.dtype), preferred_element_type=jnp.float32)
+
+    def draft_step(self, next_tokens: jnp.ndarray, hidden: jnp.ndarray,
+                   layer_cache: Optional[dict] = None, start: bool = False):
+        """The multi-token module over n positions a row: `(float32 logits
+        [B, n, V], layer_cache)`. Position i takes the trunk's output there
+        BEFORE the final norm (`hidden` [B, n, dim]) and the token at i + 1
+        (`next_tokens` [B, n]):
+
+            u_i = W_eh [rms_e(Emb(t_{i+1})); rms_h(h_i)]
+            g_i = Block(u_0 .. u_i)       full attention, a routed feed-forward
+            logits for position i + 2 = Head(rms_f(g_i))
+
+        with the trunk's own embedding and head. `layer_cache`: the module's
+        K/V layer of a decode cache, written at its own index, or with
+        `start` from position 0 on (the chunk starts the rows' sequences: a
+        prefill); left out, the n positions are a whole sequence. Everything
+        runs under the scope `mtp`."""
+        with jax.named_scope("mtp"):
+            e = self.mtp_norm_e(self.token_emb(next_tokens).astype(self.dtype))
+            u = self.mtp_proj(jnp.concatenate([e, self.mtp_norm_h(hidden)], axis=-1))
+            if layer_cache is None:
+                g = self.mtp_block(u)
+            else:
+                g, new = self.mtp_block(u, start=start, cache={decode_cache.layer_key(0): {
+                    decode_cache.ATTN: layer_cache[decode_cache.ATTN]}})
+                layer_cache = {**layer_cache, **new[decode_cache.layer_key(0)]}
+            return self._head(self.mtp_norm_f(g)), layer_cache
+
+    def draft_logits(self, tokens: jnp.ndarray) -> jnp.ndarray:
+        """[B, N - 1, V]: the module's logits over a whole sequence, uncached:
+        entry i drafts position i + 2."""
+        x = self.transformer(self.token_emb(tokens).astype(self.dtype))
+        return self.draft_step(tokens[:, 1:], x[:, :-1])[0]
 
     def __call__(self, tokens: jnp.ndarray, return_loss: bool = False):
         """Logits [B, N, V] (float32), or with `return_loss` the mean
@@ -220,6 +326,8 @@ class CausalLM(nn.Module):
         h = self.hidden(tokens)
         if not return_loss or self.is_initializing():
             logits = self.logits_dense(h).astype(jnp.float32)
+            if self.draft_layers and self.is_initializing():
+                self.draft_logits(tokens)  # the module's parameters
             if not return_loss:
                 return logits
             logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
@@ -242,8 +350,24 @@ class CausalLM(nn.Module):
 
     def init_cache(self, batch: int, max_len: Optional[int] = None) -> dict:
         """A zeroed decode cache of `batch` rows and `max_len` positions
-        (`seq_len` where left out), in the model's dtype. Usable unbound."""
-        return Transformer.init_cache(self._trunk(), batch, max_len or self.seq_len, self.dtype)
+        (`seq_len` where left out), in the model's dtype. Usable unbound. A
+        multi-token module keeps its own full K/V layer after the trunk's,
+        (`decode_cache.layer_key(depth)`), with the leaf `hidden` beside it."""
+        max_len = max_len or self.seq_len
+        trunk = self._trunk()
+        cache = Transformer.init_cache(trunk, batch, max_len, self.dtype)
+        if self.draft_layers:
+            cache[decode_cache.layer_key(self.depth)] = decode_cache.make(
+                decode_cache.PER_LAYER, 1, batch=batch, max_len=max_len, per_row=True,
+                hidden=True, heads=trunk.kv_heads or self.heads, dim_head=self.dim_head,
+                dim=self.dim, dtype=self.dtype)[decode_cache.layer_key(0)]
+        return cache
+
+    @property
+    def per_row(self) -> bool:
+        """Whether the cache keeps every row at its own position (the trunk
+        of grouped K/V heads, window layers or a rotate-half rotary)."""
+        return self._trunk()._per_row_cache()
 
     def _trunk(self) -> Transformer:
         """The trunk's configuration, unbound (for `init_cache`'s arithmetic)."""
@@ -257,7 +381,27 @@ class CausalLM(nn.Module):
         holds is not built). No logits: the prompt's last token goes through
         `decode_step`, which gives them."""
         x = self.token_emb(tokens).astype(self.dtype)
-        return self.transformer(x, cache=cache)[1]
+        x, new = self.transformer(x, cache=cache, start=True)
+        if not self.draft_layers:
+            return new
+        # the module over positions 0..n-2 (position i needs the token at
+        # i + 1); the last position's state waits for the turn's first token
+        at = decode_cache.layer_key(self.depth)
+        layer = cache[at]  # of a one-token prompt the module holds no position yet
+        if tokens.shape[1] > 1:
+            _, layer = self.draft_step(tokens[:, 1:], x[:, :-1], layer, start=True)
+        return {**new, at: {**layer, decode_cache.HIDDEN: x[:, -1]}}
+
+    def verify_step(self, tokens: jnp.ndarray, cache: dict):
+        """`(float32 logits [B, n, V], the trunk's output before the final
+        norm [B, n, dim], cache)` after n tokens a row (a committed token and
+        n - 1 drafts) at each row's own index, which comes back advanced by
+        n: the caller sets it to what stayed. The module's layer is passed
+        through untouched."""
+        x = self.token_emb(tokens).astype(self.dtype)
+        x, new = self.transformer(x, cache=cache)
+        logits = self._head(self.logits_norm(x))
+        return lax.optimization_barrier(logits), x, {**cache, **new}
 
     def decode_step(self, token: jnp.ndarray, cache: dict):
         """(float32 logits [B, V], cache) after one `token` [B] a row, at
@@ -276,9 +420,9 @@ class CausalLM(nn.Module):
     def generate(self, *args, **kwargs):
         raise NotImplementedError(
             "CausalLM has no uncached sampler: `generate_tokens_cached` decodes a trunk "
-            "of latent layers, or of linear and full ones, through its cache; cached "
-            "decode of K/V heads shared by query heads, of a window or of a rotate-half "
-            "rotary, and serving, are not built (ROADMAP.md, Queue 2 B)"
+            "of latent layers, of linear and full ones, or of window and full ones over "
+            "grouped K/V heads (with its multi-token module drafting) through its cache; "
+            "serving it (slots, pages, the engine) is not built (ROADMAP.md, Queue 2 B)"
         )
 
 
@@ -302,12 +446,19 @@ def prefill_cached(model: CausalLM, variables, tokens: jnp.ndarray, cache: dict,
     prompts (as `generate_tokens_cached` gives them). The prompts go through
     `CausalLM.prefill` into a fresh cache of their own length, whose rows
     are then written into the sessions' (one dispatch), a recurrent layer's
-    state both as the running one and as the snapshot a later turn restores
-    (`decode_cache.snapshot`). Every layer's index is left where it was: the
+    state and a window layer's ring both as the running one and as the
+    snapshot a later turn restores (`decode_cache.snapshot`). Every layer's index is left where it was: the
     caller sets it (`decode_cache.set_index`)."""
     jitted = _jitted(_prefill_builder, model, ())
     with host_span("lm.prefill", program=jitted.name, rows=int(tokens.shape[0])):
         return jitted(variables, tokens, cache, jnp.asarray(row, jnp.int32))
+
+
+def _rings(model, cache: dict) -> list:
+    """The K leaves of a cache's window layers (rings)."""
+    kinds = islice(cycle(dict(model.trunk or {}).get("attn_types") or ("full",)), model.depth)
+    return [cache[decode_cache.layer_key(i)][decode_cache.ATTN][decode_cache.K]
+            for i, kind in enumerate(kinds) if kind == "window"]
 
 
 def _prefill_builder(model, key):
@@ -338,8 +489,8 @@ def generate_tokens_cached(model: CausalLM, variables, key: jax.Array, cache: di
     `filter_thres` of 1.0 keeps one logit, which is greedy). The turn starts
     at `start` (the cache's own index where left out): the K/V layers' index
     is set there, which copies nothing, and a recurrent layer's state and
-    ring are restored from its snapshot, one device copy a turn
-    (`decode_cache.restore`). The cache rides the loop's carry and is written
+    ring, like a window layer's ring of K/V, are restored from their
+    snapshot, one device copy a turn (`decode_cache.restore`). The cache rides the loop's carry and is written
     in place, one position a step from its index on.
 
     Returns `(tokens [B, steps] int32, logits [steps, logit_rows, V] float32
@@ -348,12 +499,42 @@ def generate_tokens_cached(model: CausalLM, variables, key: jax.Array, cache: di
     [L] (held experts with at least one row), each summed over the steps;
     of a cache with recurrent layers also, as host numbers, `state_bytes`
     (running and kept), `kv_bytes` and `state_restored_bytes`, the turn's copy.
+
+    A model whose cache keeps every row at its own position (`model.per_row`:
+    the window-and-full trunk) runs `steps` VERIFY steps instead
+    (`_verify_sampler_builder`): a step emits one token a row, or two where
+    the multi-token module's draft was kept, and `forced` is what a row must
+    EMIT first (`forced[:, 0]` is the token at `start`, fed by the first
+    step; a draft is kept iff it equals the forced token). It returns
+    `tokens` [B, cap] (`cap` = steps x the positions a step takes; a row's
+    first `counts["emitted"]` entries are the tokens after `forced[:, 0]`),
+    and for `logits` a dict over the first `logit_rows` rows: `logits`
+    [steps, rows, positions, V], `draft` [steps, rows, V] (the module's
+    logits that gave the NEXT step's draft, for position `at + 2 +
+    accepted`), `at` [steps, rows] (the position of the step's committed
+    token), `drafted` [steps, rows] (the draft the step fed beside it, at
+    `at + 1`) and `accepted` [steps, rows]. `counts` gains `emitted`,
+    `accepted` [B] and, as host numbers, `verify_steps`, `kv_bytes`,
+    `ring_slots` and `ring_bytes` (the window layers' geometry: K and V, the
+    running rings and their snapshots).
     """
     assert forced.ndim == 2 and 1 <= forced.shape[1] <= steps, forced.shape
     static_key = (int(steps), float(filter_thres), float(temperature), int(logit_rows))
-    jitted = _jitted(_sampler_builder, model, static_key)
     if start is None:  # a copy: the cache's own leaf is donated with it
         start = next(iter(cache.values()))[decode_cache.ATTN][decode_cache.INDEX] + 0
+    if model.per_row:
+        jitted = _jitted(_verify_sampler_builder, model, static_key + (None,))
+        rings = _rings(model, cache)
+        geometry = {"verify_steps": int(steps), "kv_bytes": decode_cache.kv_bytes(cache),
+                    "ring_slots": rings[0].shape[2] if rings else 0,
+                    # k and v, each running and kept
+                    "ring_bytes": 4 * sum(r.size * r.dtype.itemsize for r in rings)}
+        with host_span("lm.sample.dispatch", program=jitted.name):
+            tokens, logits, counts, cache = jitted(
+                variables, key, cache, forced,
+                jnp.broadcast_to(jnp.asarray(start, jnp.int32), forced.shape[:1]))
+        return tokens, logits, {**counts, **geometry}, cache
+    jitted = _jitted(_sampler_builder, model, static_key)
     held = decode_cache.state_bytes(cache)
     with host_span("lm.sample.dispatch", program=jitted.name):
         tokens, logits, counts, cache = jitted(
@@ -366,13 +547,16 @@ def generate_tokens_cached(model: CausalLM, variables, key: jax.Array, cache: di
 
 def _moe_counts(stats: dict) -> dict:
     """The routed layers' counters of one step, stacked over those layers
-    (in layer order), with `moe_touched` beside them."""
-    layers = stats.get("transformer", {})
-    routed = sorted((n for n in layers if "moe_load" in layers[n]),
-                    key=lambda n: int(n.rsplit("_", 1)[1]))
+    (in layer order, a multi-token module's block last), with `moe_touched`
+    beside them."""
+    routed = []
+    for part in ("transformer", "mtp_block"):  # the trunk's, then the module's
+        layers = stats.get(part, {})
+        routed += [layers[n] for n in sorted((n for n in layers if "moe_load" in layers[n]),
+                                             key=lambda n: int(n.rsplit("_", 1)[1]))]
     if not routed:
         return {}
-    out = {k: jnp.stack([layers[n][k] for n in routed]).astype(jnp.int32) for k in MOE_COUNTS}
+    out = {k: jnp.stack([layer[k] for layer in routed]).astype(jnp.int32) for k in MOE_COUNTS}
     out["moe_touched"] = jnp.sum(out["moe_load"] > 0, axis=-1, dtype=jnp.int32)
     return out
 
@@ -416,3 +600,127 @@ def _sampler_builder(model, key):
 
 
 _sampler_builder._donate_argnums = (2,)
+
+
+def _verify_sampler_builder(model, key):
+    """The token loop of a model whose cache keeps every row at its own
+    position, `steps` verify steps in one `lax.scan`.
+
+    A row stands at `pos` with the committed token `c` (the sequence's token
+    at `pos`, not yet fed) and, where the model has a multi-token module, the
+    draft `d` of the token at `pos + 1`. A step feeds `[c, d]` at `pos, pos +
+    1` through the trunk; draws t1, the token at `pos + 1`, from the first
+    position's logits; keeps the draft iff `d == t1`, and then also draws t2
+    for `pos + 2` from the second's. Every layer's index is set to `pos + 1 +
+    kept`: that alone drops a rejected position (models/decode_cache.py); the
+    turn itself began from the rings' snapshot (`decode_cache.restore`). The
+    module then runs over the two positions (`h_pos` with t1, `h_{pos+1}` with
+    t2; its index, which stands at `pos`, moves by `1 + kept` too) and the
+    logits of the last one that stayed give the next draft, their argmax.
+    Before the first step it takes
+    the one position it lags the trunk by: the prompt's last state, kept by
+    the prefill (`hidden`), with the turn's first token.
+
+    A token is drawn with a key folded from the ROW and the POSITION it
+    takes, never from the step: the sequence emitted is the same whatever the
+    drafts were, and equal to the loop's without a module (one position a
+    step). The j-th token a row emits after `forced[:, 0]` is `forced[:, j +
+    1]` while there is one. `key`: `(steps, filter_thres, temperature,
+    logit_rows, drafter)`; `drafter(draft [B], position [B]) -> [B]` replaces
+    the module's draft (tests: an oracle, a coin)."""
+    steps, filter_thres, temperature, logit_rows, drafter = key
+    drafting = bool(model.draft_layers)
+    n = 2 if drafting else 1
+    at_module = decode_cache.layer_key(model.depth)
+
+    def lm_sample(variables, rng, cache, forced, start):
+        batch, n_forced = forced.shape
+        rows = jnp.arange(batch)
+        cap = n * steps
+        module = cache.get(at_module) if drafting else None
+        cache = {name: layer for name, layer in cache.items() if layer is not module}
+        run = lambda method, *args: model.apply(variables, *args, method=method,
+                                                mutable=["stats"])
+
+        def draw(logits, at, emitted):
+            """[B]: the token at position `at`, the row's `emitted`-th."""
+            with jax.named_scope("sample"):
+                keys = jax.vmap(lambda r, p: jax.random.fold_in(jax.random.fold_in(rng, r), p))(
+                    rows, at)
+                new = gumbel_sample_per_row(
+                    keys, top_k_filter(logits, thres=filter_thres),
+                    jnp.full((batch,), temperature, jnp.float32)).astype(jnp.int32)
+                must = forced[rows, jnp.minimum(emitted + 1, n_forced - 1)]
+                return jnp.where(emitted + 1 < n_forced, must, new)
+
+        def propose(logits, at):
+            with jax.named_scope("mtp"), jax.named_scope("sample"):
+                d = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return d if drafter is None else drafter(d, at)
+
+        def step(carry, _):
+            cache, module, pos, c, d, count, out, counts = carry
+            fed = jnp.stack([c, d], axis=1) if drafting else c[:, None]
+            (logits, hidden, cache), aux = run(CausalLM.verify_step, fed, cache)
+            # each part's counts from the call that ran it
+            stats = {"transformer": aux.get("stats", {}).get("transformer", {})}
+            t1 = draw(logits[:, 0], pos + 1, count)
+            if drafting:
+                with jax.named_scope("sample"), jax.named_scope("verify"):
+                    kept = d == t1
+                t2 = draw(logits[:, 1], pos + 2, count + 1)
+            else:
+                kept, t2 = jnp.zeros((batch,), bool), t1
+            with jax.named_scope("sample"), jax.named_scope("verify"):
+                out = out.at[rows, count].set(t1)
+                out = out.at[rows, jnp.where(kept, count + 1, cap)].set(t2, mode="drop")
+                moved = 1 + kept.astype(jnp.int32)
+                cache = decode_cache.set_index(cache, pos + moved)
+            draft_logits = jnp.zeros((batch, 0), jnp.float32)
+            ys = (logits[:logit_rows], pos[:logit_rows], d[:logit_rows], kept[:logit_rows])
+            if drafting:
+                (both, module), aux = run(CausalLM.draft_step, jnp.stack([t1, t2], axis=1),
+                                          hidden, module)
+                stats["mtp_block"] = aux.get("stats", {}).get("mtp_block", {})
+                with jax.named_scope("mtp"), jax.named_scope("verify"):
+                    module = decode_cache.set_index(
+                        {at_module: module}, pos + moved)[at_module]
+                    draft_logits = both[rows, kept.astype(jnp.int32)]
+                d = propose(draft_logits, pos + moved + 1)
+            counts = jax.tree.map(
+                jnp.add, counts,
+                {**_moe_counts(stats), "accepted": kept.astype(jnp.int32)})
+            carry = (cache, module, pos + moved, jnp.where(kept, t2, t1), d, count + moved,
+                     out, counts)
+            return carry, ys + (draft_logits[:logit_rows],)
+
+        cache, rings = decode_cache.restore(cache)
+        cache = decode_cache.set_index(cache, start)
+        c, d = forced[:, 0], jnp.zeros((batch,), jnp.int32)
+        if drafting:
+            # the position the module lags the trunk by: the prompt's last state
+            module = decode_cache.set_index({at_module: module}, start - 1)[at_module]
+            (first, module), _ = run(CausalLM.draft_step, c[:, None],
+                                     module[decode_cache.HIDDEN][:, None], module)
+            d = propose(first[:, 0], start + 1)
+        trunk = dict(model.trunk or {})
+        routed = sum(k == "swiglu_experts" for k in
+                     trunk.get("ff_kinds") or (trunk.get("ff_kind"),) * model.depth) + drafting
+        zeros = lambda *shape: jnp.zeros(shape, jnp.int32)
+        counts = {"accepted": zeros(batch)}
+        if routed:
+            counts.update(moe_load=zeros(routed, trunk["experts_held"][1]),
+                          **{k: zeros(routed) for k in ("moe_rows", "moe_dropped", "moe_touched")})
+        carry = (cache, module, start, c, d, zeros(batch), zeros(batch, cap), counts)
+        (cache, module, _, _, _, count, out, counts), (logits, at, fed, kept, drafts) = lax.scan(
+            step, carry, None, length=steps)
+        cache = decode_cache.snapshot(cache, rings)
+        if drafting:
+            cache = {**cache, at_module: module}
+        return (out, {"logits": logits, "draft": drafts, "at": at, "drafted": fed,
+                      "accepted": kept}, {**counts, "emitted": count}, cache)
+
+    return lm_sample
+
+
+_verify_sampler_builder._donate_argnums = (2,)

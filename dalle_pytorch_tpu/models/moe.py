@@ -57,9 +57,12 @@ def _chunk(n_rows: int) -> int:
     return min(CHUNK_ROWS, n_rows)
 
 
-def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows: int):
+def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows: int,
+          bias=None):
     """From router scores [T, E] (a softmax's probabilities, or sigmoids)
-    to the buffer's layout.
+    to the buffer's layout. `bias` [E]: the experts are CHOSEN by `probs +
+    bias` (a score-correction bias); the weights are the chosen experts' own
+    scores all the same.
 
     Returns a dict: `weights` [T, k] (the chosen experts' scores,
     renormalised), `experts` [T, k], `assign` [R] (the assignment, t * k +
@@ -71,7 +74,11 @@ def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows
     """
     first, count = held
     buffer_rows = min(buffer_rows, probs.shape[0] * per_token)
-    top, experts = lax.top_k(probs, per_token)
+    if bias is None:
+        top, experts = lax.top_k(probs, per_token)
+    else:
+        experts = lax.top_k(probs + bias, per_token)[1]
+        top = jnp.take_along_axis(probs, experts, axis=-1)
     weights = top / jnp.sum(top, axis=-1, keepdims=True)
     local = experts - first
     key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
@@ -353,6 +360,8 @@ class RoutedExperts(nn.Module):
     whole on every chip that shares the layer. `score` says how the router's
     outputs become scores: `softmax` over all of them, or a `sigmoid` of
     each; the chosen ones are renormalised and multiplied by `routed_scale`.
+    `score_bias`: a float32 vector `router_bias` [experts_total] is added to
+    the scores for the CHOICE alone (the weights stay the scores').
     The matrices are stored in `param_dtype`, the router in float32. Sows
     `moe_load` [count], `moe_rows`, `moe_dropped` and `moe_moved` into the
     `stats` collection where the caller makes it mutable.
@@ -367,6 +376,7 @@ class RoutedExperts(nn.Module):
     score: str = "softmax"  # "softmax" | "sigmoid"
     routed_scale: float = 1.0
     shared_dim: int = 0  # 0: no shared expert
+    score_bias: bool = False
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -378,6 +388,8 @@ class RoutedExperts(nn.Module):
         self.w_gate = matrix("w_gate", count, self.dim, self.expert_dim)
         self.w_up = matrix("w_up", count, self.dim, self.expert_dim)
         self.w_out = matrix("w_out", count, self.expert_dim, self.dim)
+        self.router_bias = (self.param("router_bias", nn.initializers.zeros,
+                                       (self.experts_total,)) if self.score_bias else None)
         if self.shared_dim:
             self.shared_gate = matrix("shared_gate", self.dim, self.shared_dim)
             self.shared_up = matrix("shared_up", self.dim, self.shared_dim)
@@ -401,6 +413,8 @@ class RoutedExperts(nn.Module):
     def choices(self, x: jnp.ndarray) -> jnp.ndarray:
         """[B, N, k] the experts the router chooses, largest first."""
         probs = self.router_probs(x.reshape(-1, x.shape[-1]))
+        if self.score_bias:
+            probs = probs + self.router_bias
         return lax.top_k(probs, self.experts_per_token)[1].reshape(*x.shape[:-1], -1)
 
     def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
@@ -408,8 +422,9 @@ class RoutedExperts(nn.Module):
         with jax.named_scope("moe_router"):
             probs = self.router_probs(h)
         with jax.named_scope("moe_dispatch"):
+            biased = {} if self.router_bias is None else {"bias": self.router_bias}
             r = route(probs, self.experts_per_token, tuple(self.experts_held),
-                      self.buffer_rows)
+                      self.buffer_rows, **biased)
             weights = r["weights"]
             if self.routed_scale != 1.0:
                 weights = weights * self.routed_scale

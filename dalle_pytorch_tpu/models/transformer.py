@@ -428,6 +428,9 @@ class Transformer(nn.Module):
     prenorm: bool = True
     # "window" among attn_types: query t sees key p iff 0 <= t - p < window
     window: Optional[int] = None
+    # positions a cached step may take beyond its first (a verify step of a
+    # committed token and one draft: 1); a window layer's ring is that longer
+    draft_positions: int = 0
     # attn type -> `ops/rotary.py:rotary_cos_sin` spec: one rotate-half table
     # per KIND of layer, on q and k, in place of the DALL-E table
     rotary_specs: Optional[Any] = None
@@ -441,6 +444,7 @@ class Transformer(nn.Module):
     moe_score: str = "softmax"  # or "sigmoid": how router outputs become scores
     routed_scale: float = 1.0  # on the renormalised weights of the chosen
     shared_dim: int = 0  # width of the shared expert beside the routed ones (0: none)
+    moe_score_bias: bool = False  # a score-correction bias in the router's choice
     # "latent" among attn_types (models/attention.py:LatentAttention)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -587,6 +591,7 @@ class Transformer(nn.Module):
                     score=self.moe_score,
                     routed_scale=self.routed_scale,
                     shared_dim=self.shared_dim,
+                    score_bias=self.moe_score_bias,
                     dtype=self.dtype,
                     param_dtype=self.param_dtype,
                     name=f"ff_{ff_id}",
@@ -656,6 +661,7 @@ class Transformer(nn.Module):
             kv_heads=self.kv_heads, qk_norm=self.qk_norm, norm_eps=self.norm_eps,
             use_bias=self.use_bias, param_dtype=self.param_dtype,
             window=self.window if attn_type == "window" else None,
+            step_positions=self.draft_positions + 1,
         )
 
     def _derived_text_len(self) -> int:
@@ -773,7 +779,7 @@ class Transformer(nn.Module):
             ring_end=ring_end,
         )
 
-    def _half_attn(self, i, x, key_mask, layer_cache, deterministic=True):
+    def _half_attn(self, i, x, key_mask, layer_cache, deterministic=True, start=False):
         """Attention half-block f (norm → shift → attn → [sandwich] → scale),
         the composition the reference wraps as `f` in `ReversibleBlock`
         (`reversible.py:57-63`, built at `transformer.py:291-294`).
@@ -791,6 +797,8 @@ class Transformer(nn.Module):
             {"rotary_cs": self.rotary_cs[self.type_per_layer[i]]}
             if self.type_per_layer[i] in self.rotary_cs else {}
         )
+        if start and self.type_per_layer[i] not in ("latent", "linear"):
+            variant["start"] = True  # those two take any longer chunk for a start
         h, attn_cache = self.attn_layers[i](
             h,
             key_mask=key_mask,
@@ -906,13 +914,14 @@ class Transformer(nn.Module):
         key_mask,
         layer_cache,
         deterministic: bool,
+        start: bool = False,
     ):
         """One (attn, ff) residual pair; returns (x, updated layer cache)."""
         cached = layer_cache is not None
         pos = layer_cache["attn"]["index"] if cached else None
 
         h, attn_cache, ring_attn = self._half_attn(
-            i, x, key_mask, layer_cache, deterministic
+            i, x, key_mask, layer_cache, deterministic, start
         )
         x = x + h
         h, ring_ff = self._half_ff(i, x, layer_cache, pos, deterministic)
@@ -933,7 +942,13 @@ class Transformer(nn.Module):
         reverse_model: bool = False,
         cache: Optional[dict] = None,
         deterministic: bool = True,
+        start: bool = False,
     ):
+        """`start`: the cached chunk starts every row's sequence (a prefill
+        into rows that hold nothing yet; `Attention.__call__`), on the plain
+        unrolled executor."""
+        assert not start or (cache is not None and self.executor != "scan" and not (
+            self.reversible and self.reversible_impl != "remat")), "start: a cached unrolled pass"
         if self.executor == "scan":
             return self.scan_stack(
                 x,
@@ -992,7 +1007,7 @@ class Transformer(nn.Module):
                 x, layer_cache = self._layer(
                     i, x, key_mask,
                     cache[decode_cache.layer_key(i)] if cache else None,
-                    deterministic,
+                    deterministic, start,
                 )
                 if layer_cache:
                     new_cache[decode_cache.layer_key(i)] = layer_cache
@@ -1008,6 +1023,15 @@ class Transformer(nn.Module):
             decode_cache.STACKED if self.executor == "scan" else decode_cache.PER_LAYER
         )
 
+    def _per_row_cache(self) -> bool:
+        """Whether cached attention runs `Attention._cached_grouped`, which
+        keeps every row at its own position: K/V heads shared by query heads,
+        a window layer, or a rotate-half rotary outside the latent layer."""
+        kinds = set(self.attn_types or ("full",))
+        return bool(kinds & {"full", "window"} and (
+            (self.kv_heads or self.heads) != self.heads or "window" in kinds
+            or set(dict(self.rotary_specs or {})) & {"full", "window"}))
+
     def init_cache(
         self, batch: int, max_len: int, dtype=jnp.float32, *,
         per_row: bool = False, pages: Optional[tuple] = None, kv_dtype=None,
@@ -1016,15 +1040,25 @@ class Transformer(nn.Module):
         rings), in the layout its executor takes. Pure config math: usable
         unbound. `per_row`, `pages = (n_pages, page_size)` and `kv_dtype`
         as `decode_cache.layer_spec` reads them. Each layer takes the kind
-        its attention is of: latent, recurrent (linear attention) or K/V
-        heads; linear and K/V layers may share a cache."""
+        its attention is of: latent, recurrent (linear attention), a window's
+        ring (of `window + draft_positions` slots) or K/V heads; linear,
+        window and K/V layers may share a cache."""
         cache_kind = {"latent": "latent", "linear": "recurrent"}
-        kinds = [cache_kind.get(t, "heads")
-                 for t in islice(cycle(self.attn_types or ("full",)), self.depth)]
+        types = list(islice(cycle(self.attn_types or ("full",)), self.depth))
+        kinds = [cache_kind.get(t, "heads") for t in types]
         if "latent" in kinds and set(kinds) != {"latent"}:
             raise NotImplementedError(
                 "latent layers beside K/V or recurrent ones in one cache are not built "
                 "(recurrent and K/V layers are)")
+        if self._per_row_cache():
+            assert "recurrent" not in kinds and pages is None and kv_dtype is None
+            return decode_cache.make(
+                self.cache_layout, self.depth,
+                kinds=["window" if t == "window" else "heads" for t in types],
+                batch=batch, max_len=max_len, per_row=True, dtype=dtype, dim=self.dim,
+                heads=self.kv_heads or self.heads, dim_head=self.dim_head,
+                ring=(self.window or 0) + self.draft_positions,
+            )
         if set(kinds) != {"heads"}:
             assert not per_row and pages is None and kv_dtype is None
             return decode_cache.make(

@@ -39,8 +39,11 @@ COMPONENTS = (
     "sample", "pixels", "moe_router", "moe_dispatch", "moe_experts", "unscoped",
     "mla_attend", "mla_proj", "moe_shared",
     "delta_step", "delta_proj", "delta_chunk", "state_restore",
+    "window_attend", "global_attend",
 )
-PHASES = ("fwd", "bwd", "remat")
+# `mtp`: what a multi-token module runs (models/lm.py:CausalLM.draft_step and
+# the draft's argmax), whatever its component: a phase, as `remat` is
+PHASES = ("fwd", "bwd", "remat", "mtp")
 
 # the names the program gives its seven Pallas attention kernels (`name=` in
 # ops/pallas_attention.py and ops/pallas_decode.py). The chip names the
@@ -79,7 +82,8 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         # before `loss`: the vocab-chunked loss holds the head's matmul
         ("head", r"(^|/)(DALLE\.to_logits|logits_\w+)(/|$)"),
         ("loss", _E("loss")),
-        ("sample", _E("sample|rng_split")),
+        # `verify`: a verify step's accept test and index moves (models/lm.py)
+        ("sample", _E("sample|rng_split|verify")),
         ("pixels", r"(^|/)DiscreteVAE\."),
         ("cache_read", _E("cache_read")),
         ("cache_write", _E("cache_write")),
@@ -105,6 +109,11 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         # decode cache and is named as a whole (`cached_scan`); what moves
         # the cache inside its body is under `cache_read` / `cache_write`
         ("unscoped", r"(^|/)(scan_stack|cached_scan)/while/(body|cond)/[\w\-]+$"),
+        # a cached step of grouped K/V heads (models/attention.py:
+        # Attention._cached_grouped): scores, softmax and weights x values
+        # over a window layer's ring, or over a full layer's whole K/V
+        ("window_attend", _E("window_attend")),
+        ("global_attend", _E("global_attend")),
         ("attend", _E("attend")),
         ("attn_proj", _E("to_qkv|to_out")),
         # a routed layer's three parts (models/moe.py), before `ff`, whose
@@ -118,7 +127,8 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         ("norm_resid", r"(^|/)(\w*norms?_\w+|norm_by_max)(/|$)"),
         # token shift is glue by the issue's definition, wherever it runs
         ("attn_glue", r"(^|/)(token_shift|transformer\._shift|pattern_mask)(/|$)"),
-        ("ff", r"(^|/)ff(_\d+)?(/|$)"),
+        # with the multi-token module's 2 dim -> dim projection
+        ("ff", r"(^|/)(ff(_\d+)?|mtp_proj)(/|$)"),
         # under an attention module and neither kernel nor projection:
         # rotary, padding for the kernel, head transposes, masks
         ("attn_glue", r"(^|/)attn(_\d+)?(/|$)"),
@@ -128,6 +138,7 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
 )
 REMAT_MARK = "rematted_computation"  # jax.checkpoint's name for a recompute
 BWD_MARK = "transpose("
+MTP_MARK = re.compile(_E("mtp"))  # the scope everything of the module runs under
 
 
 def component(op_name: Optional[str], opcode: str = "",
@@ -151,6 +162,8 @@ def component(op_name: Optional[str], opcode: str = "",
     op_name = op_name or ""
     if REMAT_MARK in op_name:
         return found, "remat"
+    if MTP_MARK.search(op_name):
+        return found, "mtp"
     return found, "bwd" if BWD_MARK in op_name else "fwd"
 
 

@@ -1,18 +1,24 @@
 #!/usr/bin/env python
 """Generate token ids with a causal language model (`models/lm.py:CausalLM`)
-whose trunk decodes through a cache. Two families of published configs do:
+whose trunk decodes through a cache. Three families of published configs do:
 latent attention (a compressed K/V cache), leading dense layers, a shared
-expert beside sigmoid-routed ones of which this process holds a share; and
-gated delta-rule linear layers among full ones (a recurrent state beside K/V
-in one cache, `--config benchmark/configs/olmo-hybrid-7b-pp2.json`). Either
-with parameters stored in bf16.
+expert beside sigmoid-routed ones of which this process holds a share; gated
+delta-rule linear layers among full ones (a recurrent state beside K/V in one
+cache, `--config benchmark/configs/olmo-hybrid-7b-pp2.json`); and window and
+full layers over grouped K/V heads (window rings beside full K/V, every row
+at its own position, `--config benchmark/configs/k-exaone-236b-ep8.json`),
+whose multi-token module drafts a token a row a step for a two-position
+verify step. Each with parameters stored in bf16.
 
 The sampler `generate.py` uses for DALL-E, for token sequences: every prompt
 but its last token is prefilled into a decode cache
 (`models/lm.py:prefill_cached`), then ONE dispatch runs the whole token loop
 (`generate_tokens_cached`): the last prompt token is fed, every step samples
 (top `1 - filter_thres` of the vocabulary, Gumbel noise at `temperature`),
-the cache is written in place. Token ids in, token ids out: no tokenizer.
+the cache is written in place. A model that drafts for itself runs
+`--max_new_tokens` VERIFY steps, each of which emits one token or two; the
+first `--max_new_tokens` a row are given, with the steps' `accepted` drafts
+beside them. Token ids in, token ids out: no tokenizer.
 
     python generate_lm.py --prompts seeded:7 --batch 4 --prompt_len 512 --max_new_tokens 64
     python generate_lm.py --config benchmark/configs/pangu-ultra-moe-ep16.json \\
@@ -85,9 +91,10 @@ def read_config(args):
             cfg[key] = value
         else:
             raise SystemExit(f"unknown option {key!r} (have: {', '.join([*cfg, *PROGRAM_KEYS])})")
-    if "kv_lora_rank" not in cfg and "linear_key_head_dim" not in cfg:
-        raise SystemExit("generation is built for the latent-attention trunk (kv_lora_rank ...) "
-                         "and for linear and full layers (linear_key_head_dim ...)")
+    if not {"kv_lora_rank", "linear_key_head_dim", "layer_types"} & set(cfg):
+        raise SystemExit("generation is built for the latent-attention trunk (kv_lora_rank ...), "
+                         "for linear and full layers (linear_key_head_dim ...) and for window "
+                         "and full ones (layer_types ...)")
     return cfg, program
 
 
@@ -95,11 +102,13 @@ def build_model(args, cfg: dict, program: dict, prompt_len: int, rows: int):
     """(CausalLM sized for the prompts and the new tokens, what it was built from)."""
     from dalle_pytorch_tpu.models.lm import CausalLM
 
+    # a step of a model that drafts for itself takes two positions
+    per_step = 1 + int(cfg.get("num_nextn_predict_layers", 0))
     if "moe_buffer_rows" not in program and "num_experts_per_tok" in cfg:
-        # every assignment a prefill dispatch can make: no routing drops a token
-        program["moe_buffer_rows"] = (
-            min(args.prefill_rows, rows) * max(prompt_len - 1, 1) * cfg["num_experts_per_tok"])
-    mdl = CausalLM.from_config(cfg, prompt_len + args.max_new_tokens, **program)
+        # every assignment a prefill dispatch or a step can make: no routing drops a token
+        program["moe_buffer_rows"] = cfg["num_experts_per_tok"] * max(
+            min(args.prefill_rows, rows) * max(prompt_len - 1, 1), rows * per_step)
+    mdl = CausalLM.from_config(cfg, prompt_len + per_step * args.max_new_tokens, **program)
     return mdl, {"config": args.config or "DEFAULT_CONFIG", "set": args.set, **program}
 
 
@@ -137,8 +146,12 @@ def main(argv=None):
 
         variables = {"params": load_params_npz(args.weights)[0]}
     else:
-        variables = jax.jit(mdl.init)(jax.random.fold_in(key, 1), jnp.zeros((1, 8), jnp.int32))
+        init = jax.jit(mdl.init)(jax.random.fold_in(key, 1), jnp.zeros((1, 8), jnp.int32))
+        variables = {"params": init["params"]}  # not the counts its own pass left in `stats`
     cache, dropped = mdl.init_cache(rows), 0
+    if mdl.draft_layers and prompt_len < 2:
+        raise SystemExit("a model that drafts for itself takes prompts of two tokens or more: "
+                         "its module starts from the state of the token before the last")
     if prompt_len > 1:
         for r in range(0, rows, args.prefill_rows):
             cache, counts = prefill_cached(
@@ -148,8 +161,11 @@ def main(argv=None):
         mdl, variables, key, cache, jnp.asarray(prompts[:, -1:]), args.max_new_tokens,
         filter_thres=args.filter_thres, temperature=args.temperature, start=prompt_len - 1)
     dropped += int(np.sum(counts.get("moe_dropped", 0)))
-    result = {"tokens": np.asarray(tokens).tolist(), "model": options,
+    result = {"tokens": np.asarray(tokens)[:, :args.max_new_tokens].tolist(), "model": options,
               "moe_dropped": dropped}
+    if "verify_steps" in counts:  # verify steps: a row emitted at least one token a step
+        result.update(verify_steps=counts["verify_steps"],
+                      accepted=np.asarray(counts["accepted"]).tolist())
     if args.out:
         with open(args.out, "w") as f:
             json.dump(result, f)

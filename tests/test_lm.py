@@ -167,16 +167,24 @@ def test_dense_and_flash_attention_agree_on_the_new_block(cfg, pair):
     np.testing.assert_allclose(out[0], out[1], atol=2e-5)
 
 
-def test_generate_and_cached_decode_say_what_is_missing(cfg, pair):
+def test_generate_says_what_is_missing_and_grouped_heads_decode_through_a_cache(cfg, pair):
     mdl, variables, _ = pair
     with pytest.raises(NotImplementedError, match="Queue 2 B"):
         mdl.apply(variables, method=CausalLM.generate)
     trunk = Transformer(dim=64, depth=1, seq_len=N, heads=4, dim_head=16, kv_heads=2,
                         rotary_emb=False)
-    x = jnp.zeros((1, 1, 64))
-    params = trunk.init(jax.random.PRNGKey(0), jnp.zeros((1, N, 64)))
-    with pytest.raises(NotImplementedError, match="K/V head"):
-        trunk.apply(params, x, cache=trunk.init_cache(1, N + 1))
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, N, 64))
+    params = trunk.init(jax.random.PRNGKey(0), x)
+    # K/V heads shared by query heads: a prefill, then a step at the row's own index
+    cache = trunk.init_cache(1, N + 1)
+    assert cache["layer_0"]["attn"]["k"].shape == (1, 2, N + 1, 16)
+    assert cache["layer_0"]["attn"]["index"].shape == (1,)
+    with pytest.raises(NotImplementedError, match="has to start the rows' sequences"):
+        trunk.apply(params, x[:, :N - 1], cache=cache)  # the start is said, never guessed
+    _, cache = trunk.apply(params, x[:, :N - 1], cache=cache, start=True)
+    out, cache = trunk.apply(params, x[:, N - 1:], cache=cache)
+    np.testing.assert_allclose(out[:, 0], trunk.apply(params, x)[:, -1], atol=2e-5)
+    assert int(cache["layer_0"]["attn"]["index"][0]) == N
 
 
 def test_the_scan_executor_refuses_the_new_options():

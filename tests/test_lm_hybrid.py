@@ -266,8 +266,13 @@ def test_the_narrowed_refusals_say_what_is_left(cfg):
     variables = attn.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 32)))
     cache = decode_cache.make(decode_cache.PER_LAYER, 1, batch=1, max_len=8, heads=2,
                               dim_head=8, dim=32)["layer_0"]["attn"]
-    with pytest.raises(NotImplementedError, match="fewer K/V heads than query heads"):
+    # K/V heads shared by query heads decode through a cache since PR 37, every
+    # row at its own index: a lockstep cache (scalar index) is not theirs
+    with pytest.raises(AssertionError, match="per row"):
         attn.apply(variables, x, cache=cache)
+    cache = decode_cache.make(decode_cache.PER_LAYER, 1, batch=1, max_len=8, heads=2,
+                              dim_head=8, dim=32, per_row=True)["layer_0"]["attn"]
+    assert attn.apply(variables, x, cache=cache)[1]["index"].tolist() == [1]
     with pytest.raises(NotImplementedError, match="linear and full"):
         CausalLM.from_config(cfg, 16).generate()
 

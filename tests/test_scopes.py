@@ -113,6 +113,17 @@ def test_a_trace_names_an_operation_by_its_whole_line():
     ("jit(f)/DiscreteVAE.decode/dec_head/conv", "convolution", "c.1", ("pixels", "fwd")),
     (None, "copy", "copy.399", ("unscoped", "fwd")),
     ("jit(f)/something_else/mul", "fusion", "f.7", ("unscoped", "fwd")),
+    # a verify step's attention by kind of layer, and the multi-token module as a phase
+    ("jit(lm_sample)/while/body/closed_call/transformer/attn_3/global_attend/dot_general",
+     "fusion", "f.8", ("global_attend", "fwd")),
+    ("jit(lm_sample)/while/body/closed_call/transformer/attn_0/window_attend/dot_general",
+     "fusion", "f.9", ("window_attend", "fwd")),
+    ("jit(lm_sample)/while/body/closed_call/mtp/mtp_block/attn_0/global_attend/dot_general",
+     "fusion", "f.10", ("global_attend", "mtp")),
+    ("jit(lm_sample)/while/body/closed_call/mtp/mtp_proj/dot_general", "fusion", "f.11",
+     ("ff", "mtp")),
+    ("jit(lm_sample)/while/body/mtp/verify/select_n", "fusion", "f.12", ("sample", "mtp")),
+    (None, "custom-call", "%gmm_fwd.3", ("moe_experts", "fwd")),
 ])
 def test_component_rules(op_name, opcode, name, want):
     assert scopes.component(op_name, opcode, name) == want
@@ -176,7 +187,8 @@ def program_texts():
     quant = jax.jit(lambda v, x, c: attn.apply(v, x, cache=c)).lower(
         avars, x, cache).compile().as_text()
     return {"train": train, "sample": sample, "quant": quant, "lm": _lm_step_text(),
-            "lm_sample": _lm_sample_text(), **_hybrid_texts()}
+            "lm_sample": _lm_sample_text(), "verify_sample": _verify_sample_text(),
+            **_hybrid_texts()}
 
 
 def _lm_step_text() -> str:
@@ -224,6 +236,28 @@ def _hybrid_texts() -> dict:
         "hybrid_prefill": prefill.lower(
             variables, jnp.zeros((2, 8), jnp.int32), mdl.init_cache(2), zero).compile().as_text(),
     }
+
+
+def _verify_sample_text() -> str:
+    """Compiled text of a tiny window-and-full sampler whose multi-token
+    module drafts: a ring and a full K/V layer over shared K/V heads, verify
+    steps of two positions."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl = lm.CausalLM(
+        num_tokens=40, dim=32, depth=2, seq_len=24, heads=4, dim_head=8, draft_layers=1,
+        trunk=dict(norm="rms", use_bias=False, layerscale=False, kv_heads=2, qk_norm=True,
+                   window=4, attn_types=("window", "full"), draft_positions=1,
+                   rotary_specs={"window": {"type": "default", "dim": 8, "theta": 1e4}},
+                   ff_kinds=("swiglu", "swiglu_experts"), ff_dim=48, experts_total=4,
+                   experts_per_token=2, experts_held=(0, 2), expert_dim=16, moe_buffer_rows=64,
+                   moe_score="sigmoid", moe_score_bias=True, shared_dim=16))
+    variables = jax.jit(mdl.init)(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    sampler = jax.jit(lm._verify_sampler_builder(mdl, (4, 0.9, 1.0, 1, None)),
+                      donate_argnums=(2,))
+    return sampler.lower(
+        variables, jax.random.PRNGKey(1), mdl.init_cache(2), jnp.zeros((2, 2), jnp.int32),
+        jnp.full((2,), 8, jnp.int32)).compile().as_text()
 
 
 def _lm_sample_text() -> str:

@@ -753,3 +753,74 @@ def test_hybrid_token_loop_compiles_with_the_state_held_in_place(one_chip, monke
     assert ".remat" not in text
     assert " sort(" not in text  # top-k 0.9 counts (`ops/sampling.py:kth_largest`: PR 36)
     assert ds.HEADS_PER_BLOCK == 10
+
+
+def _kexaone(one_chip, monkeypatch, sessions=24, steps=288, doc=16384):
+    """(model, its variables' and its sessions' cache's shapes on the described
+    chip) of `kexaone.decode.16k`: the cell's own sizes."""
+    import json
+    from pathlib import Path
+
+    from dalle_pytorch_tpu.models import lm
+    from dalle_pytorch_tpu.ops import grouped_matmul, pallas_attention
+
+    for module in (grouped_matmul, pallas_attention):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    root = Path(__file__).resolve().parent.parent / "benchmark"
+    cfg = json.loads((root / "configs/k-exaone-236b-ep8.json").read_text())
+    job = json.loads((root / "workloads/kexaone.decode.16k.json").read_text())["job"]
+    assert (job["sessions"], job["steps"], job["document_tokens"]) == (sessions, steps, doc)
+    mdl = lm.CausalLM.from_config(cfg, doc + 2 * steps, **job["model"])
+    on = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    variables = on(jax.eval_shape(mdl.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    cache = on(jax.eval_shape(lambda: mdl.init_cache(sessions)))
+    assert cache["layer_0"]["attn"]["k_at"].shape == (sessions, 8, 129, 128)  # a ring's snapshot
+    return mdl, variables, cache
+
+
+def test_verify_step_sampler_compiles_at_the_cells_size(one_chip, monkeypatch, capsys):
+    """The token loop of `kexaone.decode.16k` (24 sessions x 16,384 + 576
+    positions, 288 verify steps of two positions, the module drafting): the
+    two full K/V layers ride the loop's carry in place (no copy of a leaf in
+    the body; a step's K/V go in by one named `dynamic-update-slice` a row),
+    every routed block's three grouped products are Mosaic's, and the plan is
+    the weights, the cache and under half a GB beside them."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl, variables, cache = _kexaone(one_chip, monkeypatch)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(lm._verify_sampler_builder(mdl, (288, 0.9, 1.0, 2, None)),
+                       donate_argnums=(2,)).lower(
+        variables, key, cache, i32(24, 32), i32(24)).compile()
+    text, plan = compiled.as_text(), _device_bytes(compiled) / 1e9
+    with capsys.disabled():
+        print(f"\nkexaone.decode.16k sampler: planned {plan:.2f} GB on the described v5e")
+    assert text.count("tpu_custom_call") == 18  # 3 x (4 + 1) a step, 3 before the loop
+    assert not re.search(r"= bf16\[24,8,16960,128\]\S* copy\(", text)
+    writes = re.findall(r"= bf16\[24,8,16960,128\]\S* dynamic-update-slice\(.*", text)
+    assert len(writes) == 144  # 24 rows x (k, v) x (layer 3 + the module's + its first pass)
+    assert all("/cache_write/" in w for w in writes)
+    assert 12.5 < plan < 13.5
+
+
+def test_prefill_of_one_document_compiles_at_the_cells_size(one_chip, monkeypatch, capsys):
+    """The prefill of one 16,384-token document into the sessions' cache
+    (trunk, then the module over all but the last position; flash kernels
+    with the window), every weight counted as held: the plan has to leave the
+    chip's 15.74 GiB some room, which 24 sessions with rings a turn longer
+    (705 slots, 15.73 GiB) did not and with rings of 129 slots and their
+    snapshot (15.45 GiB) do (PERF.md, PR 37)."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl, variables, cache = _kexaone(one_chip, monkeypatch)
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lm._prefill_builder(mdl, ()), donate_argnums=(2,), keep_unused=True).lower(
+        variables, tokens, cache, row).compile()
+    plan = _device_bytes(compiled) / 2**30
+    with capsys.disabled():
+        print(f"\nkexaone.decode.16k prefill: planned {plan:.2f} GiB on the described v5e")
+    assert "tpu_custom_call" in compiled.as_text()
+    assert plan < 15.55

@@ -17,8 +17,9 @@ The model is described in a published `config.json`'s keys, which
 `CausalLM.from_config` reads: `--config` names a file that holds them
 (DEFAULT_CONFIG, a small model, without it), `--set key=value` replaces one
 of them, or one of the `program` group's (`dtype`, `attn_impl`, `executor`,
-`moe_buffer_rows`). Training only: decode and serving of this model are not
-built (ROADMAP.md).
+`moe_buffer_rows`). This script trains; `generate_lm.py` decodes such a model
+through its cache (grouped K/V heads, window rings beside full K/V: PR 37);
+serving it is not built (ROADMAP.md).
 """
 
 from __future__ import annotations
