@@ -23,9 +23,12 @@ work is the list of (tile, group) pairs that share a row, at most `tiles +
 groups - 1` of them: made on the device from `group_sizes` (`_plan`), handed
 to the kernels as prefetched scalars, and walked by the grid. Every pair
 multiplies its whole tile by its group's matrix and keeps the rows that are
-the group's. Three kernels, named for the device trace: `gmm_fwd`,
-`gmm_dlhs` (the same body, the matrices read transposed) and `gmm_drhs`.
-Interpreted on the CPU backend, like the flash kernels.
+the group's, so `tm` follows a group's even share of the buffer and not the
+buffer's size (`_row_tile`, the one place that says the rule; what each
+product traced so far got is in `row_tiles`). Three kernels, named for the
+device trace: `gmm_fwd`, `gmm_dlhs` (the same body, the matrices read
+transposed) and `gmm_drhs`. Interpreted on the CPU backend, like the flash
+kernels.
 
 A group without rows. The two rows products give it NO pair: a pair's grid
 steps name the group's matrix, block by block, and the pipeline fetches
@@ -57,7 +60,19 @@ from jax.experimental.pallas import tpu as pltpu
 from dalle_pytorch_tpu.ops.pallas_attention import _dot, _NT, _TN, _use_interpret
 
 TILE_ROWS = 512  # rows a grid step multiplies: the MXU's rows, four times over
+MXU_ROWS = 128  # the MXU's rows, once
 TILE_CAP = 1024  # the widest tile along K or N
+
+#: the row tile each product traced so far got, by (kernel, rows, groups);
+#: tests pin it the way they pin `pallas_attention.tiles_chosen`.
+row_tiles: dict = {}
+
+
+def forget() -> None:
+    """Drop the record of the tiles and the emitters' trace caches (tests)."""
+    row_tiles.clear()
+    for emit in (_emit_rows, _emit_drhs):
+        emit.clear_cache()
 
 
 def _tile(n: int) -> int:
@@ -160,16 +175,21 @@ def _padded(x, tm):
 
 
 def _row_tile(rows: int, groups: int = 1) -> int:
-    """Rows a tile holds: TILE_ROWS, or all the rows of a shorter buffer.
-    Every pair multiplies its WHOLE tile by its group's matrix, so where a
-    group's even share of the buffer is under a tile (a token step: 512 rows
-    for 16 experts, 58 present on 5 or 6 of them), a tile no taller than the
-    MXU keeps a pair's arithmetic under its matrix's read: the rows products
-    ask with their groups; `gmm_drhs`, whose pairs read rows and no matrix,
-    keeps the whole tile."""
-    if rows < TILE_ROWS:
-        return -(-rows // 8) * 8
-    return TILE_ROWS if rows // groups >= TILE_ROWS else 128
+    """Rows a tile holds: TILE_ROWS, or all the rows of a shorter buffer;
+    where groups share the buffer and a group's even share of it is under a
+    tile, no taller than the MXU, whatever the buffer's size. Every pair
+    multiplies its WHOLE tile by its group's matrix and a bf16 matrix element
+    does `tm` operations a byte read, so above 240 rows (197 TFLOP/s over 819
+    GB/s) a pair is bound by arithmetic on rows it throws away: a verify
+    step's 384 rows for 16 experts, 43 present on 8 of them, cost as one tile
+    1.6 times its matrices' read (PERF.md, PR 38; a tile of 64 rows read 6%
+    faster still and was not taken: there). The rows products ask with their
+    groups; `gmm_drhs`, whose pairs read rows and no matrix, asks as one
+    group, which throws nothing away."""
+    whole = min(TILE_ROWS, -(-rows // 8) * 8)
+    if groups == 1 or rows // groups >= TILE_ROWS:
+        return whole
+    return min(MXU_ROWS, whole)
 
 
 @functools.partial(jax.jit, static_argnames=("transposed", "interpret"))
@@ -178,7 +198,9 @@ def _emit_rows(lhs, rhs, group_sizes, *, transposed, interpret):
     N], the result [R, K], the matrices read as they lie)."""
     rows, k = lhs.shape
     n = rhs.shape[1] if transposed else rhs.shape[2]
+    name = "gmm_dlhs" if transposed else "gmm_fwd"
     tm, tk, tn = _row_tile(rows, rhs.shape[0]), _tile(k), _tile(n)
+    row_tiles[(name, rows, rhs.shape[0])] = tm
     lhs = _padded(lhs, tm)
     n_tiles = lhs.shape[0] // tm
     plan = _plan(group_sizes, n_tiles, tm, empty_groups=False)
@@ -195,7 +217,7 @@ def _emit_rows(lhs, rhs, group_sizes, *, transposed, interpret):
         rhs_spec = pl.BlockSpec((1, tk, tn), lambda j, w, kk, o, g, t, n_: (g[w], k_of(w, kk, n_), j))
     out = pl.pallas_call(
         functools.partial(_rows_kernel, tm=tm, transposed=transposed),
-        name="gmm_dlhs" if transposed else "gmm_fwd",
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n // tn, jnp.maximum(plan[3][0], 1), n_k),  # the pairs there are, not W
@@ -217,6 +239,7 @@ def _emit_drhs(lhs, dout, group_sizes, *, out_dtype, interpret):
     rows, k = lhs.shape
     n, groups = dout.shape[1], group_sizes.shape[0]
     tm, tk, tn = _row_tile(rows), _tile(k), _tile(n)
+    row_tiles[("gmm_drhs", rows, groups)] = tm
     lhs, dout = _padded(lhs, tm), _padded(dout, tm)
     plan = _plan(group_sizes, lhs.shape[0] // tm, tm, empty_groups=True)
     return pl.pallas_call(

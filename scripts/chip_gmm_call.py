@@ -1,16 +1,20 @@
-"""`gmm_fwd` alone on the chip at a token step's sizes of the generation cell
-(a 512-row buffer in tiles of 128, 16 held experts of 7,680 x 2,048 bf16, 58
-rows present), with the rows on a given number of the experts: what a call
-costs by the experts it touches, and, with none touched, what the grid's dead
-steps cost.
+"""`gmm_fwd` alone on the chip at a step's sizes of a routed generation cell,
+with the rows on a given number of the experts: what a call costs by the
+experts it touches, and, with none touched, what the grid's dead steps cost.
+`--shape pangu`: a token step of `pangu.decode.8k` (a 512-row buffer, 16 held
+experts of 7,680 x 2,048 bf16, 58 rows present); `--shape kexaone`: a verify
+step of `kexaone.decode.16k` (384 rows, 6,144 x 2,048, 43 present).
 
     python scripts/chip_gmm_call.py --touched 0,1,6,16 [--tree <checkout>]
+    python scripts/chip_gmm_call.py --shape kexaone --touched 0,1,8,9,16 [--tile 64]
 
 `--tree` times another checkout's `ops/grouped_matmul.py` with the same inputs
-(the parent's, unpacked beside this one). Prints a line a shape (gate/up, out)
-and count: microseconds a call (the mean of `--calls` calls inside ONE
-dispatch, each waiting on the one before), and the share of the bandwidth that
-the touched experts' matrices are read at. `--tiny` rehearses on the CPU.
+(the parent's, unpacked beside this one); `--tile` puts a row tile of that
+many rows in the place of the module's rule. Prints a line a shape (gate/up,
+out) and count: the tile the product got, microseconds a call (the mean of
+`--calls` calls inside ONE dispatch, each waiting on the one before), and the
+share of the bandwidth that the touched experts' matrices are read at.
+`--tiny` rehearses on the CPU.
 """
 
 import argparse
@@ -20,9 +24,16 @@ import time
 from pathlib import Path
 
 
+# rows of the buffer, the model's width, an expert's, experts held, rows present
+SHAPES = {"pangu": (512, 7680, 2048, 16, 58), "kexaone": (384, 6144, 2048, 16, 43)}
+TINY = {"pangu": (64, 128, 256, 4, 9), "kexaone": (48, 128, 256, 4, 7)}
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__)
+    p.add_argument("--shape", choices=sorted(SHAPES), default="pangu")
     p.add_argument("--touched", default="0,1,6,16")
+    p.add_argument("--tile", type=int, default=0)
     p.add_argument("--tree", default=str(Path(__file__).resolve().parent.parent))
     p.add_argument("--calls", type=int, default=200)
     p.add_argument("--tiny", action="store_true")
@@ -36,7 +47,9 @@ def main(argv=None) -> int:
 
     from dalle_pytorch_tpu.ops import grouped_matmul as gm
 
-    rows, dim, width, groups, present = (64, 128, 256, 4, 9) if args.tiny else (512, 7680, 2048, 16, 58)
+    rows, dim, width, groups, present = (TINY if args.tiny else SHAPES)[args.shape]
+    if args.tile:
+        gm._row_tile = lambda rows, groups=1: min(args.tile, rows)
     if not args.tiny and jax.default_backend() != "tpu":
         raise SystemExit(f"no chip here: {jax.default_backend()}")
     dt = jnp.float32 if args.tiny else jnp.bfloat16
@@ -73,7 +86,8 @@ def main(argv=None) -> int:
             err = float(jnp.max(jnp.abs(got[:live].astype(jnp.float32) - want))) if live else 0.0
             read = touched * k * n * rhs.dtype.itemsize
             print("[gmm_call]", json.dumps({
-                "tree": args.tree, "shape": name, "touched": touched, "rows": live,
+                "tree": args.tree, "cell": args.shape, "shape": name,
+                "tile": gm._row_tile(rows, groups), "touched": touched, "rows": live,
                 "us_a_call": seconds * 1e6, "matrices_mb": read / 1e6,
                 "bandwidth_pct": 100 * read / 819e9 / seconds,
                 "max_abs_err": err, "device": jax.devices()[0].device_kind}), flush=True)

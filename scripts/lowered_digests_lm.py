@@ -2,7 +2,13 @@
 tree (`_tiny-pangu` and `_tiny-olmo`: both samplers and the prefill; `_tiny-mellum`:
 the loss and its gradient), beside `lowered_digests.py`'s DALL-E programs.
 
-usage: python scripts/lowered_digests_lm.py <tree> > out.json   (parent, then `.`; compare)
+With `--real`, instead: the REAL routed cells' programs lowered for a described
+v5e with their kernels Mosaic's (`pangu.decode.8k`: both samplers;
+`mellum2.train.8k`: the loss and its gradient), which a change to the grouped
+products' tiles at OTHER shapes has to leave as they were (the `_tiny` buffers
+are re-tiled by such a change: PR 38). 10 s a tree.
+
+usage: python scripts/lowered_digests_lm.py <tree> [--real] > out.json   (parent, then `.`; compare)
 """
 import hashlib, json, os, sys
 tree = os.path.abspath(sys.argv[1]); sys.path.insert(0, tree); os.chdir(tree)
@@ -10,32 +16,54 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp
 from dalle_pytorch_tpu.models import lm
 assert lm.__file__.startswith(tree), lm.__file__
-out = {}
+real = "--real" in sys.argv[2:]
+out, on = {}, None
 def digest(name, lowered):
     text = lowered.as_text()
-    out[name] = [hashlib.sha256(text.encode()).hexdigest()[:16], len(text)]
-shape = lambda tree_: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree_)
-for cell in ("_tiny.generate_lm", "_tiny.generate_hybrid"):
+    out[name] = [hashlib.sha256(text.encode()).hexdigest()[:16], len(text), text.count("tpu_custom_call")]
+shape = lambda tree_: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on), tree_)
+if real:
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from dalle_pytorch_tpu.ops import grouped_matmul, latent_decode, pallas_attention
+    on = jax.sharding.SingleDeviceSharding(
+        topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
+    for module in (grouped_matmul, latent_decode, pallas_attention):
+        module._use_interpret = lambda: False
+    # a kernel's payload carries the source lines it was traced from: dropped, so
+    # that lines added above a kernel's in its file do not read as another kernel
+    from jax._src import tpu_custom_call
+    from jaxlib.mlir.passmanager import PassManager
+    serialize = tpu_custom_call._lower_mosaic_module_to_asm
+    def without_locations(module, **kw):
+        with module.context:
+            PassManager.parse("builtin.module(strip-debuginfo)").run(module.operation)
+        return serialize(module, **kw)
+    tpu_custom_call._lower_mosaic_module_to_asm = without_locations
+for cell in ("pangu.decode.8k",) if real else ("_tiny.generate_lm", "_tiny.generate_hybrid"):
     w = json.load(open(f"benchmark/workloads/{cell}.json")); job = w["job"]
     cfg = json.load(open(f"benchmark/configs/{w['config']}.json"))
     doc, steps, b = job["document_tokens"], job["question_tokens"] + job["answer_tokens"], job["sessions"]
     mdl = lm.CausalLM.from_config(cfg, doc + steps, **job.get("model", {}))
     variables = shape(jax.eval_shape(mdl.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
     cache = shape(jax.eval_shape(lambda: mdl.init_cache(b)))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=on)
     for thres in (1.0, 0.9):
         f = lm._sampler_builder(mdl, (steps, thres, 1.0, 2))
         digest(f"{cell}/sampler/{thres}", jax.jit(f, donate_argnums=(2,)).lower(
-            variables, jax.ShapeDtypeStruct((2,), jnp.uint32), cache, i32(b, job["question_tokens"]), i32()))
+            variables, jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=on), cache,
+            i32(b, job["question_tokens"]), i32()))
+    if real:  # its prefill's buffer holds 32,768 rows: a tile of 512 on both trees
+        continue
     f = lm._prefill_builder(mdl, ())
     digest(f"{cell}/prefill", jax.jit(f, donate_argnums=(2,)).lower(
         variables, i32(job["prefill_rows"], doc), cache, i32()))
-# the train step of _tiny-mellum
-from dalle_pytorch_tpu.training import steps as S
-w = json.load(open("benchmark/workloads/_tiny.train_lm.json")); job = w["job"]
+# the loss and its gradient of _tiny-mellum, or of the real cell
+cell = "mellum2.train.8k" if real else "_tiny.train_lm"
+w = json.load(open(f"benchmark/workloads/{cell}.json")); job = w["job"]
 cfg = json.load(open(f"benchmark/configs/{w['config']}.json"))
 mdl = lm.CausalLM.from_config(cfg, job["seq_len"], **job.get("model", {}))
 tok = jnp.zeros((job["batch"], job["seq_len"]), jnp.int32)
-digest("_tiny.train_lm/loss_grad", jax.jit(jax.grad(lambda p, t: mdl.apply({"params": p}, t, return_loss=True, mutable=["stats"])[0])).lower(
+digest(f"{cell}/loss_grad", jax.jit(jax.grad(lambda p, t: mdl.apply({"params": p}, t, return_loss=True, mutable=["stats"])[0])).lower(
     shape(jax.eval_shape(mdl.init, jax.random.PRNGKey(0), tok))["params"], shape(tok)))
 print(json.dumps(out, indent=1))

@@ -211,22 +211,58 @@ def test_the_latent_kernel_is_known_by_its_instruction_name():
 @pytest.mark.parametrize("rows,groups,tile", [
     (512, 16, 128),  # a token step: 2 rows an expert, a tile no taller than the MXU
     (262144, 16, 512), (32768, 16, 512),  # training and prefill buffers keep the whole tile
-    (192, 4, 192), (64, 16, 64),  # shorter buffers are one tile, as they were
+    (192, 4, 128), (64, 16, 64),  # the rule asks the share, not the buffer's size
+    (384, 16, 128), (256, 16, 128), (192, 16, 128),  # a verify step of 24 or 16 sessions, a one-position step
+    (40, 16, 40),  # all the rows of a buffer shorter than the MXU
+    (384, 1, 384),  # `gmm_drhs` asks as one group and keeps the whole buffer
 ])
 def test_the_grouped_products_row_tile_follows_a_groups_share(rows, groups, tile):
     assert grouped_matmul._row_tile(rows, groups) == tile
 
 
-@pytest.mark.parametrize("touched", [(), (2, 5, 6, 11, 15)], ids=["no_expert", "5_of_16"])
-def test_a_token_steps_routed_layer_takes_nothing_from_rows_no_group_owns(monkeypatch, touched):
+@pytest.mark.parametrize("sizes", [
+    (9, 0, 3, 7, 0, 0, 5, 0, 6, 0, 4, 0, 0, 5, 0, 4),  # 43 rows on 8 groups: all on tile 0
+    (40, 0, 35, 0, 0, 30, 25, 0, 0, 0, 12, 0, 0, 0, 8, 0),  # 150: group 5 straddles the edge at 128
+    (0,) * 16,
+], ids=["43_on_8", "150_straddling", "no_row"])
+def test_a_verify_steps_rows_product_walks_tiles_a_group_may_straddle(sizes):
+    """`gmm_fwd` at a verify step's form (24 sessions x 2 positions x 8
+    choices = 384 rows, 16 groups; small K and N): every owned row is its own
+    group's product whichever tile it lies on, and the tile the product got
+    is on the module's record."""
+    rows, k, n, tile = 384, 128, 256, 128
+    grouped_matmul.forget()
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    lhs = jax.random.normal(keys[0], (rows, k), jnp.float32)
+    rhs = jax.random.normal(keys[1], (16, k, n), jnp.float32) / np.sqrt(k)
+    live = sum(sizes)
+    if live > tile:
+        ends = np.cumsum(sizes)
+        assert any(a < tile < b for a, b in zip(ends - np.asarray(sizes), ends))
+    got = grouped_matmul.grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32))
+    owner = np.repeat(np.arange(16), sizes)
+    want = jnp.einsum("rk,rkn->rn", lhs[:live], rhs[owner], preferred_element_type=jnp.float32)
+    assert got.shape == (rows, n)
+    np.testing.assert_allclose(np.asarray(got[:live]), np.asarray(want), atol=1e-5)
+    assert grouped_matmul.row_tiles == {("gmm_fwd", 384, 16): tile}
+    grouped_matmul.forget()
+    assert grouped_matmul.row_tiles == {}
+
+
+@pytest.mark.parametrize("tokens,touched", [
+    (64, ()), (64, (2, 5, 6, 11, 15)), (48, (0, 2, 5, 6, 9, 11, 13, 15)),
+], ids=["no_expert", "5_of_16", "verify_8_of_16"])
+def test_a_token_steps_routed_layer_takes_nothing_from_rows_no_group_owns(monkeypatch, tokens, touched):
     """A token step's shapes (64 rows, 8 choices each, 16 experts held, a
     buffer of 512 rows in tiles of 128) with the router steered: no held
     expert gets a row (the layer is then its shared expert alone), or 5 of
-    the 16 do (58 rows, as in the cell). A group without rows has no pair in
-    the forward product's work list, so whole tiles of the buffer are never
-    written; here every row that no group owns comes out of every grouped
-    product as NaN, and none may reach a token."""
-    tokens, dim, width, total, k, held = 64, 32, 16, 32, 8, 16
+    the 16 do (58 rows, as in the cell); and a verify step's (24 sessions x 2
+    positions: 48 rows, a buffer of 384, 43 rows on 8 experts). A group
+    without rows has no pair in the forward product's work list, so whole
+    tiles of the buffer are never written; here every row that no group owns
+    comes out of every grouped product as NaN, and none may reach a token."""
+    dim, width, total, k, held = 32, 16, 32, 8, 16
+    present = {64: 58, 48: 43}[tokens] if touched else 0  # the tokens t with t % 11 send a row
     assert grouped_matmul._row_tile(tokens * k, held) == 128
 
     def poisoned(lhs, rhs, group_sizes):
@@ -251,11 +287,13 @@ def test_a_token_steps_routed_layer_takes_nothing_from_rows_no_group_owns(monkey
     x[:, :total] = logits  # a token's first features are its router logits
     params = layer.init(jax.random.PRNGKey(2), jnp.zeros((1, 8, dim)))["params"]
     params = {**params, "router": jnp.eye(dim, total)}
+    grouped_matmul.forget()
     got, aux = layer.apply({"params": params}, jnp.asarray(x)[None], mutable=["stats"])
     got, load = np.asarray(got)[0], np.asarray(aux["stats"]["moe_load"])
     assert np.flatnonzero(load).tolist() == list(touched)
-    assert int(aux["stats"]["moe_rows"]) == (58 if touched else 0)
+    assert int(aux["stats"]["moe_rows"]) == present
     assert int(aux["stats"]["moe_dropped"]) == 0 and np.isfinite(got).all()
+    assert grouped_matmul.row_tiles == {("gmm_fwd", tokens * k, held): 128}
     if touched:
         np.testing.assert_allclose(got, _layer_by_loop(params, x, k, (0, held), 2.5), atol=2e-5)
     else:

@@ -701,18 +701,25 @@ def test_latent_decode_attention_compiles(one_chip, monkeypatch):
     assert ld.BLOCK_POSITIONS == 1024
 
 
-@pytest.mark.parametrize("k,n", [(7680, 2048), (2048, 7680)], ids=["gate_up", "down"])
-def test_grouped_matmul_compiles_at_a_token_steps_sizes(one_chip, monkeypatch, k, n):
-    """`gmm_fwd` as a token step of the generation cell calls it: a buffer of
-    512 rows for 16 held experts stored in bf16, tiles of 128 rows."""
+@pytest.mark.parametrize("rows,k,n", [
+    (512, 7680, 2048), (512, 2048, 7680), (384, 6144, 2048), (384, 2048, 6144),
+], ids=["gate_up", "down", "verify_gate_up", "verify_down"])
+def test_grouped_matmul_compiles_at_a_token_steps_sizes(one_chip, monkeypatch, rows, k, n):
+    """`gmm_fwd` as a step of the routed generation cells calls it, 16 held
+    experts stored in bf16: a token step of `pangu.decode.8k` (a buffer of
+    512 rows) and a verify step of `kexaone.decode.16k` (384 rows), both in
+    tiles of 128 rows."""
     from dalle_pytorch_tpu.ops import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "_use_interpret", lambda: False)
-    lhs = jax.ShapeDtypeStruct((512, k), jnp.bfloat16, sharding=one_chip)
+    gm.forget()
+    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
     rhs = jax.ShapeDtypeStruct((16, k, n), jnp.bfloat16, sharding=one_chip)
     text = _compile(gm.grouped_matmul, lhs, rhs, _i32(one_chip, 16)).as_text()
-    assert re.search(rf"%gmm_fwd[.\d]* = bf16\[512,{n}\]", text)
+    assert re.search(rf"%gmm_fwd[.\d]* = bf16\[{rows},{n}\]", text)
+    assert gm.row_tiles == {("gmm_fwd", rows, 16): 128}
     assert gm._row_tile(512, 16) == 128 and (gm._tile(7680), gm._tile(2048)) == (768, 1024)
+    assert gm._row_tile(384, 16) == 128 and (gm._tile(6144), gm._tile(2048)) == (1024, 1024)
 
 
 def test_hybrid_token_loop_compiles_with_the_state_held_in_place(one_chip, monkeypatch):
