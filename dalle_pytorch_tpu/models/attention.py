@@ -41,7 +41,10 @@ import flax.linen as nn
 from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.ops.attention_core import dense_attention
 from dalle_pytorch_tpu.ops.delta_step import delta_step
+from dalle_pytorch_tpu.ops.index_score import index_scores
+from dalle_pytorch_tpu.ops.index_select import selected_indices, selected_mask
 from dalle_pytorch_tpu.ops.latent_decode import latent_decode_attention
+from dalle_pytorch_tpu.ops.sparse_latent_decode import sparse_latent_decode_attention
 from dalle_pytorch_tpu.ops.pallas_attention import (
     TOKEN_MAJOR,
     flash_attention,
@@ -739,22 +742,48 @@ class LatentAttention(nn.Module):
         c_q = rms(x W_dq)            q = c_q W_uq -> per head q_n | q_r
         c | k_r = x W_dkv            c = rms(c);  q_r, k_r rotated (rotate-half)
         k_n | v = c W_ukv            per head
-        s = (q_n . k_n + q_r . k_r) / sqrt(qk_nope_dim + qk_rope_dim)
+        s = (q_n . k_n + q_r . k_r) / sqrt(qk_nope_dim + qk_rope_dim) * softmax_mult
 
-    Two forms that must agree. Without a cache, and for a prefill chunk
-    written into one, the EXPANDED form: k and v of all heads made from the
+    Two forms that must agree. Without a cache, and for a chunk that STARTS
+    its rows' sequences (`start`: a prefill into rows that hold nothing
+    yet), the EXPANDED form: k and v of all heads made from the
     chunk's latent, attention by the dense path or the flash kernels (q and k
     `qk_nope_dim + qk_rope_dim` wide; v is padded with zeros to that width,
     because the kernels keep one width, and the padding is cut from the
-    result). With a one-token step, the ABSORBED form over the cache's
+    result). With a cache otherwise, the ABSORBED form over the cache's
     latent itself: `q_c = q_n W_uk^T` per head, the scores and the weighted
-    sum against `c` (ops/latent_decode.py), and `o = o_c W_uv` after; the
+    sum against `c`, and `o = o_c W_uv` after; the
     cache holds `c` and the rotated `k_r` (models/decode_cache.py, kind
-    `latent`) and no head ever has its keys or values written out.
+    `latent`) and no head ever has its keys or values written out. A
+    one-token step runs it through the kernel (ops/latent_decode.py); a
+    longer chunk of NEW tokens, written at the cache's index, attends what
+    the cache holds and itself in blocks of cached positions under a running
+    softmax (`_chunk_attend`).
+
+    `index_topk` > 0 puts a LIGHTNING INDEXER beside it (learned sparse
+    attention): from the layer's input x and the same normed query latent,
+
+        q_I = c_q W_Iq -> index_heads of index_dim, the first qk_rope_dim rotated
+        k_I = LayerNorm(x W_Ik), one key a position, rotated likewise; cached
+        w   = x W_Iw / sqrt(index_heads)
+        I(t, p) = sum_j w_t,j relu(q_I,t,j . k_I,p) / sqrt(index_dim)
+
+    and query t attends the min(index_topk, t + 1) positions p <= t of
+    largest I(t, p) alone (ties: the lower position; ops/index_select.py). A
+    token step scores the row's live positions (ops/index_score.py), selects
+    as indices and attends those positions, fetched
+    (ops/sparse_latent_decode.py); a chunk, cached or not, scores in blocks
+    and masks the blocks' scores by the selection. A sequence or a cache no
+    longer than `index_topk` selects everything: the dense forms run, and the
+    indexer only writes its keys. Sows `dsa_scored` and `dsa_selected` (the
+    positions a token step scored and attended, over its rows) into `stats`,
+    and into `picks`, where the caller makes it mutable, the step's
+    `selected` [B, index_topk] and `selected_count` [B].
 
     Parameters: `to_q_latent`, `q_norm`, `to_q`, `to_kv_latent`, `kv_norm`,
-    `to_kv` [kv_lora_rank, heads * (qk_nope_dim + v_dim)], `to_out`; no
-    biases; matrices stored in `param_dtype`, the two gains in float32.
+    `to_kv` [kv_lora_rank, heads * (qk_nope_dim + v_dim)], `to_out`, and of the
+    indexer `index_q`, `index_k`, `index_k_norm` (gain and bias), `index_w`; no
+    other biases; matrices stored in `param_dtype`, the gains in float32.
     """
 
     dim: int
@@ -767,18 +796,22 @@ class LatentAttention(nn.Module):
     v_dim: int
     norm_eps: float = 1e-6
     attn_impl: str = "auto"
+    softmax_mult: float = 1.0
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x, key_mask=None, rotary=None, cache=None, deterministic=True,
-                 rotary_cs=None):
+                 rotary_cs=None, start=False):
         assert key_mask is None and rotary is None and rotary_cs is not None, (
             "latent attention is causal, unpadded, under a rotate-half table")
         b, n, _ = x.shape
         h, dn, dr, dv, rank = (self.heads, self.qk_nope_dim, self.qk_rope_dim, self.v_dim,
                                self.kv_lora_rank)
-        sm_scale = (dn + dr) ** -0.5
+        sm_scale = (dn + dr) ** -0.5 * self.softmax_mult
         dense = lambda width, name: nn.Dense(
             width, use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype, name=name)
         norm = lambda name: nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name=name)
@@ -786,7 +819,8 @@ class LatentAttention(nn.Module):
                            (rank, h * (dn + dv)), self.param_dtype).astype(self.dtype)
         index = 0 if cache is None else cache["index"]
         with jax.named_scope("mla_proj"):
-            q = dense(h * (dn + dr), "to_q")(norm("q_norm")(dense(self.q_lora_rank, "to_q_latent")(x)))
+            c_q = norm("q_norm")(dense(self.q_lora_rank, "to_q_latent")(x))
+            q = dense(h * (dn + dr), "to_q")(c_q)
             q = q.reshape(b, n, h, dn + dr).transpose(0, 2, 1, 3)  # [b, h, n, dn + dr]
             c, k_r = jnp.split(dense(rank + dr, "to_kv_latent")(x), [rank], axis=-1)
             c = norm("kv_norm")(c)  # [b, n, rank]
@@ -794,24 +828,73 @@ class LatentAttention(nn.Module):
             q_n, q_r = q[..., :dn], apply_rotary_half(cos, sin, q[..., dn:])
             k_r = apply_rotary_half(cos, sin, k_r)  # [b, n, dr]: one head
             chunk = {decode_cache.LATENT: c, decode_cache.ROPE: k_r.transpose(0, 2, 1)}
+        if self.index_topk:
+            with jax.named_scope("dsa_index_proj"):
+                hi, di = self.index_heads, self.index_dim
+                turn = lambda t: jnp.concatenate(
+                    [apply_rotary_half(cos, sin, t[..., :dr]), t[..., dr:]], axis=-1)
+                q_i = turn(dense(hi * di, "index_q")(c_q).reshape(b, n, hi, di)
+                           .transpose(0, 2, 1, 3))  # [b, hi, n, di]
+                k_i = turn(nn.LayerNorm(epsilon=1e-6, dtype=self.dtype, name="index_k_norm")(
+                    dense(di, "index_k")(x)))  # [b, n, di]: one key
+                w_i = dense(hi, "index_w")(x).astype(jnp.float32) * (hi * di) ** -0.5
+                chunk[decode_cache.INDEX_K] = k_i
 
         new_cache = None
         if cache is not None:
             written, _ = decode_cache.write(cache, chunk, None)
             new_cache = {**written, "index": index + n}
+        # with an indexer: whether some query may have more positions than it attends
+        sparse = bool(self.index_topk) and self.index_topk < (
+            n if cache is None or start else written[decode_cache.LATENT].shape[1])
         if cache is not None and n == 1:
             with jax.named_scope("mla_proj"):
                 w = to_kv.reshape(rank, h, dn + dv)
                 q_c = jnp.einsum("bhd,rhd->bhr", q_n[:, :, 0], w[..., :dn])
-            with jax.named_scope("mla_attend"):
-                o_c = latent_decode_attention(
-                    q_c, q_r[:, :, 0], written[decode_cache.LATENT], written[decode_cache.ROPE],
-                    jnp.broadcast_to(index + 1, (b,)), sm_scale=sm_scale)
+            latent, rope = written[decode_cache.LATENT], written[decode_cache.ROPE]
+            if sparse:
+                lengths = jnp.broadcast_to(index + 1, (b,))
+                with jax.named_scope("dsa_index"):
+                    scores = index_scores(q_i[:, :, 0], w_i[:, 0], written[decode_cache.INDEX_K],
+                                          lengths)
+                with jax.named_scope("dsa_select"):
+                    chosen, count = selected_mask(scores, lengths, self.index_topk)
+                    picked = selected_indices(chosen, self.index_topk)
+                with jax.named_scope("mla_attend"):
+                    o_c = sparse_latent_decode_attention(
+                        q_c, q_r[:, :, 0], latent, rope, picked, count, sm_scale=sm_scale)
+                with jax.named_scope("dsa_select"):
+                    for name, value in (("dsa_scored", jnp.sum(lengths)),
+                                        ("dsa_selected", jnp.sum(chosen, dtype=jnp.int32))):
+                        self.sow("stats", name, value, reduce_fn=lambda _, new: new,
+                                 init_fn=lambda: None)
+                if self.is_mutable_collection("picks"):
+                    for name, value in (("selected", picked), ("selected_count", count)):
+                        self.sow("picks", name, value, reduce_fn=lambda _, new: new,
+                                 init_fn=lambda: None)
+            else:
+                with jax.named_scope("mla_attend"):
+                    o_c = latent_decode_attention(
+                        q_c, q_r[:, :, 0], latent, rope, jnp.broadcast_to(index + 1, (b,)),
+                        sm_scale=sm_scale)
             with jax.named_scope("mla_proj"):
                 out = jnp.einsum("bhr,rhv->bhv", o_c, w[..., dn:]).reshape(b, 1, h * dv)
+        elif sparse or (cache is not None and not start):
+            # new tokens against what the cache holds and themselves, or a
+            # sequence longer than a query attends: the absorbed form in blocks
+            with jax.named_scope("mla_proj"):
+                w = to_kv.reshape(rank, h, dn + dv)
+                q_c = jnp.einsum("bhnd,rhd->bhnr", q_n, w[..., :dn])
+            held = chunk if cache is None else written
+            o_c = _chunk_attend(
+                q_c, q_r, held[decode_cache.LATENT], held[decode_cache.ROPE], index,
+                sm_scale=sm_scale, **(dict(
+                    indexer=(q_i, w_i, held[decode_cache.INDEX_K]), topk=self.index_topk)
+                    if sparse else {}))
+            with jax.named_scope("mla_proj"):
+                out = jnp.einsum("bhnr,rhv->bnhv", o_c, w[..., dn:]).reshape(b, n, h * dv)
         else:
-            # a chunk is attended by itself alone: it starts the sequence (a
-            # prefill of new tokens against a cache is not built)
+            # the chunk is attended by itself alone: it starts the sequence
             with jax.named_scope("mla_proj"):
                 kv = jnp.dot(c, to_kv).reshape(b, n, h, dn + dv).transpose(0, 2, 1, 3)
                 k = jnp.concatenate(
@@ -827,10 +910,83 @@ class LatentAttention(nn.Module):
             else:
                 with jax.named_scope("mla_attend"):
                     mask = jnp.tril(jnp.ones((n, n), bool))[None, None]
-                    out = dense_attention(jnp.concatenate([q_n, q_r], -1), k, v, mask=mask)
+                    scaled = jnp.concatenate([q_n, q_r], -1)
+                    if self.softmax_mult != 1.0:  # the dense path scales by 1/sqrt(width)
+                        scaled = scaled * jnp.asarray(self.softmax_mult, scaled.dtype)
+                    out = dense_attention(scaled, k, v, mask=mask)
             out = out.transpose(0, 2, 1, 3).reshape(b, n, h * dv)
         with jax.named_scope("mla_proj"):
             return dense(self.dim, "to_out")(out), new_cache
+
+
+CHUNK_KEYS = 1024  # cached positions a step of `_chunk_attend`'s loops takes
+
+
+def _chunk_attend(q_c, q_r, latent, rope, start, *, sm_scale, indexer=None, topk=0):
+    """[B, H, n, R]: the absorbed form for n queries a row at positions
+    `start .. start + n - 1` (`start` a traced scalar: the rows stand in
+    lockstep) over `latent` [B, L, R] and `rope` [B, dr, L], which hold those
+    positions and all before them: query t sees p <= t. `indexer = (q_i [B,
+    Hi, n, Di], w_i [B, n, Hi] float32, keys [B, L, Di])` with `topk`: query t
+    sees of those the min(topk, t + 1) positions of largest index score
+    alone. Both passes walk blocks of `CHUNK_KEYS` positions and stop at the
+    last block a query of the chunk can see; a last block that would
+    overhang the arrays starts earlier and counts only what is new in it.
+    The index pass writes a chunk's scores [B, n, L] float32, whole (the
+    selection needs a row's every score); the attention pass keeps a running
+    softmax in float32 and no score past its block."""
+    b, h, n, _ = q_c.shape
+    total = latent.shape[1]
+    size = min(CHUNK_KEYS, total)
+    blocks = (start + n + size - 1) // size
+    at = start + jnp.arange(n)  # the queries' positions
+    block_at = lambda j: jnp.minimum(j * size, total - size)
+
+    chosen = None
+    if indexer is not None:
+        q_i, w_i, keys = indexer
+
+        def score(j, scores):
+            lo = block_at(j)
+            k = lax.dynamic_slice_in_dim(keys, lo, size, axis=1)
+            s = jnp.einsum("bhnd,bkd->bnhk", q_i, k, preferred_element_type=jnp.float32)
+            s = jnp.sum(jnp.maximum(s, 0.0) * w_i[..., None], axis=2)
+            return lax.dynamic_update_slice_in_dim(scores, s, lo, axis=2)
+
+        with jax.named_scope("dsa_index"):
+            scores = lax.fori_loop(0, blocks, score,
+                                   jnp.full((b, n, total), -jnp.inf, jnp.float32))
+        with jax.named_scope("dsa_select"):
+            chosen, _ = selected_mask(scores, jnp.broadcast_to(at + 1, (b, n)), topk)
+
+    def attend(j, carry):
+        m, l, acc = carry
+        lo = block_at(j)
+        c = lax.dynamic_slice_in_dim(latent, lo, size, axis=1)
+        k_r = lax.dynamic_slice_in_dim(rope, lo, size, axis=2)
+        pos = lo + jnp.arange(size)
+        if chosen is None:
+            seen = (pos[None, :] <= at[:, None])[None]
+        else:
+            seen = lax.dynamic_slice_in_dim(chosen, lo, size, axis=2)
+        seen = (seen & (pos >= j * size))[:, None]  # [b, 1, n, size]
+        s = (jnp.einsum("bhnr,bkr->bhnk", q_c, c, preferred_element_type=jnp.float32)
+             + jnp.einsum("bhnd,bdk->bhnk", q_r, k_r, preferred_element_type=jnp.float32))
+        s = jnp.where(seen, s * sm_scale, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)  # a query that has seen nothing yet
+        p = jnp.exp(s - safe)
+        corr = jnp.exp(m - safe)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jnp.einsum("bhnk,bkr->bhnr", p.astype(c.dtype), c,
+                                      preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    with jax.named_scope("mla_attend"):
+        m = jnp.full((b, h, n, 1), -jnp.inf, jnp.float32)
+        _, l, acc = lax.fori_loop(
+            0, blocks, attend, (m, jnp.zeros_like(m), jnp.zeros(q_c.shape, jnp.float32)))
+        return (acc / l).astype(q_c.dtype)
 
 
 def delta_rule_chunked(q, k, v, g, beta, chunk: int = 64):

@@ -31,6 +31,15 @@ compiler's choice for such a leaf) transposed, and copied back at every
 call of a kernel that wants them as declared (139 MB a layer a step at 64 x
 8,480: PERF.md, PR 31). With the positions last, a position costs its
 latent_dim + rope_dim numbers and no more. Per-layer layout, scalar `index`.
+Where the layer selects the positions it attends by a learned score
+(`index_dim`: a lightning indexer beside the latent attention) a third leaf
+stands beside the two:
+
+  * `index_k` [B, L, index_dim]: the indexer's one key a position, positions
+    in the middle as the latent's: 128 numbers are a whole tile of lanes, so
+    nothing is padded, the score kernel streams [block, 128] slabs as the
+    latent's kernel streams its own (ops/index_score.py), and `write` puts a
+    chunk's keys where it puts its latent.
 
 A RECURRENT layer (`layer_spec(kind="recurrent")`: linear attention by the
 gated delta rule, models/attention.py:GatedDeltaAttention) holds no position
@@ -116,7 +125,8 @@ ATTN = "attn"
 K, V, K_SCALE, V_SCALE, INDEX = "k", "v", "k_scale", "v_scale", "index"
 SCALE_KEYS = (K_SCALE, V_SCALE)
 LATENT, ROPE = "latent", "rope"
-LATENT_KEYS = (LATENT, ROPE)
+INDEX_K = "index_k"  # a latent layer's third leaf: the indexer's key
+LATENT_KEYS = (LATENT, ROPE, INDEX_K)
 STATE, CONV = "state", "conv"
 # a recurrent layer's running leaves and, beside each, its snapshot
 SNAPSHOT = {STATE: "state_at", CONV: "conv_at"}
@@ -167,6 +177,7 @@ def layer_spec(
     kind: str = "heads",
     latent_dim: Optional[int] = None,
     rope_dim: Optional[int] = None,
+    index_dim: Optional[int] = None,
     key_dim: Optional[int] = None,
     value_dim: Optional[int] = None,
     conv_taps: Optional[int] = None,
@@ -177,8 +188,9 @@ def layer_spec(
     """ONE layer's leaves as `jax.ShapeDtypeStruct`s, from the geometry.
 
     `kind="latent"`: `latent` [batch, max_len, latent_dim] and `rope`
-    [batch, rope_dim, max_len] with a scalar `index`, and nothing else (no
-    pages, no int8 store, no rings). `kind="recurrent"`: `state`
+    [batch, rope_dim, max_len] with a scalar `index`, with `index_dim` also
+    `index_k` [batch, max_len, index_dim], and nothing else (no pages, no
+    int8 store, no rings). `kind="recurrent"`: `state`
     [batch, key_dim, linear_heads * value_dim] and `conv` [batch, conv_taps - 1,
     linear_heads * (2 key_dim + value_dim)], both float32, their snapshot
     beside them, and a scalar `index`. `kind="window"`: K/V [batch, heads,
@@ -198,9 +210,11 @@ def layer_spec(
     if kind == "latent":
         assert pages is None and kv_dtype is None and not per_row and not shift_tokens, (
             "a latent cache is lanes in the cache dtype, decoded in lockstep")
+        indexed = {INDEX_K: spec((batch, max_len, index_dim), dtype)} if index_dim else {}
         return {ATTN: {
             LATENT: spec((batch, max_len, latent_dim), dtype),
             ROPE: spec((batch, rope_dim, max_len), dtype),
+            **indexed,
             INDEX: spec((), jnp.int32),
         }}
     if kind == "recurrent":
@@ -446,12 +460,19 @@ def leaf_name(path) -> str:
 
 def kv_bytes(cache: dict) -> int:
     """Bytes of the K/V leaves, quantization scales included (of a latent
-    layer: its latent and its shared rotary key)."""
+    layer: its latent, its shared rotary key and, where it has one, its
+    indexer's key)."""
     return sum(
         leaf.size * leaf.dtype.itemsize
         for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
         if leaf_name(path) in KV_KEYS + LATENT_KEYS
     )
+
+
+def max_len(cache: dict) -> int:
+    """Positions a latent cache holds a row (its `latent` leaves' middle axis)."""
+    return next(leaf.shape[1] for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+                if leaf_name(path) == LATENT)
 
 
 def pack_state(state):
@@ -710,7 +731,8 @@ def write(attn_cache: dict, vals: dict, seq_cap: int):
     length is the slotted cache's (`seq_cap`), and finished rows clamp to
     the spare last position as the lanes' dynamic_update_slice does. A
     stacked leaf (the cache carries `layer`) is written at [layer]. A latent
-    layer's `vals` are `latent` [B, n, latent_dim] and `rope` [B, rope_dim, n]."""
+    layer's `vals` are `latent` [B, n, latent_dim], `rope` [B, rope_dim, n] and,
+    where it keeps one, `index_k` [B, n, index_dim]."""
     index, layer = attn_cache[INDEX], attn_cache.get(LAYER)
     if LATENT in attn_cache:
         # a latent layer: the chunk's n positions from `index` on, along

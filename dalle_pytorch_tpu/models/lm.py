@@ -6,8 +6,10 @@ mean next-token cross-entropy over positions 0..N-2, computed by the
 vocab-chunked loss of `ops/losses.py` so that the [B, N, V] logits are
 never whole.
 
-Generation, for a trunk of latent attention layers, of linear (gated delta
-rule) and full ones, or of window and full ones over grouped K/V heads:
+Generation, for a trunk of latent attention layers (with or without a
+lightning indexer that selects the cached positions a query attends), of
+linear (gated delta rule) and full ones, or of window and full ones over
+grouped K/V heads:
 `prefill` writes a batch of prompts into a decode cache
 (models/decode_cache.py, per-layer layout: the latent kind of layer, or
 recurrent and K/V layers in one tree, or window rings beside full K/V),
@@ -15,7 +17,9 @@ recurrent and K/V layers in one tree, or window rings beside full K/V),
 runs a whole token loop in one dispatch, the cache held in place in the
 loop's carry. A turn starts where the session's document ended: the K/V
 layers' index set back, the recurrent layers' state and the window layers'
-rings restored from the snapshot `prefill_cached` took.
+rings restored from the snapshot `prefill_cached` took. A latent trunk also
+takes NEW tokens against what its cache holds (`extend`): `prefill_cached(
+chunk=)` puts a long prompt in that way, a chunk a dispatch.
 
 The window-and-full trunk keeps every row at its OWN position and may carry a
 multi-token module (`draft_layers`; `CausalLM.draft_step`): one more block
@@ -30,6 +34,7 @@ cache geometry (ROADMAP.md, Queue 2 B).
 
 from __future__ import annotations
 
+import math
 from itertools import cycle, islice
 from typing import Any, Optional
 
@@ -50,6 +55,9 @@ DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 LAYER_KINDS = {"sliding_attention": "window", "full_attention": "full",
                "linear_attention": "linear"}
 MOE_COUNTS = ("moe_load", "moe_rows", "moe_dropped")
+# an indexed latent layer's counters of a token step, beside them: the
+# positions its indexer scored and the positions it then attended, over the rows
+DSA_COUNTS = ("dsa_scored", "dsa_selected")
 
 
 def rotary_spec(spec: dict, dim: int) -> dict:
@@ -82,19 +90,57 @@ def _routed_ff(cfg: dict, dense: tuple, held: int, experts: str, shared: str,
     )
 
 
+def _router_choice(cfg: dict) -> dict:
+    """What a config with the keys `n_group` and `topk_group` says of the
+    router's CHOICE: a learned score-correction bias (`topk_method:
+    noaux_tc`, or no such key: the family of `_window_trunk`), and the groups
+    the choice is limited to. Without the keys: nothing."""
+    if "n_group" not in cfg:
+        return {}
+    method = cfg.get("topk_method", "noaux_tc")
+    if method not in ("noaux_tc", "greedy", "group_limited_greedy"):
+        raise ValueError(f"unknown topk_method {method!r}")
+    groups = (int(cfg["n_group"]), int(cfg.get("topk_group", 1)))
+    return dict(moe_score_bias=method == "noaux_tc",
+                **({} if groups == (1, 1) else {"moe_groups": groups}))
+
+
 def _latent_trunk(cfg: dict, depth: int, held: int) -> dict:
     """The trunk options of the family whose config has `kv_lora_rank`:
     latent attention in every layer, `first_k_dense_replace` dense SwiGLU
     layers before the routed ones, a shared expert of `n_shared_experts`
-    times the routed width, sigmoid scores times `routed_scaling_factor`."""
-    dense = int(cfg["first_k_dense_replace"])
+    times the routed width, sigmoid scores times `routed_scaling_factor`.
+    With `rope_scaling` of type `yarn` the rotary table is YaRN's and the
+    softmax scale is multiplied by m^2, m = 0.1 mscale_all_dim ln(factor) + 1
+    (the table's own factor is m(mscale) / m(mscale_all_dim)); with
+    `index_topk` every layer has a lightning indexer of `index_n_heads` heads
+    of `index_head_dim` and attends that many positions a query; with
+    `n_group` the router chooses as `_router_choice` says."""
+    dense, rope_dim = int(cfg["first_k_dense_replace"]), cfg["qk_rope_head_dim"]
+    rotary = {"type": "default", "dim": rope_dim, "theta": cfg["rope_theta"]}
+    extra = {}
+    scaling = cfg.get("rope_scaling")
+    if scaling:
+        if scaling.get("type") != "yarn":
+            raise ValueError(f"unknown rope_scaling type {scaling.get('type')!r}")
+        m = lambda scale: 0.1 * float(scale) * math.log(float(scaling["factor"])) + 1.0
+        all_dim = m(scaling.get("mscale_all_dim", 0.0))
+        rotary = rotary_spec(
+            {**scaling, "rope_type": "yarn", "rope_theta": cfg["rope_theta"],
+             "attention_factor": m(scaling.get("mscale", 1.0)) / all_dim}, rope_dim)
+        extra["softmax_mult"] = all_dim * all_dim
+    if cfg.get("index_topk"):
+        extra.update(index_heads=int(cfg["index_n_heads"]), index_dim=int(cfg["index_head_dim"]),
+                     index_topk=int(cfg["index_topk"]))
+    if cfg.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError("this family's router scores by sigmoid (scoring_func)")
     return dict(
         attn_types=("latent",),
-        rotary_specs={"latent": {"type": "default", "dim": cfg["qk_rope_head_dim"],
-                                 "theta": cfg["rope_theta"]}},
+        rotary_specs={"latent": rotary},
         q_lora_rank=cfg["q_lora_rank"], kv_lora_rank=cfg["kv_lora_rank"],
-        qk_nope_dim=cfg["qk_nope_head_dim"], qk_rope_dim=cfg["qk_rope_head_dim"],
+        qk_nope_dim=cfg["qk_nope_head_dim"], qk_rope_dim=rope_dim,
         v_dim=cfg["v_head_dim"], sandwich_norm=bool(cfg.get("sandwich_norm", False)),
+        **extra, **_router_choice(cfg),
         **_routed_ff(cfg, tuple(i < dense for i in range(depth)), held,
                      "n_routed_experts", "n_shared_experts", "sigmoid"),
     )
@@ -112,17 +158,15 @@ def _window_trunk(cfg: dict, depth: int, held: int) -> dict:
     (the family's convention for a hybrid of local and global layers). A
     config with the keys `n_group` and `topk_group` has the router they
     belong to: the experts are chosen by score plus a learned
-    score-correction bias (one group: a group-limited choice is not built)."""
+    score-correction bias, among the best `topk_group` of `n_group` groups
+    (`_router_choice`)."""
     rope, dim_head = cfg["rope_parameters"], cfg["head_dim"]
-    biased = "n_group" in cfg
-    if biased and (cfg["n_group"], cfg.get("topk_group", 1)) != (1, 1):
-        raise ValueError("a group-limited choice of experts is not built (n_group, topk_group)")
     return dict(
         kv_heads=cfg["num_key_value_heads"], qk_norm=True, window=int(cfg["sliding_window"]),
         attn_types=tuple(LAYER_KINDS[k] for k in cfg["layer_types"][:depth]),
         rotary_specs=({"window": rotary_spec(rope, dim_head)} if "rope_type" in rope else
                       {LAYER_KINDS[k]: rotary_spec(spec, dim_head) for k, spec in rope.items()}),
-        moe_score_bias=biased,
+        **{"moe_score_bias": False, **_router_choice(cfg)},
         **_routed_ff(cfg, tuple(t == "dense" for t in cfg["mlp_layer_types"][:depth]), held,
                      "num_experts", "num_shared_experts", "softmax"),
     )
@@ -375,10 +419,19 @@ class CausalLM(nn.Module):
                            heads=self.heads, dim_head=self.dim_head, rotary_emb=False,
                            dtype=self.dtype, parent=None, **dict(self.trunk or {}))
 
+    def extend(self, tokens: jnp.ndarray, cache: dict) -> dict:
+        """The cache after further `tokens` [B, n] at the cache's index, each
+        attending what the cache holds and the chunk up to itself: a prefill
+        in chunks. A trunk of latent layers (`LatentAttention`: any index,
+        the rows in lockstep); the other kinds raise where they meet it. No
+        logits, as `prefill`."""
+        x = self.token_emb(tokens).astype(self.dtype)
+        return self.transformer(x, cache=cache)[1]
+
     def prefill(self, tokens: jnp.ndarray, cache: dict) -> dict:
         """The cache after `tokens` [B, n], which START each row's sequence
-        (positions 0..n-1; a prefill of further tokens against what a cache
-        holds is not built). No logits: the prompt's last token goes through
+        (positions 0..n-1; further tokens against what a cache holds go
+        through `extend`). No logits: the prompt's last token goes through
         `decode_step`, which gives them."""
         x = self.token_emb(tokens).astype(self.dtype)
         x, new = self.transformer(x, cache=cache, start=True)
@@ -440,7 +493,8 @@ def _jitted(builder, model, static_key):
     return _jitted_sampler(builder, model, static_key)
 
 
-def prefill_cached(model: CausalLM, variables, tokens: jnp.ndarray, cache: dict, row: int = 0):
+def prefill_cached(model: CausalLM, variables, tokens: jnp.ndarray, cache: dict, row: int = 0,
+                   chunk: Optional[int] = None):
     """`(cache, counts)`: `cache` (DONATED) with rows `row ..` holding
     `tokens` [R, n] from position 0, and the routed layers' counts over the
     prompts (as `generate_tokens_cached` gives them). The prompts go through
@@ -448,10 +502,43 @@ def prefill_cached(model: CausalLM, variables, tokens: jnp.ndarray, cache: dict,
     are then written into the sessions' (one dispatch), a recurrent layer's
     state and a window layer's ring both as the running one and as the
     snapshot a later turn restores (`decode_cache.snapshot`). Every layer's index is left where it was: the
-    caller sets it (`decode_cache.set_index`)."""
+    caller sets it (`decode_cache.set_index`).
+
+    With `chunk` the prompts go in CHUNKS of that many tokens
+    (`prefill_chunks`, then `place_rows`): a trunk of latent layers."""
+    if chunk is not None:
+        fresh, counts = prefill_chunks(model, variables, tokens, chunk)
+        return place_rows(model, cache, fresh, row), counts
     jitted = _jitted(_prefill_builder, model, ())
     with host_span("lm.prefill", program=jitted.name, rows=int(tokens.shape[0])):
         return jitted(variables, tokens, cache, jnp.asarray(row, jnp.int32))
+
+
+def prefill_chunks(model: CausalLM, variables, tokens: jnp.ndarray, chunk: int):
+    """`(fresh, counts)`: a cache of the prompts' own length holding `tokens`
+    [R, n] from position 0, filled `chunk` tokens a dispatch through
+    `CausalLM.extend` (host span `lm.prefill` each), every chunk attending
+    what the cache holds by then and itself; the routed layers' counts summed
+    over the chunks. A trunk of latent layers: any other raises here."""
+    if tuple(dict(model.trunk or {}).get("attn_types") or ()) != ("latent",):
+        raise NotImplementedError("a prefill in chunks takes a trunk of latent layers")
+    extend = _jitted(_extend_builder, model, ())
+    fresh, counts = model.init_cache(*tokens.shape), None
+    for at in range(0, tokens.shape[1], chunk):
+        with host_span("lm.prefill", program=extend.name, rows=int(tokens.shape[0]), at=at):
+            fresh, new = extend(variables, tokens[:, at:at + chunk], fresh)
+        counts = new if counts is None else jax.tree.map(jnp.add, counts, new)
+    return fresh, counts
+
+
+def place_rows(model: CausalLM, cache: dict, fresh: dict, row: int = 0) -> dict:
+    """`cache` (DONATED) with row r of `fresh` (a cache of prompts:
+    `prefill_chunks`) written into its row `row + r`; sessions that hold one
+    document each keep their own copy of it, a call a copy. The indices are
+    left where they were."""
+    rows = next(iter(fresh.values()))[decode_cache.ATTN][decode_cache.LATENT].shape[0]
+    at = row + jnp.arange(rows, dtype=jnp.int32)
+    return _jitted(_place_builder, model, ())(cache, fresh, at)
 
 
 def _rings(model, cache: dict) -> list:
@@ -474,6 +561,28 @@ def _prefill_builder(model, key):
 
 
 _prefill_builder._donate_argnums = (2,)
+
+
+def _extend_builder(model, key):
+    def lm_extend(variables, tokens, fresh):
+        fresh, aux = model.apply(variables, tokens, fresh, method=CausalLM.extend,
+                                 mutable=["stats"])
+        return fresh, _moe_counts(aux.get("stats", {}))
+
+    return lm_extend
+
+
+_extend_builder._donate_argnums = (2,)
+
+
+def _place_builder(model, key):
+    def lm_place(cache, fresh, rows):
+        return decode_cache.scatter_rows(cache, fresh, rows)
+
+    return lm_place
+
+
+_place_builder._donate_argnums = (0,)
 
 
 def generate_tokens_cached(model: CausalLM, variables, key: jax.Array, cache: dict,
@@ -499,6 +608,13 @@ def generate_tokens_cached(model: CausalLM, variables, key: jax.Array, cache: di
     [L] (held experts with at least one row), each summed over the steps;
     of a cache with recurrent layers also, as host numbers, `state_bytes`
     (running and kept), `kv_bytes` and `state_restored_bytes`, the turn's copy.
+    Of a trunk that selects the positions it attends (`index_topk`) over a
+    cache longer than that: `dsa_scored` and `dsa_selected` [depth] (the
+    positions a layer's indexer scored and the positions it attended, summed
+    over rows and steps) and, with `logit_rows`, `picks`, NOT summed: of those
+    rows `selected` [steps, depth, rows, index_topk] and `selected_count`
+    [steps, depth, rows] (what each layer attended at each step) and
+    `experts` [steps, rows, k] (the first routed layer's choice).
 
     A model whose cache keeps every row at its own position (`model.per_row`:
     the window-and-full trunk) runs `steps` VERIFY steps instead
@@ -561,8 +677,39 @@ def _moe_counts(stats: dict) -> dict:
     return out
 
 
+def _sown(layers: dict, key: str) -> list:
+    """What the trunk's layers sowed under `key`, in layer order."""
+    return [layers[n][key] for n in sorted((n for n in layers if key in layers[n]),
+                                           key=lambda n: int(n.rsplit("_", 1)[1]))]
+
+
+def _dsa_counts(stats: dict) -> dict:
+    """The indexed latent layers' counters of one step (`DSA_COUNTS`),
+    stacked over those layers in layer order; nothing where no layer selects."""
+    layers = stats.get("transformer", {})
+    return {k: jnp.stack(_sown(layers, k)).astype(jnp.int32)
+            for k in DSA_COUNTS if _sown(layers, k)}
+
+
+def _picks(picked: dict, rows: int) -> dict:
+    """What one step sowed into `picks`, of the first `rows` rows: the
+    positions each indexed layer selected (`selected` [layers, rows, k],
+    `selected_count` [layers, rows]) and the FIRST routed layer's choice of
+    experts (`experts` [rows, k])."""
+    layers = picked.get("transformer", {})
+    out = {k: jnp.stack([leaf[:rows] for leaf in _sown(layers, k)])
+           for k in ("selected", "selected_count") if _sown(layers, k)}
+    if _sown(layers, "experts"):
+        out["experts"] = _sown(layers, "experts")[0][:rows]
+    return out
+
+
 def _sampler_builder(model, key):
     steps, filter_thres, temperature, logit_rows = key
+    trunk = dict(model.trunk or {})
+    # a trunk that selects the positions it attends says, for the rows whose
+    # logits are kept, what it selected and what its first router chose
+    picking = ["picks"] if trunk.get("index_topk") and logit_rows else []
 
     def lm_sample(variables, rng, cache, forced, start):
         batch, n_forced = forced.shape
@@ -575,25 +722,33 @@ def _sampler_builder(model, key):
                 fed = lax.dynamic_index_in_dim(forced, jnp.minimum(i, n_forced - 1), 1, False)
                 token = jnp.where(i < n_forced, fed, prev)
             (logits, cache), aux = model.apply(
-                variables, token, cache, method=CausalLM.decode_step, mutable=["stats"])
+                variables, token, cache, method=CausalLM.decode_step,
+                mutable=["stats"] + picking)
             with jax.named_scope("rng_split"):
                 rng, sample_rng = jax.random.split(rng)
             with jax.named_scope("sample"):
                 filtered = top_k_filter(logits, thres=filter_thres)
                 new = gumbel_sample(sample_rng, filtered, temperature=temperature)
                 new = new.astype(jnp.int32)
-            counts = jax.tree.map(jnp.add, counts, _moe_counts(aux.get("stats", {})))
-            return (cache, new, rng, counts), (new, logits[:logit_rows])
+            stats = aux.get("stats", {})
+            counts = jax.tree.map(jnp.add, counts, {**_moe_counts(stats), **_dsa_counts(stats)})
+            ys = (new, logits[:logit_rows])
+            return (cache, new, rng, counts), ys + ((_picks(aux["picks"], logit_rows),)
+                                                     if picking else ())
 
-        trunk = dict(model.trunk or {})
         routed = sum(k == "swiglu_experts" for k in
                      trunk.get("ff_kinds") or (trunk.get("ff_kind"),) * model.depth)
         zeros = lambda *shape: jnp.zeros(shape, jnp.int32)
         counts = {} if not routed else {
             "moe_load": zeros(routed, trunk["experts_held"][1]),
             **{k: zeros(routed) for k in ("moe_rows", "moe_dropped", "moe_touched")}}
+        if trunk.get("index_topk", 0) and trunk["index_topk"] < decode_cache.max_len(cache):
+            counts.update({k: zeros(model.depth) for k in DSA_COUNTS})
         carry = (cache, jnp.zeros((batch,), jnp.int32), rng, counts)
-        (cache, _, _, counts), (tokens, logits) = lax.scan(step, carry, jnp.arange(steps))
+        (cache, _, _, counts), (tokens, logits, *picked) = lax.scan(
+            step, carry, jnp.arange(steps))
+        if picked:
+            counts = {**counts, "picks": picked[0]}
         return tokens.T, logits, counts, decode_cache.snapshot(cache, kept)
 
     return lm_sample

@@ -57,12 +57,34 @@ def _chunk(n_rows: int) -> int:
     return min(CHUNK_ROWS, n_rows)
 
 
+def choose(probs: jnp.ndarray, per_token: int, bias=None, groups: Tuple[int, int] = (1, 1)):
+    """`(top [T, k], experts [T, k])`: the experts a token is routed to, by
+    `probs + bias` where there is a bias, and their own scores `probs`.
+    `groups = (n, kept)` with n > 1 limits the choice: the E outputs stand
+    in n groups of E / n, a group's score is the sum of its two largest
+    biased scores, the `kept` best groups stay, and the k experts are the
+    largest biased scores within those (ties, everywhere: the lower index)."""
+    n_group, kept = groups
+    if n_group == 1:
+        if bias is None:
+            return lax.top_k(probs, per_token)
+        experts = lax.top_k(probs + bias, per_token)[1]
+        return jnp.take_along_axis(probs, experts, axis=-1), experts
+    biased = probs if bias is None else probs + bias
+    by_group = biased.reshape(biased.shape[0], n_group, -1)
+    best = lax.top_k(jnp.sum(lax.top_k(by_group, 2)[0], axis=-1), kept)[1]  # [T, kept]
+    stays = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)  # [T, n_group]
+    limited = jnp.where(stays[:, :, None], by_group, -jnp.inf).reshape(biased.shape)
+    experts = lax.top_k(limited, per_token)[1]
+    return jnp.take_along_axis(probs, experts, axis=-1), experts
+
+
 def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows: int,
-          bias=None):
+          bias=None, groups: Tuple[int, int] = (1, 1)):
     """From router scores [T, E] (a softmax's probabilities, or sigmoids)
     to the buffer's layout. `bias` [E]: the experts are CHOSEN by `probs +
     bias` (a score-correction bias); the weights are the chosen experts' own
-    scores all the same.
+    scores all the same. `groups`: a group-limited choice (`choose`).
 
     Returns a dict: `weights` [T, k] (the chosen experts' scores,
     renormalised), `experts` [T, k], `assign` [R] (the assignment, t * k +
@@ -74,11 +96,7 @@ def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows
     """
     first, count = held
     buffer_rows = min(buffer_rows, probs.shape[0] * per_token)
-    if bias is None:
-        top, experts = lax.top_k(probs, per_token)
-    else:
-        experts = lax.top_k(probs + bias, per_token)[1]
-        top = jnp.take_along_axis(probs, experts, axis=-1)
+    top, experts = choose(probs, per_token, bias, groups)
     weights = top / jnp.sum(top, axis=-1, keepdims=True)
     local = experts - first
     key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
@@ -362,6 +380,9 @@ class RoutedExperts(nn.Module):
     each; the chosen ones are renormalised and multiplied by `routed_scale`.
     `score_bias`: a float32 vector `router_bias` [experts_total] is added to
     the scores for the CHOICE alone (the weights stay the scores').
+    `groups`: `(groups the outputs stand in, groups a choice is limited to)`,
+    `choose`'s. Where the caller makes the collection `picks` mutable the
+    chosen experts [T, k] are sown there (`experts`): what a check reads.
     The matrices are stored in `param_dtype`, the router in float32. Sows
     `moe_load` [count], `moe_rows`, `moe_dropped` and `moe_moved` into the
     `stats` collection where the caller makes it mutable.
@@ -377,11 +398,13 @@ class RoutedExperts(nn.Module):
     routed_scale: float = 1.0
     shared_dim: int = 0  # 0: no shared expert
     score_bias: bool = False
+    groups: Tuple[int, int] = (1, 1)
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
     def setup(self):
         count = self.experts_held[1]
+        assert self.experts_total % self.groups[0] == 0, "groups of equal size"
         assert self.score in ("softmax", "sigmoid"), f"unknown router score {self.score!r}"
         matrix = lambda name, *shape: self.param(name, _fan_in, shape, self.param_dtype)
         self.router = self.param("router", _fan_in, (self.dim, self.experts_total))
@@ -413,9 +436,8 @@ class RoutedExperts(nn.Module):
     def choices(self, x: jnp.ndarray) -> jnp.ndarray:
         """[B, N, k] the experts the router chooses, largest first."""
         probs = self.router_probs(x.reshape(-1, x.shape[-1]))
-        if self.score_bias:
-            probs = probs + self.router_bias
-        return lax.top_k(probs, self.experts_per_token)[1].reshape(*x.shape[:-1], -1)
+        experts = choose(probs, self.experts_per_token, self.router_bias, tuple(self.groups))[1]
+        return experts.reshape(*x.shape[:-1], -1)
 
     def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
         h = x.reshape(-1, x.shape[-1])
@@ -423,6 +445,8 @@ class RoutedExperts(nn.Module):
             probs = self.router_probs(h)
         with jax.named_scope("moe_dispatch"):
             biased = {} if self.router_bias is None else {"bias": self.router_bias}
+            if tuple(self.groups) != (1, 1):
+                biased["groups"] = tuple(self.groups)
             r = route(probs, self.experts_per_token, tuple(self.experts_held),
                       self.buffer_rows, **biased)
             weights = r["weights"]
@@ -436,5 +460,8 @@ class RoutedExperts(nn.Module):
             y = y + self.shared(h)
         for name in ("load", "rows", "dropped", "moved"):
             self.sow("stats", f"moe_{name}", r[name], reduce_fn=lambda _, new: new,
+                     init_fn=lambda: None)
+        if self.is_mutable_collection("picks"):
+            self.sow("picks", "experts", r["experts"], reduce_fn=lambda _, new: new,
                      init_fn=lambda: None)
         return y.reshape(x.shape)

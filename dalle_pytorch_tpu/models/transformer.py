@@ -445,12 +445,21 @@ class Transformer(nn.Module):
     routed_scale: float = 1.0  # on the renormalised weights of the chosen
     shared_dim: int = 0  # width of the shared expert beside the routed ones (0: none)
     moe_score_bias: bool = False  # a score-correction bias in the router's choice
+    # (groups the router's outputs stand in, groups a token's choice is
+    # limited to): the best groups by their two best scores, then the experts
+    moe_groups: Tuple[int, int] = (1, 1)
     # "latent" among attn_types (models/attention.py:LatentAttention)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_dim: int = 0
+    softmax_mult: float = 1.0  # on the latent attention's 1/sqrt(nope + rope): YaRN's m^2
+    # a lightning indexer beside the latent attention (`index_topk` > 0):
+    # its heads, their size, and the positions a query attends
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
     # "linear" among attn_types (models/attention.py:GatedDeltaAttention)
     linear_heads: int = 0
     linear_key_dim: int = 0
@@ -541,6 +550,8 @@ class Transformer(nn.Module):
                     q_lora_rank=self.q_lora_rank, kv_lora_rank=self.kv_lora_rank,
                     qk_nope_dim=self.qk_nope_dim, qk_rope_dim=self.qk_rope_dim,
                     v_dim=self.v_dim, norm_eps=self.norm_eps, attn_impl=self.attn_impl,
+                    softmax_mult=self.softmax_mult, index_heads=self.index_heads,
+                    index_dim=self.index_dim, index_topk=self.index_topk,
                     dtype=self.dtype, param_dtype=self.param_dtype, name=f"attn_{attn_id}",
                 )
                 shared_attn_type[attn_id] = attn_type
@@ -592,6 +603,7 @@ class Transformer(nn.Module):
                     routed_scale=self.routed_scale,
                     shared_dim=self.shared_dim,
                     score_bias=self.moe_score_bias,
+                    groups=tuple(self.moe_groups),
                     dtype=self.dtype,
                     param_dtype=self.param_dtype,
                     name=f"ff_{ff_id}",
@@ -797,8 +809,8 @@ class Transformer(nn.Module):
             {"rotary_cs": self.rotary_cs[self.type_per_layer[i]]}
             if self.type_per_layer[i] in self.rotary_cs else {}
         )
-        if start and self.type_per_layer[i] not in ("latent", "linear"):
-            variant["start"] = True  # those two take any longer chunk for a start
+        if start and self.type_per_layer[i] != "linear":
+            variant["start"] = True  # a linear layer takes any longer chunk for a start
         h, attn_cache = self.attn_layers[i](
             h,
             key_mask=key_mask,
@@ -1065,6 +1077,7 @@ class Transformer(nn.Module):
                 self.cache_layout, self.depth, kinds=kinds, batch=batch, max_len=max_len,
                 heads=self.heads, dim_head=self.dim_head, dim=self.dim,
                 latent_dim=self.kv_lora_rank, rope_dim=self.qk_rope_dim,
+                index_dim=self.index_dim if self.index_topk else None,
                 linear_heads=self.linear_heads, key_dim=self.linear_key_dim,
                 value_dim=self.linear_value_dim, conv_taps=self.linear_conv, dtype=dtype,
             )

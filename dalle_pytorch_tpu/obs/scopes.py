@@ -40,6 +40,7 @@ COMPONENTS = (
     "mla_attend", "mla_proj", "moe_shared",
     "delta_step", "delta_proj", "delta_chunk", "state_restore",
     "window_attend", "global_attend",
+    "dsa_index_proj", "dsa_index", "dsa_select",
 )
 # `mtp`: what a multi-token module runs (models/lm.py:CausalLM.draft_step and
 # the draft's argmax), whatever its component: a phase, as `remat` is
@@ -63,6 +64,10 @@ LATENT_KERNEL = "decode_latent"
 
 # the gated delta rule's token step (`name=` in ops/delta_step.py)
 DELTA_KERNEL = "delta_step"
+
+# the lightning indexer's score over a row's live positions (`name=` in
+# ops/index_score.py)
+INDEX_KERNEL = "dsa_index"
 
 CONTAINERS = ("while", "conditional", "call")  # their bodies are events too
 
@@ -94,6 +99,14 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         # latents and the rotary. The latent's write is `cache_write`, above
         ("mla_attend", _E("mla_attend|" + LATENT_KERNEL)),
         ("mla_proj", _E("mla_proj")),
+        # a lightning indexer beside it (learned sparse attention): its three
+        # projections, the key's norm and the rotary; the score of every live
+        # position, with the kernel by name; the threshold by counting and the
+        # compaction to indices. The attend over the selected positions, with
+        # their fetch, is `mla_attend`, above; the key's write `cache_write`
+        ("dsa_index_proj", _E("dsa_index_proj")),
+        ("dsa_index", _E(INDEX_KERNEL)),
+        ("dsa_select", _E("dsa_select")),
         # a gated delta-rule layer (models/attention.py:GatedDeltaAttention),
         # before `attn_proj`, whose `to_out` it also has: the token step's
         # state update, with the kernel by name; the chunked prefill form;
@@ -155,6 +168,8 @@ def component(op_name: Optional[str], opcode: str = "",
         found = "mla_attend"
     elif base == DELTA_KERNEL:
         found = "delta_step"
+    elif base == INDEX_KERNEL:
+        found = "dsa_index"
     elif not op_name or opcode in CONTAINERS:  # loop control has no owner
         return "unscoped", "fwd"
     else:

@@ -2,7 +2,10 @@
 """Generate token ids with a causal language model (`models/lm.py:CausalLM`)
 whose trunk decodes through a cache. Three families of published configs do:
 latent attention (a compressed K/V cache), leading dense layers, a shared
-expert beside sigmoid-routed ones of which this process holds a share; gated
+expert beside sigmoid-routed ones of which this process holds a share, and
+with `index_topk` a lightning indexer in every layer that selects the cached
+positions a query attends (`--config benchmark/configs/deepseek-v32-exp-ep16.json`;
+`--prefill_chunk` puts a long prompt in through the chunked prefill); gated
 delta-rule linear layers among full ones (a recurrent state beside K/V in one
 cache, `--config benchmark/configs/olmo-hybrid-7b-pp2.json`); and window and
 full layers over grouped K/V heads (window rings beside full K/V, every row
@@ -67,6 +70,9 @@ def parse_args(argv=None):
                    help="the share of the vocabulary a step drops; 1.0 is greedy")
     p.add_argument("--temperature", type=float, default=1.0)
     p.add_argument("--prefill_rows", type=int, default=4, help="prompts a prefill dispatch takes")
+    p.add_argument("--prefill_chunk", type=int, default=None,
+                   help="tokens of a prompt a prefill dispatch takes: a trunk of latent "
+                        "layers alone (any other is refused); the whole prompt where left out")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--out", default=None, help="write {tokens, ...} here as JSON (else stdout)")
     return p.parse_args(argv)
@@ -106,8 +112,9 @@ def build_model(args, cfg: dict, program: dict, prompt_len: int, rows: int):
     per_step = 1 + int(cfg.get("num_nextn_predict_layers", 0))
     if "moe_buffer_rows" not in program and "num_experts_per_tok" in cfg:
         # every assignment a prefill dispatch or a step can make: no routing drops a token
+        dispatch = max(min(prompt_len - 1, args.prefill_chunk or prompt_len), 1)  # tokens a row
         program["moe_buffer_rows"] = cfg["num_experts_per_tok"] * max(
-            min(args.prefill_rows, rows) * max(prompt_len - 1, 1), rows * per_step)
+            min(args.prefill_rows, rows) * dispatch, rows * per_step)
     mdl = CausalLM.from_config(cfg, prompt_len + per_step * args.max_new_tokens, **program)
     return mdl, {"config": args.config or "DEFAULT_CONFIG", "set": args.set, **program}
 
@@ -155,7 +162,8 @@ def main(argv=None):
     if prompt_len > 1:
         for r in range(0, rows, args.prefill_rows):
             cache, counts = prefill_cached(
-                mdl, variables, jnp.asarray(prompts[r:r + args.prefill_rows, :-1]), cache, r)
+                mdl, variables, jnp.asarray(prompts[r:r + args.prefill_rows, :-1]), cache, r,
+                chunk=args.prefill_chunk)
             dropped += int(np.sum(counts.get("moe_dropped", 0)))
     tokens, _, counts, _ = generate_tokens_cached(
         mdl, variables, key, cache, jnp.asarray(prompts[:, -1:]), args.max_new_tokens,
@@ -163,6 +171,10 @@ def main(argv=None):
     dropped += int(np.sum(counts.get("moe_dropped", 0)))
     result = {"tokens": np.asarray(tokens)[:, :args.max_new_tokens].tolist(), "model": options,
               "moe_dropped": dropped}
+    if "dsa_selected" in counts:  # learned sparse attention: positions a row-step a layer
+        per = rows * args.max_new_tokens
+        result.update(scored_per_row_step=(np.asarray(counts["dsa_scored"]) / per).tolist(),
+                      selected_per_row_step=(np.asarray(counts["dsa_selected"]) / per).tolist())
     if "verify_steps" in counts:  # verify steps: a row emitted at least one token a step
         result.update(verify_steps=counts["verify_steps"],
                       accepted=np.asarray(counts["accepted"]).tolist())

@@ -1,12 +1,15 @@
 """sha256 of the lowered text of the rehearsal language-model programs of one
-tree (`_tiny-pangu` and `_tiny-olmo`: both samplers and the prefill; `_tiny-mellum`:
-the loss and its gradient), beside `lowered_digests.py`'s DALL-E programs.
+tree (`_tiny-pangu`, `_tiny-olmo`, `_tiny-kexaone`, `_tiny-deepseek-v32`: both
+samplers and the prefill, which of the last is a chunk and the copies;
+`_tiny-mellum`: the loss and its gradient), beside `lowered_digests.py`'s
+DALL-E programs. A cell whose workload file the tree lacks is left out.
 
-With `--real`, instead: the REAL routed cells' programs lowered for a described
-v5e with their kernels Mosaic's (`pangu.decode.8k`: both samplers;
-`mellum2.train.8k`: the loss and its gradient), which a change to the grouped
-products' tiles at OTHER shapes has to leave as they were (the `_tiny` buffers
-are re-tiled by such a change: PR 38). 10 s a tree.
+With `--real`, instead: the REAL language-model cells' programs lowered for a
+described v5e with their kernels Mosaic's (`pangu.decode.8k`,
+`olmohybrid.decode.512`, `kexaone.decode.16k`, `deepseek32.decode.32k`: both
+samplers and the prefill; `mellum2.train.8k`: the loss and its gradient),
+which a change to shared code has to leave as they were (the `_tiny` buffers
+are re-tiled by a change to the grouped products' tiles: PR 38). 40 s a tree.
 
 usage: python scripts/lowered_digests_lm.py <tree> [--real] > out.json   (parent, then `.`; compare)
 """
@@ -25,11 +28,14 @@ shape = lambda tree_: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dty
 if real:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from dalle_pytorch_tpu.ops import grouped_matmul, latent_decode, pallas_attention
+    import importlib
     on = jax.sharding.SingleDeviceSharding(
         topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
-    for module in (grouped_matmul, latent_decode, pallas_attention):
-        module._use_interpret = lambda: False
+    for name in ("grouped_matmul", "latent_decode", "pallas_attention", "delta_step", "index_score"):
+        try:
+            importlib.import_module(f"dalle_pytorch_tpu.ops.{name}")._use_interpret = lambda: False
+        except ImportError:  # a tree older than the kernel
+            pass
     # a kernel's payload carries the source lines it was traced from: dropped, so
     # that lines added above a kernel's in its file do not read as another kernel
     from jax._src import tpu_custom_call
@@ -40,20 +46,35 @@ if real:
             PassManager.parse("builtin.module(strip-debuginfo)").run(module.operation)
         return serialize(module, **kw)
     tpu_custom_call._lower_mosaic_module_to_asm = without_locations
-for cell in ("pangu.decode.8k",) if real else ("_tiny.generate_lm", "_tiny.generate_hybrid"):
+cells = (("pangu.decode.8k", "olmohybrid.decode.512", "kexaone.decode.16k", "deepseek32.decode.32k")
+         if real else ("_tiny.generate_lm", "_tiny.generate_hybrid", "_tiny.generate_kexaone",
+                       "_tiny.generate_deepseek_v32"))
+for cell in cells:
+    if not os.path.exists(f"benchmark/workloads/{cell}.json"):
+        continue  # a tree older than the cell
     w = json.load(open(f"benchmark/workloads/{cell}.json")); job = w["job"]
     cfg = json.load(open(f"benchmark/configs/{w['config']}.json"))
-    doc, steps, b = job["document_tokens"], job["question_tokens"] + job["answer_tokens"], job["sessions"]
-    mdl = lm.CausalLM.from_config(cfg, doc + steps, **job.get("model", {}))
+    doc, b = job["document_tokens"], job["sessions"]
+    verify = w["kind"] == "generate_kexaone"  # steps of two positions, every row at its own
+    steps = job["steps"] if verify else job["question_tokens"] + job["answer_tokens"]
+    block = job.get("cache_block", 1)
+    length = -(-(doc + (2 if verify else 1) * steps) // block) * block
+    mdl = lm.CausalLM.from_config(cfg, length, **job.get("model", {}))
     variables = shape(jax.eval_shape(mdl.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
     cache = shape(jax.eval_shape(lambda: mdl.init_cache(b)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=on)
     for thres in (1.0, 0.9):
-        f = lm._sampler_builder(mdl, (steps, thres, 1.0, 2))
+        f = (lm._verify_sampler_builder(mdl, (steps, thres, 1.0, 2, None)) if verify
+             else lm._sampler_builder(mdl, (steps, thres, 1.0, 2)))
         digest(f"{cell}/sampler/{thres}", jax.jit(f, donate_argnums=(2,)).lower(
             variables, jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=on), cache,
-            i32(b, job["question_tokens"]), i32()))
-    if real:  # its prefill's buffer holds 32,768 rows: a tile of 512 on both trees
+            i32(b, job["question_tokens"]), i32(b) if verify else i32()))
+    if "prefill_chunk" in job:  # a document in chunks, then its copies
+        fresh = shape(jax.eval_shape(lambda: mdl.init_cache(1, doc)))
+        digest(f"{cell}/extend", jax.jit(lm._extend_builder(mdl, ()), donate_argnums=(2,)).lower(
+            variables, i32(1, job["prefill_chunk"]), fresh))
+        digest(f"{cell}/place", jax.jit(lm._place_builder(mdl, ()), donate_argnums=(0,)).lower(
+            cache, fresh, i32(1)))
         continue
     f = lm._prefill_builder(mdl, ())
     digest(f"{cell}/prefill", jax.jit(f, donate_argnums=(2,)).lower(

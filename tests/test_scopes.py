@@ -188,6 +188,8 @@ def program_texts():
         avars, x, cache).compile().as_text()
     return {"train": train, "sample": sample, "quant": quant, "lm": _lm_step_text(),
             "lm_sample": _lm_sample_text(), "verify_sample": _verify_sample_text(),
+            "dsa_sample": _lm_sample_text(index_heads=2, index_dim=16, index_topk=4,
+                                          moe_groups=(2, 1)),
             **_hybrid_texts()}
 
 
@@ -260,9 +262,11 @@ def _verify_sample_text() -> str:
         jnp.full((2,), 8, jnp.int32)).compile().as_text()
 
 
-def _lm_sample_text() -> str:
+def _lm_sample_text(**indexed) -> str:
     """Compiled text of a tiny language-model sampler: latent attention over
-    its cache, a dense layer and a routed one with a shared expert."""
+    its cache, a dense layer and a routed one with a shared expert; with
+    `indexed`, a lightning indexer beside the attention that selects 4 of the
+    16 cached positions."""
     from dalle_pytorch_tpu.models import lm
 
     rope = {"type": "default", "dim": 8, "theta": 1e4}
@@ -273,7 +277,7 @@ def _lm_sample_text() -> str:
                    kv_lora_rank=8, qk_nope_dim=8, qk_rope_dim=8, v_dim=8,
                    ff_kinds=("swiglu", "swiglu_experts"), ff_dim=48, experts_total=4,
                    experts_per_token=2, experts_held=(0, 2), expert_dim=16, moe_buffer_rows=64,
-                   moe_score="sigmoid", routed_scale=2.5, shared_dim=16))
+                   moe_score="sigmoid", routed_scale=2.5, shared_dim=16, **indexed))
     variables = jax.jit(mdl.init)(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     sampler = jax.jit(lm._sampler_builder(mdl, (4, 0.9, 1.0, 1)), donate_argnums=(2,))
     return sampler.lower(

@@ -831,3 +831,130 @@ def test_prefill_of_one_document_compiles_at_the_cells_size(one_chip, monkeypatc
         print(f"\nkexaone.decode.16k prefill: planned {plan:.2f} GiB on the described v5e")
     assert "tpu_custom_call" in compiled.as_text()
     assert plan < 15.55
+
+
+def _deepseek_v32(one_chip, monkeypatch):
+    """(model, its variables' shapes on the described chip, the job) of
+    `deepseek32.decode.32k`: the cell's own sizes."""
+    import json
+    from pathlib import Path
+
+    from dalle_pytorch_tpu.models import lm
+    from dalle_pytorch_tpu.ops import grouped_matmul, index_score, latent_decode, pallas_attention
+
+    for module in (grouped_matmul, index_score, latent_decode, pallas_attention):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    root = Path(__file__).resolve().parent.parent / "benchmark"
+    cfg = json.loads((root / "configs/deepseek-v32-exp-ep16.json").read_text())
+    job = json.loads((root / "workloads/deepseek32.decode.32k.json").read_text())["job"]
+    assert (job["sessions"], job["sessions_per_document"], job["document_tokens"]) == (16, 4, 32768)
+    assert (job["question_tokens"], job["answer_tokens"], job["cache_block"]) == (32, 256, 1024)
+    mdl = lm.CausalLM.from_config(cfg, 33792, **job["model"])  # 32,768 + 288 in blocks of 1,024
+    on = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    variables = on(jax.eval_shape(mdl.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    return mdl, variables, job, on
+
+
+def test_index_score_kernel_compiles(one_chip, monkeypatch):
+    """`dsa_index` at `deepseek32.decode.32k`'s shapes: 16 rows of 64 index
+    heads of 128 against 33,792 cached keys, in blocks of 8,192 positions of
+    which the last overhangs the cache."""
+    from dalle_pytorch_tpu.ops import index_score as ix, pallas_attention
+
+    for module in (ix, pallas_attention):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    weights = jax.ShapeDtypeStruct((16, 64), jnp.float32, sharding=one_chip)
+    text = _compile(ix.index_scores, bf16(16, 64, 128), weights, bf16(16, 33792, 128),
+                    _i32(one_chip, 16)).as_text()
+    assert re.search(r"%dsa_index[.\d]* = f32\[16,1,40960\]", text)
+    assert ix.BLOCK_POSITIONS == 8192
+
+
+def test_selection_and_sparse_attend_compile_without_a_sort(one_chip, monkeypatch):
+    """The selection of 2,048 of 33,792 scores a row (threshold by counting,
+    compaction by two small products) holds no sort, gather or scatter; the
+    sparse attend is two gathers of the selected positions (the fetch) and
+    the dense kernel `decode_latent` over them."""
+    from dalle_pytorch_tpu.ops import index_select as sel, latent_decode, pallas_attention
+    from dalle_pytorch_tpu.ops import sparse_latent_decode as sp
+
+    for module in (latent_decode, pallas_attention):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    shape = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def select(scores, lengths):
+        mask, count = sel.selected_mask(scores, lengths, 2048)
+        return sel.selected_indices(mask, 2048), count
+
+    text = jax.jit(select).lower(shape(jnp.float32, 16, 33792), _i32(one_chip, 16)).compile().as_text()
+    assert not re.search(r" (sort|gather|scatter)\(", text)
+    bf16 = functools.partial(shape, jnp.bfloat16)
+    text = _compile(functools.partial(sp.sparse_latent_decode_attention, sm_scale=0.135),
+                    bf16(16, 128, 512), bf16(16, 128, 64), bf16(16, 33792, 512),
+                    bf16(16, 64, 33792), shape(jnp.int32, 16, 2048), _i32(one_chip, 16)).as_text()
+    assert len(re.findall(r" gather\(", text)) == 2
+    assert re.search(r"%decode_latent[.\d]* = bf16\[16,128,512\]", text)
+
+
+def test_sparse_token_loop_compiles_at_the_cells_size(one_chip, monkeypatch, capsys):
+    """The token loop of `deepseek32.decode.32k` (16 sessions x 33,792
+    positions, 288 steps, top-k 0.9): every layer's three cache leaves ride
+    the loop's carry and no latent or index-key leaf is copied (the compiler
+    keeps the rotary keys positions-major through the loop, for the fetch: a
+    copy in and one out a DISPATCH); a step holds an index-score kernel and a
+    dense-kernel call a layer and three grouped products a routed layer; the
+    plan is the weights, the cache and 1.4 GB beside them."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl, variables, job, on = _deepseek_v32(one_chip, monkeypatch)
+    cache = on(jax.eval_shape(lambda: mdl.init_cache(16)))
+    assert {k: v.shape for k, v in cache["layer_4"]["attn"].items()} == {
+        "latent": (16, 33792, 512), "rope": (16, 64, 33792), "index_k": (16, 33792, 128),
+        "index": ()}
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(lm._sampler_builder(mdl, (288, 0.9, 1.0, 2)), donate_argnums=(2,)).lower(
+        variables, key, cache, i32(16, 32), i32()).compile()
+    text, plan = compiled.as_text(), _device_bytes(compiled) / 2**30
+    with capsys.disabled():
+        print(f"\ndeepseek32.decode.32k sampler: planned {plan:.2f} GiB on the described v5e")
+    assert text.count("tpu_custom_call") == 5 + 5 + 12
+    assert not re.search(r"= bf16\[16,33792,(512|128)\]\S* copy\(", text)
+    body = text[text.index("region_0"):]
+    assert not re.search(r"= bf16\[16,64,33792\]\S* copy\(", body[:body.index("\n}\n")])
+    assert 13.0 < plan < 14.0
+
+
+def test_prefill_chunk_compiles_with_the_cache_at_full_size(one_chip, monkeypatch, capsys):
+    """One chunk of 1,024 queries of a document's prefill against a fresh
+    cache of 32,768 positions (`lm_extend`): index scores and attention walk
+    blocks of 1,024 cached positions up to the chunk's end, so no array of
+    queries x positions x heads is ever whole; and the copy of a prefilled
+    document to a session (`lm_place`) writes the sessions' cache in place."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl, variables, job, on = _deepseek_v32(one_chip, monkeypatch)
+    fresh = on(jax.eval_shape(lambda: mdl.init_cache(1, 32768)))
+    tokens = jax.ShapeDtypeStruct((1, job["prefill_chunk"]), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lm._extend_builder(mdl, ()), donate_argnums=(2,), keep_unused=True).lower(
+        variables, tokens, fresh).compile()
+    plan, temp = _device_bytes(compiled) / 2**30, compiled.memory_analysis().temp_size_in_bytes
+    cache = on(jax.eval_shape(lambda: mdl.init_cache(16)))
+    rows = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    placed = jax.jit(lm._place_builder(mdl, ()), donate_argnums=(0,)).lower(
+        cache, fresh, rows).compile()
+    # the benchmark loop's set-up check of a document's four copies, beside all that is held
+    from benchmark.loops.generate_deepseek_v32 import _copies_off
+
+    rows = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    checked = _copies_off().lower(cache, fresh, rows).compile()
+    with capsys.disabled():
+        print(f"\ndeepseek32.decode.32k prefill chunk: planned {plan:.2f} GiB "
+              f"({temp / 2**30:.2f} of temporaries) on the described v5e; a copy: "
+              f"{placed.memory_analysis().temp_size_in_bytes / 2**20:.0f} MiB of temporaries; "
+              f"the copies' check: {checked.memory_analysis().temp_size_in_bytes / 2**20:.0f} MiB")
+    assert temp < 2 * 2**30 and plan < 11.5
+    assert placed.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    assert checked.memory_analysis().temp_size_in_bytes < 512 * 2**20
