@@ -41,6 +41,7 @@ import flax.linen as nn
 from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.ops.attention_core import dense_attention
 from dalle_pytorch_tpu.ops.delta_step import delta_step
+from dalle_pytorch_tpu.ops.grouped_decode import grouped_decode_attention
 from dalle_pytorch_tpu.ops.index_score import index_scores
 from dalle_pytorch_tpu.ops.index_select import selected_indices, selected_mask
 from dalle_pytorch_tpu.ops.latent_decode import latent_decode_attention
@@ -314,11 +315,12 @@ class Attention(nn.Module):
         A STEP (not `start`; n <= `step_positions`) is written from each
         row's own index on, a full layer's along its lanes, a window layer's
         into its ring, and attended against the cache, each query under its
-        own mask by true position: causal for a full layer (scope
-        `global_attend`), `0 <= t - p < window` over the positions the ring's
-        slots hold for a window layer (`window_attend`): XLA's grouped
-        product over the whole leaf, the group's heads and the step's
-        positions as one operand's rows. A chunk that STARTS the rows'
+        own mask by true position, the group's heads and the step's positions
+        as one operand's rows: causal for a full layer (scope `global_attend`:
+        the kernel `decode_grouped` over each row's live positions,
+        ops/grouped_decode.py), `0 <= t - p < window` over the positions the
+        ring's slots hold for a window layer (`window_attend`: XLA's grouped
+        product over the ring). A chunk that STARTS the rows'
         sequences (`start`: a prefill) takes positions 0..n-1 whatever the
         index was, and attends itself (`out` None: the caller's uncached
         path, flash kernels included). A longer chunk onto what a cache holds
@@ -339,18 +341,19 @@ class Attention(nn.Module):
         new_cache = {**cache, **written, "index": index + n}
         ck, cv = written["k"], written["v"]
         hkv, length = ck.shape[1], ck.shape[2]
-        at = index[:, None] + jnp.arange(n, dtype=index.dtype)  # [B, n]
-        if self.window is not None:
-            held = decode_cache.ring_positions(index + n - 1, length)[:, None]  # [B, 1, ring]
-            gap = at[:, :, None] - held
-            mask = (gap >= 0) & (gap < self.window) & (held >= 0)
-        else:
-            mask = jnp.arange(length, dtype=index.dtype)[None, None] <= at[:, :, None]
         group = h // hkv
+        q = q.reshape(b, hkv, group * n, dh)
+        if self.window is None:
+            with jax.named_scope("global_attend"):
+                out = grouped_decode_attention(q, ck, cv, new_cache["index"], n=n)
+            return out.reshape(b, h, n, dh), new_cache
+        at = index[:, None] + jnp.arange(n, dtype=index.dtype)  # [B, n]
+        held = decode_cache.ring_positions(index + n - 1, length)[:, None]  # [B, 1, ring]
+        gap = at[:, :, None] - held
+        mask = (gap >= 0) & (gap < self.window) & (held >= 0)
         mask = jnp.broadcast_to(mask[:, None, None], (b, 1, group, n, length))
-        with jax.named_scope("global_attend" if self.window is None else "window_attend"):
-            out = dense_attention(q.reshape(b, hkv, group * n, dh), ck, cv,
-                                  mask=mask.reshape(b, 1, group * n, length))
+        with jax.named_scope("window_attend"):
+            out = dense_attention(q, ck, cv, mask=mask.reshape(b, 1, group * n, length))
         return out.reshape(b, h, n, dh), new_cache
 
     def _use_flash_decode(

@@ -69,6 +69,10 @@ DELTA_KERNEL = "delta_step"
 # ops/index_score.py)
 INDEX_KERNEL = "dsa_index"
 
+# a full layer's cached step of grouped K/V heads (`name=` in
+# ops/grouped_decode.py)
+GROUPED_KERNEL = "decode_grouped"
+
 CONTAINERS = ("while", "conditional", "call")  # their bodies are events too
 
 
@@ -124,9 +128,10 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         ("unscoped", r"(^|/)(scan_stack|cached_scan)/while/(body|cond)/[\w\-]+$"),
         # a cached step of grouped K/V heads (models/attention.py:
         # Attention._cached_grouped): scores, softmax and weights x values
-        # over a window layer's ring, or over a full layer's whole K/V
+        # over a window layer's ring, or over a full layer's live K/V, with
+        # the kernel by name
         ("window_attend", _E("window_attend")),
-        ("global_attend", _E("global_attend")),
+        ("global_attend", _E("global_attend|" + GROUPED_KERNEL)),
         ("attend", _E("attend")),
         ("attn_proj", _E("to_qkv|to_out")),
         # a routed layer's three parts (models/moe.py), before `ff`, whose
@@ -170,6 +175,8 @@ def component(op_name: Optional[str], opcode: str = "",
         found = "delta_step"
     elif base == INDEX_KERNEL:
         found = "dsa_index"
+    elif base == GROUPED_KERNEL:
+        found = "global_attend"
     elif not op_name or opcode in CONTAINERS:  # loop control has no owner
         return "unscoped", "fwd"
     else:

@@ -31,7 +31,8 @@ if real:
     import importlib
     on = jax.sharding.SingleDeviceSharding(
         topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
-    for name in ("grouped_matmul", "latent_decode", "pallas_attention", "delta_step", "index_score"):
+    for name in ("grouped_matmul", "latent_decode", "pallas_attention", "delta_step", "index_score",
+                 "grouped_decode"):
         try:
             importlib.import_module(f"dalle_pytorch_tpu.ops.{name}")._use_interpret = lambda: False
         except ImportError:  # a tree older than the kernel

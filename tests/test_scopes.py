@@ -124,9 +124,34 @@ def test_a_trace_names_an_operation_by_its_whole_line():
      ("ff", "mtp")),
     ("jit(lm_sample)/while/body/mtp/verify/select_n", "fusion", "f.12", ("sample", "mtp")),
     (None, "custom-call", "%gmm_fwd.3", ("moe_experts", "fwd")),
+    # a full layer's kernel: by its instruction name where the metadata is gone,
+    # under the scope it is called from, and in the module's phase by its path
+    (None, "custom-call", "%decode_grouped.7", ("global_attend", "fwd")),
+    ("jit(lm_sample)/while/body/closed_call/transformer/attn_3/global_attend/decode_grouped",
+     "custom-call", "decode_grouped.30", ("global_attend", "fwd")),
+    ("jit(lm_sample)/while/body/closed_call/mtp/mtp_block/attn_0/global_attend/decode_grouped",
+     "custom-call", "decode_grouped.31", ("global_attend", "mtp")),
+    ("jit(lm_sample)/mtp/mtp_block/attn_0/decode_grouped", "fusion", "f.13",
+     ("global_attend", "mtp")),
 ])
 def test_component_rules(op_name, opcode, name, want):
     assert scopes.component(op_name, opcode, name) == want
+
+
+def test_the_grouped_kernels_rule_stands_before_attend_and_after_the_kernels():
+    """`global_attend` (with `decode_grouped` by name) is asked before the
+    plain `attend`, which its name contains as a word of a path would, and
+    after `attn_kernel`, whose seven names it is not among: a flash kernel
+    under a `global_attend` scope would still be the kernel's."""
+    order = [name for name, _ in scopes.RULES]
+    assert order.index("attn_kernel") < order.index("window_attend") < order.index(
+        "global_attend") < order.index("attend") < order.index("attn_proj")
+    assert scopes.GROUPED_KERNEL == "decode_grouped" and scopes.GROUPED_KERNEL not in scopes.KERNELS
+    assert dict(scopes.RULES)["global_attend"].search("a/decode_grouped/b")
+    assert scopes.component("jit(f)/attn_3/global_attend/decode_slots/x", "custom-call",
+                            "decode_slots.2") == ("attn_kernel", "fwd")
+    assert scopes.component("jit(f)/attn_3/attend/dot_general", "fusion", "f.1") == (
+        "attend", "fwd")
 
 
 # ------------------------------------------------- the programs' own text

@@ -769,9 +769,9 @@ def _kexaone(one_chip, monkeypatch, sessions=24, steps=288, doc=16384):
     from pathlib import Path
 
     from dalle_pytorch_tpu.models import lm
-    from dalle_pytorch_tpu.ops import grouped_matmul, pallas_attention
+    from dalle_pytorch_tpu.ops import grouped_decode, grouped_matmul, pallas_attention
 
-    for module in (grouped_matmul, pallas_attention):
+    for module in (grouped_decode, grouped_matmul, pallas_attention):
         monkeypatch.setattr(module, "_use_interpret", lambda: False)
     root = Path(__file__).resolve().parent.parent / "benchmark"
     cfg = json.loads((root / "configs/k-exaone-236b-ep8.json").read_text())
@@ -790,11 +790,15 @@ def test_verify_step_sampler_compiles_at_the_cells_size(one_chip, monkeypatch, c
     """The token loop of `kexaone.decode.16k` (24 sessions x 16,384 + 576
     positions, 288 verify steps of two positions, the module drafting): the
     two full K/V layers ride the loop's carry in place (no copy of a leaf in
-    the body; a step's K/V go in by one named `dynamic-update-slice` a row),
-    every routed block's three grouped products are Mosaic's, and the plan is
-    the weights, the cache and under half a GB beside them."""
+    the body, though a kernel reads them; a step's K/V go in by one named
+    `dynamic-update-slice` a row), every routed block's three grouped
+    products and every full layer's attention (`decode_grouped`, 16 query
+    rows a K/V head) are Mosaic's, and the plan is the weights, the cache and
+    under half a GB beside them."""
     from dalle_pytorch_tpu.models import lm
+    from dalle_pytorch_tpu.ops import grouped_decode
 
+    grouped_decode.forget()
     mdl, variables, cache = _kexaone(one_chip, monkeypatch)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
@@ -804,12 +808,49 @@ def test_verify_step_sampler_compiles_at_the_cells_size(one_chip, monkeypatch, c
     text, plan = compiled.as_text(), _device_bytes(compiled) / 1e9
     with capsys.disabled():
         print(f"\nkexaone.decode.16k sampler: planned {plan:.2f} GB on the described v5e")
-    assert text.count("tpu_custom_call") == 18  # 3 x (4 + 1) a step, 3 before the loop
+    # 3 x (4 + 1) products and 2 attentions a step, 3 + 1 before the loop
+    assert text.count("tpu_custom_call") == 21
+    # layer 3's and the module's at a step's two positions; the module's first
+    # pass, before the loop, at one
+    assert len(re.findall(r"%decode_grouped[.\d]* = bf16\[24,8,16,128\]", text)) == 2
+    assert len(re.findall(r"%decode_grouped[.\d]* = bf16\[24,8,8,128\]", text)) == 1
+    assert grouped_decode.calls == {(24, 8, 16, 16960): 2432, (24, 8, 8, 16960): 2432}
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert all(("/global_attend/" in line) == ("%decode_grouped" in line.split("=")[0])
+               for line in kernels)
+    assert not any("/window_attend/" in line for line in kernels)  # the rings stay XLA's
     assert not re.search(r"= bf16\[24,8,16960,128\]\S* copy\(", text)
     writes = re.findall(r"= bf16\[24,8,16960,128\]\S* dynamic-update-slice\(.*", text)
     assert len(writes) == 144  # 24 rows x (k, v) x (layer 3 + the module's + its first pass)
     assert all("/cache_write/" in w for w in writes)
     assert 12.5 < plan < 13.5
+
+
+@pytest.mark.parametrize("query_rows,n", [(16, 2), (8, 1)], ids=["verify", "token"])
+def test_grouped_decode_kernel_compiles_at_the_cells_shape(one_chip, monkeypatch, query_rows, n):
+    """`decode_grouped` alone at a full layer's step of `kexaone.decode.16k`
+    (24 rows x 8 K/V heads x 16,960 cached positions of 128, 8 query heads a
+    K/V head at two positions a step, or at one): Mosaic takes the leaves as
+    the cache holds them (no copy of either), 7 blocks of 2,432 positions of
+    which the last overhangs the leaf, and the blocks' two buffers each of K
+    and V with a block's float32 scores fit the scoped VMEM limit (16 MiB)
+    with most of it to spare."""
+    from dalle_pytorch_tpu.ops import grouped_decode as gd
+
+    monkeypatch.setattr(gd, "_use_interpret", lambda: False)
+    gd.forget()
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    compiled = _compile(functools.partial(gd.grouped_decode_attention, n=n),
+                        s(24, 8, query_rows, 128), s(24, 8, 16960, 128), s(24, 8, 16960, 128),
+                        _i32(one_chip, 24))
+    text = compiled.as_text()
+    assert re.search(rf"%decode_grouped[.\d]* = bf16\[24,8,{query_rows},128\]", text)
+    assert not re.search(r"= bf16\[24,8,16960,128\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    block = gd.calls[(24, 8, query_rows, 16960)]
+    assert (gd.BLOCK_POSITIONS, block, -(-16960 // block)) == (2560, 2432, 7)
+    # K and V, two buffers each, and a block's scores and weights in float32
+    assert 2 * 2 * block * 128 * 2 + 2 * query_rows * block * 4 < 4 * 2**20
 
 
 def test_prefill_of_one_document_compiles_at_the_cells_size(one_chip, monkeypatch, capsys):
