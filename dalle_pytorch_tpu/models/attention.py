@@ -45,7 +45,10 @@ from dalle_pytorch_tpu.ops.grouped_decode import grouped_decode_attention
 from dalle_pytorch_tpu.ops.index_score import index_scores
 from dalle_pytorch_tpu.ops.index_select import selected_indices, selected_mask
 from dalle_pytorch_tpu.ops.latent_decode import latent_decode_attention
-from dalle_pytorch_tpu.ops.sparse_latent_decode import sparse_latent_decode_attention
+from dalle_pytorch_tpu.ops.sparse_latent_decode import (
+    sparse_latent_decode_attention,
+    split_rows,
+)
 from dalle_pytorch_tpu.ops.pallas_attention import (
     TOKEN_MAJOR,
     flash_attention,
@@ -775,7 +778,9 @@ class LatentAttention(nn.Module):
     largest I(t, p) alone (ties: the lower position; ops/index_select.py). A
     token step scores the row's live positions (ops/index_score.py), selects
     as indices and attends those positions, fetched
-    (ops/sparse_latent_decode.py); a chunk, cached or not, scores in blocks
+    (ops/sparse_latent_decode.py: one fetch a position, because such a
+    layer's cache keeps `c` and `k_r` of a position in one row; which layout a
+    cache has is read off its leaves); a chunk, cached or not, scores in blocks
     and masks the blocks' scores by the selection. A sequence or a cache no
     longer than `index_topk` selects everything: the dense forms run, and the
     indexer only writes its keys. Sows `dsa_scored` and `dsa_selected` (the
@@ -830,7 +835,10 @@ class LatentAttention(nn.Module):
             cos, sin = (lax.dynamic_slice_in_dim(t, index, n, axis=0) for t in rotary_cs)
             q_n, q_r = q[..., :dn], apply_rotary_half(cos, sin, q[..., dn:])
             k_r = apply_rotary_half(cos, sin, k_r)  # [b, n, dr]: one head
-            chunk = {decode_cache.LATENT: c, decode_cache.ROPE: k_r.transpose(0, 2, 1)}
+            # as the cache keeps a position: a row of both (an indexed layer's), or two leaves
+            in_rows = cache is not None and decode_cache.ROWS in cache
+            chunk = ({decode_cache.ROWS: jnp.concatenate([c, k_r], axis=-1)} if in_rows else
+                     {decode_cache.LATENT: c, decode_cache.ROPE: k_r.transpose(0, 2, 1)})
         if self.index_topk:
             with jax.named_scope("dsa_index_proj"):
                 hi, di = self.index_heads, self.index_dim
@@ -844,21 +852,24 @@ class LatentAttention(nn.Module):
                 chunk[decode_cache.INDEX_K] = k_i
 
         new_cache = None
+        held = chunk  # what is attended: the chunk, or the cache with the chunk in it
         if cache is not None:
-            written, _ = decode_cache.write(cache, chunk, None)
-            new_cache = {**written, "index": index + n}
+            held, _ = decode_cache.write(cache, chunk, None)
+            new_cache = {**held, "index": index + n}
+        # rows of both (`rope` None: `split_rows` where two operands are wanted), or the two leaves
+        latent, rope = ((held[decode_cache.ROWS], None) if in_rows else
+                        (held[decode_cache.LATENT], held[decode_cache.ROPE]))
         # with an indexer: whether some query may have more positions than it attends
         sparse = bool(self.index_topk) and self.index_topk < (
-            n if cache is None or start else written[decode_cache.LATENT].shape[1])
+            n if cache is None or start else latent.shape[1])
         if cache is not None and n == 1:
             with jax.named_scope("mla_proj"):
                 w = to_kv.reshape(rank, h, dn + dv)
                 q_c = jnp.einsum("bhd,rhd->bhr", q_n[:, :, 0], w[..., :dn])
-            latent, rope = written[decode_cache.LATENT], written[decode_cache.ROPE]
             if sparse:
                 lengths = jnp.broadcast_to(index + 1, (b,))
                 with jax.named_scope("dsa_index"):
-                    scores = index_scores(q_i[:, :, 0], w_i[:, 0], written[decode_cache.INDEX_K],
+                    scores = index_scores(q_i[:, :, 0], w_i[:, 0], held[decode_cache.INDEX_K],
                                           lengths)
                 with jax.named_scope("dsa_select"):
                     chosen, count = selected_mask(scores, lengths, self.index_topk)
@@ -877,6 +888,8 @@ class LatentAttention(nn.Module):
                                  init_fn=lambda: None)
             else:
                 with jax.named_scope("mla_attend"):
+                    if rope is None:  # a cache no longer than the selection: cut whole
+                        latent, rope = split_rows(latent, rank, dr)
                     o_c = latent_decode_attention(
                         q_c, q_r[:, :, 0], latent, rope, jnp.broadcast_to(index + 1, (b,)),
                         sm_scale=sm_scale)
@@ -888,10 +901,8 @@ class LatentAttention(nn.Module):
             with jax.named_scope("mla_proj"):
                 w = to_kv.reshape(rank, h, dn + dv)
                 q_c = jnp.einsum("bhnd,rhd->bhnr", q_n, w[..., :dn])
-            held = chunk if cache is None else written
             o_c = _chunk_attend(
-                q_c, q_r, held[decode_cache.LATENT], held[decode_cache.ROPE], index,
-                sm_scale=sm_scale, **(dict(
+                q_c, q_r, latent, rope, index, sm_scale=sm_scale, **(dict(
                     indexer=(q_i, w_i, held[decode_cache.INDEX_K]), topk=self.index_topk)
                     if sparse else {}))
             with jax.named_scope("mla_proj"):
@@ -928,7 +939,9 @@ CHUNK_KEYS = 1024  # cached positions a step of `_chunk_attend`'s loops takes
 def _chunk_attend(q_c, q_r, latent, rope, start, *, sm_scale, indexer=None, topk=0):
     """[B, H, n, R]: the absorbed form for n queries a row at positions
     `start .. start + n - 1` (`start` a traced scalar: the rows stand in
-    lockstep) over `latent` [B, L, R] and `rope` [B, dr, L], which hold those
+    lockstep) over `latent` [B, L, R] and `rope` [B, dr, L] (or, `rope` None,
+    over rows [B, L, >= R + dr] of both: each block's two operands are cut
+    from the block, never from the leaf), which hold those
     positions and all before them: query t sees p <= t. `indexer = (q_i [B,
     Hi, n, Di], w_i [B, n, Hi] float32, keys [B, L, Di])` with `topk`: query t
     sees of those the min(topk, t + 1) positions of largest index score
@@ -966,7 +979,8 @@ def _chunk_attend(q_c, q_r, latent, rope, start, *, sm_scale, indexer=None, topk
         m, l, acc = carry
         lo = block_at(j)
         c = lax.dynamic_slice_in_dim(latent, lo, size, axis=1)
-        k_r = lax.dynamic_slice_in_dim(rope, lo, size, axis=2)
+        c, k_r = (split_rows(c, q_c.shape[-1], q_r.shape[-1]) if rope is None else
+                  (c, lax.dynamic_slice_in_dim(rope, lo, size, axis=2)))
         pos = lo + jnp.arange(size)
         if chosen is None:
             seen = (pos[None, :] <= at[:, None])[None]

@@ -24,22 +24,36 @@ place of `k` and `v`, two leaves without a heads axis:
     positions on its LAST axis.
 
 Two leaves and not one of latent_dim + rope_dim: the values' product reads
-the latent alone, and a slice of a wider leaf would be a copy of it at
-every step. The rotary key lies transposed because the chip lays a last
-axis out in tiles of 128 lanes: 64 numbers there are stored as 128, or (the
-compiler's choice for such a leaf) transposed, and copied back at every
-call of a kernel that wants them as declared (139 MB a layer a step at 64 x
-8,480: PERF.md, PR 31). With the positions last, a position costs its
+the latent alone over every live position, and a slice of a wider leaf would
+be a copy of it at every step. The rotary key lies transposed because the
+chip lays a last axis out in tiles of 128 lanes: 64 numbers there are stored
+as 128, or (the compiler's choice for such a leaf) transposed, and copied
+back at every call of a kernel that wants them as declared (139 MB a layer a
+step at 64 x 8,480: PERF.md, PR 31). With the positions last, a position costs its
 latent_dim + rope_dim numbers and no more. Per-layer layout, scalar `index`.
-Where the layer selects the positions it attends by a learned score
-(`index_dim`: a lightning indexer beside the latent attention) a third leaf
-stands beside the two:
 
+An INDEXED latent layer (`index_dim`: the layer selects the positions it
+attends by a learned score, a lightning indexer beside the latent attention)
+never runs that product over its cache: a token step reads the few positions
+its selection names, FETCHED, and a fetch costs by the rows it touches, not by
+their bytes (27 ns a position a leaf on the chip, the 128-byte rotary key the
+dearer of the two: PERF.md, PR 41). So it keeps a position's numbers in ONE
+row, and a position is fetched once:
+
+  * `rows` [B, L, latent_dim + rope_dim rounded up to `ROW_TILE`]: a
+    position's latent, then its rotary key, then zeros up to whole tiles of
+    128 lanes (576 numbers declared as 640: the chip stores a last axis of 576
+    so in any case, and the fetch out of a leaf declared as it is stored read
+    6% less than out of one declared 576 wide: PERF.md, PR 41);
   * `index_k` [B, L, index_dim]: the indexer's one key a position, positions
-    in the middle as the latent's: 128 numbers are a whole tile of lanes, so
-    nothing is padded, the score kernel streams [block, 128] slabs as the
-    latent's kernel streams its own (ops/index_score.py), and `write` puts a
-    chunk's keys where it puts its latent.
+    in the middle likewise: 128 numbers are a whole tile of lanes, so nothing
+    is padded, the score kernel streams [block, 128] slabs as the latent's
+    kernel streams its own (ops/index_score.py), and `write` puts a chunk's
+    keys where it puts its rows.
+
+Which of the two a layer holds is read off its leaves (`ROWS in attn`), as the
+layout is read off the tree; `ops/sparse_latent_decode.py:split_rows` cuts
+rows, fetched ones or a block of the leaf, into what the products take.
 
 A RECURRENT layer (`layer_spec(kind="recurrent")`: linear attention by the
 gated delta rule, models/attention.py:GatedDeltaAttention) holds no position
@@ -125,8 +139,11 @@ ATTN = "attn"
 K, V, K_SCALE, V_SCALE, INDEX = "k", "v", "k_scale", "v_scale", "index"
 SCALE_KEYS = (K_SCALE, V_SCALE)
 LATENT, ROPE = "latent", "rope"
-INDEX_K = "index_k"  # a latent layer's third leaf: the indexer's key
-LATENT_KEYS = (LATENT, ROPE, INDEX_K)
+# an indexed latent layer's leaves: a position's latent and rotary key in one
+# row, and the indexer's key
+ROWS, INDEX_K = "rows", "index_k"
+ROW_TILE = 128  # a row is declared in whole tiles of lanes, as the chip stores it
+LATENT_KEYS = (LATENT, ROPE, ROWS, INDEX_K)
 STATE, CONV = "state", "conv"
 # a recurrent layer's running leaves and, beside each, its snapshot
 SNAPSHOT = {STATE: "state_at", CONV: "conv_at"}
@@ -188,9 +205,10 @@ def layer_spec(
     """ONE layer's leaves as `jax.ShapeDtypeStruct`s, from the geometry.
 
     `kind="latent"`: `latent` [batch, max_len, latent_dim] and `rope`
-    [batch, rope_dim, max_len] with a scalar `index`, with `index_dim` also
-    `index_k` [batch, max_len, index_dim], and nothing else (no pages, no
-    int8 store, no rings). `kind="recurrent"`: `state`
+    [batch, rope_dim, max_len] with a scalar `index`; with `index_dim` in
+    their place `rows` [batch, max_len, latent_dim + rope_dim rounded up to
+    `ROW_TILE`] and `index_k` [batch, max_len, index_dim]; and nothing else
+    (no pages, no int8 store, no rings). `kind="recurrent"`: `state`
     [batch, key_dim, linear_heads * value_dim] and `conv` [batch, conv_taps - 1,
     linear_heads * (2 key_dim + value_dim)], both float32, their snapshot
     beside them, and a scalar `index`. `kind="window"`: K/V [batch, heads,
@@ -210,13 +228,14 @@ def layer_spec(
     if kind == "latent":
         assert pages is None and kv_dtype is None and not per_row and not shift_tokens, (
             "a latent cache is lanes in the cache dtype, decoded in lockstep")
-        indexed = {INDEX_K: spec((batch, max_len, index_dim), dtype)} if index_dim else {}
-        return {ATTN: {
-            LATENT: spec((batch, max_len, latent_dim), dtype),
-            ROPE: spec((batch, rope_dim, max_len), dtype),
-            **indexed,
-            INDEX: spec((), jnp.int32),
-        }}
+        if index_dim:
+            width = -(-(latent_dim + rope_dim) // ROW_TILE) * ROW_TILE
+            leaves = {ROWS: spec((batch, max_len, width), dtype),
+                      INDEX_K: spec((batch, max_len, index_dim), dtype)}
+        else:
+            leaves = {LATENT: spec((batch, max_len, latent_dim), dtype),
+                      ROPE: spec((batch, rope_dim, max_len), dtype)}
+        return {ATTN: {**leaves, INDEX: spec((), jnp.int32)}}
     if kind == "recurrent":
         assert pages is None and kv_dtype is None and not per_row and not shift_tokens, (
             "a recurrent cache is one state a row, decoded in lockstep")
@@ -460,8 +479,8 @@ def leaf_name(path) -> str:
 
 def kv_bytes(cache: dict) -> int:
     """Bytes of the K/V leaves, quantization scales included (of a latent
-    layer: its latent, its shared rotary key and, where it has one, its
-    indexer's key)."""
+    layer: its latent and its shared rotary key, as two leaves or as rows of
+    both, and, where it has one, its indexer's key)."""
     return sum(
         leaf.size * leaf.dtype.itemsize
         for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
@@ -469,10 +488,16 @@ def kv_bytes(cache: dict) -> int:
     )
 
 
+def latent_leaf(cache: dict):
+    """A latent cache's first leaf that holds a latent a position, alone or
+    in a row with its rotary key: [B, L, ...]."""
+    return next(leaf for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+                if leaf_name(path) in (LATENT, ROWS))
+
+
 def max_len(cache: dict) -> int:
-    """Positions a latent cache holds a row (its `latent` leaves' middle axis)."""
-    return next(leaf.shape[1] for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
-                if leaf_name(path) == LATENT)
+    """Positions a latent cache holds a row."""
+    return latent_leaf(cache).shape[1]
 
 
 def pack_state(state):
@@ -731,12 +756,14 @@ def write(attn_cache: dict, vals: dict, seq_cap: int):
     length is the slotted cache's (`seq_cap`), and finished rows clamp to
     the spare last position as the lanes' dynamic_update_slice does. A
     stacked leaf (the cache carries `layer`) is written at [layer]. A latent
-    layer's `vals` are `latent` [B, n, latent_dim], `rope` [B, rope_dim, n] and,
-    where it keeps one, `index_k` [B, n, index_dim]."""
+    layer's `vals` are `latent` [B, n, latent_dim] and `rope` [B, rope_dim, n],
+    or of an indexed one `rows` [B, n, latent_dim + rope_dim] (the leaf's
+    columns past those stay the zeros they were made) and `index_k` [B, n,
+    index_dim]."""
     index, layer = attn_cache[INDEX], attn_cache.get(LAYER)
-    if LATENT in attn_cache:
+    if LATENT in attn_cache or ROWS in attn_cache:
         # a latent layer: the chunk's n positions from `index` on, along
-        # the latent's middle axis and the rotary key's last
+        # the rotary key's last axis and every other leaf's middle one
         assert jnp.ndim(index) == 0 and layer is None, "a latent cache decodes in lockstep"
         with jax.named_scope("cache_write"):
             out = {
@@ -745,7 +772,7 @@ def write(attn_cache: dict, vals: dict, seq_cap: int):
                     (0, 0, index) if name == ROPE else (0, index, 0))
                 for name, val in vals.items()
             }
-        return out, out[LATENT].shape[1]
+        return out, out[LATENT if LATENT in out else ROWS].shape[1]
     n = vals[K].shape[2]
     if PAGE_TABLE not in attn_cache:
         with jax.named_scope("cache_write"):

@@ -536,7 +536,7 @@ def place_rows(model: CausalLM, cache: dict, fresh: dict, row: int = 0) -> dict:
     `prefill_chunks`) written into its row `row + r`; sessions that hold one
     document each keep their own copy of it, a call a copy. The indices are
     left where they were."""
-    rows = next(iter(fresh.values()))[decode_cache.ATTN][decode_cache.LATENT].shape[0]
+    rows = decode_cache.latent_leaf(fresh).shape[0]
     at = row + jnp.arange(rows, dtype=jnp.int32)
     return _jitted(_place_builder, model, ())(cache, fresh, at)
 

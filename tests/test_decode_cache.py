@@ -271,3 +271,71 @@ def test_restore_prefix_copies_a_page_and_writes_one_rows_rings(layout):
     same = dc.restore_prefix(state, one, jnp.int32(2))
     k = lambda c: (c if stacked else c["layer_0"])["attn"]["k"]
     assert k(same) is k(state)  # no page copy asked for: the pool untouched
+
+
+# a latent layer's leaves in key order, by whether it carries an indexer: the
+# two leaves of PR 31, or (PR 41) a position's latent and rotary key in ONE row
+LATENT_TABLE = {
+    None: [("attn/latent", (BATCH, MAX_LEN, 512), "bfloat16"),
+           ("attn/rope", (BATCH, 64, MAX_LEN), "bfloat16"), ("attn/index", (), "int32")],
+    128: [("attn/rows", (BATCH, MAX_LEN, 640), "bfloat16"),
+          ("attn/index_k", (BATCH, MAX_LEN, 128), "bfloat16"), ("attn/index", (), "int32")],
+}
+
+
+def latent(index_dim, latent_dim=512, rope_dim=64):
+    return dc.make(PER_LAYER, DEPTH, kind="latent", batch=BATCH, max_len=MAX_LEN, heads=HEADS,
+                   dim_head=DH, dim=DIM, latent_dim=latent_dim, rope_dim=rope_dim,
+                   index_dim=index_dim, dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("index_dim", [None, 128])
+def test_an_indexed_latent_layer_keeps_rows_and_an_unindexed_one_its_two_leaves(index_dim):
+    cache = latent(index_dim)
+    assert flat(cache) == [(f"layer_{i}/{path}", shape, dt) for i in range(DEPTH)
+                           for path, shape, dt in LATENT_TABLE[index_dim]]
+    numbers = 640 + 128 if index_dim else 512 + 64
+    assert dc.kv_bytes(cache) == DEPTH * BATCH * MAX_LEN * numbers * 2
+    assert dc.max_len(cache) == MAX_LEN and dc.latent_leaf(cache).shape[0] == BATCH
+
+
+@pytest.mark.parametrize("latent_dim,rope_dim,width", [(512, 64, 640), (16, 8, 128),
+                                                       (192, 64, 256)])
+def test_a_row_is_declared_in_whole_tiles_of_lanes(latent_dim, rope_dim, width):
+    attn = latent(8, latent_dim, rope_dim)["layer_0"]["attn"]
+    assert attn["rows"].shape == (BATCH, MAX_LEN, width) and width % dc.ROW_TILE == 0
+
+
+@pytest.mark.parametrize("index_dim", [None, 8])
+def test_write_scatter_and_snapshot_follow_a_latent_layers_leaves(index_dim):
+    """A chunk goes in from `index` on, along the rotary leaf's last axis and
+    every other leaf's middle one; a row's columns past its numbers stay zero;
+    `scatter_rows` copies rows of every leaf whole; `snapshot` and `restore`
+    leave a cache with no state and no ring as it is."""
+    cache = dc.set_index(latent(index_dim, 3, 2), jnp.asarray(4))
+    attn = cache["layer_1"]["attn"]
+    ones = lambda *shape: jnp.ones(shape, jnp.bfloat16)
+    chunk = ({"rows": ones(BATCH, 2, 5), "index_k": 3 * ones(BATCH, 2, 8)} if index_dim else
+             {"latent": ones(BATCH, 2, 3), "rope": 2 * ones(BATCH, 2, 2)})
+    written, length = dc.write(attn, chunk, None)
+    assert length == MAX_LEN and set(written) == set(chunk)
+    at = np.zeros(MAX_LEN)
+    at[4:6] = 1
+    if index_dim:
+        np.testing.assert_array_equal(np.asarray(written["rows"][1, :, 4], np.float32), at)
+        assert not np.asarray(written["rows"][:, :, 5:], np.float32).any()
+        np.testing.assert_array_equal(np.asarray(written["index_k"][2, :, 7], np.float32), 3 * at)
+    else:
+        np.testing.assert_array_equal(np.asarray(written["latent"][1, :, 2], np.float32), at)
+        np.testing.assert_array_equal(np.asarray(written["rope"][2, 1], np.float32), 2 * at)
+    fresh = {name: {"attn": {**layer["attn"], **written}} for name, layer in cache.items()}
+    placed = dc.scatter_rows(latent(index_dim, 3, 2),
+                             jax.tree.map(lambda x: x[1:2] if x.ndim else x, fresh),
+                             jnp.asarray([2], jnp.int32))
+    for name, leaf in placed["layer_0"]["attn"].items():
+        if name != "index":
+            np.testing.assert_array_equal(np.asarray(leaf[2], np.float32),
+                                          np.asarray(written[name][1], np.float32))
+            assert not np.asarray(leaf[:2], np.float32).any()
+    same = jax.tree.map(lambda a, b: a is b, dc.snapshot(cache), cache)
+    assert all(jax.tree.leaves(same)) and dc.restore(cache)[0] is cache

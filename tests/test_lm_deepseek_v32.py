@@ -89,9 +89,10 @@ def test_from_config_reads_the_published_file():
     count = sum(x.size for x in jax.tree.leaves(shapes["params"]))
     assert count == ref.n_params(cfg) and round(count / 1e6) == 4636
     layer = jax.eval_shape(lambda: mdl.init_cache(2, 512))["layer_0"]["attn"]
+    # a position's latent and rotary key in one row of five whole tiles of lanes
     assert {k: v.shape for k, v in layer.items()} == {
-        "latent": (2, 512, 512), "rope": (2, 64, 512), "index_k": (2, 512, 128), "index": ()}
-    assert decode_cache.kv_bytes({"layer_0": {"attn": layer}}) == 2 * 512 * (512 + 64 + 128) * 2
+        "rows": (2, 512, 640), "index_k": (2, 512, 128), "index": ()}
+    assert decode_cache.kv_bytes({"layer_0": {"attn": layer}}) == 2 * 512 * (640 + 128) * 2
     published = {k: v for k, v in cfg.items() if k in cfg["published"]}
     assert set(published) == set(cfg["reduced"]) and cfg["published"]["n_routed_experts"] == 256
 
@@ -100,9 +101,13 @@ def test_a_latent_config_without_an_indexer_builds_what_it_built():
     """`pangu-ultra-moe-ep16.json` has no `index_topk`, `rope_scaling` or
     `n_group`: none of the new trunk options appears, so the model (which
     keys its compiled programs) is the one the parent built."""
-    trunk = dict(CausalLM.from_config(_cfg("pangu-ultra-moe-ep16"), 64).trunk)
+    mdl = CausalLM.from_config(_cfg("pangu-ultra-moe-ep16"), 64)
+    trunk = dict(mdl.trunk)
     assert not {"index_topk", "index_heads", "softmax_mult", "moe_groups",
                 "moe_score_bias"} & set(trunk)
+    layer = jax.eval_shape(lambda: mdl.init_cache(2))["layer_0"]["attn"]
+    assert {k: v.shape for k, v in layer.items()} == {
+        "latent": (2, 64, 512), "rope": (2, 64, 64), "index": ()}
     assert dict(trunk["rotary_specs"]["latent"]) == {"type": "default", "dim": 64,
                                                      "theta": 25600000}
 
@@ -234,6 +239,91 @@ def test_sparse_attend_reads_the_selected_positions_alone():
     poisoned = attend(jnp.where(seen[..., None], latent, jnp.nan),
                       jnp.where(seen[:, None], rope, jnp.nan))
     np.testing.assert_allclose(poisoned, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("width", [24, 128])
+def test_one_fetch_of_rows_is_the_two_leaves_two_fetches_bit_for_bit(width, dtype):
+    """`fetch_selected` out of an indexed layer's rows (latent 16 | rotary 8
+    | padding to `width`) returns what the two gathers out of `latent` and
+    `rope` return on the same values, and the attend over either is one."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    latent = jax.random.normal(ks[0], (3, 50, 16), dtype)
+    rope = jax.random.normal(ks[1], (3, 8, 50), dtype)
+    rows = jnp.concatenate([latent, rope.transpose(0, 2, 1),
+                            jnp.full((3, 50, width - 24), jnp.nan, dtype)], axis=-1)
+    picked = jnp.asarray(np.stack([np.sort(np.random.default_rng(r).choice(50, 12, replace=False))
+                                   for r in range(3)]), jnp.int32)
+    two = sparse_latent_decode.fetch_selected(latent, rope, picked)
+    one = sparse_latent_decode.fetch_selected(rows, None, picked, (16, 8))
+    assert [t.shape for t in one] == [(3, 12, 16), (3, 8, 12)]
+    for got, want in zip(one, two):
+        assert got.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    q_c, q_r = jax.random.normal(ks[2], (3, 4, 16), dtype), jax.random.normal(ks[3], (3, 4, 8), dtype)
+    count = jnp.asarray([12, 5, 1], jnp.int32)
+    attend = lambda *leaves: np.asarray(sparse_latent_decode.sparse_latent_decode_attention(
+        q_c, q_r, *leaves, picked, count, sm_scale=0.3), np.float32)
+    np.testing.assert_array_equal(attend(rows, None), attend(latent, rope))
+
+
+# sha-256 (16 hex digits) of what PR 40's two-leaf program gave for `replayed`'s
+# drive, computed from that tree before the row leaf was built
+PINNED = {"tokens": "e360d19e4ee78727", "logits": "961428d671b74704",
+          "selected": "5ad5fd9226e921c3"}
+
+
+def _replay(mdl, variables, tokens):
+    """Two documents prefilled in chunks of 16, the first copied to sessions 0
+    and 2 and the second to session 1 (`place_rows`), then a turn of 2 forced
+    and 4 SAMPLED token steps (top 10%, temperature 1): the tokens [3, 6], the
+    three rows' logits [6, 3, V] and every layer's selection [6, 3, 3, 8]."""
+    cache = mdl.init_cache(3)
+    for doc, sessions in ((0, (0, 2)), (1, (1,))):
+        fresh, _ = lm.prefill_chunks(mdl, variables, jnp.asarray(tokens[doc:doc + 1, :DOC]), 16)
+        for row in sessions:
+            cache = lm.place_rows(mdl, cache, fresh, row)
+    forced = jnp.asarray(tokens[[0, 1, 0], DOC:DOC + 2])
+    toks, logits, counts, _ = generate_tokens_cached(
+        mdl, variables, jax.random.PRNGKey(7), cache, forced, STEPS, filter_thres=0.9,
+        temperature=1.0, logit_rows=3, start=DOC)
+    return {"tokens": np.asarray(toks), "logits": np.asarray(logits),
+            "selected": np.asarray(counts["picks"]["selected"])}
+
+
+@pytest.fixture(scope="module")
+def replayed(pair, tokens):
+    assert "rows" in pair[0].init_cache(1)["layer_0"]["attn"]
+    return _replay(*pair, tokens)
+
+
+@pytest.mark.parametrize("what", sorted(PINNED))
+def test_chunks_copies_and_token_steps_give_the_two_leaf_programs_bits(replayed, what):
+    import hashlib
+
+    got = replayed[what]
+    assert got.dtype == (np.float32 if what == "logits" else np.int32)
+    assert hashlib.sha256(np.ascontiguousarray(got).tobytes()).hexdigest()[:16] == PINNED[what]
+
+
+def test_a_two_leaf_cache_under_an_indexer_still_gives_the_same_bits(pair, tokens, replayed,
+                                                                     monkeypatch):
+    """The layer reads its cache's layout off the leaves: handed the two
+    leaves beside an index key (what `layer_spec` made until PR 41), the same
+    drive gives the row leaf's tokens, logits and selections, bit for bit."""
+    real = decode_cache.layer_spec
+
+    def two_leaves(index_dim=None, **geometry):
+        layer = real(**geometry)
+        spec = jax.ShapeDtypeStruct((geometry["batch"], geometry["max_len"], index_dim),
+                                    geometry["dtype"])
+        layer["attn"] = {**layer["attn"], "index_k": spec}
+        return layer
+
+    monkeypatch.setattr(decode_cache, "layer_spec", two_leaves)
+    assert set(pair[0].init_cache(1)["layer_0"]["attn"]) == {"latent", "rope", "index", "index_k"}
+    for what, got in _replay(*pair, tokens).items():
+        np.testing.assert_array_equal(got, replayed[what])
 
 
 def test_the_router_limits_its_choice_to_the_best_groups_by_the_biased_score(cfg):

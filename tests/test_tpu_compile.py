@@ -916,8 +916,9 @@ def test_index_score_kernel_compiles(one_chip, monkeypatch):
 def test_selection_and_sparse_attend_compile_without_a_sort(one_chip, monkeypatch):
     """The selection of 2,048 of 33,792 scores a row (threshold by counting,
     compaction by two small products) holds no sort, gather or scatter; the
-    sparse attend is two gathers of the selected positions (the fetch) and
-    the dense kernel `decode_latent` over them."""
+    sparse attend is ONE gather of the selected positions' rows (the fetch:
+    an indexed layer's cache; two out of a two-leaf one) and the dense kernel
+    `decode_latent` over them."""
     from dalle_pytorch_tpu.ops import index_select as sel, latent_decode, pallas_attention
     from dalle_pytorch_tpu.ops import sparse_latent_decode as sp
 
@@ -932,28 +933,35 @@ def test_selection_and_sparse_attend_compile_without_a_sort(one_chip, monkeypatc
     text = jax.jit(select).lower(shape(jnp.float32, 16, 33792), _i32(one_chip, 16)).compile().as_text()
     assert not re.search(r" (sort|gather|scatter)\(", text)
     bf16 = functools.partial(shape, jnp.bfloat16)
-    text = _compile(functools.partial(sp.sparse_latent_decode_attention, sm_scale=0.135),
-                    bf16(16, 128, 512), bf16(16, 128, 64), bf16(16, 33792, 512),
-                    bf16(16, 64, 33792), shape(jnp.int32, 16, 2048), _i32(one_chip, 16)).as_text()
-    assert len(re.findall(r" gather\(", text)) == 2
-    assert re.search(r"%decode_latent[.\d]* = bf16\[16,128,512\]", text)
+    attend = functools.partial(sp.sparse_latent_decode_attention, sm_scale=0.135)
+    for leaves, gathers in (((bf16(16, 33792, 640), None), 1),
+                            ((bf16(16, 33792, 512), bf16(16, 64, 33792)), 2)):
+        compiled = _compile(attend, bf16(16, 128, 512), bf16(16, 128, 64), *leaves,
+                            shape(jnp.int32, 16, 2048), _i32(one_chip, 16))
+        text = compiled.as_text()
+        assert len(re.findall(r" gather\(", text)) == gathers
+        assert re.search(r"%decode_latent[.\d]* = bf16\[16,128,512\]", text)
+        if gathers == 1:  # fetched where it lies: no copy of the leaf in front of the gather
+            assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2**20
 
 
 def test_sparse_token_loop_compiles_at_the_cells_size(one_chip, monkeypatch, capsys):
     """The token loop of `deepseek32.decode.32k` (16 sessions x 33,792
-    positions, 288 steps, top-k 0.9): every layer's three cache leaves ride
-    the loop's carry and no latent or index-key leaf is copied (the compiler
-    keeps the rotary keys positions-major through the loop, for the fetch: a
-    copy in and one out a DISPATCH); a step holds an index-score kernel and a
-    dense-kernel call a layer and three grouped products a routed layer; the
-    plan is the weights, the cache and 1.4 GB beside them."""
+    positions, 288 steps, top-k 0.9): every layer's two cache leaves (a
+    position's latent and rotary key in one row of 640, the index key) ride
+    the loop's carry and neither is copied, in the loop or around it (until PR
+    41 the compiler kept a positions-major copy of a third leaf, the rotary
+    keys, through the loop for their fetch: 346 MB); a step holds ONE gather a
+    layer under `mla_attend`, an index-score kernel and a dense-kernel call a
+    layer and three grouped products a routed layer; the plan is the weights,
+    the cache and 1.1 GB beside them, under the two-leaf program's 13.50 GiB
+    (PR 40's tree, compiled here the same way)."""
     from dalle_pytorch_tpu.models import lm
 
     mdl, variables, job, on = _deepseek_v32(one_chip, monkeypatch)
     cache = on(jax.eval_shape(lambda: mdl.init_cache(16)))
     assert {k: v.shape for k, v in cache["layer_4"]["attn"].items()} == {
-        "latent": (16, 33792, 512), "rope": (16, 64, 33792), "index_k": (16, 33792, 128),
-        "index": ()}
+        "rows": (16, 33792, 640), "index_k": (16, 33792, 128), "index": ()}
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
     compiled = jax.jit(lm._sampler_builder(mdl, (288, 0.9, 1.0, 2)), donate_argnums=(2,)).lower(
@@ -962,10 +970,12 @@ def test_sparse_token_loop_compiles_at_the_cells_size(one_chip, monkeypatch, cap
     with capsys.disabled():
         print(f"\ndeepseek32.decode.32k sampler: planned {plan:.2f} GiB on the described v5e")
     assert text.count("tpu_custom_call") == 5 + 5 + 12
-    assert not re.search(r"= bf16\[16,33792,(512|128)\]\S* copy\(", text)
-    body = text[text.index("region_0"):]
-    assert not re.search(r"= bf16\[16,64,33792\]\S* copy\(", body[:body.index("\n}\n")])
-    assert 13.0 < plan < 14.0
+    assert not re.search(r"= bf16\[16,33792,(640|128)\]\S* copy\(", text)
+    assert not re.search(r"bf16\[16,(33792,64|64,33792)\]", text)
+    gathers = [line for line in text.splitlines() if re.search(r" gather\(", line)]
+    fetches = [line for line in gathers if "mla_attend" in line]
+    assert len(fetches) == 5 and all("bf16[16,2048,640]" in line for line in fetches)
+    assert 13.0 < plan < 13.4
 
 
 def test_prefill_chunk_compiles_with_the_cache_at_full_size(one_chip, monkeypatch, capsys):
