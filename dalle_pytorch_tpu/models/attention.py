@@ -53,7 +53,6 @@ from dalle_pytorch_tpu.ops.pallas_attention import (
     TOKEN_MAJOR,
     flash_attention,
     heads_per_block,
-    lib_flash_attention,
 )
 from dalle_pytorch_tpu.ops.pallas_decode import (
     block_sparse_flash_decode_attention,
@@ -103,6 +102,33 @@ AUTO_FLASH_DECODE_MIN_LEN = 512
 DECODE_SPARSE_BLOCK = 128
 
 
+# The mixer a layer is built as and the path its cached call takes, which
+# `Transformer.plan` decides from the trunk's options and a module is TOLD
+# (`Attention.path`): by what it is built with, never by what it is called with.
+#   DALLE   the fused 3 x inner projection, the DALL-E rotary on q, k AND v; cached
+#           over K/V lanes or pages, a scalar or per-row index, int8, a block bitmap
+#   LANES   the grouped projection (a q/k norm, a K/V head a query head) over those
+#           same lanes (`_cached_lanes`)
+#   ROWS    the grouped projection over per-row K/V or a window's ring
+#           (`_cached_grouped`): K/V heads shared, a window, or a rotate-half rotary
+#   LATENT, LINEAR   `LatentAttention`, `GatedDeltaAttention`
+DALLE, LANES, ROWS, LATENT, LINEAR = "dalle", "grouped_lanes", "grouped_rows", "latent", "linear"
+ATTN_IMPLS = ("auto", "dense", "flash", "ring")
+
+
+def attention_path(heads, kv_heads, qk_norm, window, rotated: bool = False) -> str:
+    """Which of DALLE, LANES and ROWS an `Attention` of these options is on
+    (`rotated`: its layers are handed a rotate-half table)."""
+    if (kv_heads or heads) != heads or window is not None or rotated:
+        return ROWS
+    return LANES if kv_heads is not None or qk_norm else DALLE
+
+
+def _known_impl(attn_impl: str):
+    if attn_impl not in ATTN_IMPLS:
+        raise ValueError(f"unknown attn_impl {attn_impl!r}: one of {ATTN_IMPLS}")
+
+
 def _kv_quantize(x: jnp.ndarray):
     """Symmetric int8 quantization over the head dim: x [B,H,n,D] ->
     (q int8 [B,H,n,D], scale fp32 [B,H,n]).
@@ -133,9 +159,7 @@ class Attention(nn.Module):
     dropout: float = 0.0
     stable: bool = False
     static_mask: Optional[np.ndarray] = None  # [S, S] bool, True = attend
-    # "dense" | "flash" (in-repo Pallas) | "lib_flash" (jax library TPU
-    # kernel; plain causal/full only) | "ring" | "auto"
-    attn_impl: str = "auto"
+    attn_impl: str = "auto"  # one of ATTN_IMPLS; "flash" is the in-repo Pallas kernel
     sp_mesh: Any = None  # Mesh with an "sp" axis, required for attn_impl="ring"
     # serving mesh for the SHARDED flash-decode dispatch: a Pallas call is
     # a single-device program GSPMD cannot partition, so when the sharded
@@ -176,17 +200,16 @@ class Attention(nn.Module):
     use_bias: bool = True  # to_out's (to_qkv never had one)
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32  # what the two matrices are stored in
+    # DALLE, LANES or ROWS, which `Transformer` fills from its plan; None (a
+    # standalone module): `attention_path` of the options above
+    path: Optional[str] = None
+
+    def __post_init__(self):
+        _known_impl(self.attn_impl)
+        super().__post_init__()
 
     def _use_flash(self, n: int, key_mask) -> bool:
         """Flash path: static masks only (dynamic key-padding stays dense)."""
-        if self.attn_impl == "lib_flash":
-            if key_mask is not None or self.static_mask is not None:
-                raise ValueError(
-                    'attn_impl="lib_flash" supports plain causal/full '
-                    "attention only (no key-padding or static masks); use "
-                    '"flash" or "dense"'
-                )
-            return True
         if self.attn_impl == "flash":
             if key_mask is not None:
                 raise ValueError(
@@ -370,8 +393,7 @@ class Attention(nn.Module):
         block-level shadow IS the skip structure, and masked rows route
         through the block-sparse flash kernel instead of reading the whole
         cache dense. `attn_impl="flash"` forces the kernel; "auto"
-        switches on cache length; "dense"/"lib_flash"/"ring" stay dense
-        (the library kernel has no decode analog, and ring is a
+        switches on cache length; "dense"/"ring" stay dense (ring is a
         training-time layout)."""
         if has_pattern and not sparse:
             return False
@@ -422,322 +444,333 @@ class Attention(nn.Module):
         only (a traced mask cannot drive flash's host-side block-occupancy
         skipping); the cached path row-slices it at the decode position
         exactly like `static_mask`."""
+        path = self.path or attention_path(self.heads, self.kv_heads, self.qk_norm, self.window)
         if mask_array is not None:
             assert self.static_mask is None, (
                 "pass either the static_mask attribute or mask_array, not both"
             )
-            assert self.attn_impl not in ("flash", "lib_flash", "ring"), (
+            assert self.attn_impl not in ("flash", "ring"), (
                 f'attn_impl="{self.attn_impl}" cannot apply a traced pattern '
                 "mask; scan executor uses dense for masked layers"
             )
+        if rotary_cs is not None and path != ROWS:
+            raise ValueError(f"a rotate-half table (`rotary_cs`) handed to a module on the {path} "
+                             f"path: a rotated layer is built on {ROWS} (`Transformer.plan`)")
         b, n, _ = x.shape
-        h, dh = self.heads, self.dim_head
-        inner = h * dh
-
-        grouped = (self.kv_heads is not None or self.qk_norm or rotary_cs is not None
-                   or (cache is not None and self.window is not None))
+        rows = cache is not None and path == ROWS
+        if rows and jnp.ndim(cache["index"]) != 1:
+            raise ValueError(
+                f"a cache whose index is a scalar (the rows in lockstep) handed to a layer on the "
+                f"{ROWS} path, which decodes per row: `Transformer.init_cache` builds its cache")
+        # a chunk attends itself ALONE where there is no cache and, on the ROWS
+        # path, where it starts the rows' sequences (it is written as well)
+        alone = cache is None or (rows and start)
+        flash = (alone and self.attn_impl != "ring" and mask_array is None
+                 and self._use_flash(n, key_mask))
         # the uncached flash kernels read q, k, v where the DALL-E projection
         # wrote them, [B, n, heads, dh], and write the columns `to_out`
         # contracts over; every other path takes [B, heads, n, dh] and gives
-        # it back. (The grouped path stays head-major: its per-head norm and
+        # it back. (The grouped paths stay head-major: their per-head norm and
         # rotate-half rotary are XLA's, and compiled for a v5e with q, k, v
         # kept token-major, as [.., heads, 128] or as rows of [.., 128], a
         # layer's reshapes and copies hold 1.9 times the bytes of the
         # transposes they replace: PERF.md section 6, PR 34. The kernels
         # take a 128-wide head token-major all the same.)
-        # a cached chunk of the grouped kind that starts the rows' sequences
-        # is written into the cache and attends itself
-        variant = cache is not None and (
-            (self.kv_heads or h) != h or self.window is not None or rotary_cs is not None)
-        alone = cache is None or (variant and start)
-        flash = (alone and self.attn_impl != "ring" and mask_array is None
-                 and self._use_flash(n, key_mask))
-        tokens = (flash and not grouped and self.attn_impl != "lib_flash"
-                  and self._token_major())
-        if not grouped:
-            qkv = nn.Dense(inner * 3, use_bias=False, dtype=self.dtype, name="to_qkv")(x)
+        tokens = flash and path == DALLE and self._token_major()
+
+        # 1. project
+        if path == DALLE:
+            h, dh = self.heads, self.dim_head
+            qkv = nn.Dense(h * dh * 3, use_bias=False, dtype=self.dtype, name="to_qkv")(x)
             if not tokens:  # else the kernels take `qkv` as it is (`_flash_columns`)
                 q, k, v = jnp.split(qkv, 3, axis=-1)
                 q, k, v = (t.reshape(b, n, h, dh).transpose(0, 2, 1, 3) for t in (q, k, v))
-        elif variant:
-            index = cache["index"]
-            assert jnp.ndim(index) == 1, "grouped, windowed or rotated cached decode is per row"
-            q, k, v = self._grouped_qkv(
-                x, rotary_cs,
-                None if start else index[:, None] + jnp.arange(n, dtype=index.dtype))
         else:
-            q, k, v = self._grouped_qkv(x, rotary_cs)
+            at = None  # on ROWS a step's q and k are turned at each row's own positions
+            if rows and not start:
+                at = cache["index"][:, None] + jnp.arange(n, dtype=cache["index"].dtype)
+            q, k, v = self._grouped_qkv(x, rotary_cs, at)
 
+        # 2. a cached write-and-attend on the module's one path, or attend alone
         new_cache = None
-        if variant:
+        if rows:
             out, new_cache = self._cached_grouped(q, k, v, cache, start)
         elif cache is not None:
-            # n-token chunk (prefill or single-token decode) written into a
-            # fixed-shape cache at sequence position `index`. A scalar index
-            # means the whole batch decodes in lockstep; a [B] index means
-            # per-row positions (continuous-batching slots admitted at
-            # different times) — every index-dependent op below (rotary row
-            # slice, cache write, causal mask, pattern-mask row slice) then
-            # runs per row via vmap, at identical per-row numerics.
-            #
-            # A cache carrying a "page_table" key is BLOCK-PAGED: k/v are a
-            # physical page pool [P, H, page_size, D] shared by all rows
-            # and the [B, n_pages] table maps each row's logical blocks to
-            # pages (serving/paging.py allocates; released rows point at
-            # the reserved garbage page 0, so a stale write can never
-            # corrupt a reallocated page). Reads either gather the row's
-            # logical view and run the IDENTICAL dense/flash path as the
-            # slotted cache (bit-for-bit — the paging parity contract) or
-            # stream pages directly through the paged Pallas kernel
-            # (ops/pallas_decode.py PAGED_DECODE_IMPL).
-            #
-            # A cache carrying a "layer" key is the scan executor's: k, v
-            # (and their scales) are the DEPTH-STACKED leaves [L, ...] held
-            # in the layer scan's carry, and `layer` is this layer's traced
-            # index. The chunk's n positions are written into the stack at
-            # [layer], in place; what attention reads is `stack[layer]` of
-            # the updated stack, a view nothing else consumes. The other
-            # leaves (index, page_table, block_bitmap) arrive as the
-            # layer's own.
-            index = cache["index"]
-            layer = cache.get(decode_cache.LAYER)
-            per_row = jnp.ndim(index) == 1
-            paged = "page_table" in cache
-            if rotary is not None:
-                if per_row:
-                    rot = jax.vmap(
-                        lambda i: lax.dynamic_slice_in_dim(rotary, i, n, axis=0)
-                    )(index)
-                    rot = rot[:, None]  # [B,1,n,dr]
-                else:
-                    rot = lax.dynamic_slice_in_dim(rotary, index, n, axis=0)
-                    rot = jnp.expand_dims(rot, (0, 1))  # [1,1,n,dr]
-                q, k, v = (apply_rotary(rot, t) for t in (q, k, v))
-            # int8 KV cache: quantize AFTER rotary (the cache stores what
-            # attention reads), carry per-(position, head) fp32 scales in
-            # sibling leaves; q stays full precision
-            quant = "k_scale" in cache
-            if quant:
-                (qk, k_sc), (qv, v_sc) = _kv_quantize(k), _kv_quantize(v)
-                chunk = {"k": qk, "v": qv, "k_scale": k_sc, "v_scale": v_sc}
-            else:
-                chunk = {"k": k, "v": v}
-            # the chunk written at the cache's index (lanes or pages, a
-            # leaf or a stack at [layer]); max_len is the virtual
-            # contiguous length, the slotted cache's (total_seq_len + 1),
-            # so dense/flash see identical shapes on both stores
-            written, max_len = decode_cache.write(cache, chunk, self.seq_len + 1)
-            pt = cache.get("page_table")
-            # this layer's K/V as the reads below take it
-            ck, cv = (decode_cache.view(written[x], layer) for x in ("k", "v"))
-            cks = cvs = None
-            if quant:
-                cks, cvs = (
-                    decode_cache.view(written[x], layer) for x in ("k_scale", "v_scale")
-                )
-            # policy block bitmap ([B, nb] int32, nb = ceil(max_len /
-            # decode_sparse_block), nonzero = KV tile may be read): traced
-            # DATA riding the cache pytree (models/dalle.py threads it from
-            # the serving engine's host-side policy), so flipping or
-            # re-deriving the policy NEVER recompiles the chunk program.
-            # When present, it supersedes the pattern masks below — the
-            # engine derived it FROM those patterns (conservative
-            # block-level shadow, text prefix always live), and it unlocks
-            # the flash path for pattern-masked rows.
-            bitmap = cache.get("block_bitmap")
-            sparse = bitmap is not None
-            sparse_block = (
-                DECODE_SPARSE_BLOCK
-                if self.decode_sparse_block is None
-                else self.decode_sparse_block
-            )
-            # mirror the kernel's block_k clamp so bitmap widths agree on
-            # tiny caches (tests run seq_len << DECODE_SPARSE_BLOCK)
-            sparse_block = max(min(sparse_block, max_len), 1)
-            if self._use_flash_decode(
-                max_len,
-                has_pattern=(
-                    self.static_mask is not None or mask_array is not None
-                ),
-                sparse=sparse,
-            ):
-                # per-row live length = cache index + this chunk; the kernel
-                # applies the same causal-over-prefix mask the dense branch
-                # builds below, but reads ONLY each row's live K/V blocks
-                # (scalar index = lockstep decode: every row at one length)
-                lengths = jnp.broadcast_to(index + n, (b,)).astype(jnp.int32)
-                scales = {"k_scale": cks, "v_scale": cvs} if quant else {}
-                sparse_kw = (
-                    {"block_bitmap": bitmap, "sparse_block": sparse_block}
-                    if sparse else {}
-                )
-                if paged:
-                    if self.decode_mesh is not None:
-                        out = sharded_paged_decode_attention(
-                            self.decode_mesh, q, ck, cv, lengths, pt,
-                            max_len, head_axis=self.decode_heads_axis,
-                            **scales, **sparse_kw,
-                        )
-                    else:
-                        out = paged_decode_attention(
-                            q, ck, cv, lengths, pt, max_len,
-                            **scales, **sparse_kw,
-                        )
-                elif self.decode_mesh is not None:
-                    out = sharded_flash_decode_attention(
-                        self.decode_mesh, q, ck, cv, lengths,
-                        head_axis=self.decode_heads_axis,
-                        **scales, **sparse_kw,
-                    )
-                elif sparse:
-                    out = block_sparse_flash_decode_attention(
-                        q, ck, cv, lengths, bitmap,
-                        block_k=sparse_block, **scales,
-                    )
-                else:
-                    out = flash_decode_attention(q, ck, cv, lengths, **scales)
-            else:
-                with jax.named_scope("cache_read"):
-                    if paged:
-                        # one gathered view per dispatch; dead positions
-                        # hold garbage-page bytes but the causal mask below
-                        # replaces their scores with the same NEG constant
-                        # the slotted path uses, so outputs stay
-                        # bit-identical
-                        gk = paged_gather(ck, pt, max_len)
-                        gv = paged_gather(cv, pt, max_len)
-                        if quant:
-                            gk = _kv_dequantize(
-                                gk,
-                                paged_gather(cks[..., None], pt, max_len)[..., 0],
-                            )
-                            gv = _kv_dequantize(
-                                gv,
-                                paged_gather(cvs[..., None], pt, max_len)[..., 0],
-                            )
-                    else:
-                        gk, gv = ck, cv
-                        if quant:
-                            gk = _kv_dequantize(gk, cks)
-                            gv = _kv_dequantize(gv, cvs)
-                # query row i sits at global position index + i: causal over
-                # the written prefix (the reference instead relies on only
-                # having written the prefix, `attention.py:71-76,86`)
-                if per_row:
-                    valid = (
-                        jnp.arange(max_len)[None, None, :]
-                        <= index[:, None, None] + jnp.arange(n)[None, :, None]
-                    )
-                    mask = valid[:, None]  # [B,1,n,max_len]
-                else:
-                    valid = (
-                        jnp.arange(max_len)[None, :]
-                        <= index + jnp.arange(n)[:, None]
-                    )
-                    mask = valid[None, None]
+            out, new_cache = self._cached_lanes(q, k, v, cache, rotary, mask_array)
+        if tokens:  # the rotary beside the kernels
+            out = self._flash_columns(qkv, n, None if rotary is None else rotary[:n])
+        elif alone:
+            out = self._alone(q, k, v, key_mask, rotary, mask_array, flash)
 
-                def mask_rows_at(pm):
-                    # pad to max_len with True (decode caches may be 1
-                    # longer than the mask), then row-slice at the decode
-                    # position — shared by the host-side static_mask and
-                    # the scan executor's traced mask_array so the two
-                    # paths cannot drift
-                    if pm.shape[0] < max_len:
-                        pad = max_len - pm.shape[0]
-                        pm = jnp.pad(
-                            pm, ((0, pad), (0, pad)), constant_values=True
-                        )
-                    pm = pm[:, :max_len]
-                    if per_row:
-                        return jax.vmap(
-                            lambda i: lax.dynamic_slice_in_dim(pm, i, n, axis=0)
-                        )(index)[:, None]  # [B,1,n,max_len]
-                    return lax.dynamic_slice_in_dim(pm, index, n, axis=0)[
-                        None, None
-                    ]
-
-                if sparse:
-                    # the bitmap supersedes the pattern masks on the dense
-                    # fallback too (small caches / attn_impl="dense"), so
-                    # BOTH decode paths compute the identical block-level
-                    # policy — the sparse-vs-dense oracle the tests pin
-                    kv_live = jnp.repeat(bitmap != 0, sparse_block, axis=1)
-                    mask = mask & kv_live[:, :max_len][:, None, None, :]
-                else:
-                    if self.static_mask is not None:
-                        mask = mask & mask_rows_at(
-                            jnp.asarray(np.asarray(self.static_mask))
-                        )
-                    if mask_array is not None:
-                        mask = mask & mask_rows_at(mask_array)
-                with jax.named_scope("attend"):
-                    out = dense_attention(
-                        q, gk, gv, mask=mask, stable=self.stable
-                    )
-            # structural round-trip: the cache that comes back has the
-            # leaves it came with, side leaves included (the callers strip
-            # them from both layouts alike)
-            new_cache = {"k": written["k"], "v": written["v"], "index": index + n}
-            new_cache.update(written)  # + an int8 store's scale leaves
-            if paged:
-                new_cache["page_table"] = pt
-            if sparse:
-                new_cache["block_bitmap"] = bitmap
-        if alone:
-            if rotary is not None and not tokens:  # else beside the kernels, in `_flash_columns`
-                rot = jnp.expand_dims(rotary[:n], (0, 1))
-                q, k, v = (apply_rotary(rot, t) for t in (q, k, v))
-            if self.attn_impl == "ring":
-                # sequence-parallel exact attention: tokens sharded over the
-                # mesh "sp" axis, KV blocks rotate via ppermute (parallel/
-                # ring.py). Long-context path beyond the reference's
-                # sparsity-based scaling (SURVEY.md §5.7).
-                from dalle_pytorch_tpu.parallel.ring import ring_attention_sharded
-
-                assert self.sp_mesh is not None, 'attn_impl="ring" needs sp_mesh'
-                assert self.static_mask is None and key_mask is None, (
-                    "ring attention supports plain causal/full attention only"
-                )
-                # the streaming LSE accumulator is inherently max-subtracted;
-                # reject the stable flag rather than silently diverge from
-                # the dense stable-softmax numerics
-                assert not self.stable, 'attn_impl="ring" does not take stable='
-                sp = self.sp_mesh.shape["sp"]
-                assert n % sp == 0, (
-                    f"sequence length {n} must be divisible by the sp axis ({sp}); note "
-                    "the uncached generate_images() re-forwards growing "
-                    "prefixes — use the KV-cached decode path with ring models"
-                )
-                out = ring_attention_sharded(
-                    self.sp_mesh, q, k, v, causal=self.causal
-                )
-            elif flash:
-                if self.attn_impl == "lib_flash":
-                    out = lib_flash_attention(q, k, v, causal=self.causal)
-                elif tokens:
-                    out = self._flash_columns(qkv, n, None if rotary is None else rotary[:n])
-                else:
-                    out = self._flash(q, k, v, n)
-            else:
-                mask = self._full_mask(n, n)
-                mask = None if mask is None else jnp.asarray(mask)[None, None]
-                if k.shape[1] != h:  # the dense path spells the sharing out
-                    k, v = (jnp.repeat(t, h // t.shape[1], axis=1) for t in (k, v))
-                if mask_array is not None:
-                    tm = mask_array[:n, :n][None, None]
-                    mask = tm if mask is None else (mask & tm)
-                if key_mask is not None:
-                    km = key_mask[:, None, None, :]
-                    mask = km if mask is None else (mask & km)
-                out = dense_attention(q, k, v, mask=mask, stable=self.stable)
-
+        # 3. to_out
         if not tokens:
             out = out.transpose(0, 2, 1, 3)
-        out = out.reshape(b, n, inner)
         out = nn.Dense(self.dim, use_bias=self.use_bias, dtype=self.dtype,
-                       param_dtype=self.param_dtype, name="to_out")(out)
+                       param_dtype=self.param_dtype, name="to_out")(out.reshape(b, n, -1))
         out = nn.Dropout(self.dropout)(out, deterministic=deterministic)
         return out, new_cache
+
+    def _cached_lanes(self, q, k, v, cache, rotary, mask_array):
+        """`(out, cache)` of a cached chunk on the DALLE and LANES paths: the
+        chunk written into the cache's K/V lanes or pages at its index, then
+        attended against what the cache holds."""
+        b, _, n, _ = q.shape
+        # n-token chunk (prefill or single-token decode) written into a
+        # fixed-shape cache at sequence position `index`. A scalar index
+        # means the whole batch decodes in lockstep; a [B] index means
+        # per-row positions (continuous-batching slots admitted at
+        # different times) — every index-dependent op below (rotary row
+        # slice, cache write, causal mask, pattern-mask row slice) then
+        # runs per row via vmap, at identical per-row numerics.
+        #
+        # A cache carrying a "page_table" key is BLOCK-PAGED: k/v are a
+        # physical page pool [P, H, page_size, D] shared by all rows
+        # and the [B, n_pages] table maps each row's logical blocks to
+        # pages (serving/paging.py allocates; released rows point at
+        # the reserved garbage page 0, so a stale write can never
+        # corrupt a reallocated page). Reads either gather the row's
+        # logical view and run the IDENTICAL dense/flash path as the
+        # slotted cache (bit-for-bit — the paging parity contract) or
+        # stream pages directly through the paged Pallas kernel
+        # (ops/pallas_decode.py PAGED_DECODE_IMPL).
+        #
+        # A cache carrying a "layer" key is the scan executor's: k, v
+        # (and their scales) are the DEPTH-STACKED leaves [L, ...] held
+        # in the layer scan's carry, and `layer` is this layer's traced
+        # index. The chunk's n positions are written into the stack at
+        # [layer], in place; what attention reads is `stack[layer]` of
+        # the updated stack, a view nothing else consumes. The other
+        # leaves (index, page_table, block_bitmap) arrive as the
+        # layer's own.
+        index = cache["index"]
+        layer = cache.get(decode_cache.LAYER)
+        per_row = jnp.ndim(index) == 1
+        paged = "page_table" in cache
+        if rotary is not None:
+            if per_row:
+                rot = jax.vmap(
+                    lambda i: lax.dynamic_slice_in_dim(rotary, i, n, axis=0)
+                )(index)
+                rot = rot[:, None]  # [B,1,n,dr]
+            else:
+                rot = lax.dynamic_slice_in_dim(rotary, index, n, axis=0)
+                rot = jnp.expand_dims(rot, (0, 1))  # [1,1,n,dr]
+            q, k, v = (apply_rotary(rot, t) for t in (q, k, v))
+        # int8 KV cache: quantize AFTER rotary (the cache stores what
+        # attention reads), carry per-(position, head) fp32 scales in
+        # sibling leaves; q stays full precision
+        quant = "k_scale" in cache
+        if quant:
+            (qk, k_sc), (qv, v_sc) = _kv_quantize(k), _kv_quantize(v)
+            chunk = {"k": qk, "v": qv, "k_scale": k_sc, "v_scale": v_sc}
+        else:
+            chunk = {"k": k, "v": v}
+        # the chunk written at the cache's index (lanes or pages, a
+        # leaf or a stack at [layer]); max_len is the virtual
+        # contiguous length, the slotted cache's (total_seq_len + 1),
+        # so dense/flash see identical shapes on both stores
+        written, max_len = decode_cache.write(cache, chunk, self.seq_len + 1)
+        pt = cache.get("page_table")
+        # this layer's K/V as the reads below take it
+        ck, cv = (decode_cache.view(written[x], layer) for x in ("k", "v"))
+        cks = cvs = None
+        if quant:
+            cks, cvs = (
+                decode_cache.view(written[x], layer) for x in ("k_scale", "v_scale")
+            )
+        # policy block bitmap ([B, nb] int32, nb = ceil(max_len /
+        # decode_sparse_block), nonzero = KV tile may be read): traced
+        # DATA riding the cache pytree (models/dalle.py threads it from
+        # the serving engine's host-side policy), so flipping or
+        # re-deriving the policy NEVER recompiles the chunk program.
+        # When present, it supersedes the pattern masks below — the
+        # engine derived it FROM those patterns (conservative
+        # block-level shadow, text prefix always live), and it unlocks
+        # the flash path for pattern-masked rows.
+        bitmap = cache.get("block_bitmap")
+        sparse = bitmap is not None
+        sparse_block = (
+            DECODE_SPARSE_BLOCK
+            if self.decode_sparse_block is None
+            else self.decode_sparse_block
+        )
+        # mirror the kernel's block_k clamp so bitmap widths agree on
+        # tiny caches (tests run seq_len << DECODE_SPARSE_BLOCK)
+        sparse_block = max(min(sparse_block, max_len), 1)
+        if self._use_flash_decode(
+            max_len,
+            has_pattern=(
+                self.static_mask is not None or mask_array is not None
+            ),
+            sparse=sparse,
+        ):
+            # per-row live length = cache index + this chunk; the kernel
+            # applies the same causal-over-prefix mask the dense branch
+            # builds below, but reads ONLY each row's live K/V blocks
+            # (scalar index = lockstep decode: every row at one length)
+            lengths = jnp.broadcast_to(index + n, (b,)).astype(jnp.int32)
+            scales = {"k_scale": cks, "v_scale": cvs} if quant else {}
+            sparse_kw = (
+                {"block_bitmap": bitmap, "sparse_block": sparse_block}
+                if sparse else {}
+            )
+            if paged:
+                if self.decode_mesh is not None:
+                    out = sharded_paged_decode_attention(
+                        self.decode_mesh, q, ck, cv, lengths, pt,
+                        max_len, head_axis=self.decode_heads_axis,
+                        **scales, **sparse_kw,
+                    )
+                else:
+                    out = paged_decode_attention(
+                        q, ck, cv, lengths, pt, max_len,
+                        **scales, **sparse_kw,
+                    )
+            elif self.decode_mesh is not None:
+                out = sharded_flash_decode_attention(
+                    self.decode_mesh, q, ck, cv, lengths,
+                    head_axis=self.decode_heads_axis,
+                    **scales, **sparse_kw,
+                )
+            elif sparse:
+                out = block_sparse_flash_decode_attention(
+                    q, ck, cv, lengths, bitmap,
+                    block_k=sparse_block, **scales,
+                )
+            else:
+                out = flash_decode_attention(q, ck, cv, lengths, **scales)
+        else:
+            with jax.named_scope("cache_read"):
+                if paged:
+                    # one gathered view per dispatch; dead positions
+                    # hold garbage-page bytes but the causal mask below
+                    # replaces their scores with the same NEG constant
+                    # the slotted path uses, so outputs stay
+                    # bit-identical
+                    gk = paged_gather(ck, pt, max_len)
+                    gv = paged_gather(cv, pt, max_len)
+                    if quant:
+                        gk = _kv_dequantize(
+                            gk,
+                            paged_gather(cks[..., None], pt, max_len)[..., 0],
+                        )
+                        gv = _kv_dequantize(
+                            gv,
+                            paged_gather(cvs[..., None], pt, max_len)[..., 0],
+                        )
+                else:
+                    gk, gv = ck, cv
+                    if quant:
+                        gk = _kv_dequantize(gk, cks)
+                        gv = _kv_dequantize(gv, cvs)
+            # query row i sits at global position index + i: causal over
+            # the written prefix (the reference instead relies on only
+            # having written the prefix, `attention.py:71-76,86`)
+            if per_row:
+                valid = (
+                    jnp.arange(max_len)[None, None, :]
+                    <= index[:, None, None] + jnp.arange(n)[None, :, None]
+                )
+                mask = valid[:, None]  # [B,1,n,max_len]
+            else:
+                valid = (
+                    jnp.arange(max_len)[None, :]
+                    <= index + jnp.arange(n)[:, None]
+                )
+                mask = valid[None, None]
+
+            def mask_rows_at(pm):
+                # pad to max_len with True (decode caches may be 1
+                # longer than the mask), then row-slice at the decode
+                # position — shared by the host-side static_mask and
+                # the scan executor's traced mask_array so the two
+                # paths cannot drift
+                if pm.shape[0] < max_len:
+                    pad = max_len - pm.shape[0]
+                    pm = jnp.pad(
+                        pm, ((0, pad), (0, pad)), constant_values=True
+                    )
+                pm = pm[:, :max_len]
+                if per_row:
+                    return jax.vmap(
+                        lambda i: lax.dynamic_slice_in_dim(pm, i, n, axis=0)
+                    )(index)[:, None]  # [B,1,n,max_len]
+                return lax.dynamic_slice_in_dim(pm, index, n, axis=0)[
+                    None, None
+                ]
+
+            if sparse:
+                # the bitmap supersedes the pattern masks on the dense
+                # fallback too (small caches / attn_impl="dense"), so
+                # BOTH decode paths compute the identical block-level
+                # policy — the sparse-vs-dense oracle the tests pin
+                kv_live = jnp.repeat(bitmap != 0, sparse_block, axis=1)
+                mask = mask & kv_live[:, :max_len][:, None, None, :]
+            else:
+                if self.static_mask is not None:
+                    mask = mask & mask_rows_at(
+                        jnp.asarray(np.asarray(self.static_mask))
+                    )
+                if mask_array is not None:
+                    mask = mask & mask_rows_at(mask_array)
+            with jax.named_scope("attend"):
+                out = dense_attention(
+                    q, gk, gv, mask=mask, stable=self.stable
+                )
+        # structural round-trip: the cache that comes back has the
+        # leaves it came with, side leaves included (the callers strip
+        # them from both layouts alike)
+        new_cache = {"k": written["k"], "v": written["v"], "index": index + n}
+        new_cache.update(written)  # + an int8 store's scale leaves
+        if paged:
+            new_cache["page_table"] = pt
+        if sparse:
+            new_cache["block_bitmap"] = bitmap
+        return out, new_cache
+
+    def _alone(self, q, k, v, key_mask, rotary, mask_array, flash):
+        """The chunk attended by itself (head-major: ring, the flash kernels
+        or dense), the DALL-E rotary first where there is one."""
+        _, h, n, _ = q.shape
+        if rotary is not None:
+            rot = jnp.expand_dims(rotary[:n], (0, 1))
+            q, k, v = (apply_rotary(rot, t) for t in (q, k, v))
+        if self.attn_impl == "ring":
+            # sequence-parallel exact attention: tokens sharded over the
+            # mesh "sp" axis, KV blocks rotate via ppermute (parallel/
+            # ring.py). Long-context path beyond the reference's
+            # sparsity-based scaling (SURVEY.md §5.7).
+            from dalle_pytorch_tpu.parallel.ring import ring_attention_sharded
+
+            assert self.sp_mesh is not None, 'attn_impl="ring" needs sp_mesh'
+            assert self.static_mask is None and key_mask is None, (
+                "ring attention supports plain causal/full attention only"
+            )
+            # the streaming LSE accumulator is inherently max-subtracted;
+            # reject the stable flag rather than silently diverge from
+            # the dense stable-softmax numerics
+            assert not self.stable, 'attn_impl="ring" does not take stable='
+            sp = self.sp_mesh.shape["sp"]
+            assert n % sp == 0, (
+                f"sequence length {n} must be divisible by the sp axis ({sp}); note "
+                "the uncached generate_images() re-forwards growing "
+                "prefixes — use the KV-cached decode path with ring models"
+            )
+            return ring_attention_sharded(self.sp_mesh, q, k, v, causal=self.causal)
+        if flash:
+            return self._flash(q, k, v, n)
+        mask = self._full_mask(n, n)
+        mask = None if mask is None else jnp.asarray(mask)[None, None]
+        if k.shape[1] != h:  # the dense path spells the sharing out
+            k, v = (jnp.repeat(t, h // t.shape[1], axis=1) for t in (k, v))
+        if mask_array is not None:
+            tm = mask_array[:n, :n][None, None]
+            mask = tm if mask is None else (mask & tm)
+        if key_mask is not None:
+            km = key_mask[:, None, None, :]
+            mask = km if mask is None else (mask & km)
+        return dense_attention(q, k, v, mask=mask, stable=self.stable)
+
 
 
 class LatentAttention(nn.Module):
@@ -810,6 +843,10 @@ class LatentAttention(nn.Module):
     index_topk: int = 0
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        _known_impl(self.attn_impl)
+        super().__post_init__()
 
     @nn.compact
     def __call__(self, x, key_mask=None, rotary=None, cache=None, deterministic=True,

@@ -30,12 +30,15 @@ two positions a step, keeps the draft iff it IS the token the first
 position's logits give, and drops a rejected position from every layer by the
 row's index alone. `serving/paging.py` and the slot cache keep the DALL-E
 cache geometry (ROADMAP.md, Queue 2 B).
+
+Which layers a family builds (each layer's mixer and cached path, its cache
+kind, its rotary table, its feed-forward) is `CausalLM.plan()`, the trunk's
+`Transformer.plan`: what this module needs to know of a layer it reads there.
 """
 
 from __future__ import annotations
 
 import math
-from itertools import cycle, islice
 from typing import Any, Optional
 
 import jax
@@ -45,7 +48,7 @@ from flax.core import freeze
 from jax import lax
 
 from dalle_pytorch_tpu.models import decode_cache
-from dalle_pytorch_tpu.models.transformer import Transformer
+from dalle_pytorch_tpu.models.transformer import Transformer, routed_layers
 from dalle_pytorch_tpu.obs.tracing import host_span
 from dalle_pytorch_tpu.ops.losses import chunked_masked_ce
 from dalle_pytorch_tpu.ops.sampling import gumbel_sample, gumbel_sample_per_row, top_k_filter
@@ -243,7 +246,9 @@ class CausalLM(nn.Module):
         (what the matrices are stored in; `float32` where left out),
         `attn_impl`, `executor`, `moe_buffer_rows` (the static bound on the
         assignments a layer makes to the experts held), `reversible`,
-        `reversible_impl`. `overrides` replace keys of `program`."""
+        `reversible_impl`. `overrides` replace keys of `program`. Which layers
+        the options below build is not decided here: `plan()` of the model
+        made says it (`Transformer.plan`)."""
         prog = dict(cfg.get("program", {}), **overrides)
         depth = int(cfg["num_hidden_layers"])
         if (cfg["hidden_act"] != "silu" or cfg.get("attention_bias", False)
@@ -295,11 +300,8 @@ class CausalLM(nn.Module):
             embedding_init=nn.initializers.normal(self.dim**-0.5),
         )
         self.transformer = Transformer(
-            dim=self.dim, depth=self.depth, seq_len=self.seq_len, heads=self.heads,
-            dim_head=self.dim_head, causal=True, reversible=self.reversible,
-            reversible_impl=self.reversible_impl, remat_policy=self.remat_policy,
-            rotary_emb=False, dtype=self.dtype, **trunk,
-        )
+            reversible=self.reversible, reversible_impl=self.reversible_impl,
+            remat_policy=self.remat_policy, **self._trunk_options())
         norm = trunk.get("norm", "layer")
         self.logits_norm = (
             nn.RMSNorm(epsilon=trunk.get("norm_eps", 1e-6), dtype=self.dtype)
@@ -315,10 +317,9 @@ class CausalLM(nn.Module):
         self.mtp_proj = nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
                                  param_dtype=self.param_dtype)
         # the trunk's block, with full attention and a routed feed-forward
-        self.mtp_block = Transformer(
-            dim=self.dim, depth=1, seq_len=self.seq_len, heads=self.heads,
-            dim_head=self.dim_head, rotary_emb=False, dtype=self.dtype,
-            **{**trunk, "attn_types": ("full",), "ff_kinds": ("swiglu_experts",)})
+        self.mtp_block = Transformer(**{
+            **self._trunk_options(), "depth": 1, "attn_types": ("full",),
+            "ff_kinds": ("swiglu_experts",)})
 
     def hidden(self, tokens: jnp.ndarray) -> jnp.ndarray:
         """[B, N, dim]: the trunk's output under the final norm."""
@@ -407,17 +408,25 @@ class CausalLM(nn.Module):
                 dim=self.dim, dtype=self.dtype)[decode_cache.layer_key(0)]
         return cache
 
+    def plan(self) -> tuple:
+        """The trunk's plan, a `LayerPlan` a layer (`Transformer.plan`: which
+        layers a family builds, their cache kinds and cached paths)."""
+        return self._trunk().plan()
+
     @property
     def per_row(self) -> bool:
         """Whether the cache keeps every row at its own position (the trunk
         of grouped K/V heads, window layers or a rotate-half rotary)."""
-        return self._trunk()._per_row_cache()
+        return self.plan()[0].per_row
+
+    def _trunk_options(self) -> dict:
+        return dict(dim=self.dim, depth=self.depth, seq_len=self.seq_len, heads=self.heads,
+                    dim_head=self.dim_head, rotary_emb=False, dtype=self.dtype,
+                    **dict(self.trunk or {}))
 
     def _trunk(self) -> Transformer:
-        """The trunk's configuration, unbound (for `init_cache`'s arithmetic)."""
-        return Transformer(dim=self.dim, depth=self.depth, seq_len=self.seq_len,
-                           heads=self.heads, dim_head=self.dim_head, rotary_emb=False,
-                           dtype=self.dtype, parent=None, **dict(self.trunk or {}))
+        """The trunk's configuration, unbound (for its plan and `init_cache`'s arithmetic)."""
+        return Transformer(parent=None, **self._trunk_options())
 
     def extend(self, tokens: jnp.ndarray, cache: dict) -> dict:
         """The cache after further `tokens` [B, n] at the cache's index, each
@@ -461,10 +470,7 @@ class CausalLM(nn.Module):
         the cache's index."""
         x = self.token_emb(token[:, None]).astype(self.dtype)
         x, cache = self.transformer(x, cache=cache)
-        h = self.logits_norm(x[:, 0])
-        kernel = self.logits_dense.variables["params"]["kernel"]
-        with jax.named_scope("logits_head"):  # the `head` component (obs/scopes.py)
-            logits = jnp.dot(h, kernel.astype(h.dtype), preferred_element_type=jnp.float32)
+        logits = self._head(self.logits_norm(x[:, 0]))
         # whole once: the compiler otherwise computes the product again for its
         # second reader (a step samples from the logits AND keeps rows of
         # them), which at 100,352 ids is another read of the head (PERF.md, PR 33)
@@ -520,7 +526,7 @@ def prefill_chunks(model: CausalLM, variables, tokens: jnp.ndarray, chunk: int):
     `CausalLM.extend` (host span `lm.prefill` each), every chunk attending
     what the cache holds by then and itself; the routed layers' counts summed
     over the chunks. A trunk of latent layers: any other raises here."""
-    if tuple(dict(model.trunk or {}).get("attn_types") or ()) != ("latent",):
+    if {layer.cache_kind for layer in model.plan()} != {"latent"}:
         raise NotImplementedError("a prefill in chunks takes a trunk of latent layers")
     extend = _jitted(_extend_builder, model, ())
     fresh, counts = model.init_cache(*tokens.shape), None
@@ -543,9 +549,8 @@ def place_rows(model: CausalLM, cache: dict, fresh: dict, row: int = 0) -> dict:
 
 def _rings(model, cache: dict) -> list:
     """The K leaves of a cache's window layers (rings)."""
-    kinds = islice(cycle(dict(model.trunk or {}).get("attn_types") or ("full",)), model.depth)
     return [cache[decode_cache.layer_key(i)][decode_cache.ATTN][decode_cache.K]
-            for i, kind in enumerate(kinds) if kind == "window"]
+            for i, layer in enumerate(model.plan()) if layer.cache_kind == "window"]
 
 
 def _prefill_builder(model, key):
@@ -677,6 +682,16 @@ def _moe_counts(stats: dict) -> dict:
     return out
 
 
+def _zero_moe_counts(model, drafting: bool = False) -> dict:
+    """A token loop's zeroed `_moe_counts`: of the layers the plan routes and,
+    `drafting`, the multi-token module's block; nothing where none routes."""
+    routed = routed_layers(model.plan()) + drafting
+    zeros = lambda *shape: jnp.zeros(shape, jnp.int32)
+    return {} if not routed else {
+        "moe_load": zeros(routed, model.trunk["experts_held"][1]),
+        **{k: zeros(routed) for k in ("moe_rows", "moe_dropped", "moe_touched")}}
+
+
 def _sown(layers: dict, key: str) -> list:
     """What the trunk's layers sowed under `key`, in layer order."""
     return [layers[n][key] for n in sorted((n for n in layers if key in layers[n]),
@@ -706,10 +721,10 @@ def _picks(picked: dict, rows: int) -> dict:
 
 def _sampler_builder(model, key):
     steps, filter_thres, temperature, logit_rows = key
-    trunk = dict(model.trunk or {})
     # a trunk that selects the positions it attends says, for the rows whose
     # logits are kept, what it selected and what its first router chose
-    picking = ["picks"] if trunk.get("index_topk") and logit_rows else []
+    index_topk = max(layer.selects for layer in model.plan())
+    picking = ["picks"] if index_topk and logit_rows else []
 
     def lm_sample(variables, rng, cache, forced, start):
         batch, n_forced = forced.shape
@@ -736,14 +751,9 @@ def _sampler_builder(model, key):
             return (cache, new, rng, counts), ys + ((_picks(aux["picks"], logit_rows),)
                                                      if picking else ())
 
-        routed = sum(k == "swiglu_experts" for k in
-                     trunk.get("ff_kinds") or (trunk.get("ff_kind"),) * model.depth)
-        zeros = lambda *shape: jnp.zeros(shape, jnp.int32)
-        counts = {} if not routed else {
-            "moe_load": zeros(routed, trunk["experts_held"][1]),
-            **{k: zeros(routed) for k in ("moe_rows", "moe_dropped", "moe_touched")}}
-        if trunk.get("index_topk", 0) and trunk["index_topk"] < decode_cache.max_len(cache):
-            counts.update({k: zeros(model.depth) for k in DSA_COUNTS})
+        counts = _zero_moe_counts(model)
+        if index_topk and index_topk < decode_cache.max_len(cache):
+            counts.update({k: jnp.zeros((model.depth,), jnp.int32) for k in DSA_COUNTS})
         carry = (cache, jnp.zeros((batch,), jnp.int32), rng, counts)
         (cache, _, _, counts), (tokens, logits, *picked) = lax.scan(
             step, carry, jnp.arange(steps))
@@ -858,14 +868,8 @@ def _verify_sampler_builder(model, key):
             (first, module), _ = run(CausalLM.draft_step, c[:, None],
                                      module[decode_cache.HIDDEN][:, None], module)
             d = propose(first[:, 0], start + 1)
-        trunk = dict(model.trunk or {})
-        routed = sum(k == "swiglu_experts" for k in
-                     trunk.get("ff_kinds") or (trunk.get("ff_kind"),) * model.depth) + drafting
         zeros = lambda *shape: jnp.zeros(shape, jnp.int32)
-        counts = {"accepted": zeros(batch)}
-        if routed:
-            counts.update(moe_load=zeros(routed, trunk["experts_held"][1]),
-                          **{k: zeros(routed) for k in ("moe_rows", "moe_dropped", "moe_touched")})
+        counts = {"accepted": zeros(batch), **_zero_moe_counts(model, drafting)}
         carry = (cache, module, start, c, d, zeros(batch), zeros(batch, cap), counts)
         (cache, module, _, _, _, count, out, counts), (logits, at, fed, kept, drafts) = lax.scan(
             step, carry, None, length=steps)

@@ -51,9 +51,10 @@ Layer executors (orthogonal to the reversible memory modes):
 
 from __future__ import annotations
 
+import functools
 import math
 from itertools import cycle, islice
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -62,9 +63,11 @@ import flax.linen as nn
 
 from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.models.attention import (
+    LATENT, LINEAR, ROWS,
     Attention,
     GatedDeltaAttention,
     LatentAttention,
+    attention_path,
 )
 from dalle_pytorch_tpu.ops.masks import (
     axial_static_mask,
@@ -199,6 +202,67 @@ def shift_with_ring(h, ring, pos, text_len, fmap, ring_end=None):
                 return shifted, shift_ring_from_prefill_at(h, fmap, ring_end)
             return shifted, shift_ring_from_prefill(h, fmap)
         return shift_token_step(h, ring, pos, text_len, fmap)
+
+
+class LayerPlan(NamedTuple):
+    """What ONE layer of the stack is: decided once, from the trunk's options
+    (`Transformer.plan`), and read wherever a layer's kind matters."""
+
+    kind: str  # of attention: full, axial_row, axial_col, conv_like, sparse, window, latent, linear
+    attn_id: int  # layers of one id share their mixer's weights (`attn_{id}`)
+    ff_id: int  # and their feed-forward's (`ff_{id}`)
+    # the mixer it is built as and the path that mixer's cached call takes:
+    # DALLE, LANES, ROWS, LATENT or LINEAR of models/attention.py
+    path: str
+    cache_kind: str  # what `decode_cache.layer_spec` is asked for: heads, window, latent, recurrent
+    # positions a latent layer's lightning indexer selects for a query (0: no
+    # indexer; with one the layer's cache keeps the indexer's keys too)
+    selects: int
+    # the cache's index is per row whoever asks (a stack with a layer on
+    # ROWS); else scalar or, over the DALL-E lanes, the caller's choice
+    per_row: bool
+    rotary: Optional[str]  # which rotate-half table it is handed (a key of `rotary_specs`)
+    takes_start: bool  # whether its call is told that a chunk starts the rows' sequences
+    ff_kind: str  # geglu, swiglu, swiglu_experts (routed: `routed_layers`)
+
+
+def routed_layers(plan) -> int:
+    """How many layers of a plan route their tokens to experts."""
+    return sum(layer.ff_kind == "swiglu_experts" for layer in plan)
+
+
+CACHE_KINDS = {"latent": "latent", "linear": "recurrent", "window": "window"}
+
+
+@functools.lru_cache(maxsize=None)
+def _stack_plan(depth, attn_types, attn_ids, ff_ids, ff_kinds, heads, kv_heads, qk_norm,
+                window, rotated, index_topk) -> Tuple[LayerPlan, ...]:
+    """`Transformer.plan` over the options it reads, made hashable: once a
+    set of options, not once a call."""
+    assert len(ff_kinds) == depth, f"{len(ff_kinds)} ff_kinds for {depth} layers"
+    kinds = tuple(islice(cycle(attn_types), depth))
+    attn_ids = tuple(islice(cycle(attn_ids or range(depth)), depth))
+    ff_ids = tuple(islice(cycle(ff_ids or range(depth)), depth))
+    paths, kind_of_id = [], {}
+    for ind, (kind, attn_id) in enumerate(zip(kinds, attn_ids)):
+        if kind_of_id.setdefault(attn_id, kind) != kind:
+            raise ValueError(
+                "attn_types do not match shared_attn_ids "
+                f"(ind = {ind}, attn_type = {kind!r}, "
+                f"reused_attn_type = {kind_of_id[attn_id]!r})"
+            )
+        if kind == "window":
+            assert window, 'attn_types has "window" and the model no window length'
+        paths.append(kind if kind in (LATENT, LINEAR) else attention_path(
+            heads, kv_heads, qk_norm, window if kind == "window" else None, kind in rotated))
+    per_row = ROWS in paths
+    return tuple(
+        LayerPlan(kind=kind, attn_id=attn_id, ff_id=ff_id, path=path,
+                  cache_kind=CACHE_KINDS.get(kind, "heads"), per_row=per_row,
+                  selects=index_topk if kind == "latent" else 0,
+                  rotary=kind if kind in rotated else None,
+                  takes_start=kind != "linear", ff_kind=ff_kind)
+        for kind, attn_id, ff_id, path, ff_kind in zip(kinds, attn_ids, ff_ids, paths, ff_kinds))
 
 
 class _ScanBlock(nn.Module):
@@ -489,11 +553,11 @@ class Transformer(nn.Module):
             return f"the block option {self._block_variant()} (one scanned body, one kind of layer)"
         if self.attn_types and any(t != "full" for t in self.attn_types):
             # masked attn types run as dense + per-layer pattern masks
-            # scanned over depth; flash/lib_flash need host-side masks for
-            # block skipping, so they cannot take the scanned (traced) ones
-            if self.attn_impl in ("flash", "lib_flash"):
+            # scanned over depth; flash needs host-side masks for block
+            # skipping, so it cannot take the scanned (traced) ones
+            if self.attn_impl == "flash":
                 return (
-                    f'attn_impl="{self.attn_impl}" with masked attn_types '
+                    'attn_impl="flash" with masked attn_types '
                     "(scanned pattern masks are traced; use dense/auto)"
                 )
         if self.shared_attn_ids or self.shared_ff_ids:
@@ -524,112 +588,15 @@ class Transformer(nn.Module):
             return
         assert self.executor == "unrolled", f"unknown executor {self.executor!r}"
         depth = self.depth
-        attn_types = tuple(self.attn_types) if self.attn_types else ("full",)
-        type_per_layer = list(islice(cycle(attn_types), depth))
-        attn_ids = list(islice(cycle(self.shared_attn_ids or range(depth)), depth))
-        ff_ids = list(islice(cycle(self.shared_ff_ids or range(depth)), depth))
-        ff_kinds = tuple(self.ff_kinds) if self.ff_kinds else (self.ff_kind,) * depth
-        assert len(ff_kinds) == depth, f"{len(ff_kinds)} ff_kinds for {depth} layers"
-
-        shared_attn, shared_attn_type = {}, {}
-        shared_ff = {}
-        attn_layers, ff_layers = [], []
-        for ind in range(depth):
-            attn_type, attn_id, ff_id = type_per_layer[ind], attn_ids[ind], ff_ids[ind]
-            if attn_id in shared_attn:
-                if shared_attn_type[attn_id] != attn_type:
-                    raise ValueError(
-                        "attn_types do not match shared_attn_ids "
-                        f"(ind = {ind}, attn_type = {attn_type!r}, "
-                        f"reused_attn_type = {shared_attn_type[attn_id]!r})"
-                    )
-                attn = shared_attn[attn_id]
-            elif attn_type == "latent":
-                attn = shared_attn[attn_id] = LatentAttention(
-                    dim=self.dim, seq_len=self.seq_len, heads=self.heads,
-                    q_lora_rank=self.q_lora_rank, kv_lora_rank=self.kv_lora_rank,
-                    qk_nope_dim=self.qk_nope_dim, qk_rope_dim=self.qk_rope_dim,
-                    v_dim=self.v_dim, norm_eps=self.norm_eps, attn_impl=self.attn_impl,
-                    softmax_mult=self.softmax_mult, index_heads=self.index_heads,
-                    index_dim=self.index_dim, index_topk=self.index_topk,
-                    dtype=self.dtype, param_dtype=self.param_dtype, name=f"attn_{attn_id}",
-                )
-                shared_attn_type[attn_id] = attn_type
-            elif attn_type == "linear":
-                attn = shared_attn[attn_id] = GatedDeltaAttention(
-                    dim=self.dim, seq_len=self.seq_len, heads=self.linear_heads,
-                    key_dim=self.linear_key_dim, value_dim=self.linear_value_dim,
-                    conv_width=self.linear_conv, norm_eps=self.norm_eps, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name=f"attn_{attn_id}",
-                )
-                shared_attn_type[attn_id] = attn_type
-            else:
-                attn = Attention(
-                    dim=self.dim,
-                    seq_len=self.seq_len,
-                    heads=self.heads,
-                    dim_head=self.dim_head,
-                    causal=self.causal,
-                    dropout=self.attn_dropout,
-                    stable=self.stable,
-                    static_mask=None if attn_type == "window" else _build_static_mask(
-                        attn_type, self.seq_len, self.image_fmap_size, ind
-                    ),
-                    attn_impl=self.attn_impl,
-                    sp_mesh=self.sp_mesh,
-                    decode_mesh=self.decode_mesh,
-                    train_mesh=self.train_mesh,
-                    decode_heads_axis=self.decode_heads_axis,
-                    decode_sparse_block=self.decode_sparse_block,
-                    dtype=self.dtype,
-                    name=f"attn_{attn_id}",
-                    **self._attn_variant(attn_type),
-                )
-                shared_attn[attn_id] = attn
-                shared_attn_type[attn_id] = attn_type
-            attn_layers.append(attn)
-
-            if ff_id in shared_ff:
-                ff = shared_ff[ff_id]
-            elif ff_kinds[ind] == "swiglu_experts":
-                ff = shared_ff[ff_id] = RoutedExperts(
-                    dim=self.dim,
-                    expert_dim=self.expert_dim,
-                    experts_total=self.experts_total,
-                    experts_per_token=self.experts_per_token,
-                    experts_held=tuple(self.experts_held),
-                    buffer_rows=self.moe_buffer_rows,
-                    score=self.moe_score,
-                    routed_scale=self.routed_scale,
-                    shared_dim=self.shared_dim,
-                    score_bias=self.moe_score_bias,
-                    groups=tuple(self.moe_groups),
-                    dtype=self.dtype,
-                    param_dtype=self.param_dtype,
-                    name=f"ff_{ff_id}",
-                )
-            elif ff_kinds[ind] == "swiglu":
-                ff = shared_ff[ff_id] = SwiGLU(
-                    dim=self.dim, hidden=self.ff_dim, dtype=self.dtype,
-                    param_dtype=self.param_dtype, name=f"ff_{ff_id}",
-                )
-            else:
-                assert ff_kinds[ind] == "geglu", f"unknown ff_kind {ff_kinds[ind]!r}"
-                assert self.use_bias, "the GEGLU feed-forward keeps its biases"
-                ff = FeedForward(
-                    dim=self.dim,
-                    mult=self.ff_mult,
-                    dropout=self.ff_dropout,
-                    dtype=self.dtype,
-                    name=f"ff_{ff_id}",
-                )
-                shared_ff[ff_id] = ff
-            ff_layers.append(ff)
-
-        self.attn_layers = attn_layers
-        self.ff_layers = ff_layers
-        self.ff_kind_per_layer = ff_kinds
-        self.type_per_layer = tuple(type_per_layer)
+        plan = self.plan()
+        shared_attn, shared_ff = {}, {}
+        for ind, layer in enumerate(plan):
+            if layer.attn_id not in shared_attn:
+                shared_attn[layer.attn_id] = self._mixer(ind, layer)
+            if layer.ff_id not in shared_ff:
+                shared_ff[layer.ff_id] = self._feed_forward(layer)
+        self.attn_layers = [shared_attn[layer.attn_id] for layer in plan]
+        self.ff_layers = [shared_ff[layer.ff_id] for layer in plan]
         assert self.prenorm or self.sandwich_norm, "a sublayer with no norm at all"
         if self.prenorm:
             self.attn_norms = [self._norm() for _ in range(depth)]
@@ -662,19 +629,74 @@ class Transformer(nn.Module):
             for i in range(depth)
         ]
 
-    def _attn_variant(self, attn_type: str) -> dict:
-        """What an attention layer of this kind is given beyond the DALL-E
-        block's arguments: nothing, for that block."""
-        if attn_type == "window":
-            assert self.window, 'attn_types has "window" and the model no window length'
-        if self._block_variant() is None:
-            return {}
-        return dict(
+    def plan(self) -> Tuple[LayerPlan, ...]:
+        """The plan of the stack, a `LayerPlan` a layer: which mixer, which
+        cache kind and which cached path each layer takes, its rotary table,
+        its feed-forward. THE one derivation: `setup`, `init_cache`, the
+        pattern table, models/lm.py and serving/sparsity.py read it. Pure
+        config math: usable unbound, as `init_cache` is."""
+        return _stack_plan(
+            self.depth, tuple(self.attn_types or ("full",)), tuple(self.shared_attn_ids or ()),
+            tuple(self.shared_ff_ids or ()), tuple(self.ff_kinds or (self.ff_kind,) * self.depth),
+            self.heads, self.kv_heads, self.qk_norm, self.window,
+            frozenset(dict(self.rotary_specs or {})), self.index_topk)
+
+    def _mixer(self, ind: int, layer: LayerPlan):
+        """The mixer the plan says layer `ind` is built as."""
+        name = f"attn_{layer.attn_id}"
+        if layer.path == LATENT:
+            return LatentAttention(
+                dim=self.dim, seq_len=self.seq_len, heads=self.heads,
+                q_lora_rank=self.q_lora_rank, kv_lora_rank=self.kv_lora_rank,
+                qk_nope_dim=self.qk_nope_dim, qk_rope_dim=self.qk_rope_dim,
+                v_dim=self.v_dim, norm_eps=self.norm_eps, attn_impl=self.attn_impl,
+                softmax_mult=self.softmax_mult, index_heads=self.index_heads,
+                index_dim=self.index_dim, index_topk=self.index_topk,
+                dtype=self.dtype, param_dtype=self.param_dtype, name=name,
+            )
+        if layer.path == LINEAR:
+            return GatedDeltaAttention(
+                dim=self.dim, seq_len=self.seq_len, heads=self.linear_heads,
+                key_dim=self.linear_key_dim, value_dim=self.linear_value_dim,
+                conv_width=self.linear_conv, norm_eps=self.norm_eps, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name,
+            )
+        # beyond the DALL-E block's arguments: nothing, for that block
+        variant = {} if self._block_variant() is None else dict(
             kv_heads=self.kv_heads, qk_norm=self.qk_norm, norm_eps=self.norm_eps,
             use_bias=self.use_bias, param_dtype=self.param_dtype,
-            window=self.window if attn_type == "window" else None,
+            window=self.window if layer.kind == "window" else None,
             step_positions=self.draft_positions + 1,
         )
+        return Attention(
+            dim=self.dim, seq_len=self.seq_len, heads=self.heads, dim_head=self.dim_head,
+            causal=self.causal, dropout=self.attn_dropout, stable=self.stable,
+            static_mask=None if layer.kind == "window" else _build_static_mask(
+                layer.kind, self.seq_len, self.image_fmap_size, ind),
+            attn_impl=self.attn_impl, sp_mesh=self.sp_mesh, decode_mesh=self.decode_mesh,
+            train_mesh=self.train_mesh, decode_heads_axis=self.decode_heads_axis,
+            decode_sparse_block=self.decode_sparse_block, dtype=self.dtype,
+            path=layer.path, name=name, **variant,
+        )
+
+    def _feed_forward(self, layer: LayerPlan):
+        name = f"ff_{layer.ff_id}"
+        if layer.ff_kind == "swiglu_experts":
+            return RoutedExperts(
+                dim=self.dim, expert_dim=self.expert_dim, experts_total=self.experts_total,
+                experts_per_token=self.experts_per_token, experts_held=tuple(self.experts_held),
+                buffer_rows=self.moe_buffer_rows, score=self.moe_score,
+                routed_scale=self.routed_scale, shared_dim=self.shared_dim,
+                score_bias=self.moe_score_bias, groups=tuple(self.moe_groups),
+                dtype=self.dtype, param_dtype=self.param_dtype, name=name,
+            )
+        if layer.ff_kind == "swiglu":
+            return SwiGLU(dim=self.dim, hidden=self.ff_dim, dtype=self.dtype,
+                          param_dtype=self.param_dtype, name=name)
+        assert layer.ff_kind == "geglu", f"unknown ff_kind {layer.ff_kind!r}"
+        assert self.use_bias, "the GEGLU feed-forward keeps its biases"
+        return FeedForward(dim=self.dim, mult=self.ff_mult, dropout=self.ff_dropout,
+                           dtype=self.dtype, name=name)
 
     def _derived_text_len(self) -> int:
         return (
@@ -733,8 +755,7 @@ class Transformer(nn.Module):
         attn-type cycle, or (None, None) for uniform full attention.
         Pure config math (usable unbound — the pipeline executor rebuilds
         it outside this module's scope)."""
-        attn_types = tuple(self.attn_types) if self.attn_types else ("full",)
-        type_per_layer = list(islice(cycle(attn_types), self.depth))
+        type_per_layer = [layer.kind for layer in self.plan()]
         if not any(t != "full" for t in type_per_layer):
             return None, None
         S = self.seq_len
@@ -805,11 +826,9 @@ class Transformer(nn.Module):
                 h, layer_cache.get("shift_attn") if cached else None, pos,
                 ring_end=layer_cache.get("ring_end") if cached else None,
             )
-        variant = (
-            {"rotary_cs": self.rotary_cs[self.type_per_layer[i]]}
-            if self.type_per_layer[i] in self.rotary_cs else {}
-        )
-        if start and self.type_per_layer[i] != "linear":
+        layer = self.plan()[i]
+        variant = {"rotary_cs": self.rotary_cs[layer.rotary]} if layer.rotary else {}
+        if start and layer.takes_start:
             variant["start"] = True  # a linear layer takes any longer chunk for a start
         h, attn_cache = self.attn_layers[i](
             h,
@@ -849,7 +868,7 @@ class Transformer(nn.Module):
         token of x [B, N, dim] (the trunk's input), through the layers
         before it and its own attention half: what the routed layer itself
         would choose, read outside any train step."""
-        assert self.ff_kind_per_layer[layer] == "swiglu_experts", "only a routed layer chooses"
+        assert self.plan()[layer].ff_kind == "swiglu_experts", "only a routed layer chooses"
         for i in range(layer):
             x = self._layer(i, x, None, None, True)[0]
         x = x + self._half_attn(layer, x, None, None, True)[0]
@@ -1035,15 +1054,6 @@ class Transformer(nn.Module):
             decode_cache.STACKED if self.executor == "scan" else decode_cache.PER_LAYER
         )
 
-    def _per_row_cache(self) -> bool:
-        """Whether cached attention runs `Attention._cached_grouped`, which
-        keeps every row at its own position: K/V heads shared by query heads,
-        a window layer, or a rotate-half rotary outside the latent layer."""
-        kinds = set(self.attn_types or ("full",))
-        return bool(kinds & {"full", "window"} and (
-            (self.kv_heads or self.heads) != self.heads or "window" in kinds
-            or set(dict(self.rotary_specs or {})) & {"full", "window"}))
-
     def init_cache(
         self, batch: int, max_len: int, dtype=jnp.float32, *,
         per_row: bool = False, pages: Optional[tuple] = None, kv_dtype=None,
@@ -1052,49 +1062,35 @@ class Transformer(nn.Module):
         rings), in the layout its executor takes. Pure config math: usable
         unbound. `per_row`, `pages = (n_pages, page_size)` and `kv_dtype`
         as `decode_cache.layer_spec` reads them. Each layer takes the kind
-        its attention is of: latent, recurrent (linear attention), a window's
+        the plan gives it: latent, recurrent (linear attention), a window's
         ring (of `window + draft_positions` slots) or K/V heads; linear,
-        window and K/V layers may share a cache."""
-        cache_kind = {"latent": "latent", "linear": "recurrent"}
-        types = list(islice(cycle(self.attn_types or ("full",)), self.depth))
-        kinds = [cache_kind.get(t, "heads") for t in types]
+        window and K/V layers may share a cache, and a stack with a layer on
+        the ROWS path keeps every row at its own index."""
+        plan = self.plan()
+        kinds = [layer.cache_kind for layer in plan]
         if "latent" in kinds and set(kinds) != {"latent"}:
             raise NotImplementedError(
                 "latent layers beside K/V or recurrent ones in one cache are not built "
                 "(recurrent and K/V layers are)")
-        if self._per_row_cache():
-            assert "recurrent" not in kinds and pages is None and kv_dtype is None
-            return decode_cache.make(
-                self.cache_layout, self.depth,
-                kinds=["window" if t == "window" else "heads" for t in types],
-                batch=batch, max_len=max_len, per_row=True, dtype=dtype, dim=self.dim,
-                heads=self.kv_heads or self.heads, dim_head=self.dim_head,
-                ring=(self.window or 0) + self.draft_positions,
-            )
-        if set(kinds) != {"heads"}:
-            assert not per_row and pages is None and kv_dtype is None
-            return decode_cache.make(
-                self.cache_layout, self.depth, kinds=kinds, batch=batch, max_len=max_len,
-                heads=self.heads, dim_head=self.dim_head, dim=self.dim,
-                latent_dim=self.kv_lora_rank, rope_dim=self.qk_rope_dim,
-                index_dim=self.index_dim if self.index_topk else None,
-                linear_heads=self.linear_heads, key_dim=self.linear_key_dim,
-                value_dim=self.linear_value_dim, conv_taps=self.linear_conv, dtype=dtype,
-            )
+        if any(layer.path == ROWS and layer.cache_kind == "heads" and layer.kind != "full"
+               for layer in plan):
+            raise NotImplementedError(
+                "a patterned layer over shared K/V heads or under a rotate-half rotary "
+                "has no cached path (full and window layers do)")
+        # K/V lanes alone, at the caller's index: what the DALL-E ladder
+        # pages, quantises and keeps its token-shift rings beside
+        plain = not plan[0].per_row and set(kinds) == {"heads"}
+        assert plain or (pages is None and kv_dtype is None)
         return decode_cache.make(
-            self.cache_layout,
-            self.depth,
-            batch=batch,
-            max_len=max_len,
-            pages=pages,
-            per_row=per_row,
-            heads=self.heads,
-            dim_head=self.dim_head,
-            dim=self.dim,
-            image_fmap_size=self.image_fmap_size,
-            shift_tokens=self.shift_tokens,
-            dtype=dtype,
-            kv_dtype=kv_dtype,
+            self.cache_layout, self.depth, kinds=kinds, batch=batch, max_len=max_len,
+            dtype=dtype, per_row=per_row or plan[0].per_row, pages=pages, kv_dtype=kv_dtype,
+            heads=self.kv_heads or self.heads, dim_head=self.dim_head, dim=self.dim,
+            image_fmap_size=self.image_fmap_size, shift_tokens=self.shift_tokens and plain,
+            ring=(self.window or 0) + self.draft_positions,
+            latent_dim=self.kv_lora_rank, rope_dim=self.qk_rope_dim,
+            index_dim=self.index_dim if any(layer.selects for layer in plan) else None,
+            linear_heads=self.linear_heads, key_dim=self.linear_key_dim,
+            value_dim=self.linear_value_dim, conv_taps=self.linear_conv,
         )
 
 

@@ -898,36 +898,3 @@ def flash_attention(
         return out[:, :n_q].reshape(out.shape[0], n_q, hq, d)
     return out[:, :, :n_q, :]
 
-
-def lib_flash_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    *,
-    causal: bool = True,
-    sm_scale: Optional[float] = None,
-) -> jnp.ndarray:
-    """jax's library TPU flash kernel (pallas.ops.tpu.flash_attention)
-    behind the in-repo calling convention ([B, H, N, D], scale d^-0.5).
-
-    Alternative backend to the in-repo `flash_attention` for plain
-    causal/full attention (no static-mask block skipping — the library
-    kernel has no occupancy layout). Not measured on the chip against
-    `flash` (ROADMAP D4: a path a user picks and no cell runs);
-    differentiable (the library defines its own custom VJP).
-
-    CPU caveat: the interpret guard below covers only the forward trace;
-    the library's custom-VJP backward traces its own pallas_calls at grad
-    time, so CPU *training* with lib_flash must run the whole grad inside
-    `pltpu.force_tpu_interpret_mode()` (tests do). On TPU none of this
-    applies. This is a TPU-hardware option; `flash` is the portable one.
-    """
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as _lib,
-    )
-
-    scale = q.shape[-1] ** -0.5 if sm_scale is None else sm_scale
-    if _use_interpret():
-        with pltpu.force_tpu_interpret_mode():
-            return _lib(q, k, v, causal=causal, sm_scale=scale)
-    return _lib(q, k, v, causal=causal, sm_scale=scale)

@@ -33,12 +33,10 @@ retirement, and even swapping the whole policy never trigger a compile
 
 from __future__ import annotations
 
-from itertools import cycle, islice
-
 import numpy as np
 
 from dalle_pytorch_tpu.models.attention import DECODE_SPARSE_BLOCK
-from dalle_pytorch_tpu.models.transformer import _build_static_mask
+from dalle_pytorch_tpu.models.transformer import Transformer, _build_static_mask
 from dalle_pytorch_tpu.ops.masks import mask_to_block_bitmap
 
 
@@ -66,10 +64,8 @@ class DecodeSparsityPolicy:
         self.n_blocks = -(-self.max_len // self.block)
         self.depth = model.depth
 
-        attn_types = (
-            tuple(model.attn_types) if model.attn_types else ("full",)
-        )
-        type_per_layer = list(islice(cycle(attn_types), self.depth))
+        trunk = Transformer(**model.transformer_kwargs(), parent=None)
+        type_per_layer = [layer.kind for layer in trunk.plan()]
 
         # per-layer [image_seq_len, n_blocks] bool: tile liveness for a
         # chunk STARTING at image position p (union over the window).

@@ -90,7 +90,6 @@ class DalleConfig:
     # without materializing [B, N, vocab] logits
     fused_ce: bool = False
     # attention kernel selection: "dense" | "flash" (in-repo Pallas) |
-    # "lib_flash" (jax library TPU kernel, plain causal/full only) |
     # "ring" (sequence-parallel over the mesh sp axis) | "auto" (dense
     # below AUTO_FLASH_MIN_SEQ, flash above; ring when mesh.sp > 1)
     attn_impl: str = "auto"

@@ -268,7 +268,7 @@ def test_the_narrowed_refusals_say_what_is_left(cfg):
                               dim_head=8, dim=32)["layer_0"]["attn"]
     # K/V heads shared by query heads decode through a cache since PR 37, every
     # row at its own index: a lockstep cache (scalar index) is not theirs
-    with pytest.raises(AssertionError, match="per row"):
+    with pytest.raises(ValueError, match="grouped_rows path.*per row"):
         attn.apply(variables, x, cache=cache)
     cache = decode_cache.make(decode_cache.PER_LAYER, 1, batch=1, max_len=8, heads=2,
                               dim_head=8, dim=32, per_row=True)["layer_0"]["attn"]

@@ -364,70 +364,6 @@ def test_masked_flash_trains_under_remat():
     np.testing.assert_allclose(got, want, atol=5e-4)
 
 
-class TestLibFlash:
-    """jax library TPU flash kernel behind `lib_flash_attention` /
-    attn_impl="lib_flash" (interpret mode on CPU)."""
-
-    def test_matches_dense_causal(self):
-        from dalle_pytorch_tpu.ops.pallas_attention import lib_flash_attention
-
-        n = 256  # library kernel wants block-multiple seq lengths
-        q, k, v = _qkv(n)
-        out = lib_flash_attention(q, k, v, causal=True)
-        ref = _dense(q, k, v, causal_mask(n))
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-    def test_grad_matches_dense(self):
-        import jax.experimental.pallas.tpu as pltpu
-
-        from dalle_pytorch_tpu.ops.pallas_attention import lib_flash_attention
-
-        n = 256
-        q, k, v = _qkv(n)
-
-        def loss_lib(q):
-            return lib_flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
-
-        def loss_dense(q):
-            return _dense(q, k, v, causal_mask(n)).astype(jnp.float32).sum()
-
-        # the library kernel's custom-VJP backward traces its own
-        # pallas_calls outside lib_flash_attention's internal interpret
-        # guard, so on CPU the WHOLE grad must run under the interpret
-        # context (on TPU none of this applies)
-        with pltpu.force_tpu_interpret_mode():
-            gl = jax.grad(loss_lib)(q)
-        gd = jax.grad(loss_dense)(q)
-        np.testing.assert_allclose(np.asarray(gl), np.asarray(gd), atol=5e-4)
-
-    def test_attention_module_path(self):
-        from dalle_pytorch_tpu.models.attention import Attention
-
-        n = 256
-        x = jnp.asarray(np.random.RandomState(0).randn(2, n, 64), jnp.float32)
-        dense = Attention(dim=64, seq_len=n, heads=2, dim_head=32,
-                          causal=True, attn_impl="dense")
-        lib = Attention(dim=64, seq_len=n, heads=2, dim_head=32,
-                        causal=True, attn_impl="lib_flash")
-        params = dense.init(jax.random.PRNGKey(0), x)
-        out_d, _ = dense.apply(params, x)
-        out_l, _ = lib.apply(params, x)
-        np.testing.assert_allclose(
-            np.asarray(out_d), np.asarray(out_l), atol=2e-4
-        )
-
-    def test_rejects_masks(self):
-        from dalle_pytorch_tpu.models.attention import Attention
-
-        n = 256  # library kernel needs seq >= its 128 block size
-        x = jnp.zeros((1, n, 32))
-        attn = Attention(dim=32, seq_len=n, heads=2, dim_head=16,
-                         causal=True, attn_impl="lib_flash")
-        params = attn.init(jax.random.PRNGKey(0), x)
-        with pytest.raises(ValueError, match="lib_flash"):
-            attn.apply(params, x, key_mask=jnp.ones((1, n), bool))
-
-
 # ------------------------------------------- grouped K/V heads and a window
 
 
