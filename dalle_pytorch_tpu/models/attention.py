@@ -30,6 +30,8 @@ over the whole cache as the fallback for pattern masks and small caches.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any, Optional
 
 import numpy as np
@@ -45,6 +47,7 @@ from dalle_pytorch_tpu.ops.grouped_decode import grouped_decode_attention
 from dalle_pytorch_tpu.ops.index_score import index_scores
 from dalle_pytorch_tpu.ops.index_select import selected_indices, selected_mask
 from dalle_pytorch_tpu.ops.latent_decode import latent_decode_attention
+from dalle_pytorch_tpu.ops.ssm_step import ssm_step, ssm_step_operands
 from dalle_pytorch_tpu.ops.sparse_latent_decode import (
     sparse_latent_decode_attention,
     split_rows,
@@ -111,8 +114,9 @@ DECODE_SPARSE_BLOCK = 128
 #           same lanes (`_cached_lanes`)
 #   ROWS    the grouped projection over per-row K/V or a window's ring
 #           (`_cached_grouped`): K/V heads shared, a window, or a rotate-half rotary
-#   LATENT, LINEAR   `LatentAttention`, `GatedDeltaAttention`
+#   LATENT, LINEAR, SSM   `LatentAttention`, `GatedDeltaAttention`, `Mamba2Mixer`
 DALLE, LANES, ROWS, LATENT, LINEAR = "dalle", "grouped_lanes", "grouped_rows", "latent", "linear"
+SSM = "ssm"
 ATTN_IMPLS = ("auto", "dense", "flash", "ring")
 
 
@@ -1108,8 +1112,12 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    dt = jax.random.uniform(key, shape, dtype, 0.001, 0.1)
+def _dt_bias_init(key, shape, dtype=jnp.float32, log_uniform=False):
+    """The inverse softplus of a step size drawn from (0.001, 0.1): uniformly,
+    or (`log_uniform`, the state-space mixer's) uniformly in its logarithm."""
+    low, high = (math.log(0.001), math.log(0.1)) if log_uniform else (0.001, 0.1)
+    dt = jax.random.uniform(key, shape, dtype, low, high)
+    dt = jnp.exp(dt) if log_uniform else dt
     return dt + jnp.log(-jnp.expm1(-dt))  # the inverse of softplus
 
 
@@ -1208,3 +1216,157 @@ class GatedDeltaAttention(nn.Module):
             o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + self.norm_eps) * o_gain
             y = o.astype(self.dtype) * jax.nn.silu(gate.reshape(b, n, h, dv))
             return dense(self.dim, "to_out")(y.reshape(b, n, h * dv)), new_cache
+
+
+def ssm_chunked(x, dt, a, b, c, d, chunk: int = 128):
+    """A Mamba-2 layer's recurrence over n tokens from an EMPTY state, a chunk
+    at a time (the SSD form, arXiv:2405.21060): `(y [b, n, H, P], S [b, H, N,
+    P])`, float32 throughout.
+
+    x [b, n, H, P], dt [b, n, H] (after its softplus), a, d [H] (a < 0), b, c
+    [b, n, G, N], head i reading group i // (H / G). With l_t = dt_t A the log
+    of a token's decay and Lambda_t its running sum inside a chunk,
+
+        y_t = exp(Lambda_t) S_0^T C_t + sum_{j <= t} exp(Lambda_t - Lambda_j)
+              (C_t . B_j) dt_j x_j + D x_t
+        S_C = exp(Lambda_C) S_0 + sum_j exp(Lambda_C - Lambda_j) B_j (dt_j x_j)^T
+
+    so inside a chunk the work is the decay-masked product C B^T (one a group)
+    against the chunk's dt x, and a `lax.scan` over the chunks carries S alone;
+    everything of a chunk is made inside its step, a chunk's [chunk, chunk]
+    masks at a time. A tail chunk is padded with tokens that change nothing
+    (dt = 0: decay 1, dt x = 0)."""
+    bsz, n, h, p = x.shape
+    g, n_state = b.shape[2], b.shape[3]
+    r = h // g
+    pad = (-n) % chunk
+    mm = lambda spec, u, v: jnp.einsum(spec, u, v, precision="highest")
+
+    def chunks(t, *shape):  # [b, n, ...] -> [c, b, chunk, ...]
+        t = jnp.pad(t.astype(jnp.float32), ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        return jnp.moveaxis(t.reshape((bsz, -1, chunk) + shape), 1, 0)
+
+    a, d = a.astype(jnp.float32).reshape(g, r), d.astype(jnp.float32).reshape(g, r)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def one(s, xs):  # s [b, G, R, N, P]
+        x_i, dt_i, b_i, c_i = xs  # [b, chunk, G, R, P], [b, chunk, G, R], [b, chunk, G, N] x 2
+        lam = jnp.cumsum(dt_i * a, axis=1)  # Lambda [b, chunk, G, R]
+        dtx = dt_i[..., None] * x_i
+        ratio = jnp.where(lower[None, :, :, None, None],
+                          lam[:, :, None] - lam[:, None, :], -jnp.inf)  # [b, t, j, G, R]
+        mask = jnp.exp(ratio) * mm("btgn,bjgn->btjg", c_i, b_i)[..., None]
+        y = (mm("btjgr,bjgrp->btgrp", mask, dtx)
+             + jnp.exp(lam)[..., None] * mm("btgn,bgrnp->btgrp", c_i, s)
+             + d[..., None] * x_i)
+        out = jnp.exp(lam[:, -1:] - lam)[..., None] * dtx  # what reaches the chunk's end
+        new = (jnp.exp(lam[:, -1])[..., None, None] * s + mm("bjgn,bjgrp->bgrnp", b_i, out))
+        return new, y
+
+    s, y = lax.scan(one, jnp.zeros((bsz, g, r, n_state, p), jnp.float32),
+                    (chunks(x, g, r, p), chunks(dt, g, r), chunks(b, g, n_state),
+                     chunks(c, g, n_state)))
+    y = jnp.moveaxis(y, 0, 1).reshape(bsz, -1, h, p)[:, :n]
+    return y, s.reshape(bsz, h, n_state, p)
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 state-space mixer (arXiv:2405.21060, as `nemotron_h` builds
+    it): per row and head a float32 state S [state_dim, head_dim] in place of
+    keys and values (in the cache a row's heads lie side by side:
+    `decode_cache.pack_state`), `groups` pairs of B and C each shared by heads
+    / groups heads.
+
+        z | xBC | dt = x W_in        [H P | H P + 2 G N | H], no bias
+        xBC = silu(conv(xBC) + bias) causal depthwise convolution over time,
+                                     `conv_width` taps
+        x | B | C = xBC              [H x P | G x N | G x N]
+        dt = softplus(dt + dt_bias),  A = -exp(A_log)             one a head
+        S_t = exp(dt_t A) S_{t-1} + B_t (dt_t x_t)^T;  y_t = S_t^T C_t + D x_t
+        y = rmsnorm(y silu(z); gain [H P])       the norm in G groups of H P / G
+        out = y W_out
+
+    Two forms that tests hold to each other. Without a cache, and for a
+    prefill chunk written into one, the CHUNKED form from an empty state
+    (`ssm_chunked`; a prefill of further tokens against a state is not built),
+    which also leaves the state and the convolution's ring (its last
+    `conv_width - 1` inputs) in the cache. With a one-token step, the
+    RECURRENCE against the cache (ops/ssm_step.py: one pass over the state,
+    in place). The cache is the `recurrent` kind of layer
+    (models/decode_cache.py), its index a scalar or per row: neither form
+    reads it.
+
+    W_in gives float32 (bf16 operands, float32 accumulation, nothing rounded
+    after), and the convolution, dt, the recurrence, the gate and the norm are
+    float32: what is summed into a state for thousands of steps is not rounded
+    on its way there.
+
+    Parameters: `to_in`, `to_out` and `conv` [conv_width, H P + 2 G N] in
+    `param_dtype`; `conv_bias`, `A_log`, `D`, `dt_bias` [H] and `norm` [H P]
+    float32.
+    """
+
+    dim: int
+    seq_len: int
+    heads: int
+    head_dim: int
+    groups: int
+    state_dim: int
+    conv_width: int = 4
+    chunk: int = 128
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, key_mask=None, rotary=None, cache=None, deterministic=True):
+        assert key_mask is None and rotary is None, "a state-space mixer is causal and unpadded"
+        bsz, n, _ = x.shape
+        h, p, g, n_state, taps = (self.heads, self.head_dim, self.groups, self.state_dim,
+                                  self.conv_width)
+        inner, width = h * p, h * p + 2 * g * n_state
+        matrix = lambda name, shape: self.param(
+            name, nn.initializers.lecun_normal(), shape, self.param_dtype)
+        to_in = matrix("to_in", (self.dim, inner + width + h))
+        conv = matrix("conv", (taps, width)).astype(jnp.float32)
+        conv_bias = self.param("conv_bias", nn.initializers.zeros, (width,))
+        a = -jnp.exp(self.param("A_log", _a_log_init, (h,)))
+        dt_bias = self.param("dt_bias", functools.partial(_dt_bias_init, log_uniform=True), (h,))
+        skip = self.param("D", nn.initializers.ones, (h,))
+        gain = self.param("norm", nn.initializers.ones, (inner,))
+        step = cache is not None and n == 1
+        with jax.named_scope("ssm_proj"):
+            x = x.astype(self.dtype)
+            z, xbc, dt = jnp.split(
+                jnp.dot(x, to_in.astype(self.dtype), preferred_element_type=jnp.float32),
+                [inner, inner + width], axis=-1)
+            dt = jax.nn.softplus(dt + dt_bias)
+            # the convolution's inputs: the ring (zeros before a sequence
+            # starts), then this call's
+            ring = (cache[decode_cache.CONV].astype(jnp.float32) if step
+                    else jnp.zeros((bsz, taps - 1, width), jnp.float32))
+            window = jnp.concatenate([ring, xbc], axis=1)  # [b, taps - 1 + n, width]
+            mixed = jax.nn.silu(sum(conv[j] * window[:, j:j + n] for j in range(taps)) + conv_bias)
+            xs, b, c = jnp.split(mixed, [inner, inner + g * n_state], axis=-1)
+            xs = xs.reshape(bsz, n, h, p)
+            b, c = (t.reshape(bsz, n, g, n_state) for t in (b, c))
+            if step:
+                operands = ssm_step_operands(xs[:, 0], dt[:, 0], a, skip)
+        if step:
+            with jax.named_scope("ssm_step"):
+                y, state = ssm_step(cache[decode_cache.STATE], *operands, b[:, 0], c[:, 0])
+        else:
+            with jax.named_scope("ssm_chunk"):
+                y, state = ssm_chunked(xs, dt, a, b, c, skip, chunk=self.chunk)
+                state = decode_cache.pack_state(state)
+        new_cache = None
+        if cache is not None:
+            new_cache = {**cache, decode_cache.STATE: state,
+                         decode_cache.CONV: window[:, n:].astype(cache[decode_cache.CONV].dtype),
+                         decode_cache.INDEX: cache[decode_cache.INDEX] + n}
+        with jax.named_scope("ssm_proj"):
+            y = (y.reshape(bsz, n, inner) * jax.nn.silu(z)).reshape(bsz, n, g, inner // g)
+            y = y * lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + self.norm_eps)
+            y = (y.reshape(bsz, n, inner) * gain).astype(self.dtype)
+            return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name="to_out")(y), new_cache

@@ -56,7 +56,9 @@ layout is read off the tree; `ops/sparse_latent_decode.py:split_rows` cuts
 rows, fetched ones or a block of the leaf, into what the products take.
 
 A RECURRENT layer (`layer_spec(kind="recurrent")`: linear attention by the
-gated delta rule, models/attention.py:GatedDeltaAttention) holds no position
+gated delta rule, models/attention.py:GatedDeltaAttention, or a Mamba-2
+state-space mixer, `Mamba2Mixer`, whose state is [state size, heads x head
+width] and whose ring is `conv_dim` columns wide) holds no position
 at all but what the sequence so far has been folded into:
 
   * `state` float32 [B, key_dim, H * value_dim]: each head's matrix
@@ -104,8 +106,11 @@ copies running to kept (after a prefill), `restore(cache)` kept to running
 (before a turn; it also hands the kept leaves out of the tree while a token
 loop runs, and `snapshot(cache, kept)` puts them back), and those two
 functions alone know the pair; a window layer's ring is kept by them likewise.
-Both leave a cache with neither kind of layer as it is. Per-layer layout, a
-recurrent layer's `index` scalar.
+Both leave a cache with neither kind of layer as it is. Per-layer layout; a
+recurrent layer's `index` is scalar, or per row where the stack keeps every row
+at its own position (a layer of grouped K/V heads beside it): the state itself
+stands at no position, so rows whose documents differ in length share a cache
+and a step, and `restore` selects a row's leaves as it selects a ring's.
 
 and a cache holds `depth` layers in one of two LAYOUTS:
 
@@ -199,6 +204,7 @@ def layer_spec(
     value_dim: Optional[int] = None,
     conv_taps: Optional[int] = None,
     linear_heads: Optional[int] = None,
+    conv_dim: Optional[int] = None,
     ring: Optional[int] = None,
     hidden: bool = False,
 ) -> dict:
@@ -210,8 +216,9 @@ def layer_spec(
     `ROW_TILE`] and `index_k` [batch, max_len, index_dim]; and nothing else
     (no pages, no int8 store, no rings). `kind="recurrent"`: `state`
     [batch, key_dim, linear_heads * value_dim] and `conv` [batch, conv_taps - 1,
-    linear_heads * (2 key_dim + value_dim)], both float32, their snapshot
-    beside them, and a scalar `index`. `kind="window"`: K/V [batch, heads,
+    linear_heads * (2 key_dim + value_dim)] (`conv_dim` columns where given:
+    a state-space mixer's), both float32, their snapshot beside them, and an
+    `index` that is scalar or, with `per_row`, [batch]. `kind="window"`: K/V [batch, heads,
     ring, dim_head] read as a ring (`ring_positions`), their snapshot beside
     them, `index` per row.
     `hidden`: beside `attn`, a leaf `hidden` [batch, dim] (a drafting
@@ -237,15 +244,15 @@ def layer_spec(
                       ROPE: spec((batch, rope_dim, max_len), dtype)}
         return {ATTN: {**leaves, INDEX: spec((), jnp.int32)}}
     if kind == "recurrent":
-        assert pages is None and kv_dtype is None and not per_row and not shift_tokens, (
-            "a recurrent cache is one state a row, decoded in lockstep")
+        assert pages is None and kv_dtype is None and not shift_tokens, (
+            "a recurrent cache is one float32 state a row")
+        columns = conv_dim or linear_heads * (2 * key_dim + value_dim)
         running = {
             STATE: spec((batch, key_dim, linear_heads * value_dim), jnp.float32),
-            CONV: spec((batch, conv_taps - 1, linear_heads * (2 * key_dim + value_dim)),
-                       jnp.float32),
+            CONV: spec((batch, conv_taps - 1, columns), jnp.float32),
         }
         return {ATTN: {**running, **{SNAPSHOT[n]: s for n, s in running.items()},
-                       INDEX: spec((), jnp.int32)}}
+                       INDEX: spec((batch,) if per_row else (), jnp.int32)}}
     if kind == "window":
         assert pages is None and kv_dtype is None and per_row and not shift_tokens, (
             "a ring is lanes in the cache dtype, every row at its own position")
@@ -285,7 +292,8 @@ def _zeros(spec: dict, lead: tuple) -> dict:
 def make(layout: str, depth: int, kinds=None, **geometry) -> dict:
     """A zeroed cache of `depth` layers of `layer_spec(**geometry)`: the
     layout applied in this one place. `kinds`: the kind of EACH layer, where
-    they differ (else `geometry`'s one `kind` in every layer)."""
+    they differ (else `geometry`'s one `kind` in every layer); a layer of kind
+    `none` (no mixer: a feed-forward alone) holds nothing and has no entry."""
     kind = geometry.pop("kind", "heads")
     kinds = tuple(kinds) if kinds else (kind,) * depth
     assert len(kinds) == depth, f"{len(kinds)} kinds for {depth} layers"
@@ -295,8 +303,9 @@ def make(layout: str, depth: int, kinds=None, **geometry) -> dict:
             f"a cache with {sorted(set(kinds))} layers is per layer (the unrolled executor)")
         return _zeros(layer_spec(kind="heads", **geometry), (depth,))
     assert layout == PER_LAYER, f"unknown cache layout {layout!r}"
-    specs = {kind: layer_spec(kind=kind, **geometry) for kind in set(kinds)}
-    return {layer_key(i): _zeros(specs[kind], ()) for i, kind in enumerate(kinds)}
+    specs = {kind: layer_spec(kind=kind, **geometry) for kind in set(kinds) - {"none"}}
+    return {layer_key(i): _zeros(specs[kind], ()) for i, kind in enumerate(kinds)
+            if kind != "none"}
 
 
 # ------------------------------------------------- reading the layout back
@@ -395,8 +404,9 @@ def restore(cache: dict):
                 out[name] = layer
                 continue
             kept[name] = {at: attn[at] for at in pairs.values()}
-            # a window layer's index is per row
-            rows = (lambda x: x[:, None, None, None]) if attn[INDEX].ndim else (lambda x: x)
+            # a per-row index (a window layer's always) selects a row's leaves
+            rows = ((lambda x, leaf: x[(slice(None),) + (None,) * (leaf.ndim - 1)])
+                    if attn[INDEX].ndim else (lambda x, leaf: x))
             out[name] = {**layer, ATTN: {
                 **{n: leaf for n, leaf in attn.items() if n not in kept[name]},
                 # a select on the index's sign, which is never negative: an
@@ -405,7 +415,7 @@ def restore(cache: dict):
                 # unnamed copies, and that reads the running leaf, so a
                 # donated cache's buffer is the copy's target and is paired
                 # with its own output
-                **{n: jnp.where(rows(attn[INDEX] >= 0), attn[at], attn[n])
+                **{n: jnp.where(rows(attn[INDEX] >= 0, attn[n]), attn[at], attn[n])
                    for n, at in pairs.items()}}}
     return (out, kept) if kept else (cache, kept)
 

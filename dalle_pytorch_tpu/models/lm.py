@@ -8,8 +8,9 @@ never whole.
 
 Generation, for a trunk of latent attention layers (with or without a
 lightning indexer that selects the cached positions a query attends), of
-linear (gated delta rule) and full ones, or of window and full ones over
-grouped K/V heads:
+linear (gated delta rule) and full ones, of window and full ones over
+grouped K/V heads, or of layers that are ONE sublayer each (a Mamba-2 mixer,
+an attention over grouped K/V heads or ungated routed experts: `_ssm_trunk`):
 `prefill` writes a batch of prompts into a decode cache
 (models/decode_cache.py, per-layer layout: the latent kind of layer, or
 recurrent and K/V layers in one tree, or window rings beside full K/V),
@@ -21,7 +22,10 @@ rings restored from the snapshot `prefill_cached` took. A latent trunk also
 takes NEW tokens against what its cache holds (`extend`): `prefill_cached(
 chunk=)` puts a long prompt in that way, a chunk a dispatch.
 
-The window-and-full trunk keeps every row at its OWN position and may carry a
+A trunk with a layer of grouped K/V heads (the window-and-full one, and the
+one of single sublayers, whose Mamba-2 state and ring then stand in the same
+per-row cache: rows whose documents differ in length share a step) keeps every
+row at its OWN position; the window-and-full trunk may carry a
 multi-token module (`draft_layers`; `CausalLM.draft_step`): one more block
 after the trunk that, from the trunk's last hidden state at position i and
 the token at i + 1, drafts the token at i + 2. Its token loop
@@ -61,6 +65,12 @@ MOE_COUNTS = ("moe_load", "moe_rows", "moe_dropped")
 # an indexed latent layer's counters of a token step, beside them: the
 # positions its indexer scored and the positions it then attended, over the rows
 DSA_COUNTS = ("dsa_scored", "dsa_selected")
+# what the trainers say of the family `_ssm_trunk` builds (training/steps.py, train_lm.py)
+FORWARD_ONLY = (
+    "the nemotron_h family (hybrid_override_pattern: layers that are a Mamba-2 mixer, an "
+    "attention or ungated experts alone) is forward only: there is no backward for the chunked "
+    "state-space scan nor for the ungated experts' grouped products; generate with it "
+    "(generate_lm.py --config ...)")
 
 
 def rotary_spec(spec: dict, dim: int) -> dict:
@@ -200,6 +210,42 @@ def _hybrid_trunk(cfg: dict, depth: int) -> dict:
     )
 
 
+def _ssm_trunk(cfg: dict, depth: int, held: int) -> dict:
+    """The trunk options of the family whose config has
+    `hybrid_override_pattern` (`nemotron_h`): every layer ONE sublayer under
+    one pre-norm and one residual, by the pattern's character: `M` a Mamba-2
+    mixer (`mamba_num_heads` heads of `mamba_head_dim`, `n_groups` pairs of B
+    and C of `ssm_state_size`, `conv_kernel` taps with a bias, prefill in
+    chunks of `chunk_size`), `*` attention over `num_key_value_heads` shared
+    K/V heads with no position embedding (the state-space layers carry
+    position; `rope_theta` is read by nothing), `E` routed experts WITHOUT a
+    gate, relu(x W_up)^2 W_down (`mlp_hidden_act: relu2`), chosen by sigmoid
+    score plus a correction bias (`_router_choice`), beside a shared expert of
+    `moe_shared_expert_intermediate_size`. A dense `-` layer is not built."""
+    pattern = cfg["hybrid_override_pattern"][:depth]
+    if len(pattern) != depth or set(pattern) - set("M*E"):
+        raise ValueError(f"hybrid_override_pattern {pattern!r}: {depth} layers of M, * and E")
+    off = [k for k in ("attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias") if cfg.get(k)]
+    if (off or cfg["mlp_hidden_act"] != "relu2" or cfg.get("mamba_hidden_act", "silu") != "silu"
+            or not cfg.get("use_conv_bias", True) or int(cfg.get("n_shared_experts", 1)) != 1):
+        raise ValueError("this family builds relu2 experts beside one shared one, a SiLU "
+                         f"state-space mixer with a convolution bias, and no other bias {off}")
+    if not cfg.get("norm_topk_prob", True):
+        raise ValueError("the routed layer renormalises the chosen scores (norm_topk_prob)")
+    return dict(
+        attn_types=tuple({"M": "ssm", "*": "full", "E": "none"}[c] for c in pattern),
+        ff_kinds=tuple("relu2_experts" if c == "E" else "none" for c in pattern),
+        kv_heads=cfg["num_key_value_heads"],
+        ssm_heads=cfg["mamba_num_heads"], ssm_head_dim=cfg["mamba_head_dim"],
+        ssm_groups=cfg["n_groups"], ssm_state=cfg["ssm_state_size"],
+        ssm_conv=cfg["conv_kernel"], ssm_chunk=cfg["chunk_size"],
+        experts_total=cfg.get("published", {}).get("n_routed_experts", held),
+        moe_score="sigmoid", routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        shared_dim=int(cfg["moe_shared_expert_intermediate_size"]),
+        **_router_choice(cfg),
+    )
+
+
 class CausalLM(nn.Module):
     num_tokens: int  # rows of the embedding and of the head held here
     dim: int
@@ -227,8 +273,12 @@ class CausalLM(nn.Module):
         """The model that a published `config.json` describes, as this
         process's share of it. `cfg` holds the published keys at its top
         level: the first `num_hidden_layers` layers, ids below `vocab_size`,
-        and the experts held. Three families of keys are read. With
-        `linear_key_head_dim`: `layer_types` of `linear_attention` and
+        and the experts held. Four families of keys are read. With
+        `hybrid_override_pattern` (`_ssm_trunk`): `mamba_*`, `ssm_state_size`,
+        `n_groups`, `conv_kernel`, `chunk_size`, `mlp_hidden_act`,
+        `layer_norm_epsilon`, `moe_shared_expert_intermediate_size` ... (layers
+        that are a Mamba-2 mixer, an attention or ungated routed experts
+        alone). With `linear_key_head_dim`: `layer_types` of `linear_attention` and
         `full_attention`, the `linear_*` keys, `intermediate_size`, a null
         `rope_theta` (gated delta-rule layers among full ones, no experts).
         Else with `layer_types`: `hidden_size`, `head_dim`, `rope_parameters`,
@@ -251,24 +301,29 @@ class CausalLM(nn.Module):
         made says it (`Transformer.plan`)."""
         prog = dict(cfg.get("program", {}), **overrides)
         depth = int(cfg["num_hidden_layers"])
-        if (cfg["hidden_act"] != "silu" or cfg.get("attention_bias", False)
+        ssm = "hybrid_override_pattern" in cfg  # its activations are `_ssm_trunk`'s to check
+        if ((not ssm and cfg["hidden_act"] != "silu") or cfg.get("attention_bias", False)
                 or cfg["tie_word_embeddings"]):
             raise ValueError("the trunk builds SiLU gates, no biases and an untied head")
         latent, hybrid = "kv_lora_rank" in cfg, "linear_key_head_dim" in cfg
         param_dtype = DTYPES[prog.get("weights_dtype", "float32")]
         trunk = dict(
-            norm="rms", norm_eps=float(cfg["rms_norm_eps"]), use_bias=False, layerscale=False,
+            norm="rms", norm_eps=float(cfg["layer_norm_epsilon" if ssm else "rms_norm_eps"]),
+            use_bias=False, layerscale=False,
             attn_impl=prog.get("attn_impl", "auto"), executor=prog.get("executor", "unrolled"),
         )
         if not hybrid:
-            held = int(cfg["n_routed_experts" if latent else "num_experts"])
+            held = int(cfg["n_routed_experts" if latent or ssm else "num_experts"])
             trunk.update(
                 experts_per_token=cfg["num_experts_per_tok"],
                 experts_held=(cfg.get("deployment", {}).get("experts_first", 0), held),
                 expert_dim=cfg["moe_intermediate_size"],
                 moe_buffer_rows=int(prog["moe_buffer_rows"]),
             )
-        if hybrid:
+        if ssm:
+            trunk.update(_ssm_trunk(cfg, depth, held), param_dtype=param_dtype)
+            dim_head = cfg["head_dim"]
+        elif hybrid:
             trunk.update(_hybrid_trunk(cfg, depth), param_dtype=param_dtype)
             dim_head = cfg["hidden_size"] // cfg["num_attention_heads"]
         elif latent:
@@ -281,7 +336,7 @@ class CausalLM(nn.Module):
             trunk.update(_window_trunk(cfg, depth, held), param_dtype=param_dtype,
                          draft_positions=drafts)
             dim_head = cfg["head_dim"]
-        if (latent or hybrid) and cfg.get("num_nextn_predict_layers", 0):
+        if (latent or hybrid or ssm) and cfg.get("num_nextn_predict_layers", 0):
             raise ValueError("a multi-token module is built after the window-and-full trunk")
         return cls(
             num_tokens=cfg["vocab_size"], dim=cfg["hidden_size"], depth=depth,
@@ -479,8 +534,9 @@ class CausalLM(nn.Module):
     def generate(self, *args, **kwargs):
         raise NotImplementedError(
             "CausalLM has no uncached sampler: `generate_tokens_cached` decodes a trunk "
-            "of latent layers, of linear and full ones, or of window and full ones over "
-            "grouped K/V heads (with its multi-token module drafting) through its cache; "
+            "of latent layers, of linear and full ones, of window and full ones over "
+            "grouped K/V heads (with its multi-token module drafting), or of single "
+            "sublayers (state-space, attention, ungated experts) through its cache; "
             "serving it (slots, pages, the engine) is not built (ROADMAP.md, Queue 2 B)"
         )
 
@@ -501,7 +557,8 @@ def _jitted(builder, model, static_key):
 
 def prefill_cached(model: CausalLM, variables, tokens: jnp.ndarray, cache: dict, row: int = 0,
                    chunk: Optional[int] = None):
-    """`(cache, counts)`: `cache` (DONATED) with rows `row ..` holding
+    """`(cache, counts)`: `cache` (DONATED) with rows `row ..` (or, `row` a
+    sequence of R rows, those) holding
     `tokens` [R, n] from position 0, and the routed layers' counts over the
     prompts (as `generate_tokens_cached` gives them). The prompts go through
     `CausalLM.prefill` into a fresh cache of their own length, whose rows
@@ -558,7 +615,7 @@ def _prefill_builder(model, key):
         fresh, aux = model.apply(
             variables, tokens, model.init_cache(*tokens.shape), method=CausalLM.prefill,
             mutable=["stats"])
-        rows = row + jnp.arange(tokens.shape[0], dtype=jnp.int32)
+        rows = row if row.ndim else row + jnp.arange(tokens.shape[0], dtype=jnp.int32)
         return (decode_cache.scatter_rows(cache, decode_cache.snapshot(fresh), rows),
                 _moe_counts(aux.get("stats", {})))
 
@@ -637,7 +694,9 @@ def generate_tokens_cached(model: CausalLM, variables, key: jax.Array, cache: di
     `at + 1`) and `accepted` [steps, rows]. `counts` gains `emitted`,
     `accepted` [B] and, as host numbers, `verify_steps`, `kv_bytes`,
     `ring_slots` and `ring_bytes` (the window layers' geometry: K and V, the
-    running rings and their snapshots).
+    running rings and their snapshots) and, of a cache with recurrent layers,
+    `state_bytes` and `state_restored_bytes` as above. `start` may be [B]:
+    every row's own position.
     """
     assert forced.ndim == 2 and 1 <= forced.shape[1] <= steps, forced.shape
     static_key = (int(steps), float(filter_thres), float(temperature), int(logit_rows))
@@ -650,6 +709,9 @@ def generate_tokens_cached(model: CausalLM, variables, key: jax.Array, cache: di
                     "ring_slots": rings[0].shape[2] if rings else 0,
                     # k and v, each running and kept
                     "ring_bytes": 4 * sum(r.size * r.dtype.itemsize for r in rings)}
+        held = decode_cache.state_bytes(cache)
+        if held:  # recurrent layers among them: running and kept, and the turn's copy
+            geometry.update(state_bytes=held, state_restored_bytes=held // 2)
         with host_span("lm.sample.dispatch", program=jitted.name):
             tokens, logits, counts, cache = jitted(
                 variables, key, cache, forced,

@@ -362,6 +362,28 @@ def _experts_bwd(buffer_rows, res, d_out):
 experts.defvjp(_experts_fwd, _experts_bwd)
 
 
+def _relu2(up):
+    return jnp.square(nn.relu(up))
+
+
+@functools.partial(jax.jit, inline=True)
+def _relu2_rows(up, kept):
+    """[R, F]: relu(up)^2 over the rows present, written where `up` was."""
+    return _fill(kept, (up,), lambda start, size, current: (_relu2(current[0]),), read=True)[0]
+
+
+def experts_ungated(h, assign, kept, back, group_sizes, w_up, w_out, buffer_rows):
+    """`experts` for experts WITHOUT a gate, relu(rows @ up)^2 @ out: two
+    grouped products over the same buffer, moves and counters. Forward only
+    (no custom VJP: the passes over the rows present are loops that stop where
+    the rows end, which reverse-mode differentiation refuses; the train step
+    refuses such a model by name first, training/steps.py)."""
+    rows = _tokens_rows(h, assign, kept, back, buffer_rows)
+    with jax.named_scope("moe_experts"):
+        act = _relu2_rows(grouped_matmul(rows, w_up, group_sizes), kept)
+        return grouped_matmul(act, w_out, group_sizes)
+
+
 def _fan_in(key, shape, dtype=jnp.float32):
     return jax.random.normal(key, shape, dtype) / jnp.sqrt(shape[-2]).astype(dtype)
 
@@ -369,6 +391,8 @@ def _fan_in(key, shape, dtype=jnp.float32):
 class RoutedExperts(nn.Module):
     """SwiGLU experts behind a router, the held ones computed; beside them,
     where `shared_dim` is set, one shared expert that every token passes.
+    `act="relu2"`: every expert, the shared one too, is relu(x W_up)^2 W_out
+    with no gate (no `w_gate`, no `shared_gate`; forward only).
 
     Parameters: `router` [dim, experts_total], `w_gate` and `w_up` [count,
     dim, expert_dim], `w_out` [count, expert_dim, dim]; no biases. Gate and
@@ -399,6 +423,7 @@ class RoutedExperts(nn.Module):
     shared_dim: int = 0  # 0: no shared expert
     score_bias: bool = False
     groups: Tuple[int, int] = (1, 1)
+    act: str = "swiglu"  # "swiglu" | "relu2": the experts' form
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -406,15 +431,19 @@ class RoutedExperts(nn.Module):
         count = self.experts_held[1]
         assert self.experts_total % self.groups[0] == 0, "groups of equal size"
         assert self.score in ("softmax", "sigmoid"), f"unknown router score {self.score!r}"
+        assert self.act in ("swiglu", "relu2"), f"unknown expert form {self.act!r}"
+        gated = self.act == "swiglu"
         matrix = lambda name, *shape: self.param(name, _fan_in, shape, self.param_dtype)
         self.router = self.param("router", _fan_in, (self.dim, self.experts_total))
-        self.w_gate = matrix("w_gate", count, self.dim, self.expert_dim)
+        if gated:
+            self.w_gate = matrix("w_gate", count, self.dim, self.expert_dim)
         self.w_up = matrix("w_up", count, self.dim, self.expert_dim)
         self.w_out = matrix("w_out", count, self.expert_dim, self.dim)
         self.router_bias = (self.param("router_bias", nn.initializers.zeros,
                                        (self.experts_total,)) if self.score_bias else None)
         if self.shared_dim:
-            self.shared_gate = matrix("shared_gate", self.dim, self.shared_dim)
+            if gated:
+                self.shared_gate = matrix("shared_gate", self.dim, self.shared_dim)
             self.shared_up = matrix("shared_up", self.dim, self.shared_dim)
             self.shared_out = matrix("shared_out", self.shared_dim, self.dim)
 
@@ -429,6 +458,9 @@ class RoutedExperts(nn.Module):
     def shared(self, h: jnp.ndarray) -> jnp.ndarray:
         """[T, dim]: the shared expert of tokens [T, dim]."""
         with jax.named_scope("moe_shared"):
+            if self.act == "relu2":
+                up, out = (w.astype(h.dtype) for w in (self.shared_up, self.shared_out))
+                return jnp.dot(_relu2(jnp.dot(h, up)), out)
             gate, up, out = (w.astype(h.dtype) for w in
                              (self.shared_gate, self.shared_up, self.shared_out))
             return jnp.dot(_gated(jnp.dot(h, gate), jnp.dot(h, up)), out)
@@ -452,8 +484,11 @@ class RoutedExperts(nn.Module):
             weights = r["weights"]
             if self.routed_scale != 1.0:
                 weights = weights * self.routed_scale
-        rows = experts(h, r["assign"], r["kept"], r["back"], r["group_sizes"],
-                       self.w_gate, self.w_up, self.w_out, r["assign"].shape[0])
+        layout = (h, r["assign"], r["kept"], r["back"], r["group_sizes"])
+        if self.act == "relu2":
+            rows = experts_ungated(*layout, self.w_up, self.w_out, r["assign"].shape[0])
+        else:
+            rows = experts(*layout, self.w_gate, self.w_up, self.w_out, r["assign"].shape[0])
         with jax.named_scope("moe_dispatch"):
             y = to_tokens(rows, weights, r["assign"], r["kept"], r["pos"], r["back"])
         if self.shared_dim:

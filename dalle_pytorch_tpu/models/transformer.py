@@ -63,10 +63,11 @@ import flax.linen as nn
 
 from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.models.attention import (
-    LATENT, LINEAR, ROWS,
+    LATENT, LINEAR, ROWS, SSM,
     Attention,
     GatedDeltaAttention,
     LatentAttention,
+    Mamba2Mixer,
     attention_path,
 )
 from dalle_pytorch_tpu.ops.masks import (
@@ -208,13 +209,17 @@ class LayerPlan(NamedTuple):
     """What ONE layer of the stack is: decided once, from the trunk's options
     (`Transformer.plan`), and read wherever a layer's kind matters."""
 
-    kind: str  # of attention: full, axial_row, axial_col, conv_like, sparse, window, latent, linear
+    # of mixer: full, axial_row, axial_col, conv_like, sparse, window, latent,
+    # linear, ssm, or `none`: no mixer, the layer is its feed-forward alone
+    kind: str
     attn_id: int  # layers of one id share their mixer's weights (`attn_{id}`)
     ff_id: int  # and their feed-forward's (`ff_{id}`)
     # the mixer it is built as and the path that mixer's cached call takes:
-    # DALLE, LANES, ROWS, LATENT or LINEAR of models/attention.py
+    # DALLE, LANES, ROWS, LATENT, LINEAR or SSM of models/attention.py (NO_MIXER: none)
     path: str
-    cache_kind: str  # what `decode_cache.layer_spec` is asked for: heads, window, latent, recurrent
+    # what `decode_cache.layer_spec` is asked for: heads, window, latent,
+    # recurrent, or `none` (a layer without a mixer holds nothing)
+    cache_kind: str
     # positions a latent layer's lightning indexer selects for a query (0: no
     # indexer; with one the layer's cache keeps the indexer's keys too)
     selects: int
@@ -223,15 +228,23 @@ class LayerPlan(NamedTuple):
     per_row: bool
     rotary: Optional[str]  # which rotate-half table it is handed (a key of `rotary_specs`)
     takes_start: bool  # whether its call is told that a chunk starts the rows' sequences
-    ff_kind: str  # geglu, swiglu, swiglu_experts (routed: `routed_layers`)
+    # geglu, swiglu, swiglu_experts or relu2_experts (routed: `routed_layers`),
+    # or `none`: no feed-forward, the layer is its mixer alone
+    ff_kind: str
+
+
+NO_MIXER = "none"  # a layer's `kind` and `path` where it has no mixer
+# routed feed-forwards, by the expert each holds (models/moe.py:RoutedExperts.act)
+ROUTED_KINDS = {"swiglu_experts": "swiglu", "relu2_experts": "relu2"}
 
 
 def routed_layers(plan) -> int:
     """How many layers of a plan route their tokens to experts."""
-    return sum(layer.ff_kind == "swiglu_experts" for layer in plan)
+    return sum(layer.ff_kind in ROUTED_KINDS for layer in plan)
 
 
-CACHE_KINDS = {"latent": "latent", "linear": "recurrent", "window": "window"}
+CACHE_KINDS = {"latent": "latent", "linear": "recurrent", "ssm": "recurrent",
+               "window": "window", NO_MIXER: "none"}
 
 
 @functools.lru_cache(maxsize=None)
@@ -253,7 +266,7 @@ def _stack_plan(depth, attn_types, attn_ids, ff_ids, ff_kinds, heads, kv_heads, 
             )
         if kind == "window":
             assert window, 'attn_types has "window" and the model no window length'
-        paths.append(kind if kind in (LATENT, LINEAR) else attention_path(
+        paths.append(kind if kind in (LATENT, LINEAR, SSM, NO_MIXER) else attention_path(
             heads, kv_heads, qk_norm, window if kind == "window" else None, kind in rotated))
     per_row = ROWS in paths
     return tuple(
@@ -261,7 +274,7 @@ def _stack_plan(depth, attn_types, attn_ids, ff_ids, ff_kinds, heads, kv_heads, 
                   cache_kind=CACHE_KINDS.get(kind, "heads"), per_row=per_row,
                   selects=index_topk if kind == "latent" else 0,
                   rotary=kind if kind in rotated else None,
-                  takes_start=kind != "linear", ff_kind=ff_kind)
+                  takes_start=kind not in (LINEAR, SSM, NO_MIXER), ff_kind=ff_kind)
         for kind, attn_id, ff_id, path, ff_kind in zip(kinds, attn_ids, ff_ids, paths, ff_kinds))
 
 
@@ -480,7 +493,8 @@ class Transformer(nn.Module):
     norm_eps: float = 1e-6
     ff_kind: str = "geglu"  # "geglu" | "swiglu" (width ff_dim) | "swiglu_experts" (models/moe.py)
     # the feed-forward kind of EACH layer, where they differ (leading dense
-    # layers before routed ones); None: `ff_kind` in every layer
+    # layers before routed ones; "relu2_experts": routed experts without a
+    # gate; "none": the layer is its mixer alone); None: `ff_kind` in every layer
     ff_kinds: Optional[Sequence[str]] = None
     ff_dim: int = 0  # the dense SwiGLU's width
     use_bias: bool = True  # to_out's and the feed-forward's
@@ -529,6 +543,15 @@ class Transformer(nn.Module):
     linear_key_dim: int = 0
     linear_value_dim: int = 0
     linear_conv: int = 4
+    # "ssm" among attn_types (models/attention.py:Mamba2Mixer): heads, their
+    # width, the groups that share B and C, the state size, the
+    # convolution's taps and the prefill's chunk
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
     dtype: Any = jnp.float32
     # what the MATRICES are stored in (the new block options' only: norm
     # gains and the router stay float32, the DALL-E block keeps float32)
@@ -595,6 +618,10 @@ class Transformer(nn.Module):
                 shared_attn[layer.attn_id] = self._mixer(ind, layer)
             if layer.ff_id not in shared_ff:
                 shared_ff[layer.ff_id] = self._feed_forward(layer)
+        assert all(layer.kind != NO_MIXER or layer.ff_kind != "none" for layer in plan), (
+            "a layer with neither a mixer nor a feed-forward")
+        # a layer of ONE sublayer (a mixer or a feed-forward alone) has None in
+        # the other's place, and its norm there, never called, has no gain
         self.attn_layers = [shared_attn[layer.attn_id] for layer in plan]
         self.ff_layers = [shared_ff[layer.ff_id] for layer in plan]
         assert self.prenorm or self.sandwich_norm, "a sublayer with no norm at all"
@@ -642,8 +669,17 @@ class Transformer(nn.Module):
             frozenset(dict(self.rotary_specs or {})), self.index_topk)
 
     def _mixer(self, ind: int, layer: LayerPlan):
-        """The mixer the plan says layer `ind` is built as."""
+        """The mixer the plan says layer `ind` is built as (None: it has none)."""
         name = f"attn_{layer.attn_id}"
+        if layer.path == NO_MIXER:
+            return None
+        if layer.path == SSM:
+            return Mamba2Mixer(
+                dim=self.dim, seq_len=self.seq_len, heads=self.ssm_heads,
+                head_dim=self.ssm_head_dim, groups=self.ssm_groups, state_dim=self.ssm_state,
+                conv_width=self.ssm_conv, chunk=self.ssm_chunk, norm_eps=self.norm_eps,
+                dtype=self.dtype, param_dtype=self.param_dtype, name=name,
+            )
         if layer.path == LATENT:
             return LatentAttention(
                 dim=self.dim, seq_len=self.seq_len, heads=self.heads,
@@ -681,8 +717,11 @@ class Transformer(nn.Module):
 
     def _feed_forward(self, layer: LayerPlan):
         name = f"ff_{layer.ff_id}"
-        if layer.ff_kind == "swiglu_experts":
+        if layer.ff_kind == "none":
+            return None
+        if layer.ff_kind in ROUTED_KINDS:
             return RoutedExperts(
+                act=ROUTED_KINDS[layer.ff_kind],
                 dim=self.dim, expert_dim=self.expert_dim, experts_total=self.experts_total,
                 experts_per_token=self.experts_per_token, experts_held=tuple(self.experts_held),
                 buffer_rows=self.moe_buffer_rows, score=self.moe_score,
@@ -868,10 +907,11 @@ class Transformer(nn.Module):
         token of x [B, N, dim] (the trunk's input), through the layers
         before it and its own attention half: what the routed layer itself
         would choose, read outside any train step."""
-        assert self.plan()[layer].ff_kind == "swiglu_experts", "only a routed layer chooses"
+        assert self.plan()[layer].ff_kind in ROUTED_KINDS, "only a routed layer chooses"
         for i in range(layer):
             x = self._layer(i, x, None, None, True)[0]
-        x = x + self._half_attn(layer, x, None, None, True)[0]
+        if self.plan()[layer].kind != NO_MIXER:
+            x = x + self._half_attn(layer, x, None, None, True)[0]
         return self.ff_layers[layer].choices(self.ff_norms[layer](x))
 
     def _rev_f(self, x: jnp.ndarray, i: int, deterministic: bool = True):
@@ -947,16 +987,22 @@ class Transformer(nn.Module):
         deterministic: bool,
         start: bool = False,
     ):
-        """One (attn, ff) residual pair; returns (x, updated layer cache)."""
+        """One (attn, ff) residual pair, or the one sublayer of a layer that is
+        a mixer or a feed-forward alone (one norm, one residual); returns (x,
+        updated layer cache: None of a layer that holds nothing)."""
         cached = layer_cache is not None
         pos = layer_cache["attn"]["index"] if cached else None
+        layer = self.plan()[i]
 
-        h, attn_cache, ring_attn = self._half_attn(
-            i, x, key_mask, layer_cache, deterministic, start
-        )
-        x = x + h
-        h, ring_ff = self._half_ff(i, x, layer_cache, pos, deterministic)
-        x = x + h
+        attn_cache = ring_attn = ring_ff = None
+        if layer.kind != NO_MIXER:
+            h, attn_cache, ring_attn = self._half_attn(
+                i, x, key_mask, layer_cache, deterministic, start
+            )
+            x = x + h
+        if layer.ff_kind != "none":
+            h, ring_ff = self._half_ff(i, x, layer_cache, pos, deterministic)
+            x = x + h
 
         if not cached:
             return x, None
@@ -1037,7 +1083,7 @@ class Transformer(nn.Module):
             else:
                 x, layer_cache = self._layer(
                     i, x, key_mask,
-                    cache[decode_cache.layer_key(i)] if cache else None,
+                    cache.get(decode_cache.layer_key(i)) if cache else None,
                     deterministic, start,
                 )
                 if layer_cache:
@@ -1062,12 +1108,22 @@ class Transformer(nn.Module):
         rings), in the layout its executor takes. Pure config math: usable
         unbound. `per_row`, `pages = (n_pages, page_size)` and `kv_dtype`
         as `decode_cache.layer_spec` reads them. Each layer takes the kind
-        the plan gives it: latent, recurrent (linear attention), a window's
-        ring (of `window + draft_positions` slots) or K/V heads; linear,
-        window and K/V layers may share a cache, and a stack with a layer on
-        the ROWS path keeps every row at its own index."""
+        the plan gives it: latent, recurrent (linear attention, or a state-space
+        mixer: its leaves sized from that mixer), a window's ring (of `window +
+        draft_positions` slots), K/V heads, or nothing (a layer without a
+        mixer); recurrent, window and K/V layers may share a cache, and a stack
+        with a layer on the ROWS path keeps every row at its own index."""
         plan = self.plan()
         kinds = [layer.cache_kind for layer in plan]
+        recurrent = {layer.kind for layer in plan if layer.cache_kind == "recurrent"}
+        assert len(recurrent) <= 1, "linear and state-space layers in one stack are not built"
+        state = dict(linear_heads=self.linear_heads, key_dim=self.linear_key_dim,
+                     value_dim=self.linear_value_dim, conv_taps=self.linear_conv)
+        if recurrent == {SSM}:  # a head's state [state size, head width], a ring of x | B | C
+            state = dict(linear_heads=self.ssm_heads, key_dim=self.ssm_state,
+                         value_dim=self.ssm_head_dim, conv_taps=self.ssm_conv,
+                         conv_dim=self.ssm_heads * self.ssm_head_dim
+                         + 2 * self.ssm_groups * self.ssm_state)
         if "latent" in kinds and set(kinds) != {"latent"}:
             raise NotImplementedError(
                 "latent layers beside K/V or recurrent ones in one cache are not built "
@@ -1089,8 +1145,7 @@ class Transformer(nn.Module):
             ring=(self.window or 0) + self.draft_positions,
             latent_dim=self.kv_lora_rank, rope_dim=self.qk_rope_dim,
             index_dim=self.index_dim if any(layer.selects for layer in plan) else None,
-            linear_heads=self.linear_heads, key_dim=self.linear_key_dim,
-            value_dim=self.linear_value_dim, conv_taps=self.linear_conv,
+            **state,
         )
 
 

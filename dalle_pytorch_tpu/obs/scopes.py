@@ -41,6 +41,7 @@ COMPONENTS = (
     "delta_step", "delta_proj", "delta_chunk", "state_restore",
     "window_attend", "global_attend",
     "dsa_index_proj", "dsa_index", "dsa_select",
+    "ssm_step", "ssm_proj", "ssm_chunk",
 )
 # `mtp`: what a multi-token module runs (models/lm.py:CausalLM.draft_step and
 # the draft's argmax), whatever its component: a phase, as `remat` is
@@ -64,6 +65,9 @@ LATENT_KERNEL = "decode_latent"
 
 # the gated delta rule's token step (`name=` in ops/delta_step.py)
 DELTA_KERNEL = "delta_step"
+
+# a Mamba-2 layer's token step (`name=` in ops/ssm_step.py)
+SSM_KERNEL = "ssm_step"
 
 # the lightning indexer's score over a row's live positions (`name=` in
 # ops/index_score.py)
@@ -120,6 +124,14 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         ("delta_chunk", _E("delta_chunk")),
         ("delta_proj", _E("delta_proj")),
         ("state_restore", _E("state_restore")),
+        # a Mamba-2 mixer (models/attention.py:Mamba2Mixer), before
+        # `attn_proj`, whose `to_out` it also has: the token step's state
+        # update, with the kernel by name; the chunked prefill form; the two
+        # projections, the convolution, the step's per-column operands, the
+        # gate and the group norm
+        ("ssm_step", _E(SSM_KERNEL)),
+        ("ssm_chunk", _E("ssm_chunk")),
+        ("ssm_proj", _E("ssm_proj")),
         # a scan's own slicing (`dynamic_index_in_dim` of the stacked
         # parameters, LayerScale vectors and the layer index) and its counter
         # are nobody's: no owner. The cached scan carries the depth-stacked
@@ -173,6 +185,8 @@ def component(op_name: Optional[str], opcode: str = "",
         found = "mla_attend"
     elif base == DELTA_KERNEL:
         found = "delta_step"
+    elif base == SSM_KERNEL:
+        found = "ssm_step"
     elif base == INDEX_KERNEL:
         found = "dsa_index"
     elif base == GROUPED_KERNEL:

@@ -62,6 +62,9 @@ from dalle_pytorch_tpu.ops.pallas_attention import _dot, _NT, _TN, _use_interpre
 TILE_ROWS = 512  # rows a grid step multiplies: the MXU's rows, four times over
 MXU_ROWS = 128  # the MXU's rows, once
 TILE_CAP = 1024  # the widest tile along K or N
+# what a grid step's blocks (each double-buffered) and its float32 scratch may
+# take where `_fit` has to choose: under Mosaic's 16 MiB with room for its own
+VMEM_BUDGET = 12 * 1024 * 1024
 
 #: the row tile each product traced so far got, by (kernel, rows, groups);
 #: tests pin it the way they pin `pallas_attention.tiles_chosen`.
@@ -80,6 +83,30 @@ def _tile(n: int) -> int:
     2304, 896 of 896); the whole of a length 128 does not divide."""
     fits = [c for c in range(128, min(n, TILE_CAP) + 1, 128) if n % c == 0]
     return max(fits) if fits else n
+
+
+def _fit(tm: int, k: int, n: int, itemsize: int):
+    """(tm, tk, tn) of a rows product: `_tile` of K and of N, and, ONLY where
+    one of them is a length 128 does not divide (1,856: taken whole, a block
+    may not end inside it) and the step's blocks would pass VMEM_BUDGET, the
+    other shortened to its next divisor, then the rows halved. A product whose
+    lengths 128 divides keeps what `_tile` and `_row_tile` gave it, whatever
+    its size: every cell before PR 43."""
+    tk, tn = _tile(k), _tile(n)
+    if tk % 128 == 0 and tn % 128 == 0:
+        return tm, tk, tn
+    held = lambda tm, tk, tn: 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+    shorter = lambda t, whole: max([c for c in range(128, t, 128) if whole % c == 0], default=t)
+    while held(tm, tk, tn) > VMEM_BUDGET:
+        if tk % 128 == 0 and shorter(tk, k) < tk:
+            tk = shorter(tk, k)
+        elif tn % 128 == 0 and shorter(tn, n) < tn:
+            tn = shorter(tn, n)
+        elif tm > MXU_ROWS:
+            tm //= 2
+        else:
+            break
+    return tm, tk, tn
 
 
 def _plan(group_sizes: jnp.ndarray, n_tiles: int, tm: int, *, empty_groups: bool):
@@ -199,7 +226,7 @@ def _emit_rows(lhs, rhs, group_sizes, *, transposed, interpret):
     rows, k = lhs.shape
     n = rhs.shape[1] if transposed else rhs.shape[2]
     name = "gmm_dlhs" if transposed else "gmm_fwd"
-    tm, tk, tn = _row_tile(rows, rhs.shape[0]), _tile(k), _tile(n)
+    tm, tk, tn = _fit(_row_tile(rows, rhs.shape[0]), k, n, lhs.dtype.itemsize)
     row_tiles[(name, rows, rhs.shape[0])] = tm
     lhs = _padded(lhs, tm)
     n_tiles = lhs.shape[0] // tm

@@ -249,8 +249,14 @@ def make_lm_train_step(model, grad_accum: int = 1) -> Callable:
     metrics carry what they counted, per layer: `moe_load` [depth, held],
     `moe_rows`, `moe_dropped` and `moe_moved` [depth] (an assignment past a
     layer's buffer is a dropped token: callers require 0; the buffer rows a
-    pass walked: the rows present, rounded up to the chunk).
+    pass walked: the rows present, rounded up to the chunk). A model with a
+    state-space mixer or ungated experts is refused by name: neither has a
+    backward (models/lm.py:FORWARD_ONLY).
     """
+    from dalle_pytorch_tpu.models.lm import FORWARD_ONLY
+
+    if any(layer.kind == "ssm" or layer.ff_kind == "relu2_experts" for layer in model.plan()):
+        raise NotImplementedError(FORWARD_ONLY)
 
     def loss_fn(params, batch, rng):
         del rng  # no dropout in this trunk; the signature is the trainers'

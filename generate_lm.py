@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Generate token ids with a causal language model (`models/lm.py:CausalLM`)
-whose trunk decodes through a cache. Three families of published configs do:
+whose trunk decodes through a cache. Four families of published configs do:
 latent attention (a compressed K/V cache), leading dense layers, a shared
 expert beside sigmoid-routed ones of which this process holds a share, and
 with `index_topk` a lightning indexer in every layer that selects the cached
@@ -11,7 +11,12 @@ cache, `--config benchmark/configs/olmo-hybrid-7b-pp2.json`); and window and
 full layers over grouped K/V heads (window rings beside full K/V, every row
 at its own position, `--config benchmark/configs/k-exaone-236b-ep8.json`),
 whose multi-token module drafts a token a row a step for a two-position
-verify step. Each with parameters stored in bf16.
+verify step; and `nemotron_h` (`hybrid_override_pattern`: every layer ONE
+sublayer, a Mamba-2 mixer, an attention over grouped K/V heads or ungated
+relu2 experts; a state-space state beside K/V in a per-row cache, `--config
+benchmark/configs/nemotron3-nano-30b-ep2.json`, or on the CPU `--config
+benchmark/configs/_tiny-nemotron-h.json --prompts seeded:3 --batch 2
+--prompt_len 40 --max_new_tokens 6`). Each with parameters stored in bf16.
 
 The sampler `generate.py` uses for DALL-E, for token sequences: every prompt
 but its last token is prefilled into a decode cache
@@ -97,10 +102,12 @@ def read_config(args):
             cfg[key] = value
         else:
             raise SystemExit(f"unknown option {key!r} (have: {', '.join([*cfg, *PROGRAM_KEYS])})")
-    if not {"kv_lora_rank", "linear_key_head_dim", "layer_types"} & set(cfg):
+    families = {"kv_lora_rank", "linear_key_head_dim", "layer_types", "hybrid_override_pattern"}
+    if not families & set(cfg):
         raise SystemExit("generation is built for the latent-attention trunk (kv_lora_rank ...), "
-                         "for linear and full layers (linear_key_head_dim ...) and for window "
-                         "and full ones (layer_types ...)")
+                         "for linear and full layers (linear_key_head_dim ...), for window "
+                         "and full ones (layer_types ...) and for layers of one sublayer "
+                         "(hybrid_override_pattern ...)")
     return cfg, program
 
 

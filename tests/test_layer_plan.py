@@ -15,10 +15,12 @@ import pytest
 
 from dalle_pytorch_tpu.models import attention, decode_cache
 from dalle_pytorch_tpu.models.attention import (
-    DALLE, LANES, LATENT, LINEAR, ROWS, Attention, GatedDeltaAttention, LatentAttention)
+    DALLE, LANES, LATENT, LINEAR, ROWS, SSM, Attention, GatedDeltaAttention, LatentAttention,
+    Mamba2Mixer)
 from dalle_pytorch_tpu.models.lm import CausalLM
 from dalle_pytorch_tpu.models.moe import RoutedExperts
-from dalle_pytorch_tpu.models.transformer import Transformer, routed_layers
+from dalle_pytorch_tpu.models.transformer import (
+    NO_MIXER, ROUTED_KINDS, Transformer, routed_layers)
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 SEQ = 24
@@ -34,8 +36,10 @@ FAMILIES = {
     "linear_full": "_tiny-olmo",
     "latent": "_tiny-pangu",
     "latent_indexed": "_tiny-deepseek-v32",
+    "one_sublayer_ssm": "_tiny-nemotron-h",
 }
-MIXERS = {LATENT: LatentAttention, LINEAR: GatedDeltaAttention}
+MIXERS = {LATENT: LatentAttention, LINEAR: GatedDeltaAttention, SSM: Mamba2Mixer,
+          NO_MIXER: type(None)}
 
 
 @functools.lru_cache(maxsize=None)
@@ -74,6 +78,9 @@ def test_the_cache_has_exactly_the_plans_kinds_and_index_rank(family):
         assert cache[decode_cache.ATTN][decode_cache.K].shape[0] == trunk.depth
         return
     for i, layer in enumerate(plan):
+        if layer.cache_kind == "none":  # a feed-forward alone holds nothing
+            assert layer.kind == NO_MIXER and decode_cache.layer_key(i) not in cache
+            continue
         held = cache[decode_cache.layer_key(i)]
         attn = held[decode_cache.ATTN]
         assert _kind_of(held) == layer.cache_kind
@@ -93,12 +100,17 @@ def test_every_bound_mixer_is_on_the_plans_path(family):
     assert len(bound.attn_layers) == len(plan)
     for layer, mixer, ff in zip(plan, bound.attn_layers, bound.ff_layers):
         assert type(mixer) is MIXERS.get(layer.path, Attention)
-        assert mixer.name == f"attn_{layer.attn_id}" and ff.name == f"ff_{layer.ff_id}"
+        # a layer of ONE sublayer has nothing in the other's place
+        assert (mixer is None) == (layer.kind == NO_MIXER) and (ff is None) == (
+            layer.ff_kind == "none") and not (mixer is None and ff is None)
+        assert mixer is None or mixer.name == f"attn_{layer.attn_id}"
+        assert ff is None or ff.name == f"ff_{layer.ff_id}"
         if isinstance(mixer, Attention):
             assert mixer.path == layer.path
             assert (mixer.window is not None) == (layer.kind == "window")
-        assert isinstance(ff, RoutedExperts) == (layer.ff_kind == "swiglu_experts")
-        assert layer.takes_start == (layer.path != LINEAR)
+        assert isinstance(ff, RoutedExperts) == (layer.ff_kind in ROUTED_KINDS)
+        assert not isinstance(ff, RoutedExperts) or ff.act == ROUTED_KINDS[layer.ff_kind]
+        assert layer.takes_start == (layer.path not in (LINEAR, SSM, NO_MIXER))
         assert layer.rotary in (None, layer.kind) and (layer.rotary is None) == (
             layer.kind not in dict(trunk.rotary_specs or {}))
     assert routed_layers(plan) == sum(isinstance(ff, RoutedExperts) for ff in bound.ff_layers)
@@ -123,6 +135,35 @@ def test_the_two_full_layers_that_look_alike_are_on_different_paths(family, path
     assert full and {layer.path for layer in full} == {path}
     assert {layer.cache_kind for layer in full} == {"heads"}
     assert {layer.per_row for layer in full} == {per_row}
+
+
+def test_the_published_pattern_of_52_layers_is_one_sublayer_a_layer():
+    """`hybrid_override_pattern` as published: 23 Mamba-2 mixers, 6 attention
+    layers and 23 routed layers, each ONE sublayer; the attention layers' 2
+    K/V heads under 32 query heads put them on ROWS, so the WHOLE plan is per
+    row, the recurrent layers' index too (the smaller change: `per_row` stays
+    one decision a stack, and `decode_cache.layer_spec(kind="recurrent")`
+    takes it), and a routed layer has no cache entry."""
+    with open(ROOT / "benchmark" / "configs" / "nemotron3-nano-30b-ep2.json") as f:
+        cfg = json.load(f)
+    pattern = cfg["published"]["hybrid_override_pattern"]
+    assert len(pattern) == 52
+    model = CausalLM.from_config(dict(cfg, num_hidden_layers=52, hybrid_override_pattern=pattern),
+                                 64)
+    plan = model.plan()
+    kinds = {"M": ("ssm", SSM, "recurrent", "none"), "*": ("full", ROWS, "heads", "none"),
+             "E": (NO_MIXER, NO_MIXER, "none", "relu2_experts")}
+    assert [(p.kind, p.path, p.cache_kind, p.ff_kind) for p in plan] == [kinds[c] for c in pattern]
+    assert [sum(p.kind == k for p in plan) for k in ("ssm", "full", NO_MIXER)] == [23, 6, 23]
+    assert all(p.per_row and p.rotary is None and not p.selects for p in plan)
+    assert routed_layers(plan) == 23
+    cache = jax.eval_shape(lambda: model.init_cache(2, 64))
+    assert len(cache) == 29 and all(
+        (decode_cache.layer_key(i) in cache) == (c != "E") for i, c in enumerate(pattern))
+    attn = cache["layer_0"][decode_cache.ATTN]
+    assert attn[decode_cache.STATE].shape == (2, 128, 64 * 64)  # [rows, state, heads x width]
+    assert attn[decode_cache.CONV].shape == (2, 3, 6144) and attn[decode_cache.INDEX].shape == (2,)
+    assert cache["layer_5"][decode_cache.ATTN][decode_cache.K].shape == (2, 2, 64, 128)
 
 
 def test_a_cache_of_the_wrong_index_rank_is_refused_with_a_sentence():

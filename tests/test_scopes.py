@@ -138,6 +138,31 @@ def test_component_rules(op_name, opcode, name, want):
     assert scopes.component(op_name, opcode, name) == want
 
 
+def test_a_state_space_mixers_parts_are_placed_before_the_attention_rules():
+    """A Mamba-2 mixer lives in an `attn_{i}` module and has a `to_out`: its
+    rules stand before `attn_proj` and the module's glue, the kernel is known
+    by its instruction name, and the chunked prefill form is its own."""
+    path = "jit(lm_sample)/while/body/closed_call/transformer/attn_2/"
+    for op_name, opcode, instruction, want in (
+            (path + "ssm_proj/to_out/dot_general", "fusion", "f.1", ("ssm_proj", "fwd")),
+            (path + "ssm_proj/dot_general", "fusion", "f.2", ("ssm_proj", "fwd")),
+            (path + "ssm_step/ssm_step", "custom-call", "ssm_step.7", ("ssm_step", "fwd")),
+            (path + "ssm_step/transpose", "fusion", "f.3", ("ssm_step", "fwd")),
+            (None, "custom-call", "%ssm_step.12", ("ssm_step", "fwd")),
+            ("jit(lm_prefill)/CausalLM.prefill/transformer/attn_0/ssm_chunk/while/body/mul",
+             "fusion", "f.4", ("ssm_chunk", "fwd")),
+            (path.replace("attn_2", "attn_5") + "to_out/dot_general", "fusion", "f.5",
+             ("attn_proj", "fwd")),
+            (path.replace("attn_2", "ff_1") + "moe_experts/gmm_fwd", "custom-call", "gmm_fwd.3",
+             ("moe_experts", "fwd"))):
+        assert scopes.component(op_name, opcode, instruction) == want, (op_name, instruction)
+    assert {"ssm_step", "ssm_proj", "ssm_chunk"} <= set(scopes.COMPONENTS)
+    order = [name for name, _ in scopes.RULES]
+    assert max(order.index(n) for n in ("ssm_step", "ssm_chunk", "ssm_proj")) < min(
+        order.index("attend"), order.index("attn_proj"))
+    assert scopes.SSM_KERNEL == "ssm_step" and scopes.SSM_KERNEL not in scopes.KERNELS
+
+
 def test_the_grouped_kernels_rule_stands_before_attend_and_after_the_kernels():
     """`global_attend` (with `decode_grouped` by name) is asked before the
     plain `attend`, which its name contains as a word of a path would, and
@@ -215,7 +240,7 @@ def program_texts():
             "lm_sample": _lm_sample_text(), "verify_sample": _verify_sample_text(),
             "dsa_sample": _lm_sample_text(index_heads=2, index_dim=16, index_topk=4,
                                           moe_groups=(2, 1)),
-            **_hybrid_texts()}
+            **_hybrid_texts(), **_ssm_texts()}
 
 
 def _lm_step_text() -> str:
@@ -262,6 +287,34 @@ def _hybrid_texts() -> dict:
             zero + 8).compile().as_text(),
         "hybrid_prefill": prefill.lower(
             variables, jnp.zeros((2, 8), jnp.int32), mdl.init_cache(2), zero).compile().as_text(),
+    }
+
+
+def _ssm_texts() -> dict:
+    """Compiled texts of a tiny state-space hybrid's sampler and prefill:
+    layers of ONE sublayer (a Mamba-2 mixer, ungated routed experts, an
+    attention over shared K/V heads) over a per-row cache."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl = lm.CausalLM(
+        num_tokens=40, dim=32, depth=3, seq_len=24, heads=4, dim_head=8,
+        trunk=dict(norm="rms", use_bias=False, layerscale=False, kv_heads=2,
+                   attn_types=("ssm", "none", "full"),
+                   ff_kinds=("none", "relu2_experts", "none"), ssm_heads=4, ssm_head_dim=8,
+                   ssm_groups=2, ssm_state=8, ssm_chunk=4, experts_total=4, experts_per_token=2,
+                   experts_held=(0, 2), expert_dim=16, moe_buffer_rows=64, moe_score="sigmoid",
+                   moe_score_bias=True, shared_dim=16))
+    variables = jax.jit(mdl.init)(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    sampler = jax.jit(lm._verify_sampler_builder(mdl, (4, 0.9, 1.0, 1, None)),
+                      donate_argnums=(2,))
+    prefill = jax.jit(lm._prefill_builder(mdl, ()), donate_argnums=(2,))
+    return {
+        "ssm_sample": sampler.lower(
+            variables, jax.random.PRNGKey(1), mdl.init_cache(2), jnp.zeros((2, 2), jnp.int32),
+            jnp.full((2,), 8, jnp.int32)).compile().as_text(),
+        "ssm_prefill": prefill.lower(
+            variables, jnp.zeros((2, 8), jnp.int32), mdl.init_cache(2),
+            jnp.asarray(0, jnp.int32)).compile().as_text(),
     }
 
 

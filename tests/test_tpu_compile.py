@@ -1009,3 +1009,70 @@ def test_prefill_chunk_compiles_with_the_cache_at_full_size(one_chip, monkeypatc
     assert temp < 2 * 2**30 and plan < 11.5
     assert placed.memory_analysis().temp_size_in_bytes < 64 * 2**20
     assert checked.memory_analysis().temp_size_in_bytes < 512 * 2**20
+
+
+def _nemotron_h(one_chip, monkeypatch, sessions):
+    """(model, its variables' and its sessions' cache's shapes on the described
+    chip) of `nemotron3.decode.8k`: the cell's own widths and lengths, at
+    `sessions` rows (the cell's 192 plan 14.21 GB and take 64 s to compile
+    here, PR 43: a step's K/V go in by one `dynamic-update-slice` a row)."""
+    import json
+    from pathlib import Path
+
+    from dalle_pytorch_tpu.models import lm
+    from dalle_pytorch_tpu.ops import grouped_decode, grouped_matmul, pallas_attention, ssm_step
+
+    for module in (grouped_decode, grouped_matmul, pallas_attention, ssm_step):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    root = Path(__file__).resolve().parent.parent / "benchmark"
+    cfg = json.loads((root / "configs/nemotron3-nano-30b-ep2.json").read_text())
+    job = json.loads((root / "workloads/nemotron3.decode.8k.json").read_text())["job"]
+    assert (job["sessions"], max(job["document_tokens"])) == (192, 8192)
+    steps = job["question_tokens"] + job["answer_tokens"]
+    mdl = lm.CausalLM.from_config(cfg, 8192 + steps, **job.get("model", {}))
+    on = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    variables = on(jax.eval_shape(mdl.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    cache = on(jax.eval_shape(lambda: mdl.init_cache(sessions)))
+    return mdl, variables, cache
+
+
+def test_state_space_token_loop_compiles_at_the_cells_size(one_chip, monkeypatch, capsys):
+    """The token loop of `nemotron3.decode.8k` (the nine layers `MEMEM*EME` at
+    the published widths, sessions of up to 8,192 + 256 positions, every row
+    at its own index, 256 steps; 24 sessions here, an eighth of the cell's):
+    Mosaic takes `ssm_step`'s blocks of a row's 8 groups x 128 x 512 on a state
+    [rows, 128, 4096] whose rows are whole lane tiles, the loop's body holds the
+    kernel once a Mamba-2 layer and no copy of a state or of the K/V leaves, a
+    routed layer is TWO grouped products, and the attention layer's step is
+    `decode_grouped` at 16 query rows a K/V head."""
+    from dalle_pytorch_tpu.models import lm
+    from dalle_pytorch_tpu.ops import ssm_step
+
+    mdl, variables, cache = _nemotron_h(one_chip, monkeypatch, 24)
+    plan = mdl.plan()
+    assert [layer.kind for layer in plan] == [
+        "ssm", "none", "ssm", "none", "ssm", "full", "none", "ssm", "none"]
+    assert sorted(cache) == ["layer_0", "layer_2", "layer_4", "layer_5", "layer_7"]
+    attn = cache["layer_0"]["attn"]
+    assert attn["state"].shape == (24, 128, 4096) and attn["conv"].shape == (24, 3, 6144)
+    assert attn["index"].shape == (24,) and cache["layer_5"]["attn"]["k"].shape == (
+        24, 2, 8448, 128)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(lm._verify_sampler_builder(mdl, (256, 0.9, 1.0, 2, None)),
+                       donate_argnums=(2,)).lower(
+        variables, key, cache, i32(24, 32), i32(24)).compile()
+    text, gb = compiled.as_text(), _device_bytes(compiled) / 1e9
+    with capsys.disabled():
+        print(f"\nnemotron3.decode.8k sampler at 24 sessions: planned {gb:.2f} GB")
+    # a step: 4 state updates, 4 x 2 grouped products, 1 attention
+    assert text.count("tpu_custom_call") == 13
+    assert len(re.findall(r"%ssm_step[.\d]* = \(f32\[24,1,4096\]\S*, f32\[24,128,4096\]",
+                          text)) == 4
+    assert len(re.findall(r"%gmm_fwd[.\d]* = ", text)) == 8
+    assert len(re.findall(r"%decode_grouped[.\d]* = bf16\[24,2,16,128\]", text)) == 1
+    assert not re.search(r"= f32\[24,128,4096\]\S* copy\(", text)
+    assert not re.search(r"= bf16\[24,2,8448,128\]\S* copy\(", text)
+    assert ssm_step.GROUPS_PER_BLOCK == 8
+    assert gb < HBM_BYTES / 1e9
