@@ -233,10 +233,14 @@ class DALLE(nn.Module):
         is_text_vocab = vocab < self.total_text_tokens
         return is_text_row != is_text_vocab
 
-    def to_logits(self, out: jnp.ndarray) -> jnp.ndarray:
+    def _head_input(self, out: jnp.ndarray) -> jnp.ndarray:
+        """What the logits head multiplies: `out`, normalized."""
         if self.stable:
             out = self.norm_by_max(out)
-        out = self.logits_norm(out)
+        return self.logits_norm(out)
+
+    def to_logits(self, out: jnp.ndarray) -> jnp.ndarray:
+        out = self._head_input(out)
         if self.share_input_output_emb:
             kernel, bias = self._logits_kernel()
             return out @ kernel.astype(out.dtype) + bias.astype(out.dtype)
@@ -268,7 +272,7 @@ class DALLE(nn.Module):
         """Forward-mode split CE via the vocab-chunked kernel — identical
         numerics to the dense path (tests/test_dalle.py parity) without
         the [B, N, V] logits. Not measured on the chip: `flagship.train`
-        runs the dense loss (`loss_pct.train` 4.11, PERF.md section 5)."""
+        runs the dense loss (`_split_loss`; PERF.md section 5)."""
         from dalle_pytorch_tpu.ops.losses import chunked_masked_ce, split_weighted_mean
 
         h, kernel, bias, offsetted_image = self._fused_head(out, image)
@@ -297,16 +301,30 @@ class DALLE(nn.Module):
         p = self.variables["params"]["logits_dense"]
         return p["kernel"], p.get("bias")
 
+    def _logits_block(self, h, image_vocab: bool):
+        """Logits of the rows `h` over the text columns alone, or over the
+        image columns alone: `to_logits`' product and precision, on the one
+        block of the kernel the logits mask leaves a row."""
+        split = self.total_text_tokens
+        cols = slice(split, None) if image_vocab else slice(None, split)
+        if self.share_input_output_emb:
+            emb = self.image_emb if image_vocab else self.text_emb
+            kernel, bias = emb.embedding.T.astype(h.dtype), self.logits_bias
+        else:
+            kernel, bias = self._logits_kernel()
+            # cast whole, then cut: XLA casts the kernel once for both blocks
+            # either way, and a cast it moves there itself loses its name
+            kernel = kernel.astype(h.dtype)[:, cols]
+        logits = h @ kernel
+        return logits if bias is None else logits + bias[cols].astype(h.dtype)
+
     def _fused_head(self, out, image):
         """Shared fused-CE prologue: normalized head input + logits kernel
         + vocab-offset image labels. Keeping it in one place keeps the two
         objectives' numerics in lockstep with the dense path."""
         assert image is not None, "when training, image must be supplied"
-        if self.stable:
-            out = self.norm_by_max(out)
-        h = self.logits_norm(out)
         kernel, bias = self._logits_kernel()
-        return h, kernel, bias, image + self.total_text_tokens
+        return self._head_input(out), kernel, bias, image + self.total_text_tokens
 
     def _fused_inverse_loss(self, out, text, image, seq_len):
         """Inverse-mode (image->text) split CE via the vocab-chunked kernel.
@@ -425,46 +443,59 @@ class DALLE(nn.Module):
                 return self._fused_inverse_loss(out, text, image, seq_len)
             return self._fused_forward_loss(out, text, image, seq_len)
 
-        logits = self.to_logits(out)
+        if return_loss:
+            return self._split_loss(out, text, image, inverse_mapping)
 
+        logits = self.to_logits(out)
         lmask = self._logits_blocked(seq_len, inverse_mapping)[None]
         with jax.named_scope("logits_mask"):
-            logits = jnp.where(lmask, NEG_MASK_VALUE, logits.astype(jnp.float32))
+            return jnp.where(lmask, NEG_MASK_VALUE, logits.astype(jnp.float32))
 
-        if not return_loss:
-            return logits
-
+    def _split_loss(self, out, text, image, inverse_mapping):
+        """Split text/image cross-entropy over the logits the mask leaves
+        alive (`_logits_blocked`): a text row's over the text columns, an
+        image row's over the image columns, labels local to the block. A
+        blocked entry is `exp(NEG_MASK_VALUE - max)` = 0 of its row's sum
+        and takes no gradient, so this is the loss over the masked
+        [B, N, V] logits, which are never made."""
         assert image is not None, "when training, image must be supplied"
-        with jax.named_scope("loss"):
-            return self._dense_loss(logits, text, image, inverse_mapping)
-
-    def _dense_loss(self, logits, text, image, inverse_mapping):
-        """Split text/image cross-entropy over materialized logits."""
-        offsetted_image = image + self.total_text_tokens
-
+        assert image.shape[1] == self.image_seq_len, (
+            f"the loss takes all {self.image_seq_len} image tokens, "
+            f"got {image.shape[1]}"
+        )
+        if self.is_initializing():
+            self.to_logits(out[:, :1])  # makes the head's parameters
+        h = self._head_input(out)
         if inverse_mapping:
             # image first, then text: labels rotate image forward one step and
             # append the full bos-padded text (`:686-687`)
-            labels = jnp.concatenate([offsetted_image[:, 1:], text], axis=1)
             split = self.image_seq_len  # see module docstring re: fork's quirk
-            loss_text = cross_entropy(logits[:, split:], labels[:, split:])
-            loss_img = cross_entropy(logits[:, : split - 1], labels[:, : split - 1])
-            pred3 = jnp.argmax(logits[:, split : split + 3], axis=-1)
-            accuracy = jnp.mean(
-                jnp.all(pred3 == labels[:, split : split + 3], axis=-1).astype(jnp.float32)
-            )
+            image_rows, image_labels = slice(None, split - 1), image[:, 1:]
+            text_rows = slice(split, None)
             ct, ci = self.text_loss_coeff_inv, self.img_loss_coeff_inv
-            loss = (ct * loss_text + ci * loss_img) / (ct + ci)
         else:
-            labels = jnp.concatenate([text[:, 1:], offsetted_image], axis=1)
             split = self.text_seq_len
-            loss_text = cross_entropy(logits[:, :split], labels[:, :split])
-            loss_img = cross_entropy(logits[:, split:], labels[:, split:])
+            text_rows = slice(None, split)
+            image_rows, image_labels = slice(split, None), image
             ct = self.text_loss_coeff
             ci = self.loss_img_weight if self.img_loss_coeff is None else self.img_loss_coeff
+        text_labels = text[:, 1:]
+
+        with jax.named_scope("logits_text"):
+            logits_text = self._logits_block(h[:, text_rows], image_vocab=False)
+        with jax.named_scope("logits_image"):
+            logits_image = self._logits_block(h[:, image_rows], image_vocab=True)
+        with jax.named_scope("loss"):
+            loss_text = cross_entropy(logits_text, text_labels)
+            loss_img = cross_entropy(logits_image, image_labels)
             loss = (ct * loss_text + ci * loss_img) / (ct + ci)
             accuracy = None
-
+            if inverse_mapping:
+                # text ids start at 0: the block's argmax is the global one
+                pred3 = jnp.argmax(logits_text[:, :3], axis=-1)
+                accuracy = jnp.mean(
+                    jnp.all(pred3 == text_labels[:, :3], axis=-1).astype(jnp.float32)
+                )
         return loss, accuracy
 
     # ------------------------------------------------ cached decode methods

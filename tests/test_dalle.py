@@ -323,6 +323,115 @@ class TestCachedDecode:
         assert (arr >= 0).all() and (arr < NUM_IMG).all()
 
 
+def _masked_dense_loss(model, logits, text, image, inverse_mapping):
+    """The loss over the masked full [B, N, V] logits, as `DALLE._dense_loss`
+    computed it until the split loss took its place: the independent
+    reference. `text` is the bos-padded, pad-remapped ids of `embed_text`."""
+    from dalle_pytorch_tpu.models.dalle import cross_entropy
+
+    offsetted_image = image + model.total_text_tokens
+    if inverse_mapping:
+        labels = jnp.concatenate([offsetted_image[:, 1:], text], axis=1)
+        split = model.image_seq_len
+        loss_text = cross_entropy(logits[:, split:], labels[:, split:])
+        loss_img = cross_entropy(logits[:, : split - 1], labels[:, : split - 1])
+        pred3 = jnp.argmax(logits[:, split : split + 3], axis=-1)
+        accuracy = jnp.mean(
+            jnp.all(pred3 == labels[:, split : split + 3], axis=-1).astype(jnp.float32)
+        )
+        ct, ci = model.text_loss_coeff_inv, model.img_loss_coeff_inv
+        return (ct * loss_text + ci * loss_img) / (ct + ci), accuracy
+    labels = jnp.concatenate([text[:, 1:], offsetted_image], axis=1)
+    split = model.text_seq_len
+    loss_text = cross_entropy(logits[:, :split], labels[:, :split])
+    loss_img = cross_entropy(logits[:, split:], labels[:, split:])
+    ct = model.text_loss_coeff
+    ci = model.loss_img_weight if model.img_loss_coeff is None else model.img_loss_coeff
+    return (ct * loss_text + ci * loss_img) / (ct + ci), None
+
+
+class TestSplitLoss:
+    """`return_loss=True` computes a text row's logits over the text columns
+    and an image row's over the image columns, and nothing else; the loss,
+    its gradients and the inverse objective's accuracy are those of the
+    masked full logits that `return_loss=False` still returns."""
+
+    KW = dict(depth=1, text_loss_coeff=2.0, img_loss_coeff=3.0,
+              text_loss_coeff_inv=5.0, img_loss_coeff_inv=0.5)
+
+    @staticmethod
+    def _reference(model, text, image, inverse):
+        def f(params):
+            variables = {"params": params}
+            logits = model.apply(variables, text, image, inverse_mapping=inverse)
+            padded, _ = model.apply(variables, text, method=DALLE.embed_text)
+            return _masked_dense_loss(model, logits, padded, image, inverse)
+        return f
+
+    @pytest.mark.parametrize("stable", [False, True])
+    @pytest.mark.parametrize("inverse", [False, True])
+    @pytest.mark.parametrize("share_emb", [False, True])
+    def test_matches_the_loss_over_the_masked_full_logits(
+            self, batch, share_emb, inverse, stable):
+        text, image = batch
+        model = make_dalle(share_input_output_emb=share_emb, stable=stable, **self.KW)
+        params = init_vars(model, text, image)["params"]
+        # zeros at init: a bias that is there has to count. The inverse
+        # objective's first three text labels are id 5, which the bias makes
+        # the text block's argmax: an accuracy of 1, not 0 against 0
+        bias = 0.3 * jax.random.normal(jax.random.PRNGKey(7), (model.total_tokens,))
+        if inverse:
+            text, bias = text.at[:, :3].set(5), bias.at[5].add(50.0)
+        if share_emb:
+            params = dict(params, logits_bias=bias)
+        else:
+            params = dict(params, logits_dense=dict(params["logits_dense"], bias=bias))
+
+        def split(p):
+            return model.apply({"params": p}, text, image, return_loss=True,
+                               inverse_mapping=inverse)
+
+        (want, want_acc), g_want = jax.value_and_grad(
+            self._reference(model, text, image, inverse), has_aux=True)(params)
+        (got, got_acc), g_got = jax.value_and_grad(split, has_aux=True)(params)
+
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+        if inverse:
+            assert float(got_acc) == float(want_acc) == 1.0
+        else:
+            assert got_acc is None and want_acc is None
+        TestFusedCE._assert_grad_parity(g_want, g_got, atol=1e-5)
+        # the columns of the head a row's block does not touch still get
+        # their gradient from the other block: no leaf is left at zero
+        assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(g_got))
+
+    @pytest.mark.parametrize("inverse", [False, True])
+    def test_a_short_image_keeps_its_masked_logits_and_has_no_loss(self, batch, inverse):
+        """`image.shape[1] < image_seq_len`: the full logits split by the
+        row rule of `_logits_blocked` as before; a loss there has one row
+        more than labels, which the masked dense loss refused with a
+        broadcasting error and the split loss refuses by name."""
+        text, image = batch
+        model = make_dalle(**self.KW)
+        variables = init_vars(model, text, image)
+        short = image[:, : IMG_SEQ - 2]
+        logits = np.asarray(model.apply(variables, text, short, inverse_mapping=inverse))
+        assert logits.shape == (2, TEXT_SEQ + 1 + IMG_SEQ - 2, model.total_tokens)
+        text_rows = np.arange(logits.shape[1]) < TEXT_SEQ
+        if inverse:
+            text_rows = np.arange(logits.shape[1]) >= IMG_SEQ
+        text_cols = np.arange(model.total_tokens) < model.total_text_tokens
+        blocked = text_rows[:, None] != text_cols[None, :]
+        assert (logits[:, blocked] < -1e30).all()
+        assert np.isfinite(logits[:, ~blocked]).all()
+        with pytest.raises(ValueError):
+            self._reference(model, text, short, inverse)(variables["params"])
+        with pytest.raises(AssertionError, match="image tokens"):
+            model.apply(variables, text, short, return_loss=True,
+                        inverse_mapping=inverse)
+
+
 class TestFusedCE:
     """Vocab-chunked CE (ops/losses.py) must match the dense loss path
     bit-for-bit in semantics: same loss, same grads."""

@@ -25,6 +25,7 @@ from dalle_pytorch_tpu.obs.tracing import HOST_SPAN_PREFIX, host_span
 REPO = Path(__file__).resolve().parents[1]
 TEXT_SEQ, FMAP = 8, 4
 IMG_SEQ = FMAP * FMAP
+TRAIN_IMG_VOCAB = 40  # the train step's image ids: no other width of its model
 
 # ------------------------------------------------------------------ parse
 
@@ -92,8 +93,10 @@ def test_a_trace_names_an_operation_by_its_whole_line():
     ("jit(step)/optimizer/mul", "fusion", "fusion.1", ("optimizer", "fwd")),
     ("jit(step)/jvp(DALLE)/loss/logits_chunk/dot_general", "fusion", "fusion.2",
      ("head", "fwd")),
-    ("jit(step)/jvp(DALLE)/DALLE._dense_loss/loss/reduce_sum", "fusion", "f.3",
+    ("jit(step)/jvp(DALLE)/DALLE._split_loss/loss/reduce_sum", "fusion", "f.3",
      ("loss", "fwd")),
+    ("jit(step)/transpose(jvp(DALLE))/DALLE._split_loss/logits_image/DALLE._logits_block/"
+     "dot_general", "fusion", "f.5", ("head", "bwd")),
     ("jit(sample_cached)/while/body/closed_call/sample/jit(_gumbel)/add", "fusion",
      "f.4", ("sample", "fwd")),
     ("jit(f)/transformer/scan_stack/cached_scan/while/body/closed_call/layers/cache_read/"
@@ -209,7 +212,8 @@ def program_texts():
     from dalle_pytorch_tpu.training import TrainState, make_dalle_train_step, make_optimizer
 
     model, variables, text, toks = _tiny(
-        attn_impl="flash", reversible=True, reversible_impl="remat")
+        attn_impl="flash", reversible=True, reversible_impl="remat",
+        num_image_tokens=TRAIN_IMG_VOCAB)
     state = TrainState.create(
         apply_fn=model.apply, params=variables["params"],
         tx=make_optimizer(3e-4, clip_grad_norm=0.5))
@@ -402,6 +406,39 @@ def test_train_step_text_tells_forward_backward_and_recompute_apart(program_text
     for comp in ("attn_kernel", "attn_proj", "ff"):
         assert {(comp, "fwd"), (comp, "bwd"), (comp, "remat")} <= seen, comp
     assert ("optimizer", "fwd") in seen and ("loss", "bwd") in seen
+
+
+def test_the_train_step_computes_the_two_live_blocks_of_the_logits_and_no_more(program_texts):
+    """The loss's head (`DALLE._split_loss`): a text row's logits over the
+    text ids, an image row's over the image ids. No instruction of the step,
+    fused or not, has the full [B, N, V] logits' shape; both products are
+    `head`'s, both cross-entropies `loss`'s, and nothing the loss traces is
+    left `unscoped`."""
+    text_vocab, batch = 64 + TEXT_SEQ, 2
+    full = f"[{batch},{TEXT_SEQ + IMG_SEQ},{text_vocab + TRAIN_IMG_VOCAB}]"
+    blocks = {"text": f"[{batch},{TEXT_SEQ},{text_vocab}]",
+              "image": f"[{batch},{IMG_SEQ},{TRAIN_IMG_VOCAB}]"}
+    train = program_texts["train"]
+    assert full not in train
+    parsed = scopes.parse(train)
+    table = scopes.classify(parsed)
+    path = {name: op_name or "" for name, (_, _, op_name) in parsed.items()}
+    assert not any("logits_mask" in p for p in path.values())
+    for block, dims in blocks.items():
+        scope = f"/logits_{block}/"
+        shaped = {name for name, row in table.items() if row[1].endswith(dims)}
+        assert {table[n][2] for n in shaped} == {"head", "loss"}
+        # the product's bias add has the block's shape (the CPU's `dot` is
+        # two-dimensional), and so have the softmax's exponential and the
+        # cotangent the loss hands back
+        assert any(scope in path[n] and table[n][3] == "fwd" for n in shaped)
+        assert any(path[n].endswith("/loss/exp") for n in shaped)
+        assert any(table[n][2:] == ["loss", "bwd"] for n in shaped)
+        scoped = [n for n in table if scope in path[n]]
+        assert all(table[n][2] == "head" for n in scoped)
+        assert {table[n][3] for n in scoped if table[n][0] == "dot"} == {"fwd", "bwd"}
+    traced = [n for n in table if "DALLE._split_loss" in path[n]]
+    assert traced and all(table[n][2] in ("head", "loss") for n in traced)
 
 
 # ------------------------------------------------------------------ kernels
