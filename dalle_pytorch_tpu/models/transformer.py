@@ -77,6 +77,7 @@ from dalle_pytorch_tpu.ops.masks import (
     block_layout_to_token_mask,
 )
 from dalle_pytorch_tpu.models.moe import RoutedExperts
+from dalle_pytorch_tpu.ops.pallas_attention import RESIDUAL_NAMES
 from dalle_pytorch_tpu.ops.rotary import build_dalle_rotary, rotary_cos_sin
 from dalle_pytorch_tpu.ops.shift import (
     shift_tokens_dalle,
@@ -86,10 +87,29 @@ from dalle_pytorch_tpu.ops.shift import (
 )
 
 
+# the one policy name that is not `jax.checkpoint_policies`' own: keep what
+# the flash kernels' forward rule hands their backward, and nothing else.
+# ONE policy object for every layer: jax caches a jitted emitter's partial
+# evaluation by the policy's identity, and a policy made anew per layer
+# would lower each kernel body once per call site.
+FLASH_RESIDUALS = "flash_residuals"
+_KEEP_FLASH_RESIDUALS = jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
+
+
 def resolve_remat_policy(name: "Optional[str]"):
-    """`jax.checkpoint_policies` member by name, or None (save nothing).
-    Single resolution point for all three executors (scan, unrolled
-    remat, pipeline) so their activation-memory behavior cannot drift."""
+    """`jax.checkpoint_policies` member by name, `FLASH_RESIDUALS`, or None
+    (save nothing). Single resolution point for all three executors (scan,
+    unrolled remat, pipeline) so their activation-memory behavior cannot
+    drift.
+
+    `flash_residuals` keeps a layer's q, k, v, attention result and
+    log-sum-exp (`pallas_attention.RESIDUAL_NAMES`), B x N x (4 x dim x 2 +
+    heads x 4) bytes a layer at two bytes an element, so that the backward
+    runs neither the forward kernel nor the projection and rotary before
+    it a second time; a layer whose attention is not the flash kernel has
+    no such names and saves nothing."""
+    if name == FLASH_RESIDUALS:
+        return _KEEP_FLASH_RESIDUALS
     return getattr(jax.checkpoint_policies, name) if name else None
 
 
@@ -469,10 +489,13 @@ class Transformer(nn.Module):
     shared_ff_ids: Optional[Sequence[int]] = None
     reversible: bool = False
     reversible_impl: str = "remat"  # "remat" | "revnet" | "revnet_naive" (test)
-    # jax.checkpoint policy name for the remat executor (e.g.
-    # "dots_with_no_batch_dims_saveable" keeps matmul outputs and only
-    # recomputes cheap elementwise work in the backward — much faster than
-    # full recompute for a modest memory cost). None = save nothing.
+    # policy name for the remat executor (`resolve_remat_policy`):
+    # "flash_residuals" keeps what the flash kernels' backward reads, so a
+    # layer's forward kernel runs once; "dots_with_no_batch_dims_saveable"
+    # keeps matmul outputs and recomputes the elementwise work alone.
+    # None or "nothing_saveable" = save nothing (full recompute); that is
+    # the default HERE, and each model class owns its own (`DALLE`,
+    # `CausalLM`).
     remat_policy: Optional[str] = None
     attn_impl: str = "auto"  # "dense" | "flash" | "ring" | "auto"
     sp_mesh: Any = None  # Mesh with "sp" axis for attn_impl="ring"

@@ -42,10 +42,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+# what `flash_attention`'s forward rule hands its backward, by the names a
+# `jax.checkpoint` policy may keep them under (`save_only_these_names`):
+# q, k, v as the kernel took them, its result and its log-sum-exp
+RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_out", "flash_lse")
 
 CompilerParams = pltpu.CompilerParams
 
@@ -817,6 +823,15 @@ def flash_attention(
     causality is enforced in-kernel with a block-triangle loop bound and no
     materialized mask. Differentiable (custom VJP, recompute-based backward).
 
+    The forward rule hands the backward q, k, v as the kernel took them, its
+    result and its log-sum-exp, each under its name of `RESIDUAL_NAMES`
+    (`jax.ad_checkpoint.checkpoint_name`): a `jax.checkpoint` policy that
+    saves those names (`save_only_these_names`; the trunk's
+    `remat_policy="flash_residuals"`) keeps them across remat, and the
+    backward then runs neither this forward kernel nor what made q, k and v
+    a second time. Outside a checkpoint, and under a policy that does not
+    know them, a name is the identity and lowers to nothing.
+
     `window` (causal, unmasked calls only): query t sees key p iff
     `0 <= t - p < window`. It is a second loop bound beside the causal one,
     in all three kernels and in the DMA skip, and the tiles that straddle
@@ -880,6 +895,10 @@ def flash_attention(
 
     def _attn_fwd(q_, k_, v_):
         o, lse = _emit_fwd(q_, k_, v_, mask_pad, occupancy, **static)
+        # named HERE, on the values the rule hands the backward: a name on
+        # the primal result outside would mark another variable
+        q_, k_, v_, o, lse = (checkpoint_name(t, name) for t, name in
+                              zip((q_, k_, v_, o, lse), RESIDUAL_NAMES))
         return o, (q_, k_, v_, o, lse)
 
     def _attn_bwd(res, do):

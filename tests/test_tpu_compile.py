@@ -232,10 +232,12 @@ def test_sharded_flash_decode_compiles_on_four_chips(topo, compiled_kernels):
 # ------------------------------------------------------ whole train step
 
 
-def _flagship_step(devices, mesh_axes, batch, executor="scan", reversible=True):
+def _flagship_step(devices, mesh_axes, batch, executor="scan", reversible=True,
+                   remat_policy="flash_residuals"):
     """The step `train_dalle.py` jits — flagship widths, a frozen 256 px dVAE
     encoding in the step, bf16 — lowered for DESCRIBED devices: state and
-    batch are shapes with shardings, never arrays."""
+    batch are shapes with shardings, never arrays. `remat_policy` is the
+    trainer's default unless a test asks for another."""
     from dalle_pytorch_tpu.models.dvae import DiscreteVAE
     from dalle_pytorch_tpu.parallel import (
         batch_sharding, make_mesh, partition_params, state_shardings,
@@ -250,7 +252,8 @@ def _flagship_step(devices, mesh_axes, batch, executor="scan", reversible=True):
     m = cfg.model
     m.dim, m.depth, m.heads, m.dim_head, m.text_seq_len = 1024, 12, H, D, TEXT
     m.shift_tokens = m.rotary_emb = True
-    m.reversible, m.executor = reversible, executor
+    assert m.remat_policy == "flash_residuals"  # what a trainer gets unasked
+    m.reversible, m.executor, m.remat_policy = reversible, executor, remat_policy
     cfg.vae.image_size = 8 * FMAP  # 3 layers: 256 px -> 32x32 tokens
     vae = vae_from_config(cfg.vae)
     mesh = make_mesh(devices=devices, **mesh_axes)
@@ -317,14 +320,20 @@ def _flagship_step(devices, mesh_axes, batch, executor="scan", reversible=True):
 LOWERED_TEXT_BYTES = 9_159_532
 
 
-def test_flagship_step_holds_each_kernel_body_once(topo, compiled_kernels):
-    """The unrolled 12-layer step with remat calls the flash kernels 48
-    times (fwd, fwd again under remat, dq, dkv per layer) and holds 4
-    bodies: the emitters are jitted, so each is traced once and lowered to
+@pytest.mark.parametrize("policy, forward", [
+    ("flash_residuals", (12, 1)), ("nothing_saveable", (24, 2))],
+    ids=["flash_residuals", "nothing_saveable"])
+def test_flagship_step_holds_each_kernel_body_once(topo, compiled_kernels, policy, forward):
+    """The unrolled 12-layer step with remat calls the flash kernels 36
+    times (fwd, dq, dkv per layer) when it keeps the forward rule's
+    residuals, the trainer's default, and 48 when it saves nothing (fwd
+    again under remat), and holds 4 bodies either way: the emitters are
+    jitted, so each is traced once and lowered to
     Mosaic once per program. All four index the projection's columns,
     `[B, N, H x D]`, and so does the rotary's pass, which takes `to_qkv`'s
-    result whole and hands q, k and v over (forward and under remat; joined
-    again backward: 36 calls of 3 bodies); between `to_qkv` and `to_out` no
+    result whole and hands q, k and v over (forward, and again under remat
+    where q, k and v are not kept; joined again backward: 24 or 36 calls of
+    3 bodies); between `to_qkv` and `to_out` no
     array is laid out `[B, H, N, D]`, none is transposed and the fused
     projection is never sliced.
     Lowering only; nothing is compiled."""
@@ -332,7 +341,8 @@ def test_flagship_step_holds_each_kernel_body_once(topo, compiled_kernels):
 
     pa.forget()
     text = _flagship_step(
-        [topo.devices[0]], dict(dp=1), batch=TRAIN_BATCH, executor="unrolled"
+        [topo.devices[0]], dict(dp=1), batch=TRAIN_BATCH, executor="unrolled",
+        remat_policy=policy,
     ).as_text()
     # the process built one body more than the step holds: the forward at
     # batch 1, traced (and never lowered) by `model.init`'s shape pass
@@ -340,12 +350,15 @@ def test_flagship_step_holds_each_kernel_body_once(topo, compiled_kernels):
     assert [key[0] for key in init] == ["fwd"]
     assert pa.kernel_bodies - len(init) == 4, pa.tiles_chosen
     assert pa.layouts_built == {pa.TOKEN_MAJOR: 4 + len(init)}
-    calls = {"_emit_fwd": (24, 2), "_emit_dq": (12, 1), "_emit_dkv": (12, 1),
-             "_emit_split": (24, 2), "_emit_join": (12, 1)}
+    calls = {"_emit_fwd": forward, "_emit_dq": (12, 1), "_emit_dkv": (12, 1),
+             "_emit_split": forward, "_emit_join": (12, 1)}
     for emitter, (sites, bodies) in calls.items():
         assert len(re.findall(rf"call @{emitter}\w*\(", text)) == sites, emitter
         assert len(set(re.findall(rf"func.func private @({emitter}\w*)\(", text))) == bodies
-    assert text.count("tpu_custom_call") == 4 + 3
+    # lowered bodies: dq, dkv and the join once; the forward kernel and the
+    # split twice each where the first pass drops what only remat's second
+    # pass reads (the log-sum-exp; q, k, v apart), once where it keeps them
+    assert text.count("tpu_custom_call") == 3 + 2 * forward[1]
     assert set(pa.tiles_chosen.values()) == {TOKEN_TILES}
     assert {key[1] for key in pa.tiles_chosen if key not in init} == {
         (TRAIN_BATCH, SEQ, H * D)
@@ -363,8 +376,9 @@ def test_flagship_step_holds_four_bodies_with_the_new_arguments_spelled_out(
         topo, compiled_kernels, monkeypatch):
     """What the shared kernels owe `flagship.train`: a call that names the
     language-model path's arguments at their defaults (`window=None`, K/V
-    heads as many as query heads) lowers to the same four kernel bodies and
-    the same tiles as one that leaves them out."""
+    heads as many as query heads) lowers to the same kernel bodies (four
+    traced, three lowered with the residuals kept) and the same tiles as one
+    that leaves them out."""
     from dalle_pytorch_tpu.models import attention
     from dalle_pytorch_tpu.ops import pallas_attention as pa
 
@@ -379,7 +393,7 @@ def test_flagship_step_holds_four_bodies_with_the_new_arguments_spelled_out(
     text = _flagship_step(
         [topo.devices[0]], dict(dp=1), batch=TRAIN_BATCH, executor="unrolled"
     ).as_text()
-    assert text.count("tpu_custom_call") == 4 + 3  # flash bodies + the rotary's
+    assert text.count("tpu_custom_call") == 3 + 2  # flash bodies + the rotary's
     assert set(pa.tiles_chosen.values()) == {TOKEN_TILES}
     assert len(text) < LOWERED_TEXT_BYTES, len(text)
 
@@ -477,8 +491,13 @@ def test_grouped_matmul_compiles(one_chip, compiled_kernels, monkeypatch, k, n):
 
 
 @pytest.mark.slow
-def test_flagship_step_kernels_keep_their_names_and_phases(topo, compiled_kernels):
-    """Compiled, the shared bodies are 48 custom calls again, each named
+@pytest.mark.parametrize("policy, second_forward", [
+    ("flash_residuals", {}), ("nothing_saveable", {("fwd_flash", "remat"): 12})],
+    ids=["flash_residuals", "nothing_saveable"])
+def test_flagship_step_kernels_keep_their_names_and_phases(
+        topo, compiled_kernels, policy, second_forward):
+    """Compiled, the shared bodies are 36 custom calls again (48 where remat
+    saves nothing and the forward kernel runs a second time), each named
     for its kernel (`fwd_flash.N`: the benchmark's regexes and
     `obs/scopes.py` find them by that) with the output signature its
     reader matches, and each under ITS call site's layer and phase: the
@@ -486,7 +505,8 @@ def test_flagship_step_kernels_keep_their_names_and_phases(topo, compiled_kernel
     from dalle_pytorch_tpu.obs import scopes
 
     text = _flagship_step(
-        [topo.devices[0]], dict(dp=1), batch=TRAIN_BATCH, executor="unrolled"
+        [topo.devices[0]], dict(dp=1), batch=TRAIN_BATCH, executor="unrolled",
+        remat_policy=policy,
     ).compile().as_text()
     table = scopes.classify(scopes.parse(text))
     by_phase = {}
@@ -495,7 +515,7 @@ def test_flagship_step_kernels_keep_their_names_and_phases(topo, compiled_kernel
             continue  # a tuple's `get-tuple-element` carries the scope too
         by_phase.setdefault((name.rsplit(".", 1)[0], phase), []).append(shape)
     assert {k: len(v) for k, v in by_phase.items()} == {
-        ("fwd_flash", "fwd"): 12, ("fwd_flash", "remat"): 12,
+        ("fwd_flash", "fwd"): 12, **second_forward,
         ("dq_flash", "bwd"): 12, ("dkv_flash", "bwd"): 12,
     }
     o = f"bf16[{TRAIN_BATCH},{SEQ},{H * D}]"  # a pair of heads a column block
@@ -517,26 +537,50 @@ def _device_bytes(compiled):
     )
 
 
-def test_flagship_train_step_fits_one_chip(topo, compiled_kernels):
-    """Batch 16 with remat on the scan executor: what chip_smoke.py's
-    trainer phase runs (there on the default unrolled executor, which the
-    compiler also fits, 70 s of compile instead of 20)."""
+# What the compiler plans for this file's batch-16 step with the trainer's
+# default `remat_policy="flash_residuals"` (PR 45; plans for a described v5e,
+# not chip runs): 8,163,508,736 bytes on the unrolled executor, which the
+# train cell and chip_smoke.py run (5,401,605,120 with `nothing_saveable`),
+# and 11,475,951,616 on the scan executor (7,990,795,776), whose stacked
+# residuals the compiler lays out with 0.7 GB more beside them. Each bound is
+# the reading and a margin for the compiler's own moves.
+FLAGSHIP_PLAN_BYTES = {"unrolled": 8_600_000_000, "scan": 11_900_000_000}
+
+
+@pytest.mark.parametrize("executor", ["unrolled", "scan"])
+def test_flagship_train_step_fits_one_chip(topo, compiled_kernels, capsys, executor):
+    """Batch 16 with remat, every layer keeping the flash kernels' residuals
+    (170 MB a layer): on the default unrolled executor what chip_smoke.py's
+    trainer phase and the train cell run, and on the scan executor. The plan
+    stays under its bound, the cell's executor's under 9e9."""
     compiled = _flagship_step(
-        [topo.devices[0]], dict(dp=1), batch=16
+        [topo.devices[0]], dict(dp=1), batch=16, executor=executor
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()  # the flash kernel is in
-    assert _device_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()
+    plan = _device_bytes(compiled)
+    with capsys.disabled():
+        print(f"\n[plan] flagship step, {executor}, flash_residuals: {plan:,} bytes")
+    assert FLAGSHIP_PLAN_BYTES["unrolled"] < 9e9
+    assert plan < FLAGSHIP_PLAN_BYTES[executor] < HBM_BYTES, compiled.memory_analysis()
 
 
 @pytest.mark.slow
-def test_flagship_train_step_without_remat_does_not_fit(topo, compiled_kernels):
-    """Why the smoke sets model.reversible=true at batch 16: the shipped
-    default (no remat) is refused by the compiler, a few MB over HBM."""
-    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
-        _flagship_step(
-            [topo.devices[0]], dict(dp=1), batch=16, executor="unrolled",
-            reversible=False,
-        ).compile()
+def test_flagship_train_step_plans_grow_with_what_remat_keeps(topo, compiled_kernels, capsys):
+    """The unrolled batch-16 step under the three settings, each plan
+    printed: a layer's input alone, the flash kernels' residuals beside it,
+    no remat at all. All three fit the chip (no remat has since before
+    PR 40; at PR 21 it was 21 MB over), in that order."""
+    plans = {}
+    for label, kw in (("nothing_saveable", dict(remat_policy="nothing_saveable")),
+                      ("flash_residuals", dict(remat_policy="flash_residuals")),
+                      ("no remat", dict(reversible=False))):
+        plans[label] = _device_bytes(_flagship_step(
+            [topo.devices[0]], dict(dp=1), batch=16, executor="unrolled", **kw,
+        ).compile())
+        with capsys.disabled():
+            print(f"\n[plan] flagship step, unrolled, {label}: {plans[label]:,} bytes")
+    assert (plans["nothing_saveable"] < plans["flash_residuals"]
+            < FLAGSHIP_PLAN_BYTES["unrolled"] < plans["no remat"] < HBM_BYTES), plans
 
 
 @pytest.mark.slow
