@@ -10,14 +10,14 @@ program's chunked prefill (`prefill_chunks`: `job.prefill_chunk` tokens a
 dispatch, each chunk against what its cache holds by then and itself, every
 query with its own selection), then makes ONE cache of `job.sessions` rows,
 whose length is document + turn rounded up to `job.cache_block`, and copies
-each document's three leaves to the sessions that hold it (`place_rows`:
-there are no pages to share, so every session owns its copy, which is what
-fills the memory). Session r holds document r mod the number of documents, so
-the first rows, whose logits the sampler keeps, hold DIFFERENT documents; and
-set-up compares every session's leaves with its document's prefill on the
-device, bit for bit (`copies_off`). Documents and weights are made from
-`job.documents_seed` and `job.weights_seed` in EVERY run: one routing and one
-selection pattern, as a deployment has one checkpoint.
+each document's two leaves a layer (`rows`, `index_k`) to the sessions that
+hold it (`place_rows`: there are no pages to share, so every session owns its
+copy, which is what fills the memory). Session r holds document r mod the
+number of documents, so the first rows, whose logits the sampler keeps, hold
+DIFFERENT documents; and set-up compares every session's leaves with its
+document's prefill on the device, bit for bit (`copies_off`). Documents and
+weights are made from `job.documents_seed` and `job.weights_seed` in EVERY
+run: one routing and one selection pattern, as a deployment has one checkpoint.
 
 A timed batch is one further turn of all sessions in ONE dispatch
 (`generate_tokens_cached`): the cache's index set back to the documents'

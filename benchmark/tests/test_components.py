@@ -16,6 +16,7 @@ from pathlib import Path
 
 import pytest
 
+from benchmark.tests.test_declarations import cell_metrics
 from benchmark.trace import costs, reduce
 from dalle_pytorch_tpu.obs import scopes
 
@@ -36,10 +37,7 @@ def table(cell: str) -> dict:
 
 
 def metric_files(cell: str, reader: str):
-    for path in sorted((BENCH / "metrics").glob("*.json")):
-        spec = json.loads(path.read_text())
-        if spec.get("reader") == reader and cell in spec.get("workloads", []):
-            yield path.stem, spec
+    return cell_metrics(cell, reader).items()
 
 
 def read(spec: dict, ctx: dict):
@@ -101,7 +99,12 @@ def test_the_generate_cells_copies_have_no_owner():
     scopes.keep_table("sample_cached", table(cell))
     ctx = context(cell)
     unscoped = read(json.loads((BENCH / "metrics" / "unscoped_pct.gen.json").read_text()), ctx)
-    copies = read(json.loads((BENCH / "metrics" / "cache_copy_pct.gen.json").read_text()), ctx)
+    # the recording is PR 24's, when a token step still copied the cache; the
+    # metric that found those copies by shape read nothing from PR 28 on and
+    # is retired (PR 46), so the test finds them itself
+    whole_cache = {"reader": "kernel_share",
+                   "params": {"kernels": [r"^%copy\.\d+ = bf16\[64,\d+,16,1281,64\]"]}}
+    copies = read(whole_cache, ctx)
     assert unscoped >= copies > 20
 
 

@@ -26,7 +26,7 @@ from benchmark.tests.test_harness import RESULT_KEYS, last_line, run_cell
 from benchmark.trace import costs_deepseek_v32
 
 CELL = "_tiny.generate_deepseek_v32"
-COUNTERS = {"experts_touched.deepseek32", "expert_load_max_over_mean.deepseek32",
+COUNTERS = {"experts_touched.lm", "expert_load_max_over_mean.lm",
             "selected_per_row_step.deepseek32"}
 
 
@@ -57,7 +57,7 @@ def test_traced_run_reports_the_new_counters():
     assert COUNTERS | {"compiles_in_window"} <= set(line["metrics"])
     assert line["metrics"]["compiles_in_window"]["value"] == 0
     assert line["metrics"]["selected_per_row_step.deepseek32"]["value"] == 8
-    assert 0 < line["metrics"]["experts_touched.deepseek32"]["value"] <= 8
+    assert 0 < line["metrics"]["experts_touched.lm"]["value"] <= 8
     # no other cell's metric leaks in, and the device metrics of this one
     # need a device trace by HLO name, which the CPU has not: left out
     assert not [m for m in line["metrics"] if m.endswith((".pangu", ".kexaone", ".olmo"))]
@@ -182,8 +182,8 @@ def test_set_up_counts_the_copies_that_are_not_their_documents_prefill():
         cache = place_rows(prog.mdl, cache, fresh, row)
     off = lambda *rows: int(loop._copies_off()(cache, fresh, jnp.asarray(rows, jnp.int32)))
     assert off(1, 3) == 0
-    leaves = sum(x.ndim > 1 for x in jax.tree.leaves(fresh))  # three a layer
-    assert leaves == 3 * prog.d["depth"] and off(0, 1) == leaves  # row 0 was never written
+    leaves = sum(x.ndim > 1 for x in jax.tree.leaves(fresh))  # two a layer: `rows`, `index_k`
+    assert leaves == 2 * prog.d["depth"] and off(0, 1) == leaves  # row 0 was never written
     first = next(iter(cache))
     cache[first]["attn"]["index_k"] = cache[first]["attn"]["index_k"].at[3, prog.doc - 1, 0].add(1)
     assert off(1, 3) == 1

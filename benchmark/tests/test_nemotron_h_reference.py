@@ -230,17 +230,23 @@ def test_the_cost_functions_count_what_their_docstrings_say():
 
 
 def test_every_nemotron_metric_is_declared_and_lists_the_cell():
-    with open(harness.ROOT.parent / "BENCHMARK.json") as f:
-        bench = json.load(f)
-    declared = {m["name"]: m for m in bench["per_layer"]}
-    files = {p.stem: json.loads(p.read_text())
-             for p in (harness.ROOT / "metrics").glob("*.nemotron3.json")}
-    assert len(files) == 10 and set(files) <= set(declared) and len(declared) == 128
-    for name, spec in files.items():
-        assert spec["workloads"] == declared[name]["workloads"] == [REAL]
+    from benchmark.tests.test_declarations import DECLARED as bench, PER_LAYER as declared, cell_metrics
+
+    # found by the cell in a file's `workloads`, not by a suffix (PR 46): PR 43's
+    # ten, the thirteen the cap kept out, `compiles_in_window`
+    files = cell_metrics(REAL)
+    assert len(files) == 24 and set(files) <= set(declared)
+    assert sorted(n for n in files if n.endswith(".nemotron3")) == [
+        "global_attend_roofline.nemotron3", "gmm_roofline.nemotron3", "mfu_token_step.nemotron3",
+        "ssm_proj_pct.nemotron3", "ssm_step_pct.nemotron3", "ssm_step_roofline.nemotron3"]
     shares = [s["params"]["components"] for s in files.values() if s["reader"] == "component_share"]
     named = [c for group in shares for c in group]
-    assert len(named) == len(set(named))  # no component counted twice
+    assert len(shares) == 11 and len(named) == len(set(named))  # the eleven shares add up
+    # every component the cell's `[scopes]` line showed above 0 is in one of them (PR 43, call 2)
+    assert set(named) >= {
+        "attn_glue", "attn_proj", "cache_write", "embed", "global_attend", "head", "moe_dispatch",
+        "moe_experts", "moe_router", "moe_shared", "norm_resid", "sample", "ssm_proj", "ssm_step",
+        "unscoped"}
     rates = next(m for m in bench["end_to_end"] if m["name"] == "generate_tokens_per_s")
     assert rates["workloads"][-1] == REAL
     assert declared["compiles_in_window"]["workloads"][-1] == REAL  # every cell reports it

@@ -52,10 +52,10 @@ def test_traced_run_reports_the_new_counter():
     p = run_cell(CELL, trace=1)
     line = last_line(p)
     assert set(line) == RESULT_KEYS | {"breakdown"}
-    assert {"state_restored_bytes.olmo", "compiles_in_window"} <= set(line["metrics"])
+    assert {"state_restored_bytes.lm", "compiles_in_window"} <= set(line["metrics"])
     assert line["metrics"]["compiles_in_window"]["value"] == 0
     # 4 rows x 6 linear layers x (4 heads x 8 x 24 + a ring of 3 x 160) float32
-    assert line["metrics"]["state_restored_bytes.olmo"]["value"] == 4 * 6 * (768 + 480) * 4
+    assert line["metrics"]["state_restored_bytes.lm"]["value"] == 4 * 6 * (768 + 480) * 4
     # no other cell's metric leaks in, and the device metrics of this one
     # need a device trace by HLO name, which the CPU has not: left out
     assert not [m for m in line["metrics"] if m.endswith((".train", ".gen", ".mellum", ".pangu"))]
@@ -239,14 +239,13 @@ def test_the_cost_functions_count_what_their_docstrings_say():
 
 
 def test_every_olmo_metric_is_declared_and_lists_the_cell():
-    with open(harness.ROOT.parent / "BENCHMARK.json") as f:
-        bench = json.load(f)
-    declared = {m["name"]: m for m in bench["per_layer"]}
-    files = {p.stem: json.loads(p.read_text())
-             for p in (harness.ROOT / "metrics").glob("*.olmo.json")}
-    assert len(files) == 14 and set(files) <= set(declared)
-    for name, spec in files.items():
-        assert spec["workloads"] == declared[name]["workloads"] == ["olmohybrid.decode.512"]
+    from benchmark.tests.test_declarations import PER_LAYER, cell_metrics
+
+    # found by the cell in a file's `workloads`, not by a suffix (PR 46): PR 33's
+    # fourteen, the compile ledger's three, `compiles_in_window`
+    files = cell_metrics("olmohybrid.decode.512")
+    assert len(files) == 18 and set(files) <= set(PER_LAYER)
+    assert sum(n.endswith(".olmo") for n in files) == 6
     shares = [s["params"]["components"] for s in files.values() if s["reader"] == "component_share"]
     named = [c for group in shares for c in group]
-    assert len(named) == len(set(named))  # no component counted twice: the shares add up
+    assert len(shares) == 9 and len(named) == len(set(named))  # the nine shares add up

@@ -26,7 +26,7 @@ from benchmark.tests.test_harness import RESULT_KEYS, last_line, run_cell
 from benchmark.trace import costs_pangu
 
 CELL = "_tiny.generate_lm"
-COUNTERS = {"experts_touched.pangu", "expert_load_max_over_mean.pangu"}
+COUNTERS = {"experts_touched.lm", "expert_load_max_over_mean.lm"}
 
 
 def a_run(seconds=0.5, trace=False, seed=4):
@@ -56,8 +56,8 @@ def test_traced_run_reports_the_new_counters():
     assert set(line) == RESULT_KEYS | {"breakdown"}
     assert COUNTERS | {"compiles_in_window"} <= set(line["metrics"])
     assert line["metrics"]["compiles_in_window"]["value"] == 0
-    assert 0 < line["metrics"]["experts_touched.pangu"]["value"] <= 4
-    assert line["metrics"]["expert_load_max_over_mean.pangu"]["value"] >= 1.0
+    assert 0 < line["metrics"]["experts_touched.lm"]["value"] <= 4
+    assert line["metrics"]["expert_load_max_over_mean.lm"]["value"] >= 1.0
     # no other cell's metric leaks in, and the device metrics of this one
     # need a device trace by HLO name, which the CPU has not: left out
     assert not [m for m in line["metrics"] if m.endswith((".train", ".gen", ".mellum"))]
