@@ -115,8 +115,9 @@ DECODE_SPARSE_BLOCK = 128
 #   ROWS    the grouped projection over per-row K/V or a window's ring
 #           (`_cached_grouped`): K/V heads shared, a window, or a rotate-half rotary
 #   LATENT, LINEAR, SSM   `LatentAttention`, `GatedDeltaAttention`, `Mamba2Mixer`
+#   CCA     `ConvLatentAttention`: grouped K/V per row as on ROWS, a tail beside them
 DALLE, LANES, ROWS, LATENT, LINEAR = "dalle", "grouped_lanes", "grouped_rows", "latent", "linear"
-SSM = "ssm"
+SSM, CCA = "ssm", "cca"
 ATTN_IMPLS = ("auto", "dense", "flash", "ring")
 
 
@@ -1370,3 +1371,165 @@ class Mamba2Mixer(nn.Module):
             y = (y.reshape(bsz, n, inner) * gain).astype(self.dtype)
             return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
                             param_dtype=self.param_dtype, name="to_out")(y), new_cache
+
+
+def cca_tail_dim(heads: int, kv_heads: int, dim_head: int) -> int:
+    """Columns of a convolved layer's `tail` in the cache: the last position's
+    c and c' (`ConvLatentAttention`), and the shifted half of its v'."""
+    return (2 * (heads + kv_heads) + kv_heads // 2) * dim_head
+
+
+def _a_position_before(t, last):
+    """t [B, n, ...] a position later: row i holds t[:, i - 1], row 0 `last`
+    [B, ...] (the position before the chunk's first: zeros, or a cache's tail)."""
+    return jnp.concatenate([last.reshape(t[:, :1].shape).astype(t.dtype), t[:, :-1]], axis=1)
+
+
+def _shifted_values(vf, last, now: int):
+    """A convolved layer's values from its value projection vf [B, n, K d]: the
+    first `now` columns (K / 2 heads) this position's, the rest the position
+    before's."""
+    return jnp.concatenate([vf[..., :now], _a_position_before(vf[..., now:], last)], axis=-1)
+
+
+def _qk_mean(qh, kh):
+    """(m_q [B, n, H, d], m_k [B, n, K, d]) of the latents before the
+    convolutions, qh [B, n, H, d] and kh [B, n, K, d]: m_q[h] the mean of q~[h]
+    and its group's k~, m_k[j] the mean of its group's m_q."""
+    b, n, h, dh = qh.shape
+    per = h // kh.shape[2]
+    m_q = (qh + jnp.repeat(kh, per, axis=2)) / 2
+    return m_q, m_q.reshape(b, n, h // per, per, dh).mean(3)
+
+
+def _taps_init(key, shape, dtype=jnp.float32):
+    """A two-tap depthwise convolution that starts as the identity."""
+    return jnp.zeros(shape, dtype).at[1].set(1)
+
+
+class ConvLatentAttention(nn.Module):
+    """Causal attention in a COMPRESSED latent whose q and k pass two causal
+    convolutions over the sequence (compressed convolutional attention,
+    arXiv:2510.04476, as `model_type: zaya` builds it): `heads` query heads over
+    `kv_heads` K/V heads of `dim_head`, all narrower than the stream (heads x
+    dim_head < dim), so the cache holds 2 x kv_heads x dim_head numbers a
+    position. With u the normed input and anything at t - 1 < 0 zero:
+
+        q~ | k~ | v' = u W_qkv            [H d | K d | K d], no bias
+        v_t = [v'_t, the first K / 2 heads ; v'_(t-1), the last K / 2]
+        c = [q~ ; k~]                      (H + K) d channels, H + K heads
+        c'_t = b0 + w0[1] c_t + w0[0] c_(t-1)                       depthwise
+        c''_t[g] = b1[g] + c'_t[g] W1[1, g] + c'_(t-1)[g] W1[0, g]  a head a group
+        m_q[h] = (q~[h] + k~[h // P]) / 2;  m_k[j] = mean of its group's m_q
+        q[h] = unit(c''[h] + m_q[h]);  k[j] = tau[j] unit(c''[H + j] + m_k[j])
+                                           unit(t) = t / sqrt(mean(t^2) + eps)
+        rotate-half over the tables' columns (a partial rotary: the first of
+        each head), causal softmax at 1 / sqrt(d) over grouped heads, W_out
+
+    everything from `v_t` to `k[j]` under the scope `cca_mix`, in float32 on
+    c and c' rounded to the compute dtype (what the cache's tail holds of them).
+
+    Uncached, and for a chunk that STARTS its rows' sequences (`start`: a
+    prefill, written from position 0), the chunk attends itself: the flash
+    kernel from `AUTO_FLASH_MIN_SEQ` tokens under `attn_impl="auto"`, dense
+    below. Any other cached chunk goes on from each row's own `index`: the
+    position before its first is the cache's `tail` (models/decode_cache.py,
+    kind `cca`: c, c' and the shifted half of v' of the row's last position),
+    its K/V are written along the row's lanes, and it attends the row's live
+    positions: a one-token step through the kernel `decode_grouped`
+    (ops/grouped_decode.py), a longer chunk (a prefill in chunks) through
+    XLA's product over the whole leaf, both under `global_attend`. Either way
+    the chunk's last position is the new tail.
+
+    Parameters: `to_qkv`, `to_out`, `conv0` [2, C] and `conv1` [2, H + K, d, d]
+    in `param_dtype`; `conv0_bias`, `conv1_bias` [C] and `tau` [K] float32.
+    """
+
+    dim: int
+    seq_len: int
+    heads: int
+    kv_heads: int
+    dim_head: int
+    norm_eps: float = 1e-5
+    attn_impl: str = "auto"
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, key_mask=None, rotary=None, cache=None, deterministic=True,
+                 rotary_cs=None, start=False):
+        assert key_mask is None and rotary is None, "a convolved layer is causal and unpadded"
+        _known_impl(self.attn_impl)
+        b, n, _ = x.shape
+        h, hkv, dh = self.heads, self.kv_heads, self.dim_head
+        assert h % hkv == 0 and hkv % 2 == 0, f"{h} query heads over {hkv} K/V heads in two halves"
+        per, groups, width, now = h // hkv, h + hkv, (h + hkv) * dh, (hkv - hkv // 2) * dh
+        f32 = jnp.float32
+        qkv = nn.Dense(width + hkv * dh, use_bias=False, dtype=self.dtype,
+                       param_dtype=self.param_dtype, name="to_qkv")(x)
+        conv0 = self.param("conv0", _taps_init, (2, width), self.param_dtype).astype(f32)
+        conv0_bias = self.param("conv0_bias", nn.initializers.zeros, (width,))
+        conv1 = self.param("conv1", nn.initializers.lecun_normal(), (2, groups, dh, dh),
+                           self.param_dtype).astype(f32)
+        conv1_bias = self.param("conv1_bias", nn.initializers.zeros, (width,))
+        tau = self.param("tau", nn.initializers.ones, (hkv,))
+        resumed = cache is not None and not start
+        index = None if cache is None else cache[decode_cache.INDEX]
+        with jax.named_scope("cca_mix"):
+            c, vf = qkv[..., :width], qkv[..., width:]
+            if resumed:  # the position before the chunk's first: the row's tail
+                last = jnp.split(cache[decode_cache.TAIL], [width, 2 * width], axis=-1)
+            else:
+                last = [jnp.zeros((b, w), self.dtype) for w in (width, width, hkv * dh - now)]
+            v = _shifted_values(vf, last[2], now)
+            c32 = c.astype(f32)
+            c1 = (conv0_bias + conv0[1] * c32
+                  + conv0[0] * _a_position_before(c32, last[0])).astype(self.dtype)
+            # float32 operands that hold compute-dtype numbers: the chip's one
+            # pass multiplies them exactly and sums in float32 (the CPU has no
+            # bf16 x bf16 = f32 product over a batch of groups)
+            c1g = c1.reshape(b, n, groups, dh).astype(f32)
+            mix = lambda t, w: jnp.einsum("bngi,gio->bngo", t, w)
+            c2 = (conv1_bias.reshape(groups, dh) + mix(c1g, conv1[1])
+                  + mix(_a_position_before(c1g, last[1]), conv1[0]))
+            m_q, m_k = _qk_mean(c32[..., :h * dh].reshape(b, n, h, dh),
+                                c32[..., h * dh:].reshape(b, n, hkv, dh))
+            unit = lambda t: t * lax.rsqrt(jnp.mean(t * t, -1, keepdims=True) + self.norm_eps)
+            q = unit(c2[:, :, :h] + m_q).astype(self.dtype)
+            k = (unit(c2[:, :, h:] + m_k) * tau[:, None]).astype(self.dtype)
+            tail = jnp.concatenate([c[:, -1], c1[:, -1], vf[:, -1, now:]], axis=-1)
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v.reshape(b, n, hkv, dh)))
+        if rotary_cs is not None:
+            with jax.named_scope("rotary"):
+                if resumed:  # [B, 1, n, rot], over the heads: each row's own positions
+                    at = index[:, None] + jnp.arange(n, dtype=index.dtype)
+                    cos, sin = (jnp.take(t, at, axis=0, mode="clip")[:, None] for t in rotary_cs)
+                else:
+                    cos, sin = (t[:n] for t in rotary_cs)
+                q, k = apply_rotary_half(cos, sin, q), apply_rotary_half(cos, sin, k)
+
+        new_cache = None
+        if cache is not None:
+            written = decode_cache.write_rows(cache, {"k": k, "v": v}, start)
+            new_cache = {**cache, **written, decode_cache.TAIL: tail.astype(
+                cache[decode_cache.TAIL].dtype),
+                decode_cache.INDEX: jnp.full_like(index, n) if start else index + n}
+        if resumed:
+            ck, cv = written["k"], written["v"]
+            q = q.reshape(b, hkv, per * n, dh)  # a K/V head's group, position fastest
+            with jax.named_scope("global_attend"):
+                if n == 1:
+                    out = grouped_decode_attention(q, ck, cv, index + n, n=n)
+                else:
+                    at = jnp.tile(index[:, None] + jnp.arange(n, dtype=index.dtype), (1, per))
+                    live = jnp.arange(ck.shape[2], dtype=index.dtype) <= at[:, None, :, None]
+                    out = dense_attention(q, ck, cv, mask=live)
+            out = out.reshape(b, h, n, dh)
+        elif self.attn_impl == "flash" or (self.attn_impl == "auto" and n >= AUTO_FLASH_MIN_SEQ):
+            out = flash_attention(q, k, v, causal=True)
+        else:
+            k, v = (jnp.repeat(t, per, axis=1) for t in (k, v))
+            out = dense_attention(q, k, v, mask=jnp.tril(jnp.ones((n, n), bool))[None, None])
+        out = out.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
+        return nn.Dense(self.dim, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="to_out")(out), new_cache

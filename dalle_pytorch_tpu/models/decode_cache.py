@@ -86,6 +86,18 @@ whose `heads` is the K/V head count) share a cache with it; the stacked layout
 does not hold it. Beside the ring lie `k_at`, `v_at`, its SNAPSHOT: the ring
 as it stood where the session's document ended.
 
+A CONVOLVED layer (`layer_spec(kind="cca")`: attention in a compressed latent
+whose q and k pass two causal convolutions of two taps and whose values are
+half the position before's, models/attention.py:ConvLatentAttention) holds full
+`k`, `v` [B, kv_heads, L, dh] at a per-row `index` like any grouped layer, and
+beside them what its next position needs of the last one:
+
+  * `tail` [B, tail_dim], in the cache's dtype: the last position's q and k
+    latents before the convolutions, the same between the two, and the half of
+    its value projection that the next position's values take;
+  * `tail_at`: its SNAPSHOT, the tail at the position `set_index` goes back to
+    (a turn's first step convolves with the document's last position).
+
 A position that a verify step wrote and then REJECTED leaves every kind of
 K/V layer with no snapshot and no copy. A full layer: by its row's `index`
 alone, because what lies at or past it is masked and overwritten. A ring:
@@ -153,6 +165,8 @@ STATE, CONV = "state", "conv"
 # a recurrent layer's running leaves and, beside each, its snapshot
 SNAPSHOT = {STATE: "state_at", CONV: "conv_at"}
 RING_SNAPSHOT = {K: "k_at", V: "v_at"}  # a window layer's ring, kept likewise
+TAIL = "tail"  # a convolved layer's last position, and its snapshot
+TAIL_SNAPSHOT = {TAIL: "tail_at"}
 KV_KEYS = (K, V) + SCALE_KEYS
 RING_KEYS = ("shift_attn", "shift_ff")
 PAGE_TABLE, BLOCK_BITMAP, RING_END = "page_table", "block_bitmap", "ring_end"
@@ -207,6 +221,7 @@ def layer_spec(
     conv_dim: Optional[int] = None,
     ring: Optional[int] = None,
     hidden: bool = False,
+    tail_dim: Optional[int] = None,
 ) -> dict:
     """ONE layer's leaves as `jax.ShapeDtypeStruct`s, from the geometry.
 
@@ -220,7 +235,8 @@ def layer_spec(
     a state-space mixer's), both float32, their snapshot beside them, and an
     `index` that is scalar or, with `per_row`, [batch]. `kind="window"`: K/V [batch, heads,
     ring, dim_head] read as a ring (`ring_positions`), their snapshot beside
-    them, `index` per row.
+    them, `index` per row. `kind="cca"`: full K/V lanes, `tail` [batch,
+    tail_dim] in the cache dtype and its snapshot, `index` per row.
     `hidden`: beside `attn`, a leaf `hidden` [batch, dim] (a drafting
     block's layer keeps the trunk's last hidden state of each row's prompt
     there: what its next position is made from). Otherwise:
@@ -257,6 +273,9 @@ def layer_spec(
         assert pages is None and kv_dtype is None and per_row and not shift_tokens, (
             "a ring is lanes in the cache dtype, every row at its own position")
         max_len = ring
+    elif kind == "cca":
+        assert pages is None and kv_dtype is None and per_row and not shift_tokens, (
+            "a convolved layer is lanes in the cache dtype, every row at its own position")
     else:
         assert kind == "heads", f"unknown cache kind {kind!r}"
     rows, length = (batch, max_len) if pages is None else pages
@@ -271,6 +290,8 @@ def layer_spec(
         attn[V_SCALE] = spec((rows, heads, length), jnp.float32)
     if kind == "window":
         attn.update({at: attn[n] for n, at in RING_SNAPSHOT.items()})
+    if kind == "cca":
+        attn[TAIL] = attn[TAIL_SNAPSHOT[TAIL]] = spec((batch, tail_dim), dtype)
     layer = {ATTN: attn}
     if hidden:
         layer[HIDDEN] = spec((batch, dim), dtype)
@@ -364,7 +385,7 @@ def set_index(cache: dict, pos: jnp.ndarray) -> dict:
 
 def _kept_pairs(attn: dict) -> dict:
     """{running leaf: its snapshot leaf} of a layer that keeps one."""
-    pairs = SNAPSHOT if STATE in attn else RING_SNAPSHOT
+    pairs = SNAPSHOT if STATE in attn else TAIL_SNAPSHOT if TAIL in attn else RING_SNAPSHOT
     return pairs if all(at in attn for at in pairs.values()) else {}
 
 
@@ -526,8 +547,9 @@ def running_state(cache: dict, layer: int, heads: int, rows: Optional[int] = Non
 
 
 def state_bytes(cache: dict) -> int:
-    """Bytes of the recurrent layers' leaves, running and kept."""
-    names = tuple(SNAPSHOT) + tuple(SNAPSHOT.values())
+    """Bytes of the recurrent layers' leaves and the convolved layers' tails,
+    running and kept."""
+    names = (*SNAPSHOT, *SNAPSHOT.values(), *TAIL_SNAPSHOT, *TAIL_SNAPSHOT.values())
     return sum(
         leaf.size * leaf.dtype.itemsize
         for path, leaf in jax.tree_util.tree_leaves_with_path(cache)

@@ -1,7 +1,8 @@
 """A causal language model over the same trunk as DALLE.
 
 Token embedding, `Transformer` (models/transformer.py, whose block options
-the configuration sets), a final norm and an untied head; the loss is the
+the configuration sets), a final norm and a head, its own matrix or (`tied_head`)
+the embedding; the loss is the
 mean next-token cross-entropy over positions 0..N-2, computed by the
 vocab-chunked loss of `ops/losses.py` so that the [B, N, V] logits are
 never whole.
@@ -10,8 +11,10 @@ Generation, for a trunk of latent attention layers (with or without a
 lightning indexer that selects the cached positions a query attends), of
 linear (gated delta rule) and full ones, of window and full ones over
 grouped K/V heads, or of layers that are ONE sublayer each (a Mamba-2 mixer,
-an attention over grouped K/V heads or ungated routed experts: `_ssm_trunk`):
-`prefill` writes a batch of prompts into a decode cache
+an attention over grouped K/V heads or ungated routed experts: `_ssm_trunk`),
+or of attention in a compressed latent whose q and k pass two causal
+convolutions, beside top-1 experts behind a router that carries a state down
+the depth (`_cca_trunk`): `prefill` writes a batch of prompts into a decode cache
 (models/decode_cache.py, per-layer layout: the latent kind of layer, or
 recurrent and K/V layers in one tree, or window rings beside full K/V),
 `decode_step` takes one token a row against it, and `generate_tokens_cached`
@@ -24,7 +27,8 @@ chunk=)` puts a long prompt in that way, a chunk a dispatch.
 
 A trunk with a layer of grouped K/V heads (the window-and-full one, and the
 one of single sublayers, whose Mamba-2 state and ring then stand in the same
-per-row cache: rows whose documents differ in length share a step) keeps every
+per-row cache: rows whose documents differ in length share a step; and the
+convolved one, whose layers keep their last position's tail there) keeps every
 row at its OWN position; the window-and-full trunk may carry a
 multi-token module (`draft_layers`; `CausalLM.draft_step`): one more block
 after the trunk that, from the trunk's last hidden state at position i and
@@ -71,6 +75,19 @@ FORWARD_ONLY = (
     "attention or ungated experts alone) is forward only: there is no backward for the chunked "
     "state-space scan nor for the ungated experts' grouped products; generate with it "
     "(generate_lm.py --config ...)")
+# and of the family `_cca_trunk` builds
+FORWARD_ONLY_CCA = (
+    "the zaya family (cca_time0: attention in a compressed latent behind two causal "
+    "convolutions, a router that carries its state from layer to layer, a scaled residual) is "
+    "forward only: no gradient of it has been held to the reference; generate with it "
+    "(generate_lm.py --config ...)")
+
+
+def forward_only(plan) -> Optional[str]:
+    """Why a model of this plan (`CausalLM.plan`) does not train, or None."""
+    if any(layer.kind == "ssm" or layer.ff_kind == "relu2_experts" for layer in plan):
+        return FORWARD_ONLY
+    return FORWARD_ONLY_CCA if any(layer.kind == "cca" for layer in plan) else None
 
 
 def rotary_spec(spec: dict, dim: int) -> dict:
@@ -246,6 +263,33 @@ def _ssm_trunk(cfg: dict, depth: int, held: int) -> dict:
     )
 
 
+def _cca_trunk(cfg: dict, depth: int, held: int) -> dict:
+    """The trunk options of the family whose config has `cca_time0`
+    (`model_type: zaya`): every layer (`layer_types` all `hybrid`) an attention
+    sublayer in a compressed latent, `num_attention_heads` query heads over
+    `num_key_value_heads` K/V heads of `head_dim` whose q and k pass two causal
+    convolutions of `cca_time0` and `cca_time1` = 2 taps, a rotate-half rotary
+    over `partial_rotary_factor` of each head (`rope_parameters.hybrid`); then
+    `num_experts` SwiGLU experts, ONE a token, no shared one, chosen by softmax
+    score plus a balancing bias behind a router that is an MLP of
+    `router_hidden_size` carrying its state down the depth, the chosen score the
+    gate as it is (with one choice a renormalised one would be 1); every
+    sublayer's result meets the stream scaled and shifted."""
+    kinds = set(cfg["layer_types"][:depth])
+    if (kinds != {"hybrid"} or (cfg["cca_time0"], cfg["cca_time1"]) != (2, 2)
+            or cfg.get("sliding_window") or int(cfg["num_experts_per_tok"]) != 1):
+        raise ValueError("this family builds `hybrid` layers alone (no sliding window), two "
+                         f"convolutions of 2 taps each and ONE expert a token ({sorted(kinds)})")
+    rot_dim = int(cfg["head_dim"] * float(cfg["partial_rotary_factor"]))
+    return dict(
+        attn_types=("cca",), kv_heads=cfg["num_key_value_heads"],
+        rotary_specs={"cca": rotary_spec(cfg["rope_parameters"]["hybrid"], rot_dim)},
+        ff_kind="swiglu_experts", moe_score="softmax", moe_score_bias=True,
+        moe_renormalise=False, router_dim=int(cfg["router_hidden_size"]), residual="affine",
+        experts_total=cfg.get("published", {}).get("num_experts", held),
+    )
+
+
 class CausalLM(nn.Module):
     num_tokens: int  # rows of the embedding and of the head held here
     dim: int
@@ -259,6 +303,7 @@ class CausalLM(nn.Module):
     # blocks of the multi-token module after the trunk (0: none; 1: one full-
     # attention routed block that drafts one token a row a step)
     draft_layers: int = 0
+    tied_head: bool = False  # the head is the embedding (no matrix of its own)
     reversible: bool = False  # per-layer remat, as `DALLE.reversible`
     reversible_impl: str = "remat"
     remat_policy: Optional[str] = None
@@ -273,8 +318,11 @@ class CausalLM(nn.Module):
         """The model that a published `config.json` describes, as this
         process's share of it. `cfg` holds the published keys at its top
         level: the first `num_hidden_layers` layers, ids below `vocab_size`,
-        and the experts held. Four families of keys are read. With
-        `hybrid_override_pattern` (`_ssm_trunk`): `mamba_*`, `ssm_state_size`,
+        and the experts held. Five families of keys are read. With `cca_time0`
+        (`_cca_trunk`): `cca_time1`, `partial_rotary_factor`, `router_hidden_size`,
+        `num_experts` ... (attention in a compressed latent behind two causal
+        convolutions, top-1 experts behind an MLP router with a carried state,
+        a scaled residual). With `hybrid_override_pattern` (`_ssm_trunk`): `mamba_*`, `ssm_state_size`,
         `n_groups`, `conv_kernel`, `chunk_size`, `mlp_hidden_act`,
         `layer_norm_epsilon`, `moe_shared_expert_intermediate_size` ... (layers
         that are a Mamba-2 mixer, an attention or ungated routed experts
@@ -302,9 +350,11 @@ class CausalLM(nn.Module):
         prog = dict(cfg.get("program", {}), **overrides)
         depth = int(cfg["num_hidden_layers"])
         ssm = "hybrid_override_pattern" in cfg  # its activations are `_ssm_trunk`'s to check
+        cca = "cca_time0" in cfg  # the one family whose head is the embedding
         if ((not ssm and cfg["hidden_act"] != "silu") or cfg.get("attention_bias", False)
-                or cfg["tie_word_embeddings"]):
-            raise ValueError("the trunk builds SiLU gates, no biases and an untied head")
+                or cfg.get("lm_head_bias", False) or (cfg["tie_word_embeddings"] and not cca)):
+            raise ValueError("the trunk builds SiLU gates, no biases and an untied head "
+                             "(a tied one with `cca_time0` alone)")
         latent, hybrid = "kv_lora_rank" in cfg, "linear_key_head_dim" in cfg
         param_dtype = DTYPES[prog.get("weights_dtype", "float32")]
         trunk = dict(
@@ -323,6 +373,9 @@ class CausalLM(nn.Module):
         if ssm:
             trunk.update(_ssm_trunk(cfg, depth, held), param_dtype=param_dtype)
             dim_head = cfg["head_dim"]
+        elif cca:
+            trunk.update(_cca_trunk(cfg, depth, held), param_dtype=param_dtype)
+            dim_head = cfg["head_dim"]
         elif hybrid:
             trunk.update(_hybrid_trunk(cfg, depth), param_dtype=param_dtype)
             dim_head = cfg["hidden_size"] // cfg["num_attention_heads"]
@@ -336,13 +389,14 @@ class CausalLM(nn.Module):
             trunk.update(_window_trunk(cfg, depth, held), param_dtype=param_dtype,
                          draft_positions=drafts)
             dim_head = cfg["head_dim"]
-        if (latent or hybrid or ssm) and cfg.get("num_nextn_predict_layers", 0):
+        if (latent or hybrid or ssm or cca) and cfg.get("num_nextn_predict_layers", 0):
             raise ValueError("a multi-token module is built after the window-and-full trunk")
         return cls(
             num_tokens=cfg["vocab_size"], dim=cfg["hidden_size"], depth=depth,
             seq_len=seq_len, heads=cfg["num_attention_heads"], dim_head=dim_head,
             # frozen: the model is hashable, which keys its compiled samplers
             trunk=freeze(trunk), draft_layers=trunk.get("draft_positions", 0),
+            tied_head=bool(cfg["tie_word_embeddings"]),
             reversible=bool(prog.get("reversible", False)),
             reversible_impl=prog.get("reversible_impl", "remat"),
             dtype=DTYPES[prog.get("dtype", "bfloat16")], param_dtype=param_dtype,
@@ -362,8 +416,9 @@ class CausalLM(nn.Module):
             nn.RMSNorm(epsilon=trunk.get("norm_eps", 1e-6), dtype=self.dtype)
             if norm == "rms" else nn.LayerNorm(dtype=self.dtype)
         )
-        self.logits_dense = nn.Dense(self.num_tokens, use_bias=False, dtype=self.dtype,
-                                     param_dtype=self.param_dtype)
+        if not self.tied_head:
+            self.logits_dense = nn.Dense(self.num_tokens, use_bias=False, dtype=self.dtype,
+                                         param_dtype=self.param_dtype)
         if not self.draft_layers:
             return
         assert self.draft_layers == 1 and norm == "rms", "one block, under RMS norms"
@@ -383,8 +438,12 @@ class CausalLM(nn.Module):
 
     def _head(self, h: jnp.ndarray) -> jnp.ndarray:
         """Float32 logits of normed states [..., dim]."""
-        kernel = self.logits_dense.variables["params"]["kernel"]
         with jax.named_scope("logits_head"):  # the `head` component (obs/scopes.py)
+            if self.tied_head:  # over the embedding's rows, as it lies: no transpose
+                return lax.dot_general(
+                    h, self.token_emb.embedding.astype(h.dtype),
+                    (((h.ndim - 1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            kernel = self.logits_dense.variables["params"]["kernel"]
             return jnp.dot(h, kernel.astype(h.dtype), preferred_element_type=jnp.float32)
 
     def draft_step(self, next_tokens: jnp.ndarray, hidden: jnp.ndarray,
@@ -425,14 +484,16 @@ class CausalLM(nn.Module):
         cross-entropy of positions 0..N-2 against the next token."""
         h = self.hidden(tokens)
         if not return_loss or self.is_initializing():
-            logits = self.logits_dense(h).astype(jnp.float32)
+            logits = (self._head(h) if self.tied_head
+                      else self.logits_dense(h).astype(jnp.float32))
             if self.draft_layers and self.is_initializing():
                 self.draft_logits(tokens)  # the module's parameters
             if not return_loss:
                 return logits
             logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
             return -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1))
-        kernel = self.logits_dense.variables["params"]["kernel"]
+        kernel = (self.token_emb.embedding.T if self.tied_head
+                  else self.logits_dense.variables["params"]["kernel"])
         with jax.named_scope("loss"):
             per_pos = chunked_masked_ce(
                 h[:, :-1], kernel, None, tokens[:, 1:],
@@ -535,8 +596,9 @@ class CausalLM(nn.Module):
         raise NotImplementedError(
             "CausalLM has no uncached sampler: `generate_tokens_cached` decodes a trunk "
             "of latent layers, of linear and full ones, of window and full ones over "
-            "grouped K/V heads (with its multi-token module drafting), or of single "
-            "sublayers (state-space, attention, ungated experts) through its cache; "
+            "grouped K/V heads (with its multi-token module drafting), of single "
+            "sublayers (state-space, attention, ungated experts), or of convolved latent "
+            "attention beside top-1 experts through its cache; "
             "serving it (slots, pages, the engine) is not built (ROADMAP.md, Queue 2 B)"
         )
 
@@ -582,9 +644,12 @@ def prefill_chunks(model: CausalLM, variables, tokens: jnp.ndarray, chunk: int):
     [R, n] from position 0, filled `chunk` tokens a dispatch through
     `CausalLM.extend` (host span `lm.prefill` each), every chunk attending
     what the cache holds by then and itself; the routed layers' counts summed
-    over the chunks. A trunk of latent layers: any other raises here."""
-    if {layer.cache_kind for layer in model.plan()} != {"latent"}:
-        raise NotImplementedError("a prefill in chunks takes a trunk of latent layers")
+    over the chunks. A trunk of latent layers, or of convolved ones
+    (`ConvLatentAttention`: each chunk goes on from the tail the last one
+    left): any other raises here."""
+    if {layer.cache_kind for layer in model.plan()} not in ({"latent"}, {"cca"}):
+        raise NotImplementedError("a prefill in chunks takes a trunk of latent layers, or of "
+                                  "convolved ones (whose tail hands a chunk's end to the next)")
     extend = _jitted(_extend_builder, model, ())
     fresh, counts = model.init_cache(*tokens.shape), None
     for at in range(0, tokens.shape[1], chunk):
@@ -599,7 +664,9 @@ def place_rows(model: CausalLM, cache: dict, fresh: dict, row: int = 0) -> dict:
     `prefill_chunks`) written into its row `row + r`; sessions that hold one
     document each keep their own copy of it, a call a copy. The indices are
     left where they were."""
-    rows = decode_cache.latent_leaf(fresh).shape[0]
+    first = next(iter(fresh.values()))[decode_cache.ATTN]
+    rows = (first[decode_cache.K] if decode_cache.K in first
+            else decode_cache.latent_leaf(fresh)).shape[0]
     at = row + jnp.arange(rows, dtype=jnp.int32)
     return _jitted(_place_builder, model, ())(cache, fresh, at)
 
@@ -638,8 +705,8 @@ _extend_builder._donate_argnums = (2,)
 
 
 def _place_builder(model, key):
-    def lm_place(cache, fresh, rows):
-        return decode_cache.scatter_rows(cache, fresh, rows)
+    def lm_place(cache, fresh, rows):  # a convolved layer's tail also as its snapshot
+        return decode_cache.scatter_rows(cache, decode_cache.snapshot(fresh), rows)
 
     return lm_place
 

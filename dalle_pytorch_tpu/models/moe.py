@@ -80,14 +80,14 @@ def choose(probs: jnp.ndarray, per_token: int, bias=None, groups: Tuple[int, int
 
 
 def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows: int,
-          bias=None, groups: Tuple[int, int] = (1, 1)):
+          bias=None, groups: Tuple[int, int] = (1, 1), renormalise: bool = True):
     """From router scores [T, E] (a softmax's probabilities, or sigmoids)
     to the buffer's layout. `bias` [E]: the experts are CHOSEN by `probs +
     bias` (a score-correction bias); the weights are the chosen experts' own
     scores all the same. `groups`: a group-limited choice (`choose`).
 
     Returns a dict: `weights` [T, k] (the chosen experts' scores,
-    renormalised), `experts` [T, k], `assign` [R] (the assignment, t * k +
+    renormalised over the chosen, or with `renormalise=False` as they are), `experts` [T, k], `assign` [R] (the assignment, t * k +
     slot, that buffer row r holds), `kept` (the rows that hold one), `pos`
     [T, k] (the buffer row of each assignment, R where it has none: not
     held here, or dropped), `group_sizes` [count] (clipped to the buffer),
@@ -97,7 +97,7 @@ def route(probs: jnp.ndarray, per_token: int, held: Tuple[int, int], buffer_rows
     first, count = held
     buffer_rows = min(buffer_rows, probs.shape[0] * per_token)
     top, experts = choose(probs, per_token, bias, groups)
-    weights = top / jnp.sum(top, axis=-1, keepdims=True)
+    weights = top / jnp.sum(top, axis=-1, keepdims=True) if renormalise else top
     local = experts - first
     key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
     # one sort gives the order (by held expert, the rest last) and, from the
@@ -394,7 +394,10 @@ class RoutedExperts(nn.Module):
     `act="relu2"`: every expert, the shared one too, is relu(x W_up)^2 W_out
     with no gate (no `w_gate`, no `shared_gate`; forward only).
 
-    Parameters: `router` [dim, experts_total], `w_gate` and `w_up` [count,
+    Parameters: `router` [dim, experts_total] (with `router_dim` the MLP's in
+    its place: `router_down` [dim, R], `router_1`, `router_2` [R, R],
+    `router_out` [R, experts_total], their biases, `router_gamma` and the gain
+    `router_norm` [R], all float32), `w_gate` and `w_up` [count,
     dim, expert_dim], `w_out` [count, expert_dim, dim]; no biases. Gate and
     up are two grouped products, so that every product of the layer, forward
     or transposed, is rows x dim x expert_dim. The shared expert is a dense
@@ -405,9 +408,14 @@ class RoutedExperts(nn.Module):
     `score_bias`: a float32 vector `router_bias` [experts_total] is added to
     the scores for the CHOICE alone (the weights stay the scores').
     `groups`: `(groups the outputs stand in, groups a choice is limited to)`,
-    `choose`'s. Where the caller makes the collection `picks` mutable the
+    `choose`'s. `renormalise=False`: the chosen experts' scores weigh them as
+    they are (with one choice a renormalised weight would be 1).
+    `router_dim` > 0: the router is an MLP of that width that carries a STATE
+    from layer to layer (`mlp_router_probs`); the layer is then called with the
+    state of the routed layer before it (None: zeros) and returns `(y, state)`.
+    Where the caller makes the collection `picks` mutable the
     chosen experts [T, k] are sown there (`experts`): what a check reads.
-    The matrices are stored in `param_dtype`, the router in float32. Sows
+    The matrices are stored in `param_dtype`, either router in float32. Sows
     `moe_load` [count], `moe_rows`, `moe_dropped` and `moe_moved` into the
     `stats` collection where the caller makes it mutable.
     """
@@ -424,6 +432,9 @@ class RoutedExperts(nn.Module):
     score_bias: bool = False
     groups: Tuple[int, int] = (1, 1)
     act: str = "swiglu"  # "swiglu" | "relu2": the experts' form
+    renormalise: bool = True  # the chosen scores, before `routed_scale`
+    router_dim: int = 0  # 0: one matrix; else an MLP that wide with a carried state
+    norm_eps: float = 1e-6  # of the MLP router's norm
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -434,7 +445,17 @@ class RoutedExperts(nn.Module):
         assert self.act in ("swiglu", "relu2"), f"unknown expert form {self.act!r}"
         gated = self.act == "swiglu"
         matrix = lambda name, *shape: self.param(name, _fan_in, shape, self.param_dtype)
-        self.router = self.param("router", _fan_in, (self.dim, self.experts_total))
+        width = self.router_dim
+        if width:  # float32 like the one matrix: `mlp_router_probs`
+            for name, shape in (("router_down", (self.dim, width)), ("router_1", (width, width)),
+                                ("router_2", (width, width)),
+                                ("router_out", (width, self.experts_total))):
+                setattr(self, name, self.param(name, _fan_in, shape))
+            for name in ("router_down_bias", "router_1_bias", "router_2_bias", "router_gamma"):
+                setattr(self, name, self.param(name, nn.initializers.zeros, (width,)))
+            self.router_norm = self.param("router_norm", nn.initializers.ones, (width,))
+        else:
+            self.router = self.param("router", _fan_in, (self.dim, self.experts_total))
         if gated:
             self.w_gate = matrix("w_gate", count, self.dim, self.expert_dim)
         self.w_up = matrix("w_up", count, self.dim, self.expert_dim)
@@ -455,6 +476,27 @@ class RoutedExperts(nn.Module):
             return jax.nn.sigmoid(logits)
         return jax.nn.softmax(logits, axis=-1)
 
+    def mlp_router_probs(self, h2d: jnp.ndarray, carried=None):
+        """`(float32 softmax scores [T, experts_total], state [T, router_dim])`
+        of the router that is an MLP (arXiv:2511.17127, as `model_type: zaya`
+        builds it), for tokens [T, dim] and the state `carried` [T,
+        router_dim] of the routed layer before this one (None: zeros):
+
+            s = h W_down + b_down + gamma * carried      the state handed on
+            z = gelu(gelu(rmsnorm(s; g) W_1 + b_1) W_2 + b_2) W_out
+
+        under the scope `router_mlp`, float32 at the highest precision."""
+        dot = functools.partial(jnp.dot, precision=lax.Precision.HIGHEST)
+        gelu = functools.partial(jax.nn.gelu, approximate=False)
+        with jax.named_scope("router_mlp"):
+            s = dot(h2d.astype(jnp.float32), self.router_down) + self.router_down_bias
+            if carried is not None:
+                s = s + self.router_gamma * carried
+            z = s * lax.rsqrt(jnp.mean(s * s, -1, keepdims=True) + self.norm_eps)
+            z = gelu(dot(z * self.router_norm, self.router_1) + self.router_1_bias)
+            z = dot(gelu(dot(z, self.router_2) + self.router_2_bias), self.router_out)
+            return jax.nn.softmax(z, axis=-1), s
+
     def shared(self, h: jnp.ndarray) -> jnp.ndarray:
         """[T, dim]: the shared expert of tokens [T, dim]."""
         with jax.named_scope("moe_shared"):
@@ -465,20 +507,31 @@ class RoutedExperts(nn.Module):
                              (self.shared_gate, self.shared_up, self.shared_out))
             return jnp.dot(_gated(jnp.dot(h, gate), jnp.dot(h, up)), out)
 
-    def choices(self, x: jnp.ndarray) -> jnp.ndarray:
-        """[B, N, k] the experts the router chooses, largest first."""
-        probs = self.router_probs(x.reshape(-1, x.shape[-1]))
+    def _scores(self, h2d: jnp.ndarray, carried=None):
+        """`(scores [T, experts_total], the MLP router's state [T, router_dim]
+        or None)` of whichever router the layer has; `carried` [..., router_dim]."""
+        if self.router_dim:
+            return self.mlp_router_probs(
+                h2d, None if carried is None else carried.reshape(h2d.shape[0], -1))
+        with jax.named_scope("moe_router"):
+            return self.router_probs(h2d), None
+
+    def choices(self, x: jnp.ndarray, carried=None) -> jnp.ndarray:
+        """[B, N, k] the experts the router chooses, largest first (`carried`:
+        the MLP router's state of the routed layer before, [B, N, router_dim])."""
+        probs = self._scores(x.reshape(-1, x.shape[-1]), carried)[0]
         experts = choose(probs, self.experts_per_token, self.router_bias, tuple(self.groups))[1]
         return experts.reshape(*x.shape[:-1], -1)
 
-    def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray, deterministic: bool = True, carried=None):
         h = x.reshape(-1, x.shape[-1])
-        with jax.named_scope("moe_router"):
-            probs = self.router_probs(h)
+        probs, state = self._scores(h, carried)
         with jax.named_scope("moe_dispatch"):
             biased = {} if self.router_bias is None else {"bias": self.router_bias}
             if tuple(self.groups) != (1, 1):
                 biased["groups"] = tuple(self.groups)
+            if not self.renormalise:
+                biased["renormalise"] = False
             r = route(probs, self.experts_per_token, tuple(self.experts_held),
                       self.buffer_rows, **biased)
             weights = r["weights"]
@@ -499,4 +552,6 @@ class RoutedExperts(nn.Module):
         if self.is_mutable_collection("picks"):
             self.sow("picks", "experts", r["experts"], reduce_fn=lambda _, new: new,
                      init_fn=lambda: None)
+        if self.router_dim:
+            return y.reshape(x.shape), state.reshape(*x.shape[:-1], -1)
         return y.reshape(x.shape)

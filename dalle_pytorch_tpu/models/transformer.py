@@ -63,12 +63,14 @@ import flax.linen as nn
 
 from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.models.attention import (
-    LATENT, LINEAR, ROWS, SSM,
+    CCA, LATENT, LINEAR, ROWS, SSM,
     Attention,
+    ConvLatentAttention,
     GatedDeltaAttention,
     LatentAttention,
     Mamba2Mixer,
     attention_path,
+    cca_tail_dim,
 )
 from dalle_pytorch_tpu.ops.masks import (
     axial_static_mask,
@@ -166,6 +168,20 @@ class SwiGLU(nn.Module):
             nn.silu(dense(self.hidden, "w_gate")(x)) * dense(self.hidden, "w_up")(x))
 
 
+class AffineResidual(nn.Module):
+    """A sublayer's result f meeting the stream x scaled and shifted: (a x + b)
+    + (c f + d), `vectors` [4, dim] float32 the rows a, b, c, d (the plain sum
+    is 1, 0, 1, 0), computed in float32, the result in the stream's dtype."""
+
+    dim: int
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, f: jnp.ndarray) -> jnp.ndarray:
+        plain = lambda key, shape: jnp.tile(jnp.array([[1.0], [0.0]], jnp.float32), (2, shape[1]))
+        a, b, c, d = self.param("vectors", plain, (4, self.dim))
+        return ((a * x + b) + (c * f + d)).astype(x.dtype)
+
+
 def _build_static_mask(
     attn_type: str,
     seq_len: int,
@@ -230,15 +246,15 @@ class LayerPlan(NamedTuple):
     (`Transformer.plan`), and read wherever a layer's kind matters."""
 
     # of mixer: full, axial_row, axial_col, conv_like, sparse, window, latent,
-    # linear, ssm, or `none`: no mixer, the layer is its feed-forward alone
+    # linear, ssm, cca, or `none`: no mixer, the layer is its feed-forward alone
     kind: str
     attn_id: int  # layers of one id share their mixer's weights (`attn_{id}`)
     ff_id: int  # and their feed-forward's (`ff_{id}`)
     # the mixer it is built as and the path that mixer's cached call takes:
-    # DALLE, LANES, ROWS, LATENT, LINEAR or SSM of models/attention.py (NO_MIXER: none)
+    # DALLE, LANES, ROWS, LATENT, LINEAR, SSM or CCA of models/attention.py (NO_MIXER: none)
     path: str
     # what `decode_cache.layer_spec` is asked for: heads, window, latent,
-    # recurrent, or `none` (a layer without a mixer holds nothing)
+    # recurrent, cca, or `none` (a layer without a mixer holds nothing)
     cache_kind: str
     # positions a latent layer's lightning indexer selects for a query (0: no
     # indexer; with one the layer's cache keeps the indexer's keys too)
@@ -251,6 +267,9 @@ class LayerPlan(NamedTuple):
     # geglu, swiglu, swiglu_experts or relu2_experts (routed: `routed_layers`),
     # or `none`: no feed-forward, the layer is its mixer alone
     ff_kind: str
+    # what its router takes from the routed layer before it and hands to the
+    # next, beside x and the cache: `router_state` (an MLP router's), or None
+    carries: Optional[str] = None
 
 
 NO_MIXER = "none"  # a layer's `kind` and `path` where it has no mixer
@@ -264,12 +283,12 @@ def routed_layers(plan) -> int:
 
 
 CACHE_KINDS = {"latent": "latent", "linear": "recurrent", "ssm": "recurrent",
-               "window": "window", NO_MIXER: "none"}
+               "window": "window", CCA: "cca", NO_MIXER: "none"}
 
 
 @functools.lru_cache(maxsize=None)
 def _stack_plan(depth, attn_types, attn_ids, ff_ids, ff_kinds, heads, kv_heads, qk_norm,
-                window, rotated, index_topk) -> Tuple[LayerPlan, ...]:
+                window, rotated, index_topk, router_dim=0) -> Tuple[LayerPlan, ...]:
     """`Transformer.plan` over the options it reads, made hashable: once a
     set of options, not once a call."""
     assert len(ff_kinds) == depth, f"{len(ff_kinds)} ff_kinds for {depth} layers"
@@ -286,15 +305,16 @@ def _stack_plan(depth, attn_types, attn_ids, ff_ids, ff_kinds, heads, kv_heads, 
             )
         if kind == "window":
             assert window, 'attn_types has "window" and the model no window length'
-        paths.append(kind if kind in (LATENT, LINEAR, SSM, NO_MIXER) else attention_path(
+        paths.append(kind if kind in (LATENT, LINEAR, SSM, CCA, NO_MIXER) else attention_path(
             heads, kv_heads, qk_norm, window if kind == "window" else None, kind in rotated))
-    per_row = ROWS in paths
+    per_row = ROWS in paths or CCA in paths
     return tuple(
         LayerPlan(kind=kind, attn_id=attn_id, ff_id=ff_id, path=path,
                   cache_kind=CACHE_KINDS.get(kind, "heads"), per_row=per_row,
                   selects=index_topk if kind == "latent" else 0,
                   rotary=kind if kind in rotated else None,
-                  takes_start=kind not in (LINEAR, SSM, NO_MIXER), ff_kind=ff_kind)
+                  takes_start=kind not in (LINEAR, SSM, NO_MIXER), ff_kind=ff_kind,
+                  carries="router_state" if router_dim and ff_kind in ROUTED_KINDS else None)
         for kind, attn_id, ff_id, path, ff_kind in zip(kinds, attn_ids, ff_ids, paths, ff_kinds))
 
 
@@ -549,6 +569,13 @@ class Transformer(nn.Module):
     # (groups the router's outputs stand in, groups a token's choice is
     # limited to): the best groups by their two best scores, then the experts
     moe_groups: Tuple[int, int] = (1, 1)
+    moe_renormalise: bool = True  # the chosen experts' scores, before `routed_scale`
+    # > 0: the router is an MLP that wide whose state runs down the depth, from
+    # each routed layer to the next (models/moe.py:RoutedExperts.mlp_router_probs)
+    router_dim: int = 0
+    # how a sublayer's result f meets the stream x: "sum", x + f; "affine",
+    # (a x + b) + (c f + d) with four learned float32 vectors a sublayer
+    residual: str = "sum"
     # "latent" among attn_types (models/attention.py:LatentAttention)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -575,6 +602,8 @@ class Transformer(nn.Module):
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    # "cca" among attn_types (models/attention.py:ConvLatentAttention) takes
+    # `heads`, `kv_heads` and `dim_head`, and no option of its own
     dtype: Any = jnp.float32
     # what the MATRICES are stored in (the new block options' only: norm
     # gains and the router stay float32, the DALL-E block keeps float32)
@@ -584,7 +613,7 @@ class Transformer(nn.Module):
         """The first block option that is not the DALL-E block's, or None."""
         defaults = dict(norm="layer", ff_kind="geglu", ff_kinds=None, use_bias=True,
                         layerscale=True, kv_heads=None, qk_norm=False, window=None,
-                        rotary_specs=None, prenorm=True)
+                        rotary_specs=None, prenorm=True, residual="sum")
         return next((k for k, v in defaults.items() if getattr(self, k) != v), None)
 
     def _norm(self):
@@ -595,6 +624,9 @@ class Transformer(nn.Module):
 
     def _scan_supported(self) -> Optional[str]:
         """None if the scan executor can run this config, else the reason."""
+        if any(layer.carries for layer in self.plan()):
+            return ("a router that carries its state from layer to layer (the scanned body "
+                    "passes x and the cache, and nothing else)")
         if self._block_variant() is not None:
             return f"the block option {self._block_variant()} (one scanned body, one kind of layer)"
         if self.attn_types and any(t != "full" for t in self.attn_types):
@@ -635,6 +667,14 @@ class Transformer(nn.Module):
         assert self.executor == "unrolled", f"unknown executor {self.executor!r}"
         depth = self.depth
         plan = self.plan()
+        if self.reversible and self.reversible_impl != "remat" and self.residual != "sum":
+            raise ValueError("the revnet executor couples its streams by plain sums "
+                             f'(residual="{self.residual}")')
+        if self.reversible and any(layer.carries for layer in plan):
+            raise ValueError(
+                "a router that carries its state from layer to layer runs on the plain unrolled "
+                "executor: the reversible ones (remat, revnet) pass x from layer to layer, and "
+                "nothing else")
         shared_attn, shared_ff = {}, {}
         for ind, layer in enumerate(plan):
             if layer.attn_id not in shared_attn:
@@ -660,6 +700,10 @@ class Transformer(nn.Module):
             for kind, spec in dict(self.rotary_specs or {}).items()
         }
         self.text_len = self._derived_text_len()
+        assert self.residual in ("sum", "affine"), f"unknown residual {self.residual!r}"
+        if self.residual == "affine":
+            self.attn_res = [AffineResidual(self.dim) for _ in range(depth)]
+            self.ff_res = [AffineResidual(self.dim) for _ in range(depth)]
         if not self.layerscale:
             return
         self.attn_scales = [
@@ -689,7 +733,7 @@ class Transformer(nn.Module):
             self.depth, tuple(self.attn_types or ("full",)), tuple(self.shared_attn_ids or ()),
             tuple(self.shared_ff_ids or ()), tuple(self.ff_kinds or (self.ff_kind,) * self.depth),
             self.heads, self.kv_heads, self.qk_norm, self.window,
-            frozenset(dict(self.rotary_specs or {})), self.index_topk)
+            frozenset(dict(self.rotary_specs or {})), self.index_topk, self.router_dim)
 
     def _mixer(self, ind: int, layer: LayerPlan):
         """The mixer the plan says layer `ind` is built as (None: it has none)."""
@@ -701,6 +745,12 @@ class Transformer(nn.Module):
                 dim=self.dim, seq_len=self.seq_len, heads=self.ssm_heads,
                 head_dim=self.ssm_head_dim, groups=self.ssm_groups, state_dim=self.ssm_state,
                 conv_width=self.ssm_conv, chunk=self.ssm_chunk, norm_eps=self.norm_eps,
+                dtype=self.dtype, param_dtype=self.param_dtype, name=name,
+            )
+        if layer.path == CCA:
+            return ConvLatentAttention(
+                dim=self.dim, seq_len=self.seq_len, heads=self.heads, kv_heads=self.kv_heads,
+                dim_head=self.dim_head, norm_eps=self.norm_eps, attn_impl=self.attn_impl,
                 dtype=self.dtype, param_dtype=self.param_dtype, name=name,
             )
         if layer.path == LATENT:
@@ -750,7 +800,8 @@ class Transformer(nn.Module):
                 buffer_rows=self.moe_buffer_rows, score=self.moe_score,
                 routed_scale=self.routed_scale, shared_dim=self.shared_dim,
                 score_bias=self.moe_score_bias, groups=tuple(self.moe_groups),
-                dtype=self.dtype, param_dtype=self.param_dtype, name=name,
+                renormalise=self.moe_renormalise, router_dim=self.router_dim,
+                norm_eps=self.norm_eps, dtype=self.dtype, param_dtype=self.param_dtype, name=name,
             )
         if layer.ff_kind == "swiglu":
             return SwiGLU(dim=self.dim, hidden=self.ff_dim, dtype=self.dtype,
@@ -906,10 +957,13 @@ class Transformer(nn.Module):
             return h, attn_cache, ring
         return h * self.attn_scales[i].astype(h.dtype), attn_cache, ring
 
-    def _half_ff(self, i, x, layer_cache, pos, deterministic=True):
+    def _half_ff(self, i, x, layer_cache, pos, deterministic=True, carried=None):
         """Feed-forward half-block g (norm → shift → ff → [sandwich] → scale).
-        `pos` is the pre-update decode position (for the streaming shift).
-        Returns (residual_branch, new_shift_ring)."""
+        `pos` is the pre-update decode position (for the streaming shift);
+        `carried` what the routed layer before handed on, where the plan says
+        this layer's router carries anything. Returns (residual_branch,
+        new_shift_ring, what this layer hands on: `carried` where it carries
+        nothing)."""
         cached = layer_cache is not None
         h = self.ff_norms[i](x) if self.prenorm else x
         ring = None
@@ -918,12 +972,15 @@ class Transformer(nn.Module):
                 h, layer_cache.get("shift_ff") if cached else None, pos,
                 ring_end=layer_cache.get("ring_end") if cached else None,
             )
-        h = self.ff_layers[i](h, deterministic=deterministic)
+        if self.plan()[i].carries:
+            h, carried = self.ff_layers[i](h, deterministic=deterministic, carried=carried)
+        else:
+            h = self.ff_layers[i](h, deterministic=deterministic)
         if self.sandwich_norm:
             h = self.ff_norms_out[i](h)
         if not self.layerscale:
-            return h, ring
-        return h * self.ff_scales[i].astype(h.dtype), ring
+            return h, ring, carried
+        return h * self.ff_scales[i].astype(h.dtype), ring, carried
 
     def route_choices(self, x: jnp.ndarray, layer: int = 0) -> jnp.ndarray:
         """[B, N, k]: the experts layer `layer`'s router chooses for each
@@ -931,11 +988,12 @@ class Transformer(nn.Module):
         before it and its own attention half: what the routed layer itself
         would choose, read outside any train step."""
         assert self.plan()[layer].ff_kind in ROUTED_KINDS, "only a routed layer chooses"
+        carried = None
         for i in range(layer):
-            x = self._layer(i, x, None, None, True)[0]
+            x, _, carried = self._layer(i, x, None, None, True, carried=carried)
         if self.plan()[layer].kind != NO_MIXER:
-            x = x + self._half_attn(layer, x, None, None, True)[0]
-        return self.ff_layers[layer].choices(self.ff_norms[layer](x))
+            x = self._residual("attn", layer, x, self._half_attn(layer, x, None, None, True)[0])
+        return self.ff_layers[layer].choices(self.ff_norms[layer](x), carried)
 
     def _rev_f(self, x: jnp.ndarray, i: int, deterministic: bool = True):
         return self._half_attn(i, x, None, None, deterministic)[0]
@@ -1001,6 +1059,14 @@ class Transformer(nn.Module):
         # channel-duplication mean-out (`reversible.py:158,165`)
         return (y1 + y2) / 2
 
+    def _residual(self, half: str, i: int, x, h):
+        """The stream after the result h of layer i's `half` (`attn` or `ff`):
+        x + h, or with `residual="affine"` (a x + b) + (c h + d) by that
+        sublayer's four vectors (`AffineResidual`)."""
+        if self.residual == "sum":
+            return x + h
+        return (self.attn_res if half == "attn" else self.ff_res)[i](x, h)
+
     def _layer(
         self,
         i: int,
@@ -1009,10 +1075,13 @@ class Transformer(nn.Module):
         layer_cache,
         deterministic: bool,
         start: bool = False,
+        carried=None,
     ):
         """One (attn, ff) residual pair, or the one sublayer of a layer that is
         a mixer or a feed-forward alone (one norm, one residual); returns (x,
-        updated layer cache: None of a layer that holds nothing)."""
+        updated layer cache: None of a layer that holds nothing, what the
+        layer's router hands to the next routed layer's: `carried`, what the
+        one before handed to it, where the plan says it carries nothing)."""
         cached = layer_cache is not None
         pos = layer_cache["attn"]["index"] if cached else None
         layer = self.plan()[i]
@@ -1022,18 +1091,18 @@ class Transformer(nn.Module):
             h, attn_cache, ring_attn = self._half_attn(
                 i, x, key_mask, layer_cache, deterministic, start
             )
-            x = x + h
+            x = self._residual("attn", i, x, h)
         if layer.ff_kind != "none":
-            h, ring_ff = self._half_ff(i, x, layer_cache, pos, deterministic)
-            x = x + h
+            h, ring_ff, carried = self._half_ff(i, x, layer_cache, pos, deterministic, carried)
+            x = self._residual("ff", i, x, h)
 
         if not cached:
-            return x, None
+            return x, None, carried
         new_cache = {"attn": attn_cache}
         if self.shift_tokens:
             new_cache["shift_attn"] = ring_attn
             new_cache["shift_ff"] = ring_ff
-        return x, new_cache
+        return x, new_cache, carried
 
     def __call__(
         self,
@@ -1076,7 +1145,7 @@ class Transformer(nn.Module):
                         i, x2, key_mask, lc, deterministic
                     )
                     x1 = x1 + h
-                    h, ring_f = self._half_ff(i, x1, lc, pos, deterministic)
+                    h, ring_f, _ = self._half_ff(i, x1, lc, pos, deterministic)
                     x2 = x2 + h
                     layer_new = {"attn": attn_cache}
                     if self.shift_tokens:
@@ -1091,6 +1160,7 @@ class Transformer(nn.Module):
             )
             return self._revnet(x, tuple(order))
         new_cache = {} if cache is not None else None
+        carried = None  # what a routed layer's router hands to the next one's
         for i in order:
             if self.reversible and cache is None:
                 # activation rematerialization: recompute the layer in the
@@ -1104,10 +1174,10 @@ class Transformer(nn.Module):
                     layer_fn, policy=resolve_remat_policy(self.remat_policy)
                 )(self, x)
             else:
-                x, layer_cache = self._layer(
+                x, layer_cache, carried = self._layer(
                     i, x, key_mask,
                     cache.get(decode_cache.layer_key(i)) if cache else None,
-                    deterministic, start,
+                    deterministic, start, carried,
                 )
                 if layer_cache:
                     new_cache[decode_cache.layer_key(i)] = layer_cache
@@ -1168,6 +1238,7 @@ class Transformer(nn.Module):
             ring=(self.window or 0) + self.draft_positions,
             latent_dim=self.kv_lora_rank, rope_dim=self.qk_rope_dim,
             index_dim=self.index_dim if any(layer.selects for layer in plan) else None,
+            tail_dim=cca_tail_dim(self.heads, self.kv_heads or self.heads, self.dim_head),
             **state,
         )
 

@@ -42,6 +42,7 @@ COMPONENTS = (
     "window_attend", "global_attend",
     "dsa_index_proj", "dsa_index", "dsa_select",
     "ssm_step", "ssm_proj", "ssm_chunk",
+    "cca_mix", "router_mlp",
 )
 # `mtp`: what a multi-token module runs (models/lm.py:CausalLM.draft_step and
 # the draft's argmax), whatever its component: a phase, as `remat` is
@@ -132,6 +133,14 @@ RULES: Tuple[Tuple[str, "re.Pattern"], ...] = tuple(
         ("ssm_step", _E(SSM_KERNEL)),
         ("ssm_chunk", _E("ssm_chunk")),
         ("ssm_proj", _E("ssm_proj")),
+        # convolved latent attention (models/attention.py:ConvLatentAttention),
+        # before the attention rules its module's name would fall under: the
+        # value shift, the two convolutions, the q-k mean, the unit norm and
+        # tau. Its projections are `to_qkv` and `to_out` (`attn_proj`), its
+        # cached attend `global_attend`, its rotary glue. And a router that is
+        # an MLP with a carried state (models/moe.py), before `ff`
+        ("cca_mix", _E("cca_mix")),
+        ("router_mlp", _E("router_mlp")),
         # a scan's own slicing (`dynamic_index_in_dim` of the stacked
         # parameters, LayerScale vectors and the layer index) and its counter
         # are nobody's: no owner. The cached scan carries the depth-stacked

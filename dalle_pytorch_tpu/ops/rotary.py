@@ -154,7 +154,12 @@ def rotary_cos_sin(positions, spec):
 
 def apply_rotary_half(cos: jnp.ndarray, sin: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
     """Rotate every channel of t [..., n, dim] by float32 tables [n, dim],
-    halves paired; the product is float32 and the result t's dtype."""
+    halves paired; the product is float32 and the result t's dtype. Tables
+    narrower than t (a partial rotary) turn its first columns, halves of THOSE
+    paired, and the rest passes."""
+    rot = cos.shape[-1]
+    if rot < t.shape[-1]:
+        return jnp.concatenate([apply_rotary_half(cos, sin, t[..., :rot]), t[..., rot:]], axis=-1)
     x = t.astype(jnp.float32)
     x1, x2 = jnp.split(x, 2, axis=-1)
     turned = jnp.concatenate([-x2, x1], axis=-1)

@@ -250,13 +250,15 @@ def make_lm_train_step(model, grad_accum: int = 1) -> Callable:
     `moe_rows`, `moe_dropped` and `moe_moved` [depth] (an assignment past a
     layer's buffer is a dropped token: callers require 0; the buffer rows a
     pass walked: the rows present, rounded up to the chunk). A model with a
-    state-space mixer or ungated experts is refused by name: neither has a
-    backward (models/lm.py:FORWARD_ONLY).
+    state-space mixer or ungated experts (neither has a backward), or of
+    convolved latent attention (no gradient held to its reference), is refused
+    by name (models/lm.py:forward_only).
     """
-    from dalle_pytorch_tpu.models.lm import FORWARD_ONLY
+    from dalle_pytorch_tpu.models.lm import forward_only
 
-    if any(layer.kind == "ssm" or layer.ff_kind == "relu2_experts" for layer in model.plan()):
-        raise NotImplementedError(FORWARD_ONLY)
+    why = forward_only(model.plan())
+    if why:
+        raise NotImplementedError(why)
 
     def loss_fn(params, batch, rng):
         del rng  # no dropout in this trunk; the signature is the trainers'

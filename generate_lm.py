@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Generate token ids with a causal language model (`models/lm.py:CausalLM`)
-whose trunk decodes through a cache. Four families of published configs do:
+whose trunk decodes through a cache. Five families of published configs do:
 latent attention (a compressed K/V cache), leading dense layers, a shared
 expert beside sigmoid-routed ones of which this process holds a share, and
 with `index_topk` a lightning indexer in every layer that selects the cached
@@ -16,7 +16,14 @@ sublayer, a Mamba-2 mixer, an attention over grouped K/V heads or ungated
 relu2 experts; a state-space state beside K/V in a per-row cache, `--config
 benchmark/configs/nemotron3-nano-30b-ep2.json`, or on the CPU `--config
 benchmark/configs/_tiny-nemotron-h.json --prompts seeded:3 --batch 2
---prompt_len 40 --max_new_tokens 6`). Each with parameters stored in bf16.
+--prompt_len 40 --max_new_tokens 6`); and `zaya` (`cca_time0`: attention in a
+compressed latent whose q and k pass two causal convolutions, a per-row cache
+of 2 K/V heads and the last position's tail, ONE of 16 experts a token behind a
+router that is an MLP carrying its state from layer to layer, a scaled
+residual, a head that is the embedding, `--config
+benchmark/configs/zaya1-8b-pp2.json`, or on the CPU `--config
+benchmark/configs/_tiny-zaya.json --prompts seeded:3 --batch 2 --prompt_len 40
+--max_new_tokens 6`). Each with parameters stored in bf16.
 
 The sampler `generate.py` uses for DALL-E, for token sequences: every prompt
 but its last token is prefilled into a decode cache
@@ -102,12 +109,14 @@ def read_config(args):
             cfg[key] = value
         else:
             raise SystemExit(f"unknown option {key!r} (have: {', '.join([*cfg, *PROGRAM_KEYS])})")
-    families = {"kv_lora_rank", "linear_key_head_dim", "layer_types", "hybrid_override_pattern"}
+    families = {"kv_lora_rank", "linear_key_head_dim", "layer_types", "hybrid_override_pattern",
+                "cca_time0"}
     if not families & set(cfg):
         raise SystemExit("generation is built for the latent-attention trunk (kv_lora_rank ...), "
                          "for linear and full layers (linear_key_head_dim ...), for window "
-                         "and full ones (layer_types ...) and for layers of one sublayer "
-                         "(hybrid_override_pattern ...)")
+                         "and full ones (layer_types ...), for layers of one sublayer "
+                         "(hybrid_override_pattern ...) and for convolved latent ones "
+                         "(cca_time0 ...)")
     return cfg, program
 
 

@@ -15,8 +15,8 @@ import pytest
 
 from dalle_pytorch_tpu.models import attention, decode_cache
 from dalle_pytorch_tpu.models.attention import (
-    DALLE, LANES, LATENT, LINEAR, ROWS, SSM, Attention, GatedDeltaAttention, LatentAttention,
-    Mamba2Mixer)
+    CCA, DALLE, LANES, LATENT, LINEAR, ROWS, SSM, Attention, ConvLatentAttention,
+    GatedDeltaAttention, LatentAttention, Mamba2Mixer)
 from dalle_pytorch_tpu.models.lm import CausalLM
 from dalle_pytorch_tpu.models.moe import RoutedExperts
 from dalle_pytorch_tpu.models.transformer import (
@@ -37,9 +37,10 @@ FAMILIES = {
     "latent": "_tiny-pangu",
     "latent_indexed": "_tiny-deepseek-v32",
     "one_sublayer_ssm": "_tiny-nemotron-h",
+    "convolved_latent": "_tiny-zaya",
 }
 MIXERS = {LATENT: LatentAttention, LINEAR: GatedDeltaAttention, SSM: Mamba2Mixer,
-          NO_MIXER: type(None)}
+          CCA: ConvLatentAttention, NO_MIXER: type(None)}
 
 
 @functools.lru_cache(maxsize=None)
@@ -63,6 +64,8 @@ def _kind_of(layer: dict) -> str:
         return "recurrent"
     if decode_cache.LATENT in attn or decode_cache.ROWS in attn:
         return "latent"
+    if decode_cache.TAIL in attn:
+        return "cca"
     return "window" if "k_at" in attn else "heads"
 
 
@@ -110,6 +113,8 @@ def test_every_bound_mixer_is_on_the_plans_path(family):
             assert (mixer.window is not None) == (layer.kind == "window")
         assert isinstance(ff, RoutedExperts) == (layer.ff_kind in ROUTED_KINDS)
         assert not isinstance(ff, RoutedExperts) or ff.act == ROUTED_KINDS[layer.ff_kind]
+        # what a layer carries down the depth is its router's to say
+        assert (layer.carries == "router_state") == bool(getattr(ff, "router_dim", 0))
         assert layer.takes_start == (layer.path not in (LINEAR, SSM, NO_MIXER))
         assert layer.rotary in (None, layer.kind) and (layer.rotary is None) == (
             layer.kind not in dict(trunk.rotary_specs or {}))
@@ -164,6 +169,35 @@ def test_the_published_pattern_of_52_layers_is_one_sublayer_a_layer():
     assert attn[decode_cache.STATE].shape == (2, 128, 64 * 64)  # [rows, state, heads x width]
     assert attn[decode_cache.CONV].shape == (2, 3, 6144) and attn[decode_cache.INDEX].shape == (2,)
     assert cache["layer_5"][decode_cache.ATTN][decode_cache.K].shape == (2, 2, 64, 128)
+
+
+def test_the_published_40_layers_carry_a_router_state_and_no_other_executor_takes_them():
+    """`model_type: zaya` as published: 40 layers, each convolved latent
+    attention (2 K/V heads of 128 and a tail a row in a per-row cache, a rotary
+    over half of each head) and top-1 experts whose router hands its state to
+    the next layer's; the scan and the reversible executors pass x (and the
+    cache) from layer to layer and nothing else, and say so."""
+    with open(ROOT / "benchmark" / "configs" / "zaya1-8b-pp2.json") as f:
+        cfg = json.load(f)
+    assert cfg["published"]["num_hidden_layers"] == 40
+    full = dict(cfg, num_hidden_layers=40, layer_types=["hybrid"] * 40)
+    model = CausalLM.from_config(full, 64)
+    plan = model.plan()
+    assert len(plan) == 40 and {
+        (p.kind, p.path, p.cache_kind, p.ff_kind, p.carries, p.rotary, p.per_row, p.takes_start)
+        for p in plan} == {("cca", CCA, "cca", "swiglu_experts", "router_state", "cca", True, True)}
+    assert routed_layers(plan) == 40 and dict(model.trunk["rotary_specs"]["cca"])["dim"] == 64
+    cache = jax.eval_shape(lambda: model.init_cache(2, 64))
+    attn = cache["layer_39"][decode_cache.ATTN]
+    assert attn[decode_cache.K].shape == (2, 2, 64, 128) and attn[decode_cache.K].dtype == jnp.bfloat16
+    # c and c' of 8 + 2 heads of 128 and one shifted K/V head: 21 whole lane tiles
+    assert attn[decode_cache.TAIL].shape == attn["tail_at"].shape == (2, 21 * 128)
+    with pytest.raises(ValueError, match='executor="scan" does not support a router that carries'):
+        CausalLM.from_config(cfg, 64, executor="scan").bind({}).transformer.rotary_table
+    with pytest.raises(ValueError, match="carries its state.*plain unrolled executor"):
+        CausalLM.from_config(cfg, 64, reversible=True).bind({}).transformer.rotary_table
+    # a plan whose router is one matrix carries nothing
+    assert {p.carries for p in _trunk("window_full_mtp")[0].plan()} == {None}
 
 
 def test_a_cache_of_the_wrong_index_rank_is_refused_with_a_sentence():

@@ -166,6 +166,35 @@ def test_a_state_space_mixers_parts_are_placed_before_the_attention_rules():
     assert scopes.SSM_KERNEL == "ssm_step" and scopes.SSM_KERNEL not in scopes.KERNELS
 
 
+def test_a_convolved_layers_parts_and_the_mlp_router_are_placed():
+    """The convolutions, the q-k mean and the norms of a convolved layer are
+    `cca_mix`, ahead of the glue its `attn_{i}` module would make them; its
+    projections are `attn_proj`, its cached attend `global_attend` (the kernel
+    by its name), its rotary glue, the tail's restore `state_restore`; a router
+    that is an MLP is `router_mlp`, ahead of `ff`, and the routing after it
+    `moe_dispatch` as ever."""
+    path = "jit(lm_sample)/while/body/closed_call/transformer/attn_2/"
+    for op_name, opcode, instruction, want in (
+            (path + "cca_mix/mul", "fusion", "f.1", ("cca_mix", "fwd")),
+            (path + "cca_mix/bngi,gio->bngo/dot_general", "fusion", "f.2", ("cca_mix", "fwd")),
+            (path + "to_qkv/dot_general", "fusion", "f.3", ("attn_proj", "fwd")),
+            (path + "to_out/dot_general", "fusion", "f.4", ("attn_proj", "fwd")),
+            (path + "rotary/mul", "fusion", "f.5", ("attn_glue", "fwd")),
+            (path + "global_attend/decode_grouped", "custom-call", "decode_grouped.3",
+             ("global_attend", "fwd")),
+            (path + "cache_write/dynamic_update_slice", "fusion", "f.6", ("cache_write", "fwd")),
+            ("jit(lm_sample)/state_restore/select_n", "fusion", "f.7", ("state_restore", "fwd")),
+            (path.replace("attn_2", "ff_2") + "router_mlp/dot_general", "fusion", "f.8",
+             ("router_mlp", "fwd")),
+            (path.replace("attn_2", "ff_2") + "moe_dispatch/sort", "sort", "sort.1",
+             ("moe_dispatch", "fwd"))):
+        assert scopes.component(op_name, opcode, instruction) == want, (op_name, instruction)
+    assert {"cca_mix", "router_mlp"} <= set(scopes.COMPONENTS)
+    order = [name for name, _ in scopes.RULES]
+    assert order.index("cca_mix") < min(order.index("attend"), order.index("attn_proj"))
+    assert order.index("router_mlp") < order.index("ff")
+
+
 def test_the_grouped_kernels_rule_stands_before_attend_and_after_the_kernels():
     """`global_attend` (with `decode_grouped` by name) is asked before the
     plain `attend`, which its name contains as a word of a path would, and
@@ -244,7 +273,7 @@ def program_texts():
             "lm_sample": _lm_sample_text(), "verify_sample": _verify_sample_text(),
             "dsa_sample": _lm_sample_text(index_heads=2, index_dim=16, index_topk=4,
                                           moe_groups=(2, 1)),
-            **_hybrid_texts(), **_ssm_texts()}
+            **_hybrid_texts(), **_ssm_texts(), **_cca_texts()}
 
 
 def _lm_step_text() -> str:
@@ -317,6 +346,33 @@ def _ssm_texts() -> dict:
             variables, jax.random.PRNGKey(1), mdl.init_cache(2), jnp.zeros((2, 2), jnp.int32),
             jnp.full((2,), 8, jnp.int32)).compile().as_text(),
         "ssm_prefill": prefill.lower(
+            variables, jnp.zeros((2, 8), jnp.int32), mdl.init_cache(2),
+            jnp.asarray(0, jnp.int32)).compile().as_text(),
+    }
+
+
+def _cca_texts() -> dict:
+    """Compiled texts of a tiny convolved-latent sampler and prefill: attention
+    behind two causal convolutions over a per-row cache with a tail, an MLP
+    router that carries its state, a scaled residual, a tied head."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl = lm.CausalLM(
+        num_tokens=40, dim=32, depth=2, seq_len=24, heads=4, dim_head=8, tied_head=True,
+        trunk=dict(norm="rms", use_bias=False, layerscale=False, kv_heads=2, attn_types=("cca",),
+                   rotary_specs={"cca": {"type": "default", "dim": 4, "theta": 1e4}},
+                   ff_kind="swiglu_experts", experts_total=4, experts_per_token=1,
+                   experts_held=(0, 4), expert_dim=16, moe_buffer_rows=64, moe_score_bias=True,
+                   moe_renormalise=False, router_dim=8, residual="affine"))
+    variables = jax.jit(mdl.init)(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    sampler = jax.jit(lm._verify_sampler_builder(mdl, (4, 0.9, 1.0, 1, None)),
+                      donate_argnums=(2,))
+    prefill = jax.jit(lm._prefill_builder(mdl, ()), donate_argnums=(2,))
+    return {
+        "cca_sample": sampler.lower(
+            variables, jax.random.PRNGKey(1), mdl.init_cache(2), jnp.zeros((2, 2), jnp.int32),
+            jnp.full((2,), 8, jnp.int32)).compile().as_text(),
+        "cca_prefill": prefill.lower(
             variables, jnp.zeros((2, 8), jnp.int32), mdl.init_cache(2),
             jnp.asarray(0, jnp.int32)).compile().as_text(),
     }

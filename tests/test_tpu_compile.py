@@ -1120,3 +1120,79 @@ def test_state_space_token_loop_compiles_at_the_cells_size(one_chip, monkeypatch
     assert not re.search(r"= bf16\[24,2,8448,128\]\S* copy\(", text)
     assert ssm_step.GROUPS_PER_BLOCK == 8
     assert gb < HBM_BYTES / 1e9
+
+
+def _zaya(one_chip, monkeypatch, depth=None):
+    """(model, its variables' and its sessions' cache's shapes on the described
+    chip) of `zaya1.decode.8k`: the cell's own widths, lengths and 24 sessions,
+    at the configuration's 20 layers or the first `depth` of them."""
+    import json
+    from pathlib import Path
+
+    from dalle_pytorch_tpu.models import lm
+    from dalle_pytorch_tpu.ops import grouped_decode, grouped_matmul, pallas_attention
+
+    for module in (grouped_decode, grouped_matmul, pallas_attention):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    root = Path(__file__).resolve().parent.parent / "benchmark"
+    cfg = json.loads((root / "configs/zaya1-8b-pp2.json").read_text())
+    job = json.loads((root / "workloads/zaya1.decode.8k.json").read_text())["job"]
+    assert (job["sessions"], job["document_tokens"]) == (24, 8192)
+    steps = job["question_tokens"] + job["answer_tokens"]
+    mdl = lm.CausalLM.from_config(dict(cfg, num_hidden_layers=depth or cfg["num_hidden_layers"]),
+                                  8192 + steps, **job.get("model", {}))
+    on = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    variables = on(jax.eval_shape(mdl.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    cache = on(jax.eval_shape(lambda: mdl.init_cache(24)))
+    return mdl, variables, cache, steps
+
+
+def test_convolved_token_loop_compiles_at_the_cells_size(one_chip, monkeypatch, capsys):
+    """The token loop of `zaya1.decode.8k` (20 layers at the published widths,
+    24 sessions of 8,192 + 256 positions, every row at its own index, 256
+    steps): a layer's step is `decode_grouped` at 4 query rows a K/V head over 2
+    K/V heads of 128 and THREE grouped products, the K/V leaves ride the loop
+    with no copy, the tail is 21 whole lane tiles a row, and the plan (9.4 GB of
+    weights, 4.16 GB of cache, 0.54 GB of kept logits) leaves the chip room."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl, variables, cache, steps = _zaya(one_chip, monkeypatch)
+    assert {layer.kind for layer in mdl.plan()} == {"cca"} and len(cache) == 20
+    attn = cache["layer_0"]["attn"]
+    assert attn["k"].shape == (24, 2, 8448, 128) and attn["tail"].shape == (24, 21 * 128)
+    assert "logits_dense" not in variables["params"]  # the head is the embedding
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(lm._verify_sampler_builder(mdl, (steps, 0.9, 1.0, 2, None)),
+                       donate_argnums=(2,)).lower(
+        variables, key, cache, i32(24, 32), i32(24)).compile()
+    text, gb = compiled.as_text(), _device_bytes(compiled) / 1e9
+    with capsys.disabled():
+        print(f"\nzaya1.decode.8k sampler at 24 sessions: planned {gb:.2f} GB")
+    assert text.count("tpu_custom_call") == 80
+    assert len(re.findall(r"%gmm_fwd[.\d]* = ", text)) == 60
+    assert len(re.findall(r"%decode_grouped[.\d]* = bf16\[24,2,4,128\]", text)) == 20
+    assert not re.search(r"= bf16\[24,2,8448,128\]\S* copy\(", text)
+    assert gb < 14.6  # 14.15 planned (PR 47); the chip's loader takes 15.75 GiB
+
+
+def test_prefill_of_one_document_compiles_for_the_convolved_layers(one_chip, monkeypatch, capsys):
+    """The prefill of one 8,192-token document into the sessions' cache, the
+    first four layers at the published widths (the 20 plan 15.07 GB with every
+    weight held: CPU compile for the described chip, PR 47): the flash kernel
+    takes 8 query heads over 2 K/V heads of 128 head-major, and `gmm_fwd` a
+    buffer of 8,192 rows over 16 experts of 2,048 x 2,048."""
+    from dalle_pytorch_tpu.models import lm
+
+    mdl, variables, cache, _ = _zaya(one_chip, monkeypatch, depth=4)
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lm._prefill_builder(mdl, ()), donate_argnums=(2,), keep_unused=True).lower(
+        variables, tokens, cache, row).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%fwd_flash[.\d]* = ", text)) == 4
+    # three grouped products a layer but the last, whose routed sublayer feeds
+    # nothing that a prefill keeps (no logits: the cache alone)
+    assert len(re.findall(r"%gmm_fwd[.\d]* = ", text)) == 9
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30

@@ -76,10 +76,10 @@ def build_model(args):
     if args.config:
         with open(args.config) as f:
             cfg = json.load(f)
-    if "hybrid_override_pattern" in cfg:
-        from dalle_pytorch_tpu.models.lm import FORWARD_ONLY
+    if "hybrid_override_pattern" in cfg or "cca_time0" in cfg:
+        from dalle_pytorch_tpu.models.lm import FORWARD_ONLY, FORWARD_ONLY_CCA
 
-        raise NotImplementedError(FORWARD_ONLY)
+        raise NotImplementedError(FORWARD_ONLY_CCA if "cca_time0" in cfg else FORWARD_ONLY)
     program = {"reversible": not args.no_remat}
     for kv in args.set:
         key, text = kv.split("=", 1)
