@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from dalle_pytorch_tpu.models import decode_cache
-from dalle_pytorch_tpu.models.transformer import FLASH_RESIDUALS, Transformer, DivideMax
+from dalle_pytorch_tpu.models.transformer import LAYER_RESIDUALS, Transformer, DivideMax
 from dalle_pytorch_tpu.obs import scopes
 from dalle_pytorch_tpu.obs.tracing import host_span
 from dalle_pytorch_tpu.ops.sampling import top_k_filter, gumbel_sample
@@ -91,12 +91,13 @@ class DALLE(nn.Module):
     dim_head: int = 64
     reversible: bool = False
     reversible_impl: str = "remat"
-    # what a remat layer keeps for its backward (`resolve_remat_policy`):
-    # by default the flash kernels' residuals, B x N x (4 x dim x 2 + heads
-    # x 4) bytes a layer (a depth-64 trunk keeps 64 layers' worth); None or
-    # "nothing_saveable" keep the layer's input alone, for a stack at its
-    # memory's edge
-    remat_policy: Optional[str] = FLASH_RESIDUALS
+    # what a remat layer keeps for its backward (`resolve_remat_policy` has
+    # the ladder and its bytes): by default the flash kernels' residuals and
+    # the feed-forward's two products, B x N x ((5 + 2 x ff_mult) x dim x 2
+    # + heads x 4) bytes a layer (a depth-64 trunk keeps 64 layers' worth);
+    # "flash_residuals" keeps the kernels' alone, None or "nothing_saveable"
+    # the layer's input alone, for a deep trunk or a stack at its memory's edge
+    remat_policy: Optional[str] = LAYER_RESIDUALS
     attn_dropout: float = 0.0
     ff_dropout: float = 0.0
     attn_types: Optional[Sequence[str]] = None

@@ -60,6 +60,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from dalle_pytorch_tpu.models import decode_cache
 from dalle_pytorch_tpu.models.attention import (
@@ -89,29 +90,55 @@ from dalle_pytorch_tpu.ops.shift import (
 )
 
 
-# the one policy name that is not `jax.checkpoint_policies`' own: keep what
-# the flash kernels' forward rule hands their backward, and nothing else.
-# ONE policy object for every layer: jax caches a jitted emitter's partial
-# evaluation by the policy's identity, and a policy made anew per layer
-# would lower each kernel body once per call site.
+# the two policy names that are not `jax.checkpoint_policies`' own, rungs of
+# one ladder of what a remat layer keeps beside its input: `flash_residuals`
+# what the flash kernels' forward rule hands their backward and nothing
+# else, `layer_residuals` those and the feed-forward's two products.
+# ONE policy object a name for every layer: jax caches a jitted emitter's
+# partial evaluation by the policy's identity, and a policy made anew per
+# layer would lower each kernel body once per call site.
 FLASH_RESIDUALS = "flash_residuals"
+LAYER_RESIDUALS = "layer_residuals"
+FF_RESIDUAL_NAMES = ("ff_hidden", "ff_out")
 _KEEP_FLASH_RESIDUALS = jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
+_KEEP_LAYER_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    *RESIDUAL_NAMES, *FF_RESIDUAL_NAMES)
 
 
 def resolve_remat_policy(name: "Optional[str]"):
-    """`jax.checkpoint_policies` member by name, `FLASH_RESIDUALS`, or None
-    (save nothing). Single resolution point for all three executors (scan,
-    unrolled remat, pipeline) so their activation-memory behavior cannot
-    drift.
+    """`jax.checkpoint_policies` member by name, `FLASH_RESIDUALS`,
+    `LAYER_RESIDUALS`, or None (save nothing). Single resolution point for
+    all three executors (scan, unrolled remat, pipeline) so their
+    activation-memory behavior cannot drift.
 
-    `flash_residuals` keeps a layer's q, k, v, attention result and
-    log-sum-exp (`pallas_attention.RESIDUAL_NAMES`), B x N x (4 x dim x 2 +
-    heads x 4) bytes a layer at two bytes an element, so that the backward
-    runs neither the forward kernel nor the projection and rotary before
-    it a second time; a layer whose attention is not the flash kernel has
-    no such names and saves nothing."""
+    What a remat layer holds from its forward to its backward, in bytes a
+    layer at two bytes an element (B x N tokens, `dim` = heads x dim_head):
+
+    - None / `nothing_saveable`: the layer's input alone, B x N x dim x 2.
+      The backward runs the whole layer a second time.
+    - `flash_residuals`: + the flash kernels' q, k, v, attention result and
+      log-sum-exp (`pallas_attention.RESIDUAL_NAMES`), B x N x (4 x dim x 2
+      + heads x 4). The backward runs neither the forward kernel nor the
+      projection and rotary before it a second time.
+    - `layer_residuals`: + the feed-forward's first product as the GEGLU
+      reads it and its result (`FF_RESIDUAL_NAMES`), B x N x (2 x ff_mult +
+      1) x dim x 2. The backward reads the first where the GEGLU's gradient
+      needs it and the second where the LayerScale vector's does (its
+      gradient is the result times the cotangent), so neither product runs
+      twice; what it still builds again is the feed-forward's INPUT
+      (`to_out`, the token shift, a norm) and the GEGLU's elementwise pass.
+
+    The flagship step (16 x 1,280 x 1,024, 12 layers, `ff_mult` 4) plans
+    5.39 | 8.15 | 12.66 GB on one v5e, which loads 16.9 (14.65 without remat;
+    `tests/test_tpu_compile.py`). A layer whose attention is not the flash
+    kernel, or whose feed-forward is not `FeedForward`, has no such names
+    and keeps nothing for them. A deep trunk (64 layers keep 64 x 607 MB at
+    that batch) or a stack at its memory's edge asks for a leaner rung by
+    name."""
     if name == FLASH_RESIDUALS:
         return _KEEP_FLASH_RESIDUALS
+    if name == LAYER_RESIDUALS:
+        return _KEEP_LAYER_RESIDUALS
     return getattr(jax.checkpoint_policies, name) if name else None
 
 
@@ -145,11 +172,15 @@ class FeedForward(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray, deterministic: bool = True) -> jnp.ndarray:
         hidden = int(self.dim * self.mult)
-        x = nn.Dense(hidden * 2, dtype=self.dtype)(x)
+        # both products named, the first as the GEGLU reads it: a remat layer
+        # whose policy knows the names keeps them and multiplies once;
+        # anywhere else a name is the identity
+        name_hidden, name_out = FF_RESIDUAL_NAMES
+        x = checkpoint_name(nn.Dense(hidden * 2, dtype=self.dtype)(x), name_hidden)
         x, gates = jnp.split(x, 2, axis=-1)
         x = x * nn.gelu(gates)
         x = nn.Dropout(self.dropout)(x, deterministic=deterministic)
-        return nn.Dense(self.dim, dtype=self.dtype)(x)
+        return checkpoint_name(nn.Dense(self.dim, dtype=self.dtype)(x), name_out)
 
 
 class SwiGLU(nn.Module):
@@ -511,7 +542,8 @@ class Transformer(nn.Module):
     reversible_impl: str = "remat"  # "remat" | "revnet" | "revnet_naive" (test)
     # policy name for the remat executor (`resolve_remat_policy`):
     # "flash_residuals" keeps what the flash kernels' backward reads, so a
-    # layer's forward kernel runs once; "dots_with_no_batch_dims_saveable"
+    # layer's forward kernel runs once; "layer_residuals" the feed-forward's
+    # two products beside that; "dots_with_no_batch_dims_saveable"
     # keeps matmul outputs and recomputes the elementwise work alone.
     # None or "nothing_saveable" = save nothing (full recompute); that is
     # the default HERE, and each model class owns its own (`DALLE`,

@@ -826,8 +826,8 @@ def flash_attention(
     The forward rule hands the backward q, k, v as the kernel took them, its
     result and its log-sum-exp, each under its name of `RESIDUAL_NAMES`
     (`jax.ad_checkpoint.checkpoint_name`): a `jax.checkpoint` policy that
-    saves those names (`save_only_these_names`; the trunk's
-    `remat_policy="flash_residuals"`) keeps them across remat, and the
+    saves those names (`save_only_these_names`; the trunk's `remat_policy`
+    `"flash_residuals"`, `"layer_residuals"`) keeps them across remat, and the
     backward then runs neither this forward kernel nor what made q, k and v
     a second time. Outside a checkpoint, and under a policy that does not
     know them, a name is the identity and lowers to nothing.

@@ -73,12 +73,14 @@ class DalleConfig:
     attn_dropout: float = 0.0
     reversible: bool = False
     reversible_impl: str = "remat"  # remat | revnet
-    # what a remat layer keeps for its backward: by default the flash
-    # kernels' residuals (`DALLE.remat_policy`, which this mirrors: B x N x
-    # (4 x dim x 2 + heads x 4) bytes a layer); a jax.checkpoint policy such
-    # as "dots_with_no_batch_dims_saveable"; None or "nothing_saveable" =
-    # full recompute
-    remat_policy: "Optional[str]" = "flash_residuals"
+    # what a remat layer keeps for its backward (`DALLE.remat_policy`, which
+    # this mirrors; `resolve_remat_policy` has the ladder and its bytes): by
+    # default the flash kernels' residuals and the feed-forward's two
+    # products, B x N x ((5 + 2 x ff_mult) x dim x 2 + heads x 4) bytes a
+    # layer; "flash_residuals" the kernels' alone; a jax.checkpoint policy
+    # such as "dots_with_no_batch_dims_saveable"; None or "nothing_saveable"
+    # = full recompute
+    remat_policy: "Optional[str]" = "layer_residuals"
     loss_img_weight: float = 7.0
     attn_types: str = "full"  # comma separated
     shift_tokens: bool = False

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from dalle_pytorch_tpu.data.tokenizer import get_tokenizer
 from dalle_pytorch_tpu.models.dvae import DiscreteVAE
 from dalle_pytorch_tpu.models.dalle import DALLE
-from dalle_pytorch_tpu.models.transformer import FLASH_RESIDUALS
+from dalle_pytorch_tpu.models.transformer import FLASH_RESIDUALS, LAYER_RESIDUALS
 from dalle_pytorch_tpu.training.config import TrainConfig, VaeConfig, config_to_dict
 from dalle_pytorch_tpu.training.checkpoint import save_params_npz, load_params_npz
 from dalle_pytorch_tpu.version import __version__
@@ -179,11 +179,12 @@ def build_vae(cfg: TrainConfig, dtype=jnp.float32):
 
 
 # ready-to-use jax.checkpoint_policies predicates (the module's other
-# attributes are factories that require arguments), and the one name
+# attributes are factories that require arguments), and the two names
 # `resolve_remat_policy` builds from a factory itself
 REMAT_POLICIES = frozenset(
     {
         FLASH_RESIDUALS,
+        LAYER_RESIDUALS,
         "everything_saveable",
         "nothing_saveable",
         "dots_saveable",

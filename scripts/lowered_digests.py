@@ -12,6 +12,7 @@ usage: JAX_PLATFORMS=cpu python scripts/lowered_digests.py <tree> [<dump dir>] >
 import hashlib
 import json
 import os
+import re
 import sys
 
 tree = os.path.abspath(sys.argv[1])
@@ -31,7 +32,10 @@ out = {}
 
 
 def digest(name, lowered):
-    text = lowered.as_text()
+    # a private function's name ends in a count of the process's (`@_pad_335`):
+    # dropped, so that a program lowered EARLIER that traces one function more
+    # does not read as a change to every program after it
+    text = re.sub(r"(@\w+?)_\d+\b", r"\1", lowered.as_text())
     out[name] = [hashlib.sha256(text.encode()).hexdigest()[:16], len(text)]
     if dump:
         os.makedirs(dump, exist_ok=True)

@@ -462,7 +462,7 @@ def test_train_step_text_tells_forward_backward_and_recompute_apart(program_text
     for comp in ("attn_proj", "ff"):
         assert {(comp, "fwd"), (comp, "bwd"), (comp, "remat")} <= seen, comp
     # the model's default keeps the flash kernels' residuals across remat:
-    # no kernel runs in the recompute (`to_out` and `ff` do)
+    # no kernel runs in the recompute (`to_out` and `ff`'s GEGLU do)
     assert {("attn_kernel", "fwd"), ("attn_kernel", "bwd")} <= seen
     assert ("attn_kernel", "remat") not in seen
     assert ("optimizer", "fwd") in seen and ("loss", "bwd") in seen

@@ -581,8 +581,11 @@ def main():
             raise SystemExit(
                 f"train step does not fit device memory at batch_size="
                 f"{cfg.batch_size}, ga_steps={cfg.ga_steps}, model.reversible="
-                f"{cfg.model.reversible}: set model.reversible=true "
-                "(recompute activations), raise ga_steps (smaller "
+                f"{cfg.model.reversible}, model.remat_policy="
+                f"{cfg.model.remat_policy}: set model.reversible=true "
+                "(recompute activations) with a leaner model.remat_policy "
+                "(flash_residuals, then nothing_saveable: every layer keeps "
+                "less for its backward), raise ga_steps (smaller "
                 "microbatches) or lower batch_size. XLA said: "
                 + str(exc).splitlines()[0]
             ) from exc
