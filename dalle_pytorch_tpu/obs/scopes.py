@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import re
 import threading
+import time
 from typing import Dict, Iterable, Optional, Tuple
 
 from dalle_pytorch_tpu.utils import compile_guard
@@ -410,8 +411,11 @@ def remember(name: str, fn, args, **jit_kwargs) -> None:
 
 
 class remembering:
-    """A jitted function that remembers itself at its first dispatch; every
-    later call costs one attribute test."""
+    """A jitted function that remembers itself at its first dispatch, and
+    tells the compile ledger of every dispatch (`compile_guard.dispatched`:
+    when the call began and returned, and what to wait on for when the device
+    was done); with the ledger's stamping off a later call costs one
+    attribute test, with it on two clock reads and a record besides."""
 
     def __init__(self, jitted):
         self.jitted = jitted
@@ -419,6 +423,14 @@ class remembering:
         self._seen = False
 
     def __call__(self, *args):
+        if not compile_guard.stamping:
+            return self._dispatch(*args)
+        first, start = not self._seen, time.time()
+        out = self._dispatch(*args)
+        compile_guard.dispatched(self.name, id(self), first, start, time.time(), args, out)
+        return out
+
+    def _dispatch(self, *args):
         if self._seen:
             return self.jitted(*args)
         self._seen = True
