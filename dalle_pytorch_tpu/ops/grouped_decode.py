@@ -37,7 +37,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dalle_pytorch_tpu.ops.pallas_attention import NEG_INF, _dot, _NT, _use_interpret
+from dalle_pytorch_tpu.ops.pallas_attention import (
+    NEG_INF, _dot, _NT, _use_interpret, even_block,
+)
 
 # the most positions of one K/V head a grid step streams (`_block` splits a
 # leaf evenly under it). Timed alone on the chip at 24 rows x 8 K/V heads x
@@ -50,15 +52,8 @@ BLOCK_POSITIONS = 2560
 
 
 def _block(leaf: int) -> int:
-    """The positions a grid step streams: the leaf in the fewest blocks of at
-    most BLOCK_POSITIONS, as even as whole lane tiles (128 positions) allow.
-    A step computes one block while the next is fetched, so a short last
-    block's fetch beside a full block's products hides nothing, and a full
-    block's fetch beside the short one's products is waited for: 16,960
-    positions are 7 blocks of 2,432 (the last 2,368), not 6 of 2,560 and one
-    of 1,600."""
-    steps = -(-leaf // BLOCK_POSITIONS)
-    return min(leaf, -(-leaf // (128 * steps)) * 128)
+    """The positions a grid step streams: `even_block`'s, under BLOCK_POSITIONS."""
+    return even_block(leaf, BLOCK_POSITIONS)
 
 
 #: the block each call traced so far got, by (rows, K/V heads, query rows a

@@ -18,7 +18,9 @@ memory is its largest cost.
 `latent_decode_attention` is the one implementation on the path: a Pallas
 kernel (`decode_latent`) that streams blocks of positions past the row's
 resident queries with a running softmax in float32 and stops at the row's
-length: blocks past it are neither read nor computed. Interpreted on the
+length: blocks past it are neither read nor computed. The blocks are
+`even_block`'s (the rule `decode_grouped` shares), the operands the cache's
+leaves as they are held, with no copy, pad or transpose. Interpreted on the
 CPU backend, like the other kernels.
 """
 
@@ -32,13 +34,22 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dalle_pytorch_tpu.ops.pallas_attention import NEG_INF, _dot, _NT, _use_interpret
+from dalle_pytorch_tpu.ops.pallas_attention import (
+    NEG_INF, _dot, _NT, _use_interpret, even_block,
+)
 
-# positions a grid step streams: 1.2 MB of 576 bf16. Timed on the chip at 64
-# rows x 8,192..8,480 live positions x 128 heads (PERF.md, PR 31): 2.26 ms at
-# 256, 1.59 ms at 512, 1.32 ms at 1,024; XLA's two batched products around a
-# float32 softmax over the whole length took 3.63 ms, and are not on the path
-BLOCK_POSITIONS = 1024
+# the most positions a grid step streams (`even_block` splits a leaf evenly
+# under it: 8,480 are 3 blocks of 2,944, 3.4 MB of 576 bf16 each; 2,048 are
+# one). Timed alone on the chip (PERF.md, PR 50; us a call) at 64 rows x
+# 8,193..8,480 live of 8,480 x 128 heads: 1,270 at 1,024 (9 steps a row, the
+# last of 288: what PR 31's powers of two chose), 1,234 at 2,048 (5, the last
+# of 288), 1,110 at 1,792 (5), 1,049 at 2,176 (4), 1,035 at 2,944 (3: 73% of
+# the live positions' roofline, 754 us by the bytes and by the products alike);
+# 4,352 (2) does not fit the scoped VMEM; and at 16 rows x 2,048 of 2,048 live:
+# 89 at 1,024 (2 steps), 84 at 2,048 (1). A grid step costs about 0.5 us
+# whatever it holds and a block the length cuts short is computed whole. XLA's
+# two batched products around a float32 softmax took 3,630 (PR 31)
+BLOCK_POSITIONS = 2944
 
 
 def _kernel(lengths_ref, qc_ref, qr_ref, c_ref, kr_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -117,6 +128,7 @@ def latent_decode_attention(q_c, q_r, latent, rope, lengths, *, sm_scale, block=
     expansion absorbed, and their rotated part) against `latent` [B, L, R]
     and `rope` [B, dr, L] (positions last, as the cache keeps it), row b over
     its first `lengths[b]` positions."""
-    block = min(BLOCK_POSITIONS if block is None else block, latent.shape[1])
+    leaf = latent.shape[1]
+    block = even_block(leaf, BLOCK_POSITIONS) if block is None else min(block, leaf)
     return _emit(q_c, q_r, latent, rope, lengths, sm_scale=float(sm_scale), block=int(block),
                  interpret=_use_interpret())

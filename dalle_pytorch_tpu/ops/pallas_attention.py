@@ -298,6 +298,19 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
     )
 
 
+def even_block(leaf: int, cap: int) -> int:
+    """The cached positions a grid step of a streaming decode kernel takes
+    (ops/grouped_decode.py, ops/latent_decode.py): the leaf in the fewest
+    blocks of at most `cap`, as even as whole lane tiles (128 positions)
+    allow. A step computes one block while the next is fetched, so a short
+    last block's fetch beside a full block's products hides nothing, and a
+    full block's fetch beside the short one's products is waited for: under a
+    cap of 2,560, 16,960 positions are 7 blocks of 2,432 (the last 2,368), not
+    6 of 2,560 and one of 1,600."""
+    steps = -(-leaf // cap)
+    return min(leaf, -(-leaf // (128 * steps)) * 128)
+
+
 def _scores(q, kb, *, sm_scale, row0, col0, causal, mask, n_real_k, n_real_q=None,
             window=None):
     """fp32 [bq, bk] scores of one tile with everything that is not

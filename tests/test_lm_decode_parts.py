@@ -39,22 +39,55 @@ def cfg():
     return _cfg()
 
 
-@pytest.mark.parametrize("block", [8, 16, 64])
-def test_the_latent_decode_kernel_matches_dense_on_ragged_rows(block):
-    B, H, R, dr, L = 3, 4, 16, 8, 50
+@pytest.mark.parametrize("block,leaf,lengths", [
+    (8, 50, (50, 17, 1)), (16, 50, (50, 17, 1)), (64, 50, (50, 17, 1)),  # none a multiple of a block
+    (16, 50, (16, 32, 48)),  # a length on a block's edge: the row's last live block is whole
+    (16, 48, (48, 1, 33)),  # length 1, length = leaf = whole blocks, one position into a block
+    (128, 300, (300, 256, 257)),  # the leaf cuts the last block short: junk past it, in reach
+    (None, 300, (300, 129, 1)),  # the rule's block for the leaf: one, under the cap
+    (None, 50, (50, 17, 0)),  # a row with nothing live reads nothing and gives zeros
+])
+def test_the_latent_decode_kernel_matches_dense_on_ragged_rows(block, leaf, lengths):
+    B, H, R, dr, L = 3, 4, 16, 8, leaf
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q_c, q_r = jax.random.normal(ks[0], (B, H, R)), jax.random.normal(ks[1], (B, H, dr))
     latent, rope = jax.random.normal(ks[2], (B, L, R)), jax.random.normal(ks[3], (B, dr, L))
-    lengths = jnp.asarray([50, 17, 1])  # none a multiple of a block
+    lengths = jnp.asarray(lengths)
     got = latent_decode_attention(q_c, q_r, latent, rope, lengths, sm_scale=0.2, block=block)
     s = (jnp.einsum("bhr,blr->bhl", q_c, latent) + jnp.einsum("bhd,bdl->bhl", q_r, rope)) * 0.2
-    s = jnp.where(jnp.arange(L)[None, None] < lengths[:, None, None], s, -jnp.inf)
-    want = jnp.einsum("bhl,blr->bhr", jax.nn.softmax(s, -1), latent)
+    live = jnp.arange(L)[None, None] < lengths[:, None, None]
+    weights = jnp.where(live, jax.nn.softmax(jnp.where(live, s, -1e30), -1), 0)
+    want = jnp.einsum("bhl,blr->bhr", weights, latent)
     np.testing.assert_allclose(got, want, atol=2e-6)
     # positions past a row's length are never read: garbage there changes nothing
     junk = jnp.where(jnp.arange(L)[None, :, None] < lengths[:, None, None], latent, jnp.nan)
-    again = latent_decode_attention(q_c, q_r, junk, rope, lengths, sm_scale=0.2, block=block)
+    wild = jnp.where(jnp.arange(L)[None, None] < lengths[:, None, None], rope, jnp.nan)
+    again = latent_decode_attention(q_c, q_r, junk, wild, lengths, sm_scale=0.2, block=block)
     np.testing.assert_array_equal(got, again)
+
+
+@pytest.mark.parametrize("leaf,block,steps", [
+    (8480, 2944, 3),  # `pangu.decode.8k`: 2 x 2,944 and 2,592, not 8 x 1,024 and 288
+    (2048, 2048, 1),  # `deepseek32.decode.32k`'s fetched positions
+    (2944, 2944, 1), (2945, 1536, 2), (33792, 2816, 12), (50, 50, 1),
+])
+def test_the_latent_kernels_blocks_are_the_one_rules_under_its_own_cap(leaf, block, steps):
+    """What the sweep chose (ops/latent_decode.py's comment), from the rule
+    `decode_grouped` takes its blocks from: one function, a cap a kernel."""
+    from dalle_pytorch_tpu.ops import grouped_decode, latent_decode, pallas_attention
+
+    assert latent_decode.BLOCK_POSITIONS == 2944
+    got = pallas_attention.even_block(leaf, latent_decode.BLOCK_POSITIONS)
+    assert (got, -(-leaf // got)) == (block, steps)
+    assert latent_decode.even_block is grouped_decode.even_block is pallas_attention.even_block
+    assert grouped_decode._block(leaf) == pallas_attention.even_block(leaf, grouped_decode.BLOCK_POSITIONS)
+    # and the call with no `block=` walks the leaf in those
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda *a: latent_decode.latent_decode_attention(*a, sm_scale=1.0))(
+        f32(2, 4, 16), f32(2, 4, 8), f32(2, leaf, 16), f32(2, 8, leaf), jnp.zeros(2, jnp.int32))
+    (call,) = [e for e in jaxpr.eqns[0].params["jaxpr"].eqns if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (2, steps)
+    assert call.params["grid_mapping"].block_mappings[2].block_shape[1].block_size == block
 
 
 def _layer_by_loop(params, x, k, held, scale):

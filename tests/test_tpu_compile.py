@@ -736,9 +736,11 @@ def test_routed_layer_moves_walk_the_rows_present(one_chip, compiled_kernels, mo
 
 def test_latent_decode_attention_compiles(one_chip, monkeypatch):
     """`decode_latent` at the generation cell's shapes (64 rows, 128 heads
-    against one latent of 512 + a shared rotary key of 64, 8,480 positions,
-    blocks of 1,024): Mosaic takes the block the cache's end cuts short, the
-    rotary key with its positions last, and the VMEM the block asks for; and
+    against one latent of 512 + a shared rotary key of 64, 8,480 positions in
+    3 blocks of 2,944): Mosaic takes the block the cache's end cuts short, the
+    rotary key with its positions last, and the VMEM the block asks for (two
+    buffers of 3.0 MB, the block's masked copy and its float32 scores and
+    weights, under the scoped 16 MiB, which two blocks of 4,352 pass); and
     the cache's two leaves reach the kernel as they are declared, with no
     copy of either (a 64-wide last axis was transposed and copied back at
     every call: models/decode_cache.py)."""
@@ -753,7 +755,8 @@ def test_latent_decode_attention_compiles(one_chip, monkeypatch):
     assert re.search(r"%decode_latent[.\d]* = bf16\[64,128,512\]", text)
     assert not re.search(r"= bf16\[64,(8480,512|64,8480)\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
-    assert ld.BLOCK_POSITIONS == 1024
+    assert (ld.BLOCK_POSITIONS, ld.even_block(8480, ld.BLOCK_POSITIONS)) == (2944, 2944)
+    assert len(re.findall(r"%decode_latent[.\d]* = ", text)) == 1
 
 
 @pytest.mark.parametrize("rows,k,n", [
@@ -995,7 +998,9 @@ def test_selection_and_sparse_attend_compile_without_a_sort(one_chip, monkeypatc
                             shape(jnp.int32, 16, 2048), _i32(one_chip, 16))
         text = compiled.as_text()
         assert len(re.findall(r" gather\(", text)) == gathers
-        assert re.search(r"%decode_latent[.\d]* = bf16\[16,128,512\]", text)
+        # ONE call over the 2,048 fetched, which the rule walks as one block
+        assert len(re.findall(r"%decode_latent[.\d]* = bf16\[16,128,512\]", text)) == 1
+        assert latent_decode.even_block(2048, latent_decode.BLOCK_POSITIONS) == 2048
         if gathers == 1:  # fetched where it lies: no copy of the leaf in front of the gather
             assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2**20
 
